@@ -153,47 +153,31 @@ def _replace_opt_moments(state_abstract, stored_mu, stored_nu):
 
 
 def pytree_restore_args(item, **kw):
-    """``ocp.args.PyTreeRestore`` with partial restore across orbax
-    versions: newer orbax spells it ``partial_restore=True``; older ones
-    (< 0.9) restore exactly the paths present in ``item`` when given an
-    empty ``transforms`` dict."""
-    try:
-        return ocp.args.PyTreeRestore(item, partial_restore=True, **kw)
-    except TypeError:
-        # old orbax demands restore_args mirroring the item structure
-        # alongside transforms
-        kw.setdefault(
-            "restore_args", ocp.checkpoint_utils.construct_restore_args(item)
-        )
-        return ocp.args.PyTreeRestore(item, transforms={}, **kw)
+    """``ocp.args.PyTreeRestore`` restoring exactly the paths present in
+    ``item`` (orbax's ``partial_restore``)."""
+    return ocp.args.PyTreeRestore(item, partial_restore=True, **kw)
 
 
 def item_metadata_tree(manager, step: int, name: str = "state"):
-    """Tree of a checkpoint item's metadata across orbax versions (newer
-    managers wrap it in an object with a ``.tree`` attribute).
+    """Tree of a checkpoint item's metadata.
 
     A manager that has not saved in THIS process has no handler
     registered for ``name`` yet and reports the item's metadata as None
     (resume flows hit this); fall back to a throwaway manager with an
     explicit ``StandardCheckpointHandler`` registration, which resolves
     metadata without touching the caller's manager or the checkpoint.
-    Returns None when no metadata can be resolved (ancient orbax)."""
+    Returns None when the checkpoint holds no metadata for ``name``."""
     meta = manager.item_metadata(step)[name]
     if meta is None:
+        reader = ocp.CheckpointManager(
+            manager.directory,
+            item_handlers={name: ocp.StandardCheckpointHandler()},
+        )
         try:
-            reader = ocp.CheckpointManager(
-                manager.directory,
-                item_handlers={name: ocp.StandardCheckpointHandler()},
-            )
-            try:
-                meta = reader.item_metadata(step)[name]
-            finally:
-                reader.close()
-        except (TypeError, AttributeError):
-            return None
-    if meta is None:
-        return None
-    return meta.tree if hasattr(meta, "tree") else meta
+            meta = reader.item_metadata(step)[name]
+        finally:
+            reader.close()
+    return None if meta is None else meta.tree
 
 
 class Checkpointer:
@@ -444,8 +428,7 @@ class Checkpointer:
         if not os.path.isdir(root):
             return False
         try:
-            fin = getattr(ocp.utils, "is_checkpoint_finalized", None)
-            if fin is not None and not fin(root):
+            if not ocp.utils.is_checkpoint_finalized(root):
                 return False
         except ValueError:
             # orbax raises on tmp-suffixed/unfinalized layouts
@@ -457,9 +440,9 @@ class Checkpointer:
         if not os.path.isdir(item) or not os.listdir(item):
             return False
         # metadata must PARSE: a truncated payload loses its manifest /
-        # _METADATA and the readers raise. None (ancient orbax that
-        # cannot resolve metadata at all) stays permissive — the
-        # structural checks above already ran.
+        # _METADATA and the readers raise. A checkpoint without
+        # metadata (None) stays permissive — the structural checks
+        # above already ran.
         try:
             item_metadata_tree(self.manager, step)
         except Exception:
@@ -627,9 +610,9 @@ class Checkpointer:
                 stored_shapes = [tuple(l.shape)
                                  for l in jax.tree.leaves(stored_mu)]
             except (KeyError, TypeError, AttributeError):
-                # metadata unresolvable (ancient orbax): same-arm
-                # restores still work; a true cross-arm restore will
-                # fail loudly at shape-intersection time below
+                # metadata unresolvable: same-arm restores still work;
+                # a true cross-arm restore will fail loudly at
+                # shape-intersection time below
                 stored_shapes = like_shapes
             if stored_shapes != like_shapes:
                 abstract = _replace_opt_moments(abstract, stored_mu, stored_nu)

@@ -245,9 +245,8 @@ def sublane_padding_waste(per_chip_batch: int) -> float:
     a remainder of exactly 4 and sub-tile packing for power-of-two sizes
     below 8 — the model behind the measured B=10 cliff: 10 pads to 16
     (60% waste) and ran 24.22 img/s/chip where B=12 (tiles as 8+4, no
-    waste) ran 58.56 and B=8 54.46 (same session,
-    ``MEASUREMENTS_r5.md`` phC rows, docs/PERFORMANCE.md). Returns 0.0 for
-    well-tiled sizes.
+    waste) ran 58.56 and B=8 54.46 (same session; round 5, before PR 1, one v5e chip;
+    docs/PERFORMANCE.md). Returns 0.0 for well-tiled sizes.
     """
     b = int(per_chip_batch)
     if b <= 0 or b % 8 in (0, 4) or b in (1, 2, 4):
@@ -272,8 +271,8 @@ def warn_bad_batch_tiling(
 ) -> str | None:
     """Warn when a per-chip row count pads >``threshold`` on the sublane
     axis — the measured 2.4x throughput cliff (B=10: 24.22 vs 58.56
-    img/s/chip at B=12, same-session A/B, ``MEASUREMENTS_r5.md`` phC
-    rows, docs/PERFORMANCE.md). Called at config build (``load_config``) and
+    img/s/chip at B=12, same-session A/B; round 5, before PR 1, one v5e chip;
+    docs/PERFORMANCE.md). Called at config build (``load_config``) and
     by ``bench.py`` so nobody walks into the cliff silently. Returns the
     warning message, or None when the size tiles fine. ``axis`` names
     the row axis being guarded (the per-chip global batch by default;
@@ -287,8 +286,8 @@ def warn_bad_batch_tiling(
     msg = (
         f"{axis} {per_chip_batch} pads {waste:.0%} on the TPU "
         f"sublane axis — the measured-cliff class (B=10 ran "
-        f"24.22 img/s/chip vs 58.56 at B=12, same session, "
-        f"MEASUREMENTS_r5.md / docs/PERFORMANCE.md). Use "
+        f"24.22 img/s/chip vs 58.56 at B=12, same session; "
+        f"round 5, before PR 1, one v5e chip). Use "
         f"{lo} or {hi} instead."
     )
     import warnings

@@ -39,9 +39,9 @@ def get_args_parser():
 
 
 def main(argv=None):
-    from dinov3_tpu.utils import respect_jax_platforms_env
+    from dinov3_tpu.utils import configure_compile_cache, require_accelerator
 
-    respect_jax_platforms_env()
+    configure_compile_cache()
     args = get_args_parser().parse_args(argv)
 
     from dinov3_tpu.configs import load_config
@@ -50,15 +50,10 @@ def main(argv=None):
     from dinov3_tpu.parallel import initialize_distributed, is_main_process
 
     cfg = load_config(args.config_file or None, overrides=list(args.opts))
-    device = str((cfg.get("MODEL") or {}).get("DEVICE", "tpu") or "tpu")
-    if device not in ("tpu", ""):
-        import jax
-
-        try:  # MODEL.DEVICE=cpu, as in the trainer
-            jax.config.update("jax_platforms", device)
-        except RuntimeError:
-            pass
     initialize_distributed()
+    # MODEL.DEVICE as in the trainer: no chip and no explicit CPU
+    # request is an error, not a CPU evaluation
+    require_accelerator((cfg.get("MODEL") or {}).get("DEVICE"))
     model, params = build_model_for_eval(cfg, args.ckpt)
     results = do_eval(
         cfg, model, params,

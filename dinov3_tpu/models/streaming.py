@@ -254,7 +254,6 @@ def bucketed_stream_scan(
         mesh = get_current_mesh()
     from jax.sharding import PartitionSpec as P
 
-    from dinov3_tpu.parallel.context import shard_map_compat
     from dinov3_tpu.parallel.sharding import (
         UPDATE_SHARD_AXES,
         hierarchy_axes,
@@ -320,7 +319,7 @@ def bucketed_stream_scan(
         (x, _), _ = jax.lax.scan(step, (x, buf0), jnp.arange(n_buckets))
         return x
 
-    return shard_map_compat(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, axes), P()),
         out_specs=P(),

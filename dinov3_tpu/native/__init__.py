@@ -1,9 +1,12 @@
 """Native (C++) host data-path kernels, loaded via ctypes.
 
 Compiled on first use with the system toolchain into
-``~/.cache/dinov3_tpu/`` (or ``DINOV3_TPU_NATIVE_DIR``); all callers fall
+``<checkout>/.native_build/`` (or ``DINOV3_TPU_NATIVE_DIR``) — inside the
+checkout, never the home directory, so a copy built from what git
+commits finds or builds everything next to itself. All callers fall
 back to the numpy implementations when the toolchain or the build is
-unavailable, so the framework never *requires* the native path —
+unavailable (said loudly: a WARNING, and ``describe()`` names which arm
+this process got), so the framework never *requires* the native path —
 it is a throughput optimization for the host side of the input pipeline
 (the device side is XLA/Pallas, see dinov3_tpu/ops).
 """
@@ -25,12 +28,14 @@ _SRC = os.path.join(os.path.dirname(os.path.abspath(__file__)), "normalize.cpp")
 _LOCK = threading.Lock()
 _LIB: ctypes.CDLL | None = None
 _TRIED = False
+_STATUS = "not loaded yet"
 
 
 def _cache_dir() -> str:
     return os.environ.get(
         "DINOV3_TPU_NATIVE_DIR",
-        os.path.join(os.path.expanduser("~"), ".cache", "dinov3_tpu"),
+        os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))), ".native_build"),
     )
 
 
@@ -39,8 +44,10 @@ def _build() -> str | None:
         tag = hashlib.sha256(f.read()).hexdigest()[:16]
     out_dir = _cache_dir()
     os.makedirs(out_dir, exist_ok=True)
+    global _STATUS
     so_path = os.path.join(out_dir, f"dinov3_native_{tag}.so")
     if os.path.exists(so_path):
+        _STATUS = f"loaded the already-built {so_path}"
         return so_path
     cmd = [
         "g++", "-O3", "-std=c++17", "-shared", "-fPIC",
@@ -49,15 +56,18 @@ def _build() -> str | None:
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
     except (OSError, subprocess.SubprocessError) as e:
-        logger.info("native build unavailable (%s); using numpy fallbacks", e)
+        _STATUS = f"build failed ({e}); numpy fallbacks in use"
+        logger.warning("native build unavailable (%s); using numpy "
+                       "fallbacks", e)
         return None
     os.replace(so_path + ".tmp", so_path)
+    _STATUS = f"built {so_path} with g++"
     logger.info("built native kernels: %s", so_path)
     return so_path
 
 
 def _load() -> ctypes.CDLL | None:
-    global _LIB, _TRIED
+    global _LIB, _TRIED, _STATUS
     if _LIB is not None or _TRIED:
         return _LIB
     with _LOCK:
@@ -65,6 +75,7 @@ def _load() -> ctypes.CDLL | None:
             return _LIB
         _TRIED = True
         if os.environ.get("DINOV3_TPU_NO_NATIVE"):
+            _STATUS = "switched off by DINOV3_TPU_NO_NATIVE; numpy fallbacks"
             return None
         so = _build()
         if so is None:
@@ -91,6 +102,13 @@ def _load() -> ctypes.CDLL | None:
 
 def native_available() -> bool:
     return _load() is not None
+
+
+def describe() -> str:
+    """Which arm this process runs: built here, found built, or the
+    numpy fallbacks (and why) — triggers the load."""
+    _load()
+    return _STATUS
 
 
 def _scale_bias(mean, std):

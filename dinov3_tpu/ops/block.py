@@ -248,18 +248,12 @@ def _zero3_stream_trans_in(stream_dtype, constrain: bool = True,
                     and stream_castable_path(path)
                     and jnp.issubdtype(p.dtype, jnp.floating)
                     and p.dtype != stream_dtype):
-                master = p
+                # the cast output inherits the master's (sharded)
+                # placement by propagation, so the replicated constraint
+                # below puts the all-gather AFTER the convert: the
+                # gather moves the bf16 stream, not fp32 master bytes,
+                # and carries this scope in its op_name
                 p = p.astype(stream_dtype)
-                if mesh is not None:
-                    # pin the cast output to the MASTER's (sharded)
-                    # placement: without this the replicated constraint
-                    # below back-propagates through the elementwise
-                    # convert and the partitioner inserts the all-gather
-                    # at the slice — moving fp32 master bytes instead of
-                    # the bf16 stream (measured on this backend)
-                    from jax.experimental.shard_alike import shard_alike
-
-                    p, _ = shard_alike(p, master)
             if lowp_kernels:
                 from dinov3_tpu.ops.lowp import lowp_kernel_path
 

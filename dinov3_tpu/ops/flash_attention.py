@@ -40,14 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:  # pltpu is importable on CPU builds too; guard anyway
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -68,9 +61,7 @@ def _block_sizes(n_padded: int, block_q: int = 512,
 
 
 def _vmem_spec(block_shape=None, index_map=None):
-    if _VMEM is None:  # pure-CPU jaxlib
-        return pl.BlockSpec(block_shape, index_map)
-    return pl.BlockSpec(block_shape, index_map, memory_space=_VMEM)
+    return pl.BlockSpec(block_shape, index_map, memory_space=pltpu.VMEM)
 
 
 # ---------------------------------------------------------------- forward

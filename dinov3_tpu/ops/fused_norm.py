@@ -34,22 +34,13 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pltpu imports fine on CPU builds; guard anyway
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 _BLOCK_ROWS = 256
 
 
 def _vmem_spec(block_shape=None, index_map=None):
-    if _VMEM is None:  # pure-CPU jaxlib
-        return pl.BlockSpec(block_shape, index_map)
-    return pl.BlockSpec(block_shape, index_map, memory_space=_VMEM)
+    return pl.BlockSpec(block_shape, index_map, memory_space=pltpu.VMEM)
 
 
 def _stats(x, eps):
@@ -237,14 +228,22 @@ def fused_layernorm(
 
         spec = _island_specs(mesh, x.shape)
         if spec is None:
+            # the kernel was chosen and cannot run on this layout: say
+            # so (an explicit force is an error; the env opt-in warns)
+            msg = (f"fused_layernorm: shape {tuple(x.shape)} does not map "
+                   f"onto mesh {dict(mesh.shape)}; running the XLA "
+                   "layernorm instead of the Pallas kernel")
+            if force:
+                raise ValueError(msg)
+            import warnings
+
+            warnings.warn(msg, stacklevel=2)
             return _xla_layernorm(x, scale.reshape(D), bias.reshape(D), eps)
 
         def _local(xs, s, b):
             return _ln_nd(xs, s, b, float(eps), interpret)
 
-        from dinov3_tpu.parallel.context import shard_map_compat
-
-        return shard_map_compat(
+        return jax.shard_map(
             _local, mesh=mesh,
             in_specs=(spec, P(None), P(None)),
             out_specs=spec,

@@ -49,7 +49,7 @@ with zero signature changes.
 CPU-harness honesty (docs/PERFORMANCE.md): XLA:CPU emulates the fp8/int8
 dots by upconversion, so the CPU tier pins numerics and the streamed
 collective-bytes census; the speed claim is banked by the phQ on-chip
-A/B (scripts/r6_queue.sh).
+A/B (not yet run).
 """
 
 from __future__ import annotations
@@ -240,24 +240,18 @@ def lowp_scales(hist_tree, arm: str, margin: float):
 # the quantized matmul (module-level custom_vjp; arm static)
 # ---------------------------------------------------------------------
 
-def _gather_codes(q, like=None):
+def _gather_codes(q):
     """Materialize (replicate) quantized codes for the dot under the
     ``zero3_stream`` scope — the SAME scope (and so the same census
     attribution and identical collective count) as the bf16 stream this
-    replaces, at 1-byte rates. ``like`` pins the codes to the sharded
-    master's placement first (the shard_alike discipline of
-    ``_zero3_stream_trans_in``: without it the replicated constraint
-    back-propagates through the elementwise quantizer and the
-    partitioner gathers the WIDE operand). No-op without a mesh."""
+    replaces, at 1-byte rates. The codes inherit the sharded master's
+    placement through the elementwise quantizer, so the gather moves
+    the codes, not the wide operand. No-op without a mesh."""
     from dinov3_tpu.parallel.context import get_current_mesh
     from dinov3_tpu.parallel.sharding import constrain_replicated
 
     mesh = get_current_mesh()
     with jax.named_scope("zero3_stream"):
-        if mesh is not None and like is not None:
-            from jax.experimental.shard_alike import shard_alike
-
-            q, _ = shard_alike(q, like)
         return constrain_replicated(q, mesh) if mesh is not None else q
 
 
@@ -277,7 +271,7 @@ def _lowp_matmul_fwd(arm, x, w, scale):
     spec = qspec(arm)
     scale = jax.lax.stop_gradient(scale.astype(jnp.float32))
     q_w = symmetric_quantize(w, scale, spec.qmax, spec.qdtype)
-    q_w_rep = _gather_codes(q_w, like=w)
+    q_w_rep = _gather_codes(q_w)
     with jax.named_scope("lowp_amax"):
         s_x = current_scale(x, spec.qmax)
     q_x = symmetric_quantize(x, s_x, spec.qmax, spec.qdtype)

@@ -29,27 +29,3 @@ def seq_axis_size() -> int:
     if mesh is None or "seq" not in mesh.shape:
         return 1
     return int(mesh.shape["seq"])
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=None):
-    """``jax.shard_map`` across jax versions.
-
-    Newer jax exposes it as ``jax.shard_map`` (with ``check_vma``);
-    older jaxlibs only have ``jax.experimental.shard_map.shard_map``
-    (same semantics, the flag is spelled ``check_rep``). All shard_map
-    islands in this package go through here so a version bump is a
-    one-line change.
-    """
-    import jax
-
-    if hasattr(jax, "shard_map"):
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw
-        )
-    from jax.experimental.shard_map import shard_map
-
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw
-    )

@@ -278,7 +278,7 @@ def reshard_state(
     summary record — the same JSONL stream the train loop's phase spans
     live in, so preemption/resize timelines read off one file.
     """
-    from dinov3_tpu.utils import donation_safe_argnums, hlo_collective_census
+    from dinov3_tpu.utils import hlo_collective_census
 
     same_devices = src.device_ids() == dst.device_ids()
     groups = _split_groups(state, src, dst)
@@ -312,7 +312,6 @@ def reshard_state(
                 tree, dst_sh, scope, convert,
                 donate=donate, with_census=with_census,
                 census_fn=hlo_collective_census,
-                donate_argnums_fn=donation_safe_argnums,
             )
         else:
             out, row = _transfer_group(
@@ -353,7 +352,7 @@ def reshard_state(
 
 
 def _jit_group(tree, dst_sh, scope, convert, *, donate, with_census,
-               census_fn, donate_argnums_fn):
+               census_fn):
     """One jitted collective program: src layout in, dst layout out,
     every inserted collective under ``scope``."""
 
@@ -368,7 +367,7 @@ def _jit_group(tree, dst_sh, scope, convert, *, donate, with_census,
     fn = jax.jit(
         prog,
         out_shardings=dst_sh,
-        donate_argnums=donate_argnums_fn((0,)) if donate else (),
+        donate_argnums=(0,) if donate else (),
     )
     t0 = time.perf_counter()
     lowered = fn.lower(tree)

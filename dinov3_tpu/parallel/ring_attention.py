@@ -47,11 +47,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 NEG_INF = -1e30
 
 
-def _axis_size(axis_name: str) -> int:
-    return (jax.lax.axis_size(axis_name) if hasattr(jax.lax, "axis_size")
-            else jax.lax.psum(1, axis_name))  # psum(1): pre-axis_size jax
-
-
 def _masked_logits(qf, kc, rseg, csegc, src, n_valid, reduce_dtype):
     """[B, h, C, C] logits of the local (pre-scaled) query chunk against
     one rotating K chunk. Two masks, both large-finite (the flash
@@ -85,7 +80,7 @@ def _ring_fwd_local(q, k, v, seg, *, axis_name, n_valid, reduce_dtype):
     lse [B, h, C, 1] log-sum-exp in reduce_dtype — the backward's
     softmax residual)."""
     B, C, h, d = q.shape
-    size = _axis_size(axis_name)
+    size = jax.lax.axis_size(axis_name)
     # the chunk-origin tracker feeds only the global-position pad mask;
     # left dead, its PartitionId lowering trips the SPMD partitioner on
     # the custom_vjp primal path (custom-call bodies are not inlined)
@@ -143,7 +138,7 @@ def _ring_bwd_local(q, k, v, seg, out, lse, dout, *, axis_name, n_valid,
     (same ppermute schedule), so after ``size`` rotations each chunk's
     gradient arrives back on the device that owns it, complete."""
     B, C, h, d = q.shape
-    size = _axis_size(axis_name)
+    size = jax.lax.axis_size(axis_name)
     my = (jax.lax.axis_index(axis_name) if n_valid is not None
           else jnp.zeros((), jnp.int32))  # see _ring_fwd_local
     scale = d ** -0.5
@@ -224,8 +219,6 @@ def _ring_bwd_local(q, k, v, seg, out, lse, dout, *, axis_name, n_valid,
 def _ring_islands(cfg):
     """(fwd_sm, bwd_sm) shard_map islands for one static config —
     rebuilt per trace (cheap), closing only over ``cfg``."""
-    from dinov3_tpu.parallel.context import shard_map_compat
-
     mesh, seq_axis, spec, seg_spec, lse_spec, n_valid, reduce_dtype = cfg
     kw = dict(axis_name=seq_axis, n_valid=n_valid,
               reduce_dtype=reduce_dtype)
@@ -238,23 +231,23 @@ def _ring_islands(cfg):
         return _ring_bwd_local(q, k, v, seg, out, lse, dout, **kw)
 
     if has_seg:
-        fwd_sm = shard_map_compat(
+        fwd_sm = jax.shard_map(
             lambda q, k, v, seg: fwd_island(q, k, v, seg), mesh=mesh,
             in_specs=(spec, spec, spec, seg_spec),
             out_specs=(spec, lse_spec),
         )
-        bwd_sm = shard_map_compat(
+        bwd_sm = jax.shard_map(
             lambda q, k, v, seg, out, lse, dout: bwd_island(
                 q, k, v, out, lse, dout, seg), mesh=mesh,
             in_specs=(spec, spec, spec, seg_spec, spec, lse_spec, spec),
             out_specs=(spec, spec, spec),
         )
     else:
-        fwd_sm = shard_map_compat(
+        fwd_sm = jax.shard_map(
             lambda q, k, v: fwd_island(q, k, v), mesh=mesh,
             in_specs=(spec, spec, spec), out_specs=(spec, lse_spec),
         )
-        bwd_sm = shard_map_compat(
+        bwd_sm = jax.shard_map(
             lambda q, k, v, out, lse, dout: bwd_island(
                 q, k, v, out, lse, dout), mesh=mesh,
             in_specs=(spec, spec, spec, spec, lse_spec, spec),

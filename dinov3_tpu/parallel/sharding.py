@@ -420,8 +420,17 @@ def constrain_replicated(x: jax.Array, mesh: Mesh | None = None) -> jax.Array:
         mesh = get_current_mesh()
     if mesh is None:
         return x
-    return jax.lax.with_sharding_constraint(
-        x, NamedSharding(mesh, jax.sharding.PartitionSpec()))
+    # an op of the CALLER's scope that keeps x's own (sharded)
+    # placement: the partitioner stamps a reshard with its producer's
+    # op_name, so without this anchor the gather of a value produced
+    # outside the scope (a scan's weight slice) carries the producer's
+    # name and the collective census cannot attribute it
+    # (traced values only: an eager array has no open dims to keep)
+    P = jax.sharding.PartitionSpec
+    if isinstance(x, jax.core.Tracer):
+        x = jax.lax.with_sharding_constraint(
+            x, NamedSharding(mesh, P(*[P.UNCONSTRAINED] * x.ndim)))
+    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, P()))
 
 
 def zero3_materialize_tree(tree: Any, mesh: Mesh | None = None) -> Any:

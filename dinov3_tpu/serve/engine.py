@@ -158,7 +158,6 @@ class PackedServeEngine:
             serve_pad_waste_floor,
             warn_serve_pad_waste,
         )
-        from dinov3_tpu.utils import donation_safe_argnums
 
         self.model = model
         self.params = params
@@ -197,7 +196,7 @@ class PackedServeEngine:
         # not a second program)
         step = make_serve_step(model, layout.max_segments_per_row,
                                patch_features=self.patch_features)
-        jitted = jax.jit(step, donate_argnums=donation_safe_argnums((1,)))
+        jitted = jax.jit(step, donate_argnums=(1,))
         abstract = jax.tree.map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
             (self.params, self._ring) + self._abstract_planes())

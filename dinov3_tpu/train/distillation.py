@@ -71,9 +71,8 @@ def load_teacher_params(cfg: ConfigNode, state, state_shardings):
             lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
             target, state_shardings.params["teacher"],
         )
-        # version-gated partial restore (checkpoint.pytree_restore_args):
-        # this orbax TypeErrors on a raw partial_restore=True kwarg —
-        # same gate build_model_for_eval uses (models/__init__.py)
+        # partial restore of the teacher subtree only
+        # (checkpoint.pytree_restore_args, as build_model_for_eval does)
         restored = manager.restore(
             step,
             args=ocp.args.Composite(

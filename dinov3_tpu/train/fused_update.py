@@ -35,7 +35,7 @@ leaf-for-leaf equivalence over multi-step runs. The engine reuses the
 chain's ``ScheduledAdamWState`` pytree unchanged: checkpoints, sharding
 derivation (train/setup.py eval_shape) and buffer donation are
 identical on both paths. Toggle with ``optim.fused_update`` (default
-on); the bench A/B rung is armed in scripts/r6_queue.sh.
+on); the bench A/B rung has not been run on the chip.
 
 Cross-replica SHARDED update (``make_sharded_update``, toggled by
 ``optim.sharded_update``, auto = on when the data-parallel axis product
@@ -72,7 +72,7 @@ by scripts/cost_sharded_update.py so the committed census shows the
 post-rewrite collective set on any backend. The replicated fused engine
 stays the test oracle behind ``optim.sharded_update=false``
 (leaf-for-leaf equivalence pinned in tests/test_sharded_update.py);
-the on-chip A/B is armed as scripts/r6_queue.sh phZ.
+the on-chip A/B has not been run on the chip.
 """
 
 from __future__ import annotations
@@ -570,7 +570,6 @@ def make_sharded_update_schedule(
     what the data-parallel backward holds before any grad sync), and
     ``opt_state`` is in the flat sharded layout (``sharded_adam_zeros``).
     """
-    from dinov3_tpu.parallel.context import shard_map_compat
     from dinov3_tpu.parallel.sharding import (
         UPDATE_SHARD_AXES,
         update_shard_size,
@@ -676,7 +675,7 @@ def make_sharded_update_schedule(
             p_full = jax.tree.map(gather, p_new)
             return p_full, t_full, new_mu, new_nu, norms
 
-        p_full, t_full, new_mu, new_nu, norms = shard_map_compat(
+        p_full, t_full, new_mu, new_nu, norms = jax.shard_map(
             body, mesh=mesh,
             in_specs=(shard_spec, shard_spec, tf_spec, shard_spec,
                       shard_spec, mults_spec, rep_spec, rep_spec, rep_spec),
@@ -1289,7 +1288,6 @@ def make_bucketed_update_schedule(
     ``make_sharded_update_schedule`` (stacked [dp, *leaf] grad
     partials), ``opt_state`` in the bucket layout.
     """
-    from dinov3_tpu.parallel.context import shard_map_compat
     from dinov3_tpu.parallel.sharding import (
         UPDATE_SHARD_AXES,
         update_shard_size,
@@ -1432,7 +1430,7 @@ def make_bucketed_update_schedule(
             return (p_full, t_full, cat_shards(new_mu),
                     cat_shards(new_nu), norms)
 
-        p_full, t_full, new_mu, new_nu, norms = shard_map_compat(
+        p_full, t_full, new_mu, new_nu, norms = jax.shard_map(
             body, mesh=mesh,
             in_specs=(shard_spec, shard_spec, tf_spec, shard_spec,
                       shard_spec, mults_spec, rep_spec, rep_spec,
@@ -1809,7 +1807,6 @@ def make_zero3_gather_schedule(
     """
     import jax.tree_util as jtu
 
-    from dinov3_tpu.parallel.context import shard_map_compat
     from dinov3_tpu.parallel.sharding import (
         hierarchy_axes,
         split_staging_order,
@@ -1920,7 +1917,7 @@ def make_zero3_gather_schedule(
             for i in range(len(leaves))
         )
         out_specs = tuple(P() for _ in leaves)
-        out = shard_map_compat(
+        out = jax.shard_map(
             body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
             check_vma=False,
         )(*leaves)

@@ -616,17 +616,11 @@ def _build_train_setup(
     )
     rep = replicated(mesh)
     scalar_shardings = {"teacher_temp": rep, "momentum": rep}
-    from dinov3_tpu.utils import donation_safe_argnums
-
     step_fn = jax.jit(
         raw_step,
         in_shardings=(state_shardings, b_shardings, scalar_shardings, rep),
         out_shardings=(state_shardings, None),
-        # donation is dropped on jaxlib<=0.4.36 cpu with the persistent
-        # compile cache on: deserialized executables there lose the
-        # aliasing table and return donated state STALE (see
-        # utils.donation_safe_argnums)
-        donate_argnums=donation_safe_argnums((0,)),
+        donate_argnums=(0,),
     )
 
     # async metrics ring (telemetry/, auto=on; the per-step-fetch oracle
@@ -662,7 +656,7 @@ def _build_train_setup(
                               scalar_shardings, rep),
                 out_shardings=(state_shardings, ring_shardings),
                 # state AND ring donated: the ring write is in-place
-                donate_argnums=donation_safe_argnums((0, 1)),
+                donate_argnums=(0, 1),
             )
             return TelemetryPlan(
                 step_fn=t_step, metric_names=names, ring_len=ring_len,

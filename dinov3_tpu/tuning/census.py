@@ -127,7 +127,7 @@ KNOB_REGISTRY: dict = {
         "kind": "justified",
         "why": "precision-arm mode switch (bf16|fp8|int8), not a "
                "magnitude — its cost story is COST_LP_r21.json and "
-               "the phQ on-chip A/B (scripts/r6_queue.sh)"},
+               "the phQ on-chip A/B (not yet run)"},
     "train.low_precision.amax_history_len": {
         "kind": "justified",
         "why": "delayed-scaling amax ring length — the Transformer "

@@ -107,10 +107,6 @@ def run_arm(name: str, out: str, train_dir: str, val_dir: str,
 
 def main():
     from dinov3_tpu.data.textures import materialize_textures
-    from dinov3_tpu.utils import respect_jax_platforms_env
-
-    respect_jax_platforms_env()
-
     out = sys.argv[1] if len(sys.argv) > 1 else "/tmp/ablation_run"
     steps = int(os.environ.get("ABL_STEPS", "1200"))
     eval_every = int(os.environ.get("ABL_EVAL_EVERY", "400"))

@@ -43,7 +43,7 @@ CPU-harness caveat (docs/OBSERVABILITY.md): XLA:CPU runs each simulated
 device's thunks sequentially on one worker thread, so measured overlap
 fractions here are structural LOWER bounds — the committed numbers pin
 attribution, exposure ceilings, and backward-interval placement; the
-TPU overlap fractions bank when scripts/r6_queue.sh phA runs.
+TPU overlap fractions are not measured yet.
 
 Usage: JAX_PLATFORMS=cpu python scripts/anatomy_report.py [out] [--smoke]
 --smoke: dryrun + schema/attribution checks only (the CI tier-1 step).
@@ -456,12 +456,11 @@ def tiny_dryrun(steps: int = 8, window=(4, 6)) -> dict:
 
 
 def main():
-    from dinov3_tpu.utils import respect_jax_platforms_env
-
-    respect_jax_platforms_env()
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", "/tmp/jaxcache")
+    from dinov3_tpu.utils import configure_compile_cache
+
+    configure_compile_cache()
     from dinov3_tpu.telemetry.anatomy import round_floats
 
     dryrun = tiny_dryrun()
@@ -540,7 +539,7 @@ def main():
             "sequentially on one worker thread: overlap fractions are "
             "structural lower bounds, exposed-comm is the conservative "
             "ceiling. Attribution, scope split, and backward-interval "
-            "placement are exact. TPU overlap banks via r6_queue.sh phA."
+            "placement are exact. TPU overlap: not measured yet."
         ),
         "source": ("executed arm twins + tiny real-trainer dryrun under "
                    "jax.profiler, parsed by telemetry/anatomy.py "

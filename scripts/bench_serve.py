@@ -617,10 +617,15 @@ def main() -> int:
     if args.out is None:
         args.out = "SERVE_r16.json" if args.fleet else "SERVE_r14.json"
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import jax
 
     import bench
+    from dinov3_tpu.utils import configure_compile_cache, require_accelerator
+
+    configure_compile_cache()
+    # no CPU default: without the chip this fails unless the CPU is
+    # asked for explicitly (JAX_PLATFORMS=cpu, as CI's --smoke step does)
+    device = require_accelerator()
     from dinov3_tpu.configs.config import (
         apply_dot_overrides,
         get_default_config,
@@ -709,6 +714,9 @@ def main() -> int:
         "seed": args.seed,
         "n_per_mix": n,
         "backend": jax.default_backend(),
+        "platform": device["platform"],
+        "device_kind": device["device_kind"],
+        "device_count": device["count"],
         "layout": {
             "rows": layout.rows, "row_tokens": layout.row_tokens,
             "token_budget": layout.token_budget,

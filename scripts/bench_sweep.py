@@ -73,10 +73,9 @@ def main():
     specs = sys.argv[1:] or ["fused:DINOV3_FUSED_LN=1", "base:DINOV3_FUSED_LN=0"]
     import jax
 
-    from dinov3_tpu.utils import respect_jax_platforms_env
+    from dinov3_tpu.utils import configure_compile_cache
 
-    respect_jax_platforms_env()
-    jax.config.update("jax_compilation_cache_dir", "/tmp/jaxcache")
+    configure_compile_cache()
     results = {}
     for spec in specs:
         name, _, kvs = spec.partition(":")

@@ -313,9 +313,6 @@ def overlap_twin_census(cfg, dp: int, n_buckets: int = 4) -> dict:
 
 
 def main():
-    from dinov3_tpu.utils import respect_jax_platforms_env
-
-    respect_jax_platforms_env()
     import jax
 
     try:

@@ -29,7 +29,7 @@ Instruments (all on CPU, structural — no wall times):
   exactly). The serve ENGINE's features vs the in-step forward is a
   tolerance measurement, recorded as max|diff| over the executed step
   losses (bf16 packed program vs in-step program — the on-chip A/B is
-  armed as scripts/r6_queue.sh phD).
+  not yet run on the chip).
 - **cache hit == miss bitwise**: the replayed epoch's planes are
   array_equal to the first epoch's.
 - **attribution**: the teacher-source=serve train step compiles with
@@ -289,9 +289,6 @@ def loss_equivalence(teacher_yaml) -> dict:
 
 
 def main():
-    from dinov3_tpu.utils import respect_jax_platforms_env
-
-    respect_jax_platforms_env()
     import flax.linen as nn
     import jax
     import jax.numpy as jnp
@@ -346,8 +343,7 @@ def main():
             "through the precomputed-targets arm (shared "
             "teacher_targets_from_features tail); the packed engine's "
             "bf16 features vs the in-step forward is the recorded "
-            "loss-diff tolerance, priced on-chip by scripts/r6_queue.sh "
-            "phD."),
+            "loss-diff tolerance, not yet priced on the chip."),
         "source": ("TeacherServer/shared_teacher_server counters + "
                    "hlo_census of the teacher_source=serve train step "
                    "and the packed teacher program, steps executed"),

@@ -35,7 +35,7 @@ the K/V rotation O(N/s) per device. Two instruments, both on the
 CPU-harness honesty: nothing here times anything — XLA:CPU wall times
 would say nothing about TPU. The committed numbers are structural
 (collective censuses, compiled per-device memory stats, loss
-trajectories); the on-chip A/B is armed as scripts/r6_queue.sh phH.
+trajectories); the on-chip A/B has not been run on the chip.
 
 One JSON record -> COST_HIRES_r19.json (argv[1], default
 ./COST_HIRES_r19.json); also printed to stdout. ``--smoke`` runs the
@@ -245,9 +245,6 @@ def vitl_attention_twins() -> dict:
 
 
 def main():
-    from dinov3_tpu.utils import respect_jax_platforms_env
-
-    respect_jax_platforms_env()
     import jax
 
     try:
@@ -291,7 +288,7 @@ def main():
         "note": (
             "CPU harness: structural evidence only (censuses, compiled "
             "per-device memory stats, loss trajectories) — no wall "
-            "times; on-chip A/B armed as scripts/r6_queue.sh phH. "
+            "times; on-chip A/B not yet run on the chip. "
             "kernels.ring_min_seq=1 here is the test hook that makes "
             "17-token vit_test passes ring; shipped default 1024 keeps "
             "local crops dense"

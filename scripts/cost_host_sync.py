@@ -251,9 +251,6 @@ def measure_vitl_memory() -> dict:
 
 
 def main():
-    from dinov3_tpu.utils import respect_jax_platforms_env
-
-    respect_jax_platforms_env()
     import tempfile
 
     import jax

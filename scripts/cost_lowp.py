@@ -35,7 +35,7 @@ CPU mesh with the shipped ``build_train_setup`` step:
 
 Honesty caveat (docs/PERFORMANCE.md): XLA:CPU emulates fp8/int8 dot
 products by upconversion, so this artifact prices BYTES and pins
-NUMERICS; the speed story is the phQ on-chip A/B (scripts/r6_queue.sh).
+NUMERICS; the speed story is the phQ on-chip A/B (not yet run).
 
 One JSON record -> COST_LP_r21.json (argv[1], default
 ./COST_LP_r21.json); also printed to stdout. ``--smoke`` runs the
@@ -172,9 +172,6 @@ def arm_step(arm_overrides, n_steps: int, trace: bool = False) -> dict:
 
 
 def main():
-    from dinov3_tpu.utils import respect_jax_platforms_env
-
-    respect_jax_platforms_env()
     import math
 
     from dinov3_tpu.configs import get_default_config
@@ -265,7 +262,7 @@ def main():
             "this artifact prices the streamed-collective BYTES and "
             "pins the NUMERICS (trajectories, drift probe, bitwise "
             "bf16 control); the speed story is the phQ on-chip A/B "
-            "(scripts/r6_queue.sh). This container's XLA:CPU also "
+            " (not yet run). This container's XLA:CPU also "
             "float-normalizes the bf16 stream's gathers to f32 (the "
             "phW caveat), so the int8 byte ratio here overstates the "
             "on-chip 2x while fp8 lands at ~2x either way; the "

@@ -33,8 +33,8 @@ which bytes. The unrolled stack is compiled on every point (the scan
 caveat from count_flops.py: cost_analysis counts a scan body once).
 
 One JSON line on stdout -> commit as COST_PACK_r09.json. The on-chip
-A/B that measures what the TPU scheduler does with each form is armed
-as scripts/r6_queue.sh phP (both arms BENCH_PROBS=bf16 BENCH_CENSUS=1).
+A/B that measures what the TPU scheduler does with each form has not
+been run on the chip (both arms BENCH_PROBS=bf16 BENCH_CENSUS=1).
 
 Usage: JAX_PLATFORMS=cpu python scripts/cost_pack_student.py
 Env: COST_ARCH (default vit_large), COST_BATCH (default 12)
@@ -64,16 +64,12 @@ def _lane_pad_factor(n: int, lane: int = 128) -> float:
 
 
 def main():
-    from dinov3_tpu.utils import respect_jax_platforms_env
-
-    respect_jax_platforms_env()
     import jax
     import jax.numpy as jnp
 
-    jax.config.update(
-        "jax_compilation_cache_dir",
-        os.environ.get("BENCH_CACHE_DIR", "/tmp/jaxcache"),
-    )
+    from dinov3_tpu.utils import configure_compile_cache
+
+    configure_compile_cache()
 
     from dinov3_tpu.configs import apply_dot_overrides, get_default_config
     from dinov3_tpu.models import build_backbone

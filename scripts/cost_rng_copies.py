@@ -23,7 +23,7 @@ time (21,384 copy-done + 35,400 slice-done trace ops,
 PROFILE_r05.json), and the PR-2 census attributed ~98% of the 518
 compiled-step copies to RNG-scalar plumbing. This script is the
 committed host-side before/after for the engine that removes them; the
-on-chip A/B is armed as scripts/r6_queue.sh phR.
+on-chip A/B has not been run on the chip.
 
 One JSON line on stdout -> commit as COST_RNG_r08.json.
 
@@ -120,10 +120,6 @@ def student_fwd_census(cfg, B: int = 4) -> dict:
 
 
 def main():
-    from dinov3_tpu.utils import respect_jax_platforms_env
-
-    respect_jax_platforms_env()
-
     rec = {"arch": "vit_test", "granularity": {}}
     arms = {"plan_on": [], "plan_off": ["rng.plan=false"]}
     step = {t: ctp.copy_census(census_cfg(e), B=4) for t, e in arms.items()}

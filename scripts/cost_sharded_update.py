@@ -219,9 +219,6 @@ def measure(cfg, dp: int) -> dict:
 
 
 def main():
-    from dinov3_tpu.utils import respect_jax_platforms_env
-
-    respect_jax_platforms_env()
     import jax
 
     try:

@@ -293,9 +293,6 @@ def copy_census(cfg, B: int = 4) -> dict:
 
 
 def main():
-    from dinov3_tpu.utils import respect_jax_platforms_env
-
-    respect_jax_platforms_env()
     import jax.numpy as jnp
 
     from dinov3_tpu.configs import apply_dot_overrides, get_default_config

@@ -217,9 +217,6 @@ def engine_step(cfg_overrides, accum_steps: int, n_steps: int = 3) -> dict:
 
 
 def main():
-    from dinov3_tpu.utils import respect_jax_platforms_env
-
-    respect_jax_platforms_env()
     import jax
 
     try:

@@ -25,8 +25,8 @@ docs/PERFORMANCE.md):
   The engine's value is therefore structural — it guarantees the
   single-program form at the StableHLO level instead of relying on the
   backend seeing through four optax tree passes — and the on-chip A/B
-  (scripts/r6_queue.sh phU) is the measurement that decides what the
-  TPU scheduler actually does with each form.
+  (not yet run) is the measurement that decides what the TPU
+  scheduler actually does with each form.
 
 Everything in these programs is weight-shaped (grads, masters, moments,
 teacher and nothing else), so the totals ARE the weight-shaped
@@ -214,9 +214,6 @@ def main():
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={dp}").strip()
-    from dinov3_tpu.utils import respect_jax_platforms_env
-
-    respect_jax_platforms_env()
     if dp > 1:
         import jax
 

@@ -295,9 +295,6 @@ def vit7b_dryrun():
 
 
 def main():
-    from dinov3_tpu.utils import respect_jax_platforms_env
-
-    respect_jax_platforms_env()
     from dinov3_tpu.utils import hlo_collective_census
 
     arms = {}

@@ -42,7 +42,7 @@ POINTS = {
     "vitl_mask": ("vit_large", 8, 0, "mask", [_TWO_PASS]),
     "vitl_subset": ("vit_large", 8, 0, "subset", [_TWO_PASS]),
     # the r5 default program: B=12, the on-chip sweep peak
-    # (58.56 img/s/chip, MEASUREMENTS_r5.md phC row)
+    # (58.56 img/s/chip; round 5, before PR 1, one v5e chip)
     "vitl_subset_b12": ("vit_large", 12, 0, "subset", [_TWO_PASS]),
     # the PR-4 default program: crop-packed single-pass student (44
     # packed rows instead of 120; attention runs over 197-token rows
@@ -114,10 +114,9 @@ def main():
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    jax.config.update(
-        "jax_compilation_cache_dir",
-        os.environ.get("BENCH_CACHE_DIR", "/tmp/jaxcache"),
-    )
+    from dinov3_tpu.utils import configure_compile_cache
+
+    configure_compile_cache()
 
     out_path = sys.argv[1] if len(sys.argv) > 1 else "FLOPS.json"
     names = [p.strip() for p in os.environ.get(

@@ -117,11 +117,9 @@ def main():
     import jax
     import jax.numpy as jnp
 
-    from dinov3_tpu.utils import respect_jax_platforms_env
+    from dinov3_tpu.utils import configure_compile_cache
 
-    respect_jax_platforms_env()
-
-    jax.config.update("jax_compilation_cache_dir", "/tmp/jaxcache")
+    configure_compile_cache()
 
     from dinov3_tpu.configs import apply_dot_overrides, get_default_config
     from dinov3_tpu.data import make_synthetic_batch

@@ -52,10 +52,6 @@ def materialize_digits(root: str, img_px: int = 64) -> tuple[str, str]:
 
 
 def main():
-    from dinov3_tpu.utils import respect_jax_platforms_env
-
-    respect_jax_platforms_env()
-
     out = sys.argv[1] if len(sys.argv) > 1 else "/tmp/trajectory_run"
     steps = int(os.environ.get("TRAJ_STEPS", "600"))
     eval_every = int(os.environ.get("TRAJ_EVAL_EVERY", "100"))

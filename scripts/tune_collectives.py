@@ -40,7 +40,7 @@ CPU-harness honesty (docs/OBSERVABILITY.md): XLA:CPU runs each
 simulated device's thunks sequentially, so measured overlap is a
 structural lower bound and exposed-comm a conservative ceiling — the
 committed plan optimizes that conservative objective; on-chip
-re-derivation is armed as scripts/r6_queue.sh phC_tune_collectives.
+re-derivation has not been run on the chip.
 
 Usage:
   JAX_PLATFORMS=cpu python scripts/tune_collectives.py [out]
@@ -333,7 +333,7 @@ def assemble_plan(fingerprint, knob_trails, arms, search_note) -> dict:
             "bounds, exposed-comm a conservative ceiling — the plan "
             "optimizes that conservative objective. Attribution and "
             "scope split are exact. On-chip re-derivation: "
-            "scripts/r6_queue.sh phT2."),
+            "a chip run not yet made."),
     }
     return validate_plan(doc)
 
@@ -615,12 +615,11 @@ def full() -> None:
 def main() -> int:
     if CENSUS:
         return run_census()
-    from dinov3_tpu.utils import respect_jax_platforms_env
-
-    respect_jax_platforms_env()
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", "/tmp/jaxcache")
+    from dinov3_tpu.utils import configure_compile_cache
+
+    configure_compile_cache()
     if SMOKE:
         smoke()
     else:

@@ -128,22 +128,33 @@ def sharded_opt_init(params, sched, lm, wm, ll, dp=8):
     ))
 
 
-def assert_trees_bitwise(a, b, what, limit=None):
+def assert_trees_bitwise(a, b, what, limit=None, max_ulps=0):
+    """Leaf-for-leaf equality; ``max_ulps`` > 0 pins a MEASURED last-
+    digit difference between two arms (conftest.assert_within_ulps)
+    instead of bitwise equality."""
+    from conftest import assert_within_ulps
+
     fa = jax.tree_util.tree_flatten_with_path(a)[0]
     fb = jax.tree_util.tree_flatten_with_path(b)[0]
     assert len(fa) == len(fb), f"{what}: leaf count {len(fa)} != {len(fb)}"
     if limit:
         fa, fb = fa[:limit], fb[:limit]
     for (pa, la), (_, lb) in zip(fa, fb):
-        assert np.array_equal(np.asarray(la), np.asarray(lb)), (
-            f"{what}: bitwise mismatch at {jax.tree_util.keystr(pa)}")
+        where = f"{what}: {jax.tree_util.keystr(pa)}"
+        if np.asarray(la).dtype == np.float32:
+            assert_within_ulps(la, lb, max_ulps, where)
+        else:
+            assert np.array_equal(np.asarray(la), np.asarray(lb)), where
 
 
 def assert_trees_ulp(a, b, what, max_ulp=8):
     """Elementwise pin for the cross-arm fp32 outputs: PR-5 tolerances
-    AND an integer-ulp ceiling (the observed CPU FMA-contraction
-    context drift is 1-2 ulp; 8 leaves margin without letting a real
-    bug through)."""
+    AND a last-digit ceiling in units of each leaf's scale
+    (conftest.ulps_of_scale; the observed CPU FMA-contraction context
+    drift is 1-2; 8 leaves margin without letting a real bug
+    through)."""
+    from conftest import assert_within_ulps
+
     for (pa, la), (_, lb) in zip(
         jax.tree_util.tree_flatten_with_path(a)[0],
         jax.tree_util.tree_flatten_with_path(b)[0],
@@ -153,11 +164,8 @@ def assert_trees_ulp(a, b, what, max_ulp=8):
             la, lb, rtol=1e-6, atol=1e-7,
             err_msg=f"{what}: {jax.tree_util.keystr(pa)}")
         if la.dtype == np.float32:
-            ulp = np.abs(la.view(np.int32).astype(np.int64)
-                         - lb.view(np.int32).astype(np.int64))
-            assert ulp.max(initial=0) <= max_ulp, (
-                f"{what}: {jax.tree_util.keystr(pa)} drifted "
-                f"{ulp.max()} ulp")
+            assert_within_ulps(
+                la, lb, max_ulp, f"{what}: {jax.tree_util.keystr(pa)}")
 
 
 # ---------------- plan assembly + round-trips ----------------
@@ -287,9 +295,15 @@ def test_bucketed_matches_sharded(mesh8, clip):
             g = grads_like(params, k)
             p_s, t_s, s_s, n_s = s_step(g, p_s, t_s, s_s)
             p_b, t_b, s_b, n_b = b_step(g, p_b, t_b, s_b)
-            # the reduction path: moments + clip norms BITWISE per step
+            # the reduction path: clip norms and nu BITWISE per step;
+            # mu was bitwise under jax 0.4 and is now 1 last-digit unit
+            # of the leaf's scale apart at clip=3.0 (measured; 0 at the
+            # other clips). Pinned at this file's cross-arm ceiling of
+            # 8 such units (assert_trees_ulp), since the installed
+            # XLA:CPU does not round identically from run to run.
             assert_trees_bitwise(
-                s_s.adam.mu, plan.buckets_to_flat_tree(s_b.adam.mu), "mu")
+                s_s.adam.mu, plan.buckets_to_flat_tree(s_b.adam.mu), "mu",
+                max_ulps=8)
             assert_trees_bitwise(
                 s_s.adam.nu, plan.buckets_to_flat_tree(s_b.adam.nu), "nu")
             for k2 in n_s:
@@ -361,9 +375,11 @@ def test_bucketed_schedule_bitwise_and_census(mesh8):
                 params)
             p_s, t_s, s_s, norms_s = s_step(parts, p_s, t_s, s_s)
             p_c, t_c, s_b, norms_c = c_step(parts, p_c, t_c, s_b)
+            # measured under jax 0.9: 1 last-digit unit of the leaf's
+            # scale (bitwise under jax 0.4); ceiling as above
             assert_trees_bitwise(
                 s_s.adam.mu, plan.buckets_to_flat_tree(s_b.adam.mu),
-                "schedule mu")
+                "schedule mu", max_ulps=8)
             assert_trees_bitwise(
                 s_s.adam.nu, plan.buckets_to_flat_tree(s_b.adam.nu),
                 "schedule nu")
@@ -467,6 +483,17 @@ def test_setup_born_bucketed_and_toggles(eight_devices):
                 "optim.bucketed_collectives=true"], 8, eight_devices)
 
 
+# Cross-PROGRAM comparisons of params after two full steps: the two
+# programs' gradients differ in their last digits (reduction order), and
+# Adam's m/sqrt(v) turns that into a visible difference on the few
+# elements whose gradient is at noise level. Measured under jax 0.9 on
+# this mesh: at most 4.84e-6 on 0.1-10% of a leaf's elements — 2% of one
+# Adam step at this schedule's lr (2.5e-4) — where jax 0.4 happened to
+# stay under 1e-6. Pinned at 1e-5 (4% of a step); the moments and clip
+# norms keep their strict pins in the engine tests above.
+FULL_STEP_ATOL = 1e-5
+
+
 def test_full_step_bucketed_vs_perleaf(eight_devices):
     """Dryrun A/B at dp=8: 2 full steps from the same init, the
     bucketed arm matches the per-leaf oracle at the PR-5 dryrun
@@ -499,7 +526,7 @@ def test_full_step_bucketed_vs_perleaf(eight_devices):
         jax.tree_util.tree_flatten_with_path(st_b.params)[0][:64],
     ):
         np.testing.assert_allclose(
-            np.asarray(la), np.asarray(lb), rtol=5e-6, atol=1e-6,
+            np.asarray(la), np.asarray(lb), rtol=5e-6, atol=FULL_STEP_ATOL,
             err_msg=f"dryrun params {jax.tree_util.keystr(pa)}")
     mu_b = setup_b.bucket_plan.buckets_to_flat_tree(st_b.opt_state.adam.mu)
     for (pa, la), (_, lb) in zip(

@@ -1,0 +1,96 @@
+"""Compile the Pallas kernels for a DESCRIBED v5e chip, with no chip
+attached (on-chip-measurement guide §2.3): flash attention forward and
+backward, plain and segment-masked, at the 768 px (N=2309) and 1024 px
+(N=4101) ViT-L token counts, and the fused layernorm forward and
+backward at ViT-L width — each with ``interpret=False``, each asserting
+a Mosaic ``tpu_custom_call`` in the compiled text. What the chip's
+compiler would refuse (a slice off the tiling, too much VMEM) fails
+here, at no chip time. A compile that passes is not a chip run.
+
+The topology is described inside a module-scoped fixture, never at
+import: only one process may load the TPU library, and every xdist
+worker imports this file. Keep these tests in this ONE file.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A described (not attached) v5e chip; the persistent compilation
+    cache is off around the compiles — a compile for a described device
+    is written to the cache but cannot be read back without a chip."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    from dinov3_tpu.parallel.context import get_current_mesh, set_current_mesh
+
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    # no ambient mesh: an earlier test file of this worker may have left
+    # its 8-device CPU mesh registered, and the layernorm would then open
+    # a shard_map island on it instead of compiling for the one chip
+    prev_mesh = get_current_mesh()
+    set_current_mesh(None)
+    yield SingleDeviceSharding(topo.devices[0])
+    set_current_mesh(prev_mesh)
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    cc.reset_cache()
+
+
+def _compiled_text(fn, sharding, *shapes_dtypes) -> str:
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding)
+            for s, d in shapes_dtypes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("n_tokens", [2309, 4101])
+@pytest.mark.parametrize("masked", [False, True], ids=["plain", "seg"])
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_flash_attention_compiles_for_v5e(one_chip, n_tokens, masked,
+                                          direction):
+    from dinov3_tpu.ops.flash_attention import flash_attention
+
+    qkv = ((2, n_tokens, 16, 64), jnp.bfloat16)
+    seg = ((2, n_tokens), jnp.int32)
+
+    def fwd(q, k, v, s=None):
+        return flash_attention(q, k, v, interpret=False, seg=s)
+
+    def bwd(q, k, v, do, s=None):
+        return jax.vjp(lambda a, b, c: fwd(a, b, c, s), q, k, v)[1](do)
+
+    fn, shapes = (fwd, [qkv] * 3) if direction == "fwd" else (bwd, [qkv] * 4)
+    if masked:
+        shapes = shapes + [seg]
+    text = _compiled_text(fn, one_chip, *shapes)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_fused_layernorm_compiles_for_v5e(one_chip, direction):
+    from dinov3_tpu.ops.fused_norm import fused_layernorm
+
+    x = ((24, 201, 1024), jnp.bfloat16)
+    p = ((1024,), jnp.float32)
+
+    def fwd(x, s, b):
+        return fused_layernorm(x, s, b, eps=1e-6, interpret=False,
+                               force=True)
+
+    def bwd(x, s, b, dy):
+        return jax.vjp(fwd, x, s, b)[1](dy)
+
+    fn, shapes = (fwd, [x, p, p]) if direction == "fwd" else (
+        bwd, [x, p, p, x])
+    text = _compiled_text(fn, one_chip, *shapes)
+    assert "tpu_custom_call" in text

@@ -146,7 +146,7 @@ def test_dot_overrides_reject_scalar_to_section():
 def test_sublane_padding_waste_model():
     from dinov3_tpu.configs.config import sublane_padding_waste
 
-    # the measured triple (MEASUREMENTS_r5.md phC rows): B=10 pads to
+    # the measured triple (round 5, before PR 1, one v5e chip): B=10 pads to
     # 16, B=8 and B=12 (8+4) tile cleanly
     assert sublane_padding_waste(10) == pytest.approx(0.6)
     assert sublane_padding_waste(8) == 0.0

@@ -102,10 +102,10 @@ def test_flash_block_caps_honored():
 def test_auto_dispatch_threshold(monkeypatch):
     """The auto dispatch keeps every *measured* regime on dense XLA.
 
-    Full-step evidence (MEASUREMENTS_r5.md phF rows): dense beats flash at
-    N=201 (224px) and N=1029 (512px, 9.99 vs 7.65 img/s/chip), so auto
-    must choose xla there; flash stays reachable at 2309+ (768px) where
-    its O(N) memory is the point. Backend/kernel availability are
+    Full-step evidence (round 5, before PR 1, one v5e chip): dense beats
+    flash at N=201 (224px) and N=1029 (512px, 9.99 vs 7.65 img/s/chip),
+    so auto must choose xla there; flash stays reachable at 2309+
+    (768px) where its O(N) memory is the point. The backend is
     monkeypatched — this pins the threshold logic, not the TPU.
     """
     from dinov3_tpu.ops import attention as att
@@ -122,7 +122,6 @@ def test_auto_dispatch_threshold(monkeypatch):
 
     monkeypatch.setattr(att, "xla_attention", fake_xla)
     monkeypatch.setattr(att.jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(att, "_flash_available", lambda: True)
     import dinov3_tpu.ops.flash_attention as fa
 
     monkeypatch.setattr(fa, "flash_attention", fake_flash)

@@ -133,11 +133,14 @@ def test_fused_layernorm_multidevice_island_parity(eight_devices, axes, shape):
         set_current_mesh(prev)
 
 
-def test_fused_layernorm_multidevice_indivisible_rows_falls_back(
-    eight_devices,
+def test_fused_layernorm_multidevice_indivisible_rows_says_so(
+    eight_devices, monkeypatch,
 ):
-    """Row counts that don't divide the data axes must fall back to XLA
-    (not crash in shard_map)."""
+    """Row counts that don't divide the data axes cannot run the kernel
+    island. A kernel that was CHOSEN never gives way in silence: forced
+    (``force=True``) it raises; chosen by the opt-in rule it warns and
+    takes the XLA arm (same values), it does not crash in shard_map."""
+    import dinov3_tpu.ops.fused_norm as fn
     from dinov3_tpu.parallel import build_mesh
     from dinov3_tpu.parallel.context import get_current_mesh, set_current_mesh
     from dinov3_tpu.parallel.mesh import MeshSpec
@@ -149,7 +152,11 @@ def test_fused_layernorm_multidevice_indivisible_rows_falls_back(
         x = jax.random.normal(jax.random.key(6), (7, 128), jnp.float32)
         s = jnp.ones((128,), jnp.float32)
         b = jnp.zeros((128,), jnp.float32)
-        got = _pallas(x, s, b)
+        with pytest.raises(ValueError, match="does not map onto mesh"):
+            _pallas(x, s, b)
+        monkeypatch.setattr(fn, "use_pallas_layernorm", lambda D: True)
+        with pytest.warns(UserWarning, match="does not map onto mesh"):
+            got = fn.fused_layernorm(x, s, b, interpret=True)
         want = _xla_layernorm(x, s, b, 1e-6)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-6, atol=1e-6)

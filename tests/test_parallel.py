@@ -88,32 +88,41 @@ def test_sharded_train_step(eight_devices, axes):
 
 
 def test_sharded_matches_single_device(eight_devices):
-    """DPx(FSDP) global math == single-device math on the same batch."""
+    """DPx(FSDP) global math == single-device math on the same batch.
+
+    layerscale=1 for this comparison: at the recipe's 1e-5 the CLS
+    features of an untrained model collapse to ~1e-5 apart, and KoLeo's
+    -log(nearest-neighbour distance) then amplifies last-ulp reduction-
+    order noise into a 0.7% difference of that one term (measured:
+    12.1829 vs 12.2648, while the DINO/iBOT terms agree to 1e-7) — a
+    property of the loss at a collapsed init, not of the sharding. With
+    layerscale 1 every term of the two programs agrees to the last
+    printed digit, so the pin is strict."""
     B = 8
-    cfg = smol_cfg(["parallel.data=-1", "parallel.fsdp=2",
-                    "parallel.zero3=false"])
+    common = ["student.layerscale=1.0"]
+    cfg = smol_cfg(common + ["parallel.data=-1", "parallel.fsdp=2",
+                             "parallel.zero3=false"])
     batch = {k: jnp.asarray(v) for k, v in
              make_synthetic_batch(cfg, B, seed=0).items()}
 
     setup8 = build_train_setup(cfg, batch, devices=eight_devices)
-    cfg1 = smol_cfg(["parallel.data=1", "parallel.fsdp=1"])
+    cfg1 = smol_cfg(common + ["parallel.data=1", "parallel.fsdp=1"])
     setup1 = build_train_setup(cfg1, batch, devices=eight_devices[:1])
 
-    # identical init (same seed) -> identical first-step loss
+    # identical init (same seed): leaf for leaf, bitwise
+    for a, b in zip(jax.tree.leaves(setup8.state.params),
+                    jax.tree.leaves(setup1.state.params)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    # -> identical first-step loss, term by term
     d8 = put_batch(batch, setup8.batch_shardings)
     d1 = put_batch(batch, setup1.batch_shardings)
     _, m8 = setup8.step_fn(setup8.state, d8, setup8.scalars(0),
                            jax.random.key(0))
     _, m1 = setup1.step_fn(setup1.state, d1, setup1.scalars(0),
                            jax.random.key(0))
-    from conftest import legacy_tol
-
-    # jaxlib < 0.5 XLA:CPU: measured 8.4e-4 cross-program skew
-    # (documented in tests/conftest.py legacy_tol)
-    np.testing.assert_allclose(
-        float(m8["total_loss"]), float(m1["total_loss"]),
-        rtol=legacy_tol(2e-4, 2.5e-3),
-    )
+    for key in m1:
+        np.testing.assert_allclose(
+            float(m8[key]), float(m1[key]), rtol=2e-6, err_msg=key)
 
 
 def test_batch_sharding_divides_batch(eight_devices):

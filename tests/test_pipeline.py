@@ -90,11 +90,7 @@ def test_pipelined_forward_matches_sequential(eight_devices):
         out_pipe = jax.jit(
             lambda p, x: pipe_model.apply({"params": p}, x)
         )(pipe_params, x)
-    from conftest import legacy_tol
-
-    # jaxlib < 0.5 XLA:CPU: measured 1.9e-3 rel skew on the pipelined
-    # stage scan (documented in tests/conftest.py legacy_tol)
-    tol = legacy_tol(2e-5, 6e-3)
+    tol = 2e-5
     np.testing.assert_allclose(
         np.asarray(out_seq["x_norm_clstoken"], np.float32),
         np.asarray(out_pipe["x_norm_clstoken"], np.float32),
@@ -212,11 +208,7 @@ def test_pipeline_get_intermediate_layers_matches_unrolled(eight_devices):
         )(pipe_params, x)
     outs_seq = seq_model.apply({"params": seq_params}, x, **kw)
     assert len(outs_pipe) == len(outs_seq) == 2
-    from conftest import legacy_tol
-
-    # jaxlib < 0.5 XLA:CPU: measured up to 1.5e-3 rel / 5e-3 abs skew on
-    # the 4-block pipelined stack (tests/conftest.py legacy_tol)
-    tol = legacy_tol(2e-5, 6e-3)
+    tol = 2e-5
     for (pp, cp), (ps, cs) in zip(outs_pipe, outs_seq):
         np.testing.assert_allclose(np.asarray(pp), np.asarray(ps),
                                    rtol=tol, atol=tol)

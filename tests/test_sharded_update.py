@@ -258,6 +258,17 @@ def test_setup_born_sharded_and_toggles(eight_devices):
                 "optim.sharded_update=true"], 8, eight_devices)
 
 
+# Cross-PROGRAM comparisons of params after two full steps: the two
+# programs' gradients differ in their last digits (reduction order), and
+# Adam's m/sqrt(v) turns that into a visible difference on the few
+# elements whose gradient is at noise level. Measured under jax 0.9 on
+# this mesh: at most 4.84e-6 on 0.1-10% of a leaf's elements — 2% of one
+# Adam step at this schedule's lr (2.5e-4) — where jax 0.4 happened to
+# stay under 1e-6. Pinned at 1e-5 (4% of a step); the moments and clip
+# norms keep their strict pins in the engine tests above.
+FULL_STEP_ATOL = 1e-5
+
+
 @pytest.mark.parametrize("axes", [
     ["parallel.data=-1", "parallel.fsdp=2"],
     ["parallel.data=-1", "parallel.tensor=2"],
@@ -287,7 +298,7 @@ def test_full_step_sharded_vs_replicated(axes, eight_devices):
             results["false"][0].params)[0][:64],
     ):
         np.testing.assert_allclose(
-            np.asarray(la), np.asarray(lb), rtol=5e-6, atol=1e-6,
+            np.asarray(la), np.asarray(lb), rtol=5e-6, atol=FULL_STEP_ATOL,
             err_msg=f"dryrun params {jax.tree_util.keystr(pa)}")
 
 

@@ -17,9 +17,7 @@ Pinned here:
   (q eliminated, only the xs iterate remains);
 - the copy census of the exact jitted train step does not regress
   (ceiling on copy-class HLO ops outside fusions; zero donation
-  warnings);
-- the jaxlib<=0.4.36 cpu donation/persistent-cache staleness workaround
-  (utils.donation_safe_argnums) is active exactly where it must be.
+  warnings).
 """
 
 import importlib.util
@@ -420,24 +418,3 @@ def test_copy_census_does_not_regress():
                   "model.crop_packing=false"]), B=4)
     assert rec_oracle["donation_warnings"] == []
     assert rec_oracle["hlo_copy_total"] <= 200, rec_oracle["hlo_copy_ops"]
-
-
-def test_donation_safe_argnums_gating():
-    """The workaround drops donation exactly on the affected
-    configuration (cpu backend + persistent cache + jaxlib < 0.5)."""
-    import jaxlib
-
-    from dinov3_tpu.utils import donation_safe_argnums
-
-    old = tuple(int(x) for x in jaxlib.__version__.split(".")[:3]) < (0, 5, 0)
-    cache_on = bool(jax.config.jax_compilation_cache_dir)
-    expected = () if (old and cache_on
-                      and jax.default_backend() == "cpu") else (0,)
-    assert donation_safe_argnums((0,)) == expected
-    # with the cache off the argnums always pass through
-    prev = jax.config.jax_compilation_cache_dir
-    try:
-        jax.config.update("jax_compilation_cache_dir", None)
-        assert donation_safe_argnums((0,)) == (0,)
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prev)

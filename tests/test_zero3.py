@@ -87,13 +87,23 @@ def _flat_params(tree):
     return jtu.tree_flatten_with_path(tree)[0]
 
 
-def assert_trees_bitwise(a, b, what, limit=None):
-    fa, fb = _flat_params(a), _flat_params(b)
-    assert len(fa) == len(fb)
-    for (pa, la), (_, lb) in (zip(fa, fb) if limit is None
-                              else zip(fa[:limit], fb[:limit])):
-        assert np.array_equal(np.asarray(la), np.asarray(lb)), (
-            f"{what}: {jtu.keystr(pa)} differs")
+def assert_trees_bitwise(a, b, what, limit=None, max_ulps=0):
+    """Leaf-for-leaf equality; ``max_ulps`` > 0 pins a MEASURED last-
+    digit difference between two arms (conftest.assert_within_ulps)
+    instead of bitwise equality."""
+    from conftest import assert_within_ulps
+
+    fa = jax.tree_util.tree_flatten_with_path(a)[0]
+    fb = jax.tree_util.tree_flatten_with_path(b)[0]
+    assert len(fa) == len(fb), f"{what}: leaf count {len(fa)} != {len(fb)}"
+    if limit:
+        fa, fb = fa[:limit], fb[:limit]
+    for (pa, la), (_, lb) in zip(fa, fb):
+        where = f"{what}: {jax.tree_util.keystr(pa)}"
+        if np.asarray(la).dtype == np.float32:
+            assert_within_ulps(la, lb, max_ulps, where)
+        else:
+            assert np.array_equal(np.asarray(la), np.asarray(lb)), where
 
 
 # ---------------- layout / spec unit tests ----------------
@@ -231,9 +241,16 @@ def test_bitwise_equivalence_dp_only(arms_dp):
             # step-1 mu is (1-b1) * clipped grad: grads bitwise
             assert_trees_bitwise(st_z.opt_state.adam.mu,
                                  st_r.opt_state.adam.mu, "grads (mu)")
-    assert_trees_bitwise(st_z.params, st_r.params, "post-update masters")
+    # bitwise under jax 0.4; under jax 0.9 the two arms' fused updates
+    # round differently, and not identically from run to run: measured
+    # 3 last-digit units of the leaf's scale on the masters and 0 / 0.5
+    # / 1.5 on nu over five runs (values and grads stay bitwise). The
+    # pin is the suite's existing cross-arm ceiling of 8 such units
+    # (tests/test_buckets.py assert_trees_ulp) — 1e-6 of the scale.
+    assert_trees_bitwise(st_z.params, st_r.params, "post-update masters",
+                         max_ulps=8)
     assert_trees_bitwise(st_z.opt_state.adam.nu, st_r.opt_state.adam.nu,
-                         "nu")
+                         "nu", max_ulps=8)
     # the zero3 masters really are sharded (not silently replicated)
     from dinov3_tpu.telemetry.memory import layout_split
 
@@ -257,14 +274,21 @@ def test_dryrun_dp_fsdp(eight_devices):
     programs."""
     from dinov3_tpu.train import put_batch
 
+    # layerscale=1: at the recipe's 1e-5 the collapsed-init KoLeo term
+    # amplifies last-digit noise between two PROGRAMS into 2e-3 of the
+    # loss (measured here: 8.5426 vs 8.5593 at step 2; cause pinned in
+    # tests/test_parallel.py test_sharded_matches_single_device)
     common = ["parallel.data=-1", "parallel.fsdp=2",
-              "optim.sharded_update=false",
+              "optim.sharded_update=false", "student.layerscale=1.0",
               "compute_precision.compute_dtype=fp32"]
     s_z, batch = _setup(common + ["parallel.zero3=auto"], 16,
                         eight_devices)
     s_r, _ = _setup(common + ["parallel.zero3=false"], 16, eight_devices)
     assert s_z.zero3 and not s_r.zero3
-    state_r = jax.device_put(s_z.state, s_r.state_shardings)
+    # a COPY: device_put onto an equal sharding may hand back the same
+    # buffer, and the zero3 arm's step donates its state
+    state_r = jax.device_put(s_z.state, s_r.state_shardings,
+                             may_alias=False)
     results = {}
     for name, setup, state in (("zero3", s_z, s_z.state),
                                ("oracle", s_r, state_r)):
@@ -462,13 +486,13 @@ def test_checkpoint_replicated_zero3_roundtrip(tmp_path, eight_devices):
     rep_state = ck.restore(s_r.state, 1)
     assert_trees_bitwise(state1.params, rep_state.params,
                          "zero3 -> replicated params")
-    # the replicated arm RUNS from it
+    ck.save(2, rep_state)
+    ck.wait_until_finished()
+    # the replicated arm RUNS from it (last use: the step donates it)
     s_rep2, m_rep = s_r.step_fn(rep_state, d, s_r.scalars(1),
                                 jax.random.key(0))
     assert np.isfinite(float(m_rep["total_loss"]))
 
-    ck.save(2, rep_state)
-    ck.wait_until_finished()
     back = ck.restore(s_z.state, 2)
     assert_trees_bitwise(state1.opt_state, back.opt_state,
                          "round-trip opt state")
