@@ -1,0 +1,10 @@
+"""Step program: device time a step under student_backbone, forward (every
+student backbone apply: packed, global, local). Read from the device trace
+by phase_reduce.py; None where the trace carries no phase. Moves
+train_img_per_s_chip."""
+
+import phase_reduce
+
+
+def read(run):
+    return phase_reduce.metric(run, "train_student_fwd_ms_per_step")

@@ -19,6 +19,9 @@ dynamic twin: it parses a ``jax.profiler`` trace window
   intersected against the union of concurrent non-collective device
   work on its own device timeline — exposed-comm ms and overlapped
   fraction per scope, per step,
+- leaf device time by **compute phase** of the step
+  (``utils.STEP_PHASES``, forward and backward apart) from the same
+  join, with what no phase claims as "unattributed",
 - a measured **backward interval** per timeline (the time span of ops
   whose ``op_name`` carries jax's ``transpose(...)`` backward stamp),
   so "the grad-RS sits inside the backward pass" becomes a statement
@@ -68,6 +71,7 @@ _COLLECTIVE_KEYS = (
     "all-gather", "all-reduce", "reduce-scatter", "collective",
     "all-to-all", "psum", "permute",
 )
+_CONTAINER_OPCODES = frozenset(("while", "conditional", "call"))
 _COPY_OPCODES = frozenset((
     "copy", "copy-start", "copy-done", "transpose", "reshape", "bitcast",
     "slice", "dynamic-slice", "dynamic-update-slice", "concatenate",
@@ -126,7 +130,9 @@ def build_op_index(hlo_text: str) -> dict:
     ``coll_class`` (``utils.HLO_COLLECTIVE_CLASSES`` value or None),
     ``placement`` (``utils.hlo_collective_placement`` — while-loop /
     transpose markers in op_name), ``backward`` (op_name carries jax's
-    ``transpose(...)`` backward stamp).
+    ``transpose(...)`` backward stamp), ``phase`` and ``direction``
+    (``utils.classify_step_phase`` of the op_name: the step's compute
+    phase, ``utils.STEP_PHASES``, or None; "fwd" | "bwd").
 
     Fusion instructions are indexed with their called computation's
     body inspected: a fusion calling a computation that contains a
@@ -137,6 +143,7 @@ def build_op_index(hlo_text: str) -> dict:
     from dinov3_tpu.utils import (
         classify_collective,
         classify_collective_scope,
+        classify_step_phase,
         hlo_collective_placement,
     )
 
@@ -180,6 +187,7 @@ def build_op_index(hlo_text: str) -> dict:
         m = _OP_NAME_RE.search(line)
         if m and "transpose" in m.group(1):
             backward = True
+        phase, direction = classify_step_phase(m.group(1) if m else None)
         if coll_class is not None or is_done_half:
             category = "collective"
             scope = classify_collective_scope(line)
@@ -202,6 +210,8 @@ def build_op_index(hlo_text: str) -> dict:
             "coll_class": coll_class,
             "placement": placement,
             "backward": backward,
+            "phase": phase,
+            "direction": direction,
         }
     return index
 
@@ -274,24 +284,36 @@ def step_windows(events: list, n_steps: int | None = None) -> list:
 # ---------------------------------------------------------------------
 
 def _event_info(event, op_index: dict | None) -> dict:
-    """Category/scope/backward attribution for one trace op event:
+    """Category/scope/backward/phase attribution for one trace op event:
     exact from the HLO op index when the instruction is found, name
     heuristics otherwise. A collective-looking event MISSING from a
     provided index is scope "unattributed" — the structural-regression
-    bucket the artifact pins at zero."""
+    bucket the artifact pins at zero. ``phase_key``: where the event's
+    time goes in the per-step ``phases`` table — "<phase>/<fwd|bwd>",
+    "unattributed" for an op under no phase, None for a container
+    (``while``/``conditional``/``call``: its body's events are on the
+    same timeline and are counted themselves)."""
     info = (op_index or {}).get(event.op_key)
     if info is not None:
         scope = info["scope"]
+        if info["opcode"] in _CONTAINER_OPCODES:
+            phase_key = None
+        elif info["phase"] is None:
+            phase_key = "unattributed"
+        else:
+            phase_key = f"{info['phase']}/{info['direction']}"
         return {"category": info["category"],
                 "scope": scope if scope is not None else None,
                 "backward": info["backward"],
-                "placement": info["placement"]}
+                "placement": info["placement"],
+                "phase_key": phase_key}
     cat = categorize(event.name)
     scope = None
     if cat == "collective":
         scope = "unattributed" if op_index else "unscoped"
     return {"category": cat, "scope": scope, "backward": False,
-            "placement": None}
+            "placement": None,
+            "phase_key": "unattributed" if op_index else None}
 
 
 def anatomy_ledger(
@@ -334,6 +356,7 @@ def anatomy_ledger(
         default=0)
     for k in range(n_windows):
         acc_cat = {c: 0.0 for c in CATEGORIES}
+        phases: dict = {}
         coll: dict = {}
         busy = 0.0
         backward_ms = 0.0
@@ -365,6 +388,9 @@ def anatomy_ledger(
             for e, i in infos:
                 acc_cat[i["category"]] += e.dur / 1e3
                 tb += e.dur / 1e3
+                if i["phase_key"] is not None:
+                    phases[i["phase_key"]] = (
+                        phases.get(i["phase_key"], 0.0) + e.dur / 1e3)
                 if i["category"] != "collective":
                     continue
                 scope = i["scope"] or "unscoped"
@@ -399,6 +425,9 @@ def anatomy_ledger(
             "wall_ms": (t1 - t0) / 1e3 if t1 > t0 else 0.0,
             "device_busy_ms": busy,
             "device_ms": {c: v for c, v in acc_cat.items() if v > 0},
+            # leaf device time by compute phase of the step
+            # (utils.STEP_PHASES; empty without the compiled HLO)
+            "phases": phases,
             "collectives": coll,
             "exposed_comm_frac": exposed_total / busy if busy else 0.0,
             "backward_ms": backward_ms,
@@ -430,12 +459,15 @@ def ledger_summary(ledger: dict) -> dict:
     mean_wall = sum(walls) / n
     var = sum((w - mean_wall) ** 2 for w in walls) / n if steps else 0.0
     cats: dict = {}
+    phases: dict = {}
     coll: dict = {}
     busy = 0.0
     for s in steps:
         busy += s["device_busy_ms"]
         for c, v in s["device_ms"].items():
             cats[c] = cats.get(c, 0.0) + v
+        for k, v in s["phases"].items():
+            phases[k] = phases.get(k, 0.0) + v
         for scope, ent in s["collectives"].items():
             agg = coll.setdefault(scope, {
                 "ms": 0.0, "exposed_ms": 0.0, "overlapped_ms": 0.0,
@@ -474,6 +506,10 @@ def ledger_summary(ledger: dict) -> dict:
         "straggler_spread": sum(spreads) / n if steps else 0.0,
         "unattributed_collective_ms":
             ledger["unattributed_collective_ms"],
+        # the phase table needs the compiled HLO's op_names: the
+        # name-only path has none, and its summary keeps its old shape
+        **({"phases_ms_per_step": {k: v / n for k, v in phases.items()}}
+           if ledger["hlo_joined"] else {}),
     }
 
 

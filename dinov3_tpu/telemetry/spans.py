@@ -6,12 +6,17 @@ gram refresh, eval, checkpoint save — as JSON lines in
 ``<output-dir>/telemetry/spans[.rankN].jsonl``:
 
     {"name": "dispatch", "iteration": 17, "t": <epoch s at start>,
-     "dur_ms": 1.84}
+     "t_mono": <perf_counter s at start>, "dur_ms": 1.84}
 
 Durations come from ``time.perf_counter`` (monotonic); ``t`` is wall
-epoch time for cross-process alignment only. Memory samples ride the
-same stream as ``{"name": "memory", "point": "flush", ...}`` records
-(telemetry/memory.py).
+epoch time for cross-process alignment only. ``t_mono`` is the same
+monotonic clock's reading at the span's start: with the ``profile_stop``
+record's ``fence_mono`` (the moment the host saw the traced window's
+last device operation end) it lays a process's spans on the device
+trace's clock — ``device_ns = last_device_event_end_ns + (t_mono -
+fence_mono) * 1e9`` — the way ``benchmark/trace_reduce.py`` lays the
+benchmark's own. Memory samples ride the same stream as ``{"name":
+"memory", "point": "flush", ...}`` records (telemetry/memory.py).
 
 The heartbeat file (``<output-dir>/telemetry/heartbeat[.rankN]``) is
 rewritten at most once per ``heartbeat_every`` iterations with the last
@@ -119,6 +124,7 @@ class SpanTracer:
                 "name": name,
                 "iteration": None if iteration is None else int(iteration),
                 "t": round(t_wall, 6),
+                "t_mono": round(t0, 6),
                 "dur_ms": round((time.perf_counter() - t0) * 1e3, 4),
                 **fields,
             })
@@ -193,10 +199,22 @@ class SpanTracer:
         if self._profile and iteration == self._profile[0]:
             import jax
 
-            jax.profiler.start_trace(self._profile_dir)
+            options = jax.profiler.ProfileOptions()
+            if jax.devices()[0].platform == "tpu":
+                # with the host tracer on (any level) the TPU runtime
+                # stalls the traced program for hundreds of ms at a time
+                # (benchmark/run.py HOST_TRACER_LEVEL, chip runs of PR 24):
+                # the window holds device events only, and the spans are
+                # laid against it through ``fence_mono``. On the CPU the
+                # host tracer is what records ops, so it stays on.
+                options.host_tracer_level = 0
+                options.python_tracer_level = 0
+            jax.profiler.start_trace(self._profile_dir,
+                                     profiler_options=options)
             self._profiling = True
             self.emit({"name": "profile_start", "iteration": int(iteration),
-                       "t": round(time.time(), 6)})
+                       "t": round(time.time(), 6),
+                       "t_mono": round(time.perf_counter(), 6)})
 
     def profile_step_end(self, iteration: int, state=None) -> None:
         if self._profile and self._profiling \
@@ -205,10 +223,13 @@ class SpanTracer:
 
             if state is not None:
                 jax.tree.leaves(state.params)[0].block_until_ready()
+            # the fence: the host has just seen the window's last device
+            # operation end (None without a state to wait on)
+            fence = None if state is None else round(time.perf_counter(), 6)
             jax.profiler.stop_trace()
             self._profiling = False
             self.emit({"name": "profile_stop", "iteration": int(iteration),
-                       "t": round(time.time(), 6)})
+                       "t": round(time.time(), 6), "fence_mono": fence})
 
     def close(self) -> None:
         if self._f is not None:

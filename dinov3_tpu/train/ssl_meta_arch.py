@@ -44,6 +44,7 @@ from dinov3_tpu.losses import (
 )
 from dinov3_tpu.models import build_backbone
 from dinov3_tpu.ops import DINOHead, Policy
+from dinov3_tpu.utils import step_phase
 
 
 class SSLMetaArch:
@@ -528,12 +529,14 @@ class SSLMetaArch:
                 cls = batch["teacher_cls"].astype(dt)
                 patches = batch["teacher_patches"].astype(dt)
         else:
-            cls, patches = self.teacher_backbone_features(
-                teacher_params, batch, lowp=lowp)
-        return self.teacher_targets_from_features(
-            teacher_params, cls, patches, batch, teacher_temp, state,
-            update_centers,
-        )
+            with step_phase("teacher_backbone"):
+                cls, patches = self.teacher_backbone_features(
+                    teacher_params, batch, lowp=lowp)
+        with step_phase("teacher_targets"):
+            return self.teacher_targets_from_features(
+                teacher_params, cls, patches, batch, teacher_temp, state,
+                update_centers,
+            )
 
     def teacher_targets_from_features(
         self, teacher_params, cls, patches, batch, teacher_temp, state,
@@ -647,12 +650,14 @@ class SSLMetaArch:
             # segment-masked attention — the weight stack streams once
             # per direction instead of twice (ops/packing.py; oracle =
             # the two-pass branch below, model.crop_packing=false)
-            out = self._apply_backbone(
-                self.student_backbone, student_params["backbone"], g, masks,
-                crop_kind="global", train=True, rngs=rngs,
-                rng_plan=None if rng_plan is None else rng_plan["packed"],
-                local_crops=l, lowp=lowp,
-            )
+            with step_phase("student_backbone"):
+                out = self._apply_backbone(
+                    self.student_backbone, student_params["backbone"], g,
+                    masks, crop_kind="global", train=True, rngs=rngs,
+                    rng_plan=(None if rng_plan is None
+                              else rng_plan["packed"]),
+                    local_crops=l, lowp=lowp,
+                )
             g_cls, g_patch = out["x_norm_clstoken"], out["x_norm_patchtokens"]
             l_cls = out["local_cls"]
             if "moe_aux_loss" in out:
@@ -662,27 +667,31 @@ class SSLMetaArch:
         elif rng_plan is not None:
             # plan path: each pass consumes its own precomputed lane —
             # no per-pass fold_in, no make_rng anywhere in the forward
-            g_out = self._apply_backbone(
-                self.student_backbone, student_params["backbone"], g, masks,
-                crop_kind="global", train=True, rng_plan=rng_plan["global"],
-                lowp=lowp,
-            )
-            l_out = self._apply_backbone(
-                self.student_backbone, student_params["backbone"], l, None,
-                crop_kind="local", train=True, rng_plan=rng_plan["local"],
-                lowp=lowp,
-            )
+            with step_phase("student_backbone"):
+                g_out = self._apply_backbone(
+                    self.student_backbone, student_params["backbone"], g,
+                    masks, crop_kind="global", train=True,
+                    rng_plan=rng_plan["global"], lowp=lowp,
+                )
+                l_out = self._apply_backbone(
+                    self.student_backbone, student_params["backbone"], l,
+                    None, crop_kind="local", train=True,
+                    rng_plan=rng_plan["local"], lowp=lowp,
+                )
         else:
-            g_out = self._apply_backbone(
-                self.student_backbone, student_params["backbone"], g, masks,
-                crop_kind="global", train=True, rngs=rngs, lowp=lowp,
-            )
-            l_out = self._apply_backbone(
-                self.student_backbone, student_params["backbone"], l, None,
-                crop_kind="local", train=True,
-                rngs={k: jax.random.fold_in(v, 1) for k, v in rngs.items()},
-                lowp=lowp,
-            )
+            with step_phase("student_backbone"):
+                g_out = self._apply_backbone(
+                    self.student_backbone, student_params["backbone"], g,
+                    masks, crop_kind="global", train=True, rngs=rngs,
+                    lowp=lowp,
+                )
+                l_out = self._apply_backbone(
+                    self.student_backbone, student_params["backbone"], l,
+                    None, crop_kind="local", train=True,
+                    rngs={k: jax.random.fold_in(v, 1)
+                          for k, v in rngs.items()},
+                    lowp=lowp,
+                )
         if not self.crop_packing:
             g_cls, g_patch = (g_out["x_norm_clstoken"],
                               g_out["x_norm_patchtokens"])
@@ -691,17 +700,18 @@ class SSLMetaArch:
                 moe_aux = (g_out.get("moe_aux_loss", 0.0)
                            + l_out.get("moe_aux_loss", 0.0)) / 2.0
 
-        masked = self._gather_masked(g_patch, batch["mask_indices"])
-        M = masked.shape[1]
-        masked_logits = self.ibot_head.apply(
-            {"params": student_params["ibot_head"]},
-            masked.reshape(-1, self.embed_dim),
-        )
-        # one fused DINO-head call for global+local CLS
-        cls_cat = jnp.concatenate([g_cls, l_cls], axis=0)
-        cls_logits = self.dino_head.apply(
-            {"params": student_params["dino_head"]}, cls_cat
-        )
+        with step_phase("student_heads"):
+            masked = self._gather_masked(g_patch, batch["mask_indices"])
+            M = masked.shape[1]
+            masked_logits = self.ibot_head.apply(
+                {"params": student_params["ibot_head"]},
+                masked.reshape(-1, self.embed_dim),
+            )
+            # one fused DINO-head call for global+local CLS
+            cls_cat = jnp.concatenate([g_cls, l_cls], axis=0)
+            cls_logits = self.dino_head.apply(
+                {"params": student_params["dino_head"]}, cls_cat
+            )
         K = cls_logits.shape[-1]
         g_logits = cls_logits[: n_g * B].reshape(n_g, B, K)
         l_logits = cls_logits[n_g * B:].reshape(n_l, B, K)
@@ -789,17 +799,16 @@ class SSLMetaArch:
         g_rows = student_global["cls_after_head"]          # [n_g, B, K]
         l_rows = student_local["cls_after_head"]           # [n_l, B, K]
         B = g_rows.shape[1]
-        pair = pair_ce_from_spec(
-            jnp.concatenate([g_rows, l_rows], axis=0),
-            teacher_global["cls_target"], k_tile=self.loss_k_tile,
-        )                                                   # [n_g+n_l, n_g]
-
-        dino_local = pair_ce_to_loss(pair[n_g:], B)
+        with jax.named_scope("dino_loss"):
+            pair = pair_ce_from_spec(
+                jnp.concatenate([g_rows, l_rows], axis=0),
+                teacher_global["cls_target"], k_tile=self.loss_k_tile,
+            )                                               # [n_g+n_l, n_g]
+            dino_local = pair_ce_to_loss(pair[n_g:], B)
+            dino_global = pair_ce_to_loss(pair[:n_g], B,
+                                          ignore_diagonal=ignore_diag)
         loss_dict["dino_local_crops_loss"] = dino_local
         total = total + cfg.dino.loss_weight * l_scale * local_w * dino_local
-
-        dino_global = pair_ce_to_loss(pair[:n_g], B,
-                                      ignore_diagonal=ignore_diag)
         loss_dict["dino_global_crops_loss"] = dino_global
         total = total + cfg.dino.loss_weight * g_scale * dino_global
 
@@ -807,10 +816,11 @@ class SSLMetaArch:
         group = (cfg.dino.koleo_distributed_loss_group_size
                  if cfg.dino.koleo_loss_distributed else None)
         topk = cfg.dino.koleo_topk if cfg.dino.koleo_loss_distributed else 1
-        kol = sum(
-            koleo_loss(teacher_cls, topk=topk, group_size=group)
-            for teacher_cls in student_global["cls_pre_head"]
-        ) / n_g
+        with jax.named_scope("koleo_loss"):
+            kol = sum(
+                koleo_loss(teacher_cls, topk=topk, group_size=group)
+                for teacher_cls in student_global["cls_pre_head"]
+            ) / n_g
         loss_dict["koleo_loss"] = kol
         total = total + cfg.dino.koleo_loss_weight * n_g * kol
 
@@ -819,12 +829,13 @@ class SSLMetaArch:
 
         w = batch["mask_weights"].reshape(-1)
         n_images = batch["masks"].shape[0]
-        ibot = ibot_loss_from_spec(
-            student_global["masked_patch_after_head"].reshape(
-                -1, cfg.ibot.head_n_prototypes),
-            teacher_global["masked_target"],
-            w, n_images=n_images, k_tile=self.loss_k_tile,
-        )
+        with jax.named_scope("ibot_loss"):
+            ibot = ibot_loss_from_spec(
+                student_global["masked_patch_after_head"].reshape(
+                    -1, cfg.ibot.head_n_prototypes),
+                teacher_global["masked_target"],
+                w, n_images=n_images, k_tile=self.loss_k_tile,
+            )
         loss_dict["ibot_loss"] = ibot
         total = total + cfg.ibot.loss_weight * ibot
 
@@ -848,12 +859,13 @@ class SSLMetaArch:
                 tok_mask = ~batch["masks"]
             elif tokens_used != "all":
                 raise ValueError(f"unknown gram.tokens_used {tokens_used!r}")
-            g_loss = gram_loss(
-                student_global["patch_pre_head"], gram_feats,
-                img_level=(cfg.gram.img_level and tok_mask is None),
-                token_mask=tok_mask,
-                **gram_kw,
-            )
+            with jax.named_scope("gram_loss"):
+                g_loss = gram_loss(
+                    student_global["patch_pre_head"], gram_feats,
+                    img_level=(cfg.gram.img_level and tok_mask is None),
+                    token_mask=tok_mask,
+                    **gram_kw,
+                )
             loss_dict["gram_loss"] = g_loss
             loss_dict["gram_loss_weight"] = jnp.asarray(gram_w, jnp.float32)
             total = total + gram_w * g_loss
@@ -930,13 +942,15 @@ class SSLMetaArch:
         )
         gram_feats = None
         if self.gram_enabled:
-            gram_feats = self.get_gram_teacher_output(
-                frozen, batch, teacher_global["patch_pre_head"]
+            with step_phase("gram_teacher"):
+                gram_feats = self.get_gram_teacher_output(
+                    frozen, batch, teacher_global["patch_pre_head"]
+                )
+        with step_phase("losses"):
+            total, loss_dict = self.compute_losses(
+                teacher_global, student_global, student_local, gram_feats,
+                batch, iteration,
             )
-        total, loss_dict = self.compute_losses(
-            teacher_global, student_global, student_local, gram_feats,
-            batch, iteration,
-        )
         return total, (loss_dict, new_state)
 
     def _zero3_gather_params(self, tree):

@@ -51,6 +51,7 @@ import optax
 from dinov3_tpu.parallel.sharding import constrain_batch_dim
 from dinov3_tpu.train.optimizer import clip_by_per_submodel_norm
 from dinov3_tpu.train.ssl_meta_arch import SSLMetaArch
+from dinov3_tpu.utils import step_phase
 
 
 class TrainState(NamedTuple):
@@ -185,7 +186,8 @@ def make_train_step(
                 # fused draws replace the per-consumer fold_in chains
                 # below — the copy/small-op dispatch sink the r5 profile
                 # priced at 14.8%
-                rng_plan = meta.build_rng_plan(rng, batch)
+                with step_phase("rng_plan"):
+                    rng_plan = meta.build_rng_plan(rng, batch)
             else:
                 rngs = {
                     "drop_path": jax.random.fold_in(rng, 0),
@@ -235,7 +237,8 @@ def make_train_step(
                     }
                     rngs_j = plan_j = None
                     if meta.rng_plan:
-                        plan_j = meta.build_rng_plan(rj, mb)
+                        with step_phase("rng_plan"):
+                            plan_j = meta.build_rng_plan(rj, mb)
                     else:
                         rngs_j = {
                             "drop_path": jax.random.fold_in(rj, 0),
@@ -284,42 +287,41 @@ def make_train_step(
         )(state.params["student"])
 
         metrics = dict(loss_dict)
-        if fused_update is not None:
-            # single pass over every weight-shaped leaf: clip scales from
-            # one up-front batched reduction, AdamW + EMA folded into one
-            # tree.map (train/fused_update.py)
-            new_student, new_teacher, new_opt_state, norms = fused_update(
-                grads, state.params["student"], state.params["teacher"],
-                state.opt_state, scalars["momentum"],
-            )
-            if monitor_grad_norm:
-                for k, v in norms.items():
-                    metrics[f"grad_norm/{k}"] = v
-        else:
-            if clip_grad is not None and clip_grad > 0:
-                grads, norms = clip_by_per_submodel_norm(grads, clip_grad)
-                if monitor_grad_norm:
-                    for k, v in norms.items():
-                        metrics[f"grad_norm/{k}"] = v
+        with step_phase("update"):
+            if fused_update is not None:
+                # single pass over every weight-shaped leaf: clip scales
+                # from one up-front batched reduction, AdamW + EMA folded
+                # into one tree.map (train/fused_update.py)
+                new_student, new_teacher, new_opt_state, norms = fused_update(
+                    grads, state.params["student"], state.params["teacher"],
+                    state.opt_state, scalars["momentum"],
+                )
+            else:
+                norms = {}
+                if clip_grad is not None and clip_grad > 0:
+                    grads, norms = clip_by_per_submodel_norm(grads, clip_grad)
+                updates, new_opt_state = optimizer.update(
+                    grads, state.opt_state, state.params["student"]
+                )
+                new_student = optax.apply_updates(
+                    state.params["student"], updates)
+                new_teacher = meta.update_ema(
+                    state.params["teacher"], new_student, scalars["momentum"]
+                )
+            new_lowp = state.lowp
+            if fwd_lowp is not None:
+                # delayed scaling: the rings observe the UPDATED masters
+                # as part of the update epilogue (train/fused_update.py)
+                from dinov3_tpu.train.fused_update import lowp_state_step
 
-            updates, new_opt_state = optimizer.update(
-                grads, state.opt_state, state.params["student"]
-            )
-            new_student = optax.apply_updates(state.params["student"], updates)
-            new_teacher = meta.update_ema(
-                state.params["teacher"], new_student, scalars["momentum"]
-            )
+                new_lowp = lowp_state_step(
+                    state.lowp, new_student, new_teacher)
+        if monitor_grad_norm:
+            for k, v in norms.items():
+                metrics[f"grad_norm/{k}"] = v
         new_params = dict(state.params)
         new_params["student"] = new_student
         new_params["teacher"] = new_teacher
-
-        new_lowp = state.lowp
-        if fwd_lowp is not None:
-            # delayed scaling: the rings observe the UPDATED masters as
-            # part of the update epilogue (train/fused_update.py)
-            from dinov3_tpu.train.fused_update import lowp_state_step
-
-            new_lowp = lowp_state_step(state.lowp, new_student, new_teacher)
 
         new_state = TrainState(
             params=new_params,

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import json
 import logging
+import re
 from typing import Mapping
 
 import jax
@@ -160,15 +161,25 @@ def configure_compile_cache() -> str:
     the placement. Where it is not, the cache lives at one fixed path
     inside the checkout, ``<repo>/.jax_cache``: the directory is part of
     the cache key's environment, so a path that moves (a temp dir, a
-    uid, a pid, a time) never hits. Returns the directory in effect."""
+    uid, a pid, a time) never hits. Returns the directory in effect.
+
+    The key INCLUDES each instruction's metadata. JAX's default key
+    strips it, so a program that differs from a cached one only by its
+    named scopes (``STEP_PHASES``) is served the cached, scope-less
+    executable and every trace reader finds no phase. The price: an edit
+    that moves a traced source line misses the cache once. File names
+    enter the key relative to the checkout, so a checkout at another
+    path still finds what the same files compiled."""
     import os
 
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                      "^" + re.escape(root + os.sep))
     env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env_dir:
         return env_dir
-    path = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        ".jax_cache")
+    path = os.path.join(root, ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
     return path
 
@@ -507,6 +518,65 @@ HLO_COLLECTIVE_SCOPES = (
     ("reshard_rest", "reshard_rest"),
     ("telemetry_ring", "telemetry"),
 )
+
+
+# the compute phases of one training step, as ``jax.named_scope`` names
+# (train/train_step.py, train/ssl_meta_arch.py, telemetry/ring.py open
+# them through ``step_phase``). A scope is metadata: it reaches the
+# compiled program only as the ``op_name`` of each instruction, where
+# the trace readers find it (telemetry/anatomy.py for the operator's
+# ``--profile-steps`` window; benchmark/phase_reduce.py keeps its own
+# copy of the names in benchmark/phases.json). The engine scopes of
+# ``HLO_COLLECTIVE_SCOPES`` nest INSIDE these and stay what they were.
+STEP_PHASES = (
+    "teacher_backbone",   # the frozen teacher's backbone forward
+    "teacher_targets",    # teacher heads, masked gather, centering, specs
+    "student_backbone",   # every student backbone apply; bwd = its transpose
+    "student_heads",      # masked gather + iBOT head + DINO head
+    "losses",             # compute_losses (dino/ibot/koleo/gram inside)
+    "gram_teacher",       # get_gram_teacher_output
+    "update",             # clip + AdamW + EMA (fused or optax), lowp rings
+    "rng_plan",           # the step-wide RNG plan's draws
+    "telemetry_ring",     # the metrics row's write into the ring
+)
+
+_PHASE_WRAPPER = re.compile(r"^(jvp|transpose|checkpoint|remat)\((.*)\)$")
+
+
+def step_phase(name: str):
+    """Open one phase of ``STEP_PHASES``: ``jax.named_scope`` and nothing
+    else (no annotation, no counter — the compiled step is the same
+    program with or without it)."""
+    if name not in STEP_PHASES:
+        raise ValueError(f"{name!r} is not in STEP_PHASES {STEP_PHASES}")
+    return jax.named_scope(name)
+
+
+def classify_step_phase(op_name: str | None) -> tuple[str | None, str]:
+    """``(phase, direction)`` of one instruction from the VALUE of its
+    ``op_name`` metadata (``jit(step)/transpose(jvp(student_backbone))/
+    while/body/...``): split on ``/``, each component stripped of its
+    ``jvp(`` / ``transpose(`` / ``checkpoint(`` / ``remat(`` wrappers
+    and compared for EQUALITY with a phase name. The outermost phase
+    wins (``update/bucket_pack`` is ``update``); a ``transpose(`` in or
+    before that component makes the direction "bwd" (recomputation under
+    remat included), anything else "fwd". No phase: ``(None, "fwd")``.
+
+    Never feed it an instruction's whole text: operands are called
+    ``%state_params__student____ibot...`` and only the op_name says
+    where an instruction came from."""
+    direction = "fwd"
+    for comp in (op_name or "").split("/"):
+        while True:
+            m = _PHASE_WRAPPER.match(comp)
+            if m is None:
+                break
+            if m.group(1) == "transpose":
+                direction = "bwd"
+            comp = m.group(2)
+        if comp in STEP_PHASES:
+            return comp, direction
+    return None, "fwd"
 
 
 def classify_collective_scope(line: str) -> str:

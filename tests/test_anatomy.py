@@ -189,6 +189,59 @@ def test_synthetic_ledger_exact_overlap_math():
         (150.0 + 100.0) / 2)
 
 
+# the compiled text behind ``_synthetic_trace``: the first collective
+# belongs to the update, the fusion to the student's backward pass, the
+# second collective to a loss; a while that holds nothing but names a phase
+_SYNTHETIC_HLO = """
+HloModule jit_step
+
+ENTRY %main (p: f32[8]) -> f32[8] {
+  %p = f32[8]{0} parameter(0)
+  %all-reduce.1 = f32[8]{0} all-reduce(%p), to_apply=%add, metadata={op_name="jit(step)/update/bucket_pack/psum"}
+  %loop_add_fusion.1 = f32[8]{0} fusion(%p), kind=kLoop, calls=%fused_computation, metadata={op_name="jit(step)/transpose(jvp(student_backbone))/blocks_0/add_any"}
+  %all-reduce.2 = f32[8]{0} all-reduce(%p), to_apply=%add, metadata={op_name="jit(step)/jvp(losses)/dino_loss/psum"}
+  %while.3 = f32[8]{0} while(%p), condition=%c, body=%b, metadata={op_name="jit(step)/jvp(losses)/ibot_loss/while"}
+  ROOT %copy.4 = f32[8]{0} copy(%p)
+}
+"""
+
+
+def test_synthetic_ledger_phase_table():
+    """One classification, two readers: the op index carries
+    ``utils.classify_step_phase`` of each instruction's op_name, and the
+    ledger sums leaf device time by it."""
+    index = build_op_index(_SYNTHETIC_HLO)
+    assert (index["all-reduce.1"]["phase"],
+            index["all-reduce.1"]["direction"]) == ("update", "fwd")
+    assert (index["loop_add_fusion.1"]["phase"],
+            index["loop_add_fusion.1"]["direction"]) \
+        == ("student_backbone", "bwd")
+    assert index["copy.4"]["phase"] is None
+
+    trace = _synthetic_trace()
+    # a while over the whole first step and a nameless copy in the second
+    trace.events.extend([_ev("while.3", 0, 150_000),
+                         _ev("copy.4", 1_100_000, 10_000)])
+    ledger = anatomy_ledger(trace, hlo_text=_SYNTHETIC_HLO, n_steps=2)
+    s0, s1 = ledger["steps"]
+    # the container is not summed: its body's events are on the timeline
+    assert s0["phases"] == {
+        "update/fwd": pytest.approx(100.0),
+        "student_backbone/bwd": pytest.approx(100.0)}
+    assert s1["phases"] == {"losses/fwd": pytest.approx(100.0),
+                            "unattributed": pytest.approx(10.0)}
+    summary = ledger_summary(ledger)
+    assert summary["phases_ms_per_step"] == {
+        "update/fwd": pytest.approx(50.0),
+        "student_backbone/bwd": pytest.approx(50.0),
+        "losses/fwd": pytest.approx(50.0),
+        "unattributed": pytest.approx(5.0)}
+    # without the compiled text there is no phase to read
+    bare = anatomy_ledger(_synthetic_trace(), n_steps=2)
+    assert all(s["phases"] == {} for s in bare["steps"])
+    assert "phases_ms_per_step" not in ledger_summary(bare)
+
+
 def test_trace_reader_roundtrip(tmp_path):
     """Write a Chrome-trace JSON the way jax lays it out; find + load
     it back; .pb paths raise the pointed no-TF-protos error."""

@@ -316,6 +316,15 @@ def test_dryrun_span_jsonl_schema(tiny_run):
         if s["name"] in PHASES:
             assert s["dur_ms"] >= 0
             assert s["iteration"] is None or isinstance(s["iteration"], int)
+            # the monotonic start, beside the wall-clock one (additive
+            # field, same schema version)
+            assert isinstance(s["t_mono"], float) and s["t_mono"] > 0
+            assert s["v"] == 1
+    # one process, one monotonic clock: a span that started later in wall
+    # time started later on it too
+    timed = sorted((s for s in spans if s["name"] in PHASES),
+                   key=lambda s: s["t_mono"])
+    assert all(a["t"] <= b["t"] + 0.05 for a, b in zip(timed, timed[1:]))
     # memory samples ride the same stream, at setup/compile + flushes
     mem_points = [s["point"] for s in spans if s["name"] == "memory"]
     assert "setup" in mem_points and "compile" in mem_points
