@@ -1,6 +1,7 @@
 """chip_smoke.py — the quickest proof that the system still starts on the chip.
 
-    python chip_smoke.py            # one TPU chip: trainer, kernels, serve
+    python chip_smoke.py            # one TPU chip: trainer, the step under
+                                    # accumulation, kernels, serve
     python chip_smoke.py --chips 4  # four chips: ONLY the sharded train
                                     # arms and their one-device comparison
 
@@ -54,7 +55,8 @@ SIZES = {
     "train_overrides": ["data.backend=synthetic",
                         "train.batch_size_per_device=12"],
     "train_iters": 6,
-    # four-chip phase: global batch 8 on every arm (2 per chip)
+    # four-chip phase: global batch 8 on every arm (2 per chip); the
+    # one-chip accumulation phase runs the same batch as 2 microbatches
     "mesh_global_batch": 8,
     "mesh_iters": 3,
     # 768 px ViT-L token count (2304 patches + cls + 4 registers)
@@ -372,7 +374,8 @@ def _collectives(hlo_text: str) -> dict:
             "by_scope": {k: v["ops"] for k, v in cen["by_scope"].items()}}
 
 
-def _run_mesh_arm(name: str, overrides: list, devices: list) -> dict:
+def _run_mesh_arm(name: str, overrides: list, devices: list,
+                  tag: str = "mesh") -> dict:
     """Build the ViT-L/16 setup on ``devices``, run the DEFAULT
     (async-telemetry) step a few times on the seeded global batch, and
     return its per-step metric rows plus where the state lives."""
@@ -431,18 +434,41 @@ def _run_mesh_arm(name: str, overrides: list, devices: list) -> dict:
                          for d in devices},
         "collectives": _collectives(compiled.as_text()),
     }
-    log(f"mesh[{name}]: mesh {out['mesh']} zero3={out['zero3']} "
+    log(f"{tag}[{name}]: mesh {out['mesh']} zero3={out['zero3']} "
         f"bucketed={out['bucketed']} build+compile {build_s:.1f}s, "
         f"step ms {[round(x, 1) for x in step_ms]}")
-    log(f"mesh[{name}]: losses "
+    log(f"{tag}[{name}]: losses "
         f"{[round(m['total_loss'], 4) for m in metrics]}; state leaves on "
         f"{out['state_device_counts']} devices; state bytes/device "
         f"{state_bytes}; bytes_in_use {out['bytes_in_use']}")
-    log(f"mesh[{name}]: collectives {out['collectives']}")
+    log(f"{tag}[{name}]: collectives {out['collectives']}")
     # free this arm's state before the next arm is built on the same chips
     del state, ring, dbatch, compiled, plan, setup, leaves
     gc.collect()
     return out
+
+
+def phase_accum() -> None:
+    """The step's compact iBOT rows at another shape than the trainer
+    phase's: ``optim.accum_steps=2`` puts the compaction and its row
+    gather inside the accumulation scan, with the buffer sized for the
+    most-masked microbatch (``SSLMetaArch.masked_rows``). A step program
+    can hang the chip where every rehearsal passed (PERF.md section 6,
+    PR 26), so each shape the gather ships at runs here once."""
+    import jax
+
+    arm = _run_mesh_arm("accum2", ["parallel.data=1", "optim.accum_steps=2"],
+                        jax.devices()[:1], tag="step")
+    assert arm["mesh"] == {} and not arm["zero3"], arm
+    for m in arm["metrics"]:
+        assert math.isfinite(m["total_loss"]), m
+        assert math.isfinite(m["ibot_loss"]), m
+        assert m["ibot_rows_overflow"] == 0, m
+        assert 0 < m["ibot_rows_fill"] <= 1, m
+    log(f"step[accum2]: ibot_rows_fill "
+        f"{[round(m['ibot_rows_fill'], 4) for m in arm['metrics']]}, "
+        f"overflow 0, ibot_loss "
+        f"{[round(m['ibot_loss'], 4) for m in arm['metrics']]}")
 
 
 def phase_mesh(n_chips: int) -> None:
@@ -550,6 +576,7 @@ def main(argv=None) -> int:
             "JAX_COORDINATOR_ADDRESS", "JAX_PLATFORMS")}))
     if args.chips == 1:
         phase_trainer(cache)
+        phase_accum()
         phase_kernels()
         phase_serve()
     else:

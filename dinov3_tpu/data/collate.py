@@ -3,10 +3,11 @@
 (reference: dinov3_jax/data/collate.py ``collate_data_and_cast`` — stacked
 crops crop-major, sampled per-image block masks with linspaced ratios, and
 emitted dynamic-length ``mask_indices_list``/``n_masked_patches`` buffers.
-Here the masks pack into the **fixed-capacity per-image** buffers the
-TPU-static meta-arch consumes (mask_indices / mask_weights / mask_valid,
-SURVEY.md §7.3 "data-dependent mask indexing"), and crops are already
-normalized float32 NHWC — no torch, no dlpack hop.)
+Here the masks pack into the **fixed-capacity per-image** buffers of the
+TPU-static batch contract (mask_indices / mask_weights / mask_valid,
+SURVEY.md §7.3 "data-dependent mask indexing"; the step compacts them
+batch-wide on the device, train/ssl_meta_arch.py ``masked_rows``), and
+crops are already normalized float32 NHWC — no torch, no dlpack hop.)
 """
 
 from __future__ import annotations

@@ -1,9 +1,13 @@
-"""iBOT masked-patch loss (functional, fixed-capacity buffers).
+"""iBOT masked-patch loss (functional, static-shape row buffers).
 
 (reference: dinov3_jax/loss/ibot_patch_loss.py. Differences by design:
-- operates on a fixed-capacity padded buffer of masked tokens with an
+- operates on a static-shape [M, K] buffer of masked-token rows with an
   explicit validity/weight vector — TPU-static shapes, no data-dependent
-  slicing (SURVEY.md §7.3);
+  slicing (SURVEY.md §7.3). The functions here are agnostic of what M is:
+  the train step hands them its batch-wide compact buffer (``M_c`` rows,
+  the masked tokens the batch has plus under 128 rows of padding —
+  train/ssl_meta_arch.py ``masked_rows``), the tests also the per-image
+  worst-case ``2B * M_img`` rows; padding rows carry weight 0 either way;
 - the per-image mask weighting the reference commented out (:66, a latent
   bug per SURVEY.md §2.9.6) is applied;
 - the sinkhorn variant's effective count is ``sum(weights > 0)``, the

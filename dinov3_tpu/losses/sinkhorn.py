@@ -8,9 +8,9 @@ GSPMD, so every ``jnp.sum`` is already a cross-device reduction — XLA
 inserts the collectives, no axis names, no init-phase special case
 (SURVEY.md §7.1).
 
-Padded rows (fixed-capacity masked-token buffers, SURVEY.md §7.3) are
-handled by ``row_weights``: zero-weight rows contribute nothing and receive
-a harmless uniform output.
+Padded rows (the tail of the step's compact masked-token buffer,
+SURVEY.md §7.3) are handled by ``row_weights``: zero-weight rows
+contribute nothing and receive a harmless uniform output.
 """
 
 from __future__ import annotations

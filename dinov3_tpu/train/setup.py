@@ -112,6 +112,20 @@ class TrainSetup:
     telemetry_builder: Callable | None = None
     _telemetry_cache: Any = dataclasses.field(default=None, repr=False)
 
+    def mask_rows_limit(self, host_batch: dict) -> int:
+        """The masked tokens ONE ``sample_ibot_masks`` call of
+        ``host_batch``'s shape carries under this config — what
+        ``put_batch`` holds each batch of a loader that collates once a
+        host batch to (the step's compact buffer is this count times
+        ``mask_sampler_calls``, rounded up)."""
+        from dinov3_tpu.data.masking import ibot_mask_targets
+
+        n_img, capacity = host_batch["mask_indices"].shape
+        return sum(ibot_mask_targets(
+            n_img, host_batch["masks"].shape[1], capacity,
+            tuple(self.cfg.ibot.mask_ratio_min_max),
+            self.cfg.ibot.mask_sample_probability))
+
     def scalars(self, iteration: int) -> dict:
         s = self.schedules.at(iteration)
         return {
@@ -136,6 +150,7 @@ def build_train_setup(
     devices=None,
     mesh=None,
     init_state: bool = True,
+    mask_sampler_calls: int = 1,
 ) -> TrainSetup:
     """See ``_build_train_setup``; this wrapper restores the ambient
     current-mesh when setup raises (the config-validation raises fire
@@ -146,7 +161,8 @@ def build_train_setup(
     prev = get_current_mesh()
     try:
         return _build_train_setup(
-            cfg, example_batch, rng, devices, mesh, init_state)
+            cfg, example_batch, rng, devices, mesh, init_state,
+            mask_sampler_calls)
     except BaseException:
         set_current_mesh(prev)
         raise
@@ -159,8 +175,15 @@ def _build_train_setup(
     devices=None,
     mesh=None,
     init_state: bool = True,
+    mask_sampler_calls: int = 1,
 ) -> TrainSetup:
     """Build everything needed to train, with state born sharded.
+
+    ``mask_sampler_calls``: how many equal ``sample_ibot_masks`` calls
+    make up one global batch of the loader this setup will be fed from
+    (train.py: one collate call a host). The step sizes its compact iBOT
+    buffer from it (``SSLMetaArch.masked_rows``); the caller owns the
+    loader, so the caller says.
 
     ``init_state=False`` returns the setup with ``state`` as UNBOXED
     ``ShapeDtypeStruct``s instead of materialized device arrays — the
@@ -223,7 +246,7 @@ def _build_train_setup(
                 "pipelined block stack bypasses the per-block zero3 "
                 "stream the quantized gathers ride."
             )
-    meta = SSLMetaArch(cfg)
+    meta = SSLMetaArch(cfg, mask_sampler_calls=mask_sampler_calls)
     if meta.teacher_source == "serve" and "teacher_cls" not in example_batch:
         # the serve-backed teacher arm changes the STEP SIGNATURE: the
         # precomputed teacher planes are batch inputs (batch-sharded by
@@ -719,8 +742,16 @@ def elastic_resume(setup, ckpt, *, live_state=None, live_topology=None,
     return ckpt.restore(setup.state), {"path": "disk"}
 
 
-def put_batch(batch: dict, batch_shardings: dict) -> dict:
+def put_batch(batch: dict, batch_shardings: dict,
+              mask_rows_limit: int | None = None) -> dict:
     """Host batch -> sharded device arrays (each host feeds its shard).
+
+    ``mask_rows_limit`` (``TrainSetup.mask_rows_limit``): the most valid
+    masked tokens this host's batch may carry. A mask source that
+    outgrows the sampler's rule is refused here, on the host and with the
+    numbers; past this check the step's compact iBOT buffer would
+    overflow and the run would stop on a NaN ``ibot_loss`` instead
+    (``ibot_rows_overflow``, the backstop for callers that pass none).
 
     Single process: plain ``device_put`` of the (global == local) batch.
     Multi-host: each host passes only its local shard and the global array
@@ -728,11 +759,22 @@ def put_batch(batch: dict, batch_shardings: dict) -> dict:
     materializes (or decodes) the full global batch (the reference striped
     sample indices by rank for the same reason, data/samplers.py:49-60).
     """
+    import numpy as np
+
+    if mask_rows_limit is not None:
+        n_valid = int(np.count_nonzero(batch["mask_valid"]))
+        if n_valid > mask_rows_limit:
+            raise ValueError(
+                f"this host's batch masks {n_valid} tokens; "
+                f"sample_ibot_masks makes {mask_rows_limit} for its "
+                f"{batch['mask_valid'].shape[0]} mask rows under "
+                "ibot.mask_ratio_min_max / mask_sample_probability, and "
+                "the step's compact iBOT buffer is sized for that "
+                "(data/masking.py masked_rows_bound)")
     if jax.process_count() == 1:
         return jax.tree.map(
             lambda x, s: jax.device_put(x, s), dict(batch), batch_shardings
         )
-    import numpy as np
 
     return {
         k: jax.make_array_from_process_local_data(

@@ -10,11 +10,18 @@ tree wrapped by the FSDP interceptor. Redesigned:
   ["gram": {...}]}`` threaded through pure functions — the natural shape for
   GSPMD sharding, donation, and a fused teacher-EMA update (the reference's
   EMA never fed back into the teacher used by the forward, SURVEY.md §2.9.1);
-- the masked-token buffer is per-image fixed-capacity
-  ([2B, M_img] indices into each image's own tokens, gathered with
-  ``take_along_axis``) instead of the reference's global flat
-  ``mask_indices_list`` — every gather stays local to the batch shard under
-  GSPMD, and shapes are TPU-static (SURVEY.md §7.3);
+- the batch carries the masked tokens in per-image fixed-capacity buffers
+  ([2B, M_img] indices into each image's own tokens: TPU-static shapes,
+  SURVEY.md §7.3), sized for the most ONE image may mask; the step
+  compacts them on the device into ONE batch-wide buffer of ``M_c`` rows
+  (``masked_rows``) before the two iBOT heads, so the heads, Sinkhorn and
+  the iBOT loss run over the tokens the batch has masked (30 % of the
+  per-image buffers' rows at the recipe's ratios) and not over padding.
+  ``M_c`` is the sampler's own count (data/masking.py
+  ``masked_rows_bound``), derived from ``cfg`` and the batch's static
+  shape; more valid tokens than that make ``ibot_loss`` non-finite, never
+  a smaller batch. The row gather is the one place a token crosses data
+  shards: [M_c, D] rows, which then split evenly over the data axes;
 - teacher forward runs under ``stop_gradient`` on params the loss never
   differentiates, no separate "ema module" copies.)
 
@@ -22,13 +29,13 @@ Batch contract (produced by dinov3_tpu/data/collate.py):
     global_crops [2B, S, S, 3], local_crops [n_l*B, s, s, 3],
     masks [2B, T] bool, mask_indices [2B, M] int32 (per-image token index,
     0-padded), mask_weights [2B, M] f32 (1/n_masked(img), 0 for padding),
-    mask_valid [2B, M] bool.
+    mask_valid [2B, M] bool. A valid (image, slot) names a token once.
 """
 
 from __future__ import annotations
 
 from math import prod as math_prod
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -47,8 +54,28 @@ from dinov3_tpu.ops import DINOHead, Policy
 from dinov3_tpu.utils import step_phase
 
 
+class MaskedRows(NamedTuple):
+    """The batch's masked tokens, compacted (``SSLMetaArch.masked_rows``).
+
+    indices: the batch's [2B, M_img] ``mask_indices``; slots: [M_c] int32
+    flat positions in those per-image buffers, the valid ones first and
+    in order, then the last position (in range; weighted out like any
+    padding); weights: [M_c] f32 ``mask_weights`` of the same slots, 0 on
+    padding; valid: [M_c] bool; fill / overflow: f32 scalars, valid
+    tokens over M_c and the count of valid tokens that did not fit (0
+    with the repo's sampler).
+    """
+
+    indices: jax.Array
+    slots: jax.Array
+    weights: jax.Array
+    valid: jax.Array
+    fill: jax.Array
+    overflow: jax.Array
+
+
 class SSLMetaArch:
-    def __init__(self, cfg: ConfigNode):
+    def __init__(self, cfg: ConfigNode, mask_sampler_calls: int = 1):
         if cfg.crops.local_crops_number <= 0:
             raise ValueError("DINOv3 needs local crops (crops.local_crops_number > 0)")
         if not cfg.ibot.separate_head:
@@ -57,6 +84,11 @@ class SSLMetaArch:
         if not (0 <= lo < hi <= 1):
             raise ValueError("provide a valid ibot.mask_ratio_min_max")
         self.cfg = cfg
+        # how many equal ``sample_ibot_masks`` calls make up the batch the
+        # step is fed (``masked_rows``): the data layer's to say, so
+        # whoever builds the loader passes it (train.py: one collate call
+        # a host). The step program never guesses it from the mesh.
+        self.mask_sampler_calls = int(mask_sampler_calls)
         # Training masters are ALWAYS fp32, whatever compute_precision.
         # param_dtype says: the reference recipe's ``param_dtype: bf16`` is
         # torch-FSDP MixedPrecision's *compute copy* dtype — its masters
@@ -485,11 +517,76 @@ class SSLMetaArch:
             deterministic=not train, rngs=rngs, **plan_kw,
         )
 
-    def _gather_masked(self, patch_tokens, mask_indices):
-        """[2B, T, D], [2B, M] -> [2B, M, D] (local, static-shape gather)."""
-        return jnp.take_along_axis(
-            patch_tokens, mask_indices[..., None], axis=1
+    def masked_rows(self, batch, n_micro: int = 1) -> MaskedRows:
+        """Compact the per-image [2B, M_img] mask buffers into the
+        batch-wide [M_c] rows the iBOT heads and losses run over.
+
+        ``M_c`` = ``masked_rows_bound`` of the sampler's rule for this
+        batch's static shape: the mask rows were made by
+        ``self.mask_sampler_calls`` equal sampler calls, and with
+        ``n_micro`` > 1 this batch is one of that many equal microbatches
+        of them (train_step.split_microbatches). ``forward`` calls this
+        once and hands the rows to teacher, student and loss; anything
+        that calls those by itself does the same. The j-th valid flat
+        position is found by binary search in the running count of valid
+        slots (sorted and unique by construction; no scatter, no loop on
+        the device, O(M_c log 2B*M_img)).
+        """
+        from dinov3_tpu.data.masking import masked_rows_bound
+        from dinov3_tpu.parallel.context import get_current_mesh
+        from dinov3_tpu.parallel.sharding import constrain_replicated
+
+        mesh = get_current_mesh()
+        valid, weights = (
+            batch[k].reshape(-1) for k in ("mask_valid", "mask_weights"))
+        if mesh is not None and mesh.size > 1:
+            # the two [2B * M_img] vectors are small: every device holds
+            # them whole, and the search below needs no collective
+            valid, weights = (
+                constrain_replicated(x, mesh) for x in (valid, weights))
+        n_img, m_img = batch["mask_indices"].shape
+        n_tok = batch["masks"].shape[1]
+        m_c = masked_rows_bound(
+            n_img * n_micro, n_tok, m_img,
+            tuple(self.cfg.ibot.mask_ratio_min_max),
+            self.cfg.ibot.mask_sample_probability,
+            n_calls=self.mask_sampler_calls, n_seen=n_img)
+        count = jnp.cumsum(valid, dtype=jnp.int32)
+        n_valid = count[-1]
+        nth = jnp.arange(m_c, dtype=jnp.int32)
+        slots = jnp.minimum(
+            jnp.searchsorted(count, nth + 1, side="left",
+                             method="scan_unrolled"),
+            count.size - 1).astype(jnp.int32)
+        keep = nth < n_valid
+        return MaskedRows(
+            indices=batch["mask_indices"],
+            slots=slots,
+            weights=jnp.where(keep, weights[slots], 0.0),
+            valid=keep,
+            fill=n_valid.astype(jnp.float32) / m_c,
+            overflow=jnp.maximum(n_valid - m_c, 0).astype(jnp.float32),
         )
+
+    def _gather_masked(self, patch_tokens, masked: MaskedRows):
+        """[2B, T, D] -> the batch's compact masked rows [M_c, D].
+
+        Two static-shape gathers: each image's own masked tokens
+        ([2B, M_img, D], local to the batch shard), then the compact rows
+        out of those — the one gather that crosses data shards, [M_c, D]
+        rows, pinned back onto the data axes so the heads' [M_c, K]
+        planes split evenly. Keep the two stages: ONE gather of
+        ``image * T + token`` rows out of the flat [2B*T, D] tokens ran
+        on the v5e by itself and inside the ViT-S step, and hung the
+        ViT-L step there — with its padding rows out of range under
+        ``mode="fill"``, with every index in range, and under plain
+        indexing alike (bisected on the chip, PERF.md section 6, PR 26)."""
+        from dinov3_tpu.parallel.sharding import constrain_batch_dim
+
+        per_image = jnp.take_along_axis(
+            patch_tokens, masked.indices[..., None], axis=1)
+        flat = per_image.reshape(-1, patch_tokens.shape[-1])
+        return constrain_batch_dim(flat[masked.slots], 0)
 
     def teacher_backbone_features(self, teacher_params, batch, lowp=None):
         """The frozen teacher's backbone forward over the global crops:
@@ -509,7 +606,7 @@ class SSLMetaArch:
 
     def get_teacher_output(
         self, teacher_params, batch, teacher_temp, state, update_centers=True,
-        lowp=None,
+        lowp=None, *, masked: MaskedRows,
     ):
         if self.teacher_source == "serve":
             if "teacher_cls" not in batch or "teacher_patches" not in batch:
@@ -535,27 +632,27 @@ class SSLMetaArch:
         with step_phase("teacher_targets"):
             return self.teacher_targets_from_features(
                 teacher_params, cls, patches, batch, teacher_temp, state,
-                update_centers,
+                update_centers, masked=masked,
             )
 
     def teacher_targets_from_features(
         self, teacher_params, cls, patches, batch, teacher_temp, state,
-        update_centers=True,
+        update_centers=True, *, masked: MaskedRows,
     ):
         """Teacher targets from already-computed backbone features —
         the shared tail of both teacher arms (heads -> centering ->
-        target specs). ``cls`` [2B, D_t], ``patches`` [2B, T, D_t]."""
+        target specs). ``cls`` [2B, D_t], ``patches`` [2B, T, D_t];
+        ``masked``: the batch's ``masked_rows``."""
         n_g = 2
         B = cls.shape[0] // n_g
         cls_logits = self.teacher_dino_head.apply(
             {"params": teacher_params["dino_head"]}, cls
         )  # [2B, K]
-        masked = self._gather_masked(patches, batch["mask_indices"])
         masked_logits = self.teacher_ibot_head.apply(
             {"params": teacher_params["ibot_head"]},
-            masked.reshape(-1, self.teacher_embed_dim),
-        )  # [2B*M, K']
-        valid = batch["mask_valid"].reshape(-1)
+            self._gather_masked(patches, masked),
+        )  # [M_c, K']
+        valid = masked.valid
 
         new_state = dict(state)
         # Teacher-target storage dtype: bf16 halves the HBM footprint of
@@ -631,13 +728,13 @@ class SSLMetaArch:
             # teacher-target specs (losses/streaming.py pair_ce_from_spec /
             # ibot_loss_from_spec): "probs" = materialized oracle buffers,
             # "softmax_center"/"sinkhorn" = streaming (no [*, K] target
-            # buffer). masked rows stay flat [2B*M, K'].
+            # buffer). masked rows are the compact [M_c, K'].
             "cls_target": cls_target,
             "masked_target": masked_target,
         }, new_state
 
     def get_student_output(self, student_params, batch, rngs, rng_plan=None,
-                           lowp=None):
+                           lowp=None, *, masked: MaskedRows):
         g = batch["global_crops"]
         l = batch["local_crops"]
         n_g, n_l = 2, self.n_local_crops
@@ -701,12 +798,10 @@ class SSLMetaArch:
                            + l_out.get("moe_aux_loss", 0.0)) / 2.0
 
         with step_phase("student_heads"):
-            masked = self._gather_masked(g_patch, batch["mask_indices"])
-            M = masked.shape[1]
             masked_logits = self.ibot_head.apply(
                 {"params": student_params["ibot_head"]},
-                masked.reshape(-1, self.embed_dim),
-            )
+                self._gather_masked(g_patch, masked),
+            )  # [M_c, K']
             # one fused DINO-head call for global+local CLS
             cls_cat = jnp.concatenate([g_cls, l_cls], axis=0)
             cls_logits = self.dino_head.apply(
@@ -720,7 +815,7 @@ class SSLMetaArch:
             "cls_pre_head": g_cls.reshape(n_g, B, -1),
             "patch_pre_head": g_patch,
             "cls_after_head": g_logits,
-            "masked_patch_after_head": masked_logits.reshape(2 * B, M, -1),
+            "masked_patch_after_head": masked_logits,
         }
         if moe_aux is not None:
             global_out["moe_aux_loss"] = moe_aux
@@ -770,7 +865,7 @@ class SSLMetaArch:
 
     def compute_losses(
         self, teacher_global, student_global, student_local, gram_feats,
-        batch, iteration,
+        batch, iteration, masked: MaskedRows,
     ):
         cfg = self.cfg
         n_g = 2
@@ -827,16 +922,19 @@ class SSLMetaArch:
         # iBOT on masked tokens
         from dinov3_tpu.losses import ibot_loss_from_spec
 
-        w = batch["mask_weights"].reshape(-1)
         n_images = batch["masks"].shape[0]
         with jax.named_scope("ibot_loss"):
             ibot = ibot_loss_from_spec(
-                student_global["masked_patch_after_head"].reshape(
-                    -1, cfg.ibot.head_n_prototypes),
+                student_global["masked_patch_after_head"],
                 teacher_global["masked_target"],
-                w, n_images=n_images, k_tile=self.loss_k_tile,
+                masked.weights, n_images=n_images, k_tile=self.loss_k_tile,
             )
+            # a mask source that outgrows the sampler's bound must stop
+            # the run, not train on fewer tokens: NaN loss AND gradient
+            ibot = ibot * jnp.where(masked.overflow > 0, jnp.nan, 1.0)
         loss_dict["ibot_loss"] = ibot
+        loss_dict["ibot_rows_fill"] = masked.fill
+        loss_dict["ibot_rows_overflow"] = masked.overflow
         total = total + cfg.ibot.loss_weight * ibot
 
         if self.gram_enabled and gram_feats is not None:
@@ -906,6 +1004,7 @@ class SSLMetaArch:
         update_centers=True,
         gather_params=True,
         lowp=None,
+        n_micro=1,
     ):
         """Loss for one batch. ``frozen_params`` = {"teacher": ..,
         ["gram": ..]} under stop_gradient; gradients flow only through
@@ -921,7 +1020,11 @@ class SSLMetaArch:
         delayed-scaling trees for the fp8/int8 ``train.low_precision``
         arms (ops/lowp.py ``lowp_scales``) — both backbones forward
         through the quantized matmuls; the gram teacher never receives
-        the collection (its anchoring features stay bf16)."""
+        the collection (its anchoring features stay bf16).
+
+        ``n_micro``: this batch is one of that many equal microbatches of
+        the collated batch (``masked_rows`` sizes its buffer for the
+        most-masked one)."""
         lowp = lowp or {}
         frozen = jax.lax.stop_gradient(frozen_params)
         # ZeRO-3: replicate the non-streamed master subtrees for this
@@ -932,13 +1035,15 @@ class SSLMetaArch:
         if gather_params:
             student_params = self._zero3_gather_params(student_params)
             frozen = self._zero3_gather_params(frozen)
+        with step_phase("teacher_targets"):
+            masked = self.masked_rows(batch, n_micro)
         teacher_global, new_state = self.get_teacher_output(
             frozen["teacher"], batch, teacher_temp, state, update_centers,
-            lowp=lowp.get("teacher"),
+            lowp=lowp.get("teacher"), masked=masked,
         )
         student_global, student_local = self.get_student_output(
             student_params, batch, rngs, rng_plan=rng_plan,
-            lowp=lowp.get("student"),
+            lowp=lowp.get("student"), masked=masked,
         )
         gram_feats = None
         if self.gram_enabled:
@@ -949,7 +1054,7 @@ class SSLMetaArch:
         with step_phase("losses"):
             total, loss_dict = self.compute_losses(
                 teacher_global, student_global, student_local, gram_feats,
-                batch, iteration,
+                batch, iteration, masked=masked,
             )
         return total, (loss_dict, new_state)
 

@@ -230,7 +230,11 @@ def do_train(cfg, args, *, devices=None, data_rank=None, data_world=None,
                 cfg, int(example["global_crops"].shape[0])).items()
         })
     t0 = time.perf_counter()
-    setup = build_train_setup(cfg, example, devices=devices)
+    # one collate call (one ``sample_ibot_masks``) a host batch: the
+    # loader's call pattern sizes the step's compact iBOT buffer
+    setup = build_train_setup(cfg, example, devices=devices,
+                              mask_sampler_calls=world)
+    mask_rows_limit = setup.mask_rows_limit(first)
     # the bucketed collective engine keeps adam moments in the bucket
     # layout; the checkpointer needs the plan to convert to/from the
     # per-leaf on-disk layout (checkpoint.py)
@@ -501,7 +505,7 @@ def do_train(cfg, args, *, devices=None, data_rank=None, data_world=None,
 
     if teacher_server is not None:
         first = teacher_server.annotate(first)
-    pending = put_batch(first, setup.batch_shardings)
+    pending = put_batch(first, setup.batch_shardings, mask_rows_limit)
     for it, raw in metric_logger.log_every(
         tracer.wrap_iter(data_iter, start_iteration=start_iter),
         print_freq=10, header=header,
@@ -527,7 +531,7 @@ def do_train(cfg, args, *, devices=None, data_rank=None, data_world=None,
                 raw = teacher_server.annotate(raw)
         with tracer.span("h2d", it):
             # overlap next batch's host->device transfer with this step
-            pending = put_batch(raw, setup.batch_shardings)
+            pending = put_batch(raw, setup.batch_shardings, mask_rows_limit)
         if memory_on and not compile_sampled:
             # the first dispatch returned, so the step has compiled
             tracer.emit_memory("compile", it)

@@ -254,6 +254,7 @@ def make_train_step(
                         rng_plan=plan_j,
                         gather_params=False,
                         lowp=fwd_lowp,
+                        n_micro=accum_steps,
                     )
                     return loss_j, ld_j, nc_j
 

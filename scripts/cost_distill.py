@@ -215,8 +215,9 @@ def loss_equivalence(teacher_yaml) -> dict:
         frozen = setup_o.state.params["teacher"]
         state0 = meta.init_state()
         temp = 0.05
+        masked = meta.masked_rows(batch)
         oracle_out, oracle_state = meta.get_teacher_output(
-            frozen, batch, temp, state0)
+            frozen, batch, temp, state0, masked=masked)
 
         # ---- serve arm fed the oracle's OWN features: bitwise
         cls, patches = meta.teacher_backbone_features(frozen, batch)
@@ -226,7 +227,7 @@ def loss_equivalence(teacher_yaml) -> dict:
             np.asarray(patches, np.float32))
         meta.teacher_source = "serve"
         serve_out, serve_state = meta.get_teacher_output(
-            frozen, sbatch, temp, state0)
+            frozen, sbatch, temp, state0, masked=masked)
         meta.teacher_source = "in_step"
         bitwise = all(
             np.array_equal(np.asarray(x), np.asarray(y))

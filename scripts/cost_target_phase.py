@@ -95,8 +95,13 @@ def measure_target_phase(cfg, centering: str, target_dtype) -> dict:
     n_g, n_l = 2, cfg.crops.local_crops_number
     K = cfg.dino.head_n_prototypes
     K_i = cfg.ibot.head_n_prototypes
-    M = make_synthetic_batch(cfg, 2, seed=0)["mask_indices"].shape[1]
-    rows_m = 2 * B * M
+    from dinov3_tpu.data.masking import masked_rows_bound
+
+    masks = make_synthetic_batch(cfg, 2, seed=0)
+    # the step's compact iBOT rows (SSLMetaArch.masked_rows)
+    rows_m = masked_rows_bound(
+        2 * B, masks["masks"].shape[1], masks["mask_indices"].shape[1],
+        tuple(cfg.ibot.mask_ratio_min_max), cfg.ibot.mask_sample_probability)
     k_tile = int((cfg.get("loss") or {}).get("k_tile") or 8192)
 
     sd = jax.ShapeDtypeStruct

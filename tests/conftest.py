@@ -73,6 +73,19 @@ def assert_within_ulps(got, want, max_ulps: float, what: str = "") -> None:
         f"{what}: {worst:.2f} ulps of scale apart (pin {max_ulps})")
 
 
+@pytest.fixture(scope="module", autouse=True)
+def _no_mesh_from_the_file_before():
+    """``build_train_setup`` leaves its mesh as the process's current one
+    (parallel/context.py). A worker runs whole files one after another
+    (``--dist loadfile``), in an order that shifts with every file added:
+    without this, a file that expects no mesh (tests/test_fused_norm.py)
+    passes or fails by which file its worker ran before it."""
+    from dinov3_tpu.parallel.context import set_current_mesh
+
+    set_current_mesh(None)
+    yield
+
+
 @pytest.fixture(scope="session")
 def eight_devices():
     devs = jax.devices()

@@ -82,8 +82,9 @@ def test_smoke_refuses_a_device_count_it_was_not_asked_for(
 
 
 def test_smoke_rehearsal_runs_every_phase(smoke, monkeypatch, capsys):
-    """trainer (self-check, train, save, resume), kernels, serve — and
-    the last stdout line is exactly the contract's object."""
+    """trainer (self-check, train, save, resume), the step under
+    accumulation, kernels, serve — and the last stdout line is exactly
+    the contract's object."""
     _rehearse(smoke, monkeypatch, count=1)
     assert smoke.main([]) == 0
     out = capsys.readouterr().out
@@ -94,7 +95,7 @@ def test_smoke_rehearsal_runs_every_phase(smoke, monkeypatch, capsys):
         "count": 1}}
     said = "\n".join(ln for ln in lines if ln.startswith("[chip_smoke"))
     for needle in ("self-check", "0 failures", "resumed at 3",
-                   "kernels: flash ", "kernels: flash_seg",
+                   "step[accum2]: ibot_rows_fill", "kernels: flash ", "kernels: flash_seg",
                    "kernels: fused_layernorm", "serve:",
                    "compiles packed 1", "all phases passed"):
         assert needle in said, needle

@@ -187,8 +187,9 @@ def test_precomputed_targets_bitwise_vs_in_step_oracle(tmp_path):
     state0 = meta.init_state()
     temp = 0.05
 
+    masked = meta.masked_rows(batch)
     oracle_out, oracle_state = meta.get_teacher_output(
-        frozen, batch, temp, state0)
+        frozen, batch, temp, state0, masked=masked)
 
     cls, patches = meta.teacher_backbone_features(frozen, batch)
     sbatch = dict(batch)
@@ -197,10 +198,11 @@ def test_precomputed_targets_bitwise_vs_in_step_oracle(tmp_path):
     meta.teacher_source = "serve"
     try:
         serve_out, serve_state = meta.get_teacher_output(
-            frozen, sbatch, temp, state0)
+            frozen, sbatch, temp, state0, masked=masked)
         # missing planes is a hard error, not a silent oracle fallback
         with pytest.raises(ValueError, match="teacher_cls"):
-            meta.get_teacher_output(frozen, batch, temp, state0)
+            meta.get_teacher_output(frozen, batch, temp, state0,
+                                    masked=masked)
     finally:
         meta.teacher_source = "in_step"
 
