@@ -1,0 +1,9 @@
+"""Step program (decoder): device time a step under the latent-attention layer's mixer (projections and the causal blockwise core), forward and backward. Read from
+the device trace by lm_phase_table.py (lm_phases.json); None where the
+trace carries no such phase. Moves train_img_per_s_chip."""
+
+import lm_phase_table
+
+
+def read(run):
+    return lm_phase_table.metric(run, "lm_mla_ms_per_step")

@@ -1,7 +1,9 @@
 """chip_smoke.py — the quickest proof that the system still starts on the chip.
 
     python chip_smoke.py            # one TPU chip: trainer, the step under
-                                    # accumulation, kernels, serve
+                                    # accumulation, kernels, serve, the
+                                    # decoder's next-token trainer
+    python chip_smoke.py --phases lm   # one chip, that phase alone
     python chip_smoke.py --chips 4  # four chips: ONLY the sharded train
                                     # arms and their one-device comparison
 
@@ -42,6 +44,7 @@ if REPO not in sys.path:
 OUT_DIR = os.path.join(REPO, "chiprun_out", "chip_smoke")
 RUN_DIR = os.path.join(REPO, ".chip_smoke_run")
 RECIPE = os.path.join(REPO, "configs", "train", "vitl16_im1k.yaml")
+LM_RECIPE = os.path.join(REPO, "configs", "train", "kimi_linear_ep32.yaml")
 
 # What the smoke runs, at the size a user would call real: ViT-L/16 at
 # full width and depth on the recipe's 2 global + 8 local crops and its
@@ -55,6 +58,12 @@ SIZES = {
     "train_overrides": ["data.backend=synthetic",
                         "train.batch_size_per_device=12"],
     "train_iters": 6,
+    # the decoder's recipe as it stands: published widths, 2 x 8,192
+    # tokens a step, 13.9 GiB by compile-time analysis; cold it compiles
+    # in about two minutes and a step takes about a second
+    "lm_overrides": ["data.backend=synthetic"],
+    "lm_iters": 3,
+    "lm_timeout_s": 900,
     # four-chip phase: global batch 8 on every arm (2 per chip); the
     # one-chip accumulation phase runs the same batch as 2 microbatches
     "mesh_global_batch": 8,
@@ -70,6 +79,8 @@ SIZES = {
     "serve_images_hw": [(96, 96), (224, 224), (512, 512), (160, 240),
                         (384, 384), (224, 224), (128, 128), (448, 320)],
 }
+
+ONE_CHIP_PHASES = ("trainer", "accum", "kernels", "serve", "lm")
 
 _T0 = time.time()
 
@@ -124,6 +135,60 @@ def peak_bytes() -> list:
     from dinov3_tpu.telemetry.memory import sample_memory
 
     return [d["peak_bytes_in_use"] for d in sample_memory()["devices"]]
+
+
+# ------------------------------------------------- the next-token trainer
+
+def phase_lm(cache: CacheCounter) -> None:
+    """The same entry point on the decoder's recipe at published widths
+    (``configs/train/kimi_linear_ep32.yaml``): the next-token step
+    compiles, runs a few steps with a finite loss near ln(vocabulary),
+    saves its teacher-less state and resumes it for one more step.
+
+    A new step program can hang the chip where every rehearsal passed
+    (PERF.md section 6, PR 26), so this phase has a time limit of its
+    own: past it the process dumps its stacks and exits non-zero, and
+    the chip call ends there instead of at the call's limit."""
+    import faulthandler
+    import shutil
+
+    from dinov3_tpu.configs import load_config
+    from dinov3_tpu.train.train import main as train_main
+
+    run_dir = os.path.join(RUN_DIR, "lm")
+    shutil.rmtree(run_dir, ignore_errors=True)
+    common = ["--config-file", LM_RECIPE, "--output-dir", run_dir,
+              *SIZES["lm_overrides"]]
+    n = int(SIZES["lm_iters"])
+    cfg = load_config(LM_RECIPE, overrides=SIZES["lm_overrides"])
+    want = math.log(int(cfg.lm.vocab_size))
+    faulthandler.dump_traceback_later(
+        float(SIZES["lm_timeout_s"]), exit=True, file=sys.__stderr__)
+    log(f"lm: {n} iterations from scratch, "
+        f"{cfg.train.batch_size_per_device} x {cfg.lm.seq_len} tokens a "
+        f"chip, time limit {SIZES['lm_timeout_s']}s")
+    t0 = time.perf_counter()
+    result = train_main(["--no-resume", "--max-iterations", str(n),
+                         "--benchmark", str(n - 1), *common])
+    losses = result["losses"]
+    assert result["iterations"] == n and len(losses) == n, result
+    assert all(math.isfinite(x) for x in losses), losses
+    assert all(abs(x - want) < 0.5 for x in losses), (losses, want)
+    log(f"lm: losses {[round(x, 4) for x in losses]} (ln vocabulary "
+        f"{want:.3f}); fenced step times (ms) "
+        f"{[round(x, 1) for x in result['step_ms']]}; whole call "
+        f"{time.perf_counter() - t0:.1f}s; peak_bytes_in_use "
+        f"{peak_bytes()}, cache {cache.snapshot()}")
+    t0 = time.perf_counter()
+    resumed = train_main(["--max-iterations", str(n + 1), *common])
+    assert resumed["iterations"] == n + 1, resumed["iterations"]
+    assert len(resumed["losses"]) == 1, resumed["losses"]
+    assert abs(resumed["final_loss"] - want) < 0.5, resumed["final_loss"]
+    log(f"lm: resumed at {n}, step {n + 1} loss "
+        f"{resumed['final_loss']:.4f}, {time.perf_counter() - t0:.1f}s")
+    faulthandler.cancel_dump_traceback_later()
+    shutil.rmtree(os.path.join(run_dir, "ckpt"))
+    shutil.copytree(run_dir, os.path.join(OUT_DIR, "lm"), dirs_exist_ok=True)
 
 
 # ---------------------------------------------------------------- trainer
@@ -550,7 +615,14 @@ def main(argv=None) -> int:
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4),
                     help="4: run ONLY the sharded train arms and their "
                          "one-device comparison on a four-chip host")
+    ap.add_argument("--phases", default=",".join(ONE_CHIP_PHASES),
+                    help="one chip: which phases, in this order "
+                         f"(default all: {','.join(ONE_CHIP_PHASES)})")
     args = ap.parse_args(argv)
+    phases = [p for p in args.phases.split(",") if p]
+    unknown = sorted(set(phases) - set(ONE_CHIP_PHASES))
+    if unknown:
+        raise SystemExit(f"chip_smoke: unknown phases {unknown}")
 
     from dinov3_tpu.utils import configure_compile_cache
 
@@ -575,10 +647,11 @@ def main(argv=None) -> int:
             "TPU_WORKER_HOSTNAMES", "TPU_ACCELERATOR_TYPE",
             "JAX_COORDINATOR_ADDRESS", "JAX_PLATFORMS")}))
     if args.chips == 1:
-        phase_trainer(cache)
-        phase_accum()
-        phase_kernels()
-        phase_serve()
+        run = {"trainer": lambda: phase_trainer(cache), "accum": phase_accum,
+               "kernels": phase_kernels, "serve": phase_serve,
+               "lm": lambda: phase_lm(cache)}
+        for name in phases:
+            run[name]()
     else:
         phase_mesh(args.chips)
     log(f"all phases passed in {time.time() - _T0:.0f}s; "

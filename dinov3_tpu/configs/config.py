@@ -153,6 +153,15 @@ def apply_dot_overrides(cfg: ConfigNode, overrides: Iterable[str]) -> ConfigNode
     return cfg
 
 
+# architectures (``student.arch``) that are token decoders trained on a
+# next-token loss (models/decoder.py, train/lm_meta_arch.py)
+LM_ARCHS = ("kimi_linear",)
+
+
+def is_lm_arch(cfg: ConfigNode) -> bool:
+    return str(cfg.student.arch) in LM_ARCHS
+
+
 def get_default_config() -> ConfigNode:
     with open(_DEFAULT_YAML) as f:
         return _wrap(yaml.safe_load(f))
@@ -176,12 +185,15 @@ def load_config(
     apply_scaling_rules_to_cfg(cfg)
     # batch-tiling guardrail: a silent 2.4x cliff is a footgun in a
     # framework whose selling point is TPU-first layout awareness
-    warn_bad_batch_tiling(cfg.train.batch_size_per_device)
+    # (a decoder's batch is a few long sequences: no image rows to tile)
+    if not is_lm_arch(cfg):
+        warn_bad_batch_tiling(cfg.train.batch_size_per_device)
     # ... and the same guardrail over the student's OTHER row axes: the
     # local-crop row axis (n_l*B, the two-pass program) or the packed
     # row count (2B + P, the crop-packed program) — 96 rows of 37
     # tokens is precisely the pathology the packing engine removes
-    warn_student_row_tiling(cfg)
+    if not is_lm_arch(cfg):
+        warn_student_row_tiling(cfg)
     # ... and over the telemetry flush window: metrics rows still in the
     # on-device ring when a run restarts are dropped, so a flush period
     # wider than the checkpoint/eval cadence silently loses exactly the

@@ -12,12 +12,16 @@ from __future__ import annotations
 import numpy as np
 
 from dinov3_tpu.configs import ConfigNode
+from dinov3_tpu.configs.config import is_lm_arch
 from dinov3_tpu.data.masking import sample_ibot_masks
 
 
 def batch_spec(cfg: ConfigNode, batch_size: int) -> dict:
-    """Shapes/dtypes of one host batch (B images per batch)."""
+    """Shapes/dtypes of one host batch (B images per batch; for a token
+    decoder, B sequences of ``lm.seq_len`` ids)."""
     B = batch_size
+    if is_lm_arch(cfg):
+        return {"tokens": ((B, int(cfg.lm.seq_len)), np.int32)}
     p = cfg.student.patch_size
     S = cfg.crops.global_crops_size
     s = cfg.crops.local_crops_size
@@ -43,6 +47,11 @@ def make_synthetic_batch(
 ) -> dict:
     rng = np.random.default_rng(seed)
     spec = batch_spec(cfg, batch_size)
+    if is_lm_arch(cfg):
+        # ids uniform over the vocabulary the recipe holds (a slice of
+        # the published one: the traffic draws from the slice)
+        return {"tokens": rng.integers(
+            0, int(cfg.lm.vocab_size), spec["tokens"][0], dtype=np.int32)}
     B = batch_size
     p = cfg.student.patch_size
     S = cfg.crops.global_crops_size

@@ -13,6 +13,8 @@ from dinov3_tpu.models.convnext import (
     ConvNeXt,
     get_convnext_arch,
 )
+from dinov3_tpu.configs.config import LM_ARCHS
+from dinov3_tpu.models.decoder import DecoderConfig, LMDecoder
 from dinov3_tpu.models.vision_transformer import (
     ARCHS,
     DinoVisionTransformer,
@@ -167,6 +169,9 @@ def build_backbone(cfg: ConfigNode, *, teacher: bool = False,
     never round through bf16 (ssl_meta_arch.py), while eval builds keep
     the recipe's storage dtype."""
     arch = cfg.student.arch
+    if arch in LM_ARCHS:
+        # a token decoder: one student, no teacher variant to build
+        return LMDecoder(DecoderConfig.from_cfg(cfg, param_dtype=param_dtype))
     if arch.startswith("convnext"):
         from dinov3_tpu.configs.config import lowp_cfg
 
@@ -244,7 +249,8 @@ def build_model_for_eval(cfg: ConfigNode, ckpt_dir: str | None = None):
 
 
 __all__ = [
-    "ARCHS", "DinoVisionTransformer", "backbone_kwargs_from_cfg",
+    "ARCHS", "DinoVisionTransformer", "LM_ARCHS", "LMDecoder",
+    "DecoderConfig", "backbone_kwargs_from_cfg",
     "build_backbone", "build_model_from_cfg", "vit_small", "vit_base",
     "vit_large", "vit_so400m", "vit_huge2", "vit_giant2", "vit_7b", "vit_test",
 ]

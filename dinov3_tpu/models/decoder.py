@@ -1,0 +1,386 @@
+"""A causal decoder of two mixer kinds and two FFN kinds, chosen layer
+by layer: the ``kimi_linear`` family (Kimi Linear, Moonshot AI;
+``config.json`` of Kimi-Linear-48B-A3B-Instruct and the model's report).
+
+Pre-norm residual layers, RMSNorm everywhere:
+
+- **KDA** (Kimi Delta Attention), per head h with d_k = d_v = head_dim,
+  x_t the normed input:
+  q_t = L2norm(SiLU(conv(W_q x)_t)) * d_k^-0.5, k_t = L2norm(SiLU(conv(W_k x)_t)),
+  v_t = SiLU(conv(W_v x)_t) (causal depthwise convolutions of width
+  ``short_conv_kernel_size``); a_t = exp(-exp(A_log_h) softplus(W_f2 W_f1 x_t
+  + dt_bias)), one decay per key channel; b_t = sigmoid(w_b x_t);
+  S_t = (I - b_t k_t k_t^T) Diag(a_t) S_{t-1} + b_t k_t v_t^T; o_t = S_t^T q_t;
+  y_t = W_o [RMSNorm_head(o_t) * sigmoid(W_g2 W_g1 x_t)]. The delta rule
+  itself is ``ops/kda.py`` (chunked, float32).
+- **MLA** without rotary (``mla_use_nope``): q_t = W_q x_t, heads of
+  qk_nope + qk_rope; [c_t ; kpe_t] = W_kva x_t; [k_nope ; v] =
+  W_kvb RMSNorm(c_t); k = [k_nope ; kpe_t for every head]; causal
+  softmax(q k^T / sqrt(d_qk)) v through ``ops/attention.py``; W_o.
+- **FFN**: SwiGLU of ``intermediate_size`` in the first
+  ``first_k_dense_replace`` layers; after them the routed experts this
+  shard holds (``ops/ffn.py RoutedExpertsFFN``) plus ``num_shared_experts``
+  shared ones of the same width side by side, every token through them.
+
+The vocabulary may be a slice (``vocab_size`` rows of the published
+table): ids, logits and the loss are over the slice. Embedding and head
+are untied. The loss is the mean next-token cross-entropy, float32, the
+head applied a block of tokens at a time so that the ``[tokens, vocab]``
+logits never exist whole.
+
+The step's phases (``utils.STEP_PHASES``): ``lm_embed``, ``kda_mixer``
+(inner ``kda_core``), ``mla_mixer`` (inner ``mla_core``), ``dense_ffn``,
+``moe_ffn`` (inner ``moe_route``, ``moe_experts`` from the routed layer,
+``moe_shared``), ``lm_head_loss``.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+from typing import Any
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from dinov3_tpu.ops.attention import dispatch_attention
+from dinov3_tpu.ops.common import l2_normalize, part, trunc_normal_init
+from dinov3_tpu.ops.ffn import RoutedExpertsFFN, SwiGLUFFN
+from dinov3_tpu.ops.kda import kda_chunked
+from dinov3_tpu.ops.norms import RMSNorm
+from dinov3_tpu.utils import step_phase
+
+@dataclasses.dataclass(frozen=True)
+class DecoderConfig:
+    """The ``lm`` section of a recipe, with the layer table made from
+    the published lists of layer numbers (1-based, as ``config.json``
+    gives them)."""
+
+    hidden_size: int
+    vocab_size: int
+    layers: tuple              # ((mixer, ffn), ...)
+    intermediate_size: int
+    rms_norm_eps: float
+    kda_num_heads: int
+    kda_head_dim: int
+    short_conv_kernel_size: int
+    num_attention_heads: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    num_experts: int
+    num_experts_per_token: int
+    moe_intermediate_size: int
+    num_shared_experts: int
+    routed_scaling_factor: float
+    expert_shards: int
+    expert_shard: int
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+    reduce_dtype: Any = jnp.float32
+
+    @classmethod
+    def from_cfg(cls, cfg, param_dtype=None) -> "DecoderConfig":
+        from dinov3_tpu.ops.common import Policy
+
+        lm = cfg.lm
+        if lm.get("q_lora_rank") is not None:
+            raise ValueError("lm.q_lora_rank: only null (a full q projection)")
+        if str(lm.moe_router_activation_func) != "sigmoid" \
+                or not bool(lm.moe_renormalize):
+            raise ValueError("the routed layer is a renormalised sigmoid router")
+        depth = int(lm.num_hidden_layers)
+        kda, full = set(lm.kda_layers), set(lm.full_attn_layers)
+        if kda & full or (kda | full) != set(range(1, depth + 1)):
+            raise ValueError(
+                f"lm.kda_layers {sorted(kda)} and lm.full_attn_layers "
+                f"{sorted(full)} must split layers 1..{depth}")
+        layers = tuple(
+            ("kda" if i in kda else "mla",
+             "dense" if i <= int(lm.first_k_dense_replace) else "moe")
+            for i in range(1, depth + 1))
+        policy = Policy.from_cfg(cfg.compute_precision)
+        names = {f.name for f in dataclasses.fields(cls)} - {
+            "layers", "dtype", "param_dtype", "reduce_dtype"}
+        return cls(
+            layers=layers, dtype=policy.compute_dtype,
+            param_dtype=param_dtype or policy.param_dtype,
+            reduce_dtype=policy.reduce_dtype,
+            **{k: lm[k] for k in names if k in lm})
+
+
+def _dense(features: int, axes, name: str, dtype, param_dtype) -> nn.Dense:
+    return nn.Dense(features, use_bias=False, dtype=dtype,
+                    param_dtype=param_dtype, name=name,
+                    kernel_init=part(trunc_normal_init(), axes))
+
+
+def _swiglu(width: int, name: str, dtype, param_dtype) -> SwiGLUFFN:
+    """``SwiGLUFFN`` sizes its hidden layer as 2/3 of what it is given."""
+    if width % 2:
+        raise ValueError(f"SwiGLU width {width} must be even")
+    return SwiGLUFFN(hidden_dim=width * 3 // 2, use_bias=False, align_to=1,
+                     dtype=dtype, param_dtype=param_dtype, name=name)
+
+
+def causal_depthwise_conv(x, kernel):
+    """y_t = sum_j kernel[j] * x_{t - (W-1) + j}: [B, T, C] by [W, C]."""
+    w = kernel.shape[0]
+    xp = jnp.pad(x, ((0, 0), (w - 1, 0), (0, 0)))
+    t = x.shape[1]
+    return sum(xp[:, j:j + t] * kernel[j] for j in range(w))
+
+
+def a_log_init(key, shape, dtype=jnp.float32):
+    """A_log = log A, A uniform on [1, 16) (the released code's init)."""
+    return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0)
+                   ).astype(dtype)
+
+
+def dt_bias_init(key, shape, dtype=jnp.float32, lo=1e-3, hi=1e-1):
+    """softplus(dt_bias) = dt, dt log-uniform on [lo, hi] (the released
+    code's init, after Mamba's)."""
+    dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32,
+                                    jnp.log(lo), jnp.log(hi)))
+    return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+
+
+class KDAMixer(nn.Module):
+    num_heads: int
+    head_dim: int
+    conv_size: int = 4
+    eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        b, t, _ = x.shape
+        h, d = self.num_heads, self.head_dim
+        kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
+        xc = x.astype(self.dtype)
+
+        # The elementwise chains between the matmuls run in float32 and
+        # hand on bfloat16 activations; each is rematerialised by itself,
+        # so a layer's backward keeps their bf16 ends and not the dozen
+        # [tokens, heads * head_dim] float32 planes in between.
+        @functools.partial(jax.checkpoint, static_argnums=(2,))
+        def conv_act(y, kernel, normalise):
+            y = nn.silu(causal_depthwise_conv(
+                y.astype(jnp.float32), kernel.astype(jnp.float32)))
+            y = y.reshape(b, t, h, d)
+            if normalise:
+                y = l2_normalize(y)
+            return y.astype(self.dtype)
+
+        def short_conv(name, normalise):
+            y = _dense(h * d, ("embed", "heads"), f"{name}_proj", **kw)(xc)
+            kernel = self.param(
+                f"{name}_conv", part(trunc_normal_init(), (None, "heads")),
+                (self.conv_size, h * d), self.param_dtype)
+            return conv_act(y, kernel, normalise)
+
+        q, k, v = (short_conv("q", True), short_conv("k", True),
+                   short_conv("v", False))
+        # the decay, one per key channel, in log space and float32
+        a_log = self.param("A_log", part(a_log_init, ("heads",)),
+                           (h,), self.param_dtype)
+        dt_bias = self.param("dt_bias", part(dt_bias_init, ("heads",)),
+                             (h * d,), self.param_dtype)
+        f = _dense(d, ("embed", None), "f_a", **kw)(xc)
+        f = _dense(h * d, (None, "heads"), "f_b", **kw)(f)
+
+        @jax.checkpoint
+        def log_decay(f, a_log, dt_bias):
+            return -jnp.exp(a_log.astype(jnp.float32))[:, None] \
+                * jax.nn.softplus(
+                    (f.astype(jnp.float32) + dt_bias.astype(jnp.float32))
+                    .reshape(b, t, h, d))
+
+        g = log_decay(f, a_log, dt_bias)
+        beta = jax.nn.sigmoid(
+            _dense(h, ("embed", None), "b_proj", **kw)(xc).astype(jnp.float32))
+        with jax.named_scope("kda_core"):
+            # q's d^-0.5 goes in with the float32 products, after the
+            # bf16 hand-over
+            o = kda_chunked(q, k, v, g, beta, q_scale=d ** -0.5)
+        gate = _dense(d, ("embed", None), "g_a", **kw)(xc)
+        gate = _dense(h * d, (None, "heads"), "g_b", **kw)(gate)
+        scale = self.param("o_norm_scale", part(nn.initializers.ones, (None,)),
+                           (d,), self.param_dtype)
+
+        @jax.checkpoint
+        def gated_norm(o, gate, scale):
+            ms = jnp.mean(jnp.square(o), axis=-1, keepdims=True)
+            o = o * jax.lax.rsqrt(ms + self.eps) * scale.astype(jnp.float32)
+            o = o * jax.nn.sigmoid(
+                gate.astype(jnp.float32).reshape(b, t, h, d))
+            return o.reshape(b, t, h * d).astype(self.dtype)
+
+        o = gated_norm(o, gate, scale)
+        return _dense(x.shape[-1], ("heads", "embed"), "o_proj", **kw)(o)
+
+
+class MLAMixer(nn.Module):
+    num_heads: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+    reduce_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        b, t, _ = x.shape
+        h = self.num_heads
+        nope, rope, dv = (self.qk_nope_head_dim, self.qk_rope_head_dim,
+                          self.v_head_dim)
+        kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
+        xc = x.astype(self.dtype)
+        q = _dense(h * (nope + rope), ("embed", "heads"), "q_proj", **kw)(xc)
+        q = q.reshape(b, t, h, nope + rope)
+        kva = _dense(self.kv_lora_rank + rope, ("embed", None), "kv_a", **kw)(xc)
+        c, kpe = kva[..., :self.kv_lora_rank], kva[..., self.kv_lora_rank:]
+        c = RMSNorm(epsilon=self.eps, param_dtype=self.param_dtype,
+                    name="kv_a_norm")(c)
+        kvb = _dense(h * (nope + dv), (None, "heads"), "kv_b", **kw)(c)
+        kvb = kvb.reshape(b, t, h, nope + dv)
+        # no rotation is applied (mla_use_nope): kpe is one more slice
+        # of the key, the same for every head
+        k = jnp.concatenate([
+            kvb[..., :nope],
+            jnp.broadcast_to(kpe[:, :, None, :], (b, t, h, rope))], axis=-1)
+        with jax.named_scope("mla_core"):
+            o = dispatch_attention(q, k, kvb[..., nope:], causal=True,
+                                   reduce_dtype=self.reduce_dtype)
+        return _dense(x.shape[-1], ("heads", "embed"), "o_proj", **kw)(
+            o.reshape(b, t, h * dv))
+
+
+class DecoderLayer(nn.Module):
+    mixer: str                 # "kda" | "mla"
+    ffn: str                   # "dense" | "moe"
+    cfg: Any                   # the frozen ``DecoderConfig``
+
+    @nn.compact
+    def __call__(self, x):
+        c = self.cfg
+        kw = dict(dtype=c.dtype, param_dtype=c.param_dtype)
+        norm = lambda name: RMSNorm(  # noqa: E731
+            epsilon=c.rms_norm_eps, param_dtype=c.param_dtype, name=name)
+        # a phase holds its pre-norm and its residual add: what is left
+        # outside every phase is what the compiler makes between layers
+        if self.mixer == "kda":
+            with step_phase("kda_mixer"):
+                y = KDAMixer(c.kda_num_heads, c.kda_head_dim,
+                             c.short_conv_kernel_size, c.rms_norm_eps,
+                             name="kda", **kw)(norm("norm1")(x))
+                x = x + y.astype(x.dtype)
+        else:
+            with step_phase("mla_mixer"):
+                y = MLAMixer(c.num_attention_heads, c.kv_lora_rank,
+                             c.qk_nope_head_dim, c.qk_rope_head_dim,
+                             c.v_head_dim, c.rms_norm_eps,
+                             reduce_dtype=c.reduce_dtype, name="mla", **kw)(
+                                 norm("norm1")(x))
+                x = x + y.astype(x.dtype)
+        aux = None
+        if self.ffn == "dense":
+            with step_phase("dense_ffn"):
+                y = _swiglu(c.intermediate_size, "mlp", **kw)(norm("norm2")(x))
+                x = x + y.astype(x.dtype)
+        else:
+            with step_phase("moe_ffn"):
+                y = norm("norm2")(x)
+                routed, aux = RoutedExpertsFFN(
+                    c.moe_intermediate_size, c.num_experts,
+                    c.num_experts_per_token, c.expert_shards, c.expert_shard,
+                    c.routed_scaling_factor, name="experts", **kw)(y)
+                with jax.named_scope("moe_shared"):
+                    shared = _swiglu(
+                        c.moe_intermediate_size * c.num_shared_experts,
+                        "shared", **kw)(y)
+                x = x + (routed + shared).astype(x.dtype)
+        return x, aux
+
+
+class LMDecoder(nn.Module):
+    """``__call__(tokens)`` -> logits [B, T, V] float32 (small sizes);
+    ``__call__(tokens, with_loss=True)`` -> (loss, aux): the mean
+    next-token cross-entropy over positions 0..T-2 of every sequence, and
+    the routed layers' ``choice`` [L_moe, B*T, K], ``rows``, ``capacity``,
+    ``overflow`` and ``load_max_over_mean`` stacked [L_moe]."""
+
+    cfg: Any
+
+    @property
+    def embed_dim(self) -> int:
+        return self.cfg.hidden_size
+
+    @nn.compact
+    def __call__(self, tokens, with_loss: bool = False):
+        c = self.cfg
+        b, t = tokens.shape
+        table = self.param(
+            "token_embed", part(trunc_normal_init(), ("vocab", "embed")),
+            (c.vocab_size, c.hidden_size), c.param_dtype)
+        with step_phase("lm_embed"):
+            x = jnp.take(table.astype(c.dtype), tokens, axis=0)
+        # a layer is rematerialised: the backward pass keeps the [B, T, D]
+        # residual stream between layers and makes a layer's inside again
+        layer_cls = nn.remat(DecoderLayer)
+        auxes = []
+        for i, (mixer, ffn) in enumerate(c.layers):
+            x, aux = layer_cls(mixer, ffn, c, name=f"layers_{i}")(x)
+            if aux is not None:
+                auxes.append(aux)
+        aux = ({k: jnp.stack([a[k] for a in auxes]) for k in auxes[0]}
+               if auxes else {})
+        head = self.param(
+            "lm_head", part(trunc_normal_init(), ("embed", "vocab")),
+            (c.hidden_size, c.vocab_size), c.param_dtype)
+        with step_phase("lm_head_loss"):
+            x = RMSNorm(epsilon=c.rms_norm_eps, param_dtype=c.param_dtype,
+                        name="norm")(x)
+            if not with_loss:
+                return jnp.einsum("btd,dv->btv", x.astype(c.dtype),
+                                  head.astype(c.dtype),
+                                  preferred_element_type=jnp.float32)
+            loss = next_token_loss(x.astype(c.dtype), head.astype(c.dtype),
+                                   tokens)
+        return loss, aux
+
+
+LOSS_BLOCK = 2048  # tokens whose [block, V] logits exist at a time
+
+
+def next_token_loss(x, head, tokens, block: int = LOSS_BLOCK):
+    """Mean over b, t < T-1 of logsumexp(x_bt W) - (x_bt W)[token_b,t+1],
+    float32, the [block, V] logits of one block of tokens at a time
+    (rematerialised: the backward pass makes them again)."""
+    b, t, d = x.shape
+    targets = jnp.roll(tokens, -1, axis=1).reshape(-1)
+    counted = (jnp.arange(t) < t - 1)[None].repeat(b, 0).reshape(-1)
+    n = b * t
+    block = min(block, n)
+    pad = (-n) % block
+    xs = jnp.pad(x.reshape(n, d), ((0, pad), (0, 0)))
+    targets = jnp.pad(targets, (0, pad))
+    counted = jnp.pad(counted, (0, pad))
+
+    @jax.checkpoint
+    def one(args):
+        xb, tb, cb = args
+        logits = jnp.dot(xb, head, preferred_element_type=jnp.float32)
+        nll = jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(
+            logits, tb[:, None], axis=-1)[:, 0]
+        return jnp.sum(jnp.where(cb, nll, 0.0))
+
+    shape = lambda a: a.reshape((-1, block) + a.shape[1:])  # noqa: E731
+    sums = jax.lax.map(one, (shape(xs), shape(targets), shape(counted)))
+    return jnp.sum(sums) / (b * (t - 1))

@@ -9,6 +9,7 @@ up to ``align_to``.)
 
 from __future__ import annotations
 
+import math
 from typing import Any, Callable
 
 import flax.linen as nn
@@ -211,6 +212,152 @@ class MoEFFN(nn.Module):
         # mesh axis under GSPMD)
         gate_e = jnp.moveaxis(gate, -1, 0).astype(self.dtype)  # [E, ...]
         return jnp.sum(y * gate_e[..., None], axis=0)
+
+
+# The compact row buffer of ``RoutedExpertsFFN`` holds this many times
+# the rows its experts get under an even router. A CAPACITY, not a bound:
+# the true bound is every choice there is (``n_tokens * min(top_k, held)``,
+# 16 times the even share at 8 of 256 experts and top-8), which no chip
+# has the memory for. What a capacity-factor layer usually does with the
+# pairs past it is drop them silently; this one counts them and the
+# meta-arch makes the loss non-finite, so a run stops instead of training
+# on tokens that lost experts.
+ROWS_CAPACITY_FACTOR = 2.0
+
+
+def routed_rows_capacity(n_tokens: int, top_k: int, num_experts: int,
+                         held: int, factor: float = ROWS_CAPACITY_FACTOR) -> int:
+    """Rows of the compact buffer of ``RoutedExpertsFFN``: ``factor``
+    times the rows its ``held`` experts get when the router spreads
+    ``n_tokens * top_k`` choices evenly over ``num_experts``, rounded up
+    to a multiple of 128 and never more than every choice there is
+    (``n_tokens * min(top_k, held)``: past that nothing can overflow)."""
+    expected = n_tokens * top_k * held / num_experts
+    rows = -(-int(math.ceil(factor * expected)) // 128) * 128
+    return max(1, min(rows, n_tokens * min(top_k, held)))
+
+
+class RoutedExpertsFFN(nn.Module):
+    """The routed experts of one expert-parallel shard: a router over
+    all ``num_experts``, the SwiGLU experts ``[first, first + held)`` of
+    them held here (``shard`` of ``shards`` equal ones), and the part of
+    the layer's result that those give.
+
+        s = sigmoid(W_r x) (float32); the top_k largest of s + bias;
+        w = scale * s_sel / sum(s_sel);
+        y = sum over the selected experts e held here of w_e SwiGLU_e(x)
+
+    What the absent experts would add is another shard's to compute and
+    an all-to-all's to bring; on one shard the layer runs without that
+    exchange. The (token, choice) pairs routed to a held expert are
+    gathered, sorted by expert, into a compact ``[rows, D]`` buffer, two
+    grouped matmuls (``lax.ragged_dot``) run over the rows each expert
+    was sent and no others, and the weighted rows are added back to
+    their tokens. ``rows`` is ``routed_rows_capacity``: no token is
+    dropped while the held experts draw at most ``rows_factor`` times
+    their even share; beyond it ``overflow`` counts the pairs left out,
+    for the caller to make the loss non-finite with.
+
+    The selection bias takes no gradient (it is the balancing signal of
+    an aux-loss-free router, moved by its own rule and not by the loss).
+
+    Returns ``(y, aux)``: ``choice`` [N, top_k] int32 (the experts each
+    token chose, over all of them), ``rows`` (pairs routed here),
+    ``capacity``, ``overflow`` and ``load_max_over_mean`` (largest held
+    expert's rows over the held experts' mean), float32 scalars.
+    """
+
+    hidden_dim: int
+    num_experts: int
+    top_k: int
+    shards: int = 1
+    shard: int = 0
+    scale: float = 1.0
+    rows_factor: float = ROWS_CAPACITY_FACTOR
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray):
+        import jax
+
+        E, K, H = self.num_experts, self.top_k, self.hidden_dim
+        if E % self.shards or not 0 <= self.shard < self.shards:
+            raise ValueError(
+                f"shard {self.shard} of {self.shards} over {E} experts")
+        held = E // self.shards
+        first = self.shard * held
+        D = x.shape[-1]
+        x2 = x.reshape(-1, D)
+        N = x2.shape[0]
+        cap = routed_rows_capacity(N, K, E, held, self.rows_factor)
+
+        router = self.param(
+            "router", part(trunc_normal_init(), ("embed", None)),
+            (D, E), self.param_dtype)
+        bias = self.param(
+            "router_bias", part(nn.initializers.zeros, (None,)),
+            (E,), self.param_dtype)
+        w12 = self.param(
+            "w12", part(trunc_normal_init(), ("experts", "embed", "mlp")),
+            (held, D, 2 * H), self.param_dtype)
+        w3 = self.param(
+            "w3", part(trunc_normal_init(), ("experts", "mlp", "embed")),
+            (held, H, D), self.param_dtype)
+
+        with jax.named_scope("moe_route"):
+            s = jax.nn.sigmoid(jnp.dot(
+                x2.astype(jnp.float32), router.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST))
+            _, choice = jax.lax.top_k(
+                s + jax.lax.stop_gradient(bias.astype(jnp.float32)), K)
+            s_sel = jnp.take_along_axis(s, choice, axis=-1)
+            w = self.scale * s_sel / jnp.sum(s_sel, axis=-1, keepdims=True)
+            # the pairs of held experts, expert by expert, first
+            local = (choice - first).reshape(-1)
+            here = (local >= 0) & (local < held)
+            key = jnp.where(here, local, held)
+            order = jnp.argsort(key, stable=True)[:cap]
+            counts = jnp.sum(
+                key[:, None] == jnp.arange(held, dtype=key.dtype)[None],
+                axis=0, dtype=jnp.int32)
+            n_here = jnp.sum(counts)
+            before = jnp.cumsum(counts) - counts
+            sizes = jnp.clip(cap - before, 0, counts)
+            kept = jnp.arange(cap) < jnp.minimum(n_here, cap)
+            token = order // K
+            w_rows = jnp.where(kept, w.reshape(-1)[order], 0.0)
+
+        with jax.named_scope("moe_experts"):
+            # The rows past the last group belong to no expert, and
+            # ``ragged_dot`` does not say what it leaves there: on the
+            # CPU zeros, on a v5e whatever the buffer held — in the
+            # BACKWARD pass too, where the gradient with respect to
+            # those rows went into the tokens' gradient and was a
+            # million times the true one (my chip runs, PR 27). Both
+            # ends are masked by ``kept``, values and gradients alike.
+            rows = jnp.where(kept[:, None],
+                             jnp.take(x2.astype(self.dtype), token, axis=0), 0)
+            h = jax.lax.ragged_dot(
+                rows, w12.astype(self.dtype), sizes,
+                preferred_element_type=jnp.float32).astype(self.dtype)
+            gate, value = jnp.split(h, 2, axis=-1)
+            out = jax.lax.ragged_dot(
+                nn.silu(gate) * value, w3.astype(self.dtype), sizes,
+                preferred_element_type=jnp.float32)
+            out = jnp.where(kept[:, None], out, 0.0) * w_rows[:, None]
+            y = jnp.zeros((N, D), jnp.float32).at[token].add(out)
+
+        f32 = lambda v: jnp.asarray(v, jnp.float32)  # noqa: E731
+        aux = {
+            "choice": choice.astype(jnp.int32),
+            "rows": f32(n_here),
+            "capacity": f32(cap),
+            "overflow": f32(jnp.maximum(n_here - cap, 0)),
+            "load_max_over_mean": f32(jnp.max(counts)) * held
+            / jnp.maximum(f32(n_here), 1.0),
+        }
+        return y.astype(self.dtype).reshape(x.shape), aux
 
 
 def make_ffn_layer(kind: str, hidden_dim: int, *, moe_num_experts: int = 8,

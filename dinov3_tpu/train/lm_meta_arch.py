@@ -1,0 +1,84 @@
+"""The meta-arch of a next-token step: one student, no teacher.
+
+It stands where ``SSLMetaArch`` stands in ``build_train_setup`` and
+``make_train_step``: the same ``init_params`` / ``init_state`` /
+``forward`` contract, so the step's skeleton (rng fold, value_and_grad,
+the fused clip + AdamW pass, the telemetry ring), the schedules, the
+param groups and the checkpoints are the SSL step's own. What it lacks
+it lacks outright: ``params`` has no ``teacher`` entry, the update has
+no EMA leg (``ema_teacher`` is False), and the scalars ``teacher_temp``
+and ``momentum`` reach ``forward`` and are not read.
+
+It carries no non-param state (``TrainState.center_state`` is empty).
+``routing`` gives what each token's router picks over all the experts,
+for whoever asks (the benchmark's reference has to follow the same
+routing); the step itself keeps none of it.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from dinov3_tpu.configs import ConfigNode
+from dinov3_tpu.models import build_backbone
+
+
+class LMMetaArch:
+    # what build_train_setup / make_train_step ask of a meta-arch
+    rng_plan = False
+    crop_packing = False
+    distillation = False
+    ema_teacher = False
+    supports_accum = False
+    teacher_source = "in_step"
+    zero3_gather = False
+    zero3_buckets = False
+
+    def __init__(self, cfg: ConfigNode):
+        self.cfg = cfg
+        # masters are float32 whatever the policy stores (ssl_meta_arch.py)
+        self.student_backbone = build_backbone(cfg, param_dtype=jnp.float32)
+        self.embed_dim = self.student_backbone.embed_dim
+
+    def init_params(self, rng: jax.Array, batch: dict, unbox: bool = True) -> dict:
+        import flax.linen as nn
+
+        variables = self.student_backbone.init(rng, batch["tokens"])
+        params = {"student": {"backbone": variables["params"]}}
+        return nn.meta.unbox(params) if unbox else params
+
+    def init_state(self) -> dict:
+        return {}
+
+    def routing(self, student_params, batch) -> jax.Array:
+        """[routed layers, tokens, top_k] int32: the experts, out of all
+        of them, that each token's router chooses under these weights."""
+        _, aux = self.student_backbone.apply(
+            {"params": student_params["backbone"]}, batch["tokens"],
+            with_loss=True)
+        return aux["choice"]
+
+    def _zero3_gather_params(self, tree):
+        return tree
+
+    def forward(self, student_params, frozen_params, batch, *, state, **_):
+        """(loss, (metrics, new_state)); what else the step hands every
+        meta-arch (iteration, teacher temperature, rng streams) is not
+        read. A routed layer whose compact row
+        buffer overflowed left tokens out: the loss is then NaN, which
+        the loop's non-finite watchdog stops on, and ``moe_rows_overflow``
+        says by how many pairs."""
+        loss, aux = self.student_backbone.apply(
+            {"params": student_params["backbone"]}, batch["tokens"],
+            with_loss=True)
+        metrics = {"lm_loss": loss}
+        if aux:
+            overflow = jnp.sum(aux["overflow"])
+            loss = jnp.where(overflow > 0, jnp.nan, loss)
+            metrics.update(
+                moe_rows_fill=jnp.max(aux["rows"] / aux["capacity"]),
+                moe_rows_overflow=overflow,
+                moe_load_max_over_mean=jnp.max(aux["load_max_over_mean"]))
+        metrics["total_loss"] = loss
+        return loss, (metrics, state)

@@ -2,7 +2,8 @@
 
 (reference: dinov3_jax/train/param_groups.py — same semantics: ViT layerwise
 lr decay, patch-embed lr multiplier, DINO-head wd multiplier, zero wd for
-biases/norms/layerscale gammas, last-layer (prototypes) freeze flag — but
+biases/norms/layerscale gammas (and a decoder's ``A_log``; its ``dt_bias``
+and ``router_bias`` are biases by name), last-layer (prototypes) freeze flag — but
 emitted as *multiplier pytrees* consumed by one custom optax chain instead
 of string labels for ``optax.multi_transform``. This removes the reference's
 per-group adamw instances and their late-binding lr/wd closure bug
@@ -77,7 +78,7 @@ def build_multiplier_trees(
         if (
             name.endswith("bias")
             or "norm" in name
-            or path[-1] == "gamma"
+            or path[-1] in ("gamma", "A_log")
         ):
             wd = 0.0
         if "patch_embed" in name:

@@ -84,7 +84,7 @@ class TelemetryPlan:
 @dataclasses.dataclass
 class TrainSetup:
     cfg: ConfigNode
-    meta: SSLMetaArch
+    meta: Any  # SSLMetaArch | LMMetaArch
     mesh: Any
     schedules: Schedules
     optimizer: Any
@@ -112,7 +112,7 @@ class TrainSetup:
     telemetry_builder: Callable | None = None
     _telemetry_cache: Any = dataclasses.field(default=None, repr=False)
 
-    def mask_rows_limit(self, host_batch: dict) -> int:
+    def mask_rows_limit(self, host_batch: dict) -> int | None:
         """The masked tokens ONE ``sample_ibot_masks`` call of
         ``host_batch``'s shape carries under this config — what
         ``put_batch`` holds each batch of a loader that collates once a
@@ -120,6 +120,8 @@ class TrainSetup:
         ``mask_sampler_calls``, rounded up)."""
         from dinov3_tpu.data.masking import ibot_mask_targets
 
+        if "mask_indices" not in host_batch:
+            return None  # a batch of tokens: nothing is masked
         n_img, capacity = host_batch["mask_indices"].shape
         return sum(ibot_mask_targets(
             n_img, host_batch["masks"].shape[1], capacity,
@@ -246,7 +248,16 @@ def _build_train_setup(
                 "pipelined block stack bypasses the per-block zero3 "
                 "stream the quantized gathers ride."
             )
-    meta = SSLMetaArch(cfg, mask_sampler_calls=mask_sampler_calls)
+    # what kind of step a recipe runs follows from its architecture: a
+    # token decoder has a student and a next-token loss, and no teacher
+    from dinov3_tpu.configs.config import is_lm_arch
+
+    if is_lm_arch(cfg):
+        from dinov3_tpu.train.lm_meta_arch import LMMetaArch
+
+        meta = LMMetaArch(cfg)
+    else:
+        meta = SSLMetaArch(cfg, mask_sampler_calls=mask_sampler_calls)
     if meta.teacher_source == "serve" and "teacher_cls" not in example_batch:
         # the serve-backed teacher arm changes the STEP SIGNATURE: the
         # precomputed teacher planes are batch inputs (batch-sharded by
@@ -412,12 +423,12 @@ def _build_train_setup(
             warn_bucket_padding(bucket_plan.padding_stats(), target_bytes)
             fused = build_bucketed_update(
                 cfg, abstract_params["student"], schedules, mesh,
-                bucket_plan, ema=not meta.distillation,
+                bucket_plan, ema=meta.ema_teacher,
             )
         elif use_sharded:
             fused = build_sharded_update(
                 cfg, abstract_params["student"], schedules, mesh,
-                ema=not meta.distillation,
+                ema=meta.ema_teacher,
             )
             # padding guardrail: warn when the per-leaf zero-padding to
             # a multiple of dp wastes > 1% of the flat master size
@@ -432,7 +443,7 @@ def _build_train_setup(
         else:
             fused = build_fused_update(
                 cfg, abstract_params["student"], schedules,
-                ema=not meta.distillation,
+                ema=meta.ema_teacher,
             )
 
     def boxed_init(r):

@@ -111,6 +111,10 @@ class SSLMetaArch:
         # architecture resolved from its own config
         # (reference: ssl_meta_arch.py _setup_distillation:257-286).
         self.distillation = bool(cfg.distillation.enabled)
+        # the update's EMA leg: a frozen pretrained teacher has none
+        self.ema_teacher = not self.distillation
+        # a crop-major batch splits into microbatches (optim.accum_steps)
+        self.supports_accum = True
         teacher_cfg = cfg
         if self.distillation:
             from dinov3_tpu.train.distillation import resolve_distillation_cfg

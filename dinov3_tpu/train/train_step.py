@@ -55,9 +55,10 @@ from dinov3_tpu.utils import step_phase
 
 
 class TrainState(NamedTuple):
-    params: Any        # {"student": .., "teacher": .., ["gram": ..]}
+    params: Any        # {"student": .., ["teacher": ..], ["gram": ..]}
     opt_state: Any
-    center_state: Any  # softmax-centering EMA centers
+    center_state: Any  # the meta-arch's non-param state (SSL: the
+                       # softmax-centering EMA centers)
     step: jnp.ndarray
     # fp8/int8 delayed-scaling amax-history rings (ops/lowp.py):
     # {"student": tree, "teacher": tree} of f32 [H] (or [L, H] scanned)
@@ -160,6 +161,10 @@ def make_train_step(
     if accum_steps < 1:
         raise ValueError(
             f"optim.accum_steps must be >= 1, got {accum_steps}")
+    if accum_steps > 1 and not meta.supports_accum:
+        raise ValueError(
+            "optim.accum_steps > 1 splits a crop-major SSL batch "
+            f"(split_microbatches); {type(meta).__name__} takes 1")
     lowp_arm = (lowp or {}).get("arm", "bf16")
 
     def step(state: TrainState, batch: dict, scalars: dict, rng: jax.Array):
@@ -288,13 +293,16 @@ def make_train_step(
         )(state.params["student"])
 
         metrics = dict(loss_dict)
+        # a meta-arch with a student and no teacher (lm_meta_arch.py): no
+        # ``teacher`` entry in the params, no EMA leg in the update
+        teacher = state.params.get("teacher")
         with step_phase("update"):
             if fused_update is not None:
                 # single pass over every weight-shaped leaf: clip scales
                 # from one up-front batched reduction, AdamW + EMA folded
                 # into one tree.map (train/fused_update.py)
                 new_student, new_teacher, new_opt_state, norms = fused_update(
-                    grads, state.params["student"], state.params["teacher"],
+                    grads, state.params["student"], teacher,
                     state.opt_state, scalars["momentum"],
                 )
             else:
@@ -306,9 +314,8 @@ def make_train_step(
                 )
                 new_student = optax.apply_updates(
                     state.params["student"], updates)
-                new_teacher = meta.update_ema(
-                    state.params["teacher"], new_student, scalars["momentum"]
-                )
+                new_teacher = teacher if teacher is None else meta.update_ema(
+                    teacher, new_student, scalars["momentum"])
             new_lowp = state.lowp
             if fwd_lowp is not None:
                 # delayed scaling: the rings observe the UPDATED masters
@@ -322,7 +329,8 @@ def make_train_step(
                 metrics[f"grad_norm/{k}"] = v
         new_params = dict(state.params)
         new_params["student"] = new_student
-        new_params["teacher"] = new_teacher
+        if teacher is not None:
+            new_params["teacher"] = new_teacher
 
         new_state = TrainState(
             params=new_params,

@@ -538,7 +538,19 @@ STEP_PHASES = (
     "update",             # clip + AdamW + EMA (fused or optax), lowp rings
     "rng_plan",           # the step-wide RNG plan's draws
     "telemetry_ring",     # the metrics row's write into the ring
+    # the next-token step of a decoder (models/decoder.py); the update
+    # stays ``update``
+    "lm_embed",           # the token embedding's gather
+    "kda_mixer",          # a KDA layer's mixer (inner: kda_core)
+    "mla_mixer",          # a latent-attention layer's mixer (inner: mla_core)
+    "dense_ffn",          # the dense SwiGLU of the leading layers
+    "moe_ffn",            # routed + shared experts (inner: moe_route,
+                          # moe_experts, moe_shared)
+    "lm_head_loss",       # final norm, head and cross-entropy, by blocks
 )
+# the phases only a decoder's step opens
+LM_STEP_PHASES = ("lm_embed", "kda_mixer", "mla_mixer", "dense_ffn",
+                  "moe_ffn", "lm_head_loss")
 
 _PHASE_WRAPPER = re.compile(r"^(jvp|transpose|checkpoint|remat)\((.*)\)$")
 

@@ -12,6 +12,8 @@ import os
 import jax
 import pytest
 
+from test_lm_decoder import TINY as LM_TINY
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -32,6 +34,9 @@ TINY = {
         "dino.head_n_prototypes=256", "dino.head_hidden_dim=64",
         "ibot.head_n_prototypes=256", "ibot.head_hidden_dim=64"],
     "train_iters": 3,
+    "lm_overrides": ["data.backend=synthetic", *LM_TINY],
+    "lm_iters": 3,
+    "lm_timeout_s": 600,
     "mesh_global_batch": 8,
     "mesh_iters": 2,
     "flash_shape": (1, 200, 2, 32),
@@ -97,9 +102,21 @@ def test_smoke_rehearsal_runs_every_phase(smoke, monkeypatch, capsys):
     for needle in ("self-check", "0 failures", "resumed at 3",
                    "step[accum2]: ibot_rows_fill", "kernels: flash ", "kernels: flash_seg",
                    "kernels: fused_layernorm", "serve:",
-                   "compiles packed 1", "all phases passed"):
+                   "compiles packed 1", "lm: losses", "lm: resumed at 3",
+                   "all phases passed"):
         assert needle in said, needle
     assert "mesh[" not in said  # the four-chip phase is behind --chips 4
+
+
+def test_smoke_runs_the_phases_it_is_asked_for(smoke, monkeypatch, capsys):
+    """--phases lm: the decoder's trainer alone (the first chip call of a
+    new step program), and a phase nobody knows is refused."""
+    _rehearse(smoke, monkeypatch, count=1)
+    assert smoke.main(["--phases", "lm"]) == 0
+    said = capsys.readouterr().out
+    assert "lm: resumed at 3" in said and "trainer:" not in said
+    with pytest.raises(SystemExit, match="unknown phases"):
+        smoke.main(["--phases", "lm,nope"])
 
 
 def test_smoke_wraps_no_phase_in_an_except():

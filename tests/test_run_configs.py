@@ -30,10 +30,31 @@ def test_recipe_abstract_build(path):
     cfg = load_config(path)
     if cfg.distillation.enabled:
         pytest.skip("needs a teacher checkpoint; covered in test_distillation")
+    from dinov3_tpu.configs.config import is_lm_arch
     from dinov3_tpu.data import make_synthetic_batch
     from dinov3_tpu.train.schedules import build_schedules
     from dinov3_tpu.train.ssl_meta_arch import SSLMetaArch
 
+    if is_lm_arch(cfg):
+        # a decoder's recipe: a student and no teacher. Shrink the widths
+        # (tests/test_lm_decoder.py TINY) and KEEP the structure: the layer
+        # table, the experts' share, the schedules
+        from test_lm_decoder import TINY
+
+        from dinov3_tpu.train.lm_meta_arch import LMMetaArch
+
+        apply_dot_overrides(cfg, [*TINY, "train.OFFICIAL_EPOCH_LENGTH=2"])
+        assert build_schedules(cfg).at(0)["lr"] >= 0.0
+        batch = {k: jnp.asarray(v) for k, v in
+                 make_synthetic_batch(cfg, 2, seed=0).items()}
+        meta = LMMetaArch(cfg)
+        abstract = jax.eval_shape(lambda r: meta.init_params(r, batch),
+                                  jax.random.key(0))
+        assert set(abstract) == {"student"}
+        layers = [k for k in abstract["student"]["backbone"]
+                  if k.startswith("layers_")]
+        assert len(layers) == cfg.lm.num_hidden_layers
+        return
     # shrink the compute-heavy dials but KEEP the recipe's structure
     # (arch, ffn kind, norms, rope flags, gram, schedules)
     small_arch = {

@@ -27,14 +27,21 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from dinov3_tpu.utils import STEP_PHASES, classify_step_phase, step_phase
+from dinov3_tpu.utils import (
+    LM_STEP_PHASES,
+    STEP_PHASES,
+    classify_step_phase,
+    step_phase,
+)
 from test_fused_update import smol_cfg
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # what the default configuration reaches: no Gram teacher before the
-# gram phase of a recipe (gram.use_loss=false)
-REACHED = tuple(p for p in STEP_PHASES if p != "gram_teacher")
+# gram phase of a recipe (gram.use_loss=false), and none of the phases
+# only a decoder's next-token step opens (tests/test_lm_decoder.py)
+REACHED = tuple(p for p in STEP_PHASES
+                if p != "gram_teacher" and p not in LM_STEP_PHASES)
 
 
 def _compiled_step_text() -> str:
