@@ -72,6 +72,8 @@ SIZES = {
     "flash_shape": (2, 2309, 16, 64),
     # [B*201, 1024]: the 224 px ViT-L token rows of a B=12 global pass
     "ln_shape": (12 * 201, 1024),
+    # the delta rule at published width: [B, T, heads, d_k = d_v]
+    "kda_shape": (1, 1024, 4, 128),
     "kernel_interpret": False,
     "serve_overrides": ["student.arch=vit_large", "student.patch_size=16",
                         "train.scan_layers=true"],
@@ -363,6 +365,69 @@ def phase_kernels() -> None:
     log(f"kernels: fused_layernorm {(R, W)} fwd max|err| {e_fwd:.4f}, "
         f"dx max|err| {e_dx:.4f}, dscale/dbias rel err {e_dp:.4f}")
     assert e_fwd <= 4e-2 and e_dx <= 4e-2 and e_dp <= 2e-2, (e_fwd, e_dx, e_dp)
+    _kda_kernel_row(interpret)
+
+
+def _kda_kernel_row(interpret: bool) -> None:
+    """``ops/kda.py``'s forward kernel (its backward is the plain scan's)
+    against the token recurrence, float32 inputs so that the gap is the
+    products' own: at the decays the published initial values reach
+    (1.6 nats a token) the largest gap of the output and of the five
+    gradients, relative to each one's largest entry, under 1e-4 (a
+    float32 product lowered as ONE bfloat16 pass reads 1e-2; interpret
+    mode cannot see that, the chip can); at 30 nats a token on half the
+    channels and at single tokens of 200, finite and under 1e-3."""
+    import jax
+    import jax.numpy as jnp
+
+    from dinov3_tpu.ops.kda import kda_chunked, kda_recurrent
+
+    b, t, h, d = SIZES["kda_shape"]
+    ks = jax.random.split(jax.random.key(1), 5)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
+    q, k = (unit(jax.random.normal(key, (b, t, h, d))) for key in ks[:2])
+    v = jax.random.normal(ks[2], (b, t, h, d))
+    published = -1.6 * jax.random.uniform(ks[3], (b, t, h, d))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, t, h)))
+    decays = {
+        "published": (published, 1e-4),
+        "30 nats": (jnp.where(jnp.arange(d) < d // 2, -30.0, published), 1e-3),
+        "spikes of 200": (jnp.where(
+            (jnp.arange(t) % 7 == 3)[None, :, None, None], -200.0,
+            0.1 * published), 1e-3),
+    }
+
+    def kern(*x):
+        return kda_chunked(*x, q_scale=d ** -0.5,
+                           interpret=True if interpret else None)
+
+    def ref(*x):
+        return d ** -0.5 * kda_recurrent(*x)
+
+    def out_and_grads(f, argnums):
+        return jax.jit(lambda *x: (f(*x), *jax.grad(
+            lambda *y: jnp.sum(jnp.sin(f(*y))), argnums=argnums)(*x)))
+
+    def gaps(x, argnums=(0, 1, 2, 3, 4)):
+        got, want = (out_and_grads(f, argnums)(*x) for f in (kern, ref))
+        return [_max_err(a, w) / float(jnp.max(jnp.abs(w)))
+                for a, w in zip(got, want)]
+
+    _compiled_has_kernel(jax.jit(kern), q, k, v, published, beta)
+    _compiled_has_kernel(out_and_grads(kern, (0, 1, 2, 3, 4)),
+                         q, k, v, published, beta)
+    for name, (g, limit) in decays.items():
+        found = gaps((q, k, v, g, beta))
+        log(f"kernels: kda_chunk_fwd {(b, t, h, d)} decay {name}: largest "
+            f"relative gap to the recurrence, output {found[0]:.2e}, "
+            f"gradients q k v g beta {' '.join(f'{x:.2e}' for x in found[1:])}")
+        assert all(math.isfinite(x) and x <= limit for x in found), (name, found)
+    # the activation type of the step: a block packs two heads' rows a word
+    found = gaps((*(x.astype(jnp.bfloat16) for x in (q, k, v)), published,
+                  beta), argnums=(3, 4))
+    log(f"kernels: kda_chunk_fwd bfloat16 q k v: output {found[0]:.2e}, "
+        f"gradients g beta {found[1]:.2e} {found[2]:.2e}")
+    assert all(math.isfinite(x) and x <= 1e-4 for x in found), found
 
 
 # ------------------------------------------------------------------ serve

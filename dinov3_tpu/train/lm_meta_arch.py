@@ -17,11 +17,16 @@ routing); the step itself keeps none of it.
 
 from __future__ import annotations
 
+import logging
+
 import jax
 import jax.numpy as jnp
 
 from dinov3_tpu.configs import ConfigNode
 from dinov3_tpu.models import build_backbone
+from dinov3_tpu.ops.kda import kda_path
+
+logger = logging.getLogger("dinov3")
 
 
 class LMMetaArch:
@@ -40,6 +45,11 @@ class LMMetaArch:
         # masters are float32 whatever the policy stores (ssl_meta_arch.py)
         self.student_backbone = build_backbone(cfg, param_dtype=jnp.float32)
         self.embed_dim = self.student_backbone.embed_dim
+        dc = self.student_backbone.cfg
+        path, why = kda_path(dc.kda_head_dim, dc.kda_head_dim)
+        for i, (mixer, _) in enumerate(dc.layers, 1):
+            if mixer == "kda":
+                logger.info("layer %d kda_core forward: %s (%s)", i, path, why)
 
     def init_params(self, rng: jax.Array, batch: dict, unbox: bool = True) -> dict:
         import flax.linen as nn

@@ -1,8 +1,9 @@
 """Compile the Pallas kernels for a DESCRIBED v5e chip, with no chip
 attached (on-chip-measurement guide §2.3): flash attention forward and
 backward, plain and segment-masked, at the 768 px (N=2309) and 1024 px
-(N=4101) ViT-L token counts, and the fused layernorm forward and
-backward at ViT-L width — each with ``interpret=False``, each asserting
+(N=4101) ViT-L token counts, the fused layernorm forward and
+backward at ViT-L width, and the delta rule's chunk forward at the
+decoder cell's shapes — each with ``interpret=False``, each asserting
 a Mosaic ``tpu_custom_call`` in the compiled text. What the chip's
 compiler would refuse (a slice off the tiling, too much VMEM) fails
 here, at no chip time. A compile that passes is not a chip run.
@@ -94,3 +95,29 @@ def test_fused_layernorm_compiles_for_v5e(one_chip, direction):
         bwd, [x, p, p, x])
     text = _compiled_text(fn, one_chip, *shapes)
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("states", [False, True], ids=["primal", "states"])
+def test_kda_chunk_forward_compiles_for_v5e(one_chip, states):
+    """``ops/kda.py``'s forward kernel at the decoder cell's shapes
+    (2 sequences of 8,192 tokens, 32 heads of 128 x 128): the primal,
+    and the pass that also writes each chunk's starting state (with the
+    plain backward behind it)."""
+    from dinov3_tpu.ops.kda import KERNEL_NAME, kda_chunked
+
+    act = ((2, 8192, 32, 128), jnp.bfloat16)
+    g, beta, o = (act[0], jnp.float32), ((2, 8192, 32), jnp.float32), \
+        (act[0], jnp.float32)
+
+    def fwd(*x):
+        return kda_chunked(*x, q_scale=128 ** -0.5, interpret=False)
+
+    def bwd(*x):
+        return jax.vjp(fwd, *x[:-1])[1](x[-1])
+
+    fn, shapes = (bwd, [act] * 3 + [g, beta, o]) if states else (
+        fwd, [act] * 3 + [g, beta])
+    text = _compiled_text(fn, one_chip, *shapes)
+    assert "tpu_custom_call" in text and KERNEL_NAME in text
+    if states:  # the [128, 2, 32, 128, 128] float32 starting states
+        assert "f32[128,2,32,128,128]" in text

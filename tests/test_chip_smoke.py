@@ -41,6 +41,7 @@ TINY = {
     "mesh_iters": 2,
     "flash_shape": (1, 200, 2, 32),
     "ln_shape": (64, 128),
+    "kda_shape": (1, 128, 1, 128),
     "kernel_interpret": True,
     "serve_overrides": [
         "student.arch=vit_test", "student.patch_size=4", "serve.min_px=8",
@@ -101,7 +102,8 @@ def test_smoke_rehearsal_runs_every_phase(smoke, monkeypatch, capsys):
     said = "\n".join(ln for ln in lines if ln.startswith("[chip_smoke"))
     for needle in ("self-check", "0 failures", "resumed at 3",
                    "step[accum2]: ibot_rows_fill", "kernels: flash ", "kernels: flash_seg",
-                   "kernels: fused_layernorm", "serve:",
+                   "kernels: fused_layernorm", "kernels: kda_chunk_fwd",
+                   "decay spikes of 200", "serve:",
                    "compiles packed 1", "lm: losses", "lm: resumed at 3",
                    "all phases passed"):
         assert needle in said, needle

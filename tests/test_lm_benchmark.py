@@ -43,11 +43,14 @@ def test_kda_core_ops_against_xla_cost_analysis(b, h, dk, dv, chunk):
     ``lm_flops.kda_core_ops`` counts, plus the elementwise work around
     them (exponentials, the blocks' placement, the decay of the state:
     a sixth more at the smaller size), which the count leaves out. (Over ONE chunk the compiler folds the products with the
-    all-zero first state away and counts less.)"""
+    all-zero first state away and counts less.) This reads the PLAIN
+    path's loop body: XLA cannot count inside the kernel's custom call,
+    and the kernel makes the same products."""
     import lm_flops
 
-    from dinov3_tpu.ops.kda import kda_chunked
+    from dinov3_tpu.ops.kda import kda_chunked, kda_path
 
+    assert kda_path(dk, dv, chunk)[0] == "scan"
     t = 3 * chunk
     x = (jnp.zeros((b, t, h, dk)), jnp.zeros((b, t, h, dk)),
          jnp.zeros((b, t, h, dv)), jnp.zeros((b, t, h, dk)),

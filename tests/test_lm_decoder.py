@@ -138,6 +138,94 @@ def test_delta_rule_is_finite_and_exact_at_any_decay(decay):
         kda_chunked(q, k, v, g, beta, chunk=48)
 
 
+def _grad_gap(got, want):
+    return float(jnp.max(jnp.abs(got - want))) - 2e-5 * float(
+        jnp.max(jnp.abs(want))) - 1e-7
+
+
+@pytest.mark.parametrize("t", [128, 192, 100])
+def test_kda_kernel_is_the_recurrence_and_the_plain_path(t):
+    """The Pallas forward (interpreted) at widths it takes, over whole
+    chunks, three chunks and a padded tail: the token recurrence's and
+    the plain scan's output and all five gradients (its backward IS the
+    plain path's, from the starting states the kernel wrote)."""
+    from dinov3_tpu.ops.kda import kda_chunked, kda_path, kda_recurrent
+
+    assert kda_path(128, 128, interpret=True)[0] == "kernel"
+    x = _kda_inputs(t, 2, t, 2, 128, 128)
+    kernel = lambda *a: kda_chunked(*a, interpret=True)  # noqa: E731
+    got = jax.jit(kernel)(*x)
+    assert got.shape == (2, t, 2, 128) and got.dtype == jnp.float32
+    for other in (kda_recurrent, kda_chunked):
+        np.testing.assert_allclose(got, jax.jit(other)(*x), atol=2e-6)
+
+    def grads(fn):
+        return jax.jit(jax.grad(
+            lambda *a: jnp.sum(jnp.sin(fn(*a))), argnums=(0, 1, 2, 3, 4)))(*x)
+
+    got_g = grads(kernel)
+    for other in (kda_recurrent, kda_chunked):
+        for g, w in zip(got_g, grads(other)):
+            assert _grad_gap(g, w) <= 0
+
+
+@pytest.mark.parametrize("decay", ["published", "fast", "spikes"])
+def test_kda_kernel_is_finite_and_exact_at_any_decay(decay):
+    """``test_delta_rule_is_finite_and_exact_at_any_decay``'s three
+    regimes through the kernel: its levels keep every factor at most 1
+    as the plain path's do."""
+    from dinov3_tpu.ops.kda import kda_chunked, kda_recurrent
+
+    q, k, v, g, beta = _kda_inputs(3, 1, 128, 2, 128, 128)
+    if decay == "published":
+        g = jnp.full_like(g, -1.6)
+    elif decay == "fast":
+        g = jnp.where(jnp.arange(128) < 64, -30.0, g)
+    else:
+        g = jnp.where((jnp.arange(128) % 7 == 3)[None, :, None, None], -200.0, 0.1 * g)
+    q, k = q.astype(jnp.bfloat16), k.astype(jnp.bfloat16)
+    kernel = lambda *a: kda_chunked(  # noqa: E731
+        *a, q_scale=0.25, interpret=True)
+    got = jax.jit(kernel)(q, k, v, g, beta)
+    want = 0.25 * kda_recurrent(q, k, v, g, beta)
+    loose = 100.0 if decay == "spikes" else 1.0
+    assert bool(jnp.isfinite(got).all())
+    np.testing.assert_allclose(got, want, atol=2e-6 * loose)
+
+    def grads(fn):
+        return jax.jit(jax.grad(
+            lambda *a: jnp.sum(jnp.sin(fn(q, k, *a))), argnums=(0, 1, 2)))(
+                v, g, beta)
+
+    for got_g, want_g in zip(grads(kernel),
+                             grads(lambda *a: 0.25 * kda_recurrent(*a))):
+        assert bool(jnp.isfinite(got_g).all())
+        assert float(jnp.max(jnp.abs(got_g - want_g))) <= loose * 2e-5 * float(
+            jnp.max(jnp.abs(want_g))) + 1e-7
+
+
+def test_kda_dispatch_reads_the_path_off_the_input():
+    """Nobody sets the path: off the TPU, or at widths off the lane
+    tiling, or at another chunk, the plain scan runs (no kernel in the
+    program); a test's ``interpret`` alone puts the kernel there."""
+    from dinov3_tpu.ops.kda import KERNEL_NAME, kda_chunked, kda_path
+
+    assert kda_path(16, 16)[0] == "scan" and "128" in kda_path(16, 16)[1]
+    assert kda_path(16, 128, interpret=True)[0] == "scan"
+    assert kda_path(128, 128, chunk=32, interpret=True)[0] == "scan"
+    assert kda_path(128, 128) == ("scan", "the backend is cpu, not a TPU")
+    assert kda_path(128, 128, interpret=True)[0] == "kernel"
+
+    def program(dk, **kw):
+        x = _kda_inputs(0, 1, 64, 1, dk, dk)
+        return str(jax.make_jaxpr(lambda *a: kda_chunked(*a, **kw))(*x))
+
+    assert KERNEL_NAME not in program(16, interpret=True)
+    assert KERNEL_NAME not in program(128)
+    assert KERNEL_NAME not in program(128, chunk=32, interpret=True)
+    assert KERNEL_NAME in program(128, interpret=True)
+
+
 # ---------------- (b) the causal blockwise core ----------------
 
 @pytest.mark.parametrize("n, block_q", [(64, 32), (100, 48), (96, 128)])
