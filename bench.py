@@ -480,29 +480,6 @@ def main():
     # warn_lowp_divergence: setup drift probe vs divergence_tol)
     lowp_warnings = [str(w.message) for w in _bcaught
                      if "lowp divergence axis" in str(w.message)]
-    # ... and the tuned-plan resolver fallbacks (configs/config.py
-    # resolve_bucket_mb / resolve_ring_min_seq / ...): an "auto" knob
-    # that could not use the committed TUNED_* plan says so in the
-    # record, next to the provenance block below
-    tuned_warnings = [str(w.message) for w in _bcaught
-                      if "tuned plan" in str(w.message)]
-    # tuned-plan provenance: which collective-schedule knob values the
-    # benched program actually ran with and where each came from
-    # (tuned artifact / explicit config / hand-set fallback), keyed by
-    # the live fingerprint the staleness guardrail checks
-    from dinov3_tpu.configs.config import (
-        live_tuned_fingerprint,
-        warn_tuned_plan_stale,
-    )
-    from dinov3_tpu.tuning import tuned_plan_provenance
-
-    _live_fp = live_tuned_fingerprint(cfg)
-    tuned_plan = tuned_plan_provenance(cfg, live=_live_fp)
-    with _bwarnings.catch_warnings(record=True) as _tcaught:
-        _bwarnings.simplefilter("always")
-        warn_tuned_plan_stale(cfg, live=_live_fp)
-    tuned_warnings += [str(w.message) for w in _tcaught
-                       if "tuned plan" in str(w.message)]
     dbatch = put_batch(batch, setup.batch_shardings)
     rng = jax.random.key(0)
     state = setup.state
@@ -692,11 +669,6 @@ def main():
         # process-level TeacherServer dedup/cache counters, and — when
         # the census ran — the distill_fanout scope counts
         "distill": _distill_summary(setup, coll_census),
-        # tuned-plan provenance (tuning/plan.py): artifact path +
-        # fingerprint, and per schedule knob the configured value, the
-        # resolved value, and its source (tuned / explicit / fallback)
-        # — a benched number is always traceable to its exact schedule
-        "tuned_plan": tuned_plan,
     }
     if anatomy_summary is not None:
         # measured step anatomy next to the static censuses: per-scope
@@ -723,8 +695,6 @@ def main():
         rec["accum_tiling_warning"] = "; ".join(accum_warnings)
     if seq_pad_warnings:
         rec["seq_padding_warning"] = "; ".join(seq_pad_warnings)
-    if tuned_warnings:
-        rec["tuned_plan_warning"] = "; ".join(tuned_warnings)
     if degraded:
         # distinct reasons can fire for the global- and local-crop
         # batches of the same program — keep them all

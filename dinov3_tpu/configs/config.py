@@ -214,11 +214,6 @@ def load_config(
     # on: a tolerance outside (0, 1] makes the measured-overlap
     # guardrail either always-on noise or dead code
     warn_exposed_comm(cfg)
-    # ... and over the committed tuned-schedule plan: when a schedule
-    # knob is on "auto", the artifact's fingerprint must at least be
-    # well-formed (the live comparison fires from bench/setup paths,
-    # which know the device count — warn_tuned_plan_stale dual mode)
-    warn_tuned_plan_stale(cfg)
     # ... and over the elastic-resume knobs: a typo'd resume-topology
     # policy or an unusable re-padding tolerance must fail at load, not
     # at the preemption the elastic engine exists to survive (the live
@@ -547,7 +542,7 @@ def warn_reshard_padding(
     threshold: float | None = None, stacklevel: int = 2,
 ) -> list[str]:
     """Guardrail on elastic topology transitions — the axis-labelled,
-    dual-mode style of ``warn_tuned_plan_stale``.
+    dual-mode style of ``warn_exposed_comm``.
 
     **Config mode** (``load_config``, only ``cfg`` given): validates the
     elastic-resume knobs themselves — ``train.resume_topology`` must
@@ -622,7 +617,7 @@ def bucketed_collectives_wished(cfg: ConfigNode) -> bool:
     ``optim.bucketed_collectives``: auto (default) = on — the coalesced
     schedule is the default whenever the setup-time conditions hold.
     The mesh picks the arm: flat (non-zero3) meshes bucket the sharded
-    UPDATE phase (one reduce-scatter + one all-gather per ~bucket_mb
+    UPDATE phase (one reduce-scatter + one all-gather per ~128 MiB
     flat bucket, train/fused_update.py make_bucketed_update; needs the
     fused sharded update); zero3 meshes select the UNIFIED arm — the
     non-block subtree gathers of the forward and their transposed grad
@@ -750,8 +745,8 @@ def warn_bucket_padding(
                     f"than 1/8 of the median bucket ({median} bytes) — "
                     f"its collective is back in the latency-bound "
                     f"small-message regime the bucketed engine exists "
-                    f"to avoid. Retune optim.bucket_mb (target "
-                    f"{target_bytes} bytes), or set "
+                    f"to avoid (target {target_bytes} bytes, "
+                    f"train/fused_update.py BUCKET_TARGET_BYTES). Set "
                     f"optim.bucketed_collectives=false."
                 )
     for m in msgs:
@@ -1189,259 +1184,6 @@ def resolve_flash_min_seq(value: Any, artifact: Path | None = None) -> int:
         )
         return 0
     return FLASH_NEVER_SEQ if n is None else int(n)
-
-
-# ---------------------------------------------------------------------
-# tuned collective-schedule plan (the measure->tune loop): "auto" on
-# the schedule knobs resolves against this committed artifact, written
-# by ``python scripts/tune_collectives.py TUNED_r20.json`` — the
-# anatomy-ledger-driven search over optim.bucket_mb, the hierarchical
-# staging order, the stream prefetch depth, and kernels.ring_min_seq
-# (objective: measured step wall + measured exposed collective ms,
-# telemetry/anatomy.py tuning_summary). The artifact-pin test is
-# tests/test_tuning.py; the generalized form of the CROSSOVER_r19
-# flash_min_seq pattern above, from one knob to the whole schedule.
-TUNED_ARTIFACT = Path(__file__).parents[2] / "TUNED_r20.json"
-
-# Hand-set oracle values, used verbatim when a knob is set explicitly
-# and as the loud-warning fallback when "auto" cannot resolve (missing/
-# unreadable artifact, or a fingerprint mismatch against the live
-# setup). These are the exact pre-tuner constants, so every config the
-# plan was NOT tuned for keeps its historical schedule bit for bit.
-TUNED_FALLBACKS: dict = {
-    "bucket_mb": 128,          # make_bucket_plan / make_zero3_bucket_plan
-    "ring_min_seq": 1024,      # ops/attention.py RING_MIN_SEQ floor
-    "staging_order": "inter_intra",  # hier AG inter-first / RS intra-first
-    "stream_prefetch": 1,      # the classic double buffer
-}
-
-
-def _tuned_auto_knobs(cfg: ConfigNode) -> list:
-    """The tuned-schedule knobs this config leaves on "auto" (i.e. the
-    knobs whose values actually come from the TUNED_* artifact)."""
-    optim = cfg.get("optim") or {}
-    kernels = cfg.get("kernels") or {}
-    raw = {
-        "bucket_mb": optim.get("bucket_mb", "auto"),
-        "staging_order": optim.get("staging_order", "auto"),
-        "stream_prefetch": optim.get("stream_prefetch", "auto"),
-        "ring_min_seq": kernels.get("ring_min_seq", "auto"),
-    }
-    return [k for k, v in raw.items()
-            if v is None or v == "" or v == "auto"]
-
-
-def live_tuned_fingerprint(
-    cfg: ConfigNode, n_devices: int | None = None,
-) -> dict:
-    """The live setup's fingerprint, in the TUNED_* artifact's shape:
-    arch, device count, the update-shard (data-axis product) size the
-    schedule knobs actually depend on, and the jax version. Imports
-    jax lazily — call from setup/bench paths, not bare config code."""
-    import jax
-
-    if n_devices is None:
-        n_devices = jax.device_count()
-    return {
-        "arch": str(cfg.student.arch),
-        "device_count": int(n_devices),
-        "update_shard_size": int(data_parallel_world(cfg, n_devices)),
-        "jax": jax.__version__,
-    }
-
-
-def tuned_fingerprint_mismatches(fp: dict, live: dict) -> list:
-    """Field-labelled mismatch descriptions between an artifact
-    fingerprint and a live one (empty = the plan applies). jax is
-    compared at major.minor — patch releases don't re-cost a
-    schedule."""
-    bad = []
-    for key in ("arch", "device_count", "update_shard_size"):
-        if key in live and fp.get(key) != live[key]:
-            bad.append(f"{key}: live {live[key]!r} != tuned "
-                       f"{fp.get(key)!r}")
-
-    def _mm(v):
-        return ".".join(str(v).split(".")[:2])
-
-    if live.get("jax") and fp.get("jax") and \
-            _mm(live["jax"]) != _mm(fp["jax"]):
-        bad.append(f"jax: live {_mm(live['jax'])} != tuned "
-                   f"{_mm(fp['jax'])}")
-    return bad
-
-
-def warn_tuned_plan_stale(
-    cfg: ConfigNode, live: dict | None = None,
-    artifact: Path | None = None, stacklevel: int = 2,
-) -> str | None:
-    """Warn when the committed TUNED_* plan's fingerprint (arch, mesh
-    update-shard size, device count, jax version) mismatches the live
-    setup — the axis-labelled guardrail style of ``warn_exposed_comm``,
-    dual-mode like it:
-
-    Without ``live`` (the ``load_config`` call): validates only that
-    the artifact's fingerprint block is well-formed when some tuned
-    knob is on "auto" — no device/backend query at config-load time.
-    With ``live`` (a ``live_tuned_fingerprint`` dict, from bench.py or
-    a setup path): compares field for field and names every mismatched
-    axis, so the warning says exactly which assumption went stale.
-    Captured into bench records as ``tuned_plan_warning``. Returns the
-    message or None (silent when every tuned knob is hand-set — the
-    plan is then unused, staleness is moot, and the fallback values
-    the resolvers would pick are the hand-set oracle anyway)."""
-    autos = _tuned_auto_knobs(cfg)
-    if not autos:
-        return None
-    path = TUNED_ARTIFACT if artifact is None else artifact
-    try:
-        import json
-
-        with open(path) as f:
-            fp = (json.load(f).get("fingerprint") or {})
-    except Exception:  # noqa: BLE001 - the resolvers warn on unreadable
-        return None
-    required = {"arch", "device_count", "update_shard_size", "jax"}
-    if live is None:
-        missing = sorted(required - set(fp))
-        if not missing:
-            return None
-        msg = (
-            f"tuned plan [fingerprint]: {path} has no "
-            f"{'/'.join(missing)} in its fingerprint — staleness "
-            f"against the live setup cannot be checked, and the auto "
-            f"knobs ({', '.join(autos)}) would silently apply a plan "
-            f"tuned for an unknown setup. Re-derive with "
-            f"scripts/tune_collectives.py."
-        )
-    else:
-        bad = tuned_fingerprint_mismatches(fp, live)
-        if not bad:
-            return None
-        fallbacks = ", ".join(
-            f"{k}={TUNED_FALLBACKS[k]!r}" for k in autos)
-        msg = (
-            f"tuned plan [{'; '.join(bad)}]: {path} was tuned for a "
-            f"different setup — the auto schedule knobs "
-            f"({', '.join(autos)}) fall back to their hand-set oracle "
-            f"values ({fallbacks}). Re-derive the plan on this setup "
-            f"with scripts/tune_collectives.py, or hand-set the knobs "
-            f"to silence this."
-        )
-    import warnings
-
-    warnings.warn(msg, stacklevel=stacklevel + 1)
-    return msg
-
-
-def _resolve_tuned(
-    knob: str, value: Any, cast, artifact: Path | None = None,
-    live: dict | None = None, stacklevel: int = 3,
-):
-    """Shared resolver core for the tuned schedule knobs, the
-    ``resolve_flash_min_seq`` contract generalized: explicit values
-    pass through ``cast`` untouched (the hand-set oracle), "auto"
-    reads ``knobs.<knob>.chosen`` from the committed TUNED_* artifact
-    — bitwise-deterministic, the chosen value is itself re-derivable
-    from the artifact's measurement trail (tests/test_tuning.py). A
-    missing/unreadable artifact, or (when a ``live`` fingerprint is
-    supplied) a fingerprint mismatch, warns loudly and falls back to
-    the hand-set ``TUNED_FALLBACKS`` value so untuned setups keep the
-    historical schedule."""
-    import warnings
-
-    fallback = TUNED_FALLBACKS[knob]
-    if value is None or value == "":
-        value = "auto"
-    if not isinstance(value, str) or value != "auto":
-        return cast(value)
-    path = TUNED_ARTIFACT if artifact is None else artifact
-    try:
-        import json
-
-        with open(path) as f:
-            doc = json.load(f)
-        chosen = doc["knobs"][knob]["chosen"]
-        fp = doc.get("fingerprint") or {}
-    except Exception as e:  # noqa: BLE001 - degrade to the hand-set value
-        warnings.warn(
-            f"{knob}=auto but the tuned plan artifact {path} is "
-            f"unreadable ({e}); falling back to the hand-set default "
-            f"{fallback!r}. Re-derive it with "
-            f"scripts/tune_collectives.py.",
-            stacklevel=stacklevel,
-        )
-        return cast(fallback)
-    if live is not None and tuned_fingerprint_mismatches(fp, live):
-        bad = tuned_fingerprint_mismatches(fp, live)
-        warnings.warn(
-            f"{knob}=auto but the tuned plan {path} was tuned for a "
-            f"different setup [{'; '.join(bad)}]; falling back to the "
-            f"hand-set default {fallback!r}. Re-derive with "
-            f"scripts/tune_collectives.py on this setup.",
-            stacklevel=stacklevel,
-        )
-        return cast(fallback)
-    return cast(chosen)
-
-
-def resolve_bucket_mb(
-    value: Any, artifact: Path | None = None, live: dict | None = None,
-) -> int:
-    """Resolve ``optim.bucket_mb`` (MiB target of the greedy
-    leaf->bucket packing, train/fused_update.py make_bucket_plan /
-    make_zero3_bucket_plan) — int pass-through, "auto" from the tuned
-    plan, fallback 128 (the hand-set oracle) on unreadable/stale."""
-    return _resolve_tuned("bucket_mb", value, int, artifact, live)
-
-
-def resolve_ring_min_seq(
-    value: Any, artifact: Path | None = None, live: dict | None = None,
-) -> int:
-    """Resolve ``kernels.ring_min_seq`` (ring-dispatch floor in tokens
-    under parallel.seq > 1, ops/attention.py) — int pass-through
-    (0 = the ops-layer RING_MIN_SEQ fallback, the flash_min_seq
-    sentinel convention), "auto" from the tuned plan, fallback 1024
-    on unreadable/stale."""
-    return _resolve_tuned("ring_min_seq", value, int, artifact, live)
-
-
-def resolve_staging_order(
-    value: Any, artifact: Path | None = None, live: dict | None = None,
-) -> str:
-    """Resolve ``optim.staging_order`` ("<ag>_<rs>" tier-release order
-    of the hierarchy-aware bucket gathers, parallel/sharding.py
-    ``split_staging_order``) — explicit orders pass through validated,
-    "auto" from the tuned plan, fallback "inter_intra" (the hand-set
-    bandwidth-model order) on unreadable/stale."""
-    def cast(v):
-        v = str(v)
-        # validate lazily against the schedule layer's canonical set
-        # (parallel/sharding.py imports jax; keep config import-light)
-        from dinov3_tpu.parallel.sharding import split_staging_order
-
-        split_staging_order(v)
-        return v
-
-    return _resolve_tuned("staging_order", value, cast, artifact, live)
-
-
-def resolve_stream_prefetch(
-    value: Any, artifact: Path | None = None, live: dict | None = None,
-) -> int:
-    """Resolve ``optim.stream_prefetch`` (integer gather-lookahead
-    depth of the explicit weight-stream scans, models/streaming.py
-    ``prefetch_depth``: 0 = at-use, 1 = double buffer, d >= 2 =
-    deeper pipeline) — int pass-through, "auto" from the tuned plan,
-    fallback 1 (the classic double buffer) on unreadable/stale."""
-    def cast(v):
-        d = int(v)
-        if d < 0:
-            raise ValueError(
-                f"optim.stream_prefetch={v!r}: depth must be >= 0")
-        return d
-
-    return _resolve_tuned("stream_prefetch", value, cast, artifact, live)
 
 
 def warn_seq_padding(

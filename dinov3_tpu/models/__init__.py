@@ -88,18 +88,10 @@ def backbone_kwargs_from_cfg(cfg: ConfigNode, *, teacher: bool = False) -> dict:
     kw["attn_impl"] = kernels.get("flash_attention", "auto")
     kw["flash_block_q"] = int(kernels.get("flash_block_q", 512) or 512)
     kw["flash_block_kv"] = int(kernels.get("flash_block_kv", 512) or 512)
-    from dinov3_tpu.configs.config import (
-        live_tuned_fingerprint,
-        resolve_flash_min_seq,
-        resolve_ring_min_seq,
-    )
+    from dinov3_tpu.configs.config import resolve_flash_min_seq
 
     kw["flash_min_seq"] = resolve_flash_min_seq(
         kernels.get("flash_min_seq", "auto")
-    )
-    kw["ring_min_seq"] = resolve_ring_min_seq(
-        kernels.get("ring_min_seq", 0),
-        live=live_tuned_fingerprint(cfg),
     )
     parallel = cfg.get("parallel") or {}
     kw["seq_parallel"] = int(parallel.get("seq", 1) or 1) > 1

@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 
 from dinov3_tpu.ops.block import stream_castable_path
+from dinov3_tpu.parallel.sharding import STAGING_ORDER
 
 
 def cast_stream_leaves(stack_params: Any, dtype) -> Any:
@@ -63,15 +64,18 @@ def cast_stream_leaves(stack_params: Any, dtype) -> Any:
     return jtu.tree_map_with_path(leaf, stack_params)
 
 
+# Gather lookahead of both stream scans: the classic double buffer
+# (gather i+1 under block i's compute). Two gathered weight sets live at
+# once; each extra depth keeps one more block's weights on the device.
+STREAM_PREFETCH = 1
+
+
 def prefetch_depth(prefetch: bool | int) -> int:
-    """Normalize the stream-prefetch knob to an integer lookahead
+    """Normalize a ``prefetch`` argument to an integer lookahead
     depth: ``False``/0 = gather at use, ``True``/1 = the classic
     double buffer (gather i+1 under block i's compute), ``d >= 2`` = a
     ``d``-deep gather pipeline (the carry holds ``d`` gathered sets —
-    liveness grows one block's weights per extra depth). Booleans map
-    to 0/1 so every pre-tuner call site keeps its exact schedule; the
-    integer form is the tuner's candidate axis
-    (``optim.stream_prefetch``, resolve_stream_prefetch)."""
+    liveness grows one block's weights per extra depth)."""
     depth = int(prefetch)
     if depth < 0:
         raise ValueError(f"prefetch depth must be >= 0, got {depth}")
@@ -84,7 +88,7 @@ def streamed_block_scan(
     x: jnp.ndarray,
     n_blocks: int,
     mesh=None,
-    prefetch: bool | int = True,
+    prefetch: bool | int = STREAM_PREFETCH,
 ):
     """Run ``n_blocks`` blocks over ``x`` with an explicit
     ``prefetch``-deep buffered weight stream.
@@ -208,10 +212,10 @@ def bucketed_stream_scan(
     bucket_shards: jnp.ndarray,
     x: jnp.ndarray,
     mesh=None,
-    prefetch: bool | int = True,
+    prefetch: bool | int = STREAM_PREFETCH,
     consume_fn: Callable | None = None,
     hierarchical: bool = False,
-    staging_order: str = "inter_intra",
+    staging_order: str = STAGING_ORDER,
 ):
     """The BUCKETED forward weight-gather schedule, written explicitly —
     ``streamed_block_scan``'s double-buffer convention lifted from

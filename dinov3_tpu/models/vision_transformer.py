@@ -108,7 +108,6 @@ class DinoVisionTransformer(nn.Module):
     flash_block_q: int = 512   # kernels.flash_block_q/kv caps
     flash_block_kv: int = 512
     flash_min_seq: int = 0     # kernels.flash_min_seq; 0 = ops default
-    ring_min_seq: int = 0      # kernels.ring_min_seq; 0 = ops default
     seq_parallel: bool = False
     scan_layers: bool = False
     pipeline_stages: int = 1       # >1: GPipe pipeline over the pipe axis
@@ -235,7 +234,6 @@ class DinoVisionTransformer(nn.Module):
             flash_block_q=self.flash_block_q,
             flash_block_kv=self.flash_block_kv,
             flash_min_seq=self.flash_min_seq,
-            ring_min_seq=self.ring_min_seq,
             seq_parallel=self.seq_parallel, fp8=self.fp8,
             lowp_arm=self.lowp_arm,
             moe_num_experts=self.moe_num_experts, moe_top_k=self.moe_top_k,

@@ -212,8 +212,8 @@ FLASH_MIN_SEQ = 2048
 # one dp x seq mesh the 1029-token 512px globals ring while the locals
 # run dense with seq-replicated activations — the crossover is a memory
 # argument (O(N/s) per device vs O(N)), unlike flash_min_seq's measured
-# time crossover. Config knob: ``kernels.ring_min_seq`` (0 = this
-# fallback); in-step ring tests override it to 1.
+# time crossover. ``SelfAttention`` reads it when a pass is traced, so
+# the in-step ring tests patch it to 1.
 RING_MIN_SEQ = 1024
 
 
@@ -270,7 +270,6 @@ class SelfAttention(nn.Module):
     flash_block_q: int = 512   # kernels.flash_block_q/kv caps
     flash_block_kv: int = 512
     flash_min_seq: int = 0     # kernels.flash_min_seq; 0 = FLASH_MIN_SEQ
-    ring_min_seq: int = 0      # kernels.ring_min_seq; 0 = RING_MIN_SEQ
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     reduce_dtype: Any = jnp.float32
@@ -351,7 +350,7 @@ class SelfAttention(nn.Module):
             out = xla_attention(q, k, v, self.reduce_dtype, causal=True,
                                 probs_dtype=self.probs_dtype)
         if out is None and self.seq_parallel \
-                and N >= (self.ring_min_seq or RING_MIN_SEQ):
+                and N >= RING_MIN_SEQ:
             # per-pass dispatch: only passes long enough to pay for the
             # rotation ring (RING_MIN_SEQ) — under one dp x seq mesh the
             # high-res globals ring while short local crops run dense

@@ -49,7 +49,6 @@ class SelfAttentionBlock(nn.Module):
     flash_block_q: int = 512
     flash_block_kv: int = 512
     flash_min_seq: int = 0
-    ring_min_seq: int = 0
     # train.low_precision.arm: fp8/int8 quantized matmuls over the
     # castable kernels (ops/lowp.py); "bf16" = the unchanged path
     lowp_arm: str = "bf16"
@@ -95,8 +94,7 @@ class SelfAttentionBlock(nn.Module):
             flash_block_q=self.flash_block_q,
             flash_block_kv=self.flash_block_kv,
             flash_min_seq=self.flash_min_seq,
-            lowp_arm=self.lowp_arm,
-            ring_min_seq=self.ring_min_seq, dtype=self.dtype,
+            lowp_arm=self.lowp_arm, dtype=self.dtype,
             param_dtype=self.param_dtype, reduce_dtype=self.reduce_dtype,
             probs_dtype=self.probs_dtype,
             name="attn",

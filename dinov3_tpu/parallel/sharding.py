@@ -503,16 +503,20 @@ STAGING_ORDERS = ("inter_intra", "intra_inter", "inter_inter",
                   "intra_intra")
 
 
+# The order every staged bucket gather runs in, from the bandwidth
+# model: AG moves the small 1/dp shards over the slow inter links
+# first, RS shrinks the cotangent n_intra-fold on the fast links before
+# it touches a slow one (PAPERS.md 2408.13356).
+STAGING_ORDER = "inter_intra"
+
+
 def split_staging_order(order: str) -> tuple[str, str]:
     """``"<ag>_<rs>"`` -> ``(ag_first, rs_first)``, each "inter" or
     "intra" naming the tier the forward all-gather (resp. backward
-    reduce-scatter) releases FIRST. "inter_intra" is the hand-set
-    bandwidth-model default: AG moves the small 1/dp shards over the
-    slow inter links first, RS shrinks the cotangent n_intra-fold on
-    the fast links before it touches a slow one (PAPERS.md
-    2408.13356). The other three orders are the tuner's A/B candidates
-    (scripts/tune_collectives.py) — pure wire-schedule permutations of
-    the same data movement."""
+    reduce-scatter) releases FIRST. The orders other than
+    ``STAGING_ORDER`` are pure wire-schedule permutations of the same
+    data movement, kept as arguments so the tests can show that all
+    four give the same numbers."""
     if order not in STAGING_ORDERS:
         raise ValueError(
             f"staging order {order!r}: expected one of {STAGING_ORDERS}")
@@ -521,7 +525,7 @@ def split_staging_order(order: str) -> tuple[str, str]:
 
 
 def hier_gather_bucket(
-    x: jax.Array, mesh: Mesh, staging_order: str = "inter_intra",
+    x: jax.Array, mesh: Mesh, staging_order: str = STAGING_ORDER,
 ) -> jax.Array:
     """Replicate one flat gather bucket with the hierarchy-aware
     two-stage schedule, differentiable with direction-true scope names.
@@ -547,8 +551,8 @@ def hier_gather_bucket(
     (``bucket_rs_inter``) — and GSPMD materializes the partial-sum
     reductions as reduce-scatters at exactly these constraint points.
     NOTE the RS order permutes the floating-point partial-sum tree
-    across tiers, so A/B candidates match to reduction tolerance, not
-    bitwise (tests/test_tuning.py pins both properties).
+    across tiers, so the four orders match to reduction tolerance, not
+    bitwise (tests/test_unified_buckets.py pins both properties).
     """
     inter, intra = hierarchy_axes(mesh)
     if not inter and not intra:

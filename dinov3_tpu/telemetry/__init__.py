@@ -56,7 +56,6 @@ from dinov3_tpu.telemetry.anatomy import (
     fleet_report,
     ledger_summary,
     load_span_streams,
-    tuning_summary,
 )
 from dinov3_tpu.telemetry.hist import LogHistogram, quantile_nearest_rank
 from dinov3_tpu.telemetry.host_sync import blocking_fetch, host_sync_stats
@@ -103,5 +102,4 @@ __all__ = [
     "Trace", "TraceEvent", "find_trace_file", "load_trace",
     "anatomy_ledger", "build_op_index", "categorize", "emit_step_anatomy",
     "fleet_report", "ledger_summary", "load_span_streams",
-    "tuning_summary",
 ]

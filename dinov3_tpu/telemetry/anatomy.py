@@ -513,47 +513,6 @@ def ledger_summary(ledger: dict) -> dict:
     }
 
 
-def tuning_summary(summary: dict) -> dict:
-    """Tuner-facing objective view of one ``ledger_summary`` — the
-    single number the collective auto-tuner (dinov3_tpu/tuning/,
-    scripts/tune_collectives.py) minimizes per candidate, plus the
-    evidence columns its TUNED_* trail records.
-
-    ``objective_ms = step_wall_ms.mean + exposed_comm_ms_per_step``:
-    the measured step plus the measured NON-overlapped collective time.
-    Exposed comm already spends wall time inside the step, so the sum
-    double-weights exactly the failure the tuner exists to remove — two
-    candidates with equal steps but different overlap schedules rank by
-    how much of their comm they hide, while a candidate that "hides"
-    comm by inflating compute pays for it in the wall term. On the CPU
-    harness overlap fractions are structural lower bounds
-    (docs/OBSERVABILITY.md), so exposed_ms is a conservative ceiling
-    and the ranking is bandwidth-pessimistic — the honest direction for
-    a committed plan."""
-    wall = float((summary.get("step_wall_ms") or {}).get("mean", 0.0)
-                 or 0.0)
-    exposed = float(summary.get("exposed_comm_ms_per_step", 0.0) or 0.0)
-    scopes = sorted(
-        (summary.get("collectives") or {}).items(),
-        key=lambda kv: -float(kv[1].get("exposed_ms_per_step", 0.0)),
-    )
-    return {
-        "objective_ms": wall + exposed,
-        "step_wall_ms_mean": wall,
-        "exposed_comm_ms_per_step": exposed,
-        "exposed_comm_frac": float(
-            summary.get("exposed_comm_frac", 0.0) or 0.0),
-        "top_exposed_scopes": [
-            {"scope": name,
-             "exposed_ms_per_step": float(
-                 ent.get("exposed_ms_per_step", 0.0)),
-             "overlap_frac": float(ent.get("overlap_frac", 0.0))}
-            for name, ent in scopes[:3]
-            if float(ent.get("exposed_ms_per_step", 0.0)) > 0.0
-        ],
-    }
-
-
 # ---------------------------------------------------------------------
 # fleet report over the span JSONL streams
 # ---------------------------------------------------------------------

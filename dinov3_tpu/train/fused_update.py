@@ -83,6 +83,7 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from dinov3_tpu.parallel.sharding import STAGING_ORDER
 from dinov3_tpu.train.optimizer import (
     ScheduledAdamWState,
     per_submodel_norms,
@@ -938,11 +939,19 @@ class BucketPlan:
         return out
 
 
+# Payload target of one bucket, for the update-phase plan and the zero3
+# gather plan alike: large enough that a bucket's collective sits on the
+# flat part of the launch-latency curve (the >= 64 MB bin of the census
+# size histogram), small enough that a ViT-L submodel still has >= 2
+# buckets for the overlap schedule to overlap.
+BUCKET_TARGET_BYTES = 128 * 2 ** 20
+
+
 def make_bucket_plan(
     student: Any,
     dp: int,
     is_last_layer: Any = None,
-    target_bytes: int = 128 * 2 ** 20,
+    target_bytes: int = BUCKET_TARGET_BYTES,
 ) -> BucketPlan:
     """Build the leaf -> bucket assignment (see ``BucketPlan``).
 
@@ -1589,7 +1598,7 @@ def zero3_streamed_path(path) -> bool:
 def make_zero3_bucket_plan(
     tree: Any,
     mesh,
-    target_bytes: int = 128 * 2 ** 20,
+    target_bytes: int = BUCKET_TARGET_BYTES,
 ) -> Zero3GatherPlan:
     """Build the non-block leaf -> gather bucket assignment (see
     ``Zero3GatherPlan``). ``tree``: a zero3-sharded param tree (abstract
@@ -1702,9 +1711,9 @@ def _zero3_member_unrows(rows, member: Zero3BucketMember):
 
 
 def gather_zero3_bucketed(tree: Any, mesh,
-                          target_bytes: int = 128 * 2 ** 20,
+                          target_bytes: int = BUCKET_TARGET_BYTES,
                           plan: Zero3GatherPlan | None = None,
-                          staging_order: str = "inter_intra") -> Any:
+                          staging_order: str = STAGING_ORDER) -> Any:
     """The unified engine's replacement for the per-leaf non-block
     zero3 gather: pack the shardable non-block leaves into
     [n_inter, n_intra, cols] buckets (scope ``bucket_pack`` — pure
@@ -1715,11 +1724,7 @@ def gather_zero3_bucketed(tree: Any, mesh,
     ``bucket_rs_intra``/``bucket_rs_inter``), and unpack to model
     shapes (scope ``bucket_unpack``). Streamed (block-stack) leaves
     pass through untouched; leaves with no dividing dim gather per leaf
-    under ``zero3_gather`` exactly as the oracle walk does.
-
-    ``target_bytes`` and ``staging_order`` are the tuned-schedule
-    parameters (resolve_bucket_mb / resolve_staging_order over the
-    committed TUNED_* plan; defaults = the hand-set oracle values)."""
+    under ``zero3_gather`` exactly as the oracle walk does."""
     import jax.tree_util as jtu
 
     from dinov3_tpu.parallel.sharding import (
@@ -1769,7 +1774,7 @@ def gather_zero3_bucketed(tree: Any, mesh,
 
 def make_zero3_gather_schedule(
     plan: Zero3GatherPlan, mesh, bucketed: bool = True,
-    staging_order: str = "inter_intra",
+    staging_order: str = STAGING_ORDER,
 ) -> Callable:
     """The unified gather phase with EXPLICIT collectives — the
     ``make_bucketed_update_schedule`` convention applied to the zero3
@@ -1800,10 +1805,9 @@ def make_zero3_gather_schedule(
 
     ``staging_order`` ("<ag>_<rs>", parallel/sharding.py
     ``split_staging_order``) picks which tier each direction releases
-    first — the tuner's A/B axis (scripts/tune_collectives.py). The
-    gathered values are bitwise order-invariant (pure movement); the
-    backward's partial-sum tree permutes across tiers, so RS-order
-    candidates match to reduction tolerance.
+    first. The gathered values are bitwise order-invariant (pure
+    movement); the backward's partial-sum tree permutes across tiers,
+    so the RS orders match to reduction tolerance.
     """
     import jax.tree_util as jtu
 

@@ -329,7 +329,7 @@ def _build_train_setup(
     # Two arms share the flag:
     # * flat meshes (no zero3): when the sharded update engages, its
     #   per-leaf schedule (one RS + two AGs per leaf) coalesces into one
-    #   RS/AG per ~bucket_mb flat bucket (make_bucketed_update);
+    #   RS/AG per ~128 MiB flat bucket (make_bucketed_update);
     # * zero3 meshes: the UNIFIED arm — the non-block subtree gathers of
     #   the forward (and their transposed grad reduce-scatters) coalesce
     #   into hierarchy-aware gather buckets (gather_zero3_bucketed;
@@ -379,9 +379,7 @@ def _build_train_setup(
         from dinov3_tpu.train.fused_update import make_zero3_bucket_plan
 
         zero3_bucket_plan = make_zero3_bucket_plan(
-            abstract_params["student"], mesh,
-            target_bytes=meta.zero3_bucket_bytes,
-        )
+            abstract_params["student"], mesh)
     bucket_plan = None
     if fused_wished:
         from dinov3_tpu.train.fused_update import (
@@ -407,20 +405,11 @@ def _build_train_setup(
                 patch_embed_lr_mult=cfg.optim.patch_embed_lr_mult,
                 dino_head_wd_multiplier=cfg.optim.dino_head_wd_multiplier,
             )
-            from dinov3_tpu.configs.config import (
-                live_tuned_fingerprint,
-                resolve_bucket_mb,
-            )
-
-            target_bytes = resolve_bucket_mb(
-                (cfg.get("optim") or {}).get("bucket_mb", "auto"),
-                live=live_tuned_fingerprint(cfg),
-            ) * 2 ** 20
             bucket_plan = make_bucket_plan(
                 abstract_params["student"], dp, is_last_layer=is_last,
-                target_bytes=target_bytes,
             )
-            warn_bucket_padding(bucket_plan.padding_stats(), target_bytes)
+            warn_bucket_padding(
+                bucket_plan.padding_stats(), bucket_plan.target_bytes)
             fused = build_bucketed_update(
                 cfg, abstract_params["student"], schedules, mesh,
                 bucket_plan, ema=meta.ema_teacher,
@@ -607,23 +596,13 @@ def _build_train_setup(
     # token count (CLS + registers + patches) pads to a multiple of the
     # seq axis inside ring attention; warn per crop size when that
     # padding wastes > 2% of every attention pass. Only passes the
-    # per-pass dispatch actually rings (N >= kernels.ring_min_seq) are
-    # checked — short local crops run dense with no seq padding.
+    # per-pass dispatch actually rings (N >= RING_MIN_SEQ) are checked
+    # — short local crops run dense with no seq padding.
     seq_axis = int(mesh.shape.get("seq", 1))
     if seq_axis > 1 and not str(cfg.student.arch).startswith("convnext"):
         from dinov3_tpu.configs.config import warn_seq_padding
-        from dinov3_tpu.ops.attention import RING_MIN_SEQ
+        from dinov3_tpu.ops import attention
 
-        from dinov3_tpu.configs.config import (
-            live_tuned_fingerprint,
-            resolve_ring_min_seq,
-        )
-
-        kernels = cfg.get("kernels") or {}
-        ring_min = resolve_ring_min_seq(
-            kernels.get("ring_min_seq", 0),
-            live=live_tuned_fingerprint(cfg),
-        ) or RING_MIN_SEQ
         n_prefix = 1 + int(cfg.student.get("n_storage_tokens", 0) or 0)
         patch = int(cfg.student.patch_size)
         crops = cfg.get("crops") or {}
@@ -637,7 +616,7 @@ def _build_train_setup(
             if px <= 0 or px % patch:
                 continue
             n = n_prefix + (px // patch) ** 2
-            if n >= ring_min:
+            if n >= attention.RING_MIN_SEQ:
                 warn_seq_padding(
                     n, seq_axis, axis=f"{label} ({px}px)", stacklevel=2)
     raw_step = make_train_step(

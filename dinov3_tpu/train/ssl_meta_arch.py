@@ -274,19 +274,6 @@ class SSLMetaArch:
         self.zero3_buckets = (
             self.zero3_gather and bucketed_collectives_wished(cfg)
         )
-        from dinov3_tpu.configs.config import (
-            live_tuned_fingerprint,
-            resolve_bucket_mb,
-            resolve_staging_order,
-        )
-
-        _live = live_tuned_fingerprint(cfg)
-        self.zero3_bucket_bytes = resolve_bucket_mb(
-            (cfg.get("optim") or {}).get("bucket_mb", "auto"),
-            live=_live) * 2 ** 20
-        self.zero3_staging_order = resolve_staging_order(
-            (cfg.get("optim") or {}).get("staging_order", "auto"),
-            live=_live)
         self.gram_enabled = bool(cfg.gram.use_loss)
         self.gram_uses_ema_teacher = bool(cfg.gram.ema_teacher)
         # per-iteration loss-weight ramps (host numpy; moved in-graph by the
@@ -1091,9 +1078,7 @@ class SSLMetaArch:
         if self.zero3_buckets and update_shard_size(mesh) > 1:
             from dinov3_tpu.train.fused_update import gather_zero3_bucketed
 
-            return gather_zero3_bucketed(
-                tree, mesh, target_bytes=self.zero3_bucket_bytes,
-                staging_order=self.zero3_staging_order)
+            return gather_zero3_bucketed(tree, mesh)
 
         def walk(sub):
             if not isinstance(sub, dict):

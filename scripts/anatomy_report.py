@@ -156,13 +156,11 @@ def _materialize(tree, shardings):
         tree, shardings)
 
 
-def update_phase_arms(cfg, only: tuple | None = None) -> dict:
+def update_phase_arms(cfg) -> dict:
     """The three update-phase arms (replicated / flat / bucketed) over
     the real ViT-L tree, executed — same program construction as
     scripts/cost_buckets.py update_phase_twins, plus the replicated
-    fused-update arm. ``only`` restricts to a subset of arm names (the
-    tuner's per-candidate sweeps re-measure ONE arm per call,
-    scripts/tune_collectives.py)."""
+    fused-update arm."""
     import flax.linen as nn
     import jax
     import jax.numpy as jnp
@@ -203,12 +201,7 @@ def update_phase_arms(cfg, only: tuple | None = None) -> dict:
         patch_embed_lr_mult=cfg.optim.patch_embed_lr_mult,
         dino_head_wd_multiplier=cfg.optim.dino_head_wd_multiplier,
     )
-    from dinov3_tpu.configs.config import resolve_bucket_mb
-
-    target_bytes = resolve_bucket_mb(
-        cfg.optim.get("bucket_mb", "auto")) * 2 ** 20
-    plan = make_bucket_plan(student, DP, is_last_layer=isll,
-                            target_bytes=target_bytes)
+    plan = make_bucket_plan(student, DP, is_last_layer=isll)
     kw = dict(b1=cfg.optim.adamw_beta1, b2=cfg.optim.adamw_beta2,
               clip_grad=cfg.optim.clip_grad, ema=True)
 
@@ -270,8 +263,6 @@ def update_phase_arms(cfg, only: tuple | None = None) -> dict:
     out = {}
     gstack = _materialize(gstack_abs, stack_tree)
     for name, (fn, opt_abs, opt_sh) in arms.items():
-        if only is not None and name not in only:
-            continue
         _log(f"compiling {name} update-phase arm (ViT-L dp={DP})...")
         with mesh:
             compiled = jax.jit(
@@ -326,19 +317,12 @@ def stream_twin(cfg, which: str) -> dict:
     from dinov3_tpu.parallel.mesh import MeshSpec, build_mesh
     from dinov3_tpu.parallel.sharding import UPDATE_SHARD_AXES, zero3_leaf_spec
 
-    from dinov3_tpu.configs.config import (
-        resolve_staging_order,
-        resolve_stream_prefetch,
-    )
-
     mesh = build_mesh(MeshSpec(data=DP))
     set_current_mesh(mesh)
     model = build_backbone(cfg)
     kwargs = model._block_kwargs()
     kwargs["drop_path_rate"] = 0.0
     L, D, N = TWIN_BLOCKS, model.embed_dim, TWIN_TOKENS
-    depth = resolve_stream_prefetch(cfg.optim.get("stream_prefetch", "auto"))
-    order = resolve_staging_order(cfg.optim.get("staging_order", "auto"))
 
     block = SelfAttentionBlock(**kwargs)
     one_block = nn.meta.unbox(jax.eval_shape(
@@ -355,8 +339,7 @@ def stream_twin(cfg, which: str) -> dict:
         apply_fn = make_block_apply(kwargs, rope=None)
 
         def loss(stack_params, x):
-            y = streamed_block_scan(apply_fn, stack_params, x, L, mesh,
-                                    prefetch=depth)
+            y = streamed_block_scan(apply_fn, stack_params, x, L, mesh)
             return jnp.sum(y.astype(jnp.float32))
 
         def stack_sharding(p):
@@ -371,8 +354,7 @@ def stream_twin(cfg, which: str) -> dict:
             lambda s: pack_stream_buckets(s, N_BUCKETS, DP), stack_abs)
 
         def loss(bucket_shards, x):
-            y = bucketed_stream_scan(bucket_shards, x, mesh=mesh,
-                                     prefetch=depth, staging_order=order)
+            y = bucketed_stream_scan(bucket_shards, x, mesh=mesh)
             return jnp.sum(y.astype(jnp.float32))
 
         args_abs = (shards_abs, x_abs)

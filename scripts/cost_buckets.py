@@ -134,12 +134,7 @@ def update_phase_twins(cfg, dp: int) -> dict:
         patch_embed_lr_mult=cfg.optim.patch_embed_lr_mult,
         dino_head_wd_multiplier=cfg.optim.dino_head_wd_multiplier,
     )
-    from dinov3_tpu.configs.config import resolve_bucket_mb
-
-    target_bytes = resolve_bucket_mb(
-        cfg.optim.get("bucket_mb", "auto")) * 2 ** 20
-    plan = make_bucket_plan(student, dp, is_last_layer=isll,
-                            target_bytes=target_bytes)
+    plan = make_bucket_plan(student, dp, is_last_layer=isll)
     kw = dict(b1=cfg.optim.adamw_beta1, b2=cfg.optim.adamw_beta2,
               clip_grad=cfg.optim.clip_grad, ema=True)
     perleaf = make_sharded_update_schedule(schedules, lm, wm, isll, mesh,
@@ -221,7 +216,7 @@ def update_phase_twins(cfg, dp: int) -> dict:
         "n_param_leaves": len(jax.tree.leaves(student)),
         "plan": {
             "n_buckets": len(rows),
-            "target_bytes": target_bytes,
+            "target_bytes": plan.target_bytes,
             "payload_bytes": int(payload),
             "pad_fraction": round(
                 sum(r["pad_elems"] for r in rows)

@@ -13,10 +13,11 @@ the K/V rotation O(N/s) per device. Two instruments, both on the
   (``build_train_setup``) with the gram loss + gram-teacher refresh
   cadence on, at the same 16-row GLOBAL batch on three meshes —
   ``parallel.seq=1`` (dp=8, the oracle), dp=4 x seq=2, and
-  dp=2 x fsdp=2 x seq=2. ``kernels.ring_min_seq=1`` so the tiny
-  17-token passes actually ring (the per-pass dispatch would otherwise
-  keep them dense, which is the SHIPPED default — the override is the
-  test hook, not the recommendation). Pins: every arm's census has
+  dp=2 x fsdp=2 x seq=2. ``ops.attention.RING_MIN_SEQ`` is set to 1
+  for the run so the tiny 17-token passes actually ring (the per-pass
+  dispatch would otherwise keep them dense, which is the SHIPPED
+  floor — the override is the test hook, not the recommendation).
+  Pins: every arm's census has
   zero unattributed collectives; the seq arms attribute
   ``ring_permute``-scoped collectives; losses stay finite through a
   gram refresh; and the seq arms' loss trajectories match the seq=1
@@ -91,7 +92,6 @@ GRAM = [
     "gram.rep_update=true", "gram.update_frequency=2",
     "gram.it_first_update=2", "gram.max_updates=2",
     "crops.gram_teacher_crops_size=16",
-    "kernels.ring_min_seq=1",
 ]
 # same 16-row global batch on every mesh: batch_size_per_device scales
 # with the arm's data-parallel world so rows x world stays fixed
@@ -252,6 +252,9 @@ def main():
     except AttributeError:
         pass
 
+    from dinov3_tpu.ops import attention
+
+    attention.RING_MIN_SEQ = 1  # see the module docstring
     arms = [gram_stage_arm(name, ovr) for name, ovr in ARMS]
 
     # ---- acceptance pins (ISSUE 15) ----
@@ -289,9 +292,9 @@ def main():
             "CPU harness: structural evidence only (censuses, compiled "
             "per-device memory stats, loss trajectories) — no wall "
             "times; on-chip A/B not yet run on the chip. "
-            "kernels.ring_min_seq=1 here is the test hook that makes "
-            "17-token vit_test passes ring; shipped default 1024 keeps "
-            "local crops dense"
+            "ops.attention.RING_MIN_SEQ=1 here is the test hook that "
+            "makes 17-token vit_test passes ring; the shipped floor "
+            "1024 keeps local crops dense"
         ),
         "source": ("hlo_census + memory_analysis of the shipped "
                    "build_train_setup step and standalone attention "

@@ -120,11 +120,7 @@ def gather_phase_twins(cfg, mesh) -> dict:
         lambda r: meta.init_params(r, batch), jax.random.key(0)
     )["student"]
     subtree = _prune_streamed(student)
-    from dinov3_tpu.configs.config import resolve_bucket_mb
-
-    target_bytes = resolve_bucket_mb(
-        cfg.optim.get("bucket_mb", "auto")) * 2 ** 20
-    plan = make_zero3_bucket_plan(subtree, mesh, target_bytes=target_bytes)
+    plan = make_zero3_bucket_plan(subtree, mesh)
 
     def shardings(tree):
         def leaf(l):

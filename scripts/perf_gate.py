@@ -28,24 +28,11 @@ Record formats accepted on both sides (auto-detected):
 Usage:
   python scripts/perf_gate.py --baseline ANATOMY_r17.json --fresh X.json
   python scripts/perf_gate.py --self-check [--baseline ANATOMY_r17.json]
-  python scripts/perf_gate.py --tuned-vs-handset [--baseline TUNED_r20.json]
 
 --self-check (the CI invocation): gates the committed baseline against
 ITSELF (must pass — same numbers, zero drift), then against synthetic
 perturbations (x1.10 step time, +0.10 exposed fraction — both must
 fail). rc=0 only when all three behave.
-
---tuned-vs-handset: reads a TUNED_*.json plan artifact
-(scripts/tune_collectives.py) and gates every arm's TUNED measurement
-against its HAND-SET measurement — step wall AND the tuner objective
-(wall + exposed collective ms), each under the noise-calibrated
-step-time tolerance. NOT the exposed-fraction check: across two
-different schedules a smaller wall raises the fraction even when
-exposed ms shrank too (see tuned_vs_handset). The acceptance property:
-the resolved plan is never worse than the hand-set oracle on any arm
-(replicated/flat/bucketed/zero3/unified). Plan-invariant arms (the
-schedule knobs do not enter their programs) gate trivially by
-construction and are reported as such.
 """
 
 from __future__ import annotations
@@ -132,62 +119,6 @@ def gate(baseline: dict, fresh: dict) -> dict:
     }
 
 
-def tuned_vs_handset(doc: dict) -> dict:
-    """Gate a TUNED_*.json plan's per-arm tuned measurements against
-    their hand-set ones: neither the step wall nor the combined tuner
-    objective (wall + exposed collective ms, the quantity the search
-    minimized) may regress beyond the baseline-noise-calibrated
-    tolerance.
-
-    Deliberately NOT the cross-revision ``gate``'s exposed-FRACTION
-    check: that gate compares two revisions of the SAME schedule,
-    where a fraction jump means the program de-overlapped. Here the
-    two sides are different schedules — a plan that halves the wall
-    while also shrinking exposed ms RAISES the fraction (smaller
-    denominator), and a fraction gate would fail exactly the win the
-    tuner exists to find. The result carries a per-arm
-    ``plan_invariant`` / ``same_program`` annotation so "passed
-    trivially" is visible."""
-    arms = doc.get("arms") or {}
-    if not arms:
-        raise ValueError("no 'arms' in the tuned plan artifact")
-    checks = []
-    for arm in sorted(arms):
-        b = arms[arm]["handset"]["anatomy"]
-        f = arms[arm]["tuned"]["anatomy"]
-        tol = step_time_tolerance(b)
-        for metric in ("step_wall_ms", "objective_ms"):
-            if metric == "step_wall_ms":
-                b_v = float(b["step_wall_ms"]["mean"])
-                f_v = float(f["step_wall_ms"]["mean"])
-            else:
-                b_v = float(b.get("objective_ms", 0.0) or 0.0)
-                f_v = float(f.get("objective_ms", 0.0) or 0.0)
-            ratio = f_v / b_v if b_v > 0 else (math.inf if f_v else 1.0)
-            ok = ratio <= 1.0 + tol
-            checks.append({
-                "arm": arm, "metric": metric,
-                "baseline": round(b_v, 3), "fresh": round(f_v, 3),
-                "ratio": round(ratio, 4), "tol_rel": round(tol, 4),
-                "status": "ok" if ok else
-                f"FAIL: tuned {metric} regressed {100 * (ratio - 1):.1f}% "
-                f"vs hand-set (> {100 * tol:.1f}% noise-calibrated "
-                f"tolerance) — prefer the hand-set schedule",
-            })
-    notes = {}
-    for a, blk in arms.items():
-        if blk.get("plan_invariant"):
-            notes[a] = "plan-invariant (knobs do not enter this program)"
-        elif blk.get("same_program"):
-            notes[a] = "tuned == handset value (same program)"
-    return {
-        "passed": all("FAIL" not in c["status"] for c in checks),
-        "n_arms": len(arms),
-        "checks": checks,
-        "arm_notes": notes,
-    }
-
-
 def _perturb(rec: dict, *, ms_scale: float = 1.0,
              exposed_add: float = 0.0) -> dict:
     out = copy.deepcopy(rec)
@@ -235,11 +166,6 @@ def _load(path: str) -> dict:
 
 
 def main() -> int:
-    if "--tuned-vs-handset" in sys.argv:
-        doc = _load(_arg("--baseline", "TUNED_r20.json"))
-        result = tuned_vs_handset(doc)
-        print(json.dumps(result, indent=1))
-        return 0 if result["passed"] else 1
     baseline = _load(_arg("--baseline", "ANATOMY_r17.json"))
     if "--self-check" in sys.argv:
         return self_check(baseline)

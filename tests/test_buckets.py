@@ -635,23 +635,31 @@ def test_bucket_padding_guardrail(recwarn):
     # small-tree exemption the setup path relies on)
 
 
-def test_setup_guardrail_fires_on_fragmented_plan(eight_devices, recwarn):
-    """The guardrail is wired into build_train_setup: a pathologically
-    small optim.bucket_mb fragments the smol tree into stragglers and
-    the warning surfaces at setup build."""
-    _setup(["parallel.data=-1", "optim.bucket_mb=1"], 8, eight_devices)
-    # smol tree at 1MiB target: single-bucket groups of wildly unequal
-    # size -> the straggler/pad guardrail may or may not fire, but the
-    # call must not raise; force the fragmenting case directly instead
+def test_setup_guardrail_fires_on_fragmented_plan(eight_devices):
+    """The guardrail is wired into build_train_setup, on the plan the
+    setup builds at the one target there is: the smol tree's tiny
+    last-layer groups are stragglers beside its backbone bucket and
+    the warning surfaces at setup build. The same tree cut at 16 KiB
+    fragments the backbone too."""
     from dinov3_tpu.configs.config import warn_bucket_padding
-    from dinov3_tpu.train import make_bucket_plan
+    from dinov3_tpu.train.fused_update import BUCKET_TARGET_BYTES
 
-    params = {"backbone": {
-        "big": jnp.zeros((4096,)), "tiny_a": jnp.zeros((3,)),
-        "tiny_b": jnp.zeros((5,))}}
-    plan = make_bucket_plan(params, 8, target_bytes=4096 * 4)
-    msgs = warn_bucket_padding(plan.padding_stats(), plan.target_bytes)
-    assert isinstance(msgs, list)
+    with pytest.warns(UserWarning, match="bucket size axis") as rec:
+        setup, _ = _setup(["parallel.data=-1"], 8, eight_devices)
+    assert setup.bucket_plan.target_bytes == BUCKET_TARGET_BYTES
+    at_setup = [w for w in rec.list
+                if "bucket size axis" in str(w.message)]
+    assert all(str(BUCKET_TARGET_BYTES) in str(w.message)
+               for w in at_setup)
+
+    plan = make_bucket_plan(setup.state.params["student"], 8,
+                            target_bytes=2 ** 14)
+    assert len(plan.buckets) > len(setup.bucket_plan.buckets)
+    with pytest.warns(UserWarning, match="bucket size axis"):
+        msgs = warn_bucket_padding(
+            plan.padding_stats(), plan.target_bytes)
+    assert len(msgs) > len(at_setup)
+    assert any("_backbone]" in m for m in msgs)
 
 
 # ---------------- overlap twin ----------------

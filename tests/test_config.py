@@ -220,3 +220,292 @@ def test_reshard_guardrail_config_and_live_modes():
         assert any("re-padding" in str(w.message) for w in caught)
     assert warn_reshard_padding(leaf_sizes=[7], src_dp=8, dst_dp=7,
                                 threshold=0.05) == []
+
+
+# ---------------- the ``auto`` keys ----------------
+#
+# Every key of ssl_default_config.yaml whose default is ``auto`` is
+# resolved by code from what it can observe (the config, the mesh, the
+# platform). The list is READ from the yaml, so a new ``auto`` key
+# without a row below fails here.
+
+# mesh kind -> (overrides, devices, the engine the mesh picks)
+_MESHES = {
+    "one": ([], 1, dict(arm="replicated", bucketed=False, zero3=False,
+                        zero3_buckets=False)),
+    "dp8": (["parallel.data=-1"], 8,
+            dict(arm="bucketed", bucketed=True, zero3=False,
+                 zero3_buckets=False)),
+    "fsdp8": (["parallel.fsdp=8"], 8,
+              dict(arm="unified", bucketed=False, zero3=True,
+                   zero3_buckets=True)),
+}
+
+
+def _device_share(tree):
+    """Share of ``tree``'s elements that the first device holds."""
+    import jax
+
+    leaves = jax.tree.leaves(tree)
+    return (sum(x.addressable_shards[0].data.size for x in leaves)
+            / sum(x.size for x in leaves))
+
+
+def _auto_streaming_targets(s, want):
+    assert s.meta.streaming_targets is True
+
+
+def _auto_crop_packing(s, want):
+    from dinov3_tpu.configs.config import crop_packing_wished
+
+    # three 5-token local crops fit one 17-token global row: k >= 2
+    assert crop_packing_wished(s.cfg) and s.meta.crop_packing is True
+
+
+def _auto_rng_plan(s, want):
+    assert s.meta.rng_plan is True
+
+
+def _auto_async_metrics(s, want):
+    from dinov3_tpu.telemetry import telemetry_wished
+
+    assert telemetry_wished(s.cfg) and s.telemetry_builder is not None
+
+
+def _auto_serve_spans(s, want):
+    from dinov3_tpu.configs.config import serve_obs_wished
+
+    assert serve_obs_wished(s.cfg) is True
+
+
+def _auto_anatomy(s, want):
+    from dinov3_tpu.configs.config import anatomy_wished
+
+    assert anatomy_wished(s.cfg) is True
+
+
+def _auto_continuous_packing(s, want):
+    from dinov3_tpu.configs.config import continuous_packing_wished
+
+    assert continuous_packing_wished(s.cfg) is True
+
+
+def _auto_row_tokens(s, want):
+    from dinov3_tpu.serve.engine import serve_layout_from_cfg
+
+    # two images of the largest size served: (512 / 4)^2 patches + CLS
+    assert s.cfg.serve.max_px == 512 and s.cfg.student.patch_size == 4
+    assert serve_layout_from_cfg(s.cfg).row_tokens == 2 * (1 + 128 ** 2)
+
+
+def _auto_serve_cache(s, want):
+    from dinov3_tpu.configs.config import serve_cache_wished
+
+    assert serve_cache_wished(s.cfg) is True
+
+
+def _auto_flash_attention(s, want):
+    import jax
+    import numpy as np
+
+    from dinov3_tpu.models import backbone_kwargs_from_cfg
+    from dinov3_tpu.ops.attention import dispatch_attention, xla_attention
+
+    assert backbone_kwargs_from_cfg(s.cfg)["attn_impl"] == "auto"
+    # the platform decides inside the dispatch: dense off the TPU
+    assert jax.default_backend() == "cpu"
+    q, k, v = (jax.random.normal(jax.random.key(i), (1, 8, 2, 4))
+               for i in range(3))
+    assert np.array_equal(dispatch_attention(q, k, v, impl="auto"),
+                          xla_attention(q, k, v))
+
+
+def _auto_flash_min_seq(s, want):
+    from dinov3_tpu.configs.config import FLASH_NEVER_SEQ
+    from dinov3_tpu.models import backbone_kwargs_from_cfg
+
+    # CROSSOVER_r19.json's verdict is null: flash won no measured point
+    assert backbone_kwargs_from_cfg(s.cfg)["flash_min_seq"] \
+        == FLASH_NEVER_SEQ
+
+
+def _auto_sharded_update(s, want):
+    # under all-auto another engine owns the update shards on every
+    # mesh (bucketed on dp, zero3 on fsdp), and the moments are 1/dp
+    from dinov3_tpu.parallel.sharding import update_shard_size
+
+    assert s.sharded_update is False
+    assert _device_share(s.state.opt_state.adam.mu) \
+        == 1 / update_shard_size(s.mesh)
+
+
+def _auto_bucketed_collectives(s, want):
+    from dinov3_tpu.parallel.reshard import arm_name
+
+    assert arm_name(s) == want["arm"]
+    assert (s.bucketed, s.zero3_buckets) \
+        == (want["bucketed"], want["zero3_buckets"])
+    assert (s.bucket_plan is not None) == want["bucketed"]
+    assert (s.zero3_bucket_plan is not None) == want["zero3_buckets"]
+
+
+def _auto_zero3(s, want):
+    from dinov3_tpu.configs.config import zero3_wished
+    from dinov3_tpu.parallel.sharding import update_shard_size
+
+    assert zero3_wished(s.cfg) == s.zero3 == want["zero3"]
+    share = 1 / update_shard_size(s.mesh) if want["zero3"] else 1.0
+    assert _device_share(s.state.params["student"]) == share
+
+
+def _auto_resume_topology(s, want):
+    # a state that is still live on reachable devices is resharded in
+    # memory; after a preemption there is none and the checkpoint is read
+    from dinov3_tpu.parallel.reshard import topology_of
+    from dinov3_tpu.train.setup import elastic_resume
+
+    class Ckpt:
+        def restore(self, template):
+            return template
+
+    policy = s.cfg.train.resume_topology
+    _, info = elastic_resume(s, Ckpt(), policy=policy)
+    assert info["path"] == "disk"
+    _, info = elastic_resume(s, Ckpt(), live_state=s.state,
+                             live_topology=topology_of(s), policy=policy)
+    assert info["path"] == "memory"
+
+
+_AUTO_KEYS = {
+    "loss.streaming_targets": _auto_streaming_targets,
+    "model.crop_packing": _auto_crop_packing,
+    "rng.plan": _auto_rng_plan,
+    "telemetry.async_metrics": _auto_async_metrics,
+    "telemetry.serve_spans": _auto_serve_spans,
+    "telemetry.anatomy": _auto_anatomy,
+    "serve.continuous_packing": _auto_continuous_packing,
+    "serve.row_tokens": _auto_row_tokens,
+    "serve.cache.enabled": _auto_serve_cache,
+    "kernels.flash_attention": _auto_flash_attention,
+    "kernels.flash_min_seq": _auto_flash_min_seq,
+}
+_AUTO_MESH_KEYS = {
+    "optim.sharded_update": _auto_sharded_update,
+    "optim.bucketed_collectives": _auto_bucketed_collectives,
+    "parallel.zero3": _auto_zero3,
+    "train.resume_topology": _auto_resume_topology,
+}
+
+
+def _auto_cases():
+    def walk(node, prefix=""):
+        for k, v in node.items():
+            if isinstance(v, dict):
+                yield from walk(v, f"{prefix}{k}.")
+            elif v == "auto":
+                yield f"{prefix}{k}"
+
+    for key in walk(get_default_config().to_dict()):
+        for mesh in (_MESHES if key in _AUTO_MESH_KEYS else ("one",)):
+            yield pytest.param(key, mesh, id=f"{key}-{mesh}")
+
+
+@pytest.fixture(scope="module")
+def auto_setups(eight_devices):
+    import jax.numpy as jnp
+    from test_fused_update import smol_cfg
+
+    from dinov3_tpu.data import make_synthetic_batch
+    from dinov3_tpu.parallel.context import set_current_mesh
+    from dinov3_tpu.train import build_train_setup
+
+    built = {}
+
+    def get(mesh):
+        if mesh not in built:
+            overrides, n, _ = _MESHES[mesh]
+            cfg = smol_cfg(overrides)
+            batch = {k: jnp.asarray(v) for k, v in
+                     make_synthetic_batch(cfg, 2 * n, seed=0).items()}
+            built[mesh] = build_train_setup(
+                cfg, batch, devices=eight_devices[:n])
+        set_current_mesh(built[mesh].mesh)
+        return built[mesh]
+
+    yield get
+    set_current_mesh(None)
+
+
+@pytest.mark.filterwarnings("ignore:bucket size axis")
+@pytest.mark.parametrize("key,mesh", list(_auto_cases()))
+def test_auto_key_resolves_from_config_and_mesh(auto_setups, key, mesh):
+    """No ``auto`` key is resolved from a record or a plan: each is
+    decided from the config, the mesh or the platform, and the three
+    mesh kinds pick one engine each (replicated / bucketed / unified)."""
+    check = {**_AUTO_KEYS, **_AUTO_MESH_KEYS}.get(key)
+    assert check is not None, (
+        f"{key} defaults to auto in ssl_default_config.yaml and has no "
+        f"row here: say what resolves it, and from what")
+    check(auto_setups(mesh), _MESHES[mesh][2])
+
+
+@pytest.mark.parametrize("arch", ["ssl", "lm"])
+def test_package_reads_no_root_record(arch, monkeypatch):
+    """Loading a config and building the default backbone and meta-arch
+    opens no record of the repo root but CROSSOVER_r19.json (the last
+    one, ROADMAP D4), and warns of no tuned plan."""
+    import builtins
+    import io
+    import os
+    import warnings
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    opened = []
+    real_open = io.open
+
+    def recording_open(file, *args, **kwargs):
+        if isinstance(file, (str, os.PathLike)):
+            opened.append(os.path.abspath(os.fspath(file)))
+        return real_open(file, *args, **kwargs)
+
+    monkeypatch.setattr(builtins, "open", recording_open)
+    monkeypatch.setattr(io, "open", recording_open)
+
+    from test_fused_update import SMOL
+
+    from dinov3_tpu.models import build_backbone
+
+    with warnings.catch_warnings(record=True) as caught:
+        warnings.simplefilter("always")
+        if arch == "ssl":
+            from dinov3_tpu.train.ssl_meta_arch import SSLMetaArch
+
+            cfg = load_config(overrides=SMOL)
+            build_backbone(cfg)
+            SSLMetaArch(cfg)
+        else:
+            from dinov3_tpu.train.lm_meta_arch import LMMetaArch
+
+            cfg = load_config(
+                os.path.join(repo, "configs", "train",
+                             "kimi_linear_ep32.yaml"),
+                overrides=[
+                    "lm.hidden_size=64", "lm.intermediate_size=128",
+                    "lm.kda_num_heads=2", "lm.kda_head_dim=16",
+                    "lm.num_attention_heads=2", "lm.kv_lora_rank=32",
+                    "lm.qk_nope_head_dim=16", "lm.qk_rope_head_dim=8",
+                    "lm.v_head_dim=16", "lm.num_experts=16",
+                    "lm.num_experts_per_token=4",
+                    "lm.moe_intermediate_size=32", "lm.expert_shards=4",
+                    "lm.vocab_size=256", "lm.seq_len=96",
+                    "train.batch_size_per_device=2",
+                ])
+            build_backbone(cfg)
+            LMMetaArch(cfg)
+    assert not [str(w.message) for w in caught
+                if "tuned plan" in str(w.message)]
+    root_records = {os.path.basename(p) for p in opened
+                    if os.path.dirname(p) == repo
+                    and p.endswith((".json", ".jsonl"))}
+    assert root_records <= {"CROSSOVER_r19.json"}, root_records
+    assert opened, "the recorder saw no open() at all"

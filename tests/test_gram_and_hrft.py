@@ -72,18 +72,18 @@ def test_refresh_copies_teacher_into_gram():
         state2.params["teacher"]["backbone"]
 
 
-def test_gram_stage_on_dp_seq_mesh():
+def test_gram_stage_on_dp_seq_mesh(monkeypatch):
     """Gram-anchored step dryrun on a dp x seq mesh: the ring path
-    engages (kernels.ring_min_seq=1 makes even vit_test's 17-token
-    passes ring), the gram loss lands finite in the metrics, and the
-    refresh cadence still fires — the ISSUE-15 high-res stage in
-    miniature."""
+    engages (a ring floor of 1 makes even vit_test's 17-token passes
+    ring), the gram loss lands finite in the metrics, and the refresh
+    cadence still fires — the ISSUE-15 high-res stage in miniature."""
+    from dinov3_tpu.ops import attention
     from dinov3_tpu.parallel.context import set_current_mesh
     from dinov3_tpu.train import build_train_setup, put_batch
 
+    monkeypatch.setattr(attention, "RING_MIN_SEQ", 1)
     cfg = _gram_cfg([
         "parallel.data=4", "parallel.seq=2", "parallel.zero3=false",
-        "kernels.ring_min_seq=1",
     ])
     batch = {k: jnp.asarray(v) for k, v in
              make_synthetic_batch(cfg, 4, seed=0).items()}

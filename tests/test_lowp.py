@@ -30,9 +30,8 @@ pin:
   rings ignored;
 - the ``warn_lowp_divergence`` guardrail (fire/silent), the arm
   conflict raises (fp8_enabled / moe / pipe>1 / convnext / typo'd
-  arm), the no-silent-knobs census registration, the serve-quant
-  numerics staying bitwise after delegating to ops/lowp.py, and the
-  committed COST_LP_r21.json acceptance numbers.
+  arm), the serve-quant numerics staying bitwise after delegating to
+  ops/lowp.py, and the committed COST_LP_r21.json acceptance numbers.
 """
 
 import json
@@ -397,21 +396,6 @@ def test_arm_conflicts_raise(eight_devices):
               "train.low_precision.arm=int8"])
     with pytest.raises(ValueError, match="ViT backbone"):
         build_backbone(cfg)
-
-
-def test_census_registration():
-    """The no-silent-knobs census covers the train.low_precision block:
-    all four knobs registered with justifications, census green."""
-    from dinov3_tpu.tuning.census import knob_census
-
-    census = knob_census()
-    assert census["ok"], (census["unregistered"], census["stale_registry"])
-    justified = set(census["by_kind"]["justified"])
-    for knob in ("train.low_precision.arm",
-                 "train.low_precision.amax_history_len",
-                 "train.low_precision.scale_margin",
-                 "train.low_precision.divergence_tol"):
-        assert knob in justified, knob
 
 
 def test_serve_quant_numerics_unchanged():

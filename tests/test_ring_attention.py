@@ -173,35 +173,42 @@ def test_ring_collectives_scope_attributed(eight_devices, rng):
         shutil.rmtree(tdir, ignore_errors=True)
 
 
-def test_ring_min_seq_dispatch_per_pass(eight_devices):
-    """Per-pass dispatch inside SelfAttention: on a dp x seq mesh the
-    long pass (N >= ring_min_seq) compiles to a ring program
-    (collective-permutes present) while the short pass on the SAME
-    module stays dense with seq-replicated activations (none)."""
+@pytest.mark.parametrize("floor,n_tokens,rings", [
+    (64, 64, True), (64, 16, False),
+    (None, 1024, True), (None, 1023, False),
+], ids=["floor64-n64", "floor64-n16", "default-n1024", "default-n1023"])
+def test_ring_min_seq_dispatch_per_pass(
+        eight_devices, monkeypatch, floor, n_tokens, rings):
+    """Per-pass dispatch inside SelfAttention: on a dp x seq mesh a
+    pass at or above the floor (``ops.attention.RING_MIN_SEQ``, read
+    when the pass is traced) compiles to a ring program
+    (collective-permutes present) while a shorter pass on the SAME
+    module stays dense with seq-replicated activations (none). The
+    ``default`` cases pin the shipped floor of 1024 tokens."""
     import flax.linen as nn
 
-    from dinov3_tpu.ops.attention import SelfAttention
+    from dinov3_tpu.ops import attention
     from dinov3_tpu.parallel.context import set_current_mesh
 
+    if floor is None:
+        assert attention.RING_MIN_SEQ == 1024
+    else:
+        monkeypatch.setattr(attention, "RING_MIN_SEQ", floor)
     mesh = _mesh(eight_devices, 2)
     D, h = 32, 2
-    attn = SelfAttention(
-        dim=D, num_heads=h, seq_parallel=True, ring_min_seq=64,
+    attn = attention.SelfAttention(
+        dim=D, num_heads=h, seq_parallel=True,
         attn_impl="xla", dtype=jnp.float32, param_dtype=jnp.float32,
     )
-    x_long = jax.random.normal(jax.random.key(0), (2, 64, D))
-    x_short = jax.random.normal(jax.random.key(1), (2, 16, D))
-    params = nn.meta.unbox(attn.init(jax.random.key(2), x_long))
+    x = jax.random.normal(jax.random.key(0), (2, n_tokens, D))
+    params = nn.meta.unbox(attn.init(jax.random.key(2), x[:, :16]))
 
     set_current_mesh(mesh)
     try:
-        def hlo_for(x):
-            return jax.jit(
-                lambda p, x: attn.apply(p, x)
-            ).lower(params, x).compile().as_text()
-
-        assert "collective-permute" in hlo_for(x_long)
-        assert "collective-permute" not in hlo_for(x_short)
+        hlo = jax.jit(
+            lambda p, x: attn.apply(p, x)
+        ).lower(params, x).compile().as_text()
+        assert ("collective-permute" in hlo) == rings
     finally:
         set_current_mesh(None)
 

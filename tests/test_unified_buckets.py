@@ -591,6 +591,77 @@ def test_hierarchical_stream_scan_bitwise(eight_devices):
     assert cen["by_scope"].get("bucket_ag_intra", {"ops": 0})["ops"] > 0
 
 
+def test_bucketed_stream_prefetch_and_orders_bitwise(eight_devices):
+    """bucketed_stream_scan: every prefetch depth AND every staging
+    order of the hierarchical gather path is bitwise the flat
+    double-buffered default — both are pure wire schedules."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from dinov3_tpu.models.streaming import bucketed_stream_scan
+    from dinov3_tpu.parallel.sharding import STAGING_ORDERS
+
+    mesh = _dp_fsdp_mesh(eight_devices)
+    shards = jnp.arange(4 * 64, dtype=jnp.float32).reshape(4, 64) * 0.01
+    x = jnp.ones((8, 16), jnp.bfloat16)
+    sh = jax.device_put(
+        shards, NamedSharding(mesh, P(None, ("data", "fsdp"))))
+    xx = jax.device_put(x, NamedSharding(mesh, P("data")))
+
+    ref = np.asarray(jax.jit(lambda s, v: bucketed_stream_scan(
+        s, v, mesh=mesh))(sh, xx))
+    for depth in (0, 1, 2):
+        got = jax.jit(lambda s, v, d=depth: bucketed_stream_scan(
+            s, v, mesh=mesh, prefetch=d))(sh, xx)
+        assert np.array_equal(ref, np.asarray(got)), f"depth {depth}"
+    for order in STAGING_ORDERS:
+        got = jax.jit(lambda s, v, o=order: bucketed_stream_scan(
+            s, v, mesh=mesh, hierarchical=True,
+            staging_order=o))(sh, xx)
+        assert np.array_equal(ref, np.asarray(got)), order
+
+
+def test_staging_orders_equivalent_through_gather_schedule(
+        eight_devices):
+    """make_zero3_gather_schedule under all four staging orders:
+    forward bitwise identical (pure wire schedule), grads equal at
+    float tolerance (the RS transpose only reorders the reduction)."""
+    from dinov3_tpu.parallel.sharding import (
+        STAGING_ORDER,
+        STAGING_ORDERS,
+        split_staging_order,
+    )
+
+    assert STAGING_ORDERS == (
+        "inter_intra", "intra_inter", "inter_inter", "intra_intra")
+    assert split_staging_order("intra_inter") == ("intra", "inter")
+    with pytest.raises(ValueError, match="staging order"):
+        split_staging_order("inter")
+
+    mesh = _dp_fsdp_mesh(eight_devices)
+    tree = _zero3_put(_toy_tree(), mesh)
+    plan = make_zero3_bucket_plan(tree, mesh, target_bytes=2 ** 9)
+
+    def loss_of(g):
+        def loss(t):
+            return sum(jnp.sum(jnp.sin(le.astype(jnp.float32)))
+                       for le in jax.tree.leaves(g(t)))
+        return loss
+
+    outs, grads = {}, {}
+    for order in STAGING_ORDERS:
+        g = make_zero3_gather_schedule(plan, mesh, bucketed=True,
+                                       staging_order=order)
+        outs[order] = jax.jit(g)(tree)
+        grads[order] = jax.jit(jax.grad(loss_of(g)))(tree)
+    for order in STAGING_ORDERS:
+        assert_trees_bitwise(outs[STAGING_ORDER], outs[order], order)
+        for a, b in zip(jax.tree.leaves(grads[STAGING_ORDER]),
+                        jax.tree.leaves(grads[order])):
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), rtol=1e-6, atol=1e-6,
+                err_msg=order)
+
+
 # ---------------- cross-arm checkpoints ----------------
 
 def test_checkpoint_unified_perleaf_roundtrip(tmp_path, arms_unified):

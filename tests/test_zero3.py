@@ -464,6 +464,40 @@ def test_twin_prefetch_overlap_census():
     assert pf_au["at_use_scoped_ops"] == n_leaves
 
 
+def test_stream_prefetch_depths_bitwise_equivalent():
+    """Every lookahead depth (and the boolean spellings) computes the
+    SAME forward bitwise: depth is purely a gather schedule."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from dinov3_tpu.models.streaming import (
+        STREAM_PREFETCH,
+        prefetch_depth,
+        streamed_block_scan,
+    )
+
+    assert (prefetch_depth(False), prefetch_depth(True)) == (0, 1)
+    assert (prefetch_depth(0), prefetch_depth(1), prefetch_depth(3)) \
+        == (0, 1, 3)
+    assert prefetch_depth(STREAM_PREFETCH) == 1
+    with pytest.raises(ValueError, match="prefetch depth"):
+        prefetch_depth(-1)
+
+    mesh, kwargs, stack, x, L, apply_fn = _twin_fixture(jnp.float32)
+    stack_sh = _twin_shardings(stack, mesh)
+    stack_dev = jax.device_put(stack, stack_sh)
+    x_sh = NamedSharding(mesh, P("data"))
+    x_dev = jax.device_put(x, x_sh)
+    outs = []
+    with mesh:
+        for depth in (False, 0, True, 1, 2, 3):
+            outs.append(np.asarray(jax.jit(
+                lambda s, xx, d=depth: streamed_block_scan(
+                    apply_fn, s, xx, L, mesh, prefetch=d),
+                in_shardings=(stack_sh, x_sh))(stack_dev, x_dev)))
+    for o in outs[1:]:
+        assert np.array_equal(outs[0], o)
+
+
 # ---------------- cross-arm checkpoints ----------------
 
 def test_checkpoint_replicated_zero3_roundtrip(tmp_path, eight_devices):
