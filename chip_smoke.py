@@ -369,18 +369,27 @@ def phase_kernels() -> None:
 
 
 def _kda_kernel_row(interpret: bool) -> None:
-    """``ops/kda.py``'s forward kernel (its backward is the plain scan's)
-    against the token recurrence, float32 inputs so that the gap is the
-    products' own: at the decays the published initial values reach
-    (1.6 nats a token) the largest gap of the output and of the five
-    gradients, relative to each one's largest entry, under 1e-4 (a
-    float32 product lowered as ONE bfloat16 pass reads 1e-2; interpret
-    mode cannot see that, the chip can); at 30 nats a token on half the
-    channels and at single tokens of 200, finite and under 1e-3."""
+    """``ops/kda.py``'s two kernels, ``kda_chunk_fwd`` and
+    ``kda_chunk_bwd``, compiled, against the token recurrence and ITS
+    gradients. Float32 inputs, so that the gap is the products' own: at
+    the decays the published initial values reach (1.6 nats a token) the
+    largest gap of the output and of the five gradients, relative to
+    each one's largest entry, under 1e-4 (a float32 product lowered as
+    ONE bfloat16 pass reads 1e-2; interpret mode cannot see that, the
+    chip can); at 30 nats a token on half the channels and at single
+    tokens of 200, finite and under 1e-3. Then the activation type of the
+    step, bfloat16 q, k, v: their gradients come back bfloat16, packed in
+    the kernel a pair of heads a word, within a rounding (2^-8) of the
+    recurrence's."""
     import jax
     import jax.numpy as jnp
 
-    from dinov3_tpu.ops.kda import kda_chunked, kda_recurrent
+    from dinov3_tpu.ops.kda import (
+        BACKWARD_KERNEL_NAME,
+        KERNEL_NAME,
+        kda_chunked,
+        kda_recurrent,
+    )
 
     b, t, h, d = SIZES["kda_shape"]
     ks = jax.random.split(jax.random.key(1), 5)
@@ -404,30 +413,37 @@ def _kda_kernel_row(interpret: bool) -> None:
     def ref(*x):
         return d ** -0.5 * kda_recurrent(*x)
 
-    def out_and_grads(f, argnums):
+    def out_and_grads(f):
         return jax.jit(lambda *x: (f(*x), *jax.grad(
-            lambda *y: jnp.sum(jnp.sin(f(*y))), argnums=argnums)(*x)))
+            lambda *y: jnp.sum(jnp.sin(f(*y))), argnums=(0, 1, 2, 3, 4))(*x)))
 
-    def gaps(x, argnums=(0, 1, 2, 3, 4)):
-        got, want = (out_and_grads(f, argnums)(*x) for f in (kern, ref))
-        return [_max_err(a, w) / float(jnp.max(jnp.abs(w)))
+    def gaps(x):
+        got, want = (out_and_grads(f)(*x) for f in (kern, ref))
+        assert [a.dtype for a in got[1:]] == [a.dtype for a in x]
+        return [_max_err(a, w) / float(jnp.max(jnp.abs(w.astype(jnp.float32))))
                 for a, w in zip(got, want)]
 
+    if not interpret:  # both Mosaic calls are in the gradient's program
+        text = out_and_grads(kern).lower(
+            q, k, v, published, beta).compile().as_text()
+        assert text.count("tpu_custom_call") >= 2 and all(
+            name in text for name in (KERNEL_NAME, BACKWARD_KERNEL_NAME)), \
+            "the gradient's program lacks a kernel"
     _compiled_has_kernel(jax.jit(kern), q, k, v, published, beta)
-    _compiled_has_kernel(out_and_grads(kern, (0, 1, 2, 3, 4)),
-                         q, k, v, published, beta)
     for name, (g, limit) in decays.items():
         found = gaps((q, k, v, g, beta))
-        log(f"kernels: kda_chunk_fwd {(b, t, h, d)} decay {name}: largest "
-            f"relative gap to the recurrence, output {found[0]:.2e}, "
-            f"gradients q k v g beta {' '.join(f'{x:.2e}' for x in found[1:])}")
+        log(f"kernels: kda_chunk_fwd + kda_chunk_bwd {(b, t, h, d)} decay "
+            f"{name}: largest relative gap to the recurrence, output "
+            f"{found[0]:.2e}, gradients q k v g beta "
+            f"{' '.join(f'{x:.2e}' for x in found[1:])}")
         assert all(math.isfinite(x) and x <= limit for x in found), (name, found)
-    # the activation type of the step: a block packs two heads' rows a word
     found = gaps((*(x.astype(jnp.bfloat16) for x in (q, k, v)), published,
-                  beta), argnums=(3, 4))
-    log(f"kernels: kda_chunk_fwd bfloat16 q k v: output {found[0]:.2e}, "
-        f"gradients g beta {found[1]:.2e} {found[2]:.2e}")
-    assert all(math.isfinite(x) and x <= 1e-4 for x in found), found
+                  beta))
+    log(f"kernels: kda_chunk_fwd + kda_chunk_bwd bfloat16 q k v: output "
+        f"{found[0]:.2e}, gradients q k v (bfloat16) g beta "
+        f"{' '.join(f'{x:.2e}' for x in found[1:])}")
+    assert all(math.isfinite(x) for x in found) and max(
+        found[0], *found[4:]) <= 1e-4 and max(found[1:4]) <= 2 ** -8, found
 
 
 # ------------------------------------------------------------------ serve

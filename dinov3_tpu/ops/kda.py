@@ -21,29 +21,52 @@ so the chunk is a handful of matmuls: A, P, the inverse of the unit
 lower-triangular I + Diag(b) A (a product of 6 factors, since its
 strict part is nilpotent), and five products with the state.
 
-What runs where. ``kda_chunked`` has two forward paths that share these
-definitions and no loop, and ``kda_path`` chooses between them from
-what it can see, no option or variable: the KERNEL where the backend is
-a TPU (or a test asks for ``interpret``), d_k and d_v are multiples of
-128 and the chunk is 64; the PLAIN path (``_chunk`` under ``lax.scan``,
-each chunk's body rematerialised) everywhere else: CPU runs, narrow
-test widths, other chunks. The kernel (``kda_chunk_fwd``, one
-``pallas_call``) takes the chunk axis as the grid's sequential axis and
-the sequence as the parallel one; a grid step is one chunk of every
-head, worked through a pair of heads at a time. In VMEM it keeps that
-chunk of q, k, v (the activation type), g and o (float32) and every
-head's [d_v, d_k] float32 state, zeroed at a sequence's first chunk and
-carried from grid step to grid step: a state never goes to HBM inside a
-sequence, unless the pass is the one that a backward follows, which
-also writes each chunk's STARTING state ([n, B, H, d_k, d_v], what the
-scan's backward keeps). The blocks are cut from the [B, T, H, d] arrays
+What runs where. ``kda_chunked`` has two paths that share these
+definitions, and ``kda_path`` chooses between them from what it can
+see, no option or variable: the KERNELS where the backend is a TPU (or a
+test asks for ``interpret``), d_k and d_v are multiples of 128 and the
+chunk is 64; the PLAIN path (``_chunk`` under ``lax.scan``, each chunk's
+body rematerialised, its backward JAX's own transposition of the scan)
+everywhere else: CPU runs, narrow test widths, other chunks. A path is
+both of its passes: no loop is shared, none chosen apart.
+
+The forward kernel (``kda_chunk_fwd``, one ``pallas_call``) takes the
+chunk axis as the grid's sequential axis and the sequence as the
+parallel one; a grid step is one chunk of every head, worked through a
+pair of heads at a time. In VMEM it keeps that chunk of q, k, v (the
+activation type), g and o (float32) and every head's [d_v, d_k] float32
+state, zeroed at a sequence's first chunk and carried from grid step to
+grid step: a state never goes to HBM inside a sequence, unless the pass
+is the one that a backward follows (``custom_vjp``'s forward rule), which
+also writes each chunk's STARTING state ([n, B, H, d_v, d_k], what the
+scan's backward keeps) and the inverse it made ([B, n, H / 2, C, 2 C], a
+quarter of the states' bytes: six dependent products the backward then
+does not repeat). The blocks are cut from the [B, T, H, d] arrays
 as they lie (a chunk is [C * H, d] rows, token-major and head-minor, of
 which a head is every H-th: strided loads and stores), so no copy is
-made on either side of the call. The BACKWARD of both paths is the plain
-one: the ``_chunk`` body's VJP, chunk by chunk from the last, recomputed
-from the saved starting states (``custom_vjp`` on the kernel path, JAX's
-own transposition of the scan on the plain one). Under a layer's remat
-the first pass runs the kernel without the state output.
+made on either side of the call. Under a layer's remat the first pass
+runs the kernel without the state output.
+
+The backward kernel (``kda_chunk_bwd``, one ``pallas_call``, the
+``custom_vjp``'s backward rule) has the same grid with the chunks LAST
+FIRST, the same blocks, and in place of the states their cotangent, a
+[d_v, d_k] float32 scratch a head, zero at a sequence's last chunk and
+carried towards its first. A grid step makes again, from the chunk's
+saved starting state and inverse, what else the forward made (G, A and
+P by the same levels, u) and then transposes the definitions above by
+hand. The one step that is not a product's transpose is the inverse:
+with M = I + Diag(b) A, r = V - (e^G k) S and u = M^-1 (b r),
+
+    dw = M^-T du,   dM = -dw u^T,   dA = Diag(b) dM (below the diagonal),
+    db_t = sum_s dM_ts A_ts + dw_t . r_t,   dr = dV = b dw
+
+: two full-width products where the transposed six-factor chain has
+twenty [C, C] ones in a dependent line. A level's product is transposed
+twice (for its rows and its columns), and every decayed operand x e^E
+gives (x e^E) d(x e^E) to dE: E is a difference of two values of G, so
+the kernel gathers one dG per token and channel, and dg is its running
+sum from the chunk's end. bfloat16 q, k, v get bfloat16 gradients,
+rounded and packed in the kernel.
 
 Precision: g, G, every exponential, every product below and the state
 are float32, the matmuls at ``Precision.HIGHEST`` (on the TPU a float32
@@ -63,10 +86,13 @@ initial values reach 1.6, and learned ones are unbounded.)
 G itself is a float32 running sum: a difference of two of its values is
 good to |G| 2^-24, so a chunk that decays by thousands of nats resolves
 the factors of its slow channels to 1e-4 and no better.
-The kernel's arithmetic is the plain path's, product for product, in
-float32 with full-precision products (Mosaic's ``contract_precision
-<fp32>``: on the chip 8e-8 of the token recurrence, as the plain path)
-and the same halving levels: no factor above 1 at any decay. It differs
+The kernels' arithmetic is the plain path's, product for product (but
+for the inverse's gradient), in float32 with full-precision products
+(Mosaic's ``contract_precision<fp32>``: on the chip within 1.3e-6 of the
+token recurrence's output and 4e-6 of its gradients, of each one's
+largest entry, as the plain path) and
+the same halving levels, backward as forward: no factor above 1 at any
+decay, and the state's cotangent float32 like the state. They differ
 in the blocking alone: a level is ONE product over the whole chunk,
 masked to the level's blocks (12 times the score operations the count
 in ``benchmark/lm_flops.py`` needs, at shapes the MXU takes); the score
@@ -86,11 +112,15 @@ from jax.experimental.pallas import tpu as pltpu
 
 CHUNK = 64
 KERNEL_NAME = "kda_chunk_fwd"
+BACKWARD_KERNEL_NAME = "kda_chunk_bwd"
 _NEG = -1e30
 # a grid step holds a chunk of every head twice over (6 MB at 32 heads of
-# 128, 10 with the state output) beside the states' 2: past the 16 MB a
-# kernel gets unasked
-_VMEM_LIMIT = 64 * 1024 * 1024
+# 128, 12 with what the forward rule keeps, 17 in the backward) beside the
+# 2 of the states or of their cotangents: past the 16 MB a kernel gets
+# unasked
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary"),  # sequences, chunks
+    vmem_limit_bytes=64 * 1024 * 1024)
 _HI = jax.lax.Precision.HIGHEST
 
 
@@ -187,7 +217,23 @@ def _scan_forward(q, k, v, g, beta, chunk, q_scale):
     return jnp.moveaxis(o, 0, 1).reshape(b, t, h, v.shape[-1])
 
 
-# ------------------------------------------------- the forward as a kernel
+# --------------------------------------------------- the two kernels
+#
+# Both work on one (sequence, chunk) a grid step, every head of it, a
+# PAIR of heads at a time. q/k/v/g/o refs are [1, C * heads, d] (a chunk
+# of the [B, T, H, d] arrays, rows token-major and head-minor); beta is
+# [heads / 2, 2 * C], a row a pair (the two heads' chunks side by side);
+# a state is [dv, dk]: TRANSPOSED, so that the per-channel decay runs
+# along the lanes.
+#
+# Everything between a pair's loads and its stores is values: the two
+# heads' chains are independent until then and the scheduler interleaves
+# them. The score planes are kept TRANSPOSED ([s, t]: the streamed
+# operand of a level's product is then the 64 columns, not the 128
+# stacked rows) and, from the inverse on, the pair's planes sit side by
+# side in one [C, 2 * C] plane, which a product takes against a
+# block-diagonal [2 * C, 2 * C] operand: full MXU tiles where one head's
+# [C, C] would fill a quarter.
 
 
 def _dot(a, b, contract):
@@ -196,6 +242,25 @@ def _dot(a, b, contract):
 
 
 _NN, _NT, _TN = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))
+
+
+def _iota(shape, axis):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+def _blocks(x):
+    """[X0 | X1] ([C, 2 C]) -> [[X0, 0], [0, X1]]."""
+    c = x.shape[0]
+    same_half = (_iota((2 * c, 2 * c), 0) < c) == (_iota((2 * c, 2 * c), 1) < c)
+    return jnp.where(same_half, jnp.concatenate([x, x], 0), 0.0)
+
+
+def _turned(x):
+    """A row [1, n] stood up as a column [n, 1], or a column laid down
+    (through the diagonal: no relayout)."""
+    n = max(x.shape)
+    return jnp.sum(jnp.where(_iota((n, n), 0) == _iota((n, n), 1), x, 0.0),
+                   axis=x.shape.index(n), keepdims=True)
 
 
 def _pair_planes(ref, pair, heads):
@@ -213,98 +278,130 @@ def _pair_planes(ref, pair, heads):
             pltpu.bitcast(words & jnp.uint32(0xFFFF0000), jnp.float32))
 
 
-def _fwd_kernel(*refs, q_scale, heads, keep_states):
-    """One (sequence, chunk) of the grid: every head of the chunk, a PAIR
-    at a time. q/k/v/g/o refs [1, C * heads, d] (a chunk of the
-    [B, T, H, d] arrays, rows token-major and head-minor), ``b_ref``
-    [heads / 2, 2 * C] a row of beta a pair (the two heads' chunks side
-    by side), the optional states [heads, dk, dv]; ``st_ref`` [heads, dv,
-    dk] holds the states, TRANSPOSED so that the per-channel decay runs
-    along the lanes.
+def _store_pair(ref, pair, heads, even, odd):
+    """``_pair_planes`` the other way: the pair's two [C, d] float32
+    planes into a block of the gradient; into a bfloat16 block rounded to
+    nearest even and packed, a word a pair of rows."""
+    at = lambda first, stride: (0, pl.ds(first, CHUNK, stride=stride))  # noqa: E731
+    if ref.dtype == jnp.float32:
+        ref[at(2 * pair, heads)] = even
+        ref[at(2 * pair + 1, heads)] = odd
+        return
 
-    Everything between a pair's loads and its stores is values: the two
-    heads' chains are independent until then and the scheduler
-    interleaves them. The score planes are kept TRANSPOSED ([s, t]: the
-    streamed operand of a level's product is then the 64 columns, not
-    the 128 stacked rows) and, from the inverse on, the pair's planes
-    sit side by side in one [C, 2 * C] plane, which a product takes
-    against a block-diagonal [2 * C, 2 * C] operand: full MXU tiles
-    where one head's [C, C] would fill a quarter."""
+    def rounded(x):                                 # bfloat16 in the high half
+        bits = pltpu.bitcast(x, jnp.uint32)
+        return bits + jnp.uint32(0x7FFF) + ((bits >> 16) & jnp.uint32(1))
+
+    ref.bitcast(jnp.uint32)[at(pair, heads // 2)] = (
+        rounded(even) >> 16) | (rounded(odd) & jnp.uint32(0xFFFF0000))
+
+
+def _running_sum(x, reverse=False):
+    """Along the chunk's tokens (rows), in log2(C) shifted adds; reversed,
+    token t gets the sum from t to the chunk's end."""
+    c = x.shape[0]
+    rows, step = _iota(x.shape, 0), 1
+    while step < c:
+        x = x + (jnp.where(rows < c - step, pltpu.roll(x, c - step, 0), 0.0)
+                 if reverse else
+                 jnp.where(rows >= step, pltpu.roll(x, step, 0), 0.0))
+        step *= 2
+    return x
+
+
+def _level_mask(c, half):
+    """[s, t] entries of a [C, 2 C] plane inside one block of 2 * half."""
+    lane, shift = _iota((c, 2 * c), 1), half.bit_length()
+    return (_iota((c, 2 * c), 0) >> shift) == ((lane & (c - 1)) >> shift)
+
+
+def _scores(hd, q, k, big):
+    """(G_C on every row, [A^T | P^T] for head 0 of the pair and
+    [P^T | A^T] for head 1: the pair's A planes then lie side by side
+    with no lane moved, each level's two decay planes). A and P below the
+    diagonal, level by level as _decayed_products: ``end`` is G at the
+    last token of a row's block of ``half`` tokens; a later half's rows
+    decay from the earlier half's end to their token (``lat``), the
+    earlier half's columns from their token to that end (``ear``). Every
+    level is ONE product over the whole chunk, of which the entries
+    inside a block of 2 * half are kept."""
+    c = q.shape[0]
+    rows = _iota(q.shape, 0)
+    first, second = (k, q) if hd == 0 else (q, k)
+    end, out, half, decays = big, jnp.zeros((c, 2 * c), jnp.float32), 1, []
+    while half < c:
+        later = (rows & half) != 0
+        lat = jnp.exp(jnp.where(later, big - pltpu.roll(end, half, 0), _NEG))
+        ear = jnp.exp(jnp.where(later, _NEG, end - big))
+        prod = _dot(k * ear,
+                    jnp.concatenate([first * lat, second * lat], 0), _NT)
+        out = out + jnp.where(_level_mask(c, half), prod, 0.0)
+        decays.append((ear, lat))
+        end = jnp.where(later, end, pltpu.roll(end, c - half, 0))
+        half *= 2
+    return end, out, decays
+
+
+def _pair_scores(q_scale, heads, pair, *refs):
+    """The pair's planes out of ``refs`` (q, k, v, g and whatever else
+    comes in such blocks), q scaled and g summed along the chunk, and its
+    score planes side by side: ([(q, k, v, G, *others, G_C, decays)] a
+    head, [A0^T | A1^T], [P1^T | P0^T] with P's diagonal)."""
+    c = CHUNK
+    planes = []
+    for hd, (q, k, v, g, *others) in enumerate(zip(*(
+            _pair_planes(ref, pair, heads) for ref in refs))):
+        q, big = q * q_scale, _running_sum(g)
+        end, s, decays = _scores(hd, q, k, big)
+        planes.append(((q, k, v, big, *others, end, decays), s,
+                       jnp.sum(q * k, axis=-1, keepdims=True)))
+    (head0, s0, qk0), (head1, s1, qk1) = planes
+    srow, lane = _iota((c, 2 * c), 0), _iota((c, 2 * c), 1)
+    left = lane < c
+    a_t = jnp.where(left, s0, s1)                    # [A0^T | A1^T]
+    p_t = jnp.where(left, s1, s0) + jnp.where(       # [P1^T | P0^T]
+        srow == (lane & (c - 1)), jnp.where(left, qk1, qk0), 0.0)
+    return (head0, head1), a_t, p_t
+
+
+def _pair_inverse(a_t, b_row):
+    """(I + A^T Diag(b))^-1 = (I - L)(I + L^2)(I + L^4)... for the pair,
+    [C, 2 C]: a stage squares the power and multiplies it into the
+    inverse in ONE product, [power; inverse] against the power's
+    blocks."""
+    c = a_t.shape[0]
+    eye = (_iota((c, 2 * c), 0) == (_iota((c, 2 * c), 1) & (c - 1))
+           ).astype(jnp.float32)                     # [I | I]
+    strict = a_t * b_row
+    inv, power = eye - strict, _dot(strict, _blocks(strict), _NN)
+    for _ in range(c.bit_length() - 3):
+        both = _dot(jnp.concatenate([power, inv], 0), _blocks(power), _NN)
+        inv, power = inv + both[c:], both[:c]
+    return inv + _dot(inv, _blocks(power), _NN)
+
+
+def _fwd_kernel(*refs, q_scale, heads, keep_states):
+    """``st_ref`` [heads, dv, dk] holds the states from grid step to grid
+    step; with ``keep_states`` (what a backward needs of this pass)
+    ``s_ref`` takes each head's state as the chunk finds it and
+    ``inv_ref`` [heads / 2, C, 2 C] each pair's inverse."""
     if keep_states:
-        q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, s_ref, st_ref = refs
+        q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, s_ref, inv_ref, st_ref = refs
     else:
         q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, st_ref = refs
-        s_ref = None
-    c, dk = CHUNK, q_ref.shape[-1]
+    c = CHUNK
 
     @pl.when(pl.program_id(1) == 0)
     def _():
         st_ref[...] = jnp.zeros_like(st_ref)
 
-    rows = jax.lax.broadcasted_iota(jnp.int32, (c, dk), 0)
-    srow = jax.lax.broadcasted_iota(jnp.int32, (c, 2 * c), 0)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (c, 2 * c), 1)
-    tcol, left = lane & (c - 1), lane < c
-    eye = (srow == tcol).astype(jnp.float32)       # [I | I]
-    row2 = jax.lax.broadcasted_iota(jnp.int32, (2 * c, 2 * c), 0)
-    col2 = jax.lax.broadcasted_iota(jnp.int32, (2 * c, 2 * c), 1)
-    same_half = (row2 < c) == (col2 < c)
-
-    def blocks(x):                  # [X0 | X1] -> [[X0, 0], [0, X1]]
-        return jnp.where(same_half, jnp.concatenate([x, x], 0), 0.0)
-
-    def scores(hd, q, k, big):
-        """(G_C on every row, [A^T | P^T] for head 0 of the pair and
-        [P^T | A^T] for head 1: the pair's A planes then lie side by
-        side with no lane moved). A and P below the diagonal, level by
-        level as _decayed_products: ``end`` is G at the last token of a
-        row's block of ``half`` tokens; a later half's rows decay from
-        the earlier half's end to their token, the earlier half's
-        columns from their token to that end. Every level is ONE product
-        over the whole chunk, of which the entries inside a block of
-        2 * half are kept."""
-        first, second = (k, q) if hd == 0 else (q, k)
-        end, out, half = big, jnp.zeros((c, 2 * c), jnp.float32), 1
-        while half < c:
-            later = (rows & half) != 0
-            lat = jnp.exp(jnp.where(later, big - pltpu.roll(end, half, 0), _NEG))
-            ear = jnp.exp(jnp.where(later, _NEG, end - big))
-            prod = _dot(k * ear,
-                        jnp.concatenate([first * lat, second * lat], 0), _NT)
-            shift = half.bit_length()
-            out = out + jnp.where(
-                (srow >> shift) == (tcol >> shift), prod, 0.0)
-            end = jnp.where(later, end, pltpu.roll(end, c - half, 0))
-            half *= 2
-        return end, out
-
     def one_pair(pair, carry):
-        planes = []
-        for hd, (q, k, v, big) in enumerate(zip(*(
-                _pair_planes(ref, pair, heads)
-                for ref in (q_ref, k_ref, v_ref, g_ref)))):
-            q = q * q_scale
-            step = 1
-            while step < c:                              # G_t: running sum
-                big = big + jnp.where(
-                    rows >= step, pltpu.roll(big, step, 0), 0.0)
-                step *= 2
-            planes.append((q, k, v, big, *scores(hd, q, k, big),
-                           jnp.sum(q * k, axis=-1, keepdims=True)))
-        (*_, s0, qk0), (*_, s1, qk1) = planes
-        a_t = jnp.where(left, s0, s1)                    # [A0^T | A1^T]
-        p_t = jnp.where(left, s1, s0) + jnp.where(       # [P1^T | P0^T]
-            srow == tcol, jnp.where(left, qk1, qk0), 0.0)
-        # (I + A^T Diag(b))^-1 = (I - L)(I + L^2)(I + L^4)...: a stage
-        # squares the power and multiplies it into the inverse in ONE
-        # product, [power; inverse] against the power's blocks
+        planes, a_t, p_t = _pair_scores(
+            q_scale, heads, pair, q_ref, k_ref, v_ref, g_ref)
         b_row = b_ref[pl.ds(pair, 1), :]                 # [1, 2 C]
-        strict = a_t * b_row
-        inv, power = eye - strict, _dot(strict, blocks(strict), _NN)
-        for _ in range(c.bit_length() - 3):
-            both = _dot(jnp.concatenate([power, inv], 0), blocks(power), _NN)
-            inv, power = inv + both[c:], both[:c]
-        inv = inv + _dot(inv, blocks(power), _NN)
+        inv = _pair_inverse(a_t, b_row)
+        if keep_states:
+            inv_ref[pair] = inv
         states, rhs, out = [], [], []
         for hd, (q, k, v, big, *_) in enumerate(planes):
             st, from_start = st_ref[2 * pair + hd], jnp.exp(big)
@@ -313,18 +410,16 @@ def _fwd_kernel(*refs, q_scale, heads, keep_states):
             states.append(st)
             rhs.append(v - with_state[:c])
             out.append(with_state[c:])
-        # [u0; u1] = blocks(T) [b0 r0; b1 r1] (beta stood up as a column
-        # through the diagonal), then [P1 u1; P0 u0]
-        b_col = jnp.sum(jnp.where(row2 == col2, b_row, 0.0), axis=1,
-                        keepdims=True)
-        u = _dot(blocks(inv), b_col * jnp.concatenate(rhs, 0), _TN)
-        pu = _dot(blocks(p_t), jnp.concatenate([u[c:], u[:c]], 0), _TN)
-        for hd, (q, k, v, big, end, *_) in enumerate(planes):
+        # [u0; u1] = blocks(T) [b0 r0; b1 r1] (beta stood up as a
+        # column), then [P1 u1; P0 u0]
+        u = _dot(_blocks(inv), _turned(b_row) * jnp.concatenate(rhs, 0), _TN)
+        pu = _dot(_blocks(p_t), jnp.concatenate([u[c:], u[:c]], 0), _TN)
+        for hd, (q, k, v, big, end, _) in enumerate(planes):
             head = 2 * pair + hd
             o_ref[0, pl.ds(head, c, stride=heads), :] = out[hd] + (
                 pu[c:] if hd == 0 else pu[:c])
             if keep_states:
-                s_ref[head] = states[hd].T
+                s_ref[head] = states[hd]
             st_ref[head] = jnp.exp(end[:1]) * states[hd] + _dot(
                 u[hd * c:(hd + 1) * c], k * jnp.exp(end - big), _TN)
         return carry
@@ -332,92 +427,251 @@ def _fwd_kernel(*refs, q_scale, heads, keep_states):
     jax.lax.fori_loop(0, heads // 2, one_pair, 0)
 
 
-def _kernel_forward(q, k, v, g, beta, q_scale, keep_states, interpret):
-    """The forward pass as one ``pallas_call``; T a multiple of CHUNK,
-    dk and dv multiples of 128. Returns (o, states): o [B, T, H, dv]
-    float32, states [n, B, H, dk, dv] (each chunk's STARTING state) or
-    None. An odd head count gains a head that neither decays nor
-    writes."""
-    heads = q.shape[2]
-    if heads % 2:
-        widths = ((0, 0), (0, 0), (0, 1), (0, 0))
-        q, k, v, g = (jnp.pad(x, widths) for x in (q, k, v, g))
-        beta = jnp.pad(beta, widths[:3])
-    b, t, h, dk = q.shape
-    dv, n = v.shape[-1], t // CHUNK
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, s_ref, inv_ref,
+                dq_ref, dk_ref, dv_ref, dg_ref, db_ref, dst_ref, *,
+                q_scale, heads):
+    """The grid's chunks come LAST FIRST. ``s_ref`` [heads, dv, dk] is
+    the state each head's chunk started from and ``inv_ref`` [heads / 2,
+    C, 2 C] each pair's inverse (what the forward rule wrote);
+    ``dst_ref`` [heads, dv, dk] holds the cotangent of the state the
+    chunk ends with, zero at a sequence's last chunk, and leaves this grid
+    step as the cotangent of the state it started from.
+
+    First the forward again, less the inverse, P u and the output: G,
+    the score planes by the same levels, rhs = v - (k e^G) S and
+    u = M^-1 (b rhs), M = I + Diag(b) A. Then, with dS' the cotangent in
+    ``dst_ref`` and K' = k e^{G_C - G}:
+
+        du   = P^T do + K' dS'                dP = do u^T (s <= t)
+        dw   = M^-T du    dM = -dw u^T    dA = Diag(b) dM (s < t)
+        drhs = b dw = dv  dbeta_t = sum_s dM_ts A_ts + dw_t . rhs_t
+        [d(q e^G); d(k e^G)] = [do; -drhs] S^T,        dK' = u dS'^T
+        dS   = e^{G_C} dS' + [q e^G; k e^G]^T [do; -drhs]
+
+    (the inverse differentiated in closed form: two products where the
+    transposed chain has twenty), dA and dP back through the levels, two
+    transposed products a level. Every decayed operand x e^E gives
+    (x e^E) d(x e^E) to dE; E is a difference of two values of G (a
+    level's reference token gets the same sum with both signs: left
+    out), so a dG per token and channel is gathered, G_C's share too, and
+    dg is its reversed running sum."""
+    c = CHUNK
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        dst_ref[...] = jnp.zeros_like(dst_ref)
+
+    left = _iota((c, 2 * c), 1) < c
+
+    def one_pair(pair, carry):
+        planes, a_t, p_t = _pair_scores(
+            q_scale, heads, pair, q_ref, k_ref, v_ref, g_ref, do_ref)
+        b_row = b_ref[pl.ds(pair, 1), :]                 # [1, 2 C]
+        b_col = _turned(b_row)
+        inv = _blocks(inv_ref[pair])
+        do0, do1 = planes[0][4], planes[1][4]
+        # [P1^T do1; P0^T do0]
+        pt_do = _dot(_blocks(p_t), jnp.concatenate([do1, do0], 0), _NN)
+        rhs, du, decayed = [], [], []
+        for hd, (q, k, v, big, do, end, _) in enumerate(planes):
+            st, dst = s_ref[2 * pair + hd], dst_ref[2 * pair + hd]
+            from_start, to_end = jnp.exp(big), jnp.exp(end - big)
+            q_in, k_in, k_out = q * from_start, k * from_start, k * to_end
+            rhs.append(v - _dot(k_in, st, _NT))
+            du.append((pt_do[c:] if hd == 0 else pt_do[:c])
+                      + _dot(k_out, dst, _NT))
+            decayed.append((st, dst, from_start, to_end, q_in, k_in, k_out))
+        rhs = jnp.concatenate(rhs, 0)
+        u = _dot(inv, b_col * rhs, _TN)                  # [u0; u1]
+        dw = _dot(inv, jnp.concatenate(du, 0), _NN)      # M^-T du
+        drhs = b_col * dw
+        # u0 and u1 against [-dw0; do0; do1; -dw1]: rows of u0 give
+        # [dM0^T | dP0^T], rows of u1 [dP1^T | dM1^T]: head by head the
+        # layout of _scores' planes
+        both = _dot(
+            u, jnp.concatenate([-dw[:c], do0, do1, -dw[c:]], 0), _NT)
+        d0, d1 = both[:c, :2 * c], both[c:, 2 * c:]
+        db_ref[pl.ds(pair, 1), :] = jnp.sum(
+            jnp.where(left, d0, d1) * a_t, axis=0, keepdims=True) + _turned(
+                jnp.sum(dw * rhs, axis=1, keepdims=True))
+        d_scores = (jnp.where(left, d0 * b_row, d0),     # [dA0^T | dP0^T]
+                    jnp.where(left, d1, d1 * b_row))     # [dP1^T | dA1^T]
+        grads = []
+        for hd, (q, k, v, big, do, end, levels) in enumerate(planes):
+            st, dst, from_start, to_end, q_in, k_in, k_out = decayed[hd]
+            u_h, down = u[hd * c:(hd + 1) * c], jnp.concatenate(
+                [do, -drhs[hd * c:(hd + 1) * c]], 0)
+            d_in = _dot(down, st, _NN)         # [d(q e^G); d(k e^G)]
+            d_out = _dot(u_h, dst, _NN)        # d(k e^{G_C - G})
+            dst_ref[2 * pair + hd] = jnp.exp(end[:1]) * dst + _dot(
+                down, jnp.concatenate([q_in, k_in], 0), _TN)
+            # the levels: a level's product (k ear) x [first lat;
+            # second lat]^T, transposed twice
+            first, second = (k, q) if hd == 0 else (q, k)
+            d_col = d_first = d_second = jnp.zeros_like(k)
+            half = 1
+            for ear, lat in levels:
+                d_prod = jnp.where(_level_mask(c, half), d_scores[hd], 0.0)
+                d_col = d_col + ear * _dot(d_prod, jnp.concatenate(
+                    [first * lat, second * lat], 0), _NN)
+                d_rows = _dot(d_prod, k * ear, _TN)
+                d_first = d_first + lat * d_rows[:c]
+                d_second = d_second + lat * d_rows[c:]
+                half *= 2
+            diag = jnp.sum(do * u_h, axis=-1, keepdims=True)   # dP_tt
+            d_k, d_q = (d_first, d_second) if hd == 0 else (d_second, d_first)
+            dq = d_in[:c] * from_start + diag * k + d_q
+            dk = d_in[c:] * from_start + d_out * to_end + diag * q \
+                + d_col + d_k
+            dbig = q_in * d_in[:c] + k_in * d_in[c:] - k_out * d_out \
+                + first * d_first + second * d_second - k * d_col
+            at_end = jnp.sum(k_out * d_out, axis=0, keepdims=True) \
+                + jnp.exp(end[:1]) * jnp.sum(st * dst, axis=0, keepdims=True)
+            grads.append((dq * q_scale, dk, drhs[hd * c:(hd + 1) * c],
+                          _running_sum(dbig, reverse=True) + at_end))
+        for ref, even, odd in zip((dq_ref, dk_ref, dv_ref, dg_ref), *grads):
+            _store_pair(ref, pair, heads, even, odd)
+        return carry
+
+    jax.lax.fori_loop(0, heads // 2, one_pair, 0)
+
+
+def _even_heads(*arrays):
+    """An odd head count gains a head (axis 2) of zeros: one that neither
+    decays nor writes, and whose cotangents are zero."""
+    if arrays[0].shape[2] % 2 == 0:
+        return arrays
+    return tuple(jnp.pad(x, ((0, 0), (0, 0), (0, 1)) + ((0, 0),) * (x.ndim - 3))
+                 for x in arrays)
+
+
+def _kernel_operands(q, k, v, g, beta, reverse=False):
+    """What both kernels take of the (even-headed) inputs: (the arrays as
+    [B, T * H, d] views and a pair's beta chunk by chunk, the block of a
+    [B, T * H, d] array, beta's block, the blocks of what the forward
+    rule keeps for the backward: the states and the inverses). With
+    ``reverse`` grid step m is chunk n - 1 - m."""
+    b, t, h, _ = q.shape
+    n = t // CHUNK
+    chunk = (lambda m: n - 1 - m) if reverse else (lambda m: m)
     q, k, v = (x if x.dtype == jnp.bfloat16 else x.astype(jnp.float32)
                for x in (q, k, v))
     # a chunk of [B, T, H, d] as it lies: [C * H, d], no copy where H
     # fills the sublane tiles (8 rows of float32, 16 of bfloat16)
     rows = lambda x: x.reshape(b, t * h, x.shape[-1])  # noqa: E731
     spec = lambda d: pl.BlockSpec(  # noqa: E731
-        (1, CHUNK * h, d), lambda i, m: (i, m, 0), memory_space=pltpu.VMEM)
+        (1, CHUNK * h, d), lambda i, m: (i, chunk(m), 0),
+        memory_space=pltpu.VMEM)
     # a pair's beta, chunk by chunk: [B, n, H / 2, 2 * C]
     b_rows = jnp.moveaxis(
         beta.reshape(b, n, CHUNK, h // 2, 2), 2, 4).reshape(
             b, n, h // 2, 2 * CHUNK)
+    b_spec = pl.BlockSpec((None, None, h // 2, 2 * CHUNK),
+                          lambda i, m: (i, chunk(m), 0, 0),
+                          memory_space=pltpu.VMEM)
+    kept_specs = [
+        pl.BlockSpec((None, None, h, v.shape[-1], q.shape[-1]),
+                     lambda i, m: (chunk(m), i, 0, 0, 0),
+                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((None, None, h // 2, CHUNK, 2 * CHUNK),
+                     lambda i, m: (i, chunk(m), 0, 0, 0),
+                     memory_space=pltpu.VMEM)]
+    return ((rows(q), rows(k), rows(v), rows(g), b_rows), spec, b_spec,
+            kept_specs)
+
+
+# A ``pallas_call`` traces its kernel body every time it is called, and a
+# trace of the step calls these two wrappers six times a KDA layer (the
+# pass, the layer's remat and its linearisation, the backward): seconds
+# of host time a body at 32 heads, 28 s of a 102 s set-up (PERF.md,
+# PR 31). Under ``jit`` every call of one shape shares one trace;
+# ``inline`` leaves no call in the program, which is what it was.
+@functools.partial(jax.jit, inline=True,
+                   static_argnames=("q_scale", "keep_states", "interpret"))
+def _kernel_forward(q, k, v, g, beta, q_scale, keep_states, interpret):
+    """The forward pass as one ``pallas_call``; T a multiple of CHUNK,
+    dk and dv multiples of 128. Returns (o, kept): o [B, T, H, dv]
+    float32; kept, of the even head count H' the kernel works on, each
+    chunk's STARTING states [n, B, H', dv, dk] (transposed) and inverses
+    [B, n, H' / 2, C, 2 C], or None."""
+    heads = q.shape[2]
+    q, k, v, g, beta = _even_heads(q, k, v, g, beta)
+    b, t, h, dk = q.shape
+    dv, n = v.shape[-1], t // CHUNK
+    operands, spec, b_spec, kept_specs = _kernel_operands(q, k, v, g, beta)
     out_specs = [spec(dv)]
     out_shape = [jax.ShapeDtypeStruct((b, t * h, dv), jnp.float32)]
     if keep_states:
-        out_specs.append(pl.BlockSpec(
-            (None, None, h, dk, dv), lambda i, m: (m, i, 0, 0, 0),
-            memory_space=pltpu.VMEM))
-        out_shape.append(
-            jax.ShapeDtypeStruct((n, b, h, dk, dv), jnp.float32))
+        out_specs += kept_specs
+        out_shape += [
+            jax.ShapeDtypeStruct((n, b, h, dv, dk), jnp.float32),
+            jax.ShapeDtypeStruct((b, n, h // 2, CHUNK, 2 * CHUNK), jnp.float32)]
     out = pl.pallas_call(
         functools.partial(_fwd_kernel, q_scale=q_scale, heads=h,
                           keep_states=keep_states),
         grid=(b, n),
-        in_specs=[spec(dk), spec(dk), spec(dv), spec(dk),
-                  pl.BlockSpec((None, None, h // 2, 2 * CHUNK),
-                               lambda i, m: (i, m, 0, 0),
-                               memory_space=pltpu.VMEM)],
+        in_specs=[spec(dk), spec(dk), spec(dv), spec(dk), b_spec],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((h, dv, dk), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=_VMEM_LIMIT),
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
         name=KERNEL_NAME,
-    )(rows(q), rows(k), rows(v), rows(g), b_rows)
+    )(*operands)
     o = out[0].reshape(b, t, h, dv)[:, :, :heads]
-    return o, (out[1][:, :, :heads] if keep_states else None)
+    return o, (tuple(out[1:]) if keep_states else None)
+
+
+@functools.partial(jax.jit, inline=True,
+                   static_argnames=("q_scale", "interpret"))
+def _kernel_backward(q, k, v, g, beta, kept, do, q_scale, interpret):
+    """The backward pass as one ``pallas_call`` over the chunks in
+    reverse, from what the forward rule kept: the cotangents of q, k, v
+    (in their types), g and beta (float32)."""
+    heads, types = q.shape[2], (q.dtype, k.dtype, v.dtype)
+    q, k, v, g, beta, do = _even_heads(q, k, v, g, beta, do)
+    b, t, h, dk = q.shape
+    dv, n = v.shape[-1], t // CHUNK
+    operands, spec, b_spec, kept_specs = _kernel_operands(
+        q, k, v, g, beta, reverse=True)
+    # the interpreter cannot store through a block's 32-bit view: there a
+    # bfloat16 gradient leaves the kernel as float32 and is rounded after
+    like = lambda x: jax.ShapeDtypeStruct(  # noqa: E731
+        x.shape, jnp.float32 if interpret else x.dtype)
+    *grads, db_rows = pl.pallas_call(
+        functools.partial(_bwd_kernel, q_scale=q_scale, heads=h),
+        grid=(b, n),
+        in_specs=[spec(dk), spec(dk), spec(dv), spec(dk), b_spec, spec(dv),
+                  *kept_specs],
+        out_specs=[spec(dk), spec(dk), spec(dv), spec(dk), b_spec],
+        out_shape=[like(x) for x in operands],
+        scratch_shapes=[pltpu.VMEM((h, dv, dk), jnp.float32)],
+        compiler_params=_COMPILER_PARAMS,
+        interpret=interpret,
+        name=BACKWARD_KERNEL_NAME,
+    )(*operands, do.reshape(b, t * h, dv), *kept)
+    dq, dk_, dv_, dg = (
+        x.reshape(b, t, h, x.shape[-1])[:, :, :heads] for x in grads)
+    dbeta = jnp.moveaxis(
+        db_rows.reshape(b, n, h // 2, 2, CHUNK), 4, 2).reshape(b, t, h)
+    return (*(x.astype(dt) for x, dt in zip((dq, dk_, dv_), types)), dg,
+            dbeta[:, :, :heads])
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
 def _kernel_path(q, k, v, g, beta, q_scale, interpret):
-    return _kernel_forward(q, k, v, g, beta, q_scale, False, interpret)[0]
+    return _kernel_forward(q, k, v, g, beta, q_scale=q_scale,
+                           keep_states=False, interpret=interpret)[0]
 
 
 def _kernel_path_fwd(q, k, v, g, beta, q_scale, interpret):
-    o, states = _kernel_forward(q, k, v, g, beta, q_scale, True, interpret)
-    return o, (q, k, v, g, beta, states)
+    o, kept = _kernel_forward(q, k, v, g, beta, q_scale=q_scale,
+                              keep_states=True, interpret=interpret)
+    return o, (q, k, v, g, beta, kept)
 
 
 def _kernel_path_bwd(q_scale, interpret, res, do):
-    """The plain path's backward: the ``_chunk`` body's VJP, chunk by
-    chunk from the last, each recomputed from its saved starting state.
-    The chunks are sliced out of, and the gradients written into, the
-    [B, T, ...] arrays where they lie: no chunk-major copy is made."""
-    *inputs, states = res
-
-    def body(j, carry):
-        dstate, grads = carry
-        i = states.shape[0] - 1 - j
-        piece = lambda x: jax.lax.dynamic_slice_in_dim(  # noqa: E731
-            x, i * CHUNK, CHUNK, axis=1)
-        _, vjp = jax.vjp(lambda s, *a: _chunk(s, *a, q_scale),
-                         states[i], *(piece(x) for x in inputs))
-        dstate, *dinputs = vjp((dstate, piece(do)))
-        return dstate, tuple(
-            jax.lax.dynamic_update_slice_in_dim(full, d, i * CHUNK, axis=1)
-            for full, d in zip(grads, dinputs))
-
-    _, grads = jax.lax.fori_loop(
-        0, states.shape[0], body,
-        (jnp.zeros_like(states[0]), tuple(jnp.zeros_like(x) for x in inputs)))
-    return grads
+    return _kernel_backward(*res, do, q_scale=q_scale, interpret=interpret)
 
 
 # optimize_remat: under a layer's remat the pass that keeps no residuals
@@ -450,9 +704,11 @@ def kda_chunked(q, k, v, g, beta, chunk: int = CHUNK, q_scale: float = 1.0,
     g [B, T, H, dk] (log decay, <= 0), beta [B, T, H]. ``T`` need not be
     a multiple of ``chunk``: the tail is padded with tokens that neither
     decay nor write (g = 0, beta = 0, k = 0) and their outputs are
-    dropped. Which forward runs is ``kda_path``'s answer; ``interpret``
-    is for tests (True: the kernel, interpreted, off the TPU; False: the
-    kernel compiled, for a TPU that is described and not attached).
+    dropped. Which path runs, forward and backward (the two kernels, or
+    the scan and its transposition), is ``kda_path``'s answer;
+    ``interpret`` is for tests (True: the kernels, interpreted, off the
+    TPU; False: the kernels compiled, for a TPU that is described and not
+    attached).
     """
     if chunk & (chunk - 1):
         raise ValueError(f"chunk={chunk} must be a power of two")

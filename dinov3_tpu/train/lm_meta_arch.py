@@ -49,7 +49,7 @@ class LMMetaArch:
         path, why = kda_path(dc.kda_head_dim, dc.kda_head_dim)
         for i, (mixer, _) in enumerate(dc.layers, 1):
             if mixer == "kda":
-                logger.info("layer %d kda_core forward: %s (%s)", i, path, why)
+                logger.info("layer %d kda_core, both passes: %s (%s)", i, path, why)
 
     def init_params(self, rng: jax.Array, batch: dict, unbox: bool = True) -> dict:
         import flax.linen as nn

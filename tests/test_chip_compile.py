@@ -2,8 +2,8 @@
 attached (on-chip-measurement guide §2.3): flash attention forward and
 backward, plain and segment-masked, at the 768 px (N=2309) and 1024 px
 (N=4101) ViT-L token counts, the fused layernorm forward and
-backward at ViT-L width, and the delta rule's chunk forward at the
-decoder cell's shapes — each with ``interpret=False``, each asserting
+backward at ViT-L width, and the delta rule's chunk forward and
+backward at the decoder cell's shapes — each with ``interpret=False``, each asserting
 a Mosaic ``tpu_custom_call`` in the compiled text. What the chip's
 compiler would refuse (a slice off the tiling, too much VMEM) fails
 here, at no chip time. A compile that passes is not a chip run.
@@ -98,12 +98,17 @@ def test_fused_layernorm_compiles_for_v5e(one_chip, direction):
 
 
 @pytest.mark.parametrize("states", [False, True], ids=["primal", "states"])
-def test_kda_chunk_forward_compiles_for_v5e(one_chip, states):
-    """``ops/kda.py``'s forward kernel at the decoder cell's shapes
-    (2 sequences of 8,192 tokens, 32 heads of 128 x 128): the primal,
-    and the pass that also writes each chunk's starting state (with the
-    plain backward behind it)."""
-    from dinov3_tpu.ops.kda import KERNEL_NAME, kda_chunked
+def test_kda_chunk_kernels_compile_for_v5e(one_chip, states):
+    """``ops/kda.py``'s kernels at the decoder cell's shapes (2 sequences
+    of 8,192 tokens, 32 heads of 128 x 128, bfloat16 q, k, v): the
+    primal, and the gradient's program: the forward rule, which also
+    writes each chunk's starting state, and the backward kernel behind
+    it, which packs its bfloat16 gradients a pair of heads a word."""
+    from dinov3_tpu.ops.kda import (
+        BACKWARD_KERNEL_NAME,
+        KERNEL_NAME,
+        kda_chunked,
+    )
 
     act = ((2, 8192, 32, 128), jnp.bfloat16)
     g, beta, o = (act[0], jnp.float32), ((2, 8192, 32), jnp.float32), \
@@ -118,6 +123,12 @@ def test_kda_chunk_forward_compiles_for_v5e(one_chip, states):
     fn, shapes = (bwd, [act] * 3 + [g, beta, o]) if states else (
         fwd, [act] * 3 + [g, beta])
     text = _compiled_text(fn, one_chip, *shapes)
-    assert "tpu_custom_call" in text and KERNEL_NAME in text
-    if states:  # the [128, 2, 32, 128, 128] float32 starting states
+    assert KERNEL_NAME in text
+    assert text.count("tpu_custom_call") == (2 if states else 1)
+    assert (BACKWARD_KERNEL_NAME in text) == states
+    if states:
+        # what the forward rule keeps: the [128, 2, 32, 128, 128] float32
+        # starting states and the [2, 128, 16, 64, 128] inverses; no loop
+        # over the chunks beside the kernels
         assert "f32[128,2,32,128,128]" in text
+        assert "f32[2,128,16,64,128]" in text and " while(" not in text

@@ -145,10 +145,10 @@ def _grad_gap(got, want):
 
 @pytest.mark.parametrize("t", [128, 192, 100])
 def test_kda_kernel_is_the_recurrence_and_the_plain_path(t):
-    """The Pallas forward (interpreted) at widths it takes, over whole
-    chunks, three chunks and a padded tail: the token recurrence's and
-    the plain scan's output and all five gradients (its backward IS the
-    plain path's, from the starting states the kernel wrote)."""
+    """The two Pallas kernels (interpreted) at widths they take, over
+    whole chunks, three chunks and a padded tail: the token recurrence's
+    and the plain scan's output and all five gradients (the backward
+    kernel works from the starting states the forward rule wrote)."""
     from dinov3_tpu.ops.kda import kda_chunked, kda_path, kda_recurrent
 
     assert kda_path(128, 128, interpret=True)[0] == "kernel"
@@ -172,8 +172,8 @@ def test_kda_kernel_is_the_recurrence_and_the_plain_path(t):
 @pytest.mark.parametrize("decay", ["published", "fast", "spikes"])
 def test_kda_kernel_is_finite_and_exact_at_any_decay(decay):
     """``test_delta_rule_is_finite_and_exact_at_any_decay``'s three
-    regimes through the kernel: its levels keep every factor at most 1
-    as the plain path's do."""
+    regimes through the two kernels: their levels keep every factor at
+    most 1 as the plain path's do, backward as forward."""
     from dinov3_tpu.ops.kda import kda_chunked, kda_recurrent
 
     q, k, v, g, beta = _kda_inputs(3, 1, 128, 2, 128, 128)
@@ -202,6 +202,74 @@ def test_kda_kernel_is_finite_and_exact_at_any_decay(decay):
         assert bool(jnp.isfinite(got_g).all())
         assert float(jnp.max(jnp.abs(got_g - want_g))) <= loose * 2e-5 * float(
             jnp.max(jnp.abs(want_g))) + 1e-7
+
+
+@pytest.mark.parametrize("case", ["odd_heads", "bf16", "one_chunk"])
+def test_kda_backward_kernel_cases(case):
+    """What the backward kernel's blocking could get wrong: an odd head
+    count (the head the kernels add gets no gradient out), bfloat16
+    q, k, v (their gradients come back bfloat16, the recurrence's within a
+    rounding), and three sequences of ONE chunk (the state's cotangent is
+    zeroed a sequence, not a call)."""
+    from dinov3_tpu.ops.kda import kda_chunked, kda_recurrent
+
+    b, t, h = {"odd_heads": (1, 128, 3), "bf16": (2, 128, 2),
+               "one_chunk": (3, 64, 2)}[case]
+    x = _kda_inputs(7, b, t, h, 128, 128)
+    if case == "bf16":
+        x = tuple(a.astype(jnp.bfloat16) for a in x[:3]) + x[3:]
+
+    def grads(fn):
+        return jax.jit(jax.grad(
+            lambda *a: jnp.sum(jnp.sin(fn(*a))), argnums=(0, 1, 2, 3, 4)))(*x)
+
+    got = grads(lambda *a: kda_chunked(*a, interpret=True))
+    for g, w, a in zip(got, grads(kda_recurrent), x):
+        assert g.shape == a.shape and g.dtype == a.dtype
+        if g.dtype == jnp.bfloat16:      # half a unit in the last place
+            assert float(jnp.max(jnp.abs(
+                g.astype(jnp.float32) - w.astype(jnp.float32)))) <= 2 ** -8 * float(
+                    jnp.max(jnp.abs(w.astype(jnp.float32))))
+        else:
+            assert _grad_gap(g, w) <= 0
+
+
+def _loops_and_kernels(jaxpr, found):
+    """Names of the Pallas calls, and the loops OUTSIDE them, of a
+    program."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn.params["name"])
+            continue
+        if eqn.primitive.name in ("while", "scan"):
+            found.append(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _loops_and_kernels(sub, found)
+    return found
+
+
+def test_kda_gradient_program_is_two_forward_kernels_and_one_backward():
+    """A rematerialised layer's gradient on the kernel path: the primal
+    pass, the forward rule again (it writes the states) and ONE backward
+    kernel, no loop over chunks beside them; on the scan path the three
+    loops and no kernel."""
+    from dinov3_tpu.ops.kda import (
+        BACKWARD_KERNEL_NAME,
+        KERNEL_NAME,
+        kda_chunked,
+    )
+
+    x = _kda_inputs(0, 1, 128, 2, 128, 128)
+
+    def program(**kw):
+        layer = jax.checkpoint(lambda *a: kda_chunked(*a, **kw))
+        return sorted(_loops_and_kernels(jax.make_jaxpr(jax.grad(
+            lambda *a: jnp.sum(layer(*a)), argnums=(0, 1, 2, 3, 4)))(*x).jaxpr,
+            []))
+
+    assert program(interpret=True) == [
+        BACKWARD_KERNEL_NAME, KERNEL_NAME, KERNEL_NAME]
+    assert program() == ["scan"] * 3
 
 
 def test_kda_dispatch_reads_the_path_off_the_input():
@@ -541,6 +609,51 @@ def test_compiled_step_holds_the_decoder_phases(tiny_setup):
                          ("moe_ffn", "moe_shared")):
         assert any(phase in n and f"/{inner}/" in n for n in names), inner
     assert set(LM_STEP_PHASES) < set(STEP_PHASES)
+
+
+def test_step_on_the_kernel_path_holds_one_backward_call_a_kda_layer(monkeypatch):
+    """The decoder's whole step, traced with ``kda_path`` answering
+    "kernel" (heads of 128 x 128; the test steers, the program has no
+    option): every KDA layer gives its primal pass, the forward rule again
+    under the layer's remat and ONE backward kernel, and no KDA layer a
+    loop over chunks (the loops left are MLA's and the loss blocks');
+    the kernel bodies are traced once a shape, not once a call."""
+    from dinov3_tpu.data import make_synthetic_batch
+    from dinov3_tpu.ops import kda
+    from dinov3_tpu.train import build_train_setup
+
+    def names(extra):
+        cfg = tiny_cfg(extra)
+        batch = make_synthetic_batch(cfg, 2, seed=0)
+        setup = build_train_setup(
+            cfg, {k: jnp.asarray(v) for k, v in batch.items()},
+            devices=jax.devices()[:1], init_state=False)
+        args = (setup.state, {k: jnp.asarray(v) for k, v in batch.items()},
+                setup.scalars(0), jax.random.key(0))
+        n_kda = sum(mixer == "kda" for mixer, _ in setup.meta.student_backbone.cfg.layers)
+        return n_kda, _loops_and_kernels(
+            jax.make_jaxpr(setup.step_fn)(*args).jaxpr, [])
+
+    n_kda, plain = names([])
+    assert not [n for n in plain if n.startswith("kda_chunk")]
+    monkeypatch.setattr(kda, "kda_path",
+                        lambda *a, **k: ("kernel", "the test says so"))
+    bodies = []
+    for body in ("_fwd_kernel", "_bwd_kernel"):
+        def counted(*a, _body=getattr(kda, body), **k):
+            bodies.append(_body.__name__)
+            return _body(*a, **k)
+        monkeypatch.setattr(kda, body, counted)
+    n_kda, found = names(["lm.kda_head_dim=128", "lm.seq_len=128"])
+    assert found.count(kda.BACKWARD_KERNEL_NAME) == n_kda
+    assert found.count(kda.KERNEL_NAME) == 2 * n_kda
+    # the layers share ONE trace of each kernel body a shape (the pass,
+    # the forward rule, the backward), whatever their number: a body
+    # costs seconds of host time to trace at 32 heads (PERF.md, PR 31)
+    assert sorted(bodies) == ["_bwd_kernel", "_fwd_kernel", "_fwd_kernel"]
+    # the scan path's three loops a KDA layer are gone, nothing else moved
+    loops = lambda xs: sum(n in ("scan", "while") for n in xs)  # noqa: E731
+    assert loops(plain) - loops(found) == 3 * n_kda
 
 
 def test_benchmark_vocabulary_of_the_decoder_is_the_programs():
