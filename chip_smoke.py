@@ -2,7 +2,8 @@
 
     python chip_smoke.py            # one TPU chip: trainer, the step under
                                     # accumulation, kernels, serve, the
-                                    # decoder's next-token trainer
+                                    # decoder's next-token trainer, the
+                                    # banded grouped-query attention core
     python chip_smoke.py --phases lm   # one chip, that phase alone
     python chip_smoke.py --chips 4  # four chips: ONLY the sharded train
                                     # arms and their one-device comparison
@@ -75,6 +76,11 @@ SIZES = {
     # the delta rule at published width: [B, T, heads, d_k = d_v]
     "kda_shape": (1, 1024, 4, 128),
     "kernel_interpret": False,
+    # the window layers' core at published sizes: [B, T, query heads,
+    # key/value heads, head_dim, window]; the (block_q, block_kv) pairs
+    # timed beside the shipped 512 x 1,024 (information: why it stays)
+    "gqa_shape": (1, 16384, 28, 4, 128, 4096),
+    "gqa_blocks": [(1024, 1024), (1024, 2048)],
     "serve_overrides": ["student.arch=vit_large", "student.patch_size=16",
                         "train.scan_layers=true"],
     # mixed resolutions inside the default 96..512 px envelope
@@ -82,7 +88,7 @@ SIZES = {
                         (384, 384), (224, 224), (128, 128), (448, 320)],
 }
 
-ONE_CHIP_PHASES = ("trainer", "accum", "kernels", "serve", "lm")
+ONE_CHIP_PHASES = ("trainer", "accum", "kernels", "serve", "lm", "gqa")
 
 _T0 = time.time()
 
@@ -446,6 +452,89 @@ def _kda_kernel_row(interpret: bool) -> None:
         found[0], *found[4:]) <= 1e-4 and max(found[1:4]) <= 2 ** -8, found
 
 
+# ------------------------------------------- the banded grouped-query core
+
+def phase_gqa() -> None:
+    """``ops/attention.py causal_blockwise_attention`` with a window and
+    grouped heads, as the ``smallthinker`` decoder's layers call it
+    (``dispatch_attention(..., causal=True, window=W)`` and ``window=
+    None``), at the published head sizes and the whole context, against
+    the dense masked softmax in float32 (whole rows of keys, k and v
+    repeated for every query head, a block of queries at a time so that it
+    fits): the output and the three gradients. Then forward + backward
+    timed, and beside it the tiles at other block sizes."""
+    import jax
+    import jax.numpy as jnp
+
+    from dinov3_tpu.ops.attention import (
+        causal_blockwise_attention,
+        dispatch_attention,
+    )
+
+    b, t, h, hk, d, window = SIZES["gqa_shape"]
+    ks = jax.random.split(jax.random.key(2), 3)
+    q = jax.random.normal(ks[0], (b, t, h, d), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (b, t, hk, d), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (b, t, hk, d), jnp.bfloat16)
+
+    def dense(w, rows=256):
+        def fn(q, k, v):
+            q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+            k, v = (jnp.repeat(x, h // hk, axis=2) for x in (k, v))
+            pad = (-t) % rows
+            qp = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
+
+            @jax.checkpoint
+            def block(args):
+                qb, first = args
+                z = jnp.einsum("bqhd,bkhd->bhqk", qb, k) * d ** -0.5
+                at = jnp.minimum(first + jnp.arange(rows), t - 1)[:, None]
+                key = jnp.arange(t)[None, :]
+                seen = key <= at if w is None else (key <= at) & (key > at - w)
+                p = jax.nn.softmax(jnp.where(seen, z, -jnp.inf), -1)
+                return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+            blocks = jnp.moveaxis(qp.reshape(b, -1, rows, h, d), 1, 0)
+            o = jax.lax.map(block, (blocks, jnp.arange(blocks.shape[0]) * rows))
+            return jnp.moveaxis(o, 0, 1).reshape(b, t + pad, h, d)[:, :t]
+        return fn
+
+    def out_and_grads(f):
+        return jax.jit(lambda *x: (f(*x), *jax.grad(
+            lambda *y: jnp.sum(jnp.sin(f(*y).astype(jnp.float32))),
+            argnums=(0, 1, 2))(*x)))
+
+    def timed(fn, n=3):
+        t0 = time.time()
+        jax.block_until_ready(fn(q, k, v))
+        first = time.time() - t0
+        t0 = time.time()
+        for _ in range(n):
+            out = fn(q, k, v)
+        jax.block_until_ready(out)
+        return first, (time.time() - t0) / n * 1e3, out
+
+    for name, w in (("window", window), ("global", None)):
+        first, ms, got = timed(out_and_grads(
+            lambda *x, w=w: dispatch_attention(*x, causal=True, window=w)))
+        with jax.default_matmul_precision("highest"):
+            want = out_and_grads(dense(w))(q, k, v)
+        gaps = [_max_err(a, r) / float(jnp.max(jnp.abs(r.astype(jnp.float32))))
+                for a, r in zip(got, want)]
+        log(f"gqa: {name} core {(b, t, h, hk, d)} window {w}: first call "
+            f"{first:.1f}s, forward + backward {ms:.1f} ms; largest gap to "
+            f"the dense masked softmax over the largest value, output "
+            f"{gaps[0]:.2e}, gradients q k v "
+            f"{' '.join(f'{x:.2e}' for x in gaps[1:])}")
+        assert all(math.isfinite(x) and x <= 2e-2 for x in gaps), (name, gaps)
+        for bq, bkv in SIZES["gqa_blocks"]:
+            first, ms, _ = timed(out_and_grads(
+                lambda *x, w=w, bq=bq, bkv=bkv: causal_blockwise_attention(
+                    *x, block_q=bq, block_kv=bkv, window=w)))
+            log(f"gqa: {name} core at blocks {bq} x {bkv}: first call "
+                f"{first:.1f}s, forward + backward {ms:.1f} ms")
+
+
 # ------------------------------------------------------------------ serve
 
 def phase_serve() -> None:
@@ -730,7 +819,7 @@ def main(argv=None) -> int:
     if args.chips == 1:
         run = {"trainer": lambda: phase_trainer(cache), "accum": phase_accum,
                "kernels": phase_kernels, "serve": phase_serve,
-               "lm": lambda: phase_lm(cache)}
+               "lm": lambda: phase_lm(cache), "gqa": phase_gqa}
         for name in phases:
             run[name]()
     else:

@@ -1,6 +1,11 @@
-"""A causal decoder of two mixer kinds and two FFN kinds, chosen layer
-by layer: the ``kimi_linear`` family (Kimi Linear, Moonshot AI;
-``config.json`` of Kimi-Linear-48B-A3B-Instruct and the model's report).
+"""A causal decoder whose mixer and FFN are chosen layer by layer. Two
+families (``student.arch``): ``kimi_linear`` (Kimi Linear, Moonshot AI;
+``config.json`` of Kimi-Linear-48B-A3B-Instruct and the model's report:
+KDA and MLA mixers, a dense SwiGLU, routed + shared experts) and
+``smallthinker`` (SmallThinker, PowerInfer; ``config.json`` of
+SmallThinker-21BA3B-Instruct: grouped-query mixers with a window and
+rotary or with neither, routed ReGLU experts whose router reads the
+layer's input).
 
 Pre-norm residual layers, RMSNorm everywhere:
 
@@ -17,10 +22,21 @@ Pre-norm residual layers, RMSNorm everywhere:
   qk_nope + qk_rope; [c_t ; kpe_t] = W_kva x_t; [k_nope ; v] =
   W_kvb RMSNorm(c_t); k = [k_nope ; kpe_t for every head]; causal
   softmax(q k^T / sqrt(d_qk)) v through ``ops/attention.py``; W_o.
-- **FFN**: SwiGLU of ``intermediate_size`` in the first
+- **GQA** (``swa`` | ``full_attn``): q = W_q x as ``num_attention_heads``
+  heads of ``head_dim``, k = W_k x and v = W_v x as ``num_key_value_heads``;
+  query head i reads key/value head i // (heads / kv heads). ``swa``: q
+  and k rotated over token positions (rotate-half, ``rope_theta``) and
+  token t sees keys t - ``sliding_window`` < j <= t. ``full_attn``: every
+  key up to its own and no rotation at all. Same ``ops/attention.py``
+  tiles as MLA. W_o.
+- **FFN**: ``kimi_linear``: SwiGLU of ``intermediate_size`` in the first
   ``first_k_dense_replace`` layers; after them the routed experts this
-  shard holds (``ops/ffn.py RoutedExpertsFFN``) plus ``num_shared_experts``
-  shared ones of the same width side by side, every token through them.
+  shard holds (``ops/ffn.py RoutedExpertsFFN``, sigmoid router) plus
+  ``num_shared_experts`` shared ones of the same width side by side,
+  every token through them. ``smallthinker``: every layer routed, ReGLU
+  experts, softmax over the chosen logits, no shared expert; the router's
+  logits are W_r x of the LAYER'S INPUT x, before the first norm and the
+  mixer (``router_reads_layer_input``), the experts read n2(x').
 
 The vocabulary may be a slice (``vocab_size`` rows of the published
 table): ids, logits and the loss are over the slice. Embedding and head
@@ -29,9 +45,9 @@ head applied a block of tokens at a time so that the ``[tokens, vocab]``
 logits never exist whole.
 
 The step's phases (``utils.STEP_PHASES``): ``lm_embed``, ``kda_mixer``
-(inner ``kda_core``), ``mla_mixer`` (inner ``mla_core``), ``dense_ffn``,
-``moe_ffn`` (inner ``moe_route``, ``moe_experts`` from the routed layer,
-``moe_shared``), ``lm_head_loss``.
+(inner ``kda_core``), ``mla_mixer`` (inner ``mla_core``), ``swa_mixer`` and ``full_attn_mixer``
+(inner ``gqa_core``), ``dense_ffn``, ``moe_ffn`` (inner ``moe_route``,
+``moe_experts`` from the routed layer, ``moe_shared``), ``lm_head_loss``.
 """
 
 from __future__ import annotations
@@ -49,34 +65,47 @@ from dinov3_tpu.ops.common import l2_normalize, part, trunc_normal_init
 from dinov3_tpu.ops.ffn import RoutedExpertsFFN, SwiGLUFFN
 from dinov3_tpu.ops.kda import kda_chunked
 from dinov3_tpu.ops.norms import RMSNorm
+from dinov3_tpu.ops.rope import rope_apply_full, token_rope_sincos
 from dinov3_tpu.utils import step_phase
+
 
 @dataclasses.dataclass(frozen=True)
 class DecoderConfig:
-    """The ``lm`` section of a recipe, with the layer table made from
-    the published lists of layer numbers (1-based, as ``config.json``
-    gives them)."""
+    """The ``lm`` section of a recipe as the layers read it, with the
+    layer table made from the published lists (``kimi_linear``: layer
+    numbers, 1-based; ``smallthinker``: one 0/1 flag a layer), each family
+    under its own ``config.json``'s key names. A size a family has no use
+    for stays 0."""
 
     hidden_size: int
     vocab_size: int
     layers: tuple              # ((mixer, ffn), ...)
-    intermediate_size: int
     rms_norm_eps: float
-    kda_num_heads: int
-    kda_head_dim: int
-    short_conv_kernel_size: int
     num_attention_heads: int
-    kv_lora_rank: int
-    qk_nope_head_dim: int
-    qk_rope_head_dim: int
-    v_head_dim: int
     num_experts: int
     num_experts_per_token: int
     moe_intermediate_size: int
-    num_shared_experts: int
-    routed_scaling_factor: float
     expert_shards: int
     expert_shard: int
+    # kimi_linear
+    intermediate_size: int = 0
+    kda_num_heads: int = 0
+    kda_head_dim: int = 0
+    short_conv_kernel_size: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    num_shared_experts: int = 0
+    routed_scaling_factor: float = 1.0
+    # smallthinker
+    num_key_value_heads: int = 0
+    head_dim: int = 0
+    sliding_window: int = 0
+    rope_theta: float = 0.0
+    router: str = "sigmoid"            # ops/ffn.py RoutedExpertsFFN's rules
+    gate: str = "silu"
+    router_reads_layer_input: bool = False
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     reduce_dtype: Any = jnp.float32
@@ -85,30 +114,60 @@ class DecoderConfig:
     def from_cfg(cls, cfg, param_dtype=None) -> "DecoderConfig":
         from dinov3_tpu.ops.common import Policy
 
-        lm = cfg.lm
-        if lm.get("q_lora_rank") is not None:
-            raise ValueError("lm.q_lora_rank: only null (a full q projection)")
-        if str(lm.moe_router_activation_func) != "sigmoid" \
-                or not bool(lm.moe_renormalize):
-            raise ValueError("the routed layer is a renormalised sigmoid router")
-        depth = int(lm.num_hidden_layers)
-        kda, full = set(lm.kda_layers), set(lm.full_attn_layers)
-        if kda & full or (kda | full) != set(range(1, depth + 1)):
-            raise ValueError(
-                f"lm.kda_layers {sorted(kda)} and lm.full_attn_layers "
-                f"{sorted(full)} must split layers 1..{depth}")
-        layers = tuple(
-            ("kda" if i in kda else "mla",
-             "dense" if i <= int(lm.first_k_dense_replace) else "moe")
-            for i in range(1, depth + 1))
         policy = Policy.from_cfg(cfg.compute_precision)
-        names = {f.name for f in dataclasses.fields(cls)} - {
-            "layers", "dtype", "param_dtype", "reduce_dtype"}
-        return cls(
-            layers=layers, dtype=policy.compute_dtype,
-            param_dtype=param_dtype or policy.param_dtype,
-            reduce_dtype=policy.reduce_dtype,
-            **{k: lm[k] for k in names if k in lm})
+        family = {"kimi_linear": _kimi_linear_fields,
+                  "smallthinker": _smallthinker_fields}[str(cfg.student.arch)]
+        return cls(dtype=policy.compute_dtype,
+                   param_dtype=param_dtype or policy.param_dtype,
+                   reduce_dtype=policy.reduce_dtype, **family(cfg.lm))
+
+
+def _kimi_linear_fields(lm) -> dict:
+    if lm.get("q_lora_rank") is not None:
+        raise ValueError("lm.q_lora_rank: only null (a full q projection)")
+    if str(lm.moe_router_activation_func) != "sigmoid" \
+            or not bool(lm.moe_renormalize):
+        raise ValueError("the routed layer is a renormalised sigmoid router")
+    depth = int(lm.num_hidden_layers)
+    kda, full = set(lm.kda_layers), set(lm.full_attn_layers)
+    if kda & full or (kda | full) != set(range(1, depth + 1)):
+        raise ValueError(
+            f"lm.kda_layers {sorted(kda)} and lm.full_attn_layers "
+            f"{sorted(full)} must split layers 1..{depth}")
+    layers = tuple(
+        ("kda" if i in kda else "mla",
+         "dense" if i <= int(lm.first_k_dense_replace) else "moe")
+        for i in range(1, depth + 1))
+    names = {f.name for f in dataclasses.fields(DecoderConfig)} - {
+        "layers", "dtype", "param_dtype", "reduce_dtype"}
+    return dict(layers=layers, **{k: lm[k] for k in names if k in lm})
+
+
+def _smallthinker_fields(lm) -> dict:
+    if not bool(lm.moe_primary_router_apply_softmax) \
+            or not bool(lm.norm_topk_prob):
+        raise ValueError("the routed layer is a softmax router renormalised "
+                         "over the chosen experts")
+    depth = int(lm.num_hidden_layers)
+    windowed, rotated = list(lm.sliding_window_layout), list(lm.rope_layout)
+    if len(windowed) < depth or windowed[:depth] != rotated[:depth]:
+        raise ValueError(
+            "lm.sliding_window_layout and lm.rope_layout must give the "
+            f"same flag for each of the {depth} layers (a window layer "
+            "rotates, a global layer does not)")
+    return dict(
+        layers=tuple(("swa" if windowed[i] else "full_attn", "moe")
+                     for i in range(depth)),
+        hidden_size=lm.hidden_size, vocab_size=lm.vocab_size,
+        rms_norm_eps=lm.rms_norm_eps,
+        num_attention_heads=lm.num_attention_heads,
+        num_key_value_heads=lm.num_key_value_heads, head_dim=lm.head_dim,
+        sliding_window=lm.sliding_window_size, rope_theta=float(lm.rope_theta),
+        num_experts=lm.moe_num_primary_experts,
+        num_experts_per_token=lm.moe_num_active_primary_experts,
+        moe_intermediate_size=lm.moe_ffn_hidden_size,
+        expert_shards=lm.expert_shards, expert_shard=lm.expert_shard,
+        router="softmax", gate="relu", router_reads_layer_input=True)
 
 
 def _dense(features: int, axes, name: str, dtype, param_dtype) -> nn.Dense:
@@ -262,8 +321,43 @@ class MLAMixer(nn.Module):
             o.reshape(b, t, h * dv))
 
 
+class GQAMixer(nn.Module):
+    """Grouped-query attention: ``window`` None is every key up to the
+    query's own, ``rope_theta`` None no rotation."""
+
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    window: int | None = None
+    rope_theta: float | None = None
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+    reduce_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        b, t, _ = x.shape
+        h, hk, d = self.num_heads, self.num_kv_heads, self.head_dim
+        kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
+        xc = x.astype(self.dtype)
+        q = _dense(h * d, ("embed", "heads"), "q_proj", **kw)(xc)
+        k = _dense(hk * d, ("embed", "heads"), "k_proj", **kw)(xc)
+        v = _dense(hk * d, ("embed", "heads"), "v_proj", **kw)(xc)
+        q, k, v = (q.reshape(b, t, h, d), k.reshape(b, t, hk, d),
+                   v.reshape(b, t, hk, d))
+        if self.rope_theta is not None:
+            # float32 tables: the turn itself is float32, its ends bf16
+            q, k = rope_apply_full(
+                q, k, *token_rope_sincos(t, d, self.rope_theta))
+        with jax.named_scope("gqa_core"):
+            o = dispatch_attention(q, k, v, causal=True, window=self.window,
+                                   reduce_dtype=self.reduce_dtype)
+        return _dense(x.shape[-1], ("heads", "embed"), "o_proj", **kw)(
+            o.reshape(b, t, h * d))
+
+
 class DecoderLayer(nn.Module):
-    mixer: str                 # "kda" | "mla"
+    mixer: str                 # "kda" | "mla" | "swa" | "full_attn"
     ffn: str                   # "dense" | "moe"
     cfg: Any                   # the frozen ``DecoderConfig``
 
@@ -275,18 +369,28 @@ class DecoderLayer(nn.Module):
             epsilon=c.rms_norm_eps, param_dtype=c.param_dtype, name=name)
         # a phase holds its pre-norm and its residual add: what is left
         # outside every phase is what the compiler makes between layers
+        x_in = x
         if self.mixer == "kda":
             with step_phase("kda_mixer"):
                 y = KDAMixer(c.kda_num_heads, c.kda_head_dim,
                              c.short_conv_kernel_size, c.rms_norm_eps,
                              name="kda", **kw)(norm("norm1")(x))
                 x = x + y.astype(x.dtype)
-        else:
+        elif self.mixer == "mla":
             with step_phase("mla_mixer"):
                 y = MLAMixer(c.num_attention_heads, c.kv_lora_rank,
                              c.qk_nope_head_dim, c.qk_rope_head_dim,
                              c.v_head_dim, c.rms_norm_eps,
                              reduce_dtype=c.reduce_dtype, name="mla", **kw)(
+                                 norm("norm1")(x))
+                x = x + y.astype(x.dtype)
+        else:
+            swa = self.mixer == "swa"
+            with step_phase("swa_mixer" if swa else "full_attn_mixer"):
+                y = GQAMixer(c.num_attention_heads, c.num_key_value_heads,
+                             c.head_dim, c.sliding_window if swa else None,
+                             c.rope_theta if swa else None,
+                             reduce_dtype=c.reduce_dtype, name="attn", **kw)(
                                  norm("norm1")(x))
                 x = x + y.astype(x.dtype)
         aux = None
@@ -300,12 +404,16 @@ class DecoderLayer(nn.Module):
                 routed, aux = RoutedExpertsFFN(
                     c.moe_intermediate_size, c.num_experts,
                     c.num_experts_per_token, c.expert_shards, c.expert_shard,
-                    c.routed_scaling_factor, name="experts", **kw)(y)
-                with jax.named_scope("moe_shared"):
-                    shared = _swiglu(
-                        c.moe_intermediate_size * c.num_shared_experts,
-                        "shared", **kw)(y)
-                x = x + (routed + shared).astype(x.dtype)
+                    c.routed_scaling_factor, router=c.router, gate=c.gate,
+                    name="experts", **kw)(
+                        y, x_in if c.router_reads_layer_input else None)
+                if c.num_shared_experts:
+                    with jax.named_scope("moe_shared"):
+                        shared = _swiglu(
+                            c.moe_intermediate_size * c.num_shared_experts,
+                            "shared", **kw)(y)
+                    routed = routed + shared
+                x = x + routed.astype(x.dtype)
         return x, aux
 
 

@@ -33,6 +33,7 @@ from dinov3_tpu.ops.rope import rope_apply_full, rope_apply_with_prefix
 
 
 import functools as _functools
+import itertools
 
 
 @_functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
@@ -126,32 +127,69 @@ def causal_blockwise_attention(
     block_q: int = 512,
     block_kv: int = 1024,
     reduce_dtype=jnp.float32,
+    window: int | None = None,
 ) -> jnp.ndarray:
-    """Causal attention block by block: [B, N, h, dqk] q and k,
-    [B, N, h, dv] v (the value width may differ from the q/k width:
+    """Causal attention block by block: [B, N, h, dqk] q, [B, N, hk, dqk]
+    k, [B, N, hk, dv] v (the value width may differ from the q/k width:
     latent attention's 192 beside 128), statistics in reduce_dtype.
 
-    A block of ``block_q`` queries meets the keys up to its own end and
-    no further, ``block_kv`` of them at a time, under a running maximum
-    and sum (the online softmax): the slices are static, so the half of
-    the [N, N] score plane above the diagonal is never computed, only
-    the blocks on the diagonal are masked, and no plane wider than
-    [B, h, block_q, block_kv] exists. Each query block is
-    rematerialised: the backward pass holds its tiles and no others,
-    where the dense causal path of ``xla_attention`` holds [B, h, N, N]
-    (8.6 GB a sequence at 32 heads and 8,192 tokens). On a v5e the whole
-    row of keys at once — one [B, h, 512, 8192] float32 softmax a block —
-    ran 5.4 times slower than these tiles at the same block_q (1,056
-    against 196 ms forward and backward at 2 x 8,192 x 32 x 192/128, my
-    chip run, PR 27)."""
+    Query head i reads key/value head ``i // (h // hk)`` (grouped heads;
+    ``hk == h`` is plain multi-head attention). The ``h // hk`` query
+    heads of a group go into the ROWS of the group's tiles, token-major:
+    one ``[g * block_q, d] x [d, block_kv]`` product a tile in place of
+    g, and k and v are never written out g times.
+
+    ``window``: token t sees the keys ``t - window < j <= t`` (``window``
+    keys with its own); None: every key up to its own.
+
+    A block of ``block_q`` queries meets the keys of its band and no
+    others, ``block_kv`` of them at a time from the block's first key
+    (key 0, or with a window the last multiple of 128 at or before the
+    first query's first key), under a running maximum and sum (the
+    online softmax): the slices are static, so the tiles above the
+    diagonal and the tiles wholly below the window's lower edge are never
+    computed, only the tiles an edge crosses are masked, and no plane
+    wider than [B, hk, g * block_q, block_kv] exists. (A row can find a
+    whole tile masked before its window begins: what that adds to its
+    sum is multiplied by exp(-1e30 - max) = 0 when its first real key
+    comes, and the diagonal tile, which holds its own key, comes last.)
+    Each query block is rematerialised: the backward pass holds its tiles
+    and no others, where the dense causal path of ``xla_attention`` holds
+    [B, h, N, N] (8.6 GB a sequence at 32 heads and 8,192 tokens). On a
+    v5e the whole row of keys at once — one [B, h, 512, 8192] float32
+    softmax a block — ran 5.4 times slower than these tiles at the same
+    block_q (1,056 against 196 ms forward and backward at 2 x 8,192 x 32
+    x 192/128, my chip run, PR 27).
+
+    A block's program depends on where its queries stand among ITS keys
+    and on how many keys it has, not on where it stands in the sequence:
+    past the window every block of a window layer has the same geometry,
+    and such a run of blocks is traced and compiled ONCE, under
+    ``lax.map``, its keys cut out at a dynamic offset (24 of the 32
+    blocks at 16,384 tokens and a window of 4,096; without a window no
+    two blocks are alike and each is its own program, as before)."""
     b, n, h, _ = q.shape
+    hk = k.shape[2]
+    if h % hk or v.shape[2] != hk:
+        raise ValueError(
+            f"{h} query heads over {hk} key and {v.shape[2]} value heads")
+    g = h // hk
+    if window is not None and window < 1:
+        raise ValueError(f"window {window}: at least the query's own key")
     scale = q.shape[-1] ** -0.5
     # heads beside the batch: one leading batch axis for the matmuls
-    lead = lambda x: jnp.swapaxes(x, 1, 2).reshape((b * h, n, x.shape[-1]))  # noqa: E731
-    q, k, v = lead(q), lead(k), lead(v)
+    lead = lambda x: jnp.swapaxes(x, 1, 2).reshape((b * hk, n, x.shape[-1]))  # noqa: E731
+    if g > 1:  # [B, N, hk * g, d] -> [B * hk, N * g, d], token-major rows
+        q = q.reshape(b, n, hk, g, -1).transpose(0, 2, 1, 3, 4).reshape(
+            b * hk, n * g, -1)
+    else:
+        q = lead(q)
+    k, v = lead(k), lead(v)
 
     def block(qb, kb, vb, start):
+        """Queries from token ``start`` of the keys' own numbering."""
         rows = qb.shape[:2]
+        end = start + rows[1] // g
         top = jnp.full(rows, -1e30, reduce_dtype)
         total = jnp.zeros(rows, reduce_dtype)
         acc = jnp.zeros(rows + (vb.shape[-1],), reduce_dtype)
@@ -159,10 +197,17 @@ def causal_blockwise_attention(
             hi = min(lo + block_kv, kb.shape[1])
             z = jnp.einsum("zqd,zkd->zqk", qb, kb[:, lo:hi],
                            preferred_element_type=reduce_dtype) * scale
-            if hi > start + 1:  # the diagonal crosses this tile
-                row = start + jax.lax.broadcasted_iota(jnp.int32, z.shape[-2:], 0)
+            above = hi > start + 1  # the diagonal crosses this tile
+            below = window is not None and lo <= end - 1 - window
+            if above or below:
+                row = jax.lax.broadcasted_iota(jnp.int32, z.shape[-2:], 0)
+                row = start + (row // g if g > 1 else row)
                 col = lo + jax.lax.broadcasted_iota(jnp.int32, z.shape[-2:], 1)
-                z = jnp.where(col <= row, z, jnp.asarray(-1e30, z.dtype))
+                seen = col <= row
+                if below:
+                    seen = (seen & (col > row - window)) if above \
+                        else col > row - window
+                z = jnp.where(seen, z, jnp.asarray(-1e30, z.dtype))
             new_top = jnp.maximum(top, jnp.max(z, axis=-1))
             shrink = jnp.exp(top - new_top)
             p = jnp.exp(z - new_top[..., None])
@@ -174,11 +219,38 @@ def causal_blockwise_attention(
         return (acc / total[..., None]).astype(vb.dtype)
 
     block = jax.checkpoint(block, static_argnums=(3,))
-    outs = []
+    spans = []  # (start, end, first key) of every block of queries
+    align = min(block_kv, 128)
     for start in range(0, n, block_q):
-        end = min(start + block_q, n)
-        outs.append(block(q[:, start:end], k[:, :end], v[:, :end], start))
+        first = 0 if window is None else \
+            max(start - window + 1, 0) // align * align
+        spans.append((start, min(start + block_q, n), first))
+    outs = []
+    # consecutive blocks of one geometry: (queries' place among the keys,
+    # keys, queries)
+    for (at, keys, size), run in itertools.groupby(
+            spans, key=lambda s: (s[0] - s[2], s[1] - s[2], s[1] - s[0])):
+        run = list(run)
+        start, _, first = run[0]
+        if len(run) == 1:
+            outs.append(block(q[:, start * g:(start + size) * g],
+                              k[:, first:first + keys], v[:, first:first + keys], at))
+            continue
+
+        def one(xs):
+            qb, first = xs
+            cut = lambda x: jax.lax.dynamic_slice_in_dim(x, first, keys, axis=1)  # noqa: E731
+            return block(qb, cut(k), cut(v), at)
+
+        qs = q[:, start * g:(start + len(run) * size) * g].reshape(
+            b * hk, len(run), size * g, -1)
+        o = jax.lax.map(one, (jnp.moveaxis(qs, 1, 0),
+                              jnp.asarray([s[2] for s in run], jnp.int32)))
+        outs.append(jnp.moveaxis(o, 0, 1).reshape(b * hk, len(run) * size * g, -1))
     out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+    if g > 1:
+        return out.reshape(b, hk, n, g, -1).transpose(0, 2, 1, 3, 4).reshape(
+            b, n, h, -1)
     return jnp.swapaxes(out.reshape(b, h, n, out.shape[-1]), 1, 2)
 
 
@@ -224,14 +296,18 @@ def dispatch_attention(
     probs_dtype=None, flash_min_seq: int = 0,
     seg: jnp.ndarray | None = None,
     causal: bool = False,
+    window: int | None = None,
 ) -> jnp.ndarray:
+    if window is not None and not causal:
+        raise ValueError("a window is the causal path's")
     if causal:
         # the Pallas kernel is non-causal (flash_attention.py) and the
         # dense causal path holds the whole [N, N] plane: a decoder's
         # attention goes block by block, on every backend
         if seg is not None:
             raise ValueError("causal attention takes no segment ids")
-        return causal_blockwise_attention(q, k, v, reduce_dtype=reduce_dtype)
+        return causal_blockwise_attention(q, k, v, reduce_dtype=reduce_dtype,
+                                          window=window)
     if impl == "auto":
         # 0/None = built-in default, matching kernels.flash_min_seq's
         # documented sentinel (one convention for module and direct calls)

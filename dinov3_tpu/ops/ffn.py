@@ -239,13 +239,22 @@ def routed_rows_capacity(n_tokens: int, top_k: int, num_experts: int,
 
 class RoutedExpertsFFN(nn.Module):
     """The routed experts of one expert-parallel shard: a router over
-    all ``num_experts``, the SwiGLU experts ``[first, first + held)`` of
+    all ``num_experts``, the gated experts ``[first, first + held)`` of
     them held here (``shard`` of ``shards`` equal ones), and the part of
     the layer's result that those give.
 
-        s = sigmoid(W_r x) (float32); the top_k largest of s + bias;
-        w = scale * s_sel / sum(s_sel);
-        y = sum over the selected experts e held here of w_e SwiGLU_e(x)
+        router "sigmoid": s = sigmoid(W_r x_r) (float32); the top_k
+            largest of s + bias; w = scale * s_sel / sum(s_sel);
+        router "softmax": r = W_r x_r (float32); the top_k largest of r;
+            w = scale * softmax(r_sel) (= the softmax over all experts,
+            renormalised over the chosen ones); no bias exists;
+        y = sum over the selected experts e held here of
+            w_e W3_e (act(g) * u), [g ; u] = W12_e x, act = ``gate``
+            ("silu": SwiGLU, "relu": ReGLU)
+
+    ``x_r`` is ``x`` unless the caller hands in ``router_input`` (same
+    leading shape): a layer whose router reads another tensor than its
+    experts do.
 
     What the absent experts would add is another shard's to compute and
     an all-to-all's to bring; on one shard the layer runs without that
@@ -276,15 +285,21 @@ class RoutedExpertsFFN(nn.Module):
     rows_factor: float = ROWS_CAPACITY_FACTOR
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
+    router: str = "sigmoid"   # | "softmax"
+    gate: str = "silu"        # | "relu"
 
     @nn.compact
-    def __call__(self, x: jnp.ndarray):
+    def __call__(self, x: jnp.ndarray, router_input: jnp.ndarray | None = None):
         import jax
 
         E, K, H = self.num_experts, self.top_k, self.hidden_dim
         if E % self.shards or not 0 <= self.shard < self.shards:
             raise ValueError(
                 f"shard {self.shard} of {self.shards} over {E} experts")
+        if self.router not in ("sigmoid", "softmax") \
+                or self.gate not in ("silu", "relu"):
+            raise ValueError(f"router {self.router!r}, gate {self.gate!r}")
+        act = nn.silu if self.gate == "silu" else nn.relu
         held = E // self.shards
         first = self.shard * held
         D = x.shape[-1]
@@ -292,12 +307,14 @@ class RoutedExpertsFFN(nn.Module):
         N = x2.shape[0]
         cap = routed_rows_capacity(N, K, E, held, self.rows_factor)
 
+        xr = x2 if router_input is None else router_input.reshape(-1, D)
         router = self.param(
             "router", part(trunc_normal_init(), ("embed", None)),
             (D, E), self.param_dtype)
-        bias = self.param(
-            "router_bias", part(nn.initializers.zeros, (None,)),
-            (E,), self.param_dtype)
+        if self.router == "sigmoid":
+            bias = self.param(
+                "router_bias", part(nn.initializers.zeros, (None,)),
+                (E,), self.param_dtype)
         w12 = self.param(
             "w12", part(trunc_normal_init(), ("experts", "embed", "mlp")),
             (held, D, 2 * H), self.param_dtype)
@@ -306,13 +323,19 @@ class RoutedExpertsFFN(nn.Module):
             (held, H, D), self.param_dtype)
 
         with jax.named_scope("moe_route"):
-            s = jax.nn.sigmoid(jnp.dot(
-                x2.astype(jnp.float32), router.astype(jnp.float32),
-                precision=jax.lax.Precision.HIGHEST))
-            _, choice = jax.lax.top_k(
-                s + jax.lax.stop_gradient(bias.astype(jnp.float32)), K)
-            s_sel = jnp.take_along_axis(s, choice, axis=-1)
-            w = self.scale * s_sel / jnp.sum(s_sel, axis=-1, keepdims=True)
+            s = jnp.dot(
+                xr.astype(jnp.float32), router.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST)
+            if self.router == "sigmoid":
+                s = jax.nn.sigmoid(s)
+                _, choice = jax.lax.top_k(
+                    s + jax.lax.stop_gradient(bias.astype(jnp.float32)), K)
+                s_sel = jnp.take_along_axis(s, choice, axis=-1)
+                w = self.scale * s_sel / jnp.sum(s_sel, axis=-1, keepdims=True)
+            else:
+                _, choice = jax.lax.top_k(s, K)
+                w = self.scale * jax.nn.softmax(
+                    jnp.take_along_axis(s, choice, axis=-1), axis=-1)
             # the pairs of held experts, expert by expert, first
             local = (choice - first).reshape(-1)
             here = (local >= 0) & (local < held)
@@ -343,7 +366,7 @@ class RoutedExpertsFFN(nn.Module):
                 preferred_element_type=jnp.float32).astype(self.dtype)
             gate, value = jnp.split(h, 2, axis=-1)
             out = jax.lax.ragged_dot(
-                nn.silu(gate) * value, w3.astype(self.dtype), sizes,
+                act(gate) * value, w3.astype(self.dtype), sizes,
                 preferred_element_type=jnp.float32)
             out = jnp.where(kept[:, None], out, 0.0) * w_rows[:, None]
             y = jnp.zeros((N, D), jnp.float32).at[token].add(out)

@@ -1,4 +1,5 @@
-"""2-D rotary position embeddings for ViT patch grids, as pure functions.
+"""2-D rotary position embeddings for ViT patch grids, as pure functions
+(and ``token_rope_sincos``: a decoder's 1-D table over token positions).
 
 Math parity with the reference module (dinov3_jax/layers/rope_position_encoding.py):
 - period spectrum from ``base ** (2j / (D_head/2))`` for j in [0, D_head/4)
@@ -161,6 +162,23 @@ def rope_sincos(
     # [HW, 2, 1] / [P] -> [HW, 2, P] -> [HW, 2P] -> duplicated rotate-half halves
     angles = 2.0 * math.pi * coords[:, :, None] / periods[None, None, :]
     angles = angles.reshape(angles.shape[0], -1)
+    angles = jnp.concatenate([angles, angles], axis=-1)
+    return jnp.sin(angles).astype(dtype), jnp.cos(angles).astype(dtype)
+
+
+def token_rope_sincos(
+    n_tokens: int, head_dim: int, theta: float, dtype=jnp.float32,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """(sin, cos), each [n_tokens, head_dim], of a decoder's rotary
+    embedding over token positions 0..n_tokens-1: channel pair
+    (j, j + head_dim/2) of token t turns by t * theta^(-2j / head_dim)
+    (rotate-half pairing, the halves duplicated as ``rope_sincos`` has
+    them, so ``rope_apply_full`` serves both)."""
+    if head_dim % 2:
+        raise ValueError(f"head_dim must be even, got {head_dim}")
+    rates = jnp.asarray(theta, jnp.float32) ** (
+        -jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
+    angles = jnp.arange(n_tokens, dtype=jnp.float32)[:, None] * rates[None, :]
     angles = jnp.concatenate([angles, angles], axis=-1)
     return jnp.sin(angles).astype(dtype), jnp.cos(angles).astype(dtype)
 

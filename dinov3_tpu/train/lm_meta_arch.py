@@ -46,9 +46,9 @@ class LMMetaArch:
         self.student_backbone = build_backbone(cfg, param_dtype=jnp.float32)
         self.embed_dim = self.student_backbone.embed_dim
         dc = self.student_backbone.cfg
-        path, why = kda_path(dc.kda_head_dim, dc.kda_head_dim)
         for i, (mixer, _) in enumerate(dc.layers, 1):
             if mixer == "kda":
+                path, why = kda_path(dc.kda_head_dim, dc.kda_head_dim)
                 logger.info("layer %d kda_core, both passes: %s (%s)", i, path, why)
 
     def init_params(self, rng: jax.Array, batch: dict, unbox: bool = True) -> dict:

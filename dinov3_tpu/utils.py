@@ -543,14 +543,16 @@ STEP_PHASES = (
     "lm_embed",           # the token embedding's gather
     "kda_mixer",          # a KDA layer's mixer (inner: kda_core)
     "mla_mixer",          # a latent-attention layer's mixer (inner: mla_core)
+    "swa_mixer",          # a grouped-query layer with a window and rotary
+    "full_attn_mixer",    # ... with neither (inner of both: gqa_core)
     "dense_ffn",          # the dense SwiGLU of the leading layers
     "moe_ffn",            # routed + shared experts (inner: moe_route,
                           # moe_experts, moe_shared)
     "lm_head_loss",       # final norm, head and cross-entropy, by blocks
 )
 # the phases only a decoder's step opens
-LM_STEP_PHASES = ("lm_embed", "kda_mixer", "mla_mixer", "dense_ffn",
-                  "moe_ffn", "lm_head_loss")
+LM_STEP_PHASES = ("lm_embed", "kda_mixer", "mla_mixer", "swa_mixer",
+                  "full_attn_mixer", "dense_ffn", "moe_ffn", "lm_head_loss")
 
 _PHASE_WRAPPER = re.compile(r"^(jvp|transpose|checkpoint|remat)\((.*)\)$")
 

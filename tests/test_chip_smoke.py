@@ -43,6 +43,8 @@ TINY = {
     "ln_shape": (64, 128),
     "kda_shape": (1, 128, 1, 128),
     "kernel_interpret": True,
+    "gqa_shape": (1, 300, 6, 2, 16, 64),
+    "gqa_blocks": [(32, 64)],
     "serve_overrides": [
         "student.arch=vit_test", "student.patch_size=4", "serve.min_px=8",
         "serve.max_px=32", "serve.rows=4", "serve.row_tokens=65",
@@ -105,6 +107,7 @@ def test_smoke_rehearsal_runs_every_phase(smoke, monkeypatch, capsys):
                    "kernels: fused_layernorm", "kernels: kda_chunk_fwd",
                    "decay spikes of 200", "serve:",
                    "compiles packed 1", "lm: losses", "lm: resumed at 3",
+                   "gqa: window core", "gqa: global core at blocks 32 x 64",
                    "all phases passed"):
         assert needle in said, needle
     assert "mesh[" not in said  # the four-chip phase is behind --chips 4

@@ -599,8 +599,9 @@ def test_compiled_step_holds_the_decoder_phases(tiny_setup):
 
     names = re.findall(r'op_name="([^"]*)"', text)
     found = {classify_step_phase(n) for n in names}
-    assert {p for p, _ in found} - {None} == set(LM_STEP_PHASES) | {
-        "update", "telemetry_ring"}
+    # (the other decoder family's two mixers are tests/test_lm_gqa.py's)
+    assert {p for p, _ in found} - {None} == set(LM_STEP_PHASES) - {
+        "swa_mixer", "full_attn_mixer"} | {"update", "telemetry_ring"}
     for phase in ("kda_mixer", "mla_mixer", "dense_ffn", "moe_ffn",
                   "lm_head_loss"):
         assert {(phase, "fwd"), (phase, "bwd")} <= found, phase

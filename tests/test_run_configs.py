@@ -37,9 +37,12 @@ def test_recipe_abstract_build(path):
 
     if is_lm_arch(cfg):
         # a decoder's recipe: a student and no teacher. Shrink the widths
-        # (tests/test_lm_decoder.py TINY) and KEEP the structure: the layer
-        # table, the experts' share, the schedules
-        from test_lm_decoder import TINY
+        # (the family's TINY) and KEEP the structure: the layer table, the
+        # experts' share, the schedules
+        if cfg.student.arch == "smallthinker":
+            from test_lm_gqa import TINY
+        else:
+            from test_lm_decoder import TINY
 
         from dinov3_tpu.train.lm_meta_arch import LMMetaArch
 
