@@ -3,7 +3,8 @@
     python chip_smoke.py            # one TPU chip: trainer, the step under
                                     # accumulation, kernels, serve, the
                                     # decoder's next-token trainer, the
-                                    # banded grouped-query attention core
+                                    # decoders' causal attention cores
+                                    # (the kernels beside the plain tiles)
     python chip_smoke.py --phases lm   # one chip, that phase alone
     python chip_smoke.py --chips 4  # four chips: ONLY the sharded train
                                     # arms and their one-device comparison
@@ -29,6 +30,7 @@ with the device as JAX reports it.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import math
 import os
@@ -76,11 +78,17 @@ SIZES = {
     # the delta rule at published width: [B, T, heads, d_k = d_v]
     "kda_shape": (1, 1024, 4, 128),
     "kernel_interpret": False,
-    # the window layers' core at published sizes: [B, T, query heads,
-    # key/value heads, head_dim, window]; the (block_q, block_kv) pairs
-    # timed beside the shipped 512 x 1,024 (information: why it stays)
-    "gqa_shape": (1, 16384, 28, 4, 128, 4096),
-    "gqa_blocks": [(1024, 1024), (1024, 2048)],
+    # the causal cores at published sizes: [B, T, query heads, key/value
+    # heads, q/k width, value width, window]: the 16k decoder's window and
+    # global layers, the 8k decoder's latent attention; the (block_q,
+    # block_kv) pairs timed on the kernel path beside the shipped 512 x
+    # 1,024 (information: why it stays)
+    "gqa_shapes": {"window": (1, 16384, 28, 4, 128, 128, 4096),
+                   "global": (1, 16384, 28, 4, 128, 128, None),
+                   "mla": (2, 8192, 32, 32, 192, 128, None)},
+    "gqa_shipped_blocks": (512, 1024),
+    "gqa_blocks": [(512, 512), (1024, 1024), (256, 1024)],
+    "gqa_timeout_s": 1200,
     "serve_overrides": ["student.arch=vit_large", "student.patch_size=16",
                         "train.scan_layers=true"],
     # mixed resolutions inside the default 96..512 px envelope
@@ -455,29 +463,33 @@ def _kda_kernel_row(interpret: bool) -> None:
 # ------------------------------------------- the banded grouped-query core
 
 def phase_gqa() -> None:
-    """``ops/attention.py causal_blockwise_attention`` with a window and
-    grouped heads, as the ``smallthinker`` decoder's layers call it
-    (``dispatch_attention(..., causal=True, window=W)`` and ``window=
-    None``), at the published head sizes and the whole context, against
-    the dense masked softmax in float32 (whole rows of keys, k and v
-    repeated for every query head, a block of queries at a time so that it
-    fits): the output and the three gradients. Then forward + backward
-    timed, and beside it the tiles at other block sizes."""
+    """``ops/attention.py causal_blockwise_attention`` as the decoders'
+    layers call it, at the published head sizes and whole contexts: the
+    window and the global grouped-query core of the ``smallthinker``
+    family and the latent attention core of ``kimi_linear`` (q and k
+    wider than v, no groups, no window). Each row: the path the entry
+    point takes there (``causal_attention_path``'s words), the KERNEL
+    path's output and three gradients against the dense masked softmax in
+    float32 (whole rows of keys, k and v repeated for every query head, a
+    block of queries at a time so that it fits) and against the plain
+    tiles; forward alone and forward + backward timed on both paths; then
+    the kernel path at other block sizes.
+
+    A new kernel can hang the chip where every rehearsal passed (PERF.md
+    section 6, PR 26): the phase has a time limit of its own."""
+    import faulthandler
+
     import jax
     import jax.numpy as jnp
 
-    from dinov3_tpu.ops.attention import (
-        causal_blockwise_attention,
-        dispatch_attention,
-    )
+    from dinov3_tpu.ops import causal_attention as kernels
+    from dinov3_tpu.ops.attention import causal_tiles
 
-    b, t, h, hk, d, window = SIZES["gqa_shape"]
-    ks = jax.random.split(jax.random.key(2), 3)
-    q = jax.random.normal(ks[0], (b, t, h, d), jnp.bfloat16)
-    k = jax.random.normal(ks[1], (b, t, hk, d), jnp.bfloat16)
-    v = jax.random.normal(ks[2], (b, t, hk, d), jnp.bfloat16)
+    interpret = bool(SIZES["kernel_interpret"])
+    faulthandler.dump_traceback_later(
+        float(SIZES["gqa_timeout_s"]), exit=True, file=sys.__stderr__)
 
-    def dense(w, rows=256):
+    def dense(t, h, hk, d, w, rows=256):
         def fn(q, k, v):
             q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
             k, v = (jnp.repeat(x, h // hk, axis=2) for x in (k, v))
@@ -494,9 +506,10 @@ def phase_gqa() -> None:
                 p = jax.nn.softmax(jnp.where(seen, z, -jnp.inf), -1)
                 return jnp.einsum("bhqk,bkhd->bqhd", p, v)
 
-            blocks = jnp.moveaxis(qp.reshape(b, -1, rows, h, d), 1, 0)
+            blocks = jnp.moveaxis(qp.reshape(q.shape[0], -1, rows, h, d), 1, 0)
             o = jax.lax.map(block, (blocks, jnp.arange(blocks.shape[0]) * rows))
-            return jnp.moveaxis(o, 0, 1).reshape(b, t + pad, h, d)[:, :t]
+            return jnp.moveaxis(o, 0, 1).reshape(
+                q.shape[0], t + pad, h, -1)[:, :t]
         return fn
 
     def out_and_grads(f):
@@ -504,35 +517,65 @@ def phase_gqa() -> None:
             lambda *y: jnp.sum(jnp.sin(f(*y).astype(jnp.float32))),
             argnums=(0, 1, 2))(*x)))
 
-    def timed(fn, n=3):
+    def timed(fn, x, n=3):
         t0 = time.time()
-        jax.block_until_ready(fn(q, k, v))
+        jax.block_until_ready(fn(*x))
         first = time.time() - t0
         t0 = time.time()
         for _ in range(n):
-            out = fn(q, k, v)
+            out = fn(*x)
         jax.block_until_ready(out)
         return first, (time.time() - t0) / n * 1e3, out
 
-    for name, w in (("window", window), ("global", None)):
-        first, ms, got = timed(out_and_grads(
-            lambda *x, w=w: dispatch_attention(*x, causal=True, window=w)))
-        with jax.default_matmul_precision("highest"):
-            want = out_and_grads(dense(w))(q, k, v)
-        gaps = [_max_err(a, r) / float(jnp.max(jnp.abs(r.astype(jnp.float32))))
+    def gaps(got, want):  # the difference's norm over the reference's
+        f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
+        return [float(jnp.linalg.norm(f32(a) - f32(r)) / jnp.linalg.norm(f32(r)))
                 for a, r in zip(got, want)]
-        log(f"gqa: {name} core {(b, t, h, hk, d)} window {w}: first call "
-            f"{first:.1f}s, forward + backward {ms:.1f} ms; largest gap to "
-            f"the dense masked softmax over the largest value, output "
-            f"{gaps[0]:.2e}, gradients q k v "
-            f"{' '.join(f'{x:.2e}' for x in gaps[1:])}")
-        assert all(math.isfinite(x) and x <= 2e-2 for x in gaps), (name, gaps)
-        for bq, bkv in SIZES["gqa_blocks"]:
-            first, ms, _ = timed(out_and_grads(
-                lambda *x, w=w, bq=bq, bkv=bkv: causal_blockwise_attention(
-                    *x, block_q=bq, block_kv=bkv, window=w)))
-            log(f"gqa: {name} core at blocks {bq} x {bkv}: first call "
-                f"{first:.1f}s, forward + backward {ms:.1f} ms")
+
+    said = lambda xs: " ".join(f"{x:.3e}" for x in xs)  # noqa: E731
+
+    bq, bkv = SIZES["gqa_shipped_blocks"]
+    for name, (b, t, h, hk, d, dv, w) in SIZES["gqa_shapes"].items():
+        ks = jax.random.split(jax.random.key(2), 3)
+        x = tuple(jax.random.normal(key, shape, jnp.bfloat16)
+                  for key, shape in zip(ks, (
+                      (b, t, h, d), (b, t, hk, d), (b, t, hk, dv))))
+        path, why = kernels.causal_attention_path(
+            tuple(a.shape for a in x), w, interpret or None, bq, bkv)
+        log(f"gqa: {name} core {(b, t, h, hk, d, dv)} window {w}: the entry "
+            f"point takes the {path} ({why})")
+        assert path == "kernel", (name, path, why)
+
+        def kernel(*a, bq=bq, bkv=bkv, w=w, d=d):
+            return kernels.kernel_attention(
+                *a, d ** -0.5, w, bq, bkv, interpret)
+
+        def tiles(*a, w=w):
+            return causal_tiles(*a, bq, bkv, jnp.float32, w)
+
+        _compiled_has_kernel(out_and_grads(kernel), *x)
+        found = {}
+        for path, fn in (("kernel", kernel), ("tiles", tiles)):
+            first_f, ms_f, _ = timed(jax.jit(fn), x)
+            first, ms, found[path] = timed(out_and_grads(fn), x)
+            log(f"gqa: {name} core, {path}: first calls {first_f:.1f}s and "
+                f"{first:.1f}s, forward {ms_f:.1f} ms, forward + backward "
+                f"{ms:.1f} ms")
+        with jax.default_matmul_precision("highest"):
+            want = out_and_grads(dense(t, h, hk, d, w))(*x)
+        log(f"gqa: {name} core: norm of the difference over the norm, "
+            f"output and gradients q k v: kernel to the dense masked softmax "
+            f"{said(gaps(found['kernel'], want))}; tiles to it "
+            f"{said(gaps(found['tiles'], want))}; kernel to tiles "
+            f"{said(gaps(found['kernel'], found['tiles']))}")
+        for got in found.values():
+            assert all(g <= 2e-2 for g in gaps(got, want)), name
+        for obq, obkv in SIZES["gqa_blocks"]:
+            first, ms, _ = timed(out_and_grads(functools.partial(
+                kernel, bq=obq, bkv=obkv)), x)
+            log(f"gqa: {name} core, kernel at blocks {obq} x {obkv}: first "
+                f"call {first:.1f}s, forward + backward {ms:.1f} ms")
+    faulthandler.cancel_dump_traceback_later()
 
 
 # ------------------------------------------------------------------ serve

@@ -134,19 +134,47 @@ def causal_blockwise_attention(
     latent attention's 192 beside 128), statistics in reduce_dtype.
 
     Query head i reads key/value head ``i // (h // hk)`` (grouped heads;
-    ``hk == h`` is plain multi-head attention). The ``h // hk`` query
-    heads of a group go into the ROWS of the group's tiles, token-major:
-    one ``[g * block_q, d] x [d, block_kv]`` product a tile in place of
-    g, and k and v are never written out g times.
+    ``hk == h`` is plain multi-head attention).
 
     ``window``: token t sees the keys ``t - window < j <= t`` (``window``
     keys with its own); None: every key up to its own.
 
-    A block of ``block_q`` queries meets the keys of its band and no
-    others, ``block_kv`` of them at a time from the block's first key
-    (key 0, or with a window the last multiple of 128 at or before the
-    first query's first key), under a running maximum and sum (the
-    online softmax): the slices are static, so the tiles above the
+    A block of ``block_q`` queries meets the key tiles of its band and no
+    others, ``block_kv`` keys at a time under a running maximum and sum
+    (the online softmax), the diagonal's tile last. Two paths make the
+    same tiles, and ``ops/causal_attention.py causal_attention_path``
+    chooses between them from what the call can observe (backend, types,
+    widths, the length against the blocks), no option or variable: the
+    Pallas KERNELS ``causal_attn_fwd`` and ``causal_attn_bwd``, which keep
+    a tile's float32 score plane in VMEM, on a TPU; ``causal_tiles``
+    below, plain XLA, everywhere else."""
+    h, hk = q.shape[2], k.shape[2]
+    if h % hk or v.shape[2] != hk:
+        raise ValueError(
+            f"{h} query heads over {hk} key and {v.shape[2]} value heads")
+    if window is not None and window < 1:
+        raise ValueError(f"window {window}: at least the query's own key")
+    from dinov3_tpu.ops import causal_attention as kernels
+
+    if q.dtype == k.dtype == v.dtype and kernels.causal_attention_path(
+            (q.shape, k.shape, v.shape), window, None, block_q, block_kv,
+            q.dtype, reduce_dtype)[0] == "kernel":
+        return kernels.kernel_attention(
+            q, k, v, q.shape[-1] ** -0.5, window, block_q, block_kv, False)
+    return causal_tiles(q, k, v, block_q, block_kv, reduce_dtype, window)
+
+
+def causal_tiles(q, k, v, block_q, block_kv, reduce_dtype, window):
+    """``causal_blockwise_attention``'s plain path.
+
+    The ``h // hk`` query heads of a group go into the ROWS of the
+    group's tiles, token-major: one ``[g * block_q, d] x [d, block_kv]``
+    product a tile in place of g, and k and v are never written out g
+    times.
+
+    A block's keys run from its first key (key 0, or with a window the
+    last multiple of 128 at or before the first query's first key): the
+    slices are static, so the tiles above the
     diagonal and the tiles wholly below the window's lower edge are never
     computed, only the tiles an edge crosses are masked, and no plane
     wider than [B, hk, g * block_q, block_kv] exists. (A row can find a
@@ -170,12 +198,7 @@ def causal_blockwise_attention(
     two blocks are alike and each is its own program, as before)."""
     b, n, h, _ = q.shape
     hk = k.shape[2]
-    if h % hk or v.shape[2] != hk:
-        raise ValueError(
-            f"{h} query heads over {hk} key and {v.shape[2]} value heads")
     g = h // hk
-    if window is not None and window < 1:
-        raise ValueError(f"window {window}: at least the query's own key")
     scale = q.shape[-1] ** -0.5
     # heads beside the batch: one leading batch axis for the matmuls
     lead = lambda x: jnp.swapaxes(x, 1, 2).reshape((b * hk, n, x.shape[-1]))  # noqa: E731

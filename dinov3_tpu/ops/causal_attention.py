@@ -1,0 +1,412 @@
+"""The causal tiles of ``ops/attention.py causal_blockwise_attention`` as
+two Pallas kernels: banded, grouped heads in the rows, value width free.
+
+The mathematics is the plain path's (an online softmax over key tiles,
+from the band's first tile to the diagonal's, masked entries a finite
+-1e30): q, k, v, the probabilities and the score cotangent enter the MXU
+in the activation type with float32 accumulation; the scores, the
+running maximum and sum, every exponential, the log-sum-exp kept for the
+backward and every accumulator are float32. What differs is where a
+tile's score plane lives: in VMEM, from the product that makes it to the
+product that consumes it, where the plain path's XLA fusions write it to
+HBM and read it back between the product, the maximum, the exponential
+and the second product.
+
+Both kernels cut their blocks from the arrays as they lie. ``[B, N, h,
+d]`` is ``[B, N, h * d]`` for free; a block of ``g * d`` lanes at lane
+block ``kv head`` is one key/value head's group of ``g`` query heads, a
+static lane slice a head; k and v are blocks of ``d`` lanes at their own
+head count: never written out g times, never fetched g times. The grid
+is (sequence, key/value head, query block, key tile of the block's
+band): its last axis is as long as the longest band, a block whose band
+is shorter clamps the tile index (no new DMA) and skips the body, so a
+tile above the diagonal or wholly below the window's lower edge costs
+neither a product nor a DMA. Only the tiles an edge crosses build a mask
+(two bodies, chosen by the grid position).
+
+``causal_attn_fwd``: a grid step is one key tile against the ``g``
+query heads of the group, a head at a time (``g * block_q`` rows against
+the ONE key tile held in VMEM); the running maximum, sum and output
+accumulator of every head stay in VMEM scratch until the diagonal's
+tile, which comes last. With ``keep_lse`` (the forward rule of the
+``custom_vjp``) it also writes each row's log-sum-exp, ``[B, hk, g, N]``
+float32, a row vector a head.
+
+``causal_attn_bwd``: ONE kernel, the same grid. Its planes are
+TRANSPOSED (keys in the rows, queries in the lanes: the log-sum-exp and
+``sum(o * do)`` are then row vectors, and dk, dv and the score products
+take their operands as they lie; only dq's product needs its plane
+turned): five products a tile and one exponential, where a dk/dv and a
+dq kernel would make seven and two. dq of the block's ``g`` heads
+accumulates in VMEM scratch along the band; dk and dv of the WHOLE
+sequence of one key/value head accumulate in float32 VMEM scratch
+(``N * (d + dv)`` floats: 16.8 MB at 16,384 tokens of 128 + 128) over
+every query block and head of the group and leave once, rounded, when
+the head's last tile is done. ``causal_attention_path`` refuses a length
+whose dk and dv do not fit.
+
+A q/k width that is not a multiple of the 128-lane tile (latent
+attention's 192) is padded with zeros up to one (a copy through HBM on
+each pass; the products are unchanged, a 192-wide contraction fills two
+MXU passes as a 256-wide one does).
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+KERNEL_NAME = "causal_attn_fwd"
+BACKWARD_KERNEL_NAME = "causal_attn_bwd"
+_LANES = 128
+_NEG = -1e30
+# what the backward may hold of one key/value head's dk and dv: the
+# float32 accumulators and the (double-buffered) blocks they leave in
+_RESIDENT_BYTES = 48 * 1024 * 1024
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    # sequences, key/value heads, query blocks, a block's key tiles
+    dimension_semantics=("parallel", "parallel", "arbitrary", "arbitrary"),
+    vmem_limit_bytes=100 * 1024 * 1024)
+_NN = (((1,), (0,)), ((), ()))
+_NT = (((1,), (1,)), ((), ()))
+
+
+def _dot(a, b, dims=_NN):
+    # one pass of the MXU in the operands' type whatever precision the
+    # caller's context asks of ITS products (Mosaic refuses bfloat16
+    # operands under "highest")
+    return jax.lax.dot_general(a, b, dims, precision=jax.lax.Precision.DEFAULT,
+                               preferred_element_type=jnp.float32)
+
+
+def _iota(shape, axis):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+def _band(i, block_q, block_kv, window):
+    """(first key tile, number of key tiles) of query block ``i`` (a
+    Python int or a traced scalar)."""
+    last = ((i + 1) * block_q - 1) // block_kv
+    if window is None:
+        return 0, last + 1
+    below = i * block_q - window + 1
+    first = (max(below, 0) if isinstance(i, int)
+             else jnp.maximum(below, 0)) // block_kv
+    return first, last - first + 1
+
+
+def _tile_place(block_q, block_kv, window):
+    """Of this grid step: (the tile is of the block's band, an edge
+    crosses it, its first key less the block's first query, the tile's
+    index, the band's length)."""
+    i, t = pl.program_id(2), pl.program_id(3)
+    first, count = _band(i, block_q, block_kv, window)
+    off = (first + t) * block_kv - i * block_q
+    edge = off + block_kv > 1                       # the diagonal
+    if window is not None:                          # the window's lower edge
+        edge = jnp.logical_or(edge, off <= block_q - 1 - window)
+    return t < count, edge, off, first + t, count
+
+
+def _seen(shape, query_axis, off, window):
+    """[query, key] (or [key, query]) entries inside the band."""
+    rel = _iota(shape, 1 - query_axis) - _iota(shape, query_axis) + off
+    seen = rel <= 0
+    return seen if window is None else seen & (rel > -window)
+
+
+def _both_bodies(run, edge, tile):
+    pl.when(jnp.logical_and(run, edge))(functools.partial(tile, True))
+    pl.when(jnp.logical_and(run, jnp.logical_not(edge)))(
+        functools.partial(tile, False))
+
+
+def _fwd_kernel(*refs, scale, group, block_q, block_kv, window, keep_lse):
+    """q_ref [block_q, g * d], k_ref [block_kv, d], v_ref [block_kv, dv],
+    o_ref [block_q, g * dv], lse_ref [g, block_q]; scratch: the group's
+    heads stacked [g, block_q, d], the running maximum and sum
+    [g, block_q, 128] (every lane the same) and the accumulator
+    [g, block_q, dv]."""
+    if keep_lse:
+        q_ref, k_ref, v_ref, o_ref, lse_ref, q_scr, m_scr, l_scr, acc_scr = refs
+    else:
+        q_ref, k_ref, v_ref, o_ref, q_scr, m_scr, l_scr, acc_scr = refs
+    d, dv = q_scr.shape[-1], v_ref.shape[-1]
+    run, edge, off, _, count = _tile_place(block_q, block_kv, window)
+    t = pl.program_id(3)
+
+    @pl.when(t == 0)
+    def _():
+        for j in range(group):
+            q_scr[j] = q_ref[:, j * d:(j + 1) * d]
+        m_scr[...] = jnp.full_like(m_scr, _NEG)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def tile(masked):
+        k, v = k_ref[...], v_ref[...]
+
+        def head(j, carry):
+            s = _dot(q_scr[j], k, _NT) * scale
+            if masked:
+                s = jnp.where(_seen(s.shape, 0, off, window), s, _NEG)
+            m_prev = m_scr[j]
+            m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - pltpu.repeat(m_next, block_kv // _LANES, axis=1))
+            alpha = jnp.exp(m_prev - m_next)
+            l_scr[j] = alpha * l_scr[j] + jnp.sum(p, axis=1, keepdims=True)
+            m_scr[j] = m_next
+            acc_scr[j] = acc_scr[j] * pltpu.repeat(
+                alpha, dv // _LANES, axis=1) + _dot(p.astype(v.dtype), v)
+            return carry
+
+        jax.lax.fori_loop(0, group, head, 0)
+
+    _both_bodies(run, edge, tile)
+
+    @pl.when(t == count - 1)
+    def _():
+        for j in range(group):
+            total = l_scr[j]
+            o_ref[:, j * dv:(j + 1) * dv] = (
+                acc_scr[j] / pltpu.repeat(total, dv // _LANES, axis=1)
+            ).astype(o_ref.dtype)
+            if keep_lse:  # a column of [block_q, 128] laid down as a row
+                lse_ref[j:j + 1, :] = (m_scr[j] + jnp.log(total)).T[:1]
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dq_ref, dk_ref, dv_ref,
+                q_scr, do_scr, dq_scr, dk_scr, dv_scr, *,
+                scale, group, block_q, block_kv, window):
+    """The forward's blocks, do_ref like o_ref, delta_ref (the rows'
+    sum(o * do)) like lse_ref; dq_ref like q_ref; dk_ref [N, d] and
+    dv_ref [N, dv], one key/value head's whole sequence. Scratch: q and
+    do stacked a head, dq [g, block_q, d], dk [N, d] and dv [N, dv], all
+    three float32.
+
+    With s^T = k q^T (keys in the rows), p^T = exp(s^T - lse),
+    dp^T = v do^T and ds^T = p^T (dp^T - delta) scale:
+
+        dv += p^T do,    dk += ds^T q,    dq += (ds^T)^T k
+    """
+    d, dv = q_scr.shape[-1], v_ref.shape[-1]
+    run, edge, off, at, count = _tile_place(block_q, block_kv, window)
+    i, t = pl.program_id(2), pl.program_id(3)
+    last_block = i == pl.num_programs(2) - 1
+
+    @pl.when(jnp.logical_and(i == 0, t == 0))
+    def _():
+        dk_scr[...] = jnp.zeros_like(dk_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
+
+    @pl.when(t == 0)
+    def _():
+        for j in range(group):
+            q_scr[j] = q_ref[:, j * d:(j + 1) * d]
+            do_scr[j] = do_ref[:, j * dv:(j + 1) * dv]
+        dq_scr[...] = jnp.zeros_like(dq_scr)
+
+    def tile(masked):
+        k, v = k_ref[...], v_ref[...]
+        keys = pl.ds(pl.multiple_of(at * block_kv, block_kv), block_kv)
+
+        def head(j, carry):
+            q, do = q_scr[j], do_scr[j]
+            s = _dot(k, q, _NT) * scale                  # [block_kv, block_q]
+            if masked:
+                s = jnp.where(_seen(s.shape, 1, off, window), s, _NEG)
+            p = jnp.exp(s - lse_ref[pl.ds(j, 1), :])
+            dv_scr[keys, :] += _dot(p.astype(do.dtype), do)
+            ds = p * (_dot(v, do, _NT) - delta_ref[pl.ds(j, 1), :]) * scale
+            dk_scr[keys, :] += _dot(ds.astype(q.dtype), q)
+            dq_scr[j] += _dot(ds.T.astype(k.dtype), k)
+            return carry
+
+        jax.lax.fori_loop(0, group, head, 0)
+
+    _both_bodies(run, edge, tile)
+
+    @pl.when(t == count - 1)
+    def _():
+        for j in range(group):
+            dq_ref[:, j * d:(j + 1) * d] = dq_scr[j].astype(dq_ref.dtype)
+
+    @pl.when(jnp.logical_and(last_block, t == count - 1))
+    def _():
+        dk_ref[...] = dk_scr[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
+
+
+def _operands(q, k, block_q, block_kv, window):
+    """(q and k widened to the lane tile, the grid, the block of a
+    [B, N, h * w] array of query heads, the block of a [B, N, hk * w]
+    array of keys or values along the band, the block of a [B, hk, g, N]
+    array of row statistics)."""
+    b, n, h, d = q.shape
+    hk = k.shape[2]
+    g, pad = h // hk, (-d) % _LANES
+    if pad:
+        q, k = (jnp.pad(x, ((0, 0),) * 3 + ((0, pad),)) for x in (q, k))
+    blocks = n // block_q
+    steps = max(_band(i, block_q, block_kv, window)[1] for i in range(blocks))
+
+    def band_tile(s, kh, i, t):
+        first, count = _band(i, block_q, block_kv, window)
+        return s, first + jnp.minimum(t, count - 1), kh
+
+    vmem = pltpu.VMEM
+    rows = lambda w: pl.BlockSpec(  # noqa: E731
+        (None, block_q, g * w), lambda s, kh, i, t: (s, i, kh),
+        memory_space=vmem)
+    keys = lambda w: pl.BlockSpec(  # noqa: E731
+        (None, block_kv, w), band_tile, memory_space=vmem)
+    stats = pl.BlockSpec((None, None, g, block_q),
+                         lambda s, kh, i, t: (s, kh, 0, i), memory_space=vmem)
+    return q, k, (b, hk, blocks, steps), rows, keys, stats
+
+
+# A ``pallas_call`` traces its kernel body every time it is called, and a
+# trace of the step calls these wrappers three times an attention layer
+# (the pass, the forward rule under the layer's remat, the backward),
+# twice a set-up: under ``jit`` every call of one shape shares one trace
+# (ops/kda.py, PR 31); ``inline`` leaves no call in the program.
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "scale", "window", "block_q", "block_kv", "keep_lse", "interpret"))
+def _kernel_forward(q, k, v, scale, window, block_q, block_kv, keep_lse,
+                    interpret):
+    """(o [B, N, h, dv], the rows' log-sum-exp [B, hk, g, N] float32 or
+    None) as one ``pallas_call``."""
+    b, n, h, _ = q.shape
+    hk, dv = v.shape[2], v.shape[3]
+    g = h // hk
+    q, k, grid, rows, keys, stats = _operands(q, k, block_q, block_kv, window)
+    d = q.shape[-1]
+    flat = lambda x: x.reshape(b, n, -1)  # noqa: E731
+    out_specs = [rows(dv)]
+    out_shape = [jax.ShapeDtypeStruct((b, n, h * dv), v.dtype)]
+    if keep_lse:
+        out_specs.append(stats)
+        out_shape.append(jax.ShapeDtypeStruct((b, hk, g, n), jnp.float32))
+    out = pl.pallas_call(
+        functools.partial(_fwd_kernel, scale=scale, group=g, block_q=block_q,
+                          block_kv=block_kv, window=window, keep_lse=keep_lse),
+        grid=grid,
+        in_specs=[rows(d), keys(d), keys(dv)],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=[
+            pltpu.VMEM((g, block_q, d), q.dtype),
+            pltpu.VMEM((g, block_q, _LANES), jnp.float32),
+            pltpu.VMEM((g, block_q, _LANES), jnp.float32),
+            pltpu.VMEM((g, block_q, dv), jnp.float32)],
+        compiler_params=_COMPILER_PARAMS,
+        interpret=interpret,
+        name=KERNEL_NAME,
+    )(flat(q), flat(k), flat(v))
+    return out[0].reshape(b, n, h, dv), (out[1] if keep_lse else None)
+
+
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "scale", "window", "block_q", "block_kv", "interpret"))
+def _kernel_backward(q, k, v, o, lse, do, scale, window, block_q, block_kv,
+                     interpret):
+    """The cotangents of q, k, v, in their types and widths, as one
+    ``pallas_call`` from what the forward rule kept."""
+    b, n, h, width = q.shape
+    hk, dv = v.shape[2], v.shape[3]
+    g = h // hk
+    # a product would round its float32 operands on the TPU: multiply, add
+    delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
+    delta = jnp.swapaxes(delta, 1, 2).reshape(b, hk, g, n)
+    qp, kp, grid, rows, keys, stats = _operands(q, k, block_q, block_kv, window)
+    d = qp.shape[-1]
+    flat = lambda x: x.reshape(b, n, -1)  # noqa: E731
+    whole = lambda w: pl.BlockSpec(  # noqa: E731
+        (None, n, w), lambda s, kh, i, t: (s, 0, kh), memory_space=pltpu.VMEM)
+    dq, dk, dv_ = pl.pallas_call(
+        functools.partial(_bwd_kernel, scale=scale, group=g, block_q=block_q,
+                          block_kv=block_kv, window=window),
+        grid=grid,
+        in_specs=[rows(d), keys(d), keys(dv), rows(dv), stats, stats],
+        out_specs=[rows(d), whole(d), whole(dv)],
+        out_shape=[jax.ShapeDtypeStruct((b, n, h * d), q.dtype),
+                   jax.ShapeDtypeStruct((b, n, hk * d), k.dtype),
+                   jax.ShapeDtypeStruct((b, n, hk * dv), v.dtype)],
+        scratch_shapes=[
+            pltpu.VMEM((g, block_q, d), q.dtype),
+            pltpu.VMEM((g, block_q, dv), do.dtype),
+            pltpu.VMEM((g, block_q, d), jnp.float32),
+            pltpu.VMEM((n, d), jnp.float32),
+            pltpu.VMEM((n, dv), jnp.float32)],
+        compiler_params=_COMPILER_PARAMS,
+        interpret=interpret,
+        name=BACKWARD_KERNEL_NAME,
+    )(flat(qp), flat(kp), flat(v), flat(do), lse, delta)
+    return (dq.reshape(b, n, h, d)[..., :width],
+            dk.reshape(b, n, hk, d)[..., :width], dv_.reshape(b, n, hk, dv))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def kernel_attention(q, k, v, scale, window, block_q, block_kv, interpret):
+    """``causal_blockwise_attention`` on the kernel path ([B, N, h, d] q,
+    [B, N, hk, d] k, [B, N, hk, dv] v; ``causal_attention_path`` says
+    which shapes it takes)."""
+    return _kernel_forward(q, k, v, scale=scale, window=window,
+                           block_q=block_q, block_kv=block_kv,
+                           keep_lse=False, interpret=interpret)[0]
+
+
+def _kernel_attention_fwd(q, k, v, scale, window, block_q, block_kv, interpret):
+    o, lse = _kernel_forward(q, k, v, scale=scale, window=window,
+                             block_q=block_q, block_kv=block_kv,
+                             keep_lse=True, interpret=interpret)
+    return o, (q, k, v, o, lse)
+
+
+def _kernel_attention_bwd(scale, window, block_q, block_kv, interpret, res, do):
+    return _kernel_backward(*res, do, scale=scale, window=window,
+                            block_q=block_q, block_kv=block_kv,
+                            interpret=interpret)
+
+
+# optimize_remat: under a layer's remat the pass that keeps no residuals
+# runs the primal (no log-sum-exp written), not the forward rule
+kernel_attention.defvjp(_kernel_attention_fwd, _kernel_attention_bwd,
+                        optimize_remat=True)
+
+
+def causal_attention_path(shapes, window: int | None = None,
+                          interpret: bool | None = None, block_q: int = 512,
+                          block_kv: int = 1024, dtype=jnp.bfloat16,
+                          reduce_dtype=jnp.float32) -> tuple[str, str]:
+    """(path, why) ``causal_blockwise_attention`` takes for q, k, v of
+    these three ``shapes`` and this one ``dtype`` on this backend:
+    ("kernel", ...) or ("tiles", the reason it is not the kernel)."""
+    (_, n, _, d), _, (_, _, _, dv) = shapes
+    if dtype not in (jnp.bfloat16, jnp.float32):
+        return "tiles", f"{jnp.dtype(dtype).name} is neither bfloat16 nor float32"
+    if reduce_dtype != jnp.float32:
+        return "tiles", (f"statistics in {jnp.dtype(reduce_dtype).name}: the "
+                         "kernels' are float32")
+    if dv % _LANES:
+        return "tiles", f"the value width {dv} is not a multiple of {_LANES}"
+    if block_q % _LANES or block_kv % _LANES:
+        return "tiles", (f"blocks of {block_q} x {block_kv} are not "
+                         f"multiples of {_LANES}")
+    if n % block_q or n % block_kv:
+        return "tiles", (f"{n} tokens are not whole blocks of {block_q} "
+                         f"queries and {block_kv} keys")
+    wide = d + (-d) % _LANES
+    resident = n * (wide + dv) * (4 + 2 * jnp.dtype(dtype).itemsize)
+    if resident > _RESIDENT_BYTES:
+        return "tiles", (f"dk and dv of {n} tokens ({resident >> 20} MiB) "
+                         "do not fit the backward's VMEM")
+    backend = jax.default_backend()
+    if interpret is None and backend != "tpu":
+        return "tiles", f"the backend is {backend}, not a TPU"
+    return "kernel", "interpreted" if interpret else "compiled for the TPU"

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 
 from dinov3_tpu.configs import ConfigNode
 from dinov3_tpu.models import build_backbone
+from dinov3_tpu.ops.causal_attention import causal_attention_path
 from dinov3_tpu.ops.kda import kda_path
 
 logger = logging.getLogger("dinov3")
@@ -46,10 +47,26 @@ class LMMetaArch:
         self.student_backbone = build_backbone(cfg, param_dtype=jnp.float32)
         self.embed_dim = self.student_backbone.embed_dim
         dc = self.student_backbone.cfg
+        # the paths are static a shape: which calls take a kernel IS how
+        # often it engages (the trace then names the kernels)
+        rows = (int(cfg.train.batch_size_per_device), int(cfg.lm.seq_len))
+        heads, qk = dc.num_attention_heads, dc.qk_nope_head_dim + dc.qk_rope_head_dim
+        gqa = ((heads, dc.head_dim),) + ((dc.num_key_value_heads, dc.head_dim),) * 2
+        cores = {  # the scope, the (heads, width) of q, k, v and the window
+            "mla": ("mla_core", ((heads, qk),) * 2 + ((heads, dc.v_head_dim),), None),
+            "swa": ("gqa_core", gqa, dc.sliding_window),
+            "full_attn": ("gqa_core", gqa, None)}
         for i, (mixer, _) in enumerate(dc.layers, 1):
             if mixer == "kda":
                 path, why = kda_path(dc.kda_head_dim, dc.kda_head_dim)
                 logger.info("layer %d kda_core, both passes: %s (%s)", i, path, why)
+            else:
+                scope, shapes, window = cores[mixer]
+                path, why = causal_attention_path(
+                    tuple(rows + s for s in shapes), window, dtype=dc.dtype,
+                    reduce_dtype=dc.reduce_dtype)
+                logger.info("layer %d %s (%s), both passes: %s (%s)", i, scope,
+                            mixer, path, why)
 
     def init_params(self, rng: jax.Array, batch: dict, unbox: bool = True) -> dict:
         import flax.linen as nn

@@ -3,7 +3,8 @@ attached (on-chip-measurement guide §2.3): flash attention forward and
 backward, plain and segment-masked, at the 768 px (N=2309) and 1024 px
 (N=4101) ViT-L token counts, the fused layernorm forward and
 backward at ViT-L width, and the delta rule's chunk forward and
-backward at the decoder cell's shapes — each with ``interpret=False``, each asserting
+backward at the decoder cell's shapes, and the causal attention kernels
+at both decoder cells' published shapes — each with ``interpret=False``, each asserting
 a Mosaic ``tpu_custom_call`` in the compiled text. What the chip's
 compiler would refuse (a slice off the tiling, too much VMEM) fails
 here, at no chip time. A compile that passes is not a chip run.
@@ -132,3 +133,45 @@ def test_kda_chunk_kernels_compile_for_v5e(one_chip, states):
         # over the chunks beside the kernels
         assert "f32[128,2,32,128,128]" in text
         assert "f32[2,128,16,64,128]" in text and " while(" not in text
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+@pytest.mark.parametrize("q, kv, dv, window", [
+    ((1, 16384, 28, 128), 4, 128, 4096),
+    ((1, 16384, 28, 128), 4, 128, None),
+    ((2, 8192, 32, 192), 32, 128, None),
+], ids=["window", "global", "mla"])
+def test_causal_attention_kernels_compile_for_v5e(one_chip, q, kv, dv,
+                                                  window, direction):
+    """``ops/causal_attention.py`` at the shapes the two decoder cells
+    send ``causal_blockwise_attention``: the 16k cell's window and global
+    grouped-query layers (28 query heads on 4 of 128) and the 8k cell's
+    latent attention (q and k 192 wide, v 128), at the shipped blocks. The
+    gradient's program holds the forward rule and ONE backward kernel."""
+    from dinov3_tpu.ops.causal_attention import (
+        BACKWARD_KERNEL_NAME,
+        KERNEL_NAME,
+        causal_attention_path,
+        kernel_attention,
+    )
+
+    k = (q[:2] + (kv, q[3]), jnp.bfloat16)
+    v = (q[:2] + (kv, dv), jnp.bfloat16)
+    do = (q[:3] + (dv,), jnp.bfloat16)
+    q = (q, jnp.bfloat16)
+    assert causal_attention_path(
+        (q[0], k[0], v[0]), window, False)[0] == "kernel"
+
+    def fwd(*x):
+        return kernel_attention(*x, q[0][3] ** -0.5, window, 512, 1024, False)
+
+    def bwd(*x):
+        return jax.vjp(fwd, *x[:-1])[1](x[-1])
+
+    fn, shapes = (fwd, [q, k, v]) if direction == "fwd" else (
+        bwd, [q, k, v, do])
+    text = _compiled_text(fn, one_chip, *shapes)
+    assert KERNEL_NAME in text
+    assert text.count("tpu_custom_call") == (1 if direction == "fwd" else 2)
+    assert (BACKWARD_KERNEL_NAME in text) == (direction == "bwd")
+    assert " while(" not in text

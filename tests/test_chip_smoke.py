@@ -43,8 +43,14 @@ TINY = {
     "ln_shape": (64, 128),
     "kda_shape": (1, 128, 1, 128),
     "kernel_interpret": True,
-    "gqa_shape": (1, 300, 6, 2, 16, 64),
-    "gqa_blocks": [(32, 64)],
+    # lanes of 128 and blocks that are multiples of it: what the kernels
+    # (interpreted here) take; a window no block divides
+    "gqa_shapes": {"window": (1, 512, 6, 2, 128, 128, 200),
+                   "global": (1, 512, 6, 2, 128, 128, None),
+                   "mla": (2, 256, 2, 2, 192, 128, None)},
+    "gqa_shipped_blocks": (128, 256),
+    "gqa_blocks": [(256, 128)],
+    "gqa_timeout_s": 600,
     "serve_overrides": [
         "student.arch=vit_test", "student.patch_size=4", "serve.min_px=8",
         "serve.max_px=32", "serve.rows=4", "serve.row_tokens=65",
@@ -107,7 +113,10 @@ def test_smoke_rehearsal_runs_every_phase(smoke, monkeypatch, capsys):
                    "kernels: fused_layernorm", "kernels: kda_chunk_fwd",
                    "decay spikes of 200", "serve:",
                    "compiles packed 1", "lm: losses", "lm: resumed at 3",
-                   "gqa: window core", "gqa: global core at blocks 32 x 64",
+                   "gqa: window core (1, 512, 6, 2, 128, 128) window 200: the "
+                   "entry point takes the kernel (interpreted)",
+                   "gqa: global core, tiles: first calls",
+                   "gqa: mla core, kernel at blocks 256 x 128",
                    "all phases passed"):
         assert needle in said, needle
     assert "mesh[" not in said  # the four-chip phase is behind --chips 4

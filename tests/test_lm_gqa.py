@@ -580,6 +580,57 @@ def test_compiled_step_holds_the_family_phases(tiny_setup):
     assert family < set(STEP_PHASES)
 
 
+def test_step_on_the_kernel_path_holds_one_kernel_body_a_shape(monkeypatch):
+    """The family's whole step, traced with ``causal_attention_path``
+    answering "kernel" (heads of 128, a sequence of whole blocks; the test
+    steers, the program has no option): every attention layer gives its
+    primal pass, the forward rule again under the layer's remat and ONE
+    backward kernel, and no layer a loop over query blocks; the kernel
+    bodies are traced once a shape (the window layers' and the global
+    layer's), not once a call: a ``pallas_call`` traces its body whenever
+    it is called, and the step is traced twice a set-up (PERF.md, PR 31)."""
+    from test_lm_decoder import _loops_and_kernels
+
+    from dinov3_tpu.data import make_synthetic_batch
+    from dinov3_tpu.ops import causal_attention as kernels
+    from dinov3_tpu.train import build_train_setup
+
+    def names(extra):
+        cfg = tiny_cfg(extra)
+        batch = {k: jnp.asarray(v)
+                 for k, v in make_synthetic_batch(cfg, 2, seed=0).items()}
+        setup = build_train_setup(cfg, batch, devices=jax.devices()[:1],
+                                  init_state=False)
+        args = (setup.state, batch, setup.scalars(0), jax.random.key(0))
+        layers = [m for m, _ in setup.meta.student_backbone.cfg.layers]
+        return layers, _loops_and_kernels(
+            jax.make_jaxpr(setup.step_fn)(*args).jaxpr, [])
+
+    layers, plain = names([])
+    assert layers == ["full_attn", "swa", "swa", "swa"]
+    assert not [n for n in plain if n.startswith("causal_attn")]
+    monkeypatch.setattr(kernels, "causal_attention_path",
+                        lambda *a, **k: ("kernel", "the test says so"))
+    bodies = []
+    for body in ("_fwd_kernel", "_bwd_kernel"):
+        def counted(*a, _body=getattr(kernels, body), **k):
+            bodies.append((_body.__name__, k["window"], k.get("keep_lse")))
+            return _body(*a, **k)
+        monkeypatch.setattr(kernels, body, counted)
+    _, found = names(["lm.head_dim=128", "lm.seq_len=1024",
+                      "lm.sliding_window_size=300"])
+    assert found.count(kernels.KERNEL_NAME) == 2 * len(layers)
+    assert found.count(kernels.BACKWARD_KERNEL_NAME) == len(layers)
+    assert sorted(bodies, key=str) == sorted([
+        ("_fwd_kernel", w, keep) for w in (300, None)
+        for keep in (False, True)] + [
+            ("_bwd_kernel", w, None) for w in (300, None)], key=str)
+    # no attention layer brought a loop (at TINY's 100 tokens the plain
+    # tiles have none either: a single block of queries), nothing else moved
+    loops = lambda xs: sum(n in ("scan", "while") for n in xs)  # noqa: E731
+    assert loops(plain) == loops(found)
+
+
 def test_benchmark_vocabulary_of_the_family_is_the_programs():
     with open(os.path.join(BENCH, "lm_gqa_phases.json")) as f:
         bench = json.load(f)
