@@ -4,7 +4,8 @@
                                     # accumulation, kernels, serve, the
                                     # decoder's next-token trainer, the
                                     # decoders' causal attention cores
-                                    # (the kernels beside the plain tiles)
+                                    # (the kernels beside the plain tiles),
+                                    # the third decoder's two cores
     python chip_smoke.py --phases lm   # one chip, that phase alone
     python chip_smoke.py --chips 4  # four chips: ONLY the sharded train
                                     # arms and their one-device comparison
@@ -86,6 +87,11 @@ SIZES = {
     "gqa_shapes": {"window": (1, 16384, 28, 4, 128, 128, 4096),
                    "global": (1, 16384, 28, 4, 128, 128, None),
                    "mla": (2, 8192, 32, 32, 192, 128, None)},
+    # the third decoder's two cores at published sizes (--phases gdn): the
+    # delta rule with ONE decay a head, [B, T, value heads, d_k = d_v],
+    # and the gated attention's causal core (16 query heads on 2 of 256)
+    "gdn_shape": (2, 8192, 32, 128),
+    "gdn_attn_shapes": {"gated": (2, 8192, 16, 2, 256, 256, None)},
     "gqa_shipped_blocks": (512, 1024),
     "gqa_blocks": [(512, 512), (1024, 1024), (256, 1024)],
     "gqa_timeout_s": 1200,
@@ -96,7 +102,7 @@ SIZES = {
                         (384, 384), (224, 224), (128, 128), (448, 320)],
 }
 
-ONE_CHIP_PHASES = ("trainer", "accum", "kernels", "serve", "lm", "gqa")
+ONE_CHIP_PHASES = ("trainer", "accum", "kernels", "serve", "lm", "gqa", "gdn")
 
 _T0 = time.time()
 
@@ -460,9 +466,24 @@ def _kda_kernel_row(interpret: bool) -> None:
         found[0], *found[4:]) <= 1e-4 and max(found[1:4]) <= 2 ** -8, found
 
 
+def _timed(fn, x, n=3):
+    """(seconds of the first call, ms a call over ``n`` more, the last
+    result), each fenced."""
+    import jax
+
+    t0 = time.time()
+    jax.block_until_ready(fn(*x))
+    first = time.time() - t0
+    t0 = time.time()
+    for _ in range(n):
+        out = fn(*x)
+    jax.block_until_ready(out)
+    return first, (time.time() - t0) / n * 1e3, out
+
+
 # ------------------------------------------- the banded grouped-query core
 
-def phase_gqa() -> None:
+def phase_gqa(shapes: str = "gqa_shapes") -> None:
     """``ops/attention.py causal_blockwise_attention`` as the decoders'
     layers call it, at the published head sizes and whole contexts: the
     window and the global grouped-query core of the ``smallthinker``
@@ -517,16 +538,6 @@ def phase_gqa() -> None:
             lambda *y: jnp.sum(jnp.sin(f(*y).astype(jnp.float32))),
             argnums=(0, 1, 2))(*x)))
 
-    def timed(fn, x, n=3):
-        t0 = time.time()
-        jax.block_until_ready(fn(*x))
-        first = time.time() - t0
-        t0 = time.time()
-        for _ in range(n):
-            out = fn(*x)
-        jax.block_until_ready(out)
-        return first, (time.time() - t0) / n * 1e3, out
-
     def gaps(got, want):  # the difference's norm over the reference's
         f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
         return [float(jnp.linalg.norm(f32(a) - f32(r)) / jnp.linalg.norm(f32(r)))
@@ -535,7 +546,7 @@ def phase_gqa() -> None:
     said = lambda xs: " ".join(f"{x:.3e}" for x in xs)  # noqa: E731
 
     bq, bkv = SIZES["gqa_shipped_blocks"]
-    for name, (b, t, h, hk, d, dv, w) in SIZES["gqa_shapes"].items():
+    for name, (b, t, h, hk, d, dv, w) in SIZES[shapes].items():
         ks = jax.random.split(jax.random.key(2), 3)
         x = tuple(jax.random.normal(key, shape, jnp.bfloat16)
                   for key, shape in zip(ks, (
@@ -556,8 +567,8 @@ def phase_gqa() -> None:
         _compiled_has_kernel(out_and_grads(kernel), *x)
         found = {}
         for path, fn in (("kernel", kernel), ("tiles", tiles)):
-            first_f, ms_f, _ = timed(jax.jit(fn), x)
-            first, ms, found[path] = timed(out_and_grads(fn), x)
+            first_f, ms_f, _ = _timed(jax.jit(fn), x)
+            first, ms, found[path] = _timed(out_and_grads(fn), x)
             log(f"gqa: {name} core, {path}: first calls {first_f:.1f}s and "
                 f"{first:.1f}s, forward {ms_f:.1f} ms, forward + backward "
                 f"{ms:.1f} ms")
@@ -571,11 +582,76 @@ def phase_gqa() -> None:
         for got in found.values():
             assert all(g <= 2e-2 for g in gaps(got, want)), name
         for obq, obkv in SIZES["gqa_blocks"]:
-            first, ms, _ = timed(out_and_grads(functools.partial(
+            first, ms, _ = _timed(out_and_grads(functools.partial(
                 kernel, bq=obq, bkv=obkv)), x)
             log(f"gqa: {name} core, kernel at blocks {obq} x {obkv}: first "
                 f"call {first:.1f}s, forward + backward {ms:.1f} ms")
     faulthandler.cancel_dump_traceback_later()
+
+
+# ------------------------------------- the third decoder's two cores
+
+def phase_gdn() -> None:
+    """The two cores of the ``qwen3_next`` family stand-alone at published
+    sizes. The delta rule as ``GDNMixer`` calls it — ``ops/kda.py
+    kda_chunked`` with ONE log decay a head and token broadcast over the
+    key channels, at decays as large as the family's initial values give
+    (16 x softplus(1) = 21 nats a token on the fastest head): the path
+    ``kda_path`` takes there, the kernels against the plain scan, output
+    and five gradients (the decay's summed back over the channels), both
+    timed. (Against the token recurrence itself: the ``kernels`` phase, on
+    a row short enough for its gradient.) Then the gated attention's
+    causal core through ``phase_gqa``'s rows."""
+    import faulthandler
+
+    import jax
+    import jax.numpy as jnp
+
+    from dinov3_tpu.ops import kda
+
+    interpret = bool(SIZES["kernel_interpret"])
+    faulthandler.dump_traceback_later(
+        float(SIZES["gqa_timeout_s"]), exit=True, file=sys.__stderr__)
+    b, t, h, d = SIZES["gdn_shape"]
+    path, why = kda.kda_path(d, d, interpret=interpret or None)
+    log(f"gdn: delta rule {(b, t, h, d)} at a scalar gate: the entry point "
+        f"takes the {path} ({why})")
+    assert path == "kernel", (path, why)
+    ks = jax.random.split(jax.random.key(3), 5)
+    unit = lambda x: (x / jnp.linalg.norm(  # noqa: E731
+        x.astype(jnp.float32), axis=-1, keepdims=True)).astype(jnp.bfloat16)
+    q, k = (unit(jax.random.normal(key, (b, t, h, d))) for key in ks[:2])
+    v = jax.random.normal(ks[2], (b, t, h, d), jnp.bfloat16)
+    rate = jnp.linspace(0.05, 16.0, h)[None, None, :]       # A, a head
+    g = -rate * jax.nn.softplus(1.0 + jax.random.normal(ks[3], (b, t, h)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, t, h)))
+    x = (q, k, v, g, beta)
+
+    def core(scan):
+        def fn(q, k, v, g, beta):
+            gb = jnp.broadcast_to(g[..., None], (b, t, h, d))
+            if scan:
+                return kda._scan_forward(q, k, v, gb, beta, kda.CHUNK, d ** -0.5)
+            return kda.kda_chunked(q, k, v, gb, beta, q_scale=d ** -0.5,
+                                   interpret=True if interpret else None)
+        return jax.jit(lambda *a: (fn(*a), *jax.grad(
+            lambda *y: jnp.sum(jnp.sin(fn(*y))), argnums=(0, 1, 2, 3, 4))(*a)))
+
+    found = {}
+    for name, fn in (("kernel", core(False)), ("scan", core(True))):
+        first, ms, found[name] = _timed(fn, x)
+        log(f"gdn: delta rule, {name}: first call {first:.1f}s, forward + "
+            f"backward {ms:.1f} ms")
+    f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
+    gaps = [float(jnp.linalg.norm(f32(a) - f32(r)) / jnp.linalg.norm(f32(r)))
+            for a, r in zip(found["kernel"], found["scan"])]
+    log("gdn: delta rule: norm of the difference over the norm, kernel to "
+        "scan, output and gradients q k v g beta "
+        + " ".join(f"{x:.3e}" for x in gaps))
+    assert all(math.isfinite(x) for x in gaps) and gaps[0] <= 1e-4 \
+        and max(gaps[1:4]) <= 2e-2 and max(gaps[4:]) <= 1e-3, gaps
+    faulthandler.cancel_dump_traceback_later()
+    phase_gqa("gdn_attn_shapes")
 
 
 # ------------------------------------------------------------------ serve
@@ -862,7 +938,8 @@ def main(argv=None) -> int:
     if args.chips == 1:
         run = {"trainer": lambda: phase_trainer(cache), "accum": phase_accum,
                "kernels": phase_kernels, "serve": phase_serve,
-               "lm": lambda: phase_lm(cache), "gqa": phase_gqa}
+               "lm": lambda: phase_lm(cache), "gqa": phase_gqa,
+               "gdn": phase_gdn}
         for name in phases:
             run[name]()
     else:

@@ -1,13 +1,17 @@
-"""A causal decoder whose mixer and FFN are chosen layer by layer. Two
+"""A causal decoder whose mixer and FFN are chosen layer by layer. Three
 families (``student.arch``): ``kimi_linear`` (Kimi Linear, Moonshot AI;
 ``config.json`` of Kimi-Linear-48B-A3B-Instruct and the model's report:
-KDA and MLA mixers, a dense SwiGLU, routed + shared experts) and
+KDA and MLA mixers, a dense SwiGLU, routed + shared experts),
 ``smallthinker`` (SmallThinker, PowerInfer; ``config.json`` of
 SmallThinker-21BA3B-Instruct: grouped-query mixers with a window and
 rotary or with neither, routed ReGLU experts whose router reads the
-layer's input).
+layer's input) and ``qwen3_next`` (Qwen3-Next, Qwen; ``config.json`` of
+Qwen3-Next-80B-A3B-Instruct: Gated DeltaNet and gated grouped-query
+mixers 3 : 1, zero-centred norms, routed SwiGLU experts beside a shared
+one behind a sigmoid gate).
 
-Pre-norm residual layers, RMSNorm everywhere:
+Pre-norm residual layers, RMSNorm everywhere (``qwen3_next``: zero-centred,
+n(x) = x / rms(x) * (1 + w), but for the delta rule's output norm):
 
 - **KDA** (Kimi Delta Attention), per head h with d_k = d_v = head_dim,
   x_t the normed input:
@@ -29,6 +33,25 @@ Pre-norm residual layers, RMSNorm everywhere:
   token t sees keys t - ``sliding_window`` < j <= t. ``full_attn``: every
   key up to its own and no rotation at all. Same ``ops/attention.py``
   tiles as MLA. W_o.
+- **GDN** (Gated DeltaNet; ``gdn``), ``linear_num_key_heads`` key heads
+  under ``linear_num_value_heads`` value heads: [q ; k ; v ; z] = W_qkvz x,
+  [b ; a] = W_ba x; [q ; k ; v] <- SiLU(conv([q ; k ; v])), ONE causal
+  depthwise convolution of width ``linear_conv_kernel_dim`` over the
+  joined channels; per value head j, served by key head j // (value
+  heads / key heads): q_t, k_t L2-normalised (eps 1e-6), q_t * d_k^-0.5;
+  b_t = sigmoid(b); g_t = -exp(A_log_j) softplus(a_t + dt_bias_j), ONE
+  log decay a head and token; S_t = (I - b_t k_t k_t^T) e^{g_t} S_{t-1} +
+  b_t k_t v_t^T; o_t = S_t^T q_t; y_t = W_o [RMSNorm_head(o_t) * SiLU(z_t)]
+  (this one norm's scale is w, from ones). The delta rule is KDA's own
+  ``ops/kda.py``, the decay broadcast over the key channels. W_qkvz's
+  and W_ba's columns are held in the published grouping, a key head at a
+  time: [q d_k | k d_k | v r d_v | z r d_v] and [b r | a r], r = value
+  heads / key heads.
+- **Gated attention** (``gated_attn``): GQA with [q_i ; gate_i] = W_q x a
+  head, q_i <- n_q(q_i) and k <- n_k(k) (the layer's kind of norm, one
+  scale vector over ``head_dim`` for all heads), the first ``rotary_dim``
+  channels of every q and k head rotated over token positions, the rest
+  untouched; no window; y = W_o [o * sigmoid(gate)].
 - **FFN**: ``kimi_linear``: SwiGLU of ``intermediate_size`` in the first
   ``first_k_dense_replace`` layers; after them the routed experts this
   shard holds (``ops/ffn.py RoutedExpertsFFN``, sigmoid router) plus
@@ -37,6 +60,11 @@ Pre-norm residual layers, RMSNorm everywhere:
   experts, softmax over the chosen logits, no shared expert; the router's
   logits are W_r x of the LAYER'S INPUT x, before the first norm and the
   mixer (``router_reads_layer_input``), the experts read n2(x').
+  ``qwen3_next``: every layer routed, SwiGLU experts, softmax over the
+  chosen logits of a router that reads n2(x') like its experts, plus ONE
+  shared expert times sigmoid(w_s . n2(x')) (``shared_expert_gate``); the
+  row buffer of the routed layer holds ``expert_rows_factor`` times its
+  experts' even share (the recipe's ``lm.expert_rows_factor``).
 
 The vocabulary may be a slice (``vocab_size`` rows of the published
 table): ids, logits and the loss are over the slice. Embedding and head
@@ -46,15 +74,17 @@ logits never exist whole.
 
 The step's phases (``utils.STEP_PHASES``): ``lm_embed``, ``kda_mixer``
 (inner ``kda_core``), ``mla_mixer`` (inner ``mla_core``), ``swa_mixer`` and ``full_attn_mixer``
-(inner ``gqa_core``), ``dense_ffn``, ``moe_ffn`` (inner ``moe_route``,
-``moe_experts`` from the routed layer, ``moe_shared``), ``lm_head_loss``.
+(inner ``gqa_core``), ``gdn_mixer`` (inner ``gdn_core``),
+``gated_attn_mixer`` (inner ``gqa_core``), ``dense_ffn``, ``moe_ffn`` (inner
+``moe_route``, ``moe_experts`` from the routed layer, ``moe_shared``: the
+shared expert, with its gate where it has one), ``lm_head_loss``.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any
+from typing import Any, Callable
 
 import flax.linen as nn
 import jax
@@ -62,10 +92,10 @@ import jax.numpy as jnp
 
 from dinov3_tpu.ops.attention import dispatch_attention
 from dinov3_tpu.ops.common import l2_normalize, part, trunc_normal_init
-from dinov3_tpu.ops.ffn import RoutedExpertsFFN, SwiGLUFFN
+from dinov3_tpu.ops.ffn import ROWS_CAPACITY_FACTOR, RoutedExpertsFFN, SwiGLUFFN
 from dinov3_tpu.ops.kda import kda_chunked
 from dinov3_tpu.ops.norms import RMSNorm
-from dinov3_tpu.ops.rope import rope_apply_full, token_rope_sincos
+from dinov3_tpu.ops.rope import rope_apply_leading, token_rope_sincos
 from dinov3_tpu.utils import step_phase
 
 
@@ -106,6 +136,16 @@ class DecoderConfig:
     router: str = "sigmoid"            # ops/ffn.py RoutedExpertsFFN's rules
     gate: str = "silu"
     router_reads_layer_input: bool = False
+    # qwen3_next
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 0
+    rotary_dim: int = 0                # of head_dim; 0: all of it
+    zero_centered_norms: bool = False
+    shared_expert_gate: bool = False
+    expert_rows_factor: float = ROWS_CAPACITY_FACTOR
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     reduce_dtype: Any = jnp.float32
@@ -116,7 +156,8 @@ class DecoderConfig:
 
         policy = Policy.from_cfg(cfg.compute_precision)
         family = {"kimi_linear": _kimi_linear_fields,
-                  "smallthinker": _smallthinker_fields}[str(cfg.student.arch)]
+                  "smallthinker": _smallthinker_fields,
+                  "qwen3_next": _qwen3_next_fields}[str(cfg.student.arch)]
         return cls(dtype=policy.compute_dtype,
                    param_dtype=param_dtype or policy.param_dtype,
                    reduce_dtype=policy.reduce_dtype, **family(cfg.lm))
@@ -170,6 +211,47 @@ def _smallthinker_fields(lm) -> dict:
         router="softmax", gate="relu", router_reads_layer_input=True)
 
 
+def _qwen3_next_fields(lm) -> dict:
+    if not bool(lm.norm_topk_prob):
+        raise ValueError("the routed layer is a softmax router renormalised "
+                         "over the chosen experts")
+    if int(lm.decoder_sparse_step) != 1 or list(lm.mlp_only_layers):
+        raise ValueError("every layer's FFN is routed (lm.decoder_sparse_step "
+                         "1, no lm.mlp_only_layers)")
+    width, shared = (int(lm.moe_intermediate_size),
+                     int(lm.shared_expert_intermediate_size))
+    if shared % width:
+        raise ValueError(f"lm.shared_expert_intermediate_size {shared} is not "
+                         f"a multiple of lm.moe_intermediate_size {width}")
+    rotary = float(lm.partial_rotary_factor) * int(lm.head_dim)
+    if rotary != int(rotary) or int(rotary) % 2 or not 0 < rotary <= lm.head_dim:
+        raise ValueError(f"lm.partial_rotary_factor {lm.partial_rotary_factor} "
+                         f"of lm.head_dim {lm.head_dim} is not an even "
+                         "number of channels")
+    every = int(lm.full_attention_interval)
+    return dict(
+        layers=tuple(("gated_attn" if (i + 1) % every == 0 else "gdn", "moe")
+                     for i in range(int(lm.num_hidden_layers))),
+        hidden_size=lm.hidden_size, vocab_size=lm.vocab_size,
+        rms_norm_eps=lm.rms_norm_eps,
+        num_attention_heads=lm.num_attention_heads,
+        num_key_value_heads=lm.num_key_value_heads, head_dim=lm.head_dim,
+        rope_theta=float(lm.rope_theta), rotary_dim=int(rotary),
+        zero_centered_norms=True,
+        linear_num_key_heads=lm.linear_num_key_heads,
+        linear_num_value_heads=lm.linear_num_value_heads,
+        linear_key_head_dim=lm.linear_key_head_dim,
+        linear_value_head_dim=lm.linear_value_head_dim,
+        linear_conv_kernel_dim=lm.linear_conv_kernel_dim,
+        num_experts=lm.num_experts,
+        num_experts_per_token=lm.num_experts_per_tok,
+        moe_intermediate_size=width, num_shared_experts=shared // width,
+        shared_expert_gate=True,
+        expert_shards=lm.expert_shards, expert_shard=lm.expert_shard,
+        expert_rows_factor=float(lm.expert_rows_factor),
+        router="softmax", gate="silu")
+
+
 def _dense(features: int, axes, name: str, dtype, param_dtype) -> nn.Dense:
     return nn.Dense(features, use_bias=False, dtype=dtype,
                     param_dtype=param_dtype, name=name,
@@ -192,9 +274,9 @@ def causal_depthwise_conv(x, kernel):
     return sum(xp[:, j:j + t] * kernel[j] for j in range(w))
 
 
-def a_log_init(key, shape, dtype=jnp.float32):
-    """A_log = log A, A uniform on [1, 16) (the released code's init)."""
-    return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0)
+def a_log_init(key, shape, dtype=jnp.float32, lo=1.0):
+    """A_log = log A, A uniform on [lo, 16) (the released code's init)."""
+    return jnp.log(jax.random.uniform(key, shape, jnp.float32, lo, 16.0)
                    ).astype(dtype)
 
 
@@ -282,6 +364,91 @@ class KDAMixer(nn.Module):
         return _dense(x.shape[-1], ("heads", "embed"), "o_proj", **kw)(o)
 
 
+GDN_A_FLOOR = 1e-6  # A uniform on [floor, 16): no A_log is -inf
+
+
+class GDNMixer(nn.Module):
+    """Gated DeltaNet: the delta rule with ONE decay a value head and
+    token, ``value_heads`` heads on ``key_heads`` key heads."""
+
+    key_heads: int
+    value_heads: int
+    key_dim: int
+    value_dim: int
+    conv_size: int = 4
+    eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        b, t, _ = x.shape
+        hk, hv, dk, dv = (self.key_heads, self.value_heads, self.key_dim,
+                          self.value_dim)
+        if hv % hk:
+            raise ValueError(f"{hv} value heads on {hk} key heads")
+        kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
+        xc = x.astype(self.dtype)
+        r, nk = hv // hk, hk * dk
+        # the published grouping of the columns, a key head at a time:
+        # [q dk | k dk | v r dv | z r dv] and [b r | a r]
+        qkvz = _dense(2 * nk + 2 * hv * dv, ("embed", "heads"), "in_proj_qkvz",
+                      **kw)(xc).reshape(b, t, hk, 2 * dk + 2 * r * dv)
+        ba = _dense(2 * hv, ("embed", None), "in_proj_ba", **kw)(xc)
+        ba = ba.reshape(b, t, hk, 2 * r).astype(jnp.float32)
+        kernel = self.param(
+            "conv", part(trunc_normal_init(), (None, "heads")),
+            (self.conv_size, 2 * nk + hv * dv), self.param_dtype)
+
+        # (the float32 chains between the matmuls are rematerialised by
+        # themselves, as KDAMixer's are)
+        @jax.checkpoint
+        def conv_act(qkvz, kernel):
+            # ONE convolution over the joined channels: every q head, then
+            # every k head, then every v head
+            joined = jnp.concatenate([
+                qkvz[..., :dk].reshape(b, t, nk),
+                qkvz[..., dk:2 * dk].reshape(b, t, nk),
+                qkvz[..., 2 * dk:2 * dk + r * dv].reshape(b, t, hv * dv)], -1)
+            y = nn.silu(causal_depthwise_conv(
+                joined.astype(jnp.float32), kernel.astype(jnp.float32)))
+            # eps INSIDE the root, the released code's 1e-6
+            unit = lambda u: l2_normalize(  # noqa: E731
+                u.reshape(b, t, hk, dk), eps=1e-3).astype(self.dtype)
+            return (unit(y[..., :nk]), unit(y[..., nk:2 * nk]),
+                    y[..., 2 * nk:].reshape(b, t, hv, dv).astype(self.dtype))
+
+        q, k, v = conv_act(qkvz, kernel)
+        a_log = self.param(
+            "A_log", part(functools.partial(a_log_init, lo=GDN_A_FLOOR),
+                          ("heads",)), (hv,), self.param_dtype)
+        dt_bias = self.param("dt_bias", part(nn.initializers.ones, ("heads",)),
+                             (hv,), self.param_dtype)
+        f32 = lambda u: u.astype(jnp.float32)  # noqa: E731
+        beta = jax.nn.sigmoid(ba[..., :r].reshape(b, t, hv))
+        g = -jnp.exp(f32(a_log)) * jax.nn.softplus(
+            ba[..., r:].reshape(b, t, hv) + f32(dt_bias))
+        with jax.named_scope("gdn_core"):
+            # key head j serves value heads j r .. j r + r - 1; the one
+            # decay of a head stands for all its key channels
+            q, k = (jnp.repeat(u, r, axis=2) for u in (q, k))
+            o = kda_chunked(
+                q, k, v, jnp.broadcast_to(g[..., None], (b, t, hv, dk)), beta,
+                q_scale=dk ** -0.5)
+        scale = self.param("o_norm_scale", part(nn.initializers.ones, (None,)),
+                           (dv,), self.param_dtype)
+
+        @jax.checkpoint
+        def gated_norm(o, qkvz, scale):
+            ms = jnp.mean(jnp.square(o), axis=-1, keepdims=True)
+            o = o * jax.lax.rsqrt(ms + self.eps) * f32(scale)
+            o = o * nn.silu(f32(qkvz[..., 2 * dk + r * dv:]).reshape(b, t, hv, dv))
+            return o.reshape(b, t, hv * dv).astype(self.dtype)
+
+        return _dense(x.shape[-1], ("heads", "embed"), "o_proj", **kw)(
+            gated_norm(o, qkvz, scale))
+
+
 class MLAMixer(nn.Module):
     num_heads: int
     kv_lora_rank: int
@@ -323,13 +490,21 @@ class MLAMixer(nn.Module):
 
 class GQAMixer(nn.Module):
     """Grouped-query attention: ``window`` None is every key up to the
-    query's own, ``rope_theta`` None no rotation."""
+    query's own, ``rope_theta`` None no rotation, ``rotary_dim`` None the
+    whole head rotated (else its leading channels). ``output_gate``: the
+    q projection makes [q ; gate] a head and the core's output goes
+    through sigmoid(gate). ``qk_norm``: given a name, makes the norm that
+    every q head (``q_norm``) and every k head (``k_norm``) goes through
+    before the rotation, one ``[head_dim]`` scale each."""
 
     num_heads: int
     num_kv_heads: int
     head_dim: int
     window: int | None = None
     rope_theta: float | None = None
+    rotary_dim: int | None = None
+    output_gate: bool = False
+    qk_norm: Callable[[str], nn.Module] | None = None
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     reduce_dtype: Any = jnp.float32
@@ -340,24 +515,34 @@ class GQAMixer(nn.Module):
         h, hk, d = self.num_heads, self.num_kv_heads, self.head_dim
         kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
         xc = x.astype(self.dtype)
-        q = _dense(h * d, ("embed", "heads"), "q_proj", **kw)(xc)
+        q = _dense(h * d * (2 if self.output_gate else 1), ("embed", "heads"),
+                   "q_proj", **kw)(xc)
         k = _dense(hk * d, ("embed", "heads"), "k_proj", **kw)(xc)
         v = _dense(hk * d, ("embed", "heads"), "v_proj", **kw)(xc)
+        if self.output_gate:
+            q = q.reshape(b, t, h, 2 * d)
+            q, gate = q[..., :d], q[..., d:]
         q, k, v = (q.reshape(b, t, h, d), k.reshape(b, t, hk, d),
                    v.reshape(b, t, hk, d))
+        if self.qk_norm is not None:
+            q, k = self.qk_norm("q_norm")(q), self.qk_norm("k_norm")(k)
         if self.rope_theta is not None:
             # float32 tables: the turn itself is float32, its ends bf16
-            q, k = rope_apply_full(
-                q, k, *token_rope_sincos(t, d, self.rope_theta))
+            q, k = rope_apply_leading(q, k, *token_rope_sincos(
+                t, self.rotary_dim or d, self.rope_theta))
         with jax.named_scope("gqa_core"):
             o = dispatch_attention(q, k, v, causal=True, window=self.window,
                                    reduce_dtype=self.reduce_dtype)
+        if self.output_gate:
+            o = (o.astype(jnp.float32)
+                 * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(self.dtype)
         return _dense(x.shape[-1], ("heads", "embed"), "o_proj", **kw)(
             o.reshape(b, t, h * d))
 
 
 class DecoderLayer(nn.Module):
-    mixer: str                 # "kda" | "mla" | "swa" | "full_attn"
+    mixer: str                 # "kda" | "mla" | "swa" | "full_attn" | "gdn"
+                               # | "gated_attn"
     ffn: str                   # "dense" | "moe"
     cfg: Any                   # the frozen ``DecoderConfig``
 
@@ -366,7 +551,8 @@ class DecoderLayer(nn.Module):
         c = self.cfg
         kw = dict(dtype=c.dtype, param_dtype=c.param_dtype)
         norm = lambda name: RMSNorm(  # noqa: E731
-            epsilon=c.rms_norm_eps, param_dtype=c.param_dtype, name=name)
+            epsilon=c.rms_norm_eps, param_dtype=c.param_dtype,
+            zero_centered=c.zero_centered_norms, name=name)
         # a phase holds its pre-norm and its residual add: what is left
         # outside every phase is what the compiler makes between layers
         x_in = x
@@ -384,14 +570,26 @@ class DecoderLayer(nn.Module):
                              reduce_dtype=c.reduce_dtype, name="mla", **kw)(
                                  norm("norm1")(x))
                 x = x + y.astype(x.dtype)
+        elif self.mixer == "gdn":
+            with step_phase("gdn_mixer"):
+                y = GDNMixer(c.linear_num_key_heads, c.linear_num_value_heads,
+                             c.linear_key_head_dim, c.linear_value_head_dim,
+                             c.linear_conv_kernel_dim, c.rms_norm_eps,
+                             name="gdn", **kw)(norm("norm1")(x))
+                x = x + y.astype(x.dtype)
         else:
-            swa = self.mixer == "swa"
-            with step_phase("swa_mixer" if swa else "full_attn_mixer"):
-                y = GQAMixer(c.num_attention_heads, c.num_key_value_heads,
-                             c.head_dim, c.sliding_window if swa else None,
-                             c.rope_theta if swa else None,
-                             reduce_dtype=c.reduce_dtype, name="attn", **kw)(
-                                 norm("norm1")(x))
+            # "swa": a window and rotary; "full_attn": neither; "gated_attn":
+            # no window, a partial rotary, an output gate and the layer's
+            # kind of norm on q and k
+            gated = self.mixer == "gated_attn"
+            with step_phase(f"{self.mixer}_mixer"):
+                y = GQAMixer(
+                    c.num_attention_heads, c.num_key_value_heads, c.head_dim,
+                    c.sliding_window if self.mixer == "swa" else None,
+                    None if self.mixer == "full_attn" else c.rope_theta,
+                    c.rotary_dim or None, gated, norm if gated else None,
+                    reduce_dtype=c.reduce_dtype, name="attn", **kw)(
+                        norm("norm1")(x))
                 x = x + y.astype(x.dtype)
         aux = None
         if self.ffn == "dense":
@@ -404,14 +602,19 @@ class DecoderLayer(nn.Module):
                 routed, aux = RoutedExpertsFFN(
                     c.moe_intermediate_size, c.num_experts,
                     c.num_experts_per_token, c.expert_shards, c.expert_shard,
-                    c.routed_scaling_factor, router=c.router, gate=c.gate,
-                    name="experts", **kw)(
+                    c.routed_scaling_factor, c.expert_rows_factor,
+                    router=c.router, gate=c.gate, name="experts", **kw)(
                         y, x_in if c.router_reads_layer_input else None)
                 if c.num_shared_experts:
                     with jax.named_scope("moe_shared"):
                         shared = _swiglu(
                             c.moe_intermediate_size * c.num_shared_experts,
                             "shared", **kw)(y)
+                        if c.shared_expert_gate:
+                            shared = shared * jax.nn.sigmoid(_dense(
+                                1, ("embed", None), "shared_gate", **kw)(
+                                    y.astype(c.dtype)).astype(jnp.float32)
+                            ).astype(shared.dtype)
                     routed = routed + shared
                 x = x + routed.astype(x.dtype)
         return x, aux
@@ -454,7 +657,7 @@ class LMDecoder(nn.Module):
             (c.hidden_size, c.vocab_size), c.param_dtype)
         with step_phase("lm_head_loss"):
             x = RMSNorm(epsilon=c.rms_norm_eps, param_dtype=c.param_dtype,
-                        name="norm")(x)
+                        zero_centered=c.zero_centered_norms, name="norm")(x)
             if not with_loss:
                 return jnp.einsum("btd,dv->btv", x.astype(c.dtype),
                                   head.astype(c.dtype),

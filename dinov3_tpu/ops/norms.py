@@ -48,21 +48,25 @@ class LayerNorm(nn.Module):
 
 
 class RMSNorm(nn.Module):
-    """RMSNorm: fp32 mean-square, learned scale."""
+    """RMSNorm: fp32 mean-square, learned scale. ``zero_centered``: the
+    scale is 1 + w, w from zeros (weight decay then pulls towards 1)."""
 
     epsilon: float = 1e-6
     param_dtype: Any = jnp.float32
     reduce_dtype: Any = jnp.float32
+    zero_centered: bool = False
 
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
         dim = x.shape[-1]
-        scale = self.param("scale", part(nn.initializers.ones, ("embed",)), (dim,),
+        init = nn.initializers.zeros if self.zero_centered else nn.initializers.ones
+        scale = self.param("scale", part(init, ("embed",)), (dim,),
                            self.param_dtype)
         xf = x.astype(self.reduce_dtype)
         ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
         y = xf * jax.lax.rsqrt(ms + self.epsilon)
-        y = y * scale.astype(self.reduce_dtype)
+        scale = scale.astype(self.reduce_dtype)
+        y = y * (1.0 + scale if self.zero_centered else scale)
         return y.astype(x.dtype)
 
 

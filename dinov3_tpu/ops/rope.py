@@ -244,6 +244,24 @@ def rope_apply_full(
     return rot(q), rot(k)
 
 
+def rope_apply_leading(
+    q: jnp.ndarray,
+    k: jnp.ndarray,
+    sin: jnp.ndarray,
+    cos: jnp.ndarray,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """``rope_apply_full`` on the leading ``sin.shape[-1]`` channels of
+    every head (a partial rotary: rotate-half INSIDE those channels); the
+    channels past them carry no position and pass as they are. A table as
+    wide as the head is ``rope_apply_full`` itself."""
+    width = sin.shape[-1]
+    if width == q.shape[-1]:
+        return rope_apply_full(q, k, sin, cos)
+    rq, rk = rope_apply_full(q[..., :width], k[..., :width], sin, cos)
+    return (jnp.concatenate([rq, q[..., width:]], axis=-1),
+            jnp.concatenate([rk, k[..., width:]], axis=-1))
+
+
 def rope_packed_rows(
     global_table: tuple[jnp.ndarray, jnp.ndarray],
     local_table: tuple[jnp.ndarray, jnp.ndarray],

@@ -55,11 +55,16 @@ class LMMetaArch:
         cores = {  # the scope, the (heads, width) of q, k, v and the window
             "mla": ("mla_core", ((heads, qk),) * 2 + ((heads, dc.v_head_dim),), None),
             "swa": ("gqa_core", gqa, dc.sliding_window),
-            "full_attn": ("gqa_core", gqa, None)}
+            "full_attn": ("gqa_core", gqa, None),
+            "gated_attn": ("gqa_core", gqa, None)}
+        delta = {  # the scope and the delta rule's (key, value) widths
+            "kda": ("kda_core", dc.kda_head_dim, dc.kda_head_dim),
+            "gdn": ("gdn_core", dc.linear_key_head_dim, dc.linear_value_head_dim)}
         for i, (mixer, _) in enumerate(dc.layers, 1):
-            if mixer == "kda":
-                path, why = kda_path(dc.kda_head_dim, dc.kda_head_dim)
-                logger.info("layer %d kda_core, both passes: %s (%s)", i, path, why)
+            if mixer in delta:
+                scope, dk, dv = delta[mixer]
+                path, why = kda_path(dk, dv)
+                logger.info("layer %d %s, both passes: %s (%s)", i, scope, path, why)
             else:
                 scope, shapes, window = cores[mixer]
                 path, why = causal_attention_path(

@@ -545,6 +545,9 @@ STEP_PHASES = (
     "mla_mixer",          # a latent-attention layer's mixer (inner: mla_core)
     "swa_mixer",          # a grouped-query layer with a window and rotary
     "full_attn_mixer",    # ... with neither (inner of both: gqa_core)
+    "gdn_mixer",          # a Gated DeltaNet layer's mixer (inner: gdn_core)
+    "gated_attn_mixer",   # a grouped-query layer with q/k norms, a partial
+                          # rotary and an output gate (inner: gqa_core)
     "dense_ffn",          # the dense SwiGLU of the leading layers
     "moe_ffn",            # routed + shared experts (inner: moe_route,
                           # moe_experts, moe_shared)
@@ -552,7 +555,8 @@ STEP_PHASES = (
 )
 # the phases only a decoder's step opens
 LM_STEP_PHASES = ("lm_embed", "kda_mixer", "mla_mixer", "swa_mixer",
-                  "full_attn_mixer", "dense_ffn", "moe_ffn", "lm_head_loss")
+                  "full_attn_mixer", "gdn_mixer", "gated_attn_mixer",
+                  "dense_ffn", "moe_ffn", "lm_head_loss")
 
 _PHASE_WRAPPER = re.compile(r"^(jvp|transpose|checkpoint|remat)\((.*)\)$")
 

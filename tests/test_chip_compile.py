@@ -140,13 +140,16 @@ def test_kda_chunk_kernels_compile_for_v5e(one_chip, states):
     ((1, 16384, 28, 128), 4, 128, 4096),
     ((1, 16384, 28, 128), 4, 128, None),
     ((2, 8192, 32, 192), 32, 128, None),
-], ids=["window", "global", "mla"])
+    ((2, 8192, 16, 256), 2, 256, None),
+], ids=["window", "global", "mla", "gated"])
 def test_causal_attention_kernels_compile_for_v5e(one_chip, q, kv, dv,
                                                   window, direction):
     """``ops/causal_attention.py`` at the shapes the two decoder cells
     send ``causal_blockwise_attention``: the 16k cell's window and global
     grouped-query layers (28 query heads on 4 of 128) and the 8k cell's
-    latent attention (q and k 192 wide, v 128), at the shipped blocks. The
+    latent attention (q and k 192 wide, v 128) and the ``qwen3_next``
+    cell's gated attention (16 query heads on 2 of 256, values 256 wide: 8
+    heads a key tile), at the shipped blocks. The
     gradient's program holds the forward rule and ONE backward kernel."""
     from dinov3_tpu.ops.causal_attention import (
         BACKWARD_KERNEL_NAME,

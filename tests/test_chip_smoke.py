@@ -48,6 +48,8 @@ TINY = {
     "gqa_shapes": {"window": (1, 512, 6, 2, 128, 128, 200),
                    "global": (1, 512, 6, 2, 128, 128, None),
                    "mla": (2, 256, 2, 2, 192, 128, None)},
+    "gdn_shape": (1, 128, 2, 128),
+    "gdn_attn_shapes": {"gated": (1, 512, 4, 2, 256, 256, None)},
     "gqa_shipped_blocks": (128, 256),
     "gqa_blocks": [(256, 128)],
     "gqa_timeout_s": 600,
@@ -117,6 +119,11 @@ def test_smoke_rehearsal_runs_every_phase(smoke, monkeypatch, capsys):
                    "entry point takes the kernel (interpreted)",
                    "gqa: global core, tiles: first calls",
                    "gqa: mla core, kernel at blocks 256 x 128",
+                   "gdn: delta rule (1, 128, 2, 128) at a scalar gate: the "
+                   "entry point takes the kernel (interpreted)",
+                   "gdn: delta rule: norm of the difference over the norm",
+                   "gqa: gated core (1, 512, 4, 2, 256, 256) window None: the "
+                   "entry point takes the kernel (interpreted)",
                    "all phases passed"):
         assert needle in said, needle
     assert "mesh[" not in said  # the four-chip phase is behind --chips 4

@@ -39,10 +39,11 @@ def test_recipe_abstract_build(path):
         # a decoder's recipe: a student and no teacher. Shrink the widths
         # (the family's TINY) and KEEP the structure: the layer table, the
         # experts' share, the schedules
-        if cfg.student.arch == "smallthinker":
-            from test_lm_gqa import TINY
-        else:
-            from test_lm_decoder import TINY
+        import importlib
+
+        TINY = importlib.import_module({
+            "kimi_linear": "test_lm_decoder", "smallthinker": "test_lm_gqa",
+            "qwen3_next": "test_lm_gdn"}[str(cfg.student.arch)]).TINY
 
         from dinov3_tpu.train.lm_meta_arch import LMMetaArch
 
