@@ -178,3 +178,50 @@ def test_causal_attention_kernels_compile_for_v5e(one_chip, q, kv, dv,
     assert text.count("tpu_custom_call") == (1 if direction == "fwd" else 2)
     assert (BACKWARD_KERNEL_NAME in text) == (direction == "bwd")
     assert " while(" not in text
+
+
+def test_ibot_row_ce_gradient_compiles_without_a_loop_for_v5e(one_chip):
+    """Value-and-gradient of the streaming iBOT row CE at the ViT-S cell's
+    ``[1920, 65536]`` float32 planes (PR 36): the forward is reductions
+    over the whole plane and the backward the closed-form rule, so the
+    program holds no loop and no ``dynamic-update-slice`` (the parent's
+    two K-tile loops pinned a copy of every tile, and the transposed one
+    carried the gradient plane and wrote it a tile at a time). The gradient
+    is the output; the temporaries stay under two planes, and the rule BY
+    ITSELF needs none of a plane's size: one fusion writes the gradient,
+    neither q nor the softmax is a value of its own."""
+    from dinov3_tpu.losses.ibot_loss import ibot_patch_loss_from_parts
+    from dinov3_tpu.losses.streaming import (
+        SinkhornFactors,
+        _row_ce_sinkhorn_bwd,
+        _row_ce_sinkhorn_stream,
+    )
+
+    M, K = 1920, 65536
+    f32 = jnp.float32
+    plane, rows = ((M, K), f32), ((M,), f32)
+    factors = [plane, ((M, 1), f32), ((1, K), f32), ((), f32)]
+
+    def loss(x, xs, r, c, log_b, w):
+        parts = _row_ce_sinkhorn_stream(
+            x, SinkhornFactors(xs, r, c, log_b, None), 0.1)
+        return ibot_patch_loss_from_parts(*parts, w, 64)
+
+    def rule(x, xs, r, c, log_b, lse, d_dot, d_lse):
+        res = (x, SinkhornFactors(xs, r, c, log_b, None), lse)
+        return _row_ce_sinkhorn_bwd(0.1, res, (d_dot, d_dot, d_lse))[0]
+
+    def compiled(fn, *shapes_dtypes):
+        args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                for s, d in shapes_dtypes]
+        c = jax.jit(fn).lower(*args).compile()
+        return c.as_text(), c.memory_analysis().temp_size_in_bytes
+
+    for fn, shapes, temp_limit in (
+            (jax.value_and_grad(loss), [plane, *factors, rows],
+             2 * M * K * 4),
+            (rule, [plane, *factors, rows, rows, rows], M * K * 4 // 100)):
+        text, temp = compiled(fn, *shapes)
+        assert " while(" not in text
+        assert "dynamic-update-slice(" not in text
+        assert temp < temp_limit

@@ -723,11 +723,15 @@ def test_save_and_resume_through_do_train(tmp_path):
 # ---------------- (g) the SSL step is the parent's ----------------
 
 # sha256 of the default SSL telemetry step's StableHLO text (``.lower(
-# ...).as_text()``, no locations) at test width, read from the parent of
-# PR 27 (commit b2aaaf8) in this sandbox. A change to the step's
-# skeleton that reaches the SSL program moves it; what PR 27 added for a
-# meta-arch without a teacher does not.
-SSL_STEP_SHA256 = "82f65802f197b9f51a578cf62a8f875ca32960c636f38a7f435216506f167185"
+# ...).as_text()``, no locations) at test width, read UNDER PYTEST from
+# PR 36's tree in this sandbox. A change to the step's skeleton that
+# reaches the SSL program moves it; what the decoder PRs (27, 32, 35)
+# added does not. Moved on purpose by PR 36 (82f65802... at its parent,
+# d66609e, and since PR 27's parent b2aaaf8): the Sinkhorn CEs of
+# losses/streaming.py differentiate by a closed-form ``custom_vjp``, so
+# the transposed K-tile scans left the step, and the iBOT rows' forward
+# is reductions over the whole plane, so its K-tile scan left it too.
+SSL_STEP_SHA256 = "3971a7eba21b956b05819fe5a7d94101d3c02f744334d93d380ddcfc4b21eeac"
 
 
 def test_ssl_step_stablehlo_is_unchanged():

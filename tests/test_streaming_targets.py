@@ -177,6 +177,254 @@ def test_streaming_gradients_match_oracle():
                                rtol=1e-4, atol=1e-6)
 
 
+# ---------------- the closed-form backward (PR 36) ----------------
+
+
+def _scan_row_ce_sinkhorn(student_logits, factors, s_temp, tk):
+    """The row CE as PR 36's parent (d66609e) computed and differentiated
+    it: a K-tile scan with online rescaling, JAX's own transposition of
+    its checkpointed body. Kept here as the second reference of the
+    whole-plane forward and the closed-form rule."""
+    from dinov3_tpu.losses.streaming import _slice_k
+
+    M, K = student_logits.shape
+    f32 = jnp.float32
+    r = factors.r.astype(f32)
+    log_B = factors.log_B.astype(f32)
+
+    def body(carry, i):
+        dot, qsum, m_s, s_s = carry
+        lq = (_slice_k(factors.xs, i, tk, 1).astype(f32) - r
+              - _slice_k(factors.c, i, tk, 1).astype(f32) + log_B)
+        q = jnp.exp(lq)
+        xt_f = (_slice_k(student_logits, i, tk, 1) / jnp.asarray(
+            s_temp, student_logits.dtype)).astype(f32)
+        dot = dot + (xt_f * q).sum(-1)
+        qsum = qsum + q.sum(-1)
+        new_m_s = jnp.maximum(m_s, xt_f.max(-1))
+        s_s = (s_s * jnp.exp(m_s - new_m_s)
+               + jnp.exp(xt_f - new_m_s[:, None]).sum(-1))
+        return (dot, qsum, new_m_s, s_s), None
+
+    z = jnp.zeros((M,), f32)
+    (dot, qsum, m_s, s_s), _ = jax.lax.scan(
+        jax.checkpoint(body), (z, z, jnp.full((M,), -jnp.inf, f32), z),
+        jnp.arange(K // tk))
+    return dot, qsum, m_s + jnp.log(s_s)
+
+
+def _scan_pair_ce_sinkhorn(student_logits, factors, s_temp, tk):
+    """The pair CE under the parent's scan autodiff (see above)."""
+    from dinov3_tpu.losses.streaming import _slice_k
+
+    S, B, K = student_logits.shape
+    T = factors.xs.shape[0] // B
+    f32 = jnp.float32
+    r = factors.r.astype(f32)
+    log_B = factors.log_B.astype(f32)
+
+    def body(carry, i):
+        dot, qsum, m_s, s_s = carry
+        lq = (_slice_k(factors.xs, i, tk, 1).astype(f32) - r
+              - _slice_k(factors.c, i, tk, 1).astype(f32) + log_B)
+        q = jnp.exp(lq).reshape(T, B, tk)
+        xt_f = (_slice_k(student_logits, i, tk, 2) / jnp.asarray(
+            s_temp, student_logits.dtype)).astype(f32)
+        dot = dot + jnp.einsum(
+            "sbk,tbk->stb", xt_f, q, preferred_element_type=f32)
+        qsum = qsum + q.sum(-1)
+        new_m_s = jnp.maximum(m_s, xt_f.max(-1))
+        s_s = (s_s * jnp.exp(m_s - new_m_s)
+               + jnp.exp(xt_f - new_m_s[..., None]).sum(-1))
+        return (dot, qsum, new_m_s, s_s), None
+
+    init = (jnp.zeros((S, T, B), f32), jnp.zeros((T, B), f32),
+            jnp.full((S, B), -jnp.inf, f32), jnp.zeros((S, B), f32))
+    (dot, qsum, m_s, s_s), _ = jax.lax.scan(
+        jax.checkpoint(body), init, jnp.arange(K // tk))
+    lse_s = m_s + jnp.log(s_s)
+    return jnp.einsum("sb,tb->st", lse_s, qsum) - dot.sum(-1)
+
+
+def _ibot_rows(tgt, K=192, M=12, n_valid=8):
+    key = jax.random.key(3)
+    sm = jax.random.normal(key, (M, K))
+    tm = jax.random.normal(jax.random.fold_in(key, 1), (M, K)) * 2
+    valid = jnp.arange(M) < n_valid
+    w = jnp.where(valid, 1.0 / n_valid, 0.0)
+    kw = dict(row_weights=valid.astype(jnp.float32), storage_dtype=tgt)
+    probs = sinkhorn_knopp(tm, 0.07, **kw)
+    factors = sinkhorn_knopp(tm, 0.07, return_factors=True, **kw)
+    return sm, probs, factors, w, valid
+
+
+# the rule shares xs with both references; what differs is where q is
+# rounded (the oracle stores it in tgt) and the scan's online rescaling
+_RULE_TOL = {None: dict(rtol=1e-4, atol=1e-7),
+             jnp.bfloat16: dict(rtol=2e-2, atol=1e-5)}
+
+
+@pytest.mark.parametrize("tgt", [None, jnp.bfloat16], ids=["fp32", "bf16"])
+def test_ibot_whole_plane_forward_matches_the_scan(tgt):
+    """(dot, qsum, lse) as reductions over the whole plane == the parent's
+    K-tile scan with its online rescaling, row by row, padding included."""
+    from dinov3_tpu.losses.streaming import _row_ce_sinkhorn_stream
+
+    sm, _, factors, _, valid = _ibot_rows(tgt)
+    plane = _row_ce_sinkhorn_stream(sm, factors, 0.1)
+    scan = _scan_row_ce_sinkhorn(sm, factors, 0.1, 64)
+    for a, b in zip(plane, scan):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-7)
+    assert np.all(np.asarray(plane[1])[~np.asarray(valid)] == 0.0)
+
+
+@pytest.mark.parametrize("tgt", [None, jnp.bfloat16], ids=["fp32", "bf16"])
+@pytest.mark.parametrize("upstream", [1.0, -2.5], ids=["unit", "scaled"])
+def test_ibot_rule_matches_oracle_and_scan_autodiff(tgt, upstream):
+    """The closed-form backward of the row CE == the materialized
+    oracle's gradient == the parent's scan autodiff, with padding rows and
+    a non-unit upstream cotangent; padding rows take exactly zero."""
+    from dinov3_tpu.losses.ibot_loss import ibot_patch_loss_from_parts
+
+    sm, probs, factors, w, valid = _ibot_rows(tgt)
+    spec = {"kind": "sinkhorn", "factors": factors}
+
+    def scan_loss(s):
+        return ibot_patch_loss_from_parts(
+            *_scan_row_ce_sinkhorn(s, factors, 0.1, 64), w, 2)
+
+    g_rule = jax.grad(lambda s: upstream * ibot_loss_from_spec(
+        s, spec, w, 2, k_tile=64))(sm)
+    g_scan = jax.grad(lambda s: upstream * scan_loss(s))(sm)
+    g_oracle = jax.grad(lambda s: upstream * ibot_patch_loss_masked(
+        s, probs, w, n_images=2))(sm)
+    assert g_rule.dtype == sm.dtype
+    np.testing.assert_allclose(np.asarray(g_rule), np.asarray(g_scan),
+                               rtol=1e-4, atol=1e-7)
+    np.testing.assert_allclose(np.asarray(g_rule), np.asarray(g_oracle),
+                               **_RULE_TOL[tgt])
+    assert np.all(np.asarray(g_rule)[~np.asarray(valid)] == 0.0)
+    assert np.abs(np.asarray(g_rule)[np.asarray(valid)]).min() > 0.0
+
+
+@pytest.mark.parametrize("tgt", [None, jnp.bfloat16], ids=["fp32", "bf16"])
+@pytest.mark.parametrize("ignore_diagonal", [False, True],
+                         ids=["all_pairs", "off_diagonal"])
+def test_dino_pair_rule_matches_oracle_and_scan_autodiff(tgt,
+                                                         ignore_diagonal):
+    """The same rule with its sum over the teacher crops; the
+    off-diagonal case gives the pairs unequal (and zero) cotangents."""
+    sl, tl, _ = _pair_data(K=128)
+    T, B, K = tl.shape
+    S = T if ignore_diagonal else sl.shape[0]
+    sl = sl[:S]
+    flat = tl.reshape(T * B, K)
+    q = sinkhorn_knopp(flat, 0.05, storage_dtype=tgt).reshape(T, B, K)
+    f = sinkhorn_knopp(flat, 0.05, storage_dtype=tgt, return_factors=True)
+    kw = dict(ignore_diagonal=ignore_diagonal)
+    g_rule = jax.grad(lambda s: pair_ce_to_loss(pair_ce_from_spec(
+        s, {"kind": "sinkhorn", "factors": f}, k_tile=32), B, **kw))(sl)
+    g_scan = jax.grad(lambda s: pair_ce_to_loss(
+        _scan_pair_ce_sinkhorn(s, f, 0.1, 32), B, **kw))(sl)
+    g_oracle = jax.grad(lambda s: dino_loss(s, q, **kw))(sl)
+    np.testing.assert_allclose(np.asarray(g_rule), np.asarray(g_scan),
+                               rtol=1e-4, atol=1e-7)
+    np.testing.assert_allclose(np.asarray(g_rule), np.asarray(g_oracle),
+                               **_RULE_TOL[tgt])
+
+
+def _sinkhorn_term(term, K):
+    """(student logits, the loss under the rule, the same loss under the
+    parent's scan autodiff) of the iBOT rows or the DINO pairs."""
+    from dinov3_tpu.losses.ibot_loss import ibot_patch_loss_from_parts
+
+    if term == "ibot":
+        sm, _, factors, w, _ = _ibot_rows(None, K=K)
+        spec = {"kind": "sinkhorn", "factors": factors}
+
+        def rule(s):
+            return ibot_loss_from_spec(s, spec, w, 2, k_tile=64)
+
+        def scan(s):
+            return ibot_patch_loss_from_parts(
+                *_scan_row_ce_sinkhorn(s, factors, 0.1, 64), w, 2)
+    else:
+        sm, tl, _ = _pair_data(K=K)
+        T, B, _ = tl.shape
+        f = sinkhorn_knopp(tl.reshape(T * B, K), 0.05, return_factors=True)
+
+        def rule(s):
+            return pair_ce_to_loss(pair_ce_from_spec(
+                s, {"kind": "sinkhorn", "factors": f}, k_tile=64), B)
+
+        def scan(s):
+            return pair_ce_to_loss(
+                _scan_pair_ce_sinkhorn(s, f, 0.1, 64), B)
+    return sm, rule, scan
+
+
+@pytest.mark.parametrize("term", ["ibot", "dino"])
+def test_sinkhorn_rule_carries_a_nan_cotangent(term):
+    """The overflow guard multiplies the loss by NaN
+    (ssl_meta_arch: ``masked.overflow``): the NaN must reach every
+    student logit through the rule, as it did through the scan."""
+    sm, loss, _ = _sinkhorn_term(term, K=128)
+    val, g = jax.value_and_grad(lambda s: loss(s) * jnp.nan)(sm)
+    assert np.isnan(float(val))
+    assert np.isnan(np.asarray(g)).all()
+    assert np.isfinite(np.asarray(jax.grad(loss)(sm))).all()
+
+
+def test_ibot_rule_bf16_student_logits():
+    """Student logits in bf16 (a head with ``reduce_dtype`` bf16; no
+    recipe has one): the cotangent comes back in bf16, within what
+    rounding ``x / tau`` (|x / tau| up to 40: half an ulp is 0.125) to
+    bf16 does to an exponential."""
+    from dinov3_tpu.losses.ibot_loss import ibot_patch_loss_from_parts
+
+    sm, _, factors, w, _ = _ibot_rows(None)
+    sm = sm.astype(jnp.bfloat16)
+    g_rule = jax.grad(lambda s: ibot_loss_from_spec(
+        s, {"kind": "sinkhorn", "factors": factors}, w, 2, k_tile=64))(sm)
+    g_scan = jax.grad(lambda s: ibot_patch_loss_from_parts(
+        *_scan_row_ce_sinkhorn(s, factors, 0.1, 64), w, 2))(sm)
+    assert g_rule.dtype == jnp.bfloat16
+    np.testing.assert_allclose(
+        np.asarray(g_rule, np.float32), np.asarray(g_scan, np.float32),
+        rtol=0.15, atol=1e-6)
+
+
+@pytest.mark.parametrize("term", ["ibot", "dino"])
+def test_sinkhorn_rule_lowers_without_a_backward_loop(term):
+    """The counter of PR 36: the lowered gradient of the iBOT term holds
+    no ``while`` and no ``dynamic_update_slice`` (forward: whole-plane
+    reductions; backward: the rule); the DINO term's holds ONE ``while``,
+    its forward's K-tile scan, whose carry is [S,T,B] statistics. The
+    parent's programs held a second, transposed loop that carried the
+    cotangent plane and wrote it tile by tile (the local scan references
+    validate the detector). The iBOT rule's ``dx`` passes ONE
+    ``optimization_barrier`` (it keeps the rule a fusion of its own, out
+    of the head's backward matmuls: the ViT-L step is 3 % slower without
+    it, ``PERF.md`` section 6, PR 36); the DINO rule's passes none (the
+    three of that program are the forward scan's pinned tiles)."""
+    sm, rule, scan = _sinkhorn_term(term, K=256)
+
+    def lowered(fn):
+        return jax.jit(jax.grad(fn)).lower(sm).as_text()
+
+    def loops_and_updates(text):
+        return (text.count("stablehlo.while"),
+                text.count("dynamic_update_slice"))
+
+    text = lowered(rule)
+    assert loops_and_updates(text) == ((0 if term == "ibot" else 1), 0)
+    assert text.count("optimization_barrier") == (
+        1 if term == "ibot" else 3)
+    loops, updates = loops_and_updates(lowered(scan))
+    assert loops >= 2 and updates >= 1
+
+
 def test_choose_k_tile():
     assert choose_k_tile(65536, 8192) == 8192
     assert choose_k_tile(65536, 8000) == 4096  # largest divisor <= cap
