@@ -132,25 +132,14 @@ def require_tpu() -> dict:
             "count": len(jax.devices())}
 
 
-class CacheCounter:
-    """Counts JAX's persistent-compilation-cache hits and misses through
-    its monitoring events (information for the log lines only)."""
+def cache_counts() -> dict:
+    """The persistent compilation cache's hits and misses so far, as the
+    process's span log counts them (``configure_compile_cache`` registers
+    its listeners; information for the log lines only)."""
+    from dinov3_tpu.telemetry import spans
 
-    def __init__(self):
-        import jax
-
-        self.hits = 0
-        self.misses = 0
-        jax.monitoring.register_event_listener(self._on_event)
-
-    def _on_event(self, event: str, **_) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
-
-    def snapshot(self) -> dict:
-        return {"hits": self.hits, "misses": self.misses}
+    c = spans.LOG.counters
+    return {"hits": c["cache_hits"], "misses": c["cache_misses"]}
 
 
 def peak_bytes() -> list:
@@ -161,7 +150,7 @@ def peak_bytes() -> list:
 
 # ------------------------------------------------- the next-token trainer
 
-def phase_lm(cache: CacheCounter) -> None:
+def phase_lm() -> None:
     """The same entry point on the decoder's recipe at published widths
     (``configs/train/kimi_linear_ep32.yaml``): the next-token step
     compiles, runs a few steps with a finite loss near ln(vocabulary),
@@ -200,7 +189,7 @@ def phase_lm(cache: CacheCounter) -> None:
         f"{want:.3f}); fenced step times (ms) "
         f"{[round(x, 1) for x in result['step_ms']]}; whole call "
         f"{time.perf_counter() - t0:.1f}s; peak_bytes_in_use "
-        f"{peak_bytes()}, cache {cache.snapshot()}")
+        f"{peak_bytes()}, cache {cache_counts()}")
     t0 = time.perf_counter()
     resumed = train_main(["--max-iterations", str(n + 1), *common])
     assert resumed["iterations"] == n + 1, resumed["iterations"]
@@ -215,7 +204,7 @@ def phase_lm(cache: CacheCounter) -> None:
 
 # ---------------------------------------------------------------- trainer
 
-def phase_trainer(cache: CacheCounter) -> None:
+def phase_trainer() -> None:
     """The pretrain entry point on the ViT-L/16 recipe: self-check,
     a few training steps, a checkpoint save and a one-step resume."""
     import shutil
@@ -235,7 +224,7 @@ def phase_trainer(cache: CacheCounter) -> None:
                     if k.startswith("check/") and not v)
     log(f"trainer: self-check {len(checks) - 1} probes, "
         f"{checks['self_check_failures']} failures, "
-        f"{time.perf_counter() - t0:.1f}s, cache {cache.snapshot()}")
+        f"{time.perf_counter() - t0:.1f}s, cache {cache_counts()}")
     assert checks["self_check_failures"] == 0, failed
 
     log(f"trainer: {n} iterations from scratch (--no-resume)")
@@ -262,7 +251,7 @@ def phase_trainer(cache: CacheCounter) -> None:
         f"{result['img_per_sec']:.2f} img/s over the fenced steps; "
         f"whole call {wall:.1f}s")
     log(f"trainer: peak_bytes_in_use per device {peak_bytes()}, "
-        f"cache {cache.snapshot()}")
+        f"cache {cache_counts()}")
 
     log("trainer: resume from the saved checkpoint for one more step")
     t0 = time.perf_counter()
@@ -272,7 +261,7 @@ def phase_trainer(cache: CacheCounter) -> None:
     assert math.isfinite(resumed["final_loss"]), resumed["final_loss"]
     log(f"trainer: resumed at {n}, step {n + 1} loss "
         f"{resumed['final_loss']:.4f}, {time.perf_counter() - t0:.1f}s, "
-        f"cache {cache.snapshot()}")
+        f"cache {cache_counts()}")
     # bring the small files back; drop the checkpoints
     shutil.rmtree(os.path.join(run_dir, "ckpt"))
     shutil.copytree(run_dir, os.path.join(OUT_DIR, "train"),
@@ -926,7 +915,6 @@ def main(argv=None) -> int:
 
     from dinov3_tpu import native
 
-    cache = CacheCounter()
     log(f"device {device}; jax {jax.__version__} jaxlib "
         f"{jaxlib.__version__}; compile cache {cache_dir} "
         f"({'JAX_COMPILATION_CACHE_DIR' if os.environ.get('JAX_COMPILATION_CACHE_DIR') else 'in-checkout default'})")
@@ -936,16 +924,15 @@ def main(argv=None) -> int:
             "TPU_WORKER_HOSTNAMES", "TPU_ACCELERATOR_TYPE",
             "JAX_COORDINATOR_ADDRESS", "JAX_PLATFORMS")}))
     if args.chips == 1:
-        run = {"trainer": lambda: phase_trainer(cache), "accum": phase_accum,
+        run = {"trainer": phase_trainer, "accum": phase_accum,
                "kernels": phase_kernels, "serve": phase_serve,
-               "lm": lambda: phase_lm(cache), "gqa": phase_gqa,
-               "gdn": phase_gdn}
+               "lm": phase_lm, "gqa": phase_gqa, "gdn": phase_gdn}
         for name in phases:
             run[name]()
     else:
         phase_mesh(args.chips)
     log(f"all phases passed in {time.time() - _T0:.0f}s; "
-        f"cache {cache.snapshot()}")
+        f"cache {cache_counts()}")
     print(json.dumps({"ok": True, "device": device}), flush=True)
     return 0
 

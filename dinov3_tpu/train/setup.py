@@ -42,6 +42,7 @@ from dinov3_tpu.parallel import (
     state_shardings_from_abstract,
 )
 from dinov3_tpu.parallel.mesh import MeshSpec
+from dinov3_tpu.telemetry import spans
 from dinov3_tpu.train.optimizer import build_optimizer
 from dinov3_tpu.train.schedules import Schedules, build_schedules
 from dinov3_tpu.train.ssl_meta_arch import SSLMetaArch
@@ -162,9 +163,10 @@ def build_train_setup(
 
     prev = get_current_mesh()
     try:
-        return _build_train_setup(
-            cfg, example_batch, rng, devices, mesh, init_state,
-            mask_sampler_calls)
+        with spans.LOG.span("setup.build"):
+            return _build_train_setup(
+                cfg, example_batch, rng, devices, mesh, init_state,
+                mask_sampler_calls)
     except BaseException:
         set_current_mesh(prev)
         raise
@@ -272,9 +274,10 @@ def _build_train_setup(
 
     # Optimizer multiplier trees need only the param paths/shapes: derive
     # them abstractly (no FLOPs, no memory).
-    abstract_params = jax.eval_shape(
-        lambda r: meta.init_params(r, example_batch), rng
-    )
+    with spans.LOG.span("setup.abstract_params"):
+        abstract_params = jax.eval_shape(
+            lambda r: meta.init_params(r, example_batch), rng
+        )
     optimizer = build_optimizer(cfg, abstract_params["student"], schedules)
     # default path: the single-pass fused clip+AdamW+EMA engine (state
     # pytree identical to the optax chain's, so init/sharding/checkpoints
@@ -500,7 +503,8 @@ def _build_train_setup(
             lowp=lowp_state,
         )
 
-    abstract = jax.eval_shape(boxed_init, rng)
+    with spans.LOG.span("setup.abstract_state"):
+        abstract = jax.eval_shape(boxed_init, rng)
     state_shardings = state_shardings_from_abstract(
         abstract, mesh, DEFAULT_LOGICAL_RULES
     )
@@ -556,7 +560,7 @@ def _build_train_setup(
             lambda r: nn.meta.unbox(boxed_init(r)),
             out_shardings=state_shardings,
         )
-        with mesh:
+        with mesh, spans.LOG.span("setup.init_state"):
             state = init_jit(rng)
     else:
         state = nn.meta.unbox(abstract)
@@ -655,10 +659,13 @@ def _build_train_setup(
                 "teacher_temp": jax.ShapeDtypeStruct((), jnp.float32),
                 "momentum": jax.ShapeDtypeStruct((), jnp.float32),
             }
-            abs_metrics = jax.eval_shape(
-                raw_step, nn.meta.unbox(abstract), example_batch,
-                abstract_scalars, jax.random.key(0),
-            )[1]
+            # the whole raw step traced once for its metric names; the
+            # jitted step's first call traces it again
+            with spans.LOG.span("setup.telemetry_plan"):
+                abs_metrics = jax.eval_shape(
+                    raw_step, nn.meta.unbox(abstract), example_batch,
+                    abstract_scalars, jax.random.key(0),
+                )[1]
             names = sorted(abs_metrics)
             ring_len = int(tele_cfg.get("flush_every", 50))
             ring_shardings = jax.tree.map(

@@ -47,6 +47,7 @@ from dinov3_tpu.checkpoint import Checkpointer
 from dinov3_tpu.configs import load_config, setup_job
 from dinov3_tpu.logging_utils import MetricLogger, setup_logging
 from dinov3_tpu.parallel import initialize_distributed, is_main_process
+from dinov3_tpu.telemetry import spans
 from dinov3_tpu.train.setup import build_train_setup, put_batch
 
 logger = logging.getLogger("dinov3")
@@ -200,9 +201,15 @@ def do_train(cfg, args, *, devices=None, data_rank=None, data_world=None,
         start_iter = (int(live_state.step) if live_state is not None
                       else int(ckpt.latest_step()))
 
-    data_iter = build_data_iterator(cfg, B, rank=rank, world_size=world,
-                                    start_iter=start_iter)
-    first = next(data_iter)
+    # set-up goes to the process's span log (telemetry/spans.py): the
+    # tracer that will stream it needs this function's own results. A
+    # second incarnation in one process starts a set-up of its own
+    log = spans.LOG
+    setup_began = log.begin_setup()
+    with log.span("setup.data_iterator"):
+        data_iter = build_data_iterator(
+            cfg, B, rank=rank, world_size=world, start_iter=start_iter)
+        first = next(data_iter)
     # serve-backed teacher (distillation.teacher_source=serve): the
     # frozen teacher forwards OUTSIDE the step — a host-shared packed
     # AOT engine + content-addressed cache (train/distillation.py
@@ -229,7 +236,6 @@ def do_train(cfg, args, *, devices=None, data_rank=None, data_world=None,
             k: jnp.asarray(v) for k, v in teacher_feature_example(
                 cfg, int(example["global_crops"].shape[0])).items()
         })
-    t0 = time.perf_counter()
     # one collate call (one ``sample_ibot_masks``) a host batch: the
     # loader's call pattern sizes the step's compact iBOT buffer
     setup = build_train_setup(cfg, example, devices=devices,
@@ -247,7 +253,8 @@ def do_train(cfg, args, *, devices=None, data_rank=None, data_world=None,
     run_topology = describe_topology(topology_of(setup))
     logger.info(
         "mesh %s | global batch %d | %d devices x %d hosts | setup %.1fs",
-        dict(setup.mesh.shape), B, n_devices, world, time.perf_counter() - t0,
+        dict(setup.mesh.shape), B, n_devices, world,
+        log.seconds("setup.build", since=setup_began),
     )
 
     if args.self_check:
@@ -276,20 +283,18 @@ def do_train(cfg, args, *, devices=None, data_rank=None, data_world=None,
         total_iters = min(total_iters, args.max_iterations)
 
     state = setup.state
-    restore_s = 0.0
-    resume_info = None
     if resuming:
         from dinov3_tpu.train.setup import elastic_resume
 
-        t_res = time.perf_counter()
-        state, resume_info = elastic_resume(
-            setup, ckpt,
-            live_state=live_state, live_topology=live_topology,
-            policy=getattr(args, "resume_topology", "auto") or "auto",
-        )
-        restore_s = time.perf_counter() - t_res
+        with log.span("setup.restore") as restore:
+            state, resume_info = elastic_resume(
+                setup, ckpt,
+                live_state=live_state, live_topology=live_topology,
+                policy=getattr(args, "resume_topology", "auto") or "auto",
+            )
+            restore["path"] = resume_info["path"]
         logger.info("elastic resume via %s path (%.2fs)",
-                    resume_info["path"], restore_s)
+                    restore["path"], restore["dur_ms"] / 1e3)
         # the freshly initialised state was only the restore's template:
         # free its buffers now, or the run holds TWO full states on the
         # device for its whole life (ViT-L at B=12 on one 16 GB chip:
@@ -415,6 +420,7 @@ def do_train(cfg, args, *, devices=None, data_rank=None, data_world=None,
         profile_steps=prof, profile_dir=f"{cfg.train.output_dir}/trace",
         role="train",
         flush_every_emits=int(tele_cfg.get("span_autoflush_every", 32)),
+        log=log,
     )
     # unified watchdog (telemetry/watchdog.py): a metrics-flush window
     # whose wall time exceeds the deadline emits a stall span into the
@@ -424,15 +430,13 @@ def do_train(cfg, args, *, devices=None, data_rank=None, data_world=None,
     from dinov3_tpu.telemetry import emit_preempt_chain, last_preempt_record
 
     if resuming and tracer.enabled:
-        # third link of the preemption span chain: the restore happened
-        # before the tracer could exist (it decides the resume step), so
-        # the measured duration is emitted post-hoc; joining against the
-        # dead incarnation's preempt_save record on the same stream
-        # yields the preemption-to-resume latency
+        # third link of the preemption span chain, with the seconds of
+        # the ``setup.restore`` span; joining against the dead
+        # incarnation's preempt_save record on the same stream yields
+        # the preemption-to-resume latency
         prev_save = last_preempt_record(cfg.train.output_dir,
                                         "preempt_save")
-        rec = {"dur_ms": round(restore_s * 1e3, 4),
-               "path": resume_info["path"] if resume_info else "disk"}
+        rec = {"dur_ms": restore["dur_ms"], "path": restore["path"]}
         if prev_save is not None:
             rec["since_preempt_s"] = round(
                 time.time() - float(prev_save["t"]), 3)
@@ -461,11 +465,14 @@ def do_train(cfg, args, *, devices=None, data_rank=None, data_world=None,
 
     preemption = PreemptionHandler().__enter__()
 
-    ring = plan.init_ring() if plan is not None else None
+    with log.span("setup.init_ring"):
+        ring = plan.init_ring() if plan is not None else None
     reader = plan.reader(start_iteration=start_iter) if plan is not None \
         else None
     timer = StepTimer(bench_n, total_iters)
-    compile_sampled = False
+    # the first dispatch traces, lowers and compiles (or loads) the step:
+    # it is set-up's last span and those ``jit.*`` spans' parent
+    first_dispatch = {"first": True}
 
     def _sched_row(i: int) -> dict:
         s = setup.schedules.at(i)
@@ -492,6 +499,8 @@ def do_train(cfg, args, *, devices=None, data_rank=None, data_world=None,
             comparator.check_batch(its_arr, plan.metric_names, rows)
         metric_logger.consume_flush(
             plan.metric_names, its_arr, rows, scheds=_sched_row)
+        if log.counters["recompiles"]:
+            metric_logger.update(recompiles=log.counters["recompiles"])
         last_loss = float(rows[-1][loss_col])
         loss_history.extend(float(r[loss_col]) for r in rows)
         if memory_on:
@@ -513,7 +522,7 @@ def do_train(cfg, args, *, devices=None, data_rank=None, data_world=None,
     ):
         batch = pending
         tracer.profile_step_begin(it)
-        with tracer.span("dispatch", it):
+        with tracer.span("dispatch", it, **first_dispatch):
             if plan is not None:
                 # async path: metrics land in the donated device ring,
                 # nothing crosses to the host — dispatch never fences
@@ -532,10 +541,17 @@ def do_train(cfg, args, *, devices=None, data_rank=None, data_world=None,
         with tracer.span("h2d", it):
             # overlap next batch's host->device transfer with this step
             pending = put_batch(raw, setup.batch_shardings, mask_rows_limit)
-        if memory_on and not compile_sampled:
+        if first_dispatch:
             # the first dispatch returned, so the step has compiled
-            tracer.emit_memory("compile", it)
-            compile_sampled = True
+            first_dispatch = {}
+            made = log.setup_done(it)
+            logger.info(
+                "set-up compiled %d programs (%d loaded from the cache, "
+                "which saved %.1fs of compiling; %d written to it)",
+                made["programs_compiled"], made["cache_hits"],
+                made["compile_time_saved_s"], made["cache_misses"])
+            if memory_on:
+                tracer.emit_memory("compile", it)
 
         if plan is None:
             # oracle path (telemetry.async_metrics=false): ONE blocking
@@ -571,6 +587,8 @@ def do_train(cfg, args, *, devices=None, data_rank=None, data_world=None,
                 mom=sched["momentum"], teacher_temp=sched["teacher_temp"],
                 **host_metrics,
             )
+            if log.counters["recompiles"]:
+                metric_logger.update(recompiles=log.counters["recompiles"])
         if timer.active(it):
             # --benchmark fences EXPLICITLY (one tiny value fetch per
             # timed step) instead of free-riding on the per-step metrics

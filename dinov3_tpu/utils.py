@@ -169,19 +169,29 @@ def configure_compile_cache() -> str:
     executable and every trace reader finds no phase. The price: an edit
     that moves a traced source line misses the cache once. File names
     enter the key relative to the checkout, so a checkout at another
-    path still finds what the same files compiled."""
+    path still finds what the same files compiled.
+
+    Being the first call of every entry point, it is also where the
+    process's span log (``telemetry/spans.py``) starts to hear JAX's
+    trace / lower / compile events, and its own span
+    ``setup.compile_cache`` starts where the process's imports end."""
     import os
 
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
-    jax.config.update("jax_hlo_source_file_canonicalization_regex",
-                      "^" + re.escape(root + os.sep))
-    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    if env_dir:
-        return env_dir
-    path = os.path.join(root, ".jax_cache")
-    jax.config.update("jax_compilation_cache_dir", path)
-    return path
+    from dinov3_tpu.telemetry import spans
+
+    with spans.LOG.span("setup.compile_cache"):
+        spans.listen_to_jax()
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        jax.config.update(
+            "jax_compilation_cache_include_metadata_in_key", True)
+        jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                          "^" + re.escape(root + os.sep))
+        env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+        if env_dir:
+            return env_dir
+        path = os.path.join(root, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+        return path
 
 
 def require_accelerator(device: str | None = "tpu") -> dict:

@@ -288,7 +288,9 @@ def test_cell_and_its_files(bench, conf):
     assert (traffic["pool_batches"], traffic["warmup_steps"],
             traffic["trace_lead_steps"], traffic["traced_steps"],
             traffic["start_iteration"]) == (8, 3, 2, 8, 1250)
-    listed = [m["name"] for m in bench["per_layer"] if CELL in m.get("workloads", ())]
+    # the metrics of the step (set-up's seven: tests/test_setup_spans.py)
+    listed = [m["name"] for m in bench["per_layer"] if CELL in m.get("workloads", ())
+              and m["moves"] == "train_img_per_s_chip"]
     assert len(listed) == 16 and listed[-8:] == [
         "lm_gdn_ms_per_step", "lm_gdn_core_ms_per_step",
         "lm_gdn_core_roofline_pct", "lm_gated_attn_ms_per_step",
@@ -303,7 +305,7 @@ def test_cell_and_its_files(bench, conf):
         if CELL in m.get("workloads", ()):
             assert os.path.isfile(os.path.join(
                 BENCH, "layer_metrics", m["name"] + ".py"))
-            assert m["moves"] == "train_img_per_s_chip"
+            assert m["moves"] in ("train_img_per_s_chip", "setup_s")
             if m["name"] in listed[-7:]:
                 assert m["workloads"] == [CELL], m["name"]
     e2e = {m["name"] for m in bench["end_to_end"]

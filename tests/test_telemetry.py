@@ -334,6 +334,59 @@ def test_dryrun_span_jsonl_schema(tiny_run):
             assert all(d["bytes_in_use"] >= 0 for d in s["devices"])
 
 
+def test_dryrun_stream_starts_with_set_up(tiny_run):
+    """The stream's head is the process's set-up: ``setup.*`` spans and
+    JAX's ``jit.*`` by program, all started before the first dispatch,
+    which is marked and is the parent of the step's own trace, lowering
+    and compile; then one record of what set-up compiled, and no program
+    compiled a second time once the loop was steady."""
+    out, _ = tiny_run
+    spans = [json.loads(l)
+             for l in open(out / "telemetry" / "spans.jsonl")]
+    dispatches = [s for s in spans if s["name"] == "dispatch"]
+    first = dispatches[0]
+    assert first["first"] is True and first["iteration"] == 0
+    assert not any("first" in s for s in dispatches[1:])
+    # one span path: the loop's records carry ids like set-up's
+    assert len({s["id"] for s in spans if "id" in s}) == sum(
+        "id" in s for s in spans) >= len(dispatches)
+    head = spans[:spans.index(first)]
+    names = {s["name"] for s in head}
+    assert {"setup.compile_cache", "setup.data_iterator", "setup.build",
+            "setup.telemetry_plan", "setup.init_ring", "jit.trace",
+            "jit.lower", "jit.compile"} <= names
+    for s in head:
+        # the hot loop's first data_wait and the set-up memory sample lie
+        # between set-up and the first dispatch too
+        assert s["name"].startswith(("setup.", "jit.")) or s["name"] in (
+            "data_wait", "memory", "dispatch")
+        if "t_mono" in s:
+            assert s["t_mono"] <= first["t_mono"] + first["dur_ms"] / 1e3
+    for s in head:
+        if s["name"].startswith("setup."):
+            assert s["t_mono"] < first["t_mono"]
+            assert s["proc"] == first["proc"] and s["v"] == 1
+    step = {s["name"]: s for s in head
+            if s.get("program") in ("telemetry_step", "jit(telemetry_step)")}
+    assert set(step) == {"jit.trace", "jit.lower", "jit.compile"}
+    assert all(s["parent"] == first["id"] for s in step.values())
+    # the steady mark: what the process had built or loaded by then
+    [made] = [s for s in spans if s["name"] == "setup.compiled"]
+    assert made["iteration"] == 0 and made["t_mono"] > first["t_mono"]
+    before = spans[:spans.index(made)]
+    assert made["programs_compiled"] >= sum(
+        s["name"] == "jit.compile" for s in before) >= 2
+    assert made["cache_hits"] >= 0 and made["compile_time_saved_s"] >= 0
+    # a program first compiled after it (a flush's or a save's small one)
+    # says at which iteration; none was compiled AGAIN, so a healthy run
+    # counts no recompile and its metric log has no such column
+    for s in spans[spans.index(made):]:
+        if s["name"] == "jit.compile":
+            assert s["iteration"] is not None and "recompile" not in s
+    assert not any("recompiles" in json.loads(l)
+                   for l in open(out / "training_metrics.json"))
+
+
 def test_dryrun_heartbeat(tiny_run):
     out, _ = tiny_run
     # role-namespaced since PR 11 (telemetry/watchdog.py keeps the
