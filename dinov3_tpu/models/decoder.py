@@ -94,6 +94,13 @@ from dinov3_tpu.ops.attention import dispatch_attention
 from dinov3_tpu.ops.common import l2_normalize, part, trunc_normal_init
 from dinov3_tpu.ops.ffn import ROWS_CAPACITY_FACTOR, RoutedExpertsFFN, SwiGLUFFN
 from dinov3_tpu.ops.kda import kda_chunked
+from dinov3_tpu.ops.mixer_chains import (
+    IN_ORDER,
+    conv_silu_norm,
+    gated_rms_norm,
+    log_decay,
+    mixer_chain_path,
+)
 from dinov3_tpu.ops.norms import RMSNorm
 from dinov3_tpu.ops.rope import rope_apply_leading, token_rope_sincos
 from dinov3_tpu.utils import step_phase
@@ -295,6 +302,7 @@ class KDAMixer(nn.Module):
     eps: float = 1e-5
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
+    chains_interpret: bool | None = None   # tests: ops/mixer_chains.py's
 
     @nn.compact
     def __call__(self, x):
@@ -302,13 +310,23 @@ class KDAMixer(nn.Module):
         h, d = self.num_heads, self.head_dim
         kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
         xc = x.astype(self.dtype)
-
         # The elementwise chains between the matmuls run in float32 and
-        # hand on bfloat16 activations; each is rematerialised by itself,
-        # so a layer's backward keeps their bf16 ends and not the dozen
-        # [tokens, heads * head_dim] float32 planes in between.
+        # hand on bfloat16 activations (g: float32), and a layer's backward
+        # keeps their ends alone, not the dozen [tokens, heads * head_dim]
+        # float32 planes in between. On the kernel path
+        # (``mixer_chain_path``: a TPU, bfloat16, heads of 128, whole time
+        # blocks) each is a kernel pair of ops/mixer_chains.py: the float32
+        # lives in VMEM, the backward makes it again there from the chain's
+        # inputs, and the [B, T, H, d] planes are written and read as the
+        # delta rule's kernels lay them. On the plain path each is the XLA
+        # chain below, rematerialised by itself (``jax.checkpoint``).
+        fused = mixer_chain_path(
+            t, (d,), (h,), self.dtype, interpret=self.chains_interpret
+        )[0] == "kernel"
+        chain = dict(interpret=self.chains_interpret)
+
         @functools.partial(jax.checkpoint, static_argnums=(2,))
-        def conv_act(y, kernel, normalise):
+        def plain_conv_act(y, kernel, normalise):
             y = nn.silu(causal_depthwise_conv(
                 y.astype(jnp.float32), kernel.astype(jnp.float32)))
             y = y.reshape(b, t, h, d)
@@ -321,7 +339,10 @@ class KDAMixer(nn.Module):
             kernel = self.param(
                 f"{name}_conv", part(trunc_normal_init(), (None, "heads")),
                 (self.conv_size, h * d), self.param_dtype)
-            return conv_act(y, kernel, normalise)
+            if fused:
+                return conv_silu_norm(y, (kernel,), (IN_ORDER,), (normalise,),
+                                      d, **chain)[0]
+            return plain_conv_act(y, kernel, normalise)
 
         q, k, v = (short_conv("q", True), short_conv("k", True),
                    short_conv("v", False))
@@ -334,13 +355,14 @@ class KDAMixer(nn.Module):
         f = _dense(h * d, (None, "heads"), "f_b", **kw)(f)
 
         @jax.checkpoint
-        def log_decay(f, a_log, dt_bias):
+        def plain_log_decay(f, a_log, dt_bias):
             return -jnp.exp(a_log.astype(jnp.float32))[:, None] \
                 * jax.nn.softplus(
                     (f.astype(jnp.float32) + dt_bias.astype(jnp.float32))
                     .reshape(b, t, h, d))
 
-        g = log_decay(f, a_log, dt_bias)
+        g = (log_decay(f, a_log, dt_bias, **chain) if fused
+             else plain_log_decay(f, a_log, dt_bias))
         beta = jax.nn.sigmoid(
             _dense(h, ("embed", None), "b_proj", **kw)(xc).astype(jnp.float32))
         with jax.named_scope("kda_core"):
@@ -360,7 +382,9 @@ class KDAMixer(nn.Module):
                 gate.astype(jnp.float32).reshape(b, t, h, d))
             return o.reshape(b, t, h * d).astype(self.dtype)
 
-        o = gated_norm(o, gate, scale)
+        o = (gated_rms_norm(o, gate, scale, IN_ORDER, "sigmoid", self.eps,
+                            **chain)
+             if fused else gated_norm(o, gate, scale))
         return _dense(x.shape[-1], ("heads", "embed"), "o_proj", **kw)(o)
 
 
@@ -379,6 +403,7 @@ class GDNMixer(nn.Module):
     eps: float = 1e-6
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
+    chains_interpret: bool | None = None   # tests: ops/mixer_chains.py's
 
     @nn.compact
     def __call__(self, x):
@@ -393,15 +418,28 @@ class GDNMixer(nn.Module):
         # the published grouping of the columns, a key head at a time:
         # [q dk | k dk | v r dv | z r dv] and [b r | a r]
         qkvz = _dense(2 * nk + 2 * hv * dv, ("embed", "heads"), "in_proj_qkvz",
-                      **kw)(xc).reshape(b, t, hk, 2 * dk + 2 * r * dv)
+                      **kw)(xc)
+        # on the kernel path (KDAMixer's words; one head width) the chains
+        # read q, k, v and z out of that grouping where they lie, a lane
+        # group a head: nothing is concatenated and the plane stays
+        # [B, T, columns]
+        fused = mixer_chain_path(
+            t, (dk, dv), (hk, hv), self.dtype, interpret=self.chains_interpret
+        )[0] == "kernel"
+        chain = dict(interpret=self.chains_interpret)
+        # (first, n, per): n heads side by side from lane group ``first``
+        # of every key head's 2 + 2 r
+        layout = lambda first, n: (first, n, 2 + 2 * r)  # noqa: E731
+        if not fused:
+            qkvz = qkvz.reshape(b, t, hk, 2 * dk + 2 * r * dv)
         ba = _dense(2 * hv, ("embed", None), "in_proj_ba", **kw)(xc)
         ba = ba.reshape(b, t, hk, 2 * r).astype(jnp.float32)
         kernel = self.param(
             "conv", part(trunc_normal_init(), (None, "heads")),
             (self.conv_size, 2 * nk + hv * dv), self.param_dtype)
 
-        # (the float32 chains between the matmuls are rematerialised by
-        # themselves, as KDAMixer's are)
+        # (the float32 chains between the matmuls: on the plain path
+        # rematerialised by themselves, as KDAMixer's are)
         @jax.checkpoint
         def conv_act(qkvz, kernel):
             # ONE convolution over the joined channels: every q head, then
@@ -418,7 +456,15 @@ class GDNMixer(nn.Module):
             return (unit(y[..., :nk]), unit(y[..., nk:2 * nk]),
                     y[..., 2 * nk:].reshape(b, t, hv, dv).astype(self.dtype))
 
-        q, k, v = conv_act(qkvz, kernel)
+        if fused:
+            # the same ONE convolution: the taps are in the joined order,
+            # q's then k's then v's; eps as above
+            q, k, v = conv_silu_norm(
+                qkvz, (kernel[:, :nk], kernel[:, nk:2 * nk], kernel[:, 2 * nk:]),
+                (layout(0, 1), layout(1, 1), layout(2, r)),
+                (True, True, False), dk, eps=1e-3, **chain)
+        else:
+            q, k, v = conv_act(qkvz, kernel)
         a_log = self.param(
             "A_log", part(functools.partial(a_log_init, lo=GDN_A_FLOOR),
                           ("heads",)), (hv,), self.param_dtype)
@@ -445,8 +491,10 @@ class GDNMixer(nn.Module):
             o = o * nn.silu(f32(qkvz[..., 2 * dk + r * dv:]).reshape(b, t, hv, dv))
             return o.reshape(b, t, hv * dv).astype(self.dtype)
 
-        return _dense(x.shape[-1], ("heads", "embed"), "o_proj", **kw)(
-            gated_norm(o, qkvz, scale))
+        o = (gated_rms_norm(o, qkvz, scale, layout(2 + r, r), "silu",
+                            self.eps, **chain)
+             if fused else gated_norm(o, qkvz, scale))
+        return _dense(x.shape[-1], ("heads", "embed"), "o_proj", **kw)(o)
 
 
 class MLAMixer(nn.Module):

@@ -263,14 +263,14 @@ def _turned(x):
                    axis=x.shape.index(n), keepdims=True)
 
 
-def _pair_planes(ref, pair, heads):
-    """Heads ``2 * pair`` and ``2 * pair + 1``'s [C, d] float32 planes out
-    of a block [1, C * heads, d] whose rows run token-major, head-minor
+def _pair_planes(ref, pair, heads, rows=CHUNK):
+    """Heads ``2 * pair`` and ``2 * pair + 1``'s [rows, d] float32 planes out
+    of a block [1, rows * heads, d] whose rows run token-major, head-minor
     (the [B, T, H, d] array as it lies). A float32 block gives a head's
     rows by a strided load; a bfloat16 block packs two rows a 32-bit
     word, which here are the pair's own two heads: one strided load of
     words, the even head in the low halves."""
-    at = lambda first, stride: (0, pl.ds(first, CHUNK, stride=stride))  # noqa: E731
+    at = lambda first, stride: (0, pl.ds(first, rows, stride=stride))  # noqa: E731
     if ref.dtype == jnp.float32:
         return tuple(ref[at(2 * pair + hd, heads)] for hd in (0, 1))
     words = ref.bitcast(jnp.uint32)[at(pair, heads // 2)]
@@ -278,11 +278,11 @@ def _pair_planes(ref, pair, heads):
             pltpu.bitcast(words & jnp.uint32(0xFFFF0000), jnp.float32))
 
 
-def _store_pair(ref, pair, heads, even, odd):
-    """``_pair_planes`` the other way: the pair's two [C, d] float32
+def _store_pair(ref, pair, heads, even, odd, rows=CHUNK):
+    """``_pair_planes`` the other way: the pair's two [rows, d] float32
     planes into a block of the gradient; into a bfloat16 block rounded to
     nearest even and packed, a word a pair of rows."""
-    at = lambda first, stride: (0, pl.ds(first, CHUNK, stride=stride))  # noqa: E731
+    at = lambda first, stride: (0, pl.ds(first, rows, stride=stride))  # noqa: E731
     if ref.dtype == jnp.float32:
         ref[at(2 * pair, heads)] = even
         ref[at(2 * pair + 1, heads)] = odd

@@ -1,0 +1,621 @@
+"""The elementwise chains between a delta-rule mixer's matmuls, each a
+Pallas kernel pair: bfloat16 planes in, float32 in VMEM, out what the
+next consumer takes, so that no float32 ``[tokens, heads * width]`` plane
+crosses HBM and none is re-laid between the projections' ``[B, T, H * d]``
+(8 tokens x 128 channels a tile) and the delta rule's ``[B, T, H, d]``
+(``ops/kda.py``: 8 HEADS x 128 channels a tile, rows token-major and
+head-minor). The relayout happens in VMEM: a head is one 128-lane group
+of a ``[B, T, C]`` block and every H-th row of a ``[B, T * H, d]`` block,
+read and written by strided loads and stores (bfloat16 a pair of heads a
+32-bit word, ``ops/kda.py _pair_planes`` / ``_store_pair``).
+
+Three chains, shared by ``models/decoder.py KDAMixer`` and ``GDNMixer``
+(which differ in what a kernel is GIVEN: which lane group of the input
+holds which head, which outputs are normalised, eps, the gate's
+activation, the convolution's width). A kernel's body is ONE pair of
+heads inside a ``fori_loop`` over the pairs (lane offsets that are the
+loop's index, hinted as multiples of the head width): unrolled over 16
+pairs the bodies cost 15 s of a warm set-up's tracing (``PERF.md``, PR 38):
+
+- ``conv_silu_norm``: y = SiLU(causal depthwise convolution of width W)
+  and, where asked, y / sqrt(sum_d y^2 + eps^2) a head
+  (``ops/common.py l2_normalize``), out of one ``[B, T, C]`` plane into
+  one ``[B, T, H, d]`` plane an output. A grid step is a block of tokens
+  of every head; the W - 1 tokens before it come from the previous
+  block's last rows (a second, 16-row view of the same plane).
+- ``gated_rms_norm``: o / sqrt(mean_d o^2 + eps) * scale * act(gate),
+  o float32 ``[B, T, H, d]`` (the delta rule's output as it lies), gate a
+  lane group a head of a bfloat16 ``[B, T, C]`` plane, out bfloat16
+  ``[B, T, H * d]`` for the output projection.
+- ``log_decay``: g = -exp(A_log) softplus(f + dt_bias), bfloat16
+  ``[B, T, H * d]`` to the float32 ``[B, T, H, d]`` the delta rule reads.
+
+Each is a ``jax.custom_vjp`` whose residuals are the chain's INPUTS and
+nothing else (what the plain chains' ``jax.checkpoint`` keeps); the
+backward kernel makes the float32 intermediates again in VMEM and applies
+the chain's derivative in closed form. The gradients of the small
+parameters (the ``[W, C]`` taps, the norm's scale, the decay's two
+vectors) are summed in float32 in an output block that stays in VMEM over
+a sequence's grid steps, a sequence a row, and added up outside. The
+convolution's backward runs the blocks LAST FIRST and carries the first
+rows of a block's pre-activation gradient to the block before it (the
+taps reach W - 1 tokens ahead).
+
+Rounding is where the plain chains round: the inputs are the bfloat16
+projection results, everything inside is float32, the outputs are rounded
+once (to nearest even) or stay float32. ``mixer_chain_path`` reads the
+path off what it can see; the plain chains live in the mixers.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from dinov3_tpu.ops.kda import _pair_planes, _store_pair
+
+TIME_BLOCK = 128   # tokens a grid step
+LANES = 128
+_HALO = 16         # rows of the previous block a step is handed (a bf16 tile)
+_EDGE = 8          # of them, and of a carried gradient, the rows used
+CONV_KERNEL_NAME = "conv_silu_norm_fwd"
+CONV_BACKWARD_KERNEL_NAME = "conv_silu_norm_bwd"
+NORM_KERNEL_NAME = "gated_rms_norm_fwd"
+NORM_BACKWARD_KERNEL_NAME = "gated_rms_norm_bwd"
+DECAY_KERNEL_NAME = "log_decay_fwd"
+DECAY_BACKWARD_KERNEL_NAME = "log_decay_bwd"
+# a step holds its blocks twice over (the pipeline's two buffers): 4 MB a
+# 128 x 8192 bfloat16 plane and its gradient, past what a kernel gets unasked
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary"),  # sequences, time blocks
+    vmem_limit_bytes=64 * 1024 * 1024)
+_ACTS = {"sigmoid": jax.nn.sigmoid, "silu": jax.nn.silu}
+
+
+def mixer_chain_path(length: int, head_dims, heads, dtype,
+                     block: int = TIME_BLOCK,
+                     interpret: bool | None = None) -> tuple[str, str]:
+    """(path, why) the chains of a mixer take: ("kernel", ...) or
+    ("plain", the reason it is not the kernels). ``head_dims`` and
+    ``heads``: the head widths (key, value) and the head counts of the
+    ``[B, T, H, d]`` planes the chains read or write."""
+    if jnp.dtype(dtype) != jnp.bfloat16:
+        return "plain", f"the planes are {jnp.dtype(dtype).name}, not bfloat16"
+    if len(set(head_dims)) != 1 or head_dims[0] % LANES:
+        return "plain", (f"heads {tuple(head_dims)} wide, not one multiple "
+                         f"of {LANES}")
+    if any(h % 2 for h in heads):
+        return "plain", f"head counts {tuple(heads)}: a pair of heads a word"
+    if length % block:
+        return "plain", f"{length} tokens are not whole blocks of {block}"
+    backend = jax.default_backend()
+    if interpret is None and backend != "tpu":
+        return "plain", f"the backend is {backend}, not a TPU"
+    return "kernel", "interpreted" if interpret else "compiled for the TPU"
+
+
+# ------------------------------------------------------------ helpers
+
+
+def _tokens_spec(rows: int, width: int, reverse_of: int | None = None):
+    """A block of tokens of a [B, T, C] array (``rows`` tokens of ``width``
+    channels) or of a [B, T * H, d] one (``rows`` = tokens x heads: every
+    head of them); with ``reverse_of`` = n grid step m is block n - 1 - m."""
+    at = (lambda m: reverse_of - 1 - m) if reverse_of else (lambda m: m)
+    return pl.BlockSpec((1, rows, width), lambda i, m: (i, at(m), 0),
+                        memory_space=pltpu.VMEM)
+
+
+def _whole_spec(shape):
+    return pl.BlockSpec(shape, lambda i, m: (0,) * len(shape),
+                        memory_space=pltpu.VMEM)
+
+
+def _sums_spec(rows: int, c: int):
+    """A sequence's row of a [B, rows, C] float32 sum: the same block at
+    every time step, so it stays in VMEM until the sequence is done."""
+    return pl.BlockSpec((None, rows, c), lambda i, m: (i, 0, 0),
+                        memory_space=pltpu.VMEM)
+
+
+def _rows_type(x_shape, heads: int, d: int, dtype, interpret: bool):
+    """The type of a [B, T * H, d] output; the interpreter cannot store
+    through a block's 32-bit view, so there a bfloat16 plane leaves the
+    kernel as float32 and is rounded after (``ops/kda.py``)."""
+    b, t = x_shape[:2]
+    return jax.ShapeDtypeStruct(
+        (b, t * heads, d), jnp.float32 if interpret else dtype)
+
+
+def _lanes(group, d: int):
+    """The ``d`` channels of lane group ``group`` (a head of a [B, T, C]
+    block), static or a loop's index."""
+    if isinstance(group, int):
+        return slice(group * d, (group + 1) * d)
+    return pl.ds(pl.multiple_of(group * d, d), d)
+
+
+def _group(layout, head):
+    """The lane group that holds ``head``: ``layout`` = (first, n, per)
+    says head h lies at (h // n) * per + first + h % n: n heads side by
+    side from ``first`` in every stretch of ``per`` groups."""
+    first, n, per = layout
+    return (head // n) * per + first + head % n
+
+
+def _fed(layouts, heads):
+    """The lane groups the heads of every output read, in order."""
+    return [_group(layout, h) for layout, n in zip(layouts, heads)
+            for h in range(n)]
+
+
+def _each_pair(heads: int, body):
+    """``body(pair)`` for every pair of heads, as ONE traced loop: the
+    kernels' bodies are a pair long, not ``heads / 2`` pairs."""
+    jax.lax.fori_loop(0, heads // 2, lambda pair, c: (body(pair), c)[1], 0)
+
+
+def _shifted(x, edge, k: int, back: bool):
+    """Row t of x [n, d] replaced by row t - k (``back``: the rows before
+    x's first are ``edge``'s [8, d] last) or by row t + k (the rows past
+    x's last are ``edge``'s first)."""
+    n = x.shape[0]
+    if k == 0:
+        return x
+    if back:
+        return pltpu.roll(jnp.concatenate([edge, x], 0), k, 0)[_EDGE:]
+    return pltpu.roll(jnp.concatenate([x, edge], 0), n + _EDGE - k, 0)[:n]
+
+
+def _first_step_zeros(ref):
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        ref[...] = jnp.zeros_like(ref)
+
+
+# ---------------------------------------- convolution + SiLU + unit norm
+
+
+def _conv_pre(x_ref, halo_ref, w_ref, group, cols, d, width, starts):
+    """The pre-activation s = conv(x) of one head, float32 [block, d], and
+    the W shifted planes it is the weighted sum of (tap j reads the token
+    W - 1 - j before). ``starts``: this block is its sequence's first and
+    has nothing before it."""
+    x = x_ref[0, :, _lanes(group, d)].astype(jnp.float32)
+    before = halo_ref[0, :, _lanes(group, d)].astype(jnp.float32)[_EDGE:]
+    before = jnp.where(starts, 0.0, before)
+    planes = [_shifted(x, before, width - 1 - j, back=True)
+              for j in range(width)]
+    s = sum(w_ref[j:j + 1, cols] * planes[j] for j in range(width))
+    return s, planes
+
+
+def _conv_fwd_kernel(x_ref, halo_ref, *refs, layouts, heads, normalise, eps,
+                     d, width, block):
+    n = len(layouts)
+    starts = pl.program_id(1) == 0     # (read outside the loops' bodies)
+    for w_ref, y_ref, layout, h, unit in zip(refs[:n], refs[n:], layouts,
+                                             heads, normalise):
+        def one_pair(pair):        # (traced at once, inside this turn)
+            out = []
+            for head in (2 * pair, 2 * pair + 1):
+                s, _ = _conv_pre(x_ref, halo_ref, w_ref, _group(layout, head),
+                                 _lanes(head, d), d, width, starts)
+                a = s * jax.nn.sigmoid(s)
+                if unit:
+                    a = a * jax.lax.rsqrt(
+                        jnp.sum(a * a, axis=-1, keepdims=True) + eps * eps)
+                out.append(a)
+            _store_pair(y_ref, pair, h, *out, rows=block)
+
+        _each_pair(h, one_pair)
+
+
+def _conv_bwd_kernel(x_ref, halo_ref, *refs, layouts, heads, normalise, eps,
+                     d, width, block, last_step):
+    """The blocks come LAST FIRST (the wrapper's grid: a sequence's first
+    block is grid step ``last_step``; ``halo_ref`` is still the rows
+    BEFORE this block). ``carry_ref`` [8, channels] holds, head by head,
+    the first rows of the pre-activation's gradient of the block after
+    this one."""
+    n = len(layouts)
+    w_refs, dy_refs = refs[:n], refs[n:2 * n]
+    dx_ref, dw_refs, carry_ref = refs[2 * n], refs[2 * n + 1:3 * n + 1], refs[-1]
+    first = pl.program_id(1) == 0            # the sequence's LAST block
+    starts = pl.program_id(1) == last_step
+    if len(_fed(layouts, heads)) < x_ref.shape[-1] // d:
+        dx_ref[...] = jnp.zeros_like(dx_ref)  # the groups no head reads
+    for dw_ref in dw_refs:
+        _first_step_zeros(dw_ref)
+    at = 0
+    for w_ref, dy_ref, dw_ref, layout, h, unit in zip(
+            w_refs, dy_refs, dw_refs, layouts, heads, normalise):
+        def one_pair(pair):
+            for hd, dy in enumerate(
+                    _pair_planes(dy_ref, pair, h, rows=block)):
+                head = 2 * pair + hd
+                group = _group(layout, head)
+                cols, mine = _lanes(head, d), _lanes(at + head, d)
+                s, planes = _conv_pre(x_ref, halo_ref, w_ref, group, cols, d,
+                                      width, starts)
+                sig = jax.nn.sigmoid(s)
+                a = s * sig
+                if unit:
+                    r = jax.lax.rsqrt(
+                        jnp.sum(a * a, axis=-1, keepdims=True) + eps * eps)
+                    dy = r * (dy - a * (r * r) * jnp.sum(
+                        dy * a, axis=-1, keepdims=True))
+                ds = dy * sig * (1.0 + s * (1.0 - sig))
+                after = jnp.where(first, 0.0, carry_ref[:, mine])
+                carry_ref[:, mine] = ds[:_EDGE]
+                dx = sum(w_ref[j:j + 1, cols] * _shifted(
+                    ds, after, width - 1 - j, back=False)
+                    for j in range(width))
+                dx_ref[0, :, _lanes(group, d)] = dx.astype(dx_ref.dtype)
+                for j in range(width):
+                    dw_ref[j:j + 1, cols] += jnp.sum(
+                        ds * planes[j], axis=0, keepdims=True)
+
+        _each_pair(h, one_pair)
+        at += h
+
+
+def _conv_operands(x, block, reverse):
+    """x's two views: a block of tokens, and the 16 rows before it (the
+    first block is handed its own first rows, which the kernel zeroes)."""
+    b, t, c = x.shape
+    if block % _HALO:
+        raise ValueError(f"a time block of {block} is not whole tiles of {_HALO}")
+    n = t // block
+    at = (lambda m: n - 1 - m) if reverse else (lambda m: m)
+    per = block // _HALO
+    halo = pl.BlockSpec(
+        (1, _HALO, c), lambda i, m: (i, jnp.maximum(at(m) * per - 1, 0), 0),
+        memory_space=pltpu.VMEM)
+    return _tokens_spec(block, c, n if reverse else None), halo, n
+
+
+def _head_counts(kernels, d):
+    return tuple(w.shape[1] // d for w in kernels)
+
+
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "layouts", "normalise", "eps", "d", "block", "interpret"))
+def _conv_forward(x, kernels, layouts, normalise, eps, d, block, interpret):
+    b, t, c = x.shape
+    heads = _head_counts(kernels, d)
+    spec, halo, n = _conv_operands(x, block, reverse=False)
+    out = pl.pallas_call(
+        functools.partial(_conv_fwd_kernel, layouts=layouts, heads=heads,
+                          normalise=normalise, eps=eps, d=d,
+                          width=kernels[0].shape[0], block=block),
+        grid=(b, n),
+        in_specs=[spec, halo] + [_whole_spec(w.shape) for w in kernels],
+        out_specs=[_tokens_spec(block * h, d) for h in heads],
+        out_shape=[_rows_type(x.shape, h, d, x.dtype, interpret)
+                   for h in heads],
+        compiler_params=_COMPILER_PARAMS,
+        interpret=interpret,
+        name=CONV_KERNEL_NAME,
+    )(x, x, *(w.astype(jnp.float32) for w in kernels))
+    return tuple(y.astype(x.dtype).reshape(b, t, h, d)
+                 for y, h in zip(out, heads))
+
+
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "layouts", "normalise", "eps", "d", "block", "interpret"))
+def _conv_backward(x, kernels, dys, layouts, normalise, eps, d, block,
+                   interpret):
+    b, t, c = x.shape
+    heads = _head_counts(kernels, d)
+    spec, halo, n = _conv_operands(x, block, reverse=True)
+    dx, *dws = pl.pallas_call(
+        functools.partial(_conv_bwd_kernel, layouts=layouts, heads=heads,
+                          normalise=normalise, eps=eps, d=d,
+                          width=kernels[0].shape[0], block=block,
+                          last_step=n - 1),
+        grid=(b, n),
+        in_specs=[spec, halo] + [_whole_spec(w.shape) for w in kernels]
+        + [_tokens_spec(block * h, d, n) for h in heads],
+        out_specs=[spec] + [_sums_spec(*w.shape) for w in kernels],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)] + [
+            jax.ShapeDtypeStruct((b,) + w.shape, jnp.float32)
+            for w in kernels],
+        scratch_shapes=[pltpu.VMEM((_EDGE, sum(heads) * d), jnp.float32)],
+        compiler_params=_COMPILER_PARAMS,
+        interpret=interpret,
+        name=CONV_BACKWARD_KERNEL_NAME,
+    )(x, x, *(w.astype(jnp.float32) for w in kernels),
+      *(dy.reshape(b, t * h, d) for dy, h in zip(dys, heads)))
+    return dx, tuple(jnp.sum(dw, axis=0).astype(w.dtype)
+                     for dw, w in zip(dws, kernels))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6, 7))
+def _conv_chain(x, kernels, layouts, normalise, eps, d, block, interpret):
+    return _conv_forward(x, kernels, layouts=layouts, normalise=normalise,
+                         eps=eps, d=d, block=block, interpret=interpret)
+
+
+def _conv_chain_fwd(x, kernels, *static):
+    return _conv_chain(x, kernels, *static), (x, kernels)
+
+
+def _conv_chain_bwd(layouts, normalise, eps, d, block, interpret, res, dys):
+    return _conv_backward(*res, dys, layouts=layouts, normalise=normalise,
+                          eps=eps, d=d, block=block, interpret=interpret)
+
+
+_conv_chain.defvjp(_conv_chain_fwd, _conv_chain_bwd)
+
+IN_ORDER = (0, 1, 1)   # the layout of a plane whose heads lie in order
+
+
+def _checked(layouts, heads, groups: int):
+    layouts = tuple(tuple(int(v) for v in layout) for layout in layouts)
+    fed = _fed(layouts, heads)
+    if len(set(fed)) != len(fed) or not all(0 <= g < groups for g in fed):
+        raise ValueError(f"layouts {layouts} of {heads} heads: a lane group "
+                         f"of the {groups} feeds two heads, or none is there")
+    return layouts
+
+
+def conv_silu_norm(x, kernels, layouts, normalise, head_dim: int,
+                   eps: float = 1e-12, block: int = TIME_BLOCK,
+                   interpret: bool | None = None):
+    """One ``[B, T, H_i, head_dim]`` plane an output i, in x's type:
+    head h of output i is SiLU(conv) of the lane group of x that
+    ``layouts[i]`` = (first, n, per) gives it, (h // n) * per + first +
+    h % n (``IN_ORDER``: group h), under the taps
+    ``kernels[i][:, h * head_dim:(h + 1) * head_dim]``, divided by
+    sqrt(its sum of squares + eps^2) where ``normalise[i]``.
+
+    x [B, T, C] bfloat16, T whole blocks; kernels[i] [W, H_i * head_dim];
+    no lane group feeds two heads (the backward writes a group's gradient
+    once; a group that feeds none gets zeros)."""
+    d = int(head_dim)
+    layouts = _checked(layouts, _head_counts(kernels, d), x.shape[-1] // d)
+    return _conv_chain(x, tuple(kernels), layouts, tuple(map(bool, normalise)),
+                       float(eps), d, int(block), bool(interpret))
+
+
+# ------------------------------------------------------ gated RMS norm
+
+
+def _normed(o, eps):
+    r = jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps)
+    return o * r, r
+
+
+def _norm_fwd_kernel(o_ref, gate_ref, scale_ref, y_ref, *, layout, heads, act,
+                     eps, d, block):
+    scale = scale_ref[...]
+
+    def one_pair(pair):
+        for hd, o in enumerate(_pair_planes(o_ref, pair, heads, rows=block)):
+            head = 2 * pair + hd
+            z = gate_ref[0, :, _lanes(_group(layout, head), d)]
+            y_ref[0, :, _lanes(head, d)] = (
+                _normed(o, eps)[0] * scale
+                * _ACTS[act](z.astype(jnp.float32))).astype(y_ref.dtype)
+
+    _each_pair(heads, one_pair)
+
+
+def _norm_bwd_kernel(o_ref, gate_ref, scale_ref, dy_ref, do_ref, dgate_ref,
+                     dscale_ref, *, layout, heads, act, eps, d, block):
+    scale = scale_ref[...]
+    _first_step_zeros(dscale_ref)
+    if heads < gate_ref.shape[-1] // d:
+        dgate_ref[...] = jnp.zeros_like(dgate_ref)  # the groups no head reads
+
+    def one_pair(pair):
+        grads = []
+        for hd, o in enumerate(_pair_planes(o_ref, pair, heads, rows=block)):
+            head = 2 * pair + hd
+            lanes = _lanes(_group(layout, head), d)
+            z = gate_ref[0, :, lanes].astype(jnp.float32)
+            dy = dy_ref[0, :, _lanes(head, d)].astype(jnp.float32)
+            normed, r = _normed(o, eps)
+            sig = jax.nn.sigmoid(z)
+            if act == "sigmoid":
+                gated, slope = sig, sig * (1.0 - sig)
+            else:
+                gated, slope = z * sig, sig * (1.0 + z * (1.0 - sig))
+            dscale_ref[0:1, :] += jnp.sum(dy * normed * gated, axis=0,
+                                          keepdims=True)
+            dgate_ref[0, :, lanes] = (
+                dy * normed * scale * slope).astype(dgate_ref.dtype)
+            dn = dy * scale * gated
+            grads.append(r * (dn - normed * jnp.mean(
+                dn * normed, axis=-1, keepdims=True)))
+        _store_pair(do_ref, pair, heads, *grads, rows=block)
+
+    _each_pair(heads, one_pair)
+
+
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "layout", "act", "eps", "block", "interpret"))
+def _norm_forward(o, gate, scale, layout, act, eps, block, interpret):
+    b, t, h, d = o.shape
+    return pl.pallas_call(
+        functools.partial(_norm_fwd_kernel, layout=layout, heads=h, act=act,
+                          eps=eps, d=d, block=block),
+        grid=(b, t // block),
+        in_specs=[_tokens_spec(block * h, d),
+                  _tokens_spec(block, gate.shape[-1]), _whole_spec((1, d))],
+        out_specs=_tokens_spec(block, h * d),
+        out_shape=jax.ShapeDtypeStruct((b, t, h * d), gate.dtype),
+        compiler_params=_COMPILER_PARAMS,
+        interpret=interpret,
+        name=NORM_KERNEL_NAME,
+    )(o.reshape(b, t * h, d), gate, scale.astype(jnp.float32).reshape(1, d))
+
+
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "layout", "act", "eps", "block", "interpret"))
+def _norm_backward(o, gate, scale, dy, layout, act, eps, block, interpret):
+    b, t, h, d = o.shape
+    do, dgate, dscale = pl.pallas_call(
+        functools.partial(_norm_bwd_kernel, layout=layout, heads=h, act=act,
+                          eps=eps, d=d, block=block),
+        grid=(b, t // block),
+        in_specs=[_tokens_spec(block * h, d),
+                  _tokens_spec(block, gate.shape[-1]), _whole_spec((1, d)),
+                  _tokens_spec(block, h * d)],
+        out_specs=[_tokens_spec(block * h, d),
+                   _tokens_spec(block, gate.shape[-1]), _sums_spec(_EDGE, d)],
+        out_shape=[jax.ShapeDtypeStruct((b, t * h, d), jnp.float32),
+                   jax.ShapeDtypeStruct(gate.shape, gate.dtype),
+                   jax.ShapeDtypeStruct((b, _EDGE, d), jnp.float32)],
+        compiler_params=_COMPILER_PARAMS,
+        interpret=interpret,
+        name=NORM_BACKWARD_KERNEL_NAME,
+    )(o.reshape(b, t * h, d), gate, scale.astype(jnp.float32).reshape(1, d), dy)
+    return (do.reshape(o.shape), dgate,
+            jnp.sum(dscale[:, 0], axis=0).astype(scale.dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _norm_chain(o, gate, scale, layout, act, eps, block, interpret):
+    return _norm_forward(o, gate, scale, layout=layout, act=act, eps=eps,
+                         block=block, interpret=interpret)
+
+
+def _norm_chain_fwd(o, gate, scale, *static):
+    return _norm_chain(o, gate, scale, *static), (o, gate, scale)
+
+
+def _norm_chain_bwd(layout, act, eps, block, interpret, res, dy):
+    return _norm_backward(*res, dy, layout=layout, act=act, eps=eps,
+                          block=block, interpret=interpret)
+
+
+_norm_chain.defvjp(_norm_chain_fwd, _norm_chain_bwd)
+
+
+def gated_rms_norm(o, gate, scale, layout=IN_ORDER, act: str = "sigmoid",
+                   eps: float = 1e-6, block: int = TIME_BLOCK,
+                   interpret: bool | None = None):
+    """[B, T, H * d] in the gate's type: o / sqrt(mean_d o^2 + eps) *
+    scale * act(gate), head by head.
+
+    o [B, T, H, d] float32; gate [B, T, C] bfloat16, head h's gate the
+    lane group ``layout`` gives it (``conv_silu_norm``'s words); scale
+    [d]; ``act`` "sigmoid" or "silu". The gate's gradient is zero in the
+    groups no head reads."""
+    h, d = o.shape[2:]
+    (layout,) = _checked((layout,), (h,), gate.shape[-1] // d)
+    return _norm_chain(o.astype(jnp.float32), gate, scale, layout, act,
+                       float(eps), int(block), bool(interpret))
+
+
+# ------------------------------------------------------------ log decay
+
+
+def _softplus(x):
+    return jnp.maximum(x, 0.0) + jnp.log1p(jnp.exp(-jnp.abs(x)))
+
+
+def _decay_fwd_kernel(f_ref, rate_ref, bias_ref, g_ref, *, heads, d, block):
+    def one_pair(pair):
+        out = []
+        for head in (2 * pair, 2 * pair + 1):
+            cols = _lanes(head, d)
+            out.append(rate_ref[:, cols] * _softplus(
+                f_ref[0, :, cols].astype(jnp.float32) + bias_ref[:, cols]))
+        _store_pair(g_ref, pair, heads, *out, rows=block)
+
+    _each_pair(heads, one_pair)
+
+
+def _decay_bwd_kernel(f_ref, rate_ref, bias_ref, dg_ref, df_ref, drate_ref,
+                      dbias_ref, *, heads, d, block):
+    _first_step_zeros(drate_ref)
+    _first_step_zeros(dbias_ref)
+
+    def one_pair(pair):
+        for hd, dg in enumerate(_pair_planes(dg_ref, pair, heads, rows=block)):
+            cols = _lanes(2 * pair + hd, d)
+            x = f_ref[0, :, cols].astype(jnp.float32) + bias_ref[:, cols]
+            df = dg * rate_ref[:, cols] * jax.nn.sigmoid(x)
+            df_ref[0, :, cols] = df.astype(df_ref.dtype)
+            dbias_ref[0:1, cols] += jnp.sum(df, axis=0, keepdims=True)
+            drate_ref[0:1, cols] += jnp.sum(dg * _softplus(x), axis=0,
+                                            keepdims=True)
+
+    _each_pair(heads, one_pair)
+
+
+@functools.partial(jax.jit, inline=True,
+                   static_argnames=("heads", "block", "interpret"))
+def _decay_forward(f, rate, bias, heads, block, interpret):
+    b, t, c = f.shape
+    d = c // heads
+    return pl.pallas_call(
+        functools.partial(_decay_fwd_kernel, heads=heads, d=d, block=block),
+        grid=(b, t // block),
+        in_specs=[_tokens_spec(block, c), _whole_spec((1, c)),
+                  _whole_spec((1, c))],
+        out_specs=_tokens_spec(block * heads, d),
+        out_shape=jax.ShapeDtypeStruct((b, t * heads, d), jnp.float32),
+        compiler_params=_COMPILER_PARAMS,
+        interpret=interpret,
+        name=DECAY_KERNEL_NAME,
+    )(f, rate, bias).reshape(b, t, heads, d)
+
+
+@functools.partial(jax.jit, inline=True,
+                   static_argnames=("heads", "block", "interpret"))
+def _decay_backward(f, rate, bias, dg, heads, block, interpret):
+    b, t, c = f.shape
+    d = c // heads
+    df, drate, dbias = pl.pallas_call(
+        functools.partial(_decay_bwd_kernel, heads=heads, d=d, block=block),
+        grid=(b, t // block),
+        in_specs=[_tokens_spec(block, c), _whole_spec((1, c)),
+                  _whole_spec((1, c)), _tokens_spec(block * heads, d)],
+        out_specs=[_tokens_spec(block, c), _sums_spec(_EDGE, c),
+                   _sums_spec(_EDGE, c)],
+        out_shape=[jax.ShapeDtypeStruct(f.shape, f.dtype)]
+        + [jax.ShapeDtypeStruct((b, _EDGE, c), jnp.float32)] * 2,
+        compiler_params=_COMPILER_PARAMS,
+        interpret=interpret,
+        name=DECAY_BACKWARD_KERNEL_NAME,
+    )(f, rate, bias, dg.reshape(b, t * heads, d))
+    return (df, jnp.sum(drate[:, :1], axis=0), jnp.sum(dbias[:, :1], axis=0))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _decay_chain(f, rate, bias, heads, block, interpret):
+    return _decay_forward(f, rate, bias, heads=heads, block=block,
+                          interpret=interpret)
+
+
+def _decay_chain_fwd(f, rate, bias, *static):
+    return _decay_chain(f, rate, bias, *static), (f, rate, bias)
+
+
+def _decay_chain_bwd(heads, block, interpret, res, dg):
+    return _decay_backward(*res, dg, heads=heads, block=block,
+                           interpret=interpret)
+
+
+_decay_chain.defvjp(_decay_chain_fwd, _decay_chain_bwd)
+
+
+def log_decay(f, a_log, dt_bias, block: int = TIME_BLOCK,
+              interpret: bool | None = None):
+    """g [B, T, H, d] float32 = -exp(A_log_h) softplus(f + dt_bias): f
+    [B, T, H * d] bfloat16, a_log [H], dt_bias [H * d]. The two vectors
+    go in as float32 rows a channel; A_log's exponential and the sum of
+    its row's gradient over a head's channels are JAX's, outside."""
+    heads = a_log.shape[0]
+    d = f.shape[-1] // heads
+    rate = jnp.repeat(-jnp.exp(a_log.astype(jnp.float32)), d)[None]
+    return _decay_chain(f, rate, dt_bias.astype(jnp.float32)[None], heads,
+                        int(block), bool(interpret))

@@ -26,6 +26,7 @@ from dinov3_tpu.configs import ConfigNode
 from dinov3_tpu.models import build_backbone
 from dinov3_tpu.ops.causal_attention import causal_attention_path
 from dinov3_tpu.ops.kda import kda_path
+from dinov3_tpu.ops.mixer_chains import mixer_chain_path
 
 logger = logging.getLogger("dinov3")
 
@@ -57,14 +58,20 @@ class LMMetaArch:
             "swa": ("gqa_core", gqa, dc.sliding_window),
             "full_attn": ("gqa_core", gqa, None),
             "gated_attn": ("gqa_core", gqa, None)}
-        delta = {  # the scope and the delta rule's (key, value) widths
-            "kda": ("kda_core", dc.kda_head_dim, dc.kda_head_dim),
-            "gdn": ("gdn_core", dc.linear_key_head_dim, dc.linear_value_head_dim)}
+        delta = {  # the core's scope, the delta rule's (key, value) widths
+            # and the head counts of the planes the mixer's chains lay out
+            "kda": ("kda_core", dc.kda_head_dim, dc.kda_head_dim,
+                    (dc.kda_num_heads,)),
+            "gdn": ("gdn_core", dc.linear_key_head_dim, dc.linear_value_head_dim,
+                    (dc.linear_num_key_heads, dc.linear_num_value_heads))}
         for i, (mixer, _) in enumerate(dc.layers, 1):
             if mixer in delta:
-                scope, dk, dv = delta[mixer]
+                scope, dk, dv, heads = delta[mixer]
                 path, why = kda_path(dk, dv)
                 logger.info("layer %d %s, both passes: %s (%s)", i, scope, path, why)
+                path, why = mixer_chain_path(rows[1], (dk, dv), heads, dc.dtype)
+                logger.info("layer %d %s_mixer's chains, both passes: %s (%s)",
+                            i, mixer, path, why)
             else:
                 scope, shapes, window = cores[mixer]
                 path, why = causal_attention_path(

@@ -48,7 +48,7 @@ def _dense_attention(q, k, v, window=None):
         # at key 254, in tile 1: tile 0 is wholly masked for it
         (768, 2, 1, 128, 128, 130, 128, 128, jnp.float32),
         (512, 4, 2, 128, 256, 200, 256, 128, jnp.float32),    # v wider, blocks turned
-        (768, 14, 2, 128, 128, 300, 128, 256, jnp.bfloat16),  # the step's type
+        (768, 7, 1, 128, 128, 300, 128, 256, jnp.bfloat16),   # the step's type
     ], ids=["mla", "global", "window_block", "window_odd", "window_wide",
             "window_starts_a_tile_later", "wide_v", "bfloat16"])
 def test_kernels_are_the_banded_softmax(

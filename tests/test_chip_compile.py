@@ -3,8 +3,9 @@ attached (on-chip-measurement guide §2.3): flash attention forward and
 backward, plain and segment-masked, at the 768 px (N=2309) and 1024 px
 (N=4101) ViT-L token counts, the fused layernorm forward and
 backward at ViT-L width, and the delta rule's chunk forward and
-backward at the decoder cell's shapes, and the causal attention kernels
-at both decoder cells' published shapes — each with ``interpret=False``, each asserting
+backward at the decoder cell's shapes, the causal attention kernels
+at both decoder cells' published shapes, and the delta-rule mixers'
+chains (``ops/mixer_chains.py``) at both delta-rule cells' — each with ``interpret=False``, each asserting
 a Mosaic ``tpu_custom_call`` in the compiled text. What the chip's
 compiler would refuse (a slice off the tiling, too much VMEM) fails
 here, at no chip time. A compile that passes is not a chip run.
@@ -178,6 +179,68 @@ def test_causal_attention_kernels_compile_for_v5e(one_chip, q, kv, dv,
     assert text.count("tpu_custom_call") == (1 if direction == "fwd" else 2)
     assert (BACKWARD_KERNEL_NAME in text) == (direction == "bwd")
     assert " while(" not in text
+
+
+def _chain_cases():
+    """name -> (the chain at the shipped block, compiled, its argument
+    shapes, its kernels' names): ``KDAMixer``'s at the 8k cell's shapes (2
+    x 8,192 tokens, 32 heads of 128, the heads in order) and
+    ``GDNMixer``'s at the third cell's (16 key heads under 32 value heads
+    of 128, q, k, v and z read out of the ``[2, 8192, 16 x 768]``
+    grouping where they lie)."""
+    from dinov3_tpu.ops import mixer_chains as mc
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    b, t, d, h, hk, r = 2, 8192, 128, 32, 16, 2
+    per = 2 + 2 * r
+    layout = lambda first, n: (first, n, per)  # noqa: E731
+    plane, grouped = ((b, t, h * d), bf16), ((b, t, hk * per * d), bf16)
+    o, taps = ((b, t, h, d), f32), lambda n: ((4, n * d), f32)  # noqa: E731
+    conv, norm = ((mc.CONV_KERNEL_NAME, mc.CONV_BACKWARD_KERNEL_NAME),
+                  (mc.NORM_KERNEL_NAME, mc.NORM_BACKWARD_KERNEL_NAME))
+    return {
+        "kda_conv": (lambda x, w: mc.conv_silu_norm(
+            x, (w,), (mc.IN_ORDER,), (True,), d, interpret=False),
+            [plane, taps(h)], conv),
+        "kda_decay": (lambda f, a, bias: mc.log_decay(
+            f, a, bias, interpret=False),
+            [plane, ((h,), f32), ((h * d,), f32)],
+            (mc.DECAY_KERNEL_NAME, mc.DECAY_BACKWARD_KERNEL_NAME)),
+        "kda_norm": (lambda o, g, s: mc.gated_rms_norm(
+            o, g, s, mc.IN_ORDER, "sigmoid", 1e-5, interpret=False),
+            [o, plane, ((d,), f32)], norm),
+        "gdn_conv": (lambda x, wq, wk, wv: mc.conv_silu_norm(
+            x, (wq, wk, wv), (layout(0, 1), layout(1, 1), layout(2, r)),
+            (True, True, False), d, eps=1e-3, interpret=False),
+            [grouped, taps(hk), taps(hk), taps(h)], conv),
+        "gdn_norm": (lambda o, g, s: mc.gated_rms_norm(
+            o, g, s, layout(2 + r, r), "silu", 1e-6, interpret=False),
+            [o, grouped, ((d,), f32)], norm),
+    }
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+@pytest.mark.parametrize(
+    "case", ["kda_conv", "kda_decay", "kda_norm", "gdn_conv", "gdn_norm"])
+def test_mixer_chain_kernels_compile_for_v5e(one_chip, case, direction):
+    """Each chain ALONE (a whole ``GDNMixer`` gradient compiles for two
+    minutes here): the forward is one kernel, the backward one kernel
+    (its rule keeps the inputs and runs no forward), and the ``[B, T, H,
+    d]`` planes on either side are the kernels' own rows: no copy and no
+    fusion of a plane's size beside the call."""
+    fn, shapes, (forward, backward) = _chain_cases()[case]
+    if direction == "bwd":
+        out = jax.eval_shape(fn, *(jax.ShapeDtypeStruct(*s) for s in shapes))
+        n, fwd = len(shapes), fn
+        shapes = shapes + [(o.shape, o.dtype) for o in jax.tree.leaves(out)]
+        fn = lambda *x: jax.vjp(fwd, *x[:n])[1](  # noqa: E731
+            jax.tree.unflatten(jax.tree.structure(out), x[n:]))
+    text = _compiled_text(fn, one_chip, *shapes)
+    assert text.count("tpu_custom_call") == 1
+    assert (backward if direction == "bwd" else forward) in text
+    entry = text[text.index("ENTRY"):]
+    assert " copy(" not in entry and "[2,8192," not in "".join(
+        line for line in entry.splitlines() if " fusion(" in line)
 
 
 def test_ibot_row_ce_gradient_compiles_without_a_loop_for_v5e(one_chip):

@@ -244,16 +244,19 @@ def test_causal_blockwise_attention_is_masked_softmax(n, block_q):
     q = jax.random.normal(ks[0], (2, n, 3, 24))
     k = jax.random.normal(ks[1], (2, n, 3, 24))
     v = jax.random.normal(ks[2], (2, n, 3, 16))  # narrower than q and k
-    want = xla_attention(q, k, v, causal=True)
-    got = causal_blockwise_attention(q, k, v, block_q=block_q)
-    np.testing.assert_allclose(got, want, atol=2e-6)
-    via = dispatch_attention(q, k, v, causal=True)  # the shipped blocks
+    # each side ONE compiled program: op by op the tiles dispatch for
+    # half a minute
+    blockwise = lambda *a: causal_blockwise_attention(  # noqa: E731
+        *a, block_q=block_q)
+    dense = lambda *a: xla_attention(*a, causal=True)  # noqa: E731
+    want = jax.jit(dense)(q, k, v)
+    np.testing.assert_allclose(jax.jit(blockwise)(q, k, v), want, atol=2e-6)
+    via = jax.jit(lambda *a: dispatch_attention(*a, causal=True))(
+        q, k, v)  # the shipped blocks
     np.testing.assert_allclose(via, want, atol=2e-6)
-    f = lambda fn: jax.grad(lambda *a: jnp.sum(jnp.sin(fn(*a))),  # noqa: E731
-                            argnums=(0, 1, 2))(q, k, v)
-    for g, w in zip(
-            f(lambda *a: causal_blockwise_attention(*a, block_q=block_q)),
-            f(lambda *a: xla_attention(*a, causal=True))):
+    f = lambda fn: jax.jit(jax.grad(  # noqa: E731
+        lambda *a: jnp.sum(jnp.sin(fn(*a))), argnums=(0, 1, 2)))(q, k, v)
+    for g, w in zip(f(blockwise), f(dense)):
         np.testing.assert_allclose(g, w, atol=5e-6)
     with pytest.raises(ValueError, match="segment"):
         dispatch_attention(q, k, v, causal=True, seg=jnp.zeros((2, n), jnp.int32))
