@@ -5,7 +5,8 @@
                                     # decoder's next-token trainer, the
                                     # decoders' causal attention cores
                                     # (the kernels beside the plain tiles),
-                                    # the third decoder's two cores
+                                    # the third decoder's two cores, the
+                                    # fourth's sparse attention
     python chip_smoke.py --phases lm   # one chip, that phase alone
     python chip_smoke.py --chips 4  # four chips: ONLY the sharded train
                                     # arms and their one-device comparison
@@ -92,6 +93,10 @@ SIZES = {
     # and the gated attention's causal core (16 query heads on 2 of 256)
     "gdn_shape": (2, 8192, 32, 128),
     "gdn_attn_shapes": {"gated": (2, 8192, 16, 2, 256, 256, None)},
+    # the fourth decoder's sparse attention at published sizes (--phases
+    # dsa): [B, T, query heads, key/value heads, head width, index heads,
+    # index width, keys a query keeps]
+    "dsa_shape": (1, 16384, 32, 4, 128, 16, 64, 2048),
     "gqa_shipped_blocks": (512, 1024),
     "gqa_blocks": [(512, 512), (1024, 1024), (256, 1024)],
     "gqa_timeout_s": 1200,
@@ -102,7 +107,8 @@ SIZES = {
                         (384, 384), (224, 224), (128, 128), (448, 320)],
 }
 
-ONE_CHIP_PHASES = ("trainer", "accum", "kernels", "serve", "lm", "gqa", "gdn")
+ONE_CHIP_PHASES = ("trainer", "accum", "kernels", "serve", "lm", "gqa", "gdn",
+                   "dsa")
 
 _T0 = time.time()
 
@@ -643,6 +649,147 @@ def phase_gdn() -> None:
     phase_gqa("gdn_attn_shapes")
 
 
+def phase_dsa() -> None:
+    """The ``keye_vl2`` family's sparse attention stand-alone at published
+    sizes (``ops/sparse_index.py``, ``ops/causal_attention.py``): the
+    exact selection of every query's ``topk`` keys (every count right),
+    the index loss with its closed-form gradient, its target by the third
+    kernel (``causal_attn_probs``) and by the plain strips, and the causal
+    kernel pair UNDER that selection against a masked softmax over whole
+    rows (a block of queries at a time), output and three gradients; each
+    timed. The selection is made INSIDE the program that uses it, as the
+    step makes it."""
+    import faulthandler
+
+    import jax
+    import jax.numpy as jnp
+
+    from dinov3_tpu.ops import sparse_index
+    from dinov3_tpu.ops.attention import causal_blockwise_attention
+    from dinov3_tpu.ops.causal_attention import (
+        causal_attention_path,
+        selected_head_probs,
+        selected_lse,
+    )
+
+    interpret = bool(SIZES["kernel_interpret"])
+    faulthandler.dump_traceback_later(
+        float(SIZES["gqa_timeout_s"]), exit=True, file=sys.__stderr__)
+    b, t, h, hk, d, hi, di, topk = SIZES["dsa_shape"]
+    ks = jax.random.split(jax.random.key(5), 7)
+    bf = lambda key, shape: jax.random.normal(key, shape, jnp.bfloat16)  # noqa: E731
+    q, k, v = bf(ks[0], (b, t, h, d)), bf(ks[1], (b, t, hk, d)), bf(ks[2], (b, t, hk, d))
+    qi, ki = bf(ks[3], (b, t, hi, di)), bf(ks[4], (b, t, di))
+    a = jax.random.normal(ks[5], (b, t, hi)) * (hi * di) ** -0.5
+    do = bf(ks[6], (b, t, h, d))
+
+    select = jax.jit(lambda qi, ki, a: sparse_index.select_thresholds(
+        qi, ki, a, topk=topk))
+    first, ms, (thr, last) = _timed(select, (qi, ki, a))
+    log(f"dsa: selection {(b, t, hi, di)} keep {topk}: first call "
+        f"{first:.1f}s, {ms:.1f} ms")
+    plane_of = jax.jit(lambda qi, ki, a, thr, last: sparse_index.selection_plane(
+        qi, ki, a, thr, last, topk=topk))
+    first, ms, (plane, excess) = _timed(plane_of, (qi, ki, a, thr, last))
+    kept = int(jnp.sum(plane.astype(jnp.int32)))
+    want = b * sum(min(i + 1, topk) for i in range(t))
+    log(f"dsa: selected plane: first call {first:.1f}s, {ms:.1f} ms; kept "
+        f"{kept} pairs of {want} wanted, {int(excess)} queries miscounted")
+    assert kept == want and int(excess) == 0, (kept, want, int(excess))
+
+    bq, bkv = SIZES["gqa_shipped_blocks"]
+    path, why = causal_attention_path(
+        (q.shape, k.shape, v.shape), None, interpret or None, bq, bkv)
+    log(f"dsa: core {(b, t, h, hk, d)} under a selection: the entry point "
+        f"takes the {path} ({why})")
+    assert path == "kernel", (path, why)
+    # the rows' log-sum-exp, then the index loss's target of the LAST rows
+    # (a whole plane would be 1 GB here): every row sums to 1
+    first, ms, lse = _timed(jax.jit(lambda q, k, v: selected_lse(
+        q, k, v, plane, bq, bkv, interpret)), (q, k, v))
+    log(f"dsa: log-sum-exp under the selection: first call {first:.1f}s, {ms:.1f} ms")
+    r0 = t - min(t, 8 * bq)
+    first, ms, target = _timed(jax.jit(lambda q, k, lse: selected_head_probs(
+        q[:, r0:], k, lse[:, :, r0:], plane[:, r0:], r0, bq, bkv, interpret)),
+        (q, k, lse))
+    rows = jnp.sum(jnp.where(plane[:, r0:] != 0, target, 0.0), axis=-1)
+    log(f"dsa: target of the last {t - r0} queries: first call {first:.1f}s, "
+        f"{ms:.1f} ms; a row sums to {float(rows.min()):.4f} .. "
+        f"{float(rows.max()):.4f}")
+    assert abs(float(rows.min()) - 1) < 2e-2 and abs(float(rows.max()) - 1) < 2e-2
+    # the index loss with its gradient, the target by the kernel and by
+    # the plain strips
+    found = {}
+    for name, rows_lse in (("kernel", lse), ("strips", None)):
+        loss_of = jax.jit(jax.value_and_grad(
+            lambda qi, ki, a, rows_lse=rows_lse: sparse_index.index_loss(
+                qi, ki, a, plane, q, k, rows_lse, sparse_index.CHUNK,
+                sparse_index.GROUP, interpret), argnums=(0, 1, 2)))
+        first, ms, found[name] = _timed(loss_of, (qi, ki, a), n=1)
+        log(f"dsa: index loss, target by the {name}: {float(found[name][0]):.4f}; "
+            f"first call {first:.1f}s, loss + gradient {ms:.1f} ms")
+    (loss, grads), (want, want_grads) = found["kernel"], found["strips"]
+    f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
+    gaps = [abs(float(loss) - float(want)) / float(want)] + [
+        float(jnp.linalg.norm(f32(g) - f32(w)) / jnp.linalg.norm(f32(w)))
+        for g, w in zip(grads, want_grads)]
+    log("dsa: index loss: kernel to strips, the loss's relative gap and the "
+        "gradients' (q^I, k^I, a) " + " ".join(f"{x:.3e}" for x in gaps))
+    assert all(math.isfinite(x) and x <= 2e-2 for x in gaps) and float(loss) > 0, gaps
+
+    from dinov3_tpu.ops.causal_attention import kernel_attention_selected
+
+    if interpret:
+        core = lambda q, k, v: kernel_attention_selected(  # noqa: E731
+            q, k, v, plane, d ** -0.5, bq, bkv, True)
+    else:
+        core = lambda q, k, v: causal_blockwise_attention(  # noqa: E731
+            q, k, v, selection=plane)
+
+    def rows(q, k, v):
+        """A masked softmax over whole rows, a block of queries at a time."""
+        g, block = h // hk, min(512, t)
+        kr, vr = (jnp.repeat(x, g, axis=2) for x in (k, v))
+
+        @jax.checkpoint
+        def one(xs):
+            qb, sel = xs
+            z = jnp.einsum("bqhd,bkhd->bhqk", qb, kr,
+                           preferred_element_type=jnp.float32) * d ** -0.5
+            p = jax.nn.softmax(jnp.where(sel[:, None] != 0, z, -jnp.inf), -1)
+            return jnp.einsum("bhqk,bkhd->bqhd", p.astype(vr.dtype), vr)
+
+        cut = lambda x: jnp.moveaxis(x.reshape(  # noqa: E731
+            (b, t // block, block) + x.shape[2:]), 1, 0)
+        o = jax.lax.map(one, (cut(q), cut(plane)))
+        return jnp.moveaxis(o, 0, 1).reshape(b, t, h, d)
+
+    found = {}
+    for name, fn in (("kernel", core), ("rows", rows)):
+        both = jax.jit(lambda q, k, v, fn=fn: (fn(q, k, v), *jax.vjp(
+            fn, q, k, v)[1](do)))
+        first, ms, found[name] = _timed(both, (q, k, v))
+        log(f"dsa: core, {name}: first call {first:.1f}s, forward + "
+            f"backward {ms:.1f} ms")
+    # ... and with the plane made by the program that hands it on
+
+    def in_program(q, k, v, qi, ki, a):
+        made = sparse_index.selection_plane(qi, ki, a, thr, last, topk=topk)[0]
+        fn = lambda q, k, v: (  # noqa: E731
+            kernel_attention_selected(q, k, v, made, d ** -0.5, bq, bkv, True)
+            if interpret else causal_blockwise_attention(q, k, v, selection=made))
+        return (fn(q, k, v), *jax.vjp(fn, q, k, v)[1](do))
+
+    found["kernel, plane in program"] = jax.jit(in_program)(q, k, v, qi, ki, a)
+    for name in ("kernel", "kernel, plane in program"):
+        gaps = [float(jnp.linalg.norm(f32(x) - f32(r)) / jnp.linalg.norm(f32(r)))
+                for x, r in zip(found[name], found["rows"])]
+        log(f"dsa: core: norm of the difference over the norm, {name} to whole "
+            "rows, output and gradients q k v " + " ".join(f"{x:.3e}" for x in gaps))
+        assert all(math.isfinite(x) and x <= 2e-2 for x in gaps), gaps
+    faulthandler.cancel_dump_traceback_later()
+
+
 # ------------------------------------------------------------------ serve
 
 def phase_serve() -> None:
@@ -926,7 +1073,8 @@ def main(argv=None) -> int:
     if args.chips == 1:
         run = {"trainer": phase_trainer, "accum": phase_accum,
                "kernels": phase_kernels, "serve": phase_serve,
-               "lm": phase_lm, "gqa": phase_gqa, "gdn": phase_gdn}
+               "lm": phase_lm, "gqa": phase_gqa, "gdn": phase_gdn,
+               "dsa": phase_dsa}
         for name in phases:
             run[name]()
     else:

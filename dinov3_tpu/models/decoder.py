@@ -1,4 +1,4 @@
-"""A causal decoder whose mixer and FFN are chosen layer by layer. Three
+"""A causal decoder whose mixer and FFN are chosen layer by layer. Four
 families (``student.arch``): ``kimi_linear`` (Kimi Linear, Moonshot AI;
 ``config.json`` of Kimi-Linear-48B-A3B-Instruct and the model's report:
 KDA and MLA mixers, a dense SwiGLU, routed + shared experts),
@@ -8,7 +8,10 @@ rotary or with neither, routed ReGLU experts whose router reads the
 layer's input) and ``qwen3_next`` (Qwen3-Next, Qwen; ``config.json`` of
 Qwen3-Next-80B-A3B-Instruct: Gated DeltaNet and gated grouped-query
 mixers 3 : 1, zero-centred norms, routed SwiGLU experts beside a shared
-one behind a sigmoid gate).
+one behind a sigmoid gate) and ``keye_vl2`` (Keye-VL-2.0's language model,
+Kwai; ``config.json`` of Keye-VL-2.0-30B-A3B: a Qwen3-MoE block whose
+grouped-query attention reads, for each query, the ``topk`` keys a
+learned indexer selects; text tokens only).
 
 Pre-norm residual layers, RMSNorm everywhere (``qwen3_next``: zero-centred,
 n(x) = x / rms(x) * (1 + w), but for the delta rule's output norm):
@@ -52,6 +55,20 @@ n(x) = x / rms(x) * (1 + w), but for the delta rule's output norm):
   scale vector over ``head_dim`` for all heads), the first ``rotary_dim``
   channels of every q and k head rotated over token positions, the rest
   untouched; no window; y = W_o [o * sigmoid(gate)].
+- **DSA** (``dsa``; DeepSeek-V3.2-Exp's sparse attention on a
+  grouped-query layer): q, k, v as GQA with n_q, n_k on every q and k head
+  and the whole head rotated. The INDEXER reads the layer's normed input
+  detached, h' = stop_gradient(h): q^I = W^I_q h' as ``index_num_heads``
+  heads of ``index_head_dim``, ONE key head k^I = LayerNorm(W^I_k h'),
+  both rotated whole, head weights a = W^I_w h' * (heads * width)^-1/2;
+  I[t, s] = sum_j a[t, j] ReLU(q^I[t, j] . k^I[s]) over s <= t; S_t = the
+  ``index_topk`` largest (all of them while t < topk; ties to the lower
+  s), one selection a query for all heads; o[t, i] = softmax over S_t of
+  q_i . k / sqrt(d) times v; W_o. With p = stop_gradient(mean over heads
+  of those softmaxes) the layer also gives the INDEX LOSS L^I = mean_t
+  KL(p[t, .] || softmax_{S_t} I[t, .]), whose gradient reaches the
+  indexer's leaves alone, as the next-token loss reaches every leaf but
+  them; no gradient crosses the selection (``ops/sparse_index.py``).
 - **FFN**: ``kimi_linear``: SwiGLU of ``intermediate_size`` in the first
   ``first_k_dense_replace`` layers; after them the routed experts this
   shard holds (``ops/ffn.py RoutedExpertsFFN``, sigmoid router) plus
@@ -65,6 +82,8 @@ n(x) = x / rms(x) * (1 + w), but for the delta rule's output norm):
   shared expert times sigmoid(w_s . n2(x')) (``shared_expert_gate``); the
   row buffer of the routed layer holds ``expert_rows_factor`` times its
   experts' even share (the recipe's ``lm.expert_rows_factor``).
+  ``keye_vl2``: every layer routed, SwiGLU experts, softmax over the
+  chosen logits of a router that reads n2(x'), no shared expert.
 
 The vocabulary may be a slice (``vocab_size`` rows of the published
 table): ids, logits and the loss are over the slice. Embedding and head
@@ -75,7 +94,9 @@ logits never exist whole.
 The step's phases (``utils.STEP_PHASES``): ``lm_embed``, ``kda_mixer``
 (inner ``kda_core``), ``mla_mixer`` (inner ``mla_core``), ``swa_mixer`` and ``full_attn_mixer``
 (inner ``gqa_core``), ``gdn_mixer`` (inner ``gdn_core``),
-``gated_attn_mixer`` (inner ``gqa_core``), ``dense_ffn``, ``moe_ffn`` (inner
+``gated_attn_mixer`` (inner ``gqa_core``), ``dsa_mixer`` (inner
+``dsa_index``: the indexer's projections and score planes, ``dsa_select``:
+the thresholds, ``dsa_core``, ``dsa_index_loss``), ``dense_ffn``, ``moe_ffn`` (inner
 ``moe_route``, ``moe_experts`` from the routed layer, ``moe_shared``: the
 shared expert, with its gate where it has one), ``lm_head_loss``.
 """
@@ -90,7 +111,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from dinov3_tpu.ops.attention import dispatch_attention
+from dinov3_tpu.ops.attention import causal_selected_lse, dispatch_attention
 from dinov3_tpu.ops.common import l2_normalize, part, trunc_normal_init
 from dinov3_tpu.ops.ffn import ROWS_CAPACITY_FACTOR, RoutedExpertsFFN, SwiGLUFFN
 from dinov3_tpu.ops.kda import kda_chunked
@@ -101,8 +122,19 @@ from dinov3_tpu.ops.mixer_chains import (
     log_decay,
     mixer_chain_path,
 )
-from dinov3_tpu.ops.norms import RMSNorm
-from dinov3_tpu.ops.rope import rope_apply_leading, token_rope_sincos
+from dinov3_tpu.ops.norms import LayerNorm, RMSNorm
+from dinov3_tpu.ops.rope import (
+    rope_apply_full,
+    rope_apply_leading,
+    token_rope_sincos,
+)
+from dinov3_tpu.ops.sparse_index import (
+    INT_MIN,
+    index_loss,
+    pack_selection,
+    select_thresholds,
+    selection_plane,
+)
 from dinov3_tpu.utils import step_phase
 
 
@@ -153,6 +185,11 @@ class DecoderConfig:
     zero_centered_norms: bool = False
     shared_expert_gate: bool = False
     expert_rows_factor: float = ROWS_CAPACITY_FACTOR
+    # keye_vl2 (sa_config)
+    index_num_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    index_chunk: int = 0               # queries a strip of the index planes
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     reduce_dtype: Any = jnp.float32
@@ -164,7 +201,8 @@ class DecoderConfig:
         policy = Policy.from_cfg(cfg.compute_precision)
         family = {"kimi_linear": _kimi_linear_fields,
                   "smallthinker": _smallthinker_fields,
-                  "qwen3_next": _qwen3_next_fields}[str(cfg.student.arch)]
+                  "qwen3_next": _qwen3_next_fields,
+                  "keye_vl2": _keye_vl2_fields}[str(cfg.student.arch)]
         return cls(dtype=policy.compute_dtype,
                    param_dtype=param_dtype or policy.param_dtype,
                    reduce_dtype=policy.reduce_dtype, **family(cfg.lm))
@@ -256,6 +294,37 @@ def _qwen3_next_fields(lm) -> dict:
         shared_expert_gate=True,
         expert_shards=lm.expert_shards, expert_shard=lm.expert_shard,
         expert_rows_factor=float(lm.expert_rows_factor),
+        router="softmax", gate="silu")
+
+
+def _keye_vl2_fields(lm) -> dict:
+    if not bool(lm.norm_topk_prob):
+        raise ValueError("the routed layer is a softmax router renormalised "
+                         "over the chosen experts")
+    if int(lm.decoder_sparse_step) != 1 or list(lm.mlp_only_layers):
+        raise ValueError("every layer's FFN is routed (lm.decoder_sparse_step "
+                         "1, no lm.mlp_only_layers)")
+    sa = lm.sa_config
+    if int(sa.indexer_num_kv_heads) != 1:
+        raise ValueError("the indexer has ONE key head "
+                         "(lm.sa_config.indexer_num_kv_heads 1)")
+    if int(sa.q_chunk_size) != int(sa.kv_chunk_size):
+        raise ValueError("lm.sa_config.q_chunk_size and kv_chunk_size are one "
+                         "tile's two sides")
+    return dict(
+        layers=(("dsa", "moe"),) * int(lm.num_hidden_layers),
+        hidden_size=lm.hidden_size, vocab_size=lm.vocab_size,
+        rms_norm_eps=lm.rms_norm_eps,
+        num_attention_heads=lm.num_attention_heads,
+        num_key_value_heads=lm.num_key_value_heads, head_dim=lm.head_dim,
+        rope_theta=float(lm.rope_theta),
+        index_num_heads=sa.indexer_num_heads,
+        index_head_dim=sa.indexer_head_dim, index_topk=sa.topk,
+        index_chunk=sa.q_chunk_size,
+        num_experts=lm.num_experts,
+        num_experts_per_token=lm.num_experts_per_tok,
+        moe_intermediate_size=lm.moe_intermediate_size,
+        expert_shards=lm.expert_shards, expert_shard=lm.expert_shard,
         router="softmax", gate="silu")
 
 
@@ -588,11 +657,93 @@ class GQAMixer(nn.Module):
             o.reshape(b, t, h * d))
 
 
+class DSAMixer(nn.Module):
+    """Grouped-query attention over the ``topk`` keys a learned indexer
+    selects for each query (the module's docstring, **DSA**): ``(y,
+    {"index_loss", "select_excess"[, "selection"]})``. ``qk_norm`` as
+    ``GQAMixer``'s. While a sequence is no longer than ``topk`` every
+    query keeps every key up to its own and the core is the dense causal
+    one, bit for bit; the index loss is there all the same.
+
+    The selection reaches the core as an operand, [B, T, T] int8, made
+    from two int32 a query (the threshold and the last tie kept). A
+    rematerialised layer makes both again in its backward: kept across
+    the layer's remat (``save_only_these_names``) they gave a wrong
+    gradient on the chip, cause not found (PERF.md section 6, PR 39).
+    ``keep_selection`` adds the plane as packed bits, for a caller that
+    has to follow it."""
+
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    rope_theta: float
+    index_heads: int
+    index_head_dim: int
+    topk: int
+    chunk: int
+    qk_norm: Callable[[str], nn.Module]
+    eps: float = 1e-6
+    keep_selection: bool = False
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+    reduce_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        b, t, _ = x.shape
+        h, hk, d = self.num_heads, self.num_kv_heads, self.head_dim
+        hi, di = self.index_heads, self.index_head_dim
+        kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
+        xc = x.astype(self.dtype)
+        q = _dense(h * d, ("embed", "heads"), "q_proj", **kw)(xc)
+        k = _dense(hk * d, ("embed", "heads"), "k_proj", **kw)(xc)
+        v = _dense(hk * d, ("embed", "heads"), "v_proj", **kw)(xc)
+        q, k, v = (q.reshape(b, t, h, d), k.reshape(b, t, hk, d),
+                   v.reshape(b, t, hk, d))
+        q, k = self.qk_norm("q_norm")(q), self.qk_norm("k_norm")(k)
+        q, k = rope_apply_full(q, k, *token_rope_sincos(t, d, self.rope_theta))
+        with jax.named_scope("dsa_index"):
+            hb = jax.lax.stop_gradient(xc)
+            qi = _dense(hi * di, ("embed", "heads"), "index_q_proj", **kw)(hb)
+            ki = _dense(di, ("embed", None), "index_k_proj", **kw)(hb)
+            ki = LayerNorm(epsilon=self.eps, param_dtype=self.param_dtype,
+                           fused=False, name="index_k_norm")(ki)
+            a = _dense(hi, ("embed", None), "index_w_proj", **kw)(hb)
+            a = a.astype(jnp.float32) * (hi ** -0.5 * di ** -0.5)
+            qi, ki = rope_apply_full(
+                qi.reshape(b, t, hi, di), ki[:, :, None, :],
+                *token_rope_sincos(t, di, self.rope_theta))
+            ki = ki[:, :, 0]
+        if t > self.topk:
+            thr, last = select_thresholds(qi, ki, a, topk=self.topk,
+                                          chunk=self.chunk)
+        else:  # every key up to the query's own
+            thr = jnp.full((b, t), INT_MIN, jnp.int32)
+            last = jnp.full((b, t), t, jnp.int32)
+        plane, excess = selection_plane(qi, ki, a, thr, last, topk=self.topk,
+                                        chunk=self.chunk)
+        with jax.named_scope("dsa_index_loss"):
+            lse = causal_selected_lse(q, k, v, plane,
+                                      reduce_dtype=self.reduce_dtype)
+        loss = index_loss(qi, ki, a, plane, jax.lax.stop_gradient(q),
+                          jax.lax.stop_gradient(k), lse, self.chunk)
+        with jax.named_scope("dsa_core"):
+            o = dispatch_attention(
+                q, k, v, causal=True, reduce_dtype=self.reduce_dtype,
+                selection=plane if t > self.topk else None)
+        aux = {"index_loss": loss, "select_excess": excess}
+        if self.keep_selection:
+            aux["selection"] = pack_selection(plane)
+        return _dense(x.shape[-1], ("heads", "embed"), "o_proj", **kw)(
+            o.reshape(b, t, h * d)), aux
+
+
 class DecoderLayer(nn.Module):
     mixer: str                 # "kda" | "mla" | "swa" | "full_attn" | "gdn"
-                               # | "gated_attn"
+                               # | "gated_attn" | "dsa"
     ffn: str                   # "dense" | "moe"
     cfg: Any                   # the frozen ``DecoderConfig``
+    keep_selection: bool = False   # "dsa": the selection among the aux
 
     @nn.compact
     def __call__(self, x):
@@ -624,6 +775,15 @@ class DecoderLayer(nn.Module):
                              c.linear_key_head_dim, c.linear_value_head_dim,
                              c.linear_conv_kernel_dim, c.rms_norm_eps,
                              name="gdn", **kw)(norm("norm1")(x))
+                x = x + y.astype(x.dtype)
+        elif self.mixer == "dsa":
+            with step_phase("dsa_mixer"):
+                y, index_aux = DSAMixer(
+                    c.num_attention_heads, c.num_key_value_heads, c.head_dim,
+                    c.rope_theta, c.index_num_heads, c.index_head_dim,
+                    c.index_topk, c.index_chunk, norm, c.rms_norm_eps,
+                    self.keep_selection, reduce_dtype=c.reduce_dtype,
+                    name="attn", **kw)(norm("norm1")(x))
                 x = x + y.astype(x.dtype)
         else:
             # "swa": a window and rotary; "full_attn": neither; "gated_attn":
@@ -665,6 +825,8 @@ class DecoderLayer(nn.Module):
                             ).astype(shared.dtype)
                     routed = routed + shared
                 x = x + routed.astype(x.dtype)
+        if self.mixer == "dsa":
+            aux = {**(aux or {}), **index_aux}
         return x, aux
 
 
@@ -673,7 +835,10 @@ class LMDecoder(nn.Module):
     ``__call__(tokens, with_loss=True)`` -> (loss, aux): the mean
     next-token cross-entropy over positions 0..T-2 of every sequence, and
     the routed layers' ``choice`` [L_moe, B*T, K], ``rows``, ``capacity``,
-    ``overflow`` and ``load_max_over_mean`` stacked [L_moe]."""
+    ``overflow`` and ``load_max_over_mean`` stacked [L_moe]; of ``dsa``
+    layers also ``index_loss`` and ``select_excess`` [L] and, with
+    ``with_selection``, ``selection`` [L, B, T, T / 8] uint8 (each query's
+    kept keys as packed bits). The index losses are NOT in ``loss``."""
 
     cfg: Any
 
@@ -682,7 +847,8 @@ class LMDecoder(nn.Module):
         return self.cfg.hidden_size
 
     @nn.compact
-    def __call__(self, tokens, with_loss: bool = False):
+    def __call__(self, tokens, with_loss: bool = False,
+                 with_selection: bool = False):
         c = self.cfg
         b, t = tokens.shape
         table = self.param(
@@ -695,7 +861,8 @@ class LMDecoder(nn.Module):
         layer_cls = nn.remat(DecoderLayer)
         auxes = []
         for i, (mixer, ffn) in enumerate(c.layers):
-            x, aux = layer_cls(mixer, ffn, c, name=f"layers_{i}")(x)
+            x, aux = layer_cls(mixer, ffn, c, with_selection,
+                               name=f"layers_{i}")(x)
             if aux is not None:
                 auxes.append(aux)
         aux = ({k: jnp.stack([a[k] for a in auxes]) for k in auxes[0]}

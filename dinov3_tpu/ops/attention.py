@@ -120,6 +120,15 @@ def xla_attention(
     return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v)
 
 
+def _takes_causal_kernels(q, k, v, window, block_q, block_kv, reduce_dtype):
+    """Whether this call goes to ``ops/causal_attention.py``'s kernels."""
+    from dinov3_tpu.ops.causal_attention import causal_attention_path
+
+    return q.dtype == k.dtype == v.dtype and causal_attention_path(
+        (q.shape, k.shape, v.shape), window, None, block_q, block_kv,
+        q.dtype, reduce_dtype)[0] == "kernel"
+
+
 def causal_blockwise_attention(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -128,6 +137,7 @@ def causal_blockwise_attention(
     block_kv: int = 1024,
     reduce_dtype=jnp.float32,
     window: int | None = None,
+    selection: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Causal attention block by block: [B, N, h, dqk] q, [B, N, hk, dqk]
     k, [B, N, hk, dv] v (the value width may differ from the q/k width:
@@ -138,6 +148,11 @@ def causal_blockwise_attention(
 
     ``window``: token t sees the keys ``t - window < j <= t`` (``window``
     keys with its own); None: every key up to its own.
+
+    ``selection``: [B, N, N] int8, 1 where query t keeps key s (the same
+    keys for every head; no pair above the diagonal and at least one key
+    a query): token t sees the keys it keeps and no others. It takes no
+    gradient, and goes with no window.
 
     A block of ``block_q`` queries meets the key tiles of its band and no
     others, ``block_kv`` keys at a time under a running maximum and sum
@@ -154,17 +169,40 @@ def causal_blockwise_attention(
             f"{h} query heads over {hk} key and {v.shape[2]} value heads")
     if window is not None and window < 1:
         raise ValueError(f"window {window}: at least the query's own key")
+    if selection is not None and window is not None:
+        raise ValueError("a selection goes with no window")
     from dinov3_tpu.ops import causal_attention as kernels
 
-    if q.dtype == k.dtype == v.dtype and kernels.causal_attention_path(
-            (q.shape, k.shape, v.shape), window, None, block_q, block_kv,
-            q.dtype, reduce_dtype)[0] == "kernel":
+    if _takes_causal_kernels(q, k, v, window, block_q, block_kv, reduce_dtype):
+        if selection is not None:
+            return kernels.kernel_attention_selected(
+                q, k, v, selection, q.shape[-1] ** -0.5, block_q, block_kv,
+                False)
         return kernels.kernel_attention(
             q, k, v, q.shape[-1] ** -0.5, window, block_q, block_kv, False)
+    if selection is not None:
+        return causal_tiles(q, k, v, block_q, block_kv, reduce_dtype, window,
+                            jax.lax.stop_gradient(selection))
     return causal_tiles(q, k, v, block_q, block_kv, reduce_dtype, window)
 
 
-def causal_tiles(q, k, v, block_q, block_kv, reduce_dtype, window):
+def causal_selected_lse(q, k, v, selection, block_q: int = 512,
+                        block_kv: int = 1024, reduce_dtype=jnp.float32):
+    """[B, h, N] float32, every head's log-sum-exp over the keys
+    ``selection`` keeps (``ops/causal_attention.py selected_lse``), where
+    ``causal_blockwise_attention`` itself takes the kernels; None where it
+    takes the plain tiles. What ``ops/sparse_index.py index_loss`` needs to
+    make its target with the third kernel; given None it makes it in plain
+    XLA. No gradient."""
+    if _takes_causal_kernels(q, k, v, None, block_q, block_kv, reduce_dtype):
+        from dinov3_tpu.ops.causal_attention import selected_lse
+
+        return selected_lse(q, k, v, selection, block_q, block_kv, False)
+    return None
+
+
+def causal_tiles(q, k, v, block_q, block_kv, reduce_dtype, window,
+                 selection=None):
     """``causal_blockwise_attention``'s plain path.
 
     The ``h // hk`` query heads of a group go into the ROWS of the
@@ -195,7 +233,10 @@ def causal_tiles(q, k, v, block_q, block_kv, reduce_dtype, window):
     and such a run of blocks is traced and compiled ONCE, under
     ``lax.map``, its keys cut out at a dynamic offset (24 of the 32
     blocks at 16,384 tokens and a window of 4,096; without a window no
-    two blocks are alike and each is its own program, as before)."""
+    two blocks are alike and each is its own program, as before).
+
+    Under a ``selection`` a block also takes its ``[B, block_q, keys]``
+    rows of it, and every tile is masked by them."""
     b, n, h, _ = q.shape
     hk = k.shape[2]
     g = h // hk
@@ -209,7 +250,7 @@ def causal_tiles(q, k, v, block_q, block_kv, reduce_dtype, window):
         q = lead(q)
     k, v = lead(k), lead(v)
 
-    def block(qb, kb, vb, start):
+    def block(qb, kb, vb, start, sb=None):
         """Queries from token ``start`` of the keys' own numbering."""
         rows = qb.shape[:2]
         end = start + rows[1] // g
@@ -231,6 +272,8 @@ def causal_tiles(q, k, v, block_q, block_kv, reduce_dtype, window):
                     seen = (seen & (col > row - window)) if above \
                         else col > row - window
                 z = jnp.where(seen, z, jnp.asarray(-1e30, z.dtype))
+            if sb is not None:
+                z = jnp.where(sb[:, :, lo:hi], z, jnp.asarray(-1e30, z.dtype))
             new_top = jnp.maximum(top, jnp.max(z, axis=-1))
             shrink = jnp.exp(top - new_top)
             p = jnp.exp(z - new_top[..., None])
@@ -256,8 +299,15 @@ def causal_tiles(q, k, v, block_q, block_kv, reduce_dtype, window):
         run = list(run)
         start, _, first = run[0]
         if len(run) == 1:
+            chosen = ()
+            if selection is not None:
+                # the block's rows of it, a row for each head of the group
+                # and a copy for each key/value head, as q's rows lie
+                sb = selection[:, start:start + size, first:first + keys] != 0
+                chosen = (jnp.repeat(jnp.repeat(sb, g, axis=1), hk, axis=0),)
             outs.append(block(q[:, start * g:(start + size) * g],
-                              k[:, first:first + keys], v[:, first:first + keys], at))
+                              k[:, first:first + keys], v[:, first:first + keys],
+                              at, *chosen))
             continue
 
         def one(xs):
@@ -320,15 +370,20 @@ def dispatch_attention(
     seg: jnp.ndarray | None = None,
     causal: bool = False,
     window: int | None = None,
+    selection: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
-    if window is not None and not causal:
-        raise ValueError("a window is the causal path's")
+    if (window is not None or selection is not None) and not causal:
+        raise ValueError("a window or a selection is the causal path's")
     if causal:
         # the Pallas kernel is non-causal (flash_attention.py) and the
         # dense causal path holds the whole [N, N] plane: a decoder's
         # attention goes block by block, on every backend
         if seg is not None:
             raise ValueError("causal attention takes no segment ids")
+        if selection is not None:
+            return causal_blockwise_attention(
+                q, k, v, reduce_dtype=reduce_dtype, window=window,
+                selection=selection)
         return causal_blockwise_attention(q, k, v, reduce_dtype=reduce_dtype,
                                           window=window)
     if impl == "auto":

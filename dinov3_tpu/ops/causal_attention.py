@@ -45,6 +45,27 @@ every query block and head of the group and leave once, rounded, when
 the head's last tile is done. ``causal_attention_path`` refuses a length
 whose dk and dv do not fit.
 
+A per-query SELECTION besides the band (``selection`` [B, N, N] int8, 1
+where query t keeps key s: which keys a query sees is then data, a
+learned indexer's choice) is one more block a tile: ``[block_q,
+block_kv]`` of it beside the key tile, in both kernels (the backward,
+whose planes have the keys in the rows, turns the block once a tile in
+VMEM), and one more ``where`` on the score plane in every tile. A tile none of
+whose pairs is kept costs what any other does: the grid is still the
+band's (no tile is skipped by what the data says).
+
+``causal_attn_probs`` (``selected_head_probs``) gives what a learned
+indexer is trained towards: the mean over ALL query heads of each head's
+softmax over the keys its query keeps, float32, for a run of query blocks
+at a time (``ops/sparse_index.py`` asks for a group of strips and reads
+it at once: a whole ``[N, N]`` plane would be 1 GB a layer at 16,384
+tokens). A
+grid step is one key tile against every head of a query block, the planes
+transposed as the backward's (the rows' log-sum-exp, which a forward pass
+of ``causal_attn_fwd`` under the same selection wrote, is then a row
+vector): one product and one exponential a head, summed in VMEM and
+turned back once a tile, no plane a head ever in HBM.
+
 A q/k width that is not a multiple of the 128-lane tile (latent
 attention's 192) is padded with zeros up to one (a copy through HBM on
 each pass; the products are unchanged, a 192-wide contraction fills two
@@ -62,6 +83,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 KERNEL_NAME = "causal_attn_fwd"
 BACKWARD_KERNEL_NAME = "causal_attn_bwd"
+PROBS_KERNEL_NAME = "causal_attn_probs"
 _LANES = 128
 _NEG = -1e30
 # what the backward may hold of one key/value head's dk and dv: the
@@ -125,12 +147,16 @@ def _both_bodies(run, edge, tile):
         functools.partial(tile, False))
 
 
-def _fwd_kernel(*refs, scale, group, block_q, block_kv, window, keep_lse):
+def _fwd_kernel(*refs, scale, group, block_q, block_kv, window, keep_lse,
+                selected=False):
     """q_ref [block_q, g * d], k_ref [block_kv, d], v_ref [block_kv, dv],
-    o_ref [block_q, g * dv], lse_ref [g, block_q]; scratch: the group's
-    heads stacked [g, block_q, d], the running maximum and sum
-    [g, block_q, 128] (every lane the same) and the accumulator
-    [g, block_q, dv]."""
+    (``selected``: sel_ref [block_q, block_kv] int8,) o_ref [block_q,
+    g * dv], lse_ref [g, block_q]; scratch: the group's heads stacked
+    [g, block_q, d], the running maximum and sum [g, block_q, 128] (every
+    lane the same) and the accumulator [g, block_q, dv]."""
+    sel_ref = None
+    if selected:
+        sel_ref, refs = refs[3], refs[:3] + refs[4:]
     if keep_lse:
         q_ref, k_ref, v_ref, o_ref, lse_ref, q_scr, m_scr, l_scr, acc_scr = refs
     else:
@@ -154,6 +180,8 @@ def _fwd_kernel(*refs, scale, group, block_q, block_kv, window, keep_lse):
             s = _dot(q_scr[j], k, _NT) * scale
             if masked:
                 s = jnp.where(_seen(s.shape, 0, off, window), s, _NEG)
+            if selected:
+                s = jnp.where(sel_ref[...].astype(jnp.int32) != 0, s, _NEG)
             m_prev = m_scr[j]
             m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
             p = jnp.exp(s - pltpu.repeat(m_next, block_kv // _LANES, axis=1))
@@ -179,12 +207,12 @@ def _fwd_kernel(*refs, scale, group, block_q, block_kv, window, keep_lse):
                 lse_ref[j:j + 1, :] = (m_scr[j] + jnp.log(total)).T[:1]
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dq_ref, dk_ref, dv_ref,
-                q_scr, do_scr, dq_scr, dk_scr, dv_scr, *,
-                scale, group, block_q, block_kv, window):
+def _bwd_kernel(*refs, scale, group, block_q, block_kv, window,
+                selected=False):
     """The forward's blocks, do_ref like o_ref, delta_ref (the rows'
-    sum(o * do)) like lse_ref; dq_ref like q_ref; dk_ref [N, d] and
+    sum(o * do)) like lse_ref (``selected``: then sel_ref [block_q,
+    block_kv] int8, the forward's own block, turned once a tile into the
+    last scratch, [block_kv, block_q] float32); dq_ref like q_ref; dk_ref [N, d] and
     dv_ref [N, dv], one key/value head's whole sequence. Scratch: q and
     do stacked a head, dq [g, block_q, d], dk [N, d] and dv [N, dv], all
     three float32.
@@ -194,6 +222,11 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
         dv += p^T do,    dk += ds^T q,    dq += (ds^T)^T k
     """
+    sel_ref = kept_scr = None
+    if selected:
+        sel_ref, kept_scr, refs = refs[6], refs[-1], refs[:6] + refs[7:-1]
+    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
+     q_scr, do_scr, dq_scr, dk_scr, dv_scr) = refs
     d, dv = q_scr.shape[-1], v_ref.shape[-1]
     run, edge, off, at, count = _tile_place(block_q, block_kv, window)
     i, t = pl.program_id(2), pl.program_id(3)
@@ -214,12 +247,16 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def tile(masked):
         k, v = k_ref[...], v_ref[...]
         keys = pl.ds(pl.multiple_of(at * block_kv, block_kv), block_kv)
+        if selected:  # keys in the rows, as this kernel's planes lie
+            kept_scr[...] = sel_ref[...].astype(jnp.float32).T
 
         def head(j, carry):
             q, do = q_scr[j], do_scr[j]
             s = _dot(k, q, _NT) * scale                  # [block_kv, block_q]
             if masked:
                 s = jnp.where(_seen(s.shape, 1, off, window), s, _NEG)
+            if selected:
+                s = jnp.where(kept_scr[...] != 0.0, s, _NEG)
             p = jnp.exp(s - lse_ref[pl.ds(j, 1), :])
             dv_scr[keys, :] += _dot(p.astype(do.dtype), do)
             ds = p * (_dot(v, do, _NT) - delta_ref[pl.ds(j, 1), :]) * scale
@@ -246,7 +283,8 @@ def _operands(q, k, block_q, block_kv, window):
     """(q and k widened to the lane tile, the grid, the block of a
     [B, N, h * w] array of query heads, the block of a [B, N, hk * w]
     array of keys or values along the band, the block of a [B, hk, g, N]
-    array of row statistics)."""
+    array of row statistics, the [block_q, block_kv] block of a [B, N, N]
+    selection along the band)."""
     b, n, h, d = q.shape
     hk = k.shape[2]
     g, pad = h // hk, (-d) % _LANES
@@ -267,7 +305,11 @@ def _operands(q, k, block_q, block_kv, window):
         (None, block_kv, w), band_tile, memory_space=vmem)
     stats = pl.BlockSpec((None, None, g, block_q),
                          lambda s, kh, i, t: (s, kh, 0, i), memory_space=vmem)
-    return q, k, (b, hk, blocks, steps), rows, keys, stats
+
+    pair = pl.BlockSpec(
+        (None, block_q, block_kv),
+        lambda s, kh, i, t: (s, i, band_tile(s, kh, i, t)[1]), memory_space=vmem)
+    return q, k, (b, hk, blocks, steps), rows, keys, stats, pair
 
 
 # A ``pallas_call`` traces its kernel body every time it is called, and a
@@ -278,15 +320,17 @@ def _operands(q, k, block_q, block_kv, window):
 @functools.partial(jax.jit, inline=True, static_argnames=(
     "scale", "window", "block_q", "block_kv", "keep_lse", "interpret"))
 def _kernel_forward(q, k, v, scale, window, block_q, block_kv, keep_lse,
-                    interpret):
+                    interpret, selection=None):
     """(o [B, N, h, dv], the rows' log-sum-exp [B, hk, g, N] float32 or
     None) as one ``pallas_call``."""
     b, n, h, _ = q.shape
     hk, dv = v.shape[2], v.shape[3]
     g = h // hk
-    q, k, grid, rows, keys, stats = _operands(q, k, block_q, block_kv, window)
+    q, k, grid, rows, keys, stats, pair = _operands(
+        q, k, block_q, block_kv, window)
     d = q.shape[-1]
     flat = lambda x: x.reshape(b, n, -1)  # noqa: E731
+    chosen = () if selection is None else (selection,)
     out_specs = [rows(dv)]
     out_shape = [jax.ShapeDtypeStruct((b, n, h * dv), v.dtype)]
     if keep_lse:
@@ -294,9 +338,10 @@ def _kernel_forward(q, k, v, scale, window, block_q, block_kv, keep_lse,
         out_shape.append(jax.ShapeDtypeStruct((b, hk, g, n), jnp.float32))
     out = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, group=g, block_q=block_q,
-                          block_kv=block_kv, window=window, keep_lse=keep_lse),
+                          block_kv=block_kv, window=window, keep_lse=keep_lse,
+                          **({"selected": True} if chosen else {})),
         grid=grid,
-        in_specs=[rows(d), keys(d), keys(dv)],
+        in_specs=[rows(d), keys(d), keys(dv)] + [pair] * len(chosen),
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
@@ -307,14 +352,14 @@ def _kernel_forward(q, k, v, scale, window, block_q, block_kv, keep_lse,
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
         name=KERNEL_NAME,
-    )(flat(q), flat(k), flat(v))
+    )(flat(q), flat(k), flat(v), *chosen)
     return out[0].reshape(b, n, h, dv), (out[1] if keep_lse else None)
 
 
 @functools.partial(jax.jit, inline=True, static_argnames=(
     "scale", "window", "block_q", "block_kv", "interpret"))
 def _kernel_backward(q, k, v, o, lse, do, scale, window, block_q, block_kv,
-                     interpret):
+                     interpret, selection=None):
     """The cotangents of q, k, v, in their types and widths, as one
     ``pallas_call`` from what the forward rule kept."""
     b, n, h, width = q.shape
@@ -323,16 +368,20 @@ def _kernel_backward(q, k, v, o, lse, do, scale, window, block_q, block_kv,
     # a product would round its float32 operands on the TPU: multiply, add
     delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
     delta = jnp.swapaxes(delta, 1, 2).reshape(b, hk, g, n)
-    qp, kp, grid, rows, keys, stats = _operands(q, k, block_q, block_kv, window)
+    qp, kp, grid, rows, keys, stats, pair = _operands(
+        q, k, block_q, block_kv, window)
+    chosen = () if selection is None else (selection,)
     d = qp.shape[-1]
     flat = lambda x: x.reshape(b, n, -1)  # noqa: E731
     whole = lambda w: pl.BlockSpec(  # noqa: E731
         (None, n, w), lambda s, kh, i, t: (s, 0, kh), memory_space=pltpu.VMEM)
     dq, dk, dv_ = pl.pallas_call(
         functools.partial(_bwd_kernel, scale=scale, group=g, block_q=block_q,
-                          block_kv=block_kv, window=window),
+                          block_kv=block_kv, window=window,
+                          **({"selected": True} if chosen else {})),
         grid=grid,
-        in_specs=[rows(d), keys(d), keys(dv), rows(dv), stats, stats],
+        in_specs=[rows(d), keys(d), keys(dv), rows(dv), stats, stats]
+        + [pair] * len(chosen),
         out_specs=[rows(d), whole(d), whole(dv)],
         out_shape=[jax.ShapeDtypeStruct((b, n, h * d), q.dtype),
                    jax.ShapeDtypeStruct((b, n, hk * d), k.dtype),
@@ -342,11 +391,12 @@ def _kernel_backward(q, k, v, o, lse, do, scale, window, block_q, block_kv,
             pltpu.VMEM((g, block_q, dv), do.dtype),
             pltpu.VMEM((g, block_q, d), jnp.float32),
             pltpu.VMEM((n, d), jnp.float32),
-            pltpu.VMEM((n, dv), jnp.float32)],
+            pltpu.VMEM((n, dv), jnp.float32)]
+        + [pltpu.VMEM((block_kv, block_q), jnp.float32)] * len(chosen),
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
         name=BACKWARD_KERNEL_NAME,
-    )(flat(qp), flat(kp), flat(v), flat(do), lse, delta)
+    )(flat(qp), flat(kp), flat(v), flat(do), lse, delta, *chosen)
     return (dq.reshape(b, n, h, d)[..., :width],
             dk.reshape(b, n, hk, d)[..., :width], dv_.reshape(b, n, hk, dv))
 
@@ -378,6 +428,146 @@ def _kernel_attention_bwd(scale, window, block_q, block_kv, interpret, res, do):
 # runs the primal (no log-sum-exp written), not the forward rule
 kernel_attention.defvjp(_kernel_attention_fwd, _kernel_attention_bwd,
                         optimize_remat=True)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def kernel_attention_selected(q, k, v, selection, scale, block_q, block_kv,
+                              interpret):
+    """``kernel_attention`` under a per-query selection ([B, N, N] int8,
+    1 where query t keeps key s, no pair above the diagonal; every query
+    keeps at least one key). No window; the selection takes no gradient."""
+    return _kernel_forward(q, k, v, scale=scale, window=None, block_q=block_q,
+                           block_kv=block_kv, keep_lse=False,
+                           interpret=interpret, selection=selection)[0]
+
+
+def _kernel_attention_selected_fwd(q, k, v, selection, scale, block_q,
+                                   block_kv, interpret):
+    o, lse = _kernel_forward(q, k, v, scale=scale, window=None,
+                             block_q=block_q, block_kv=block_kv, keep_lse=True,
+                             interpret=interpret, selection=selection)
+    return o, (q, k, v, o, lse, selection)
+
+
+def _kernel_attention_selected_bwd(scale, block_q, block_kv, interpret, res, do):
+    *res, selection = res
+    return (*_kernel_backward(*res, do, scale=scale, window=None,
+                              block_q=block_q, block_kv=block_kv,
+                              interpret=interpret, selection=selection), None)
+
+
+kernel_attention_selected.defvjp(
+    _kernel_attention_selected_fwd, _kernel_attention_selected_bwd,
+    optimize_remat=True)
+
+
+def _probs_kernel(q_ref, k_ref, lse_ref, sel_ref, p_ref, q_scr, kept_scr,
+                  sum_scr, *, scale, group, block_q, block_kv, first_block):
+    """q_ref [block_q, h * d], k_ref [block_kv, hk * d], lse_ref
+    [h, block_q], sel_ref [block_q, block_kv] int8, p_ref [block_q,
+    block_kv] float32; scratch: every head stacked [h, block_q, d], the
+    selection's block turned and the sum over heads, both [block_kv,
+    block_q] float32. Grid: (sequence, query block, key tile of the
+    block's band); the rows' first query block is the sequence's
+    ``first_block``-th."""
+    heads, d = q_scr.shape[0], q_scr.shape[-1]
+    i, t = pl.program_id(1), pl.program_id(2)
+    _, count = _band(i + first_block, block_q, block_kv, None)
+
+    @pl.when(t == 0)
+    def _():
+        for j in range(heads):
+            q_scr[j] = q_ref[:, j * d:(j + 1) * d]
+
+    @pl.when(t < count)
+    def _():
+        kept_scr[...] = sel_ref[...].astype(jnp.float32).T
+        sum_scr[...] = jnp.zeros_like(sum_scr)
+        for kh in range(heads // group):
+            k = k_ref[:, kh * d:(kh + 1) * d]
+
+            def head(j, carry, k=k, kh=kh):
+                at = kh * group + j
+                s = _dot(k, q_scr[at], _NT) * scale      # [block_kv, block_q]
+                p = jnp.exp(s - lse_ref[pl.ds(at, 1), :])
+                sum_scr[...] += jnp.where(kept_scr[...] != 0.0, p, 0.0)
+                return carry
+
+            jax.lax.fori_loop(0, group, head, 0)
+        p_ref[...] = (sum_scr[...] * (1.0 / heads)).T
+
+
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "block_q", "block_kv", "interpret"))
+def selected_lse(q, k, v, selection, block_q=512, block_kv=1024,
+                 interpret=False):
+    """[B, h, N] float32: every head's log-sum-exp over the keys
+    ``selection`` keeps for the query — a forward pass of
+    ``causal_attn_fwd`` under it, the output dropped. No gradient."""
+    q, k, v = (jax.lax.stop_gradient(x) for x in (q, k, v))
+    b, n, h, width = q.shape
+    _, lse = _kernel_forward(q, k, v, scale=width ** -0.5, window=None,
+                             block_q=block_q, block_kv=block_kv, keep_lse=True,
+                             interpret=interpret, selection=selection)
+    return lse.reshape(b, h, n)
+
+
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "first", "block_q", "block_kv", "interpret"))
+def selected_head_probs(q, k, lse, selection, first=0, block_q=512,
+                        block_kv=1024, interpret=False):
+    """[B, R, K] float32 for R consecutive queries from the sequence's
+    ``first``-th on and its first K keys: the mean over the query heads of
+    each head's softmax (``lse`` [B, h, R]: ``selected_lse``'s rows) over
+    the keys ``selection`` [B, R, K] keeps, 0 where it keeps none inside a
+    block's band; the tiles ABOVE a block's band are never written, so
+    read it where ``selection`` is set and nowhere else. q [B, R, h, d],
+    k [B, K, hk, d]; R whole query blocks, K whole key tiles that reach
+    the last query's own key. No gradient."""
+    q, k = jax.lax.stop_gradient(q), jax.lax.stop_gradient(k)
+    b, r, h, width = q.shape
+    keys, hk = k.shape[1], k.shape[2]
+    if first % block_q or r % block_q or keys % block_kv or keys < first + r:
+        raise ValueError(f"{r} queries from {first} on over {keys} keys are "
+                         f"not whole blocks of {block_q} x {block_kv}")
+    pad = (-width) % _LANES
+    if pad:
+        q, k = (jnp.pad(x, ((0, 0),) * 3 + ((0, pad),)) for x in (q, k))
+    d = width + pad
+    first_block, blocks = first // block_q, r // block_q
+    steps = _band(first_block + blocks - 1, block_q, block_kv, None)[1]
+
+    def tile(i, t):
+        return jnp.minimum(
+            t, _band(i + first_block, block_q, block_kv, None)[1] - 1)
+
+    vmem = pltpu.VMEM
+    pair = pl.BlockSpec((None, block_q, block_kv),
+                        lambda s, i, t: (s, i, tile(i, t)), memory_space=vmem)
+    return pl.pallas_call(
+        functools.partial(_probs_kernel, scale=width ** -0.5, group=h // hk,
+                          block_q=block_q, block_kv=block_kv,
+                          first_block=first_block),
+        grid=(b, blocks, steps),
+        in_specs=[
+            pl.BlockSpec((None, block_q, h * d), lambda s, i, t: (s, i, 0),
+                         memory_space=vmem),
+            pl.BlockSpec((None, block_kv, hk * d),
+                         lambda s, i, t: (s, tile(i, t), 0), memory_space=vmem),
+            pl.BlockSpec((None, h, block_q), lambda s, i, t: (s, 0, i),
+                         memory_space=vmem),
+            pair],
+        out_specs=pair,
+        out_shape=jax.ShapeDtypeStruct((b, r, keys), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((h, block_q, d), q.dtype),
+                        pltpu.VMEM((block_kv, block_q), jnp.float32),
+                        pltpu.VMEM((block_kv, block_q), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=100 * 1024 * 1024),
+        interpret=interpret,
+        name=PROBS_KERNEL_NAME,
+    )(q.reshape(b, r, -1), k.reshape(b, keys, -1), lse, selection)
 
 
 def causal_attention_path(shapes, window: int | None = None,

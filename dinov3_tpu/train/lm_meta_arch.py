@@ -12,7 +12,14 @@ and ``momentum`` reach ``forward`` and are not read.
 It carries no non-param state (``TrainState.center_state`` is empty).
 ``routing`` gives what each token's router picks over all the experts,
 for whoever asks (the benchmark's reference has to follow the same
-routing); the step itself keeps none of it.
+routing); the step itself keeps none of it. ``selection`` gives beside
+it the keys each query of a sparse-attention layer keeps.
+
+A decoder whose layers select their keys (``dsa``) trains its indexers by
+a loss of their own: the step minimises the next-token loss + the sum over
+layers of the index loss (``models/decoder.py``: each reaches its own
+leaves alone), ``lm_loss`` stays the next-token loss and ``lm_index_loss``
+is that sum.
 """
 
 from __future__ import annotations
@@ -57,7 +64,9 @@ class LMMetaArch:
             "mla": ("mla_core", ((heads, qk),) * 2 + ((heads, dc.v_head_dim),), None),
             "swa": ("gqa_core", gqa, dc.sliding_window),
             "full_attn": ("gqa_core", gqa, None),
-            "gated_attn": ("gqa_core", gqa, None)}
+            "gated_attn": ("gqa_core", gqa, None),
+            # under a per-query selection: an operand, not a shape
+            "dsa": ("dsa_core", gqa, None)}
         delta = {  # the core's scope, the delta rule's (key, value) widths
             # and the head counts of the planes the mixer's chains lay out
             "kda": ("kda_core", dc.kda_head_dim, dc.kda_head_dim,
@@ -98,6 +107,15 @@ class LMMetaArch:
             with_loss=True)
         return aux["choice"]
 
+    def selection(self, student_params, batch) -> tuple:
+        """(``routing``'s choices, [sparse-attention layers, B, T, T / 8]
+        uint8: the keys each query keeps under these weights, one bit a
+        key, ``numpy.unpackbits``'s order)."""
+        _, aux = self.student_backbone.apply(
+            {"params": student_params["backbone"]}, batch["tokens"],
+            with_loss=True, with_selection=True)
+        return aux["choice"], aux["selection"]
+
     def _zero3_gather_params(self, tree):
         return tree
 
@@ -107,7 +125,9 @@ class LMMetaArch:
         read. A routed layer whose compact row
         buffer overflowed left tokens out: the loss is then NaN, which
         the loop's non-finite watchdog stops on, and ``moe_rows_overflow``
-        says by how many pairs."""
+        says by how many pairs. A sparse-attention layer whose selection
+        miscounted (``dsa_select_excess``: queries whose kept keys are not
+        min(t + 1, topk)) does the same."""
         loss, aux = self.student_backbone.apply(
             {"params": student_params["backbone"]}, batch["tokens"],
             with_loss=True)
@@ -119,5 +139,10 @@ class LMMetaArch:
                 moe_rows_fill=jnp.max(aux["rows"] / aux["capacity"]),
                 moe_rows_overflow=overflow,
                 moe_load_max_over_mean=jnp.max(aux["load_max_over_mean"]))
+        if "index_loss" in aux:
+            index_loss = jnp.sum(aux["index_loss"])
+            excess = jnp.sum(aux["select_excess"])
+            loss = jnp.where(excess > 0, jnp.nan, loss + index_loss)
+            metrics.update(lm_index_loss=index_loss, dsa_select_excess=excess)
         metrics["total_loss"] = loss
         return loss, (metrics, state)

@@ -558,6 +558,9 @@ STEP_PHASES = (
     "gdn_mixer",          # a Gated DeltaNet layer's mixer (inner: gdn_core)
     "gated_attn_mixer",   # a grouped-query layer with q/k norms, a partial
                           # rotary and an output gate (inner: gqa_core)
+    "dsa_mixer",          # a grouped-query layer over the keys a learned
+                          # indexer selects (inner: dsa_index, dsa_select,
+                          # dsa_core, dsa_index_loss)
     "dense_ffn",          # the dense SwiGLU of the leading layers
     "moe_ffn",            # routed + shared experts (inner: moe_route,
                           # moe_experts, moe_shared)
@@ -566,7 +569,7 @@ STEP_PHASES = (
 # the phases only a decoder's step opens
 LM_STEP_PHASES = ("lm_embed", "kda_mixer", "mla_mixer", "swa_mixer",
                   "full_attn_mixer", "gdn_mixer", "gated_attn_mixer",
-                  "dense_ffn", "moe_ffn", "lm_head_loss")
+                  "dsa_mixer", "dense_ffn", "moe_ffn", "lm_head_loss")
 
 _PHASE_WRAPPER = re.compile(r"^(jvp|transpose|checkpoint|remat)\((.*)\)$")
 

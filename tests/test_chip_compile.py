@@ -181,6 +181,66 @@ def test_causal_attention_kernels_compile_for_v5e(one_chip, q, kv, dv,
     assert " while(" not in text
 
 
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_causal_attention_kernels_compile_under_a_selection_for_v5e(
+        one_chip, direction):
+    """The kernel pair under a per-query selection at the ``keye_vl2``
+    cell's shape (1 x 16,384 tokens, 32 query heads on 4 of 128: 8 heads a
+    key tile), the [B, N, N] int8 plane one more block a tile, its
+    transpose made outside the backward kernel."""
+    from dinov3_tpu.ops.causal_attention import (
+        BACKWARD_KERNEL_NAME,
+        KERNEL_NAME,
+        causal_attention_path,
+        kernel_attention_selected,
+    )
+
+    q = ((1, 16384, 32, 128), jnp.bfloat16)
+    kv = ((1, 16384, 4, 128), jnp.bfloat16)
+    sel = ((1, 16384, 16384), jnp.int8)
+    assert causal_attention_path((q[0], kv[0], kv[0]), None, False)[0] == "kernel"
+
+    def fwd(*x):
+        return kernel_attention_selected(*x, 128 ** -0.5, 512, 1024, False)
+
+    def bwd(*x):
+        return jax.vjp(lambda q, k, v: fwd(q, k, v, x[3]), *x[:3])[1](x[4])
+
+    fn, shapes = (fwd, [q, kv, kv, sel]) if direction == "fwd" else (
+        bwd, [q, kv, kv, sel, q])
+    text = _compiled_text(fn, one_chip, *shapes)
+    assert KERNEL_NAME in text
+    assert text.count("tpu_custom_call") == (1 if direction == "fwd" else 2)
+    assert (BACKWARD_KERNEL_NAME in text) == (direction == "bwd")
+    assert " while(" not in text
+
+
+def test_selected_head_probs_compiles_for_v5e(one_chip):
+    """The index loss's target as ``ops/sparse_index.py`` asks for it at
+    the ``keye_vl2`` cell's shape: the rows' log-sum-exp (a forward pass of
+    ``causal_attn_fwd`` under the selection), then ``causal_attn_probs``
+    for the LAST group of 4,096 queries against all 16,384 keys."""
+    from dinov3_tpu.ops.causal_attention import (
+        KERNEL_NAME,
+        PROBS_KERNEL_NAME,
+        selected_head_probs,
+        selected_lse,
+    )
+
+    q = ((1, 16384, 32, 128), jnp.bfloat16)
+    kv = ((1, 16384, 4, 128), jnp.bfloat16)
+    plane = ((1, 16384, 16384), jnp.int8)
+    text = _compiled_text(selected_lse, one_chip, q, kv, kv, plane)
+    assert KERNEL_NAME in text and text.count("tpu_custom_call") == 1
+    text = _compiled_text(
+        lambda q, k, lse, sel: selected_head_probs(q, k, lse, sel, 12288),
+        one_chip, ((1, 4096, 32, 128), jnp.bfloat16), kv,
+        ((1, 32, 4096), jnp.float32), ((1, 4096, 16384), jnp.int8))
+    assert PROBS_KERNEL_NAME in text
+    assert text.count("tpu_custom_call") == 1 and " while(" not in text
+    assert "f32[1,4096,16384]" in text
+
+
 def _chain_cases():
     """name -> (the chain at the shipped block, compiled, its argument
     shapes, its kernels' names): ``KDAMixer``'s at the 8k cell's shapes (2

@@ -50,6 +50,7 @@ TINY = {
                    "mla": (2, 256, 2, 2, 192, 128, None)},
     "gdn_shape": (1, 128, 2, 128),
     "gdn_attn_shapes": {"gated": (1, 512, 4, 2, 256, 256, None)},
+    "dsa_shape": (1, 256, 2, 1, 128, 2, 16, 32),
     "gqa_shipped_blocks": (128, 256),
     "gqa_blocks": [(256, 128)],
     "gqa_timeout_s": 600,
@@ -124,6 +125,12 @@ def test_smoke_rehearsal_runs_every_phase(smoke, monkeypatch, capsys):
                    "gdn: delta rule: norm of the difference over the norm",
                    "gqa: gated core (1, 512, 4, 2, 256, 256) window None: the "
                    "entry point takes the kernel (interpreted)",
+                   "dsa: selected plane:", "0 queries miscounted",
+                   "dsa: core (1, 256, 2, 1, 128) under a selection: the entry "
+                   "point takes the kernel (interpreted)",
+                   "dsa: index loss: kernel to strips",
+                   "dsa: core: norm of the difference over the norm, kernel, "
+                   "plane in program to whole rows",
                    "all phases passed"):
         assert needle in said, needle
     assert "mesh[" not in said  # the four-chip phase is behind --chips 4

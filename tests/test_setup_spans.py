@@ -26,7 +26,8 @@ if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
 CELLS = ["vitl16-pretrain", "vits16-pretrain", "kimi-linear-ep32-pretrain-8k",
-         "smallthinker-ep4-pretrain-16k", "qwen3-next-ep16-pretrain-8k"]
+         "smallthinker-ep4-pretrain-16k", "qwen3-next-ep16-pretrain-8k",
+         "keye-vl2-ep8-pretrain-16k"]
 METRICS = ["setup_import_s", "setup_build_s", "setup_plan_trace_s",
            "setup_jit_trace_s", "setup_lower_s", "setup_compile_load_s",
            "setup_programs"]
@@ -550,7 +551,8 @@ def test_every_new_metric_has_a_reader_the_five_cells_and_moves_setup_s():
     assert [w["name"] for w in bench["workloads"]] == CELLS
     new = [m for m in bench["per_layer"] if m["layer"] == "set-up"]
     assert [m["name"] for m in new] == METRICS
-    assert bench["per_layer"][-len(METRICS):] == new   # appended, at the end
+    first = bench["per_layer"].index(new[0])              # appended together
+    assert bench["per_layer"][first:first + len(METRICS)] == new
     for m in new:
         assert os.path.isfile(os.path.join(
             BENCH, "layer_metrics", m["name"] + ".py"))
