@@ -653,11 +653,11 @@ def phase_dsa() -> None:
     """The ``keye_vl2`` family's sparse attention stand-alone at published
     sizes (``ops/sparse_index.py``, ``ops/causal_attention.py``): the
     exact selection of every query's ``topk`` keys (every count right),
-    the index loss with its closed-form gradient, its target by the third
-    kernel (``causal_attn_probs``) and by the plain strips, and the causal
-    kernel pair UNDER that selection against a masked softmax over whole
-    rows (a block of queries at a time), output and three gradients; each
-    timed. The selection is made INSIDE the program that uses it, as the
+    the causal kernel pair UNDER that selection against a masked softmax
+    over whole rows (a block of queries at a time), output and three
+    gradients, and the index loss with its closed-form gradient by its
+    kernel (fed the log-sum-exp the core hands on) against the plain
+    strips; each timed. The selection is made INSIDE the program that uses it, as the
     step makes it."""
     import faulthandler
 
@@ -668,8 +668,9 @@ def phase_dsa() -> None:
     from dinov3_tpu.ops.attention import causal_blockwise_attention
     from dinov3_tpu.ops.causal_attention import (
         causal_attention_path,
-        selected_head_probs,
-        selected_lse,
+        index_loss_tiles,
+        kernel_attention,
+        kernel_attention_selected,
     )
 
     interpret = bool(SIZES["kernel_interpret"])
@@ -703,31 +704,39 @@ def phase_dsa() -> None:
     log(f"dsa: core {(b, t, h, hk, d)} under a selection: the entry point "
         f"takes the {path} ({why})")
     assert path == "kernel", (path, why)
-    # the rows' log-sum-exp, then the index loss's target of the LAST rows
-    # (a whole plane would be 1 GB here): every row sums to 1
-    first, ms, lse = _timed(jax.jit(lambda q, k, v: selected_lse(
-        q, k, v, plane, bq, bkv, interpret)), (q, k, v))
-    log(f"dsa: log-sum-exp under the selection: first call {first:.1f}s, {ms:.1f} ms")
-    r0 = t - min(t, 8 * bq)
-    first, ms, target = _timed(jax.jit(lambda q, k, lse: selected_head_probs(
-        q[:, r0:], k, lse[:, :, r0:], plane[:, r0:], r0, bq, bkv, interpret)),
-        (q, k, lse))
-    rows = jnp.sum(jnp.where(plane[:, r0:] != 0, target, 0.0), axis=-1)
-    log(f"dsa: target of the last {t - r0} queries: first call {first:.1f}s, "
-        f"{ms:.1f} ms; a row sums to {float(rows.min()):.4f} .. "
-        f"{float(rows.max()):.4f}")
-    assert abs(float(rows.min()) - 1) < 2e-2 and abs(float(rows.max()) - 1) < 2e-2
-    # the index loss with its gradient, the target by the kernel and by
-    # the plain strips
-    found = {}
-    for name, rows_lse in (("kernel", lse), ("strips", None)):
-        loss_of = jax.jit(jax.value_and_grad(
-            lambda qi, ki, a, rows_lse=rows_lse: sparse_index.index_loss(
-                qi, ki, a, plane, q, k, rows_lse, sparse_index.CHUNK,
-                sparse_index.GROUP, interpret), argnums=(0, 1, 2)))
-        first, ms, found[name] = _timed(loss_of, (qi, ki, a), n=1)
-        log(f"dsa: index loss, target by the {name}: {float(found[name][0]):.4f}; "
-            f"first call {first:.1f}s, loss + gradient {ms:.1f} ms")
+    def under(plane):
+        """The core under ``plane``: (output, the rows' log-sum-exp)."""
+        if interpret:
+            return lambda q, k, v: kernel_attention_selected(
+                q, k, v, plane, d ** -0.5, bq, bkv, True)
+        return lambda q, k, v: causal_blockwise_attention(q, k, v, selection=plane)
+
+    # the core's forward pass hands on the rows' log-sum-exp; with it the
+    # index loss is one kernel: alone (a layer's forward pass), then with
+    # its gradient (the forward rule), against the plain strips
+    # (the planes are ARGUMENTS of these programs: closed over, a 268 MB
+    # constant costs each compile half a minute)
+    first, ms, (_, lse) = _timed(jax.jit(
+        lambda q, k, v, plane: under(plane)(q, k, v)), (q, k, v, plane))
+    log(f"dsa: core forward, with its rows' log-sum-exp: first call "
+        f"{first:.1f}s, {ms:.1f} ms")
+    operands = (qi, ki, a, plane, q, k, lse)
+
+    def by_kernel(with_grad):
+        return jax.jit(lambda *x: index_loss_tiles(
+            *x, with_grad, bq, bkv, interpret))
+
+    first, ms, _ = _timed(by_kernel(False), operands)
+    log(f"dsa: index loss alone by the kernel: first call {first:.1f}s, {ms:.1f} ms")
+    found, took = {}, {}
+    for name, fn in (("kernel", by_kernel(True)), ("strips", jax.jit(
+            jax.value_and_grad(lambda *x: sparse_index.index_loss(*x[:6]),
+                               argnums=(0, 1, 2))))):
+        first, took[name], found[name] = _timed(fn, operands, n=2)
+        log(f"dsa: index loss by the {name}: {float(found[name][0]):.4f}; "
+            f"first call {first:.1f}s")
+    log(f"dsa: the index loss with its gradient {took['kernel']:.1f} ms a layer "
+        f"call by the kernel against {took['strips']:.1f} by the strips")
     (loss, grads), (want, want_grads) = found["kernel"], found["strips"]
     f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
     gaps = [abs(float(loss) - float(want)) / float(want)] + [
@@ -736,15 +745,6 @@ def phase_dsa() -> None:
     log("dsa: index loss: kernel to strips, the loss's relative gap and the "
         "gradients' (q^I, k^I, a) " + " ".join(f"{x:.3e}" for x in gaps))
     assert all(math.isfinite(x) and x <= 2e-2 for x in gaps) and float(loss) > 0, gaps
-
-    from dinov3_tpu.ops.causal_attention import kernel_attention_selected
-
-    if interpret:
-        core = lambda q, k, v: kernel_attention_selected(  # noqa: E731
-            q, k, v, plane, d ** -0.5, bq, bkv, True)
-    else:
-        core = lambda q, k, v: causal_blockwise_attention(  # noqa: E731
-            q, k, v, selection=plane)
 
     def rows(q, k, v):
         """A masked softmax over whole rows, a block of queries at a time."""
@@ -765,7 +765,7 @@ def phase_dsa() -> None:
         return jnp.moveaxis(o, 0, 1).reshape(b, t, h, d)
 
     found = {}
-    for name, fn in (("kernel", core), ("rows", rows)):
+    for name, fn in (("kernel", lambda *x: under(plane)(*x)[0]), ("rows", rows)):
         both = jax.jit(lambda q, k, v, fn=fn: (fn(q, k, v), *jax.vjp(
             fn, q, k, v)[1](do)))
         first, ms, found[name] = _timed(both, (q, k, v))
@@ -775,9 +775,7 @@ def phase_dsa() -> None:
 
     def in_program(q, k, v, qi, ki, a):
         made = sparse_index.selection_plane(qi, ki, a, thr, last, topk=topk)[0]
-        fn = lambda q, k, v: (  # noqa: E731
-            kernel_attention_selected(q, k, v, made, d ** -0.5, bq, bkv, True)
-            if interpret else causal_blockwise_attention(q, k, v, selection=made))
+        fn = lambda *x: under(made)(*x)[0]  # noqa: E731
         return (fn(q, k, v), *jax.vjp(fn, q, k, v)[1](do))
 
     found["kernel, plane in program"] = jax.jit(in_program)(q, k, v, qi, ki, a)
@@ -787,6 +785,20 @@ def phase_dsa() -> None:
         log(f"dsa: core: norm of the difference over the norm, {name} to whole "
             "rows, output and gradients q k v " + " ".join(f"{x:.3e}" for x in gaps))
         assert all(math.isfinite(x) and x <= 2e-2 for x in gaps), gaps
+    # a sequence no longer than topk keeps every causal pair: under that
+    # triangle the pair is the dense kernels', bit for bit where one
+    # compiler makes both (interpreted, XLA:CPU contracts them differently)
+    n = min(t, max(topk, bkv))   # whole blocks
+    triangle = jnp.tril(jnp.ones((b, n, n), jnp.int8))
+    dense = (lambda q, k, v: kernel_attention(q, k, v, d ** -0.5, None, bq, bkv, True)
+             ) if interpret else causal_blockwise_attention
+    short = tuple(x[:, :n] for x in (q, k, v))
+    pair = [jax.jit(lambda q, k, v, fn=fn: (fn(q, k, v), *jax.vjp(fn, q, k, v)[1](
+        do[:, :n])))(*short) for fn in (dense, lambda *x: under(triangle)(*x)[0])]
+    gap = max(float(jnp.max(jnp.abs(f32(x) - f32(y)))) for x, y in zip(*pair))
+    log(f"dsa: core under the causal triangle at {n} tokens against the dense "
+        f"kernels, output and gradients: largest difference {gap:.3e}")
+    assert gap <= (1e-2 if interpret else 0.0), gap
     faulthandler.cancel_dump_traceback_later()
 
 

@@ -111,7 +111,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from dinov3_tpu.ops.attention import causal_selected_lse, dispatch_attention
+from dinov3_tpu.ops.attention import dispatch_attention
 from dinov3_tpu.ops.common import l2_normalize, part, trunc_normal_init
 from dinov3_tpu.ops.ffn import ROWS_CAPACITY_FACTOR, RoutedExpertsFFN, SwiGLUFFN
 from dinov3_tpu.ops.kda import kda_chunked
@@ -662,11 +662,16 @@ class DSAMixer(nn.Module):
     selects for each query (the module's docstring, **DSA**): ``(y,
     {"index_loss", "select_excess"[, "selection"]})``. ``qk_norm`` as
     ``GQAMixer``'s. While a sequence is no longer than ``topk`` every
-    query keeps every key up to its own and the core is the dense causal
-    one, bit for bit; the index loss is there all the same.
+    query keeps every key up to its own (the selection is the causal
+    triangle) and the core's output is the dense causal one's, bit for
+    bit; the index loss is there all the same.
 
     The selection reaches the core as an operand, [B, T, T] int8, made
-    from two int32 a query (the threshold and the last tie kept). A
+    from two int32 a query (the threshold and the last tie kept). The
+    core runs first and hands on, beside its output, its rows'
+    log-sum-exp over the selected keys where it ran the kernels (None
+    where it ran the plain tiles): the index loss makes its target from
+    it and runs no attention pass of its own. A
     rematerialised layer makes both again in its backward: kept across
     the layer's remat (``save_only_these_names``) they gave a wrong
     gradient on the chip, cause not found (PERF.md section 6, PR 39).
@@ -722,15 +727,12 @@ class DSAMixer(nn.Module):
             last = jnp.full((b, t), t, jnp.int32)
         plane, excess = selection_plane(qi, ki, a, thr, last, topk=self.topk,
                                         chunk=self.chunk)
-        with jax.named_scope("dsa_index_loss"):
-            lse = causal_selected_lse(q, k, v, plane,
-                                      reduce_dtype=self.reduce_dtype)
-        loss = index_loss(qi, ki, a, plane, jax.lax.stop_gradient(q),
-                          jax.lax.stop_gradient(k), lse, self.chunk)
         with jax.named_scope("dsa_core"):
-            o = dispatch_attention(
+            o, lse = dispatch_attention(
                 q, k, v, causal=True, reduce_dtype=self.reduce_dtype,
-                selection=plane if t > self.topk else None)
+                selection=plane)
+        loss = index_loss(qi, ki, a, plane, *jax.lax.stop_gradient((q, k, lse)),
+                          self.chunk)
         aux = {"index_loss": loss, "select_excess": excess}
         if self.keep_selection:
             aux["selection"] = pack_selection(plane)

@@ -120,15 +120,6 @@ def xla_attention(
     return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v)
 
 
-def _takes_causal_kernels(q, k, v, window, block_q, block_kv, reduce_dtype):
-    """Whether this call goes to ``ops/causal_attention.py``'s kernels."""
-    from dinov3_tpu.ops.causal_attention import causal_attention_path
-
-    return q.dtype == k.dtype == v.dtype and causal_attention_path(
-        (q.shape, k.shape, v.shape), window, None, block_q, block_kv,
-        q.dtype, reduce_dtype)[0] == "kernel"
-
-
 def causal_blockwise_attention(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -138,7 +129,7 @@ def causal_blockwise_attention(
     reduce_dtype=jnp.float32,
     window: int | None = None,
     selection: jnp.ndarray | None = None,
-) -> jnp.ndarray:
+) -> jnp.ndarray | tuple:
     """Causal attention block by block: [B, N, h, dqk] q, [B, N, hk, dqk]
     k, [B, N, hk, dv] v (the value width may differ from the q/k width:
     latent attention's 192 beside 128), statistics in reduce_dtype.
@@ -152,7 +143,11 @@ def causal_blockwise_attention(
     ``selection``: [B, N, N] int8, 1 where query t keeps key s (the same
     keys for every head; no pair above the diagonal and at least one key
     a query): token t sees the keys it keeps and no others. It takes no
-    gradient, and goes with no window.
+    gradient, and goes with no window. The call then returns a PAIR: the
+    output and the rows' log-sum-exp over their kept keys, [B, h, N]
+    float32, which the kernels have in hand (no gradient goes back
+    through it; ``ops/sparse_index.py index_loss`` makes its target with
+    it), or None where the plain tiles ran.
 
     A block of ``block_q`` queries meets the key tiles of its band and no
     others, ``block_kv`` keys at a time under a running maximum and sum
@@ -173,7 +168,9 @@ def causal_blockwise_attention(
         raise ValueError("a selection goes with no window")
     from dinov3_tpu.ops import causal_attention as kernels
 
-    if _takes_causal_kernels(q, k, v, window, block_q, block_kv, reduce_dtype):
+    if q.dtype == k.dtype == v.dtype and kernels.causal_attention_path(
+            (q.shape, k.shape, v.shape), window, None, block_q, block_kv,
+            q.dtype, reduce_dtype)[0] == "kernel":
         if selection is not None:
             return kernels.kernel_attention_selected(
                 q, k, v, selection, q.shape[-1] ** -0.5, block_q, block_kv,
@@ -182,23 +179,8 @@ def causal_blockwise_attention(
             q, k, v, q.shape[-1] ** -0.5, window, block_q, block_kv, False)
     if selection is not None:
         return causal_tiles(q, k, v, block_q, block_kv, reduce_dtype, window,
-                            jax.lax.stop_gradient(selection))
+                            jax.lax.stop_gradient(selection)), None
     return causal_tiles(q, k, v, block_q, block_kv, reduce_dtype, window)
-
-
-def causal_selected_lse(q, k, v, selection, block_q: int = 512,
-                        block_kv: int = 1024, reduce_dtype=jnp.float32):
-    """[B, h, N] float32, every head's log-sum-exp over the keys
-    ``selection`` keeps (``ops/causal_attention.py selected_lse``), where
-    ``causal_blockwise_attention`` itself takes the kernels; None where it
-    takes the plain tiles. What ``ops/sparse_index.py index_loss`` needs to
-    make its target with the third kernel; given None it makes it in plain
-    XLA. No gradient."""
-    if _takes_causal_kernels(q, k, v, None, block_q, block_kv, reduce_dtype):
-        from dinov3_tpu.ops.causal_attention import selected_lse
-
-        return selected_lse(q, k, v, selection, block_q, block_kv, False)
-    return None
 
 
 def causal_tiles(q, k, v, block_q, block_kv, reduce_dtype, window,
@@ -371,7 +353,7 @@ def dispatch_attention(
     causal: bool = False,
     window: int | None = None,
     selection: jnp.ndarray | None = None,
-) -> jnp.ndarray:
+) -> jnp.ndarray | tuple:
     if (window is not None or selection is not None) and not causal:
         raise ValueError("a window or a selection is the causal path's")
     if causal:

@@ -54,17 +54,26 @@ VMEM), and one more ``where`` on the score plane in every tile. A tile none of
 whose pairs is kept costs what any other does: the grid is still the
 band's (no tile is skipped by what the data says).
 
-``causal_attn_probs`` (``selected_head_probs``) gives what a learned
-indexer is trained towards: the mean over ALL query heads of each head's
-softmax over the keys its query keeps, float32, for a run of query blocks
-at a time (``ops/sparse_index.py`` asks for a group of strips and reads
-it at once: a whole ``[N, N]`` plane would be 1 GB a layer at 16,384
-tokens). A
-grid step is one key tile against every head of a query block, the planes
-transposed as the backward's (the rows' log-sum-exp, which a forward pass
-of ``causal_attn_fwd`` under the same selection wrote, is then a row
-vector): one product and one exponential a head, summed in VMEM and
-turned back once a tile, no plane a head ever in HBM.
+Under a selection the forward kernel's log-sum-exp is an OUTPUT of the
+call (``kernel_attention_selected``: ``[B, h, N]`` float32 beside o, in
+the primal as in the forward rule, no gradient through it): what a
+learned indexer is trained towards — the mean over ALL query heads of
+each head's softmax over the keys its query keeps — needs those row
+statistics and nothing else of the attention pass, so no second pass
+makes them.
+
+``index_loss_value`` / ``index_loss_grad`` (``index_loss_tiles``) are
+that index loss (``ops/sparse_index.py``) by causal tiles: grid
+(sequence, query block, key tile of the block's band), the planes
+transposed as the backward's. A tile's target (one product and one
+exponential a main head, summed in VMEM), its index scores (one product
+of 64 a pair and index head, ReLU, the weighted sum over heads), the
+rows' softmax statistics over their kept keys, the KL and — in the
+forward rule, which walks a block's band twice: statistics first — the
+closed-form gradient (dq^I along the band, da, and dk^I of the whole
+sequence in float32 VMEM scratch, as dk and dv above) never leave VMEM:
+no plane a head, no target plane, no [queries, keys] float32 at all in
+HBM.
 
 A q/k width that is not a multiple of the 128-lane tile (latent
 attention's 192) is padded with zeros up to one (a copy through HBM on
@@ -83,7 +92,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 KERNEL_NAME = "causal_attn_fwd"
 BACKWARD_KERNEL_NAME = "causal_attn_bwd"
-PROBS_KERNEL_NAME = "causal_attn_probs"
+INDEX_LOSS_KERNEL_NAME = "index_loss_value"
+INDEX_LOSS_GRAD_KERNEL_NAME = "index_loss_grad"
 _LANES = 128
 _NEG = -1e30
 # what the backward may hold of one key/value head's dk and dv: the
@@ -435,10 +445,12 @@ def kernel_attention_selected(q, k, v, selection, scale, block_q, block_kv,
                               interpret):
     """``kernel_attention`` under a per-query selection ([B, N, N] int8,
     1 where query t keeps key s, no pair above the diagonal; every query
-    keeps at least one key). No window; the selection takes no gradient."""
-    return _kernel_forward(q, k, v, scale=scale, window=None, block_q=block_q,
-                           block_kv=block_kv, keep_lse=False,
-                           interpret=interpret, selection=selection)[0]
+    keeps at least one key), and the rows' log-sum-exp over their kept
+    keys beside it: (o, [B, h, N] float32). No window; the selection takes
+    no gradient, and the log-sum-exp hands none back (its cotangent is
+    dropped: a consumer reads it detached)."""
+    return _kernel_attention_selected_fwd(
+        q, k, v, selection, scale, block_q, block_kv, interpret)[0]
 
 
 def _kernel_attention_selected_fwd(q, k, v, selection, scale, block_q,
@@ -446,128 +458,278 @@ def _kernel_attention_selected_fwd(q, k, v, selection, scale, block_q,
     o, lse = _kernel_forward(q, k, v, scale=scale, window=None,
                              block_q=block_q, block_kv=block_kv, keep_lse=True,
                              interpret=interpret, selection=selection)
-    return o, (q, k, v, o, lse, selection)
+    b, n, h, _ = q.shape
+    return (o, lse.reshape(b, h, n)), (q, k, v, o, lse, selection)
 
 
-def _kernel_attention_selected_bwd(scale, block_q, block_kv, interpret, res, do):
+def _kernel_attention_selected_bwd(scale, block_q, block_kv, interpret, res, ct):
     *res, selection = res
-    return (*_kernel_backward(*res, do, scale=scale, window=None,
+    return (*_kernel_backward(*res, ct[0], scale=scale, window=None,
                               block_q=block_q, block_kv=block_kv,
                               interpret=interpret, selection=selection), None)
 
 
+# no optimize_remat: the primal IS the forward rule (the log-sum-exp is an
+# output), and several outputs under it break JAX's DCE (PERF.md, PR 39)
 kernel_attention_selected.defvjp(
-    _kernel_attention_selected_fwd, _kernel_attention_selected_bwd,
-    optimize_remat=True)
+    _kernel_attention_selected_fwd, _kernel_attention_selected_bwd)
 
 
-def _probs_kernel(q_ref, k_ref, lse_ref, sel_ref, p_ref, q_scr, kept_scr,
-                  sum_scr, *, scale, group, block_q, block_kv, first_block):
-    """q_ref [block_q, h * d], k_ref [block_kv, hk * d], lse_ref
-    [h, block_q], sel_ref [block_q, block_kv] int8, p_ref [block_q,
-    block_kv] float32; scratch: every head stacked [h, block_q, d], the
-    selection's block turned and the sum over heads, both [block_kv,
-    block_q] float32. Grid: (sequence, query block, key tile of the
-    block's band); the rows' first query block is the sequence's
-    ``first_block``-th."""
+def _index_loss_kernel(*refs, scale, norm, group, index_heads, block_q,
+                       block_kv, steps, with_grad):
+    """A tile of ``ops/sparse_index.py``'s index loss, every plane with the
+    keys in its rows and the queries in its lanes (a row statistic or a
+    head weight is then a row vector, as in the backward above).
+
+    qit_ref [H_I * d_I, block_q] (q^I turned: a head is d_I rows), at_ref
+    [H_I, block_q], ki_ref [block_kv, d_I] (``with_grad``: then
+    kit_ref [d_I, block_kv], the same tile turned), sel_ref [block_q,
+    block_kv] int8, q_ref [block_q, h * d], k_ref [block_kv, hk * d],
+    lse_ref [h, block_q] (the main attention's, from the core);
+    loss_ref [1, block_q] float32: each query's KL, not yet divided by
+    their number.
+
+    Of a tile: the target p = mean_i exp(q_i . k scale - lse_i) on the
+    kept pairs (one product and one exponential a main head), the index
+    scores I = sum_j a_j ReLU(k^I . q^I_j) (one product a head), and the
+    rows' softmax statistics of I over their kept keys.
+
+    Without ``with_grad`` the grid is (sequence, query block, key tile of
+    the block's band) and the statistics run on (a maximum, a sum) beside
+    sum p (log p - I) and sum p, closed when the band ends:
+    KL = sum p (log p - I) + (m + log l) sum p.
+
+    ``with_grad`` walks a block's band TWICE (the grid's last axis is
+    ``2 * steps``): first the scores alone for (m, l), then everything,
+    with log softmax_S(I) = I - m - log l in hand: the KL term by term and
+
+        dI = (softmax_S(I) - p) norm  on the kept pairs,
+        dz_j = dI a_j [z_j > 0],   da_j = sum_s dI ReLU(z_j),
+        dq^I_j (turned) += k^I(turned) dz_j,    dk^I += dz_j q^I_j
+
+    (z_j made again from the operands: no plane a head is kept); dz enters
+    the MXU in the operands' type, everything else is float32. dqt_ref and
+    dat_ref are laid out as qit_ref and at_ref; dk_ref [N, d_I] is the
+    whole sequence's and leaves once, as dk and dv do above. Scratch: the
+    main heads stacked [h, block_q, d], three planes [block_kv, block_q]
+    float32 (the selection's block turned, the target, the scores and
+    then dI), the row statistics, and the gradients' accumulators."""
+    if with_grad:
+        (qit_ref, at_ref, ki_ref, kit_ref, sel_ref, q_ref, k_ref, lse_ref,
+         loss_ref, dqt_ref, dat_ref, dk_ref, q_scr, kept_scr, p_scr, i_scr,
+         m_scr, l_scr, sum_scr, dqt_scr, dat_scr, dk_scr) = refs
+    else:
+        (qit_ref, at_ref, ki_ref, sel_ref, q_ref, k_ref, lse_ref, loss_ref,
+         q_scr, kept_scr, p_scr, i_scr, m_scr, l_scr, sum_scr, mass_scr) = refs
     heads, d = q_scr.shape[0], q_scr.shape[-1]
+    di = qit_ref.shape[0] // index_heads
     i, t = pl.program_id(1), pl.program_id(2)
-    _, count = _band(i + first_block, block_q, block_kv, None)
+    _, count = _band(i, block_q, block_kv, None)
+    # ``with_grad``: the tile of the band's second walk (negative in the first)
+    at_tile = t - steps if with_grad else t
 
     @pl.when(t == 0)
     def _():
         for j in range(heads):
             q_scr[j] = q_ref[:, j * d:(j + 1) * d]
+        m_scr[...] = jnp.full_like(m_scr, _NEG)
+        for ref in (l_scr, sum_scr) + (
+                (dqt_scr, dat_scr) if with_grad else (mass_scr,)):
+            ref[...] = jnp.zeros_like(ref)
 
-    @pl.when(t < count)
-    def _():
+    if with_grad:
+        @pl.when(jnp.logical_and(i == 0, t == 0))
+        def _():
+            dk_scr[...] = jnp.zeros_like(dk_scr)
+
+    def head_rows(j):
+        return pl.ds(pl.multiple_of(j * di, di), di)
+
+    def kept():
+        return kept_scr[...] != 0.0
+
+    def scores():
+        """I into ``i_scr``, the selection's block turned into ``kept_scr``."""
         kept_scr[...] = sel_ref[...].astype(jnp.float32).T
-        sum_scr[...] = jnp.zeros_like(sum_scr)
+        i_scr[...] = jnp.zeros_like(i_scr)
+        ki = ki_ref[...]
+
+        def head(j, carry):
+            z = _dot(ki, qit_ref[head_rows(j), :])
+            i_scr[...] += jnp.maximum(z, 0.0) * at_ref[pl.ds(j, 1), :]
+            return carry
+
+        jax.lax.fori_loop(0, index_heads, head, 0)
+
+    def statistics():
+        """The running (maximum, sum) of exp(I) over the rows' kept keys
+        (a row that has kept none yet stands at (-1e30, 0))."""
+        scores_, m_prev, held = i_scr[...], m_scr[...], kept()
+        m_next = jnp.maximum(m_prev, jnp.max(
+            jnp.where(held, scores_, _NEG), axis=0, keepdims=True))
+        e = jnp.where(held, jnp.exp(scores_ - m_next), 0.0)
+        l_scr[...] = jnp.exp(m_prev - m_next) * l_scr[...] + jnp.sum(
+            e, axis=0, keepdims=True)
+        m_scr[...] = m_next
+
+    def target():
+        """p [block_kv, block_q]: 0 off the kept pairs (where a head's
+        exponential may have overflowed: masked once, after the sum)."""
+        p_scr[...] = jnp.zeros_like(p_scr)
         for kh in range(heads // group):
             k = k_ref[:, kh * d:(kh + 1) * d]
 
             def head(j, carry, k=k, kh=kh):
                 at = kh * group + j
-                s = _dot(k, q_scr[at], _NT) * scale      # [block_kv, block_q]
-                p = jnp.exp(s - lse_ref[pl.ds(at, 1), :])
-                sum_scr[...] += jnp.where(kept_scr[...] != 0.0, p, 0.0)
+                s = _dot(k, q_scr[at], _NT) * scale
+                p_scr[...] += jnp.exp(s - lse_ref[pl.ds(at, 1), :])
                 return carry
 
             jax.lax.fori_loop(0, group, head, 0)
-        p_ref[...] = (sum_scr[...] * (1.0 / heads)).T
+        return jnp.where(kept(), p_scr[...] * (1.0 / heads), 0.0)
+
+    def cross_entropy(p, logq):
+        """sum_s p (log p - logq) a query, over the pairs with p > 0."""
+        live = p > 0.0
+        return jnp.sum(jnp.where(
+            live, p * (jnp.log(jnp.where(live, p, 1.0)) - logq), 0.0),
+            axis=0, keepdims=True)
+
+    if not with_grad:
+        @pl.when(t < count)
+        def _():
+            scores()
+            statistics()
+            p = target()
+            sum_scr[...] += cross_entropy(p, i_scr[...])
+            mass_scr[...] += jnp.sum(p, axis=0, keepdims=True)
+
+        @pl.when(t == count - 1)
+        def _():
+            loss_ref[...] = sum_scr[...] + mass_scr[...] * (
+                m_scr[...] + jnp.log(l_scr[...]))
+        return
+
+    @pl.when(t < count)
+    def _():
+        scores()
+        statistics()
+
+    @pl.when(jnp.logical_and(at_tile >= 0, at_tile < count))
+    def _():
+        scores()
+        p = target()
+        logq = i_scr[...] - (m_scr[...] + jnp.log(l_scr[...]))
+        sum_scr[...] += cross_entropy(p, logq)
+        i_scr[...] = jnp.where(kept(), jnp.exp(logq) - p, 0.0) * norm     # dI
+        ki, kit = ki_ref[...], kit_ref[...]
+        keys = pl.ds(pl.multiple_of(at_tile * block_kv, block_kv), block_kv)
+
+        def head(j, carry):
+            rows = head_rows(j)
+            qt, di_ = qit_ref[rows, :], i_scr[...]
+            z = _dot(ki, qt)
+            dat_scr[pl.ds(j, 1), :] += jnp.sum(
+                jnp.maximum(z, 0.0) * di_, axis=0, keepdims=True)
+            dz = jnp.where(z > 0.0, di_ * at_ref[pl.ds(j, 1), :], 0.0).astype(
+                qt.dtype)
+            dk_scr[keys, :] += _dot(dz, qt, _NT)
+            dqt_scr[rows, :] += _dot(kit, dz)
+            return carry
+
+        jax.lax.fori_loop(0, index_heads, head, 0)
+
+    @pl.when(at_tile == count - 1)
+    def _():
+        loss_ref[...] = sum_scr[...]
+        dqt_ref[...] = dqt_scr[...].astype(dqt_ref.dtype)
+        dat_ref[...] = dat_scr[...].astype(dat_ref.dtype)
+
+    @pl.when(jnp.logical_and(i == pl.num_programs(1) - 1, at_tile == count - 1))
+    def _():
+        dk_ref[...] = dk_scr[...].astype(dk_ref.dtype)
+
+
+def index_loss_fits(q_shape, index_shape) -> bool:
+    """Whether ``index_loss_tiles`` takes main heads [.., h, d] beside an
+    indexer [.., H_I, d_I] (given the core took the kernels): whole lane
+    tiles a main head, whole sublane tiles (of a 2-byte type) an index
+    head."""
+    return not (q_shape[-1] % _LANES or index_shape[-1] % 16)
 
 
 @functools.partial(jax.jit, inline=True, static_argnames=(
-    "block_q", "block_kv", "interpret"))
-def selected_lse(q, k, v, selection, block_q=512, block_kv=1024,
-                 interpret=False):
-    """[B, h, N] float32: every head's log-sum-exp over the keys
-    ``selection`` keeps for the query — a forward pass of
-    ``causal_attn_fwd`` under it, the output dropped. No gradient."""
-    q, k, v = (jax.lax.stop_gradient(x) for x in (q, k, v))
-    b, n, h, width = q.shape
-    _, lse = _kernel_forward(q, k, v, scale=width ** -0.5, window=None,
-                             block_q=block_q, block_kv=block_kv, keep_lse=True,
-                             interpret=interpret, selection=selection)
-    return lse.reshape(b, h, n)
-
-
-@functools.partial(jax.jit, inline=True, static_argnames=(
-    "first", "block_q", "block_kv", "interpret"))
-def selected_head_probs(q, k, lse, selection, first=0, block_q=512,
-                        block_kv=1024, interpret=False):
-    """[B, R, K] float32 for R consecutive queries from the sequence's
-    ``first``-th on and its first K keys: the mean over the query heads of
-    each head's softmax (``lse`` [B, h, R]: ``selected_lse``'s rows) over
-    the keys ``selection`` [B, R, K] keeps, 0 where it keeps none inside a
-    block's band; the tiles ABOVE a block's band are never written, so
-    read it where ``selection`` is set and nowhere else. q [B, R, h, d],
-    k [B, K, hk, d]; R whole query blocks, K whole key tiles that reach
-    the last query's own key. No gradient."""
-    q, k = jax.lax.stop_gradient(q), jax.lax.stop_gradient(k)
-    b, r, h, width = q.shape
-    keys, hk = k.shape[1], k.shape[2]
-    if first % block_q or r % block_q or keys % block_kv or keys < first + r:
-        raise ValueError(f"{r} queries from {first} on over {keys} keys are "
-                         f"not whole blocks of {block_q} x {block_kv}")
-    pad = (-width) % _LANES
-    if pad:
-        q, k = (jnp.pad(x, ((0, 0),) * 3 + ((0, pad),)) for x in (q, k))
-    d = width + pad
-    first_block, blocks = first // block_q, r // block_q
-    steps = _band(first_block + blocks - 1, block_q, block_kv, None)[1]
+    "with_grad", "block_q", "block_kv", "interpret"))
+def index_loss_tiles(qi, ki, a, selection, q, k, lse, with_grad=False,
+                     block_q=512, block_kv=1024, interpret=False):
+    """``ops/sparse_index.py index_loss`` on the kernel path, ONE
+    ``pallas_call``: (L, () or L's gradient on (q^I, k^I, a)). qi [B, N,
+    H_I, d_I], ki [B, N, d_I], a [B, N, H_I], ``selection`` [B, N,
+    N] int8, q [B, N, h, d], k [B, N, hk, d] and ``lse`` [B, h, N], the
+    main attention's rows' log-sum-exp over their kept keys, as the core
+    hands it on. No [.., keys] float32 plane leaves VMEM."""
+    b, n, hi, di = qi.shape
+    h, d, hk = q.shape[2], q.shape[3], k.shape[2]
+    blocks = n // block_q
+    steps = _band(blocks - 1, block_q, block_kv, None)[1]
+    turned = lambda x: jnp.swapaxes(x.reshape(b, n, -1), 1, 2)  # noqa: E731
 
     def tile(i, t):
-        return jnp.minimum(
-            t, _band(i + first_block, block_q, block_kv, None)[1] - 1)
+        return jnp.minimum(jnp.where(t >= steps, t - steps, t),
+                           _band(i, block_q, block_kv, None)[1] - 1)
 
     vmem = pltpu.VMEM
-    pair = pl.BlockSpec((None, block_q, block_kv),
-                        lambda s, i, t: (s, i, tile(i, t)), memory_space=vmem)
-    return pl.pallas_call(
-        functools.partial(_probs_kernel, scale=width ** -0.5, group=h // hk,
-                          block_q=block_q, block_kv=block_kv,
-                          first_block=first_block),
-        grid=(b, blocks, steps),
-        in_specs=[
-            pl.BlockSpec((None, block_q, h * d), lambda s, i, t: (s, i, 0),
-                         memory_space=vmem),
-            pl.BlockSpec((None, block_kv, hk * d),
-                         lambda s, i, t: (s, tile(i, t), 0), memory_space=vmem),
-            pl.BlockSpec((None, h, block_q), lambda s, i, t: (s, 0, i),
-                         memory_space=vmem),
-            pair],
-        out_specs=pair,
-        out_shape=jax.ShapeDtypeStruct((b, r, keys), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((h, block_q, d), q.dtype),
-                        pltpu.VMEM((block_kv, block_q), jnp.float32),
-                        pltpu.VMEM((block_kv, block_q), jnp.float32)],
+    rows = lambda w: pl.BlockSpec(  # noqa: E731
+        (None, w, block_q), lambda s, i, t: (s, 0, i), memory_space=vmem)
+    keys = lambda w: pl.BlockSpec(  # noqa: E731
+        (None, block_kv, w), lambda s, i, t: (s, tile(i, t), 0), memory_space=vmem)
+    operands = [turned(qi), turned(a), ki, selection, q.reshape(b, n, -1),
+                k.reshape(b, n, -1), lse]
+    in_specs = [
+        rows(hi * di), rows(hi), keys(di),
+        pl.BlockSpec((None, block_q, block_kv),
+                     lambda s, i, t: (s, i, tile(i, t)), memory_space=vmem),
+        pl.BlockSpec((None, block_q, h * d), lambda s, i, t: (s, i, 0),
+                     memory_space=vmem),
+        keys(hk * d), rows(h)]
+    plane = pltpu.VMEM((block_kv, block_q), jnp.float32)
+    row = pltpu.VMEM((1, block_q), jnp.float32)
+    out_specs, out_shape = [rows(1)], [jax.ShapeDtypeStruct((b, 1, n), jnp.float32)]
+    scratch = [pltpu.VMEM((h, block_q, d), q.dtype)] + [plane] * 3 + [row] * 4
+    if with_grad:
+        operands.insert(3, turned(ki))
+        in_specs.insert(3, pl.BlockSpec(
+            (None, di, block_kv), lambda s, i, t: (s, 0, tile(i, t)),
+            memory_space=vmem))
+        out_specs += [rows(hi * di), rows(hi), pl.BlockSpec(
+            (None, n, di), lambda s, i, t: (s, 0, 0), memory_space=vmem)]
+        out_shape += [jax.ShapeDtypeStruct((b, hi * di, n), qi.dtype),
+                      jax.ShapeDtypeStruct((b, hi, n), a.dtype),
+                      jax.ShapeDtypeStruct((b, n, di), ki.dtype)]
+        scratch = scratch[:-1] + [pltpu.VMEM((hi * di, block_q), jnp.float32),
+                                  pltpu.VMEM((hi, block_q), jnp.float32),
+                                  pltpu.VMEM((n, di), jnp.float32)]
+    norm = 1.0 / (b * n)
+    out = pl.pallas_call(
+        functools.partial(
+            _index_loss_kernel, scale=d ** -0.5, norm=norm, group=h // hk,
+            index_heads=hi, block_q=block_q, block_kv=block_kv, steps=steps,
+            with_grad=with_grad),
+        grid=(b, blocks, steps * (2 if with_grad else 1)),
+        in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
+        scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=interpret,
-        name=PROBS_KERNEL_NAME,
-    )(q.reshape(b, r, -1), k.reshape(b, keys, -1), lse, selection)
+        name=INDEX_LOSS_GRAD_KERNEL_NAME if with_grad else INDEX_LOSS_KERNEL_NAME,
+    )(*operands)
+    loss = jnp.sum(out[0]) * norm
+    if not with_grad:
+        return loss, ()
+    dqt, dat, dki = out[1:]
+    return loss, (jnp.swapaxes(dqt, 1, 2).reshape(qi.shape), dki,
+                  jnp.swapaxes(dat, 1, 2))
 
 
 def causal_attention_path(shapes, window: int | None = None,
@@ -600,3 +762,18 @@ def causal_attention_path(shapes, window: int | None = None,
     if interpret is None and backend != "tpu":
         return "tiles", f"the backend is {backend}, not a TPU"
     return "kernel", "interpreted" if interpret else "compiled for the TPU"
+
+
+def index_loss_path(shapes, index_width: int, **how) -> tuple[str, str]:
+    """(path, why) ``ops/sparse_index.py index_loss`` takes beside a core
+    of these q, k, v ``shapes`` and an indexer of heads ``index_width``
+    wide (``how``: ``causal_attention_path``'s other arguments):
+    ("kernel", ...) or ("strips", the reason it is not the kernel)."""
+    path, why = causal_attention_path(shapes, None, **how)
+    if path != "kernel":
+        return "strips", f"the core hands on no log-sum-exp: {why}"
+    if not index_loss_fits(shapes[0], (index_width,)):
+        return "strips", (
+            f"heads of {shapes[0][-1]} and index heads of {index_width} are "
+            f"not whole tiles of {_LANES} lanes and 16 sublanes")
+    return "kernel", why

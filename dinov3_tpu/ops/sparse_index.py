@@ -31,18 +31,20 @@ detached, to the indexer's softmax over the same keys:
     p[t, s] = (1 / H) sum_i P_i[t, s],   P_i[t, .] = softmax_{S_t}(q_i[t] . k_g(i)[s] / sqrt(d))
     L = (1 / T) sum_t sum_{s in S_t} p[t, s] (log p[t, s] - log softmax_{S_t}(I[t, .])[s])
 
-The target p is made a group of strips at a time and read at once: by
-the kernel ``ops/causal_attention.py selected_head_probs`` where the
-causal kernels run (``ops/attention.py causal_selected_lse`` then hands
-``index_loss`` the rows' log-sum-exp, chosen as the attention call
-chooses its path), a strip at a time in plain XLA elsewhere. ``index_loss``
-is a ``custom_vjp`` of ONE output (a rematerialised layer drops or keeps
-it whole): its forward rule computes, strip by strip, the loss AND its
-gradient on q^I, k^I and a (what crosses the layer's backward is three
-small arrays, never a [T, T] plane); the selection and the main
-attention's q and k take no gradient.
+Two paths make it, chosen by what the attention core hands on. Where the
+core ran the kernels it returns its rows' log-sum-exp over the selected
+keys beside its output, and ``index_loss`` is ONE kernel a pass
+(``ops/causal_attention.py index_loss_tiles``: a causal tile's target,
+index scores, KL and closed-form gradient are made in VMEM and nothing as
+wide as the keys leaves it); where the core ran the plain tiles it hands
+on None and the loss goes by strips in plain XLA, each strip's target made
+where the strip is. ``index_loss`` is a ``custom_vjp`` of ONE output (a
+rematerialised layer drops or keeps it whole): its forward rule computes
+the loss AND its gradient on q^I, k^I and a (what crosses the layer's
+backward is three small arrays, never a [T, T] plane); the selection and
+the main attention's q, k and log-sum-exp take no gradient.
 
-Everything goes by strips of ``chunk`` queries against the keys up to
+Everything in plain XLA goes by strips of ``chunk`` queries against the keys up to
 the strip's last (``q_chunk_size`` / ``kv_chunk_size`` of the published
 ``sa_config`` read as this tile: no effect on the mathematics); strips
 are grouped ``group`` at a time under one key extent, so a group is ONE
@@ -271,45 +273,38 @@ def _strip_loss(qi, ki, a, sel, p):
                              0.0))
 
 
-BLOCK_Q, BLOCK_KV = 512, 1024  # ops/causal_attention.py's shipped blocks
-
-
 def _index_loss(qi, ki, a, plane, q, k, lse, chunk, group, interpret,
                 with_grad):
-    """One pass over every sequence's strips: the loss and, ``with_grad``,
-    its gradient on (qi, ki, a). The target of a group of strips is the
-    kernel's (``lse`` given: a TPU, or ``interpret``) or a strip's in plain
-    XLA, and is read at once: no [T, T] float32 plane exists."""
+    """The loss and, ``with_grad``, its gradient on (qi, ki, a): by
+    ``ops/causal_attention.py index_loss_tiles`` where the core handed on
+    its rows' log-sum-exp (``lse``: the core took the kernels) and the
+    widths fit, else one pass over every sequence's strips in plain XLA,
+    each strip's target made where the strip is and read at once. No
+    [T, T] float32 plane exists on either path."""
+    from dinov3_tpu.ops import causal_attention as kernels
+
+    if lse is not None and kernels.index_loss_fits(q.shape, qi.shape):
+        with jax.named_scope("dsa_index_loss"):
+            return kernels.index_loss_tiles(
+                qi, ki, a, plane, q, k, lse, with_grad=with_grad,
+                interpret=bool(interpret))
     b, n = qi.shape[:2]
     scale, norm = q.shape[-1] ** -0.5, 1.0 / (b * n)
 
     def sequence(args):
-        qi, ki, a, plane, q, k, lse = args
+        qi, ki, a, plane, q, k = args
         loss = jnp.zeros((), jnp.float32)
         dqs, das = [], []
         dki = jnp.zeros(ki.shape, jnp.float32)
         for first, strips, rows, extent in _layout(n, chunk, group):
             count = strips * rows
-            by_kernel = lse is not None and not (
-                first % BLOCK_Q or count % BLOCK_Q or extent % BLOCK_KV)
-            if by_kernel:
-                from dinov3_tpu.ops.causal_attention import selected_head_probs
 
-                target = selected_head_probs(
-                    q[None, first:first + count], k[None, :extent],
-                    lse[None, :, first:first + count],
-                    plane[None, first:first + count, :extent], first,
-                    BLOCK_Q, BLOCK_KV, bool(interpret))[0]
-                target = target.reshape(strips, rows, extent)
-            else:  # a strip's, where the strip is
-                target = _strips(q, first, strips, rows)
-
-            def strip(carry, xs, extent=extent, by_kernel=by_kernel):
+            def strip(carry, xs, extent=extent):
                 loss, dki = carry
-                q_i, a_s, sel, t_s = xs
+                q_i, a_s, sel, q_s = xs
                 sel = sel[:, :extent] != 0
-                p_s = t_s if by_kernel else jax.lax.stop_gradient(
-                    _target(t_s, k[:extent], sel, scale))
+                p_s = jax.lax.stop_gradient(
+                    _target(q_s, k[:extent], sel, scale))
 
                 def f(q_i, k_i, a_s):
                     return norm * _strip_loss(q_i, k_i, a_s, sel, p_s)
@@ -323,7 +318,7 @@ def _index_loss(qi, ki, a, plane, q, k, lse, chunk, group, interpret,
 
             cut = lambda x: _strips(x, first, strips, rows)  # noqa: E731
             (loss, dki), out = jax.lax.scan(
-                strip, (loss, dki), (cut(qi), cut(a), cut(plane), target))
+                strip, (loss, dki), (cut(qi), cut(a), cut(plane), cut(q)))
             if with_grad:
                 dqs.append(out[0].reshape((count,) + qi.shape[1:]))
                 das.append(out[1].reshape((count,) + a.shape[1:]))
@@ -332,7 +327,7 @@ def _index_loss(qi, ki, a, plane, q, k, lse, chunk, group, interpret,
         return loss, grads
 
     with jax.named_scope("dsa_index_loss"):
-        loss, grads = _sequences(sequence, (qi, ki, a, plane, q, k, lse))
+        loss, grads = _sequences(sequence, (qi, ki, a, plane, q, k))
     return jnp.sum(loss), grads
 
 
@@ -344,10 +339,11 @@ def index_loss(qi, ki, a, plane, q, k, lse=None, chunk: int = CHUNK,
     qi [B, T, H_I, d_I], ki [B, T, d_I], a [B, T, H_I]: the indexer's, the
     only operands that take a gradient; ``plane``: ``selection_plane``'s;
     q [B, T, h, d], k [B, T, hk, d]: the main attention's, read detached;
-    ``lse`` [B, h, T]: their rows' log-sum-exp over the selected keys
-    (``ops/attention.py causal_selected_lse``), with which the target is
-    the kernel ``causal_attn_probs``'s, a group of strips at a time; None:
-    each strip's target in plain XLA."""
+    ``lse`` [B, h, T]: their rows' log-sum-exp over the selected keys, as
+    the attention core hands it on where it ran the kernels
+    (``ops/attention.py causal_blockwise_attention`` under the same
+    ``plane``): the whole loss is then one kernel a pass; None: the plain
+    strips."""
     return _index_loss(qi, ki, a, plane, q, k, lse, chunk, group, interpret,
                        False)[0]
 

@@ -31,7 +31,7 @@ import jax.numpy as jnp
 
 from dinov3_tpu.configs import ConfigNode
 from dinov3_tpu.models import build_backbone
-from dinov3_tpu.ops.causal_attention import causal_attention_path
+from dinov3_tpu.ops.causal_attention import causal_attention_path, index_loss_path
 from dinov3_tpu.ops.kda import kda_path
 from dinov3_tpu.ops.mixer_chains import mixer_chain_path
 
@@ -88,6 +88,12 @@ class LMMetaArch:
                     reduce_dtype=dc.reduce_dtype)
                 logger.info("layer %d %s (%s), both passes: %s (%s)", i, scope,
                             mixer, path, why)
+                if mixer == "dsa":
+                    path, why = index_loss_path(
+                        tuple(rows + s for s in shapes), dc.index_head_dim,
+                        dtype=dc.dtype, reduce_dtype=dc.reduce_dtype)
+                    logger.info("layer %d dsa_index_loss, both passes: %s (%s)",
+                                i, path, why)
 
     def init_params(self, rng: jax.Array, batch: dict, unbox: bool = True) -> dict:
         import flax.linen as nn

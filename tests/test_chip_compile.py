@@ -4,7 +4,8 @@ backward, plain and segment-masked, at the 768 px (N=2309) and 1024 px
 (N=4101) ViT-L token counts, the fused layernorm forward and
 backward at ViT-L width, and the delta rule's chunk forward and
 backward at the decoder cell's shapes, the causal attention kernels
-at both decoder cells' published shapes, and the delta-rule mixers'
+at both decoder cells' published shapes (under a selection too, and the
+index loss's kernel beside them), and the delta-rule mixers'
 chains (``ops/mixer_chains.py``) at both delta-rule cells' — each with ``interpret=False``, each asserting
 a Mosaic ``tpu_custom_call`` in the compiled text. What the chip's
 compiler would refuse (a slice off the tiling, too much VMEM) fails
@@ -186,8 +187,8 @@ def test_causal_attention_kernels_compile_under_a_selection_for_v5e(
         one_chip, direction):
     """The kernel pair under a per-query selection at the ``keye_vl2``
     cell's shape (1 x 16,384 tokens, 32 query heads on 4 of 128: 8 heads a
-    key tile), the [B, N, N] int8 plane one more block a tile, its
-    transpose made outside the backward kernel."""
+    key tile), the [B, N, N] int8 plane one more block a tile; the pass
+    hands on the rows' log-sum-exp beside its output."""
     from dinov3_tpu.ops.causal_attention import (
         BACKWARD_KERNEL_NAME,
         KERNEL_NAME,
@@ -200,11 +201,11 @@ def test_causal_attention_kernels_compile_under_a_selection_for_v5e(
     sel = ((1, 16384, 16384), jnp.int8)
     assert causal_attention_path((q[0], kv[0], kv[0]), None, False)[0] == "kernel"
 
-    def fwd(*x):
+    def fwd(*x):  # (the output, the rows' log-sum-exp [1, 32, 16384])
         return kernel_attention_selected(*x, 128 ** -0.5, 512, 1024, False)
 
     def bwd(*x):
-        return jax.vjp(lambda q, k, v: fwd(q, k, v, x[3]), *x[:3])[1](x[4])
+        return jax.vjp(lambda q, k, v: fwd(q, k, v, x[3])[0], *x[:3])[1](x[4])
 
     fn, shapes = (fwd, [q, kv, kv, sel]) if direction == "fwd" else (
         bwd, [q, kv, kv, sel, q])
@@ -215,30 +216,31 @@ def test_causal_attention_kernels_compile_under_a_selection_for_v5e(
     assert " while(" not in text
 
 
-def test_selected_head_probs_compiles_for_v5e(one_chip):
-    """The index loss's target as ``ops/sparse_index.py`` asks for it at
-    the ``keye_vl2`` cell's shape: the rows' log-sum-exp (a forward pass of
-    ``causal_attn_fwd`` under the selection), then ``causal_attn_probs``
-    for the LAST group of 4,096 queries against all 16,384 keys."""
+@pytest.mark.parametrize("with_grad", [False, True], ids=["value", "grad"])
+def test_index_loss_kernel_compiles_for_v5e(one_chip, with_grad):
+    """The index loss as ONE kernel at the ``keye_vl2`` cell's shape (1 x
+    16,384 tokens; an indexer of 16 heads of 64 on one key head beside 32
+    query heads on 4 of 128, the core's log-sum-exp [1, 32, 16384]): the
+    loss alone and with its gradient, no loop and no float32 plane as wide
+    as the keys beside it."""
     from dinov3_tpu.ops.causal_attention import (
-        KERNEL_NAME,
-        PROBS_KERNEL_NAME,
-        selected_head_probs,
-        selected_lse,
+        INDEX_LOSS_GRAD_KERNEL_NAME,
+        INDEX_LOSS_KERNEL_NAME,
+        index_loss_path,
+        index_loss_tiles,
     )
 
-    q = ((1, 16384, 32, 128), jnp.bfloat16)
-    kv = ((1, 16384, 4, 128), jnp.bfloat16)
-    plane = ((1, 16384, 16384), jnp.int8)
-    text = _compiled_text(selected_lse, one_chip, q, kv, kv, plane)
-    assert KERNEL_NAME in text and text.count("tpu_custom_call") == 1
+    n, bf16, f32 = 16384, jnp.bfloat16, jnp.float32
+    q, kv = (1, n, 32, 128), (1, n, 4, 128)
+    assert index_loss_path((q, kv, kv), 64, interpret=False)[0] == "kernel"
     text = _compiled_text(
-        lambda q, k, lse, sel: selected_head_probs(q, k, lse, sel, 12288),
-        one_chip, ((1, 4096, 32, 128), jnp.bfloat16), kv,
-        ((1, 32, 4096), jnp.float32), ((1, 4096, 16384), jnp.int8))
-    assert PROBS_KERNEL_NAME in text
+        lambda *x: index_loss_tiles(*x, with_grad=with_grad), one_chip,
+        ((1, n, 16, 64), bf16), ((1, n, 64), bf16), ((1, n, 16), f32),
+        ((1, n, n), jnp.int8), (q, bf16), (kv, bf16), ((1, 32, n), f32))
+    assert (INDEX_LOSS_GRAD_KERNEL_NAME if with_grad else INDEX_LOSS_KERNEL_NAME) in text
     assert text.count("tpu_custom_call") == 1 and " while(" not in text
-    assert "f32[1,4096,16384]" in text
+    # neither a strip's [512, 16, keys] products nor a [T, T] target
+    assert "f32[512,16," not in text and f"f32[1,{n},{n}]" not in text
 
 
 def _chain_cases():

@@ -4,8 +4,8 @@
     selected keys: output, index loss and the gradient of every leaf.
 (b) ``ops/sparse_index.py``: the exact threshold against ``lax.top_k``
     with planted ties; the causal kernel pair (interpreted) under a
-    selection against the plain tiles, both passes, and the third kernel
-    (the index loss's target) against the plain strips.
+    selection against the plain tiles, both passes (the index loss's
+    kernel against the plain strips: ``tests/test_index_loss_kernel.py``).
 (c) The two stop-gradients; a sequence no longer than ``topk`` is the
     dense layer bit for bit; what a rematerialised layer makes again.
 (d) The share tied to the model: 8 shards' routed parts add up to the
@@ -233,7 +233,7 @@ def test_kernel_pair_under_a_selection_is_the_plain_tiles(
     do = jax.random.normal(ks[3], q.shape)
     sel = _random_selection(rng, n, 40)
     kernel = lambda *x: kernel_attention_selected(  # noqa: E731
-        *x, sel, 128 ** -0.5, block_q, block_kv, True)
+        *x, sel, 128 ** -0.5, block_q, block_kv, True)[0]
     tiles = lambda *x: causal_tiles(  # noqa: E731
         *x, block_q, block_kv, jnp.float32, None, sel)
 
@@ -255,60 +255,6 @@ def test_kernel_pair_under_a_selection_is_the_plain_tiles(
             np.testing.assert_allclose(got, want, atol=3e-5, err_msg=name)
     for got, want in zip(again, out["kernel"][1:]):
         np.testing.assert_allclose(got, want, atol=1e-6)
-    # the index loss's target: the third kernel, whole and for the rows
-    # from the second query block on, against the heads' mean of the dense
-    # probabilities (read where the selection is set)
-    from dinov3_tpu.ops.causal_attention import selected_head_probs, selected_lse
-
-    with jax.default_matmul_precision("highest"):
-        lse = selected_lse(q, k, v, sel, block_q, block_kv, True)
-        got = jnp.where(sel != 0, selected_head_probs(
-            q, k, lse, sel, 0, block_q, block_kv, True), 0.0)
-        late = jnp.where(sel[:, block_q:] != 0, selected_head_probs(
-            q[:, block_q:], k, lse[:, :, block_q:], sel[:, block_q:], block_q,
-            block_q, block_kv, True), 0.0)
-        g = heads // kv_heads
-        z = jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k, g, 2)) / math.sqrt(128)
-        want = jnp.mean(jax.nn.softmax(
-            jnp.where(sel[:, None] != 0, z, -jnp.inf), -1), axis=1)
-    np.testing.assert_allclose(got, want, atol=2e-6)
-    np.testing.assert_allclose(late, want[:, block_q:], atol=2e-6)
-    np.testing.assert_allclose(jnp.sum(got, -1), 1.0, atol=1e-5)
-    with pytest.raises(ValueError, match="whole blocks"):
-        selected_head_probs(q[:, 64:], k, lse[:, :, 64:], sel[:, 64:], 64,
-                            block_q, block_kv, True)
-
-
-@pytest.mark.slow  # (as above; chip_smoke.py --phases dsa reads the same on the chip)
-def test_index_loss_with_the_kernels_target_is_the_plain_strips():
-    """``index_loss`` given the rows' log-sum-exp makes its target with the
-    third kernel (interpreted here), a group of strips at a time; given
-    none, a strip at a time in plain XLA: one loss, one gradient. Garbage
-    where the selection is not set reaches neither."""
-    from dinov3_tpu.ops import sparse_index as si
-    from dinov3_tpu.ops.causal_attention import selected_lse
-
-    ks = jax.random.split(jax.random.key(4), 6)
-    b, t, topk = 1, 1024, 96
-    qi, ki = jax.random.normal(ks[0], (b, t, 2, 8)), jax.random.normal(ks[1], (b, t, 8))
-    a = jax.random.normal(ks[2], (b, t, 2))
-    q = jax.random.normal(ks[3], (b, t, 2, 128))
-    k, v = (jax.random.normal(key, (b, t, 1, 128)) for key in ks[4:])
-    thr, last = si.select_thresholds(qi, ki, a, topk=topk, group=2)
-    plane, excess = si.selection_plane(qi, ki, a, thr, last, topk=topk, group=2)
-    assert int(excess) == 0
-    lse = selected_lse(q, k, v, plane, interpret=True)
-
-    def both(rows_lse):
-        return jax.jit(jax.value_and_grad(
-            lambda qi, ki, a: si.index_loss(qi, ki, a, plane, q, k, rows_lse,
-                                            si.CHUNK, 2, True),
-            argnums=(0, 1, 2)))(qi, ki, a)
-
-    (loss, grads), (want, want_grads) = both(lse), both(None)
-    assert float(loss) == pytest.approx(float(want), rel=1e-5) and float(want) > 0
-    for g, w in zip(grads, want_grads):
-        np.testing.assert_allclose(g, w, atol=1e-5 * float(jnp.max(jnp.abs(w))))
 
 
 def test_a_selection_is_the_causal_paths_alone():
@@ -320,8 +266,10 @@ def test_a_selection_is_the_causal_paths_alone():
         dispatch_attention(q, q, q, selection=sel)
     with pytest.raises(ValueError, match="no window"):
         dispatch_attention(q, q, q, causal=True, window=4, selection=sel)
-    full = dispatch_attention(q + 1.0, q + 1.0, q + 1.0, causal=True, selection=sel)
+    full, lse = dispatch_attention(q + 1.0, q + 1.0, q + 1.0, causal=True,
+                                   selection=sel)
     np.testing.assert_allclose(full, 1.0, atol=1e-6)
+    assert lse is None  # the plain tiles hand on no log-sum-exp
 
 
 # ---------------- (c) stop-gradients, the short sequence, remat ----------------
@@ -630,3 +578,6 @@ def test_the_paths_are_read_off_shapes_at_the_published_sizes():
         logger.disabled = was[1]
     said = [m for m in lines if "dsa_core" in m]
     assert len(said) == 5 and all("tiles" in m for m in said)
+    said = [m for m in lines if "dsa_index_loss" in m]
+    assert len(said) == 5 and all(
+        "strips (the core hands on no log-sum-exp" in m for m in said)
