@@ -1,0 +1,10 @@
+"""Step program (lfm2_moe decoder): share of all device time of the traced steps
+under no phase of lm_sconv_phases.json (a scope renamed in the program shows
+here). None where the trace carries no such phase. Moves
+train_img_per_s_chip."""
+
+import lm_sconv_phase_table
+
+
+def read(run):
+    return lm_sconv_phase_table.metric(run, "lm_sconv_unattributed_pct")

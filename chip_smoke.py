@@ -6,7 +6,9 @@
                                     # decoders' causal attention cores
                                     # (the kernels beside the plain tiles),
                                     # the third decoder's two cores, the
-                                    # fourth's sparse attention
+                                    # fourth's sparse attention, the
+                                    # fifth's gated short convolution and
+                                    # 64-wide causal core
     python chip_smoke.py --phases lm   # one chip, that phase alone
     python chip_smoke.py --chips 4  # four chips: ONLY the sharded train
                                     # arms and their one-device comparison
@@ -97,6 +99,11 @@ SIZES = {
     # dsa): [B, T, query heads, key/value heads, head width, index heads,
     # index width, keys a query keeps]
     "dsa_shape": (1, 16384, 32, 4, 128, 16, 64, 2048),
+    # the fifth decoder's two kernels at published sizes (--phases sconv):
+    # the gated short convolution's chain, [B, T, channels], and the causal
+    # core at 32 query heads on 8 key/value heads of 64
+    "sconv_shape": (4, 8192, 2048),
+    "sconv_attn_shapes": {"heads64": (4, 8192, 32, 8, 64, 64, None)},
     "gqa_shipped_blocks": (512, 1024),
     "gqa_blocks": [(512, 512), (1024, 1024), (256, 1024)],
     "gqa_timeout_s": 1200,
@@ -108,7 +115,7 @@ SIZES = {
 }
 
 ONE_CHIP_PHASES = ("trainer", "accum", "kernels", "serve", "lm", "gqa", "gdn",
-                   "dsa")
+                   "dsa", "sconv")
 
 _T0 = time.time()
 
@@ -649,6 +656,64 @@ def phase_gdn() -> None:
     phase_gqa("gdn_attn_shapes")
 
 
+def phase_sconv() -> None:
+    """The ``lfm2_moe`` family's two kernels stand-alone at published
+    sizes. The gated short convolution's chain (``ops/mixer_chains.py
+    gated_short_conv``: y = C * conv3(B * u) of one ``[B, T, 3 C]`` plane)
+    on the path ``mixer_chain_path`` takes there, the kernel pair against
+    the plain XLA chain, output and both gradients, both timed; then the
+    causal core at heads of 64 (two key/value heads a lane group) through
+    ``phase_gqa``'s rows."""
+    import faulthandler
+
+    import jax
+    import jax.numpy as jnp
+
+    from dinov3_tpu.models.decoder import causal_depthwise_conv
+    from dinov3_tpu.ops import mixer_chains as mc
+
+    interpret = bool(SIZES["kernel_interpret"])
+    faulthandler.dump_traceback_later(
+        float(SIZES["gqa_timeout_s"]), exit=True, file=sys.__stderr__)
+    b, t, c = SIZES["sconv_shape"]
+    path, why = mc.mixer_chain_path(t, (c,), (), jnp.bfloat16,
+                                    interpret=interpret or None)
+    log(f"sconv: chain {(b, t, c)}: the entry point takes the {path} ({why})")
+    assert path == "kernel", (path, why)
+    ks = jax.random.split(jax.random.key(4), 2)
+    x = (jax.random.normal(ks[0], (b, t, 3 * c), jnp.bfloat16),
+         jax.random.uniform(ks[1], (3, c), jnp.float32, -3 ** -0.5, 3 ** -0.5))
+
+    def plain(plane, taps):
+        gate, mid, u = (plane[..., i * c:(i + 1) * c].astype(jnp.float32)
+                        for i in range(3))
+        return (mid * causal_depthwise_conv(gate * u, taps)).astype(plane.dtype)
+
+    def kernel(plane, taps):
+        return mc.gated_short_conv(plane, taps,
+                                   interpret=True if interpret else None)
+
+    def out_and_grads(f):
+        return jax.jit(lambda *a: (f(*a), *jax.grad(
+            lambda *y: jnp.sum(jnp.sin(f(*y).astype(jnp.float32))),
+            argnums=(0, 1))(*a)))
+
+    found = {}
+    for name, fn in (("kernel", kernel), ("plain", plain)):
+        first, ms, found[name] = _timed(out_and_grads(fn), x)
+        log(f"sconv: chain, {name}: first call {first:.1f}s, forward + "
+            f"backward {ms:.2f} ms")
+    f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
+    gaps = [float(jnp.linalg.norm(f32(a) - f32(r)) / jnp.linalg.norm(f32(r)))
+            for a, r in zip(found["kernel"], found["plain"])]
+    log("sconv: chain: norm of the difference over the norm, kernel to "
+        "plain, output and gradients plane taps "
+        + " ".join(f"{g:.3e}" for g in gaps))
+    assert all(math.isfinite(g) for g in gaps) and max(gaps) <= 3e-3, gaps
+    faulthandler.cancel_dump_traceback_later()
+    phase_gqa("sconv_attn_shapes")
+
+
 def phase_dsa() -> None:
     """The ``keye_vl2`` family's sparse attention stand-alone at published
     sizes (``ops/sparse_index.py``, ``ops/causal_attention.py``): the
@@ -1086,7 +1151,7 @@ def main(argv=None) -> int:
         run = {"trainer": phase_trainer, "accum": phase_accum,
                "kernels": phase_kernels, "serve": phase_serve,
                "lm": phase_lm, "gqa": phase_gqa, "gdn": phase_gdn,
-               "dsa": phase_dsa}
+               "dsa": phase_dsa, "sconv": phase_sconv}
         for name in phases:
             run[name]()
     else:

@@ -1,4 +1,4 @@
-"""A causal decoder whose mixer and FFN are chosen layer by layer. Four
+"""A causal decoder whose mixer and FFN are chosen layer by layer. Five
 families (``student.arch``): ``kimi_linear`` (Kimi Linear, Moonshot AI;
 ``config.json`` of Kimi-Linear-48B-A3B-Instruct and the model's report:
 KDA and MLA mixers, a dense SwiGLU, routed + shared experts),
@@ -11,7 +11,10 @@ mixers 3 : 1, zero-centred norms, routed SwiGLU experts beside a shared
 one behind a sigmoid gate) and ``keye_vl2`` (Keye-VL-2.0's language model,
 Kwai; ``config.json`` of Keye-VL-2.0-30B-A3B: a Qwen3-MoE block whose
 grouped-query attention reads, for each query, the ``topk`` keys a
-learned indexer selects; text tokens only).
+learned indexer selects; text tokens only) and ``lfm2_moe`` (LFM2-MoE,
+LiquidAI; ``config.json`` of LFM2-24B-A2B: gated short convolutions 3 : 1
+with grouped-query attention on heads of 64, leading dense SwiGLU layers,
+then routed experts behind a biased sigmoid router, a tied head).
 
 Pre-norm residual layers, RMSNorm everywhere (``qwen3_next``: zero-centred,
 n(x) = x / rms(x) * (1 + w), but for the delta rule's output norm):
@@ -69,6 +72,13 @@ n(x) = x / rms(x) * (1 + w), but for the delta rule's output norm):
   KL(p[t, .] || softmax_{S_t} I[t, .]), whose gradient reaches the
   indexer's leaves alone, as the next-token loss reaches every leaf but
   them; no gradient crosses the selection (``ops/sparse_index.py``).
+- **Gated short convolution** (``conv``): [B ; C ; u] = W_in x, three
+  blocks of ``hidden_size`` in that order; z = B * u; c_t = sum_j k_j *
+  z_{t - (W-1) + j}, depthwise and causal, W = ``conv_L_cache``, no bias;
+  y = W_out (C * c). No activation. The chain between the two matmuls is
+  ``ops/mixer_chains.py gated_short_conv`` on a TPU, the plain XLA chain
+  elsewhere. ``lfm2_moe``'s ``full_attn`` layers are GQA with n_q, n_k on
+  every q and k head and the whole head rotated, no window.
 - **FFN**: ``kimi_linear``: SwiGLU of ``intermediate_size`` in the first
   ``first_k_dense_replace`` layers; after them the routed experts this
   shard holds (``ops/ffn.py RoutedExpertsFFN``, sigmoid router) plus
@@ -84,10 +94,15 @@ n(x) = x / rms(x) * (1 + w), but for the delta rule's output norm):
   experts' even share (the recipe's ``lm.expert_rows_factor``).
   ``keye_vl2``: every layer routed, SwiGLU experts, softmax over the
   chosen logits of a router that reads n2(x'), no shared expert.
+  ``lfm2_moe``: SwiGLU of ``intermediate_size`` in the first
+  ``num_dense_layers`` layers, then routed SwiGLU experts under Kimi's
+  sigmoid rule with w = s_sel / (sum(s_sel) + 1e-6), no shared expert.
 
 The vocabulary may be a slice (``vocab_size`` rows of the published
 table): ids, logits and the loss are over the slice. Embedding and head
-are untied. The loss is the mean next-token cross-entropy, float32, the
+are untied, but in ``lfm2_moe`` (``tie_word_embeddings``), whose logits
+are n(x) E^T of the embedding table E: ONE leaf, its gradient the sum of
+both uses. The loss is the mean next-token cross-entropy, float32, the
 head applied a block of tokens at a time so that the ``[tokens, vocab]``
 logits never exist whole.
 
@@ -96,7 +111,8 @@ The step's phases (``utils.STEP_PHASES``): ``lm_embed``, ``kda_mixer``
 (inner ``gqa_core``), ``gdn_mixer`` (inner ``gdn_core``),
 ``gated_attn_mixer`` (inner ``gqa_core``), ``dsa_mixer`` (inner
 ``dsa_index``: the indexer's projections and score planes, ``dsa_select``:
-the thresholds, ``dsa_core``, ``dsa_index_loss``), ``dense_ffn``, ``moe_ffn`` (inner
+the thresholds, ``dsa_core``, ``dsa_index_loss``), ``sconv_mixer`` (inner ``sconv_chain``: the kernel
+pair or the plain chain, nothing else), ``dense_ffn``, ``moe_ffn`` (inner
 ``moe_route``, ``moe_experts`` from the routed layer, ``moe_shared``: the
 shared expert, with its gate where it has one), ``lm_head_loss``.
 """
@@ -119,6 +135,7 @@ from dinov3_tpu.ops.mixer_chains import (
     IN_ORDER,
     conv_silu_norm,
     gated_rms_norm,
+    gated_short_conv,
     log_decay,
     mixer_chain_path,
 )
@@ -190,6 +207,11 @@ class DecoderConfig:
     index_head_dim: int = 0
     index_topk: int = 0
     index_chunk: int = 0               # queries a strip of the index planes
+    # lfm2_moe
+    full_attn_rotary: bool = False     # a "full_attn" layer rotates q and k
+    attn_qk_norm: bool = False         # ... and norms every q and k head
+    router_norm_eps: float = 0.0       # the sigmoid rule's normaliser
+    tie_word_embeddings: bool = False  # the head is the embedding table
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     reduce_dtype: Any = jnp.float32
@@ -202,7 +224,8 @@ class DecoderConfig:
         family = {"kimi_linear": _kimi_linear_fields,
                   "smallthinker": _smallthinker_fields,
                   "qwen3_next": _qwen3_next_fields,
-                  "keye_vl2": _keye_vl2_fields}[str(cfg.student.arch)]
+                  "keye_vl2": _keye_vl2_fields,
+                  "lfm2_moe": _lfm2_moe_fields}[str(cfg.student.arch)]
         return cls(dtype=policy.compute_dtype,
                    param_dtype=param_dtype or policy.param_dtype,
                    reduce_dtype=policy.reduce_dtype, **family(cfg.lm))
@@ -326,6 +349,45 @@ def _keye_vl2_fields(lm) -> dict:
         moe_intermediate_size=lm.moe_intermediate_size,
         expert_shards=lm.expert_shards, expert_shard=lm.expert_shard,
         router="softmax", gate="silu")
+
+
+LFM2_ROUTER_EPS = 1e-6  # the public implementation's, added to the sum
+
+
+def _lfm2_moe_fields(lm) -> dict:
+    if not bool(lm.norm_topk_prob) or not bool(lm.use_expert_bias):
+        raise ValueError("the routed layer is a renormalised sigmoid router "
+                         "with a selection bias (lm.norm_topk_prob, "
+                         "lm.use_expert_bias)")
+    if bool(lm.conv_bias):
+        raise ValueError("lm.conv_bias: only false")
+    depth, kinds = int(lm.num_hidden_layers), list(lm.layer_types)
+    if len(kinds) != depth or set(kinds) - {"conv", "full_attention"}:
+        raise ValueError(
+            f"lm.layer_types must name each of the {depth} layers conv or "
+            f"full_attention: {kinds}")
+    heads = int(lm.num_attention_heads)
+    if int(lm.hidden_size) % heads:
+        raise ValueError(f"lm.hidden_size {lm.hidden_size} on {heads} heads")
+    return dict(
+        layers=tuple(("conv" if kind == "conv" else "full_attn",
+                      "dense" if i < int(lm.num_dense_layers) else "moe")
+                     for i, kind in enumerate(kinds)),
+        hidden_size=lm.hidden_size, vocab_size=lm.vocab_size,
+        rms_norm_eps=lm.norm_eps, intermediate_size=lm.intermediate_size,
+        short_conv_kernel_size=lm.conv_L_cache,
+        num_attention_heads=heads,
+        num_key_value_heads=lm.num_key_value_heads,
+        head_dim=int(lm.hidden_size) // heads,
+        rope_theta=float(lm.rope_parameters.rope_theta),
+        full_attn_rotary=True, attn_qk_norm=True,
+        num_experts=lm.num_experts,
+        num_experts_per_token=lm.num_experts_per_tok,
+        moe_intermediate_size=lm.moe_intermediate_size,
+        routed_scaling_factor=float(lm.routed_scaling_factor),
+        router_norm_eps=LFM2_ROUTER_EPS,
+        expert_shards=lm.expert_shards, expert_shard=lm.expert_shard,
+        tie_word_embeddings=True, router="sigmoid", gate="silu")
 
 
 def _dense(features: int, axes, name: str, dtype, param_dtype) -> nn.Dense:
@@ -566,6 +628,49 @@ class GDNMixer(nn.Module):
         return _dense(x.shape[-1], ("heads", "embed"), "o_proj", **kw)(o)
 
 
+def conv_taps_init(key, shape, dtype=jnp.float32):
+    """Uniform on +-1/sqrt(W): a depthwise ``Conv1d``'s default."""
+    bound = shape[0] ** -0.5
+    return jax.random.uniform(key, shape, jnp.float32, -bound, bound
+                              ).astype(dtype)
+
+
+class ShortConvMixer(nn.Module):
+    """y = W_out (C * conv(B * u)), [B ; C ; u] = W_in x (the module's
+    docstring, **Gated short convolution**). The chain between the two
+    matmuls keeps its bfloat16 input plane for the backward and nothing
+    else, on either path (``KDAMixer``'s words)."""
+
+    conv_size: int = 3
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+    chains_interpret: bool | None = None   # tests: ops/mixer_chains.py's
+
+    @nn.compact
+    def __call__(self, x):
+        t, c = x.shape[1:]
+        kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
+        plane = _dense(3 * c, ("embed", "heads"), "in_proj", **kw)(
+            x.astype(self.dtype))
+        taps = self.param("conv", part(conv_taps_init, (None, "heads")),
+                          (self.conv_size, c), self.param_dtype)
+        fused = mixer_chain_path(
+            t, (c,), (), self.dtype, interpret=self.chains_interpret
+        )[0] == "kernel"
+
+        @jax.checkpoint
+        def plain_chain(plane, taps):
+            gate, mid, u = (plane[..., i * c:(i + 1) * c].astype(jnp.float32)
+                            for i in range(3))
+            return (mid * causal_depthwise_conv(
+                gate * u, taps.astype(jnp.float32))).astype(self.dtype)
+
+        with jax.named_scope("sconv_chain"):
+            y = (gated_short_conv(plane, taps, interpret=self.chains_interpret)
+                 if fused else plain_chain(plane, taps))
+        return _dense(c, ("heads", "embed"), "out_proj", **kw)(y)
+
+
 class MLAMixer(nn.Module):
     num_heads: int
     kv_lora_rank: int
@@ -742,7 +847,7 @@ class DSAMixer(nn.Module):
 
 class DecoderLayer(nn.Module):
     mixer: str                 # "kda" | "mla" | "swa" | "full_attn" | "gdn"
-                               # | "gated_attn" | "dsa"
+                               # | "gated_attn" | "dsa" | "conv"
     ffn: str                   # "dense" | "moe"
     cfg: Any                   # the frozen ``DecoderConfig``
     keep_selection: bool = False   # "dsa": the selection among the aux
@@ -787,17 +892,25 @@ class DecoderLayer(nn.Module):
                     self.keep_selection, reduce_dtype=c.reduce_dtype,
                     name="attn", **kw)(norm("norm1")(x))
                 x = x + y.astype(x.dtype)
+        elif self.mixer == "conv":
+            with step_phase("sconv_mixer"):
+                y = ShortConvMixer(c.short_conv_kernel_size, name="conv",
+                                   **kw)(norm("norm1")(x))
+                x = x + y.astype(x.dtype)
         else:
-            # "swa": a window and rotary; "full_attn": neither; "gated_attn":
-            # no window, a partial rotary, an output gate and the layer's
-            # kind of norm on q and k
+            # "swa": a window and rotary; "full_attn": neither (``lfm2_moe``:
+            # a rotary and normed q and k heads); "gated_attn": no window, a
+            # partial rotary, an output gate and the layer's kind of norm on
+            # q and k
             gated = self.mixer == "gated_attn"
+            plain = self.mixer == "full_attn" and not c.full_attn_rotary
             with step_phase(f"{self.mixer}_mixer"):
                 y = GQAMixer(
                     c.num_attention_heads, c.num_key_value_heads, c.head_dim,
                     c.sliding_window if self.mixer == "swa" else None,
-                    None if self.mixer == "full_attn" else c.rope_theta,
-                    c.rotary_dim or None, gated, norm if gated else None,
+                    None if plain else c.rope_theta,
+                    c.rotary_dim or None, gated,
+                    norm if gated or c.attn_qk_norm else None,
                     reduce_dtype=c.reduce_dtype, name="attn", **kw)(
                         norm("norm1")(x))
                 x = x + y.astype(x.dtype)
@@ -813,7 +926,8 @@ class DecoderLayer(nn.Module):
                     c.moe_intermediate_size, c.num_experts,
                     c.num_experts_per_token, c.expert_shards, c.expert_shard,
                     c.routed_scaling_factor, c.expert_rows_factor,
-                    router=c.router, gate=c.gate, name="experts", **kw)(
+                    router=c.router, gate=c.gate,
+                    norm_eps=c.router_norm_eps, name="experts", **kw)(
                         y, x_in if c.router_reads_layer_input else None)
                 if c.num_shared_experts:
                     with jax.named_scope("moe_shared"):
@@ -869,7 +983,8 @@ class LMDecoder(nn.Module):
                 auxes.append(aux)
         aux = ({k: jnp.stack([a[k] for a in auxes]) for k in auxes[0]}
                if auxes else {})
-        head = self.param(
+        # tied: the head IS the embedding table, one leaf read twice
+        head = table.T if c.tie_word_embeddings else self.param(
             "lm_head", part(trunc_normal_init(), ("embed", "vocab")),
             (c.hidden_size, c.vocab_size), c.param_dtype)
         with step_phase("lm_head_loss"):

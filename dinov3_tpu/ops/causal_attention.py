@@ -95,6 +95,7 @@ BACKWARD_KERNEL_NAME = "causal_attn_bwd"
 INDEX_LOSS_KERNEL_NAME = "index_loss_value"
 INDEX_LOSS_GRAD_KERNEL_NAME = "index_loss_grad"
 _LANES = 128
+_HALF = 64     # the head width that goes two heads a lane group
 _NEG = -1e30
 # what the backward may hold of one key/value head's dk and dv: the
 # float32 accumulators and the (double-buffered) blocks they leave in
@@ -157,13 +158,51 @@ def _both_bodies(run, edge, tile):
         functools.partial(tile, False))
 
 
+def _placed(ref, j, per_kv):
+    """Head ``j`` of a block of 64-wide heads ([rows, heads * 64], two
+    heads a 128-lane group), alone in a 128-lane plane: in the half its
+    key/value head has in the pair's block (``j // per_kv``: lanes 0..63
+    or 64..127), zeros in the other. A product with the pair's keys or
+    values over all 128 lanes is then the product with that one head's."""
+    x = ref[:, (j // 2) * _LANES:(j // 2 + 1) * _LANES].astype(jnp.float32)
+    upper = j // per_kv == 1
+    if upper != bool(j % 2):
+        x = pltpu.roll(x, _HALF, 1)
+    there = _iota(x.shape, 1) >= _HALF
+    return jnp.where(there if upper else jnp.logical_not(there), x, 0.0).astype(
+        ref.dtype)
+
+
+def _unplaced(ref, planes, per_kv):
+    """``_placed`` undone: ``planes(j)`` [rows, 128] float32 holds head
+    j's result in its key/value head's half; two heads a lane group go
+    back into ``ref`` as they lie in HBM."""
+    def moved(j):
+        x = planes(j)
+        return x if (j // per_kv == 1) == bool(j % 2) else pltpu.roll(x, _HALF, 1)
+
+    for m in range(ref.shape[-1] // _LANES):
+        low, high = moved(2 * m), moved(2 * m + 1)
+        ref[:, m * _LANES:(m + 1) * _LANES] = jnp.where(
+            _iota(low.shape, 1) < _HALF, low, high).astype(ref.dtype)
+
+
 def _fwd_kernel(*refs, scale, group, block_q, block_kv, window, keep_lse,
-                selected=False):
+                selected=False, packed=False):
     """q_ref [block_q, g * d], k_ref [block_kv, d], v_ref [block_kv, dv],
     (``selected``: sel_ref [block_q, block_kv] int8,) o_ref [block_q,
     g * dv], lse_ref [g, block_q]; scratch: the group's heads stacked
     [g, block_q, d], the running maximum and sum [g, block_q, 128] (every
-    lane the same) and the accumulator [g, block_q, dv]."""
+    lane the same) and the accumulator [g, block_q, dv].
+
+    ``packed``: heads of 64. A grid step is then a PAIR of key/value heads
+    (k_ref and v_ref [block_kv, 128]: the pair side by side, one lane
+    group) and the ``group`` = 2 g query heads that read them (q_ref
+    [block_q, group * 64]); the scratch planes are 128 wide, a head's q
+    stands alone in its key/value head's half (``_placed``), and of a
+    head's accumulator that half is its output, the other a product
+    nobody reads. A tile's body is the same: a 128 x 128 MXU pass does a
+    64-deep contraction at half its rate, zeros or no zeros."""
     sel_ref = None
     if selected:
         sel_ref, refs = refs[3], refs[:3] + refs[4:]
@@ -178,7 +217,8 @@ def _fwd_kernel(*refs, scale, group, block_q, block_kv, window, keep_lse,
     @pl.when(t == 0)
     def _():
         for j in range(group):
-            q_scr[j] = q_ref[:, j * d:(j + 1) * d]
+            q_scr[j] = (_placed(q_ref, j, group // 2) if packed
+                        else q_ref[:, j * d:(j + 1) * d])
         m_scr[...] = jnp.full_like(m_scr, _NEG)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
@@ -208,24 +248,33 @@ def _fwd_kernel(*refs, scale, group, block_q, block_kv, window, keep_lse,
 
     @pl.when(t == count - 1)
     def _():
+        if packed:
+            _unplaced(o_ref, lambda j: acc_scr[j] / l_scr[j], group // 2)
         for j in range(group):
             total = l_scr[j]
-            o_ref[:, j * dv:(j + 1) * dv] = (
-                acc_scr[j] / pltpu.repeat(total, dv // _LANES, axis=1)
-            ).astype(o_ref.dtype)
+            if not packed:
+                o_ref[:, j * dv:(j + 1) * dv] = (
+                    acc_scr[j] / pltpu.repeat(total, dv // _LANES, axis=1)
+                ).astype(o_ref.dtype)
             if keep_lse:  # a column of [block_q, 128] laid down as a row
                 lse_ref[j:j + 1, :] = (m_scr[j] + jnp.log(total)).T[:1]
 
 
 def _bwd_kernel(*refs, scale, group, block_q, block_kv, window,
-                selected=False):
+                selected=False, packed=False):
     """The forward's blocks, do_ref like o_ref, delta_ref (the rows'
     sum(o * do)) like lse_ref (``selected``: then sel_ref [block_q,
     block_kv] int8, the forward's own block, turned once a tile into the
     last scratch, [block_kv, block_q] float32); dq_ref like q_ref; dk_ref [N, d] and
     dv_ref [N, dv], one key/value head's whole sequence. Scratch: q and
     do stacked a head, dq [g, block_q, d], dk [N, d] and dv [N, dv], all
-    three float32.
+    three float32. ``packed`` as the forward's: q and do of a head stand
+    alone in their key/value head's half of a 128-lane plane, so dk and
+    dv of the pair [N, 128] take each head's part where that key/value
+    head lies, and of dq's plane the same half is the head's; and o_ref
+    (like do_ref) stands where delta_ref stood: the rows' sum(o * do) over
+    64 of 128 lanes is made here, into one more scratch [g, block_q] (XLA
+    makes it of a whole float32 plane and a copy of it).
 
     With s^T = k q^T (keys in the rows), p^T = exp(s^T - lse),
     dp^T = v do^T and ds^T = p^T (dp^T - delta) scale:
@@ -235,8 +284,12 @@ def _bwd_kernel(*refs, scale, group, block_q, block_kv, window,
     sel_ref = kept_scr = None
     if selected:
         sel_ref, kept_scr, refs = refs[6], refs[-1], refs[:6] + refs[7:-1]
-    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
-     q_scr, do_scr, dq_scr, dk_scr, dv_scr) = refs
+    if packed:  # o in delta's place; the rows' sum(o * do) is made here
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref, dq_ref, dk_ref, dv_ref,
+         q_scr, do_scr, dq_scr, dk_scr, dv_scr, delta_ref) = refs
+    else:
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref,
+         dv_ref, q_scr, do_scr, dq_scr, dk_scr, dv_scr) = refs
     d, dv = q_scr.shape[-1], v_ref.shape[-1]
     run, edge, off, at, count = _tile_place(block_q, block_kv, window)
     i, t = pl.program_id(2), pl.program_id(3)
@@ -250,8 +303,22 @@ def _bwd_kernel(*refs, scale, group, block_q, block_kv, window,
     @pl.when(t == 0)
     def _():
         for j in range(group):
-            q_scr[j] = q_ref[:, j * d:(j + 1) * d]
-            do_scr[j] = do_ref[:, j * dv:(j + 1) * dv]
+            if packed:
+                q_scr[j] = _placed(q_ref, j, group // 2)
+                do_scr[j] = _placed(do_ref, j, group // 2)
+                if j % 2 == 0:   # a lane group's two heads: sum(o * do) a row
+                    lanes = slice(j // 2 * _LANES, (j // 2 + 1) * _LANES)
+                    both = (o_ref[:, lanes].astype(jnp.float32)
+                            * do_ref[:, lanes].astype(jnp.float32))
+                    low = _iota(both.shape, 1) < _HALF
+                    for at, half in ((j, low), (j + 1, jnp.logical_not(low))):
+                        total = jnp.sum(jnp.where(half, both, 0.0), axis=1,
+                                        keepdims=True)
+                        delta_ref[at:at + 1, :] = jnp.broadcast_to(
+                            total, both.shape).T[:1]
+            else:
+                q_scr[j] = q_ref[:, j * d:(j + 1) * d]
+                do_scr[j] = do_ref[:, j * dv:(j + 1) * dv]
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
     def tile(masked):
@@ -280,6 +347,9 @@ def _bwd_kernel(*refs, scale, group, block_q, block_kv, window,
 
     @pl.when(t == count - 1)
     def _():
+        if packed:
+            _unplaced(dq_ref, lambda j: dq_scr[j], group // 2)
+            return
         for j in range(group):
             dq_ref[:, j * d:(j + 1) * d] = dq_scr[j].astype(dq_ref.dtype)
 
@@ -289,15 +359,26 @@ def _bwd_kernel(*refs, scale, group, block_q, block_kv, window,
         dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _operands(q, k, block_q, block_kv, window):
+def _packs(d: int, dv: int, hk: int) -> bool:
+    """Heads of 64 + 64 go two key/value heads a lane group."""
+    return d == dv == _HALF and hk % 2 == 0
+
+
+def _operands(q, k, block_q, block_kv, window, dv=None):
     """(q and k widened to the lane tile, the grid, the block of a
     [B, N, h * w] array of query heads, the block of a [B, N, hk * w]
     array of keys or values along the band, the block of a [B, hk, g, N]
     array of row statistics, the [block_q, block_kv] block of a [B, N, N]
-    selection along the band)."""
+    selection along the band, the query heads a grid step and how many
+    key/value heads a step holds: 1 or 2). Heads of 64 + 64 (``_packs``): nothing is widened, a
+    grid step is a PAIR of key/value heads, one 128-lane block of the
+    keys as they lie, and the 2 g query heads that read them; the row
+    statistics are then [B, hk / 2, 2 g, N], the same bytes."""
     b, n, h, d = q.shape
     hk = k.shape[2]
-    g, pad = h // hk, (-d) % _LANES
+    packed = _packs(d, dv, hk)
+    pack = 2 if packed else 1
+    g, pad = h // hk * pack, 0 if packed else (-d) % _LANES
     if pad:
         q, k = (jnp.pad(x, ((0, 0),) * 3 + ((0, pad),)) for x in (q, k))
     blocks = n // block_q
@@ -312,14 +393,14 @@ def _operands(q, k, block_q, block_kv, window):
         (None, block_q, g * w), lambda s, kh, i, t: (s, i, kh),
         memory_space=vmem)
     keys = lambda w: pl.BlockSpec(  # noqa: E731
-        (None, block_kv, w), band_tile, memory_space=vmem)
+        (None, block_kv, w * pack), band_tile, memory_space=vmem)
     stats = pl.BlockSpec((None, None, g, block_q),
                          lambda s, kh, i, t: (s, kh, 0, i), memory_space=vmem)
 
     pair = pl.BlockSpec(
         (None, block_q, block_kv),
         lambda s, kh, i, t: (s, i, band_tile(s, kh, i, t)[1]), memory_space=vmem)
-    return q, k, (b, hk, blocks, steps), rows, keys, stats, pair
+    return q, k, (b, hk // pack, blocks, steps), rows, keys, stats, pair, g, pack
 
 
 # A ``pallas_call`` traces its kernel body every time it is called, and a
@@ -335,35 +416,37 @@ def _kernel_forward(q, k, v, scale, window, block_q, block_kv, keep_lse,
     None) as one ``pallas_call``."""
     b, n, h, _ = q.shape
     hk, dv = v.shape[2], v.shape[3]
-    g = h // hk
-    q, k, grid, rows, keys, stats, pair = _operands(
-        q, k, block_q, block_kv, window)
+    q, k, grid, rows, keys, stats, pair, g, pack = _operands(
+        q, k, block_q, block_kv, window, dv)
     d = q.shape[-1]
+    packed, wide = pack == 2, pack * d    # (the scratch planes' width)
     flat = lambda x: x.reshape(b, n, -1)  # noqa: E731
     chosen = () if selection is None else (selection,)
     out_specs = [rows(dv)]
     out_shape = [jax.ShapeDtypeStruct((b, n, h * dv), v.dtype)]
     if keep_lse:
         out_specs.append(stats)
-        out_shape.append(jax.ShapeDtypeStruct((b, hk, g, n), jnp.float32))
+        out_shape.append(jax.ShapeDtypeStruct((b, grid[1], g, n), jnp.float32))
     out = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, group=g, block_q=block_q,
                           block_kv=block_kv, window=window, keep_lse=keep_lse,
-                          **({"selected": True} if chosen else {})),
+                          **({"selected": True} if chosen else {}),
+                          **({"packed": True} if packed else {})),
         grid=grid,
         in_specs=[rows(d), keys(d), keys(dv)] + [pair] * len(chosen),
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((g, block_q, d), q.dtype),
+            pltpu.VMEM((g, block_q, wide), q.dtype),
             pltpu.VMEM((g, block_q, _LANES), jnp.float32),
             pltpu.VMEM((g, block_q, _LANES), jnp.float32),
-            pltpu.VMEM((g, block_q, dv), jnp.float32)],
+            pltpu.VMEM((g, block_q, wide if packed else dv), jnp.float32)],
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
         name=KERNEL_NAME,
     )(flat(q), flat(k), flat(v), *chosen)
-    return out[0].reshape(b, n, h, dv), (out[1] if keep_lse else None)
+    return (out[0].reshape(b, n, h, dv),
+            out[1].reshape(b, hk, h // hk, n) if keep_lse else None)
 
 
 @functools.partial(jax.jit, inline=True, static_argnames=(
@@ -374,34 +457,43 @@ def _kernel_backward(q, k, v, o, lse, do, scale, window, block_q, block_kv,
     ``pallas_call`` from what the forward rule kept."""
     b, n, h, width = q.shape
     hk, dv = v.shape[2], v.shape[3]
-    g = h // hk
-    # a product would round its float32 operands on the TPU: multiply, add
-    delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
-    delta = jnp.swapaxes(delta, 1, 2).reshape(b, hk, g, n)
-    qp, kp, grid, rows, keys, stats, pair = _operands(
-        q, k, block_q, block_kv, window)
-    chosen = () if selection is None else (selection,)
+    packed = _packs(width, dv, hk)
+    if not packed:
+        # a product would round its float32 operands on the TPU: multiply, add
+        delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
+        delta = jnp.swapaxes(delta, 1, 2).reshape(b, hk, h // hk, n)
+    qp, kp, grid, rows, keys, stats, pair, g, pack = _operands(
+        q, k, block_q, block_kv, window, dv)
     d = qp.shape[-1]
+    wide = pack * d
     flat = lambda x: x.reshape(b, n, -1)  # noqa: E731
+    delta_spec = stats
+    if packed:  # the kernel makes the rows' sum(o * do), of o
+        delta, delta_spec = flat(o), rows(dv)
+    lse = lse.reshape(b, grid[1], g, n)
+    chosen = () if selection is None else (selection,)
     whole = lambda w: pl.BlockSpec(  # noqa: E731
-        (None, n, w), lambda s, kh, i, t: (s, 0, kh), memory_space=pltpu.VMEM)
+        (None, n, w * pack), lambda s, kh, i, t: (s, 0, kh),
+        memory_space=pltpu.VMEM)
     dq, dk, dv_ = pl.pallas_call(
         functools.partial(_bwd_kernel, scale=scale, group=g, block_q=block_q,
                           block_kv=block_kv, window=window,
-                          **({"selected": True} if chosen else {})),
+                          **({"selected": True} if chosen else {}),
+                          **({"packed": True} if packed else {})),
         grid=grid,
-        in_specs=[rows(d), keys(d), keys(dv), rows(dv), stats, stats]
+        in_specs=[rows(d), keys(d), keys(dv), rows(dv), stats, delta_spec]
         + [pair] * len(chosen),
         out_specs=[rows(d), whole(d), whole(dv)],
         out_shape=[jax.ShapeDtypeStruct((b, n, h * d), q.dtype),
                    jax.ShapeDtypeStruct((b, n, hk * d), k.dtype),
                    jax.ShapeDtypeStruct((b, n, hk * dv), v.dtype)],
         scratch_shapes=[
-            pltpu.VMEM((g, block_q, d), q.dtype),
-            pltpu.VMEM((g, block_q, dv), do.dtype),
-            pltpu.VMEM((g, block_q, d), jnp.float32),
-            pltpu.VMEM((n, d), jnp.float32),
-            pltpu.VMEM((n, dv), jnp.float32)]
+            pltpu.VMEM((g, block_q, wide), q.dtype),
+            pltpu.VMEM((g, block_q, wide if packed else dv), do.dtype),
+            pltpu.VMEM((g, block_q, wide), jnp.float32),
+            pltpu.VMEM((n, wide), jnp.float32),
+            pltpu.VMEM((n, wide if packed else dv), jnp.float32)]
+        + [pltpu.VMEM((g, block_q), jnp.float32)] * packed
         + [pltpu.VMEM((block_kv, block_q), jnp.float32)] * len(chosen),
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
@@ -739,22 +831,27 @@ def causal_attention_path(shapes, window: int | None = None,
     """(path, why) ``causal_blockwise_attention`` takes for q, k, v of
     these three ``shapes`` and this one ``dtype`` on this backend:
     ("kernel", ...) or ("tiles", the reason it is not the kernel)."""
-    (_, n, _, d), _, (_, _, _, dv) = shapes
+    (_, n, _, d), (_, _, hk, _), (_, _, _, dv) = shapes
     if dtype not in (jnp.bfloat16, jnp.float32):
         return "tiles", f"{jnp.dtype(dtype).name} is neither bfloat16 nor float32"
     if reduce_dtype != jnp.float32:
         return "tiles", (f"statistics in {jnp.dtype(reduce_dtype).name}: the "
                          "kernels' are float32")
-    if dv % _LANES:
-        return "tiles", f"the value width {dv} is not a multiple of {_LANES}"
+    packed = _packs(d, dv, hk)
+    if dv % _LANES and not packed:
+        return "tiles", (
+            f"the value width {dv} is not a multiple of {_LANES}, nor are the "
+            f"heads {_HALF} + {_HALF} wide on an even number of key/value "
+            f"heads ({d} + {dv} on {hk})")
     if block_q % _LANES or block_kv % _LANES:
         return "tiles", (f"blocks of {block_q} x {block_kv} are not "
                          f"multiples of {_LANES}")
     if n % block_q or n % block_kv:
         return "tiles", (f"{n} tokens are not whole blocks of {block_q} "
                          f"queries and {block_kv} keys")
-    wide = d + (-d) % _LANES
-    resident = n * (wide + dv) * (4 + 2 * jnp.dtype(dtype).itemsize)
+    # (heads of 64: a PAIR of key/value heads is resident, 128 + 128)
+    wide, wide_v = (_LANES, _LANES) if packed else (d + (-d) % _LANES, dv)
+    resident = n * (wide + wide_v) * (4 + 2 * jnp.dtype(dtype).itemsize)
     if resident > _RESIDENT_BYTES:
         return "tiles", (f"dk and dv of {n} tokens ({resident >> 20} MiB) "
                          "do not fit the backward's VMEM")

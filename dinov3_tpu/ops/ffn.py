@@ -244,7 +244,8 @@ class RoutedExpertsFFN(nn.Module):
     the layer's result that those give.
 
         router "sigmoid": s = sigmoid(W_r x_r) (float32); the top_k
-            largest of s + bias; w = scale * s_sel / sum(s_sel);
+            largest of s + bias; w = scale * s_sel / (sum(s_sel) +
+            ``norm_eps``) (0 where a family's normaliser has none);
         router "softmax": r = W_r x_r (float32); the top_k largest of r;
             w = scale * softmax(r_sel) (= the softmax over all experts,
             renormalised over the chosen ones); no bias exists;
@@ -287,6 +288,7 @@ class RoutedExpertsFFN(nn.Module):
     param_dtype: Any = jnp.float32
     router: str = "sigmoid"   # | "softmax"
     gate: str = "silu"        # | "relu"
+    norm_eps: float = 0.0     # "sigmoid": added to the chosen scores' sum
 
     @nn.compact
     def __call__(self, x: jnp.ndarray, router_input: jnp.ndarray | None = None):
@@ -331,7 +333,11 @@ class RoutedExpertsFFN(nn.Module):
                 _, choice = jax.lax.top_k(
                     s + jax.lax.stop_gradient(bias.astype(jnp.float32)), K)
                 s_sel = jnp.take_along_axis(s, choice, axis=-1)
-                w = self.scale * s_sel / jnp.sum(s_sel, axis=-1, keepdims=True)
+                if self.norm_eps:
+                    w = self.scale * s_sel / (
+                        jnp.sum(s_sel, axis=-1, keepdims=True) + self.norm_eps)
+                else:
+                    w = self.scale * s_sel / jnp.sum(s_sel, axis=-1, keepdims=True)
             else:
                 _, choice = jax.lax.top_k(s, K)
                 w = self.scale * jax.nn.softmax(

@@ -9,7 +9,7 @@ of a ``[B, T, C]`` block and every H-th row of a ``[B, T * H, d]`` block,
 read and written by strided loads and stores (bfloat16 a pair of heads a
 32-bit word, ``ops/kda.py _pair_planes`` / ``_store_pair``).
 
-Three chains, shared by ``models/decoder.py KDAMixer`` and ``GDNMixer``
+Three chains shared by ``models/decoder.py KDAMixer`` and ``GDNMixer``
 (which differ in what a kernel is GIVEN: which lane group of the input
 holds which head, which outputs are normalised, eps, the gate's
 activation, the convolution's width). A kernel's body is ONE pair of
@@ -29,6 +29,16 @@ pairs the bodies cost 15 s of a warm set-up's tracing (``PERF.md``, PR 38):
   ``[B, T, H * d]`` for the output projection.
 - ``log_decay``: g = -exp(A_log) softplus(f + dt_bias), bfloat16
   ``[B, T, H * d]`` to the float32 ``[B, T, H, d]`` the delta rule reads.
+
+and a fourth, ``ShortConvMixer``'s whole sequence mixing, with no heads
+and no relayout (planes ``[B, T, channels]`` in and out), on the same
+block / tail / taps-gradient machinery:
+
+- ``gated_short_conv``: y = C * conv(B * u), the causal depthwise
+  convolution of width W between two multiplicative gates and no
+  activation, [B ; C ; u] the three lane groups of ONE ``[B, T, 3 C]``
+  plane read where they lie; the backward writes [dB ; dC ; du] as one
+  such plane.
 
 Each is a ``jax.custom_vjp`` whose residuals are the chain's INPUTS and
 nothing else (what the plain chains' ``jax.checkpoint`` keeps); the
@@ -68,6 +78,8 @@ NORM_KERNEL_NAME = "gated_rms_norm_fwd"
 NORM_BACKWARD_KERNEL_NAME = "gated_rms_norm_bwd"
 DECAY_KERNEL_NAME = "log_decay_fwd"
 DECAY_BACKWARD_KERNEL_NAME = "log_decay_bwd"
+GATED_CONV_KERNEL_NAME = "gated_short_conv_fwd"
+GATED_CONV_BACKWARD_KERNEL_NAME = "gated_short_conv_bwd"
 # a step holds its blocks twice over (the pipeline's two buffers): 4 MB a
 # 128 x 8192 bfloat16 plane and its gradient, past what a kernel gets unasked
 _COMPILER_PARAMS = pltpu.CompilerParams(
@@ -82,7 +94,8 @@ def mixer_chain_path(length: int, head_dims, heads, dtype,
     """(path, why) the chains of a mixer take: ("kernel", ...) or
     ("plain", the reason it is not the kernels). ``head_dims`` and
     ``heads``: the head widths (key, value) and the head counts of the
-    ``[B, T, H, d]`` planes the chains read or write."""
+    ``[B, T, H, d]`` planes the chains read or write (``gated_short_conv``,
+    which has no heads: its channels as one width, no head count)."""
     if jnp.dtype(dtype) != jnp.bfloat16:
         return "plain", f"the planes are {jnp.dtype(dtype).name}, not bfloat16"
     if len(set(head_dims)) != 1 or head_dims[0] % LANES:
@@ -381,6 +394,161 @@ def conv_silu_norm(x, kernels, layouts, normalise, head_dim: int,
     layouts = _checked(layouts, _head_counts(kernels, d), x.shape[-1] // d)
     return _conv_chain(x, tuple(kernels), layouts, tuple(map(bool, normalise)),
                        float(eps), d, int(block), bool(interpret))
+
+
+# --------------------------------------------- gated short convolution
+
+
+def _gconv_parts(x_ref, halo_b_ref, halo_u_ref, g, groups, starts):
+    """Of the 128 channels ``g`` of a [B ; C ; u] block, float32: B, u,
+    z = B * u [block, 128] and z's rows before the block [8, 128] (zeros
+    where the block ``starts`` its sequence)."""
+    gate = x_ref[0, :, _lanes(g, LANES)].astype(jnp.float32)
+    u = x_ref[0, :, _lanes(2 * groups + g, LANES)].astype(jnp.float32)
+    before = (halo_b_ref[0, :, _lanes(g, LANES)].astype(jnp.float32)
+              * halo_u_ref[0, :, _lanes(g, LANES)].astype(jnp.float32))[_EDGE:]
+    return gate, u, gate * u, jnp.where(starts, 0.0, before)
+
+
+def _each_group(groups: int, body):
+    jax.lax.fori_loop(0, groups, lambda g, c: (body(g), c)[1], 0)
+
+
+def _gconv_fwd_kernel(x_ref, halo_b_ref, halo_u_ref, w_ref, y_ref, *, groups,
+                      width):
+    starts = pl.program_id(1) == 0
+
+    def one(g):
+        cols = _lanes(g, LANES)
+        _, _, z, before = _gconv_parts(x_ref, halo_b_ref, halo_u_ref, g, groups,
+                                       starts)
+        c = sum(w_ref[j:j + 1, cols] * _shifted(z, before, width - 1 - j, True)
+                for j in range(width))
+        out = x_ref[0, :, _lanes(groups + g, LANES)].astype(jnp.float32) * c
+        y_ref[0, :, cols] = out.astype(y_ref.dtype)
+
+    _each_group(groups, one)
+
+
+def _gconv_bwd_kernel(x_ref, halo_b_ref, halo_u_ref, w_ref, dy_ref, dx_ref,
+                      dw_ref, carry_ref, *, groups, width, last_step):
+    """The blocks come LAST FIRST, as ``_conv_bwd_kernel``'s: ``carry_ref``
+    [8, C] holds the first rows of dc = dy * C of the block after this
+    one. With c = conv(z) made again:
+
+        dC = dy * c,   dz_t = sum_j k_j dc_{t + W - 1 - j},
+        dB = dz * u,   du = dz * B,   dk_j += sum_t dc_t z_{t - W + 1 + j}
+    """
+    first = pl.program_id(1) == 0            # the sequence's LAST block
+    starts = pl.program_id(1) == last_step
+    _first_step_zeros(dw_ref)
+
+    def one(g):
+        cols, mid = _lanes(g, LANES), _lanes(groups + g, LANES)
+        gate, u, z, before = _gconv_parts(x_ref, halo_b_ref, halo_u_ref, g,
+                                          groups, starts)
+        planes = [_shifted(z, before, width - 1 - j, True) for j in range(width)]
+        c = sum(w_ref[j:j + 1, cols] * planes[j] for j in range(width))
+        dy = dy_ref[0, :, cols].astype(jnp.float32)
+        dc = dy * x_ref[0, :, mid].astype(jnp.float32)
+        dx_ref[0, :, mid] = (dy * c).astype(dx_ref.dtype)
+        after = jnp.where(first, 0.0, carry_ref[:, cols])
+        carry_ref[:, cols] = dc[:_EDGE]
+        dz = sum(w_ref[j:j + 1, cols] * _shifted(dc, after, width - 1 - j, False)
+                 for j in range(width))
+        dx_ref[0, :, cols] = (dz * u).astype(dx_ref.dtype)
+        dx_ref[0, :, _lanes(2 * groups + g, LANES)] = (dz * gate).astype(
+            dx_ref.dtype)
+        for j in range(width):
+            dw_ref[j:j + 1, cols] += jnp.sum(dc * planes[j], axis=0,
+                                             keepdims=True)
+
+    _each_group(groups, one)
+
+
+def _gconv_operands(x, block, reverse):
+    """``_conv_operands`` for a [B ; C ; u] plane: the block of tokens, and
+    the 16 rows before it of B's and of u's third alone."""
+    b, t, c3 = x.shape
+    if block % _HALO:
+        raise ValueError(f"a time block of {block} is not whole tiles of {_HALO}")
+    n = t // block
+    at = (lambda m: n - 1 - m) if reverse else (lambda m: m)
+    per = block // _HALO
+    halo = lambda third: pl.BlockSpec(  # noqa: E731
+        (1, _HALO, c3 // 3),
+        lambda i, m: (i, jnp.maximum(at(m) * per - 1, 0), third),
+        memory_space=pltpu.VMEM)
+    return _tokens_spec(block, c3, n if reverse else None), halo(0), halo(2), n
+
+
+@functools.partial(jax.jit, inline=True, static_argnames=("block", "interpret"))
+def _gconv_forward(x, taps, block, interpret):
+    b, t, c3 = x.shape
+    c = c3 // 3
+    spec, halo_b, halo_u, n = _gconv_operands(x, block, reverse=False)
+    return pl.pallas_call(
+        functools.partial(_gconv_fwd_kernel, groups=c // LANES,
+                          width=taps.shape[0]),
+        grid=(b, n),
+        in_specs=[spec, halo_b, halo_u, _whole_spec(taps.shape)],
+        out_specs=_tokens_spec(block, c),
+        out_shape=jax.ShapeDtypeStruct((b, t, c), x.dtype),
+        compiler_params=_COMPILER_PARAMS,
+        interpret=interpret,
+        name=GATED_CONV_KERNEL_NAME,
+    )(x, x, x, taps.astype(jnp.float32))
+
+
+@functools.partial(jax.jit, inline=True, static_argnames=("block", "interpret"))
+def _gconv_backward(x, taps, dy, block, interpret):
+    b, t, c3 = x.shape
+    c = c3 // 3
+    spec, halo_b, halo_u, n = _gconv_operands(x, block, reverse=True)
+    dx, dw = pl.pallas_call(
+        functools.partial(_gconv_bwd_kernel, groups=c // LANES,
+                          width=taps.shape[0], last_step=n - 1),
+        grid=(b, n),
+        in_specs=[spec, halo_b, halo_u, _whole_spec(taps.shape),
+                  _tokens_spec(block, c, n)],
+        out_specs=[spec, _sums_spec(*taps.shape)],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
+                   jax.ShapeDtypeStruct((b,) + taps.shape, jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((_EDGE, c), jnp.float32)],
+        compiler_params=_COMPILER_PARAMS,
+        interpret=interpret,
+        name=GATED_CONV_BACKWARD_KERNEL_NAME,
+    )(x, x, x, taps.astype(jnp.float32), dy)
+    return dx, jnp.sum(dw, axis=0).astype(taps.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _gconv_chain(x, taps, block, interpret):
+    return _gconv_forward(x, taps, block=block, interpret=interpret)
+
+
+def _gconv_chain_fwd(x, taps, block, interpret):
+    return _gconv_chain(x, taps, block, interpret), (x, taps)
+
+
+def _gconv_chain_bwd(block, interpret, res, dy):
+    return _gconv_backward(*res, dy, block=block, interpret=interpret)
+
+
+_gconv_chain.defvjp(_gconv_chain_fwd, _gconv_chain_bwd)
+
+
+def gated_short_conv(x, taps, block: int = TIME_BLOCK,
+                     interpret: bool | None = None):
+    """y [B, T, C] in x's type: y = C * conv(B * u), [B ; C ; u] the three
+    thirds of x [B, T, 3 C] bfloat16 in that order, conv the causal
+    depthwise convolution under ``taps`` [W, C] (tap j reads the token
+    W - 1 - j before; nothing before a sequence's first token), float32
+    inside. T whole blocks, C whole lane groups of 128, W - 1 <= 8."""
+    if x.shape[-1] != 3 * taps.shape[1] or taps.shape[1] % LANES \
+            or taps.shape[0] - 1 > _EDGE:
+        raise ValueError(f"a plane {x.shape} under taps {taps.shape}")
+    return _gconv_chain(x, taps, int(block), bool(interpret))
 
 
 # ------------------------------------------------------ gated RMS norm

@@ -81,6 +81,11 @@ class LMMetaArch:
                 path, why = mixer_chain_path(rows[1], (dk, dv), heads, dc.dtype)
                 logger.info("layer %d %s_mixer's chains, both passes: %s (%s)",
                             i, mixer, path, why)
+            elif mixer == "conv":
+                path, why = mixer_chain_path(rows[1], (dc.hidden_size,), (),
+                                             dc.dtype)
+                logger.info("layer %d sconv_chain (conv), both passes: %s (%s)",
+                            i, path, why)
             else:
                 scope, shapes, window = cores[mixer]
                 path, why = causal_attention_path(

@@ -3,7 +3,8 @@
 (reference: dinov3_jax/train/param_groups.py — same semantics: ViT layerwise
 lr decay, patch-embed lr multiplier, DINO-head wd multiplier, zero wd for
 biases/norms/layerscale gammas (and a decoder's ``A_log``; its ``dt_bias``
-and ``router_bias`` are biases by name), last-layer (prototypes) freeze flag — but
+and ``router_bias`` are biases by name; a tied ``token_embed``, embedding and
+head in one leaf, decays as the matrix it is, under ONE rule), last-layer (prototypes) freeze flag — but
 emitted as *multiplier pytrees* consumed by one custom optax chain instead
 of string labels for ``optax.multi_transform``. This removes the reference's
 per-group adamw instances and their late-binding lr/wd closure bug

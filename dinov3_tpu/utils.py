@@ -561,6 +561,8 @@ STEP_PHASES = (
     "dsa_mixer",          # a grouped-query layer over the keys a learned
                           # indexer selects (inner: dsa_index, dsa_select,
                           # dsa_core, dsa_index_loss)
+    "sconv_mixer",        # a gated short convolution layer's mixer (inner:
+                          # sconv_chain)
     "dense_ffn",          # the dense SwiGLU of the leading layers
     "moe_ffn",            # routed + shared experts (inner: moe_route,
                           # moe_experts, moe_shared)
@@ -569,7 +571,8 @@ STEP_PHASES = (
 # the phases only a decoder's step opens
 LM_STEP_PHASES = ("lm_embed", "kda_mixer", "mla_mixer", "swa_mixer",
                   "full_attn_mixer", "gdn_mixer", "gated_attn_mixer",
-                  "dsa_mixer", "dense_ffn", "moe_ffn", "lm_head_loss")
+                  "dsa_mixer", "sconv_mixer", "dense_ffn", "moe_ffn",
+                  "lm_head_loss")
 
 _PHASE_WRAPPER = re.compile(r"^(jvp|transpose|checkpoint|remat)\((.*)\)$")
 

@@ -1,10 +1,12 @@
-"""The delta-rule mixers' chains (``ops/mixer_chains.py``) alone on the
-chip, at both decoder cells' shapes: each kernel pair against its plain
+"""The mixers' chains (``ops/mixer_chains.py``) alone on the chip, at the
+decoder cells' shapes (the delta-rule mixers' three at 2 x 8,192 tokens of
+32 heads of 128; the gated short convolution at 4 x 8,192 x 2,048
+channels): each kernel pair against its plain
 XLA chain (``models/decoder.py``'s arithmetic), output and every gradient
 compared, forward and forward + backward timed, then the kernels at other
 time blocks (information: why the shipped block stays).
 
-    chiprun -- python3 scripts/chip_mixer_chains.py [--blocks 64,256]
+    chiprun -- python3 scripts/chip_mixer_chains.py [--blocks 64,256] [--only sconv]
 
 One process, no child; every first call of a program under a
 ``faulthandler`` limit of its own (a program can hang the chip where every
@@ -85,6 +87,11 @@ def cases():
         return -jnp.exp(a_log)[:, None] * jax.nn.softplus(
             (f.astype(f32) + dt_bias).reshape(B, T, h, D))
 
+    def plain_gated_conv(x, w):
+        c = x.shape[-1] // 3
+        gate, mid, u = (x[..., i * c:(i + 1) * c].astype(f32) for i in range(3))
+        return (mid * causal_depthwise_conv(gate * u, w)).astype(bf16)
+
     plane, rows32 = ((B, T, h * D), bf16), ((B, T, hv, D), f32)
     qkvz = ((B, T, hk * per * D), bf16)
     taps = lambda n: ((4, n * D), f32)  # noqa: E731
@@ -119,6 +126,10 @@ def cases():
             plain_norm(jax.nn.silu, 1e-6, lambda g: g.reshape(
                 B, T, hk, per * D)[..., (2 + r) * D:]),
             [rows32, qkvz, ((D,), f32)], (0, 1, 2)),
+        "sconv gated_short_conv ([B ; C ; u] of 2048)": (
+            lambda blk: lambda x, w: mc.gated_short_conv(x, w, block=blk),
+            plain_gated_conv, [((4, T, 3 * 2048), bf16), ((3, 2048), f32)],
+            (0, 1)),
     }
 
 
@@ -141,6 +152,8 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--blocks", default="",
                     help="other time blocks to time the kernels at")
+    ap.add_argument("--only", default="",
+                    help="run the cases whose name holds this word")
     args = ap.parse_args(argv)
 
     import jax
@@ -162,6 +175,8 @@ def main(argv=None) -> int:
         x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
     worst = 0.0
     for name, (kernel, plain, shapes, wrt) in cases().items():
+        if args.only not in name:
+            continue
         keys = jax.random.split(jax.random.key(len(name)), len(shapes) + 1)
         x = [jax.random.normal(k, s, f32).astype(d)
              for k, (s, d) in zip(keys, shapes)]

@@ -88,7 +88,10 @@ SHAPES = ((1, 1024, 28, 128), (1, 1024, 4, 128), (1, 1024, 4, 128))
     (((2, 1024, 32, 192), (2, 1024, 32, 192), (2, 1024, 32, 128)),
      {"interpret": True}, "kernel", "interpreted"),
     (SHAPES[:2] + ((1, 1024, 4, 64),), {"interpret": True}, "tiles",
-     "the value width 64 is not a multiple of 128"),
+     "the value width 64 is not a multiple of 128, nor are the heads 64 + 64 "
+     "wide on an even number of key/value heads (128 + 64 on 4)"),
+    (((2, 1024, 8, 64), (2, 1024, 2, 64), (2, 1024, 2, 64)),
+     {"interpret": True}, "kernel", "interpreted"),
     (tuple((1, 1300) + s[2:] for s in SHAPES), {"interpret": True}, "tiles",
      "1300 tokens are not whole blocks of 512 queries and 1024 keys"),
     (SHAPES, {"interpret": True, "block_q": 32, "block_kv": 64}, "tiles",
@@ -99,7 +102,8 @@ SHAPES = ((1, 1024, 28, 128), (1, 1024, 4, 128), (1, 1024, 4, 128))
      "float16 is neither bfloat16 nor float32"),
     (SHAPES, {"interpret": True, "reduce_dtype": jnp.bfloat16}, "tiles",
      "statistics in bfloat16: the kernels' are float32"),
-], ids=["cpu", "interpret", "compile", "window", "mla", "width", "length",
+], ids=["cpu", "interpret", "compile", "window", "mla", "width", "heads64",
+        "length",
         "blocks", "vmem", "dtype", "reduce_dtype"])
 def test_path_is_read_off_the_call(shapes, kwargs, path, why):
     assert kernels.causal_attention_path(shapes, **kwargs) == (path, why)

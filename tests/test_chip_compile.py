@@ -6,7 +6,9 @@ backward at ViT-L width, and the delta rule's chunk forward and
 backward at the decoder cell's shapes, the causal attention kernels
 at both decoder cells' published shapes (under a selection too, and the
 index loss's kernel beside them), and the delta-rule mixers'
-chains (``ops/mixer_chains.py``) at both delta-rule cells' — each with ``interpret=False``, each asserting
+chains (``ops/mixer_chains.py``) at both delta-rule cells', the gated
+short convolution's chain and the causal pair at heads of 64 at the
+``lfm2_moe`` cell's — each with ``interpret=False``, each asserting
 a Mosaic ``tpu_custom_call`` in the compiled text. What the chip's
 compiler would refuse (a slice off the tiling, too much VMEM) fails
 here, at no chip time. A compile that passes is not a chip run.
@@ -303,6 +305,79 @@ def test_mixer_chain_kernels_compile_for_v5e(one_chip, case, direction):
     entry = text[text.index("ENTRY"):]
     assert " copy(" not in entry and "[2,8192," not in "".join(
         line for line in entry.splitlines() if " fusion(" in line)
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_gated_short_conv_kernels_compile_for_v5e(one_chip, direction):
+    """``ShortConvMixer``'s chain at the ``lfm2_moe`` cell's shape (4 x
+    8,192 tokens, [B ; C ; u] of 2,048 channels each in ONE ``[4, 8192,
+    6144]`` plane): one kernel a pass, the plane read where it lies and
+    the backward's [dB ; dC ; du] written as one plane: no copy and no
+    fusion of a plane's size beside the call."""
+    from dinov3_tpu.ops import mixer_chains as mc
+
+    plane, taps = ((4, 8192, 6144), jnp.bfloat16), ((3, 2048), jnp.float32)
+    assert mc.mixer_chain_path(8192, (2048,), (), jnp.bfloat16,
+                               interpret=False)[0] == "kernel"
+
+    def fwd(x, w):
+        return mc.gated_short_conv(x, w, interpret=False)
+
+    def bwd(x, w, dy):
+        return jax.vjp(fwd, x, w)[1](dy)
+
+    fn, shapes = (fwd, [plane, taps]) if direction == "fwd" else (
+        bwd, [plane, taps, ((4, 8192, 2048), jnp.bfloat16)])
+    text = _compiled_text(fn, one_chip, *shapes)
+    assert text.count("tpu_custom_call") == 1
+    assert (mc.GATED_CONV_BACKWARD_KERNEL_NAME if direction == "bwd"
+            else mc.GATED_CONV_KERNEL_NAME) in text
+    entry = text[text.index("ENTRY"):]
+    assert " copy(" not in entry and "[4,8192," not in "".join(
+        line for line in entry.splitlines() if " fusion(" in line)
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_causal_attention_kernels_compile_at_heads_of_64_for_v5e(
+        one_chip, direction):
+    """The kernel pair at the ``lfm2_moe`` cell's shape (4 x 8,192 tokens,
+    32 query heads on 8 key/value heads of 64 + 64: a PAIR of key/value
+    heads a 128-lane block, 8 query heads a grid step), fed the
+    projections' ``[B, T, heads * 64]`` planes as they lie: one kernel a
+    pass (the gradient's program: the forward rule and ONE backward
+    kernel), no key or value padded to 128 and no copy of a plane's size
+    beside the calls."""
+    from dinov3_tpu.ops.causal_attention import (
+        BACKWARD_KERNEL_NAME,
+        KERNEL_NAME,
+        causal_attention_path,
+        kernel_attention,
+    )
+
+    b, n, h, hk, d = 4, 8192, 32, 8, 64
+    q, kv = ((b, n, h * d), jnp.bfloat16), ((b, n, hk * d), jnp.bfloat16)
+    assert causal_attention_path(
+        ((b, n, h, d), (b, n, hk, d), (b, n, hk, d)), None, False)[0] == "kernel"
+
+    def fwd(q, k, v):
+        return kernel_attention(
+            q.reshape(b, n, h, d), k.reshape(b, n, hk, d),
+            v.reshape(b, n, hk, d), d ** -0.5, None, 512, 1024, False
+        ).reshape(b, n, h * d)
+
+    def bwd(*x):
+        return jax.vjp(fwd, *x[:-1])[1](x[-1])
+
+    fn, shapes = (fwd, [q, kv, kv]) if direction == "fwd" else (
+        bwd, [q, kv, kv, q])
+    text = _compiled_text(fn, one_chip, *shapes)
+    assert KERNEL_NAME in text
+    assert text.count("tpu_custom_call") == (1 if direction == "fwd" else 2)
+    assert (BACKWARD_KERNEL_NAME in text) == (direction == "bwd")
+    entry = text[text.index("ENTRY"):]
+    assert " while(" not in text and " copy(" not in entry
+    assert ",128]" not in "".join(   # no [.., heads, 128] widened plane
+        line for line in entry.splitlines() if " pad(" in line)
 
 
 def test_ibot_row_ce_gradient_compiles_without_a_loop_for_v5e(one_chip):

@@ -34,7 +34,12 @@ TINY = {
         "dino.head_n_prototypes=256", "dino.head_hidden_dim=64",
         "ibot.head_n_prototypes=256", "ibot.head_hidden_dim=64"],
     "train_iters": 3,
-    "lm_overrides": ["data.backend=synthetic", *LM_TINY],
+    # two layers, one of each kind of mixer and of FFN (KDA + dense, MLA +
+    # routed): the rehearsal is of the smoke's control flow, and the
+    # decoder's step compiles twice in it (fresh, resumed)
+    "lm_overrides": ["data.backend=synthetic", *LM_TINY,
+                     "lm.num_hidden_layers=2", "lm.kda_layers=[1]",
+                     "lm.full_attn_layers=[2]"],
     "lm_iters": 3,
     "lm_timeout_s": 600,
     "mesh_global_batch": 8,
@@ -51,6 +56,8 @@ TINY = {
     "gdn_shape": (1, 128, 2, 128),
     "gdn_attn_shapes": {"gated": (1, 512, 4, 2, 256, 256, None)},
     "dsa_shape": (1, 256, 2, 1, 128, 2, 16, 32),
+    "sconv_shape": (1, 256, 128),
+    "sconv_attn_shapes": {"heads64": (1, 256, 8, 2, 64, 64, None)},
     "gqa_shipped_blocks": (128, 256),
     "gqa_blocks": [(256, 128)],
     "gqa_timeout_s": 600,
@@ -134,6 +141,11 @@ def test_smoke_rehearsal_runs_every_phase(smoke, monkeypatch, capsys):
                    "dsa: core under the causal triangle at 256 tokens",
                    "dsa: core: norm of the difference over the norm, kernel, "
                    "plane in program to whole rows",
+                   "sconv: chain (1, 256, 128): the entry point takes the "
+                   "kernel (interpreted)",
+                   "sconv: chain: norm of the difference over the norm",
+                   "gqa: heads64 core (1, 256, 8, 2, 64, 64) window None: the "
+                   "entry point takes the kernel (interpreted)",
                    "all phases passed"):
         assert needle in said, needle
     assert "mesh[" not in said  # the four-chip phase is behind --chips 4

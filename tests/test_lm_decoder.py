@@ -363,7 +363,7 @@ def test_compiled_step_holds_the_decoder_phases(tiny_setup):
     # tests/test_lm_gdn.py's and tests/test_lm_dsa.py's)
     assert {p for p, _ in found} - {None} == set(LM_STEP_PHASES) - {
         "swa_mixer", "full_attn_mixer", "gdn_mixer", "gated_attn_mixer",
-        "dsa_mixer"} | {
+        "dsa_mixer", "sconv_mixer"} | {
             "update", "telemetry_ring"}
     for phase in ("kda_mixer", "mla_mixer", "dense_ffn", "moe_ffn",
                   "lm_head_loss"):
@@ -466,11 +466,14 @@ def test_synthetic_tokens_and_config_rules():
 
 def test_save_and_resume_through_do_train(tmp_path):
     """The teacher-less state through the normal entry point: three
-    steps and a save, then a resume for one more."""
+    steps and a save, then a resume for one more. (Two layers, one of
+    each kind of mixer and of FFN: the step compiles twice here, and what
+    is saved and restored does not depend on the depth.)"""
     from dinov3_tpu.train.train import main as train_main
 
     common = ["--config-file", RECIPE, "--output-dir", str(tmp_path / "run"),
-              *TINY, "MODEL.DEVICE=cpu"]
+              *TINY, "lm.num_hidden_layers=2", "lm.kda_layers=[1]",
+              "lm.full_attn_layers=[2]", "MODEL.DEVICE=cpu"]
     first = train_main(["--no-resume", "--max-iterations", "3", *common])
     assert first["iterations"] == 3 and len(first["losses"]) == 3
     assert all(abs(x - math.log(256)) < 0.5 for x in first["losses"])

@@ -283,11 +283,11 @@ def test_required_flops_by_hand(conf):
 
 def test_cell_and_its_files(bench, conf):
     cell = next(w for w in bench["workloads"] if w["name"] == CELL)
-    assert cell["chips"] == 1 and bench["workloads"][-1] is cell
-    assert len(cell["why"]) <= 200 and len(bench["workloads"]) == 6
+    assert cell["chips"] == 1 and bench["workloads"][5] is cell
+    assert len(cell["why"]) <= 200 and len(bench["workloads"]) >= 6
     assert all(w["chips"] == 1 for w in bench["workloads"])
     entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
-    assert bench["configs"][-1] is entry and len(entry["why"]) <= 200
+    assert bench["configs"][5] is entry and len(entry["why"]) <= 200
     assert entry["source"] == conf["source"] and entry["file"].endswith(
         cell["config"] + ".json")
     assert sorted(entry["reduced"]) == sorted(conf["reduced"]) == sorted(REDUCED)
@@ -306,7 +306,9 @@ def test_cell_and_its_files(bench, conf):
         "lm_dsa_core_roofline_pct", "lm_dsa_index_ms_per_step",
         "lm_dsa_select_ms_per_step", "lm_dsa_unattributed_pct",
         "lm_dsa_mfu_pct"]
-    assert bench["per_layer"][-7]["name"] == listed[-7]
+    names = [m["name"] for m in bench["per_layer"]]   # appended together
+    at = names.index(listed[-7])
+    assert names[at:at + 7] == listed[-7:]
     assert set(listed[:8]) == {
         "train_host_ms_per_step", "train_device_ms_per_step",
         "train_device_idle_pct", "train_update_ms_per_step",
@@ -319,7 +321,7 @@ def test_cell_and_its_files(bench, conf):
         if CELL in m.get("workloads", ()):
             assert os.path.isfile(os.path.join(
                 BENCH, "layer_metrics", m["name"] + ".py"))
-            assert m["workloads"][-1] == CELL
+            assert CELL in m["workloads"][-2:]   # (PR 41 appended one more)
             if m["name"] in listed[-7:]:
                 assert m["workloads"] == [CELL], m["name"]
                 assert set(m) == {"name", "unit", "better", "source", "layer",

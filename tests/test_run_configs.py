@@ -44,7 +44,8 @@ def test_recipe_abstract_build(path):
         TINY = importlib.import_module({
             "kimi_linear": "test_lm_decoder", "smallthinker": "test_lm_gqa",
             "qwen3_next": "test_lm_gdn",
-            "keye_vl2": "test_lm_dsa"}[str(cfg.student.arch)]).TINY
+            "keye_vl2": "test_lm_dsa",
+            "lfm2_moe": "test_lm_sconv"}[str(cfg.student.arch)]).TINY
 
         from dinov3_tpu.train.lm_meta_arch import LMMetaArch
 
