@@ -91,9 +91,10 @@ SIZES = {
                    "global": (1, 16384, 28, 4, 128, 128, None),
                    "mla": (2, 8192, 32, 32, 192, 128, None)},
     # the third decoder's two cores at published sizes (--phases gdn): the
-    # delta rule with ONE decay a head, [B, T, value heads, d_k = d_v],
+    # delta rule with ONE decay a head, [B, T, key heads, value heads,
+    # d_k = d_v],
     # and the gated attention's causal core (16 query heads on 2 of 256)
-    "gdn_shape": (2, 8192, 32, 128),
+    "gdn_shape": (2, 8192, 16, 32, 128),
     "gdn_attn_shapes": {"gated": (2, 8192, 16, 2, 256, 256, None)},
     # the fourth decoder's sparse attention at published sizes (--phases
     # dsa): [B, T, query heads, key/value heads, head width, index heads,
@@ -596,11 +597,13 @@ def phase_gqa(shapes: str = "gqa_shapes") -> None:
 def phase_gdn() -> None:
     """The two cores of the ``qwen3_next`` family stand-alone at published
     sizes. The delta rule as ``GDNMixer`` calls it — ``ops/kda.py
-    kda_chunked`` with ONE log decay a head and token broadcast over the
-    key channels, at decays as large as the family's initial values give
-    (16 x softplus(1) = 21 nats a token on the fastest head): the path
-    ``kda_path`` takes there, the kernels against the plain scan, output
-    and five gradients (the decay's summed back over the channels), both
+    kda_chunked`` with q and k at the key heads and ONE log decay a value
+    head and token (a gate of rank 3), at decays as large as the family's
+    initial values give (16 x softplus(1) = 21 nats a token on the fastest
+    head): the path ``kda_path`` takes there, the scalar-gate kernels
+    against the plain scan fed q and k repeated and the gate broadcast
+    over the key channels, output and five gradients (the scan's dq and dk
+    summed over a key head's value heads, its dg over the channels), both
     timed. (Against the token recurrence itself: the ``kernels`` phase, on
     a row short enough for its gradient.) Then the gated attention's
     causal core through ``phase_gqa``'s rows."""
@@ -614,15 +617,16 @@ def phase_gdn() -> None:
     interpret = bool(SIZES["kernel_interpret"])
     faulthandler.dump_traceback_later(
         float(SIZES["gqa_timeout_s"]), exit=True, file=sys.__stderr__)
-    b, t, h, d = SIZES["gdn_shape"]
-    path, why = kda.kda_path(d, d, interpret=interpret or None)
-    log(f"gdn: delta rule {(b, t, h, d)} at a scalar gate: the entry point "
-        f"takes the {path} ({why})")
-    assert path == "kernel", (path, why)
+    b, t, hk, h, d = SIZES["gdn_shape"]
+    path, why = kda.kda_path(d, d, interpret=interpret or None,
+                             gate_heads=(hk, h))
+    log(f"gdn: delta rule {(b, t, hk, h, d)} at a scalar gate: the entry "
+        f"point takes the {path} ({why})")
+    assert path == "kernel" and "scalar" in why, (path, why)
     ks = jax.random.split(jax.random.key(3), 5)
     unit = lambda x: (x / jnp.linalg.norm(  # noqa: E731
         x.astype(jnp.float32), axis=-1, keepdims=True)).astype(jnp.bfloat16)
-    q, k = (unit(jax.random.normal(key, (b, t, h, d))) for key in ks[:2])
+    q, k = (unit(jax.random.normal(key, (b, t, hk, d))) for key in ks[:2])
     v = jax.random.normal(ks[2], (b, t, h, d), jnp.bfloat16)
     rate = jnp.linspace(0.05, 16.0, h)[None, None, :]       # A, a head
     g = -rate * jax.nn.softplus(1.0 + jax.random.normal(ks[3], (b, t, h)))
@@ -631,10 +635,10 @@ def phase_gdn() -> None:
 
     def core(scan):
         def fn(q, k, v, g, beta):
-            gb = jnp.broadcast_to(g[..., None], (b, t, h, d))
             if scan:
-                return kda._scan_forward(q, k, v, gb, beta, kda.CHUNK, d ** -0.5)
-            return kda.kda_chunked(q, k, v, gb, beta, q_scale=d ** -0.5,
+                q, k, g = kda._per_channel(q, k, v, g)
+                return kda._scan_forward(q, k, v, g, beta, kda.CHUNK, d ** -0.5)
+            return kda.kda_chunked(q, k, v, g, beta, q_scale=d ** -0.5,
                                    interpret=True if interpret else None)
         return jax.jit(lambda *a: (fn(*a), *jax.grad(
             lambda *y: jnp.sum(jnp.sin(fn(*y))), argnums=(0, 1, 2, 3, 4))(*a)))

@@ -49,7 +49,8 @@ n(x) = x / rms(x) * (1 + w), but for the delta rule's output norm):
   log decay a head and token; S_t = (I - b_t k_t k_t^T) e^{g_t} S_{t-1} +
   b_t k_t v_t^T; o_t = S_t^T q_t; y_t = W_o [RMSNorm_head(o_t) * SiLU(z_t)]
   (this one norm's scale is w, from ones). The delta rule is KDA's own
-  ``ops/kda.py``, the decay broadcast over the key channels. W_qkvz's
+  ``ops/kda.py``, which reads ONE decay a head off the gate's rank and
+  takes q and k at the key heads. W_qkvz's
   and W_ba's columns are held in the published grouping, a key head at a
   time: [q d_k | k d_k | v r d_v | z r d_v] and [b r | a r], r = value
   heads / key heads.
@@ -606,12 +607,9 @@ class GDNMixer(nn.Module):
         g = -jnp.exp(f32(a_log)) * jax.nn.softplus(
             ba[..., r:].reshape(b, t, hv) + f32(dt_bias))
         with jax.named_scope("gdn_core"):
-            # key head j serves value heads j r .. j r + r - 1; the one
-            # decay of a head stands for all its key channels
-            q, k = (jnp.repeat(u, r, axis=2) for u in (q, k))
-            o = kda_chunked(
-                q, k, v, jnp.broadcast_to(g[..., None], (b, t, hv, dk)), beta,
-                q_scale=dk ** -0.5)
+            # q and k at the key heads and ONE decay a value head: the
+            # gate's rank tells ops/kda.py which delta rule this is
+            o = kda_chunked(q, k, v, g, beta, q_scale=dk ** -0.5)
         scale = self.param("o_norm_scale", part(nn.initializers.ones, (None,)),
                            (dv,), self.param_dtype)
 

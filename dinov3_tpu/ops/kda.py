@@ -1,5 +1,6 @@
-"""Kimi Delta Attention: the gated delta rule with one decay per key
-channel, in chunks.
+"""The gated delta rule in chunks, at either form of its gate: one decay
+per key channel (Kimi Delta Attention) or ONE decay a head (Gated
+DeltaNet).
 
 Per head, with q_t, k_t in R^dk (k L2-normalised), v_t in R^dv, a decay
 a_t = exp(g_t) in (0, 1)^dk and a write strength b_t in (0, 1):
@@ -21,14 +22,54 @@ so the chunk is a handful of matmuls: A, P, the inverse of the unit
 lower-triangular I + Diag(b) A (a product of 6 factors, since its
 strict part is nilpotent), and five products with the state.
 
-What runs where. ``kda_chunked`` has two paths that share these
-definitions, and ``kda_path`` chooses between them from what it can
-see, no option or variable: the KERNELS where the backend is a TPU (or a
-test asks for ``interpret``), d_k and d_v are multiples of 128 and the
-chunk is 64; the PLAIN path (``_chunk`` under ``lax.scan``, each chunk's
-body rematerialised, its backward JAX's own transposition of the scan)
-everywhere else: CPU runs, narrow test widths, other chunks. A path is
-both of its passes: no loop is shared, none chosen apart.
+The two forms of the gate. ``kda_chunked`` reads which delta rule a
+call is off the gate's RANK, an operand's shape and no option: g
+[B, T, H, dk] is the per-channel gate above, with q, k, v and beta all at
+H heads; g [B, T, Hv] is ONE log decay a value head and token (the same
+recurrence with a_t a scalar), with q and k at their own Hk key heads,
+key head j serving value heads j r .. j r + r - 1, r = Hv / Hk. With one
+decay a head e^{G_t - G_s} is one number a token pair, so
+
+    A = strict_lower(k k^T) * D,  P = lower(q k^T) * D,
+    D_ts = e^{G_t - G_s} (s <= t), an outer difference of ONE vector,
+
+k k^T and q k^T hold no decay and do not depend on the value head (one
+product a KEY head serves its r value heads), the decays to and from
+the state scale rows (e^{G_t} (k_t S), not (e^{G_t} k_t) S), and nothing
+needs a reference token: below the diagonal every exponent is <= 0 as it
+stands. So the scalar gate needs NONE of the halving levels described
+under Precision, which are the per-channel gate's alone.
+
+What runs where. ``kda_chunked`` has three paths that share these
+definitions, and ``kda_path`` chooses from what it can see, no option or
+variable. Where the backend is a TPU (or a test asks for ``interpret``),
+d_k and d_v are multiples of 128 and the chunk is 64: the PER-CHANNEL
+KERNELS (``kda_chunk_fwd`` / ``kda_chunk_bwd``) for a gate of rank 4;
+the SCALAR-GATE KERNELS (``gdn_chunk_fwd`` / ``gdn_chunk_bwd``) for a
+gate of rank 3 with one or two value heads a key head. Everywhere else —
+CPU runs, narrow test widths, other chunks, other head groupings — the
+PLAIN path (``_chunk`` under ``lax.scan``, each chunk's body
+rematerialised, its backward JAX's own transposition of the scan), which
+a gate of rank 3 reaches as the per-channel case it also is: q and k
+repeated over a key head's value heads, the gate broadcast over the key
+channels (``_per_channel``; ``kda_recurrent`` takes both forms the same
+way). A path is both of its passes: no loop is shared, none chosen apart.
+
+The scalar-gate kernels keep the per-channel kernels' grid, blocks,
+state scratch, pair layout, inverse and what the forward rule keeps, and
+the same float32 set; their bodies share only the helpers. A pair of
+heads is a key head's two value heads (r = 2) or two key heads' (r = 1):
+ONE product k [k; q]^T a key head gives both raw planes, ONE masked
+exponential [C, 2 C] the pair's two D — the EXPONENT is masked, since
+above the diagonal G_t - G_s is positive (63 x 21 nats at the published
+initial values) and e^that times 0 is NaN. The backward needs no level
+either: with X = dA A + dP P (= dD D), dG_t = sum_s X_ts - sum_s X_st
+plus the row scales' shares, dq = (dP D) k and dk = (dP D)^T q +
+(dA D + (dA D)^T) k, with a key head's two value heads' planes ADDED
+before those products, so dq and dk leave the kernel once, at the key
+heads. In units of 64 x 128 x 128 a pair and chunk the forward does 21
+products and the backward 25, against the per-channel pair's 32 and 60.
+The per-channel kernels, from here on:
 
 The forward kernel (``kda_chunk_fwd``, one ``pallas_call``) takes the
 chunk axis as the grid's sequential axis and the sequence as the
@@ -95,10 +136,11 @@ the same halving levels, backward as forward: no factor above 1 at any
 decay, and the state's cotangent float32 like the state. They differ
 in the blocking alone: a level is ONE product over the whole chunk,
 masked to the level's blocks (12 times the score operations the count
-in ``benchmark/lm_flops.py`` needs, at shapes the MXU takes); the score
-planes are transposed; beta scales the right-hand side of T's product
-and not T's columns; and two heads' [C, C] planes share a [C, 2 C]
-plane against block-diagonal operands.
+in ``benchmark/lm_flops.py`` needs, at shapes the MXU takes: the
+per-channel path's cost alone, the scalar-gate kernels make each score
+plane once); the score planes are transposed; beta scales the
+right-hand side of T's product and not T's columns; and two heads'
+[C, C] planes share a [C, 2 C] plane against block-diagonal operands.
 """
 
 from __future__ import annotations
@@ -113,6 +155,8 @@ from jax.experimental.pallas import tpu as pltpu
 CHUNK = 64
 KERNEL_NAME = "kda_chunk_fwd"
 BACKWARD_KERNEL_NAME = "kda_chunk_bwd"
+SCALAR_KERNEL_NAME = "gdn_chunk_fwd"
+SCALAR_BACKWARD_KERNEL_NAME = "gdn_chunk_bwd"
 _NEG = -1e30
 # a grid step holds a chunk of every head twice over (6 MB at 32 heads of
 # 128, 12 with what the forward rule keeps, 17 in the backward) beside the
@@ -545,6 +589,21 @@ def _even_heads(*arrays):
                  for x in arrays)
 
 
+def _pair_rows(x):
+    """[B, T, H] (H even) chunk by chunk with a row a pair of heads, the
+    two heads' chunks side by side: [B, n, H / 2, 2 C]."""
+    b, t, h = x.shape
+    return jnp.moveaxis(x.reshape(b, t // CHUNK, CHUNK, h // 2, 2), 2, 4).reshape(
+        b, t // CHUNK, h // 2, 2 * CHUNK)
+
+
+def _token_rows(x):
+    """``_pair_rows`` the other way."""
+    b, n, pairs, _ = x.shape
+    return jnp.moveaxis(x.reshape(b, n, pairs, 2, CHUNK), 4, 2).reshape(
+        b, n * CHUNK, 2 * pairs)
+
+
 def _kernel_operands(q, k, v, g, beta, reverse=False):
     """What both kernels take of the (even-headed) inputs: (the arrays as
     [B, T * H, d] views and a pair's beta chunk by chunk, the block of a
@@ -562,10 +621,6 @@ def _kernel_operands(q, k, v, g, beta, reverse=False):
     spec = lambda d: pl.BlockSpec(  # noqa: E731
         (1, CHUNK * h, d), lambda i, m: (i, chunk(m), 0),
         memory_space=pltpu.VMEM)
-    # a pair's beta, chunk by chunk: [B, n, H / 2, 2 * C]
-    b_rows = jnp.moveaxis(
-        beta.reshape(b, n, CHUNK, h // 2, 2), 2, 4).reshape(
-            b, n, h // 2, 2 * CHUNK)
     b_spec = pl.BlockSpec((None, None, h // 2, 2 * CHUNK),
                           lambda i, m: (i, chunk(m), 0, 0),
                           memory_space=pltpu.VMEM)
@@ -576,8 +631,8 @@ def _kernel_operands(q, k, v, g, beta, reverse=False):
         pl.BlockSpec((None, None, h // 2, CHUNK, 2 * CHUNK),
                      lambda i, m: (i, chunk(m), 0, 0, 0),
                      memory_space=pltpu.VMEM)]
-    return ((rows(q), rows(k), rows(v), rows(g), b_rows), spec, b_spec,
-            kept_specs)
+    return ((rows(q), rows(k), rows(v), rows(g), _pair_rows(beta)), spec,
+            b_spec, kept_specs)
 
 
 # A ``pallas_call`` traces its kernel body every time it is called, and a
@@ -652,46 +707,425 @@ def _kernel_backward(q, k, v, g, beta, kept, do, q_scale, interpret):
     )(*operands, do.reshape(b, t * h, dv), *kept)
     dq, dk_, dv_, dg = (
         x.reshape(b, t, h, x.shape[-1])[:, :, :heads] for x in grads)
-    dbeta = jnp.moveaxis(
-        db_rows.reshape(b, n, h // 2, 2, CHUNK), 4, 2).reshape(b, t, h)
     return (*(x.astype(dt) for x, dt in zip((dq, dk_, dv_), types)), dg,
-            dbeta[:, :, :heads])
+            _token_rows(db_rows)[:, :, :heads])
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _kernel_path(q, k, v, g, beta, q_scale, interpret):
-    return _kernel_forward(q, k, v, g, beta, q_scale=q_scale,
-                           keep_states=False, interpret=interpret)[0]
+def _kernel_pair(forward, backward):
+    """``forward`` and ``backward`` (a ``pallas_call`` each) as one
+    differentiable function of (q, k, v, g, beta, q_scale, interpret)."""
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+    def path(q, k, v, g, beta, q_scale, interpret):
+        return forward(q, k, v, g, beta, q_scale=q_scale, keep_states=False,
+                       interpret=interpret)[0]
+
+    def path_fwd(q, k, v, g, beta, q_scale, interpret):
+        o, kept = forward(q, k, v, g, beta, q_scale=q_scale, keep_states=True,
+                          interpret=interpret)
+        return o, (q, k, v, g, beta, kept)
+
+    def path_bwd(q_scale, interpret, res, do):
+        return backward(*res, do, q_scale=q_scale, interpret=interpret)
+
+    # optimize_remat: under a layer's remat the pass that keeps no
+    # residuals runs the primal (no state output), not the forward rule
+    # with its states thrown away
+    path.defvjp(path_fwd, path_bwd, optimize_remat=True)
+    return path
 
 
-def _kernel_path_fwd(q, k, v, g, beta, q_scale, interpret):
-    o, kept = _kernel_forward(q, k, v, g, beta, q_scale=q_scale,
-                              keep_states=True, interpret=interpret)
-    return o, (q, k, v, g, beta, kept)
+_kernel_path = _kernel_pair(_kernel_forward, _kernel_backward)
 
 
-def _kernel_path_bwd(q_scale, interpret, res, do):
-    return _kernel_backward(*res, do, q_scale=q_scale, interpret=interpret)
+# ------------------------------------- the two kernels of a scalar gate
+#
+# ONE log decay a value head and token (g [B, T, Hv]; q and k at Hk key
+# heads, Hv = r Hk, r 1 or 2). Grid, blocks, state scratch, what the
+# forward rule keeps and the pair layouts are the kernels' above. What
+# differs is the decay: e^{G_t - G_s} is one number a token pair, at most
+# 1 below the diagonal, so the plane D is ONE masked exponential of an
+# outer difference (the EXPONENT is masked: above the diagonal it is
+# positive and overflows), k k^T and q k^T are one product a KEY head with
+# no decay inside, and the decays to and from the state scale rows.
+# g and beta come as a row a pair of value heads ([Hv / 2, 2 C], the two
+# heads' chunks side by side), and dg and dbeta leave so.
+#
+# A loop step is a PAIR OF KEY HEADS (one strided load of bfloat16 words,
+# as a pair of heads above) with its r pairs of value heads: at r = 2 a
+# key head's two value heads are a pair, at r = 1 the two key heads' own
+# are. A pair's chain of products is one dependent line (scores, the
+# inverse's six, u, P u: each waits for the last), so a step first loads
+# what all its pairs read, then computes them as values, then stores: at
+# r = 2 the scheduler has two independent lines to interleave.
 
 
-# optimize_remat: under a layer's remat the pass that keeps no residuals
-# runs the primal (no state output), not the forward rule with its states
-# thrown away
-_kernel_path.defvjp(_kernel_path_fwd, _kernel_path_bwd, optimize_remat=True)
+def _lane_running_sum(x, reverse=False):
+    """``_running_sum`` along the lanes of a pair's row [1, 2 C], inside
+    each head's half."""
+    n = x.shape[1]
+    at, step = _iota(x.shape, 1) & (n // 2 - 1), 1
+    while step < n // 2:
+        x = x + (jnp.where(at < n // 2 - step, pltpu.roll(x, n - step, 1), 0.0)
+                 if reverse else
+                 jnp.where(at >= step, pltpu.roll(x, step, 1), 0.0))
+        step *= 2
+    return x
+
+
+def _scalar_pairs(step, heads_k, heads, q_scale, q_ref, k_ref):
+    """The pairs of value heads of loop step ``step`` (key heads
+    ``2 * step`` and ``2 * step + 1``): [(pair, [(q, k)] a key head the
+    pair reads, q scaled)]."""
+    keys = tuple(zip((x * q_scale for x in _pair_planes(q_ref, step, heads_k)),
+                     _pair_planes(k_ref, step, heads_k)))
+    if heads == heads_k:
+        return [(step, keys)]
+    return [(2 * step + j, keys[j:j + 1]) for j in (0, 1)]
+
+
+def _scalar_scores(keys, g_row):
+    """What both scalar kernels make first of a pair, from its key heads'
+    planes and its row of g [1, 2 C]: (G as columns [2 C, 1], head 0's
+    chunk above head 1's; [A0^T | A1^T]; [P0^T | P1^T] with P's diagonal;
+    D^T below the diagonal, the factor of A's raw products, and D^T with
+    the diagonal, P's). ONE product a key head, k against [k; q]:
+    [k k^T | k q^T], the raw planes transposed, which the pair's two
+    decay planes then scale."""
+    c = CHUNK
+    big = _lane_running_sum(g_row)                      # [1, 2 C]: G_t
+    col = _turned(big)                                  # [2 C, 1]: G_s
+    srow, lane = _iota((c, 2 * c), 0), _iota((c, 2 * c), 1)
+    left, t = lane < c, lane & (c - 1)
+    decay = jnp.exp(jnp.where(
+        srow <= t, big - jnp.where(left, col[:c], col[c:]), _NEG))
+    strict = jnp.where(srow < t, decay, 0.0)
+    raw = [_dot(k, jnp.concatenate([k, q], 0), _NT) for q, k in keys]
+    swapped = [pltpu.roll(x, c, 1) for x in raw]        # [k q^T | k k^T]
+    a_t = jnp.where(left, raw[0], swapped[-1]) * strict
+    p_t = jnp.where(left, swapped[0], raw[-1]) * decay
+    return col, a_t, p_t, strict, decay
+
+
+def _scalar_fwd_pair(keys, g_row, b_row, values, states):
+    """A pair's forward as values: (the inverse [C, 2 C], the two heads'
+    outputs, their states at the chunk's end)."""
+    c = CHUNK
+    col, a_t, p_t, _, _ = _scalar_scores(keys, g_row)
+    inv = _pair_inverse(a_t, b_row)
+    from_start = jnp.exp(col)                        # e^{G_t}, a row scale
+    both = [jnp.concatenate([k, q], 0) for q, k in keys]
+    halves = (slice(0, c), slice(c, 2 * c))
+    rhs, out = [], []
+    for rows, kq, v, st in zip(halves, both * 2, values, states):
+        with_state = _dot(kq, st, _NT)               # [k S; q S]
+        rhs.append(v - from_start[rows] * with_state[:c])
+        out.append(from_start[rows] * with_state[c:])
+    u = _dot(_blocks(inv), _turned(b_row) * jnp.concatenate(rhs, 0), _TN)
+    pu = _dot(_blocks(p_t), u, _TN)                  # [P0 u0; P1 u1]
+    new = []
+    for rows, (_, k), st in zip(halves, keys * 2, states):
+        end = col[rows][c - 1:]                      # G_C, [1, 1]
+        new.append(jnp.exp(end) * st + _dot(
+            jnp.exp(end - col[rows]) * u[rows], k, _TN))
+    return inv, [o + pu[rows] for o, rows in zip(out, halves)], new
+
+
+def _scalar_fwd_kernel(*refs, q_scale, heads_k, heads, keep_states):
+    """``_fwd_kernel`` at a scalar gate: ``heads`` value heads on
+    ``heads_k`` key heads (as the blocks hold them: ``heads_k`` even,
+    ``heads`` once or twice it)."""
+    if keep_states:
+        q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, s_ref, inv_ref, st_ref = refs
+    else:
+        q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, st_ref = refs
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        st_ref[...] = jnp.zeros_like(st_ref)
+
+    def key_pair(step, carry):
+        loaded = [
+            (pair, keys, g_ref[pl.ds(pair, 1), :], b_ref[pl.ds(pair, 1), :],
+             _pair_planes(v_ref, pair, heads),
+             [st_ref[2 * pair + hd] for hd in (0, 1)])
+            for pair, keys in _scalar_pairs(
+                step, heads_k, heads, q_scale, q_ref, k_ref)]
+        done = [(pair, states, _scalar_fwd_pair(keys, g_row, b_row, vs, states))
+                for pair, keys, g_row, b_row, vs, states in loaded]
+        for pair, states, (inv, out, new) in done:
+            if keep_states:
+                inv_ref[pair] = inv
+            for hd in (0, 1):
+                head = 2 * pair + hd
+                o_ref[0, pl.ds(head, CHUNK, stride=heads), :] = out[hd]
+                if keep_states:
+                    s_ref[head] = states[hd]
+                st_ref[head] = new[hd]
+        return carry
+
+    jax.lax.fori_loop(0, heads_k // 2, key_pair, 0)
+
+
+def _scalar_bwd_pair(keys, g_row, b_row, values, dos, states, dstates, inv):
+    """A pair's backward as values: ([dq] and [dk] a key head of the pair,
+    q's still to be scaled; [dv0; dv1]; the rows of dg and dbeta; the two
+    heads' state cotangents at the chunk's start).
+
+    ``_bwd_kernel``'s transposition with rhs = v - e^G (k S),
+    o = e^G (q S) + P u and K' = e^{G_C - G} k. The score planes are
+    A = (k k^T) D below the diagonal and P = (q k^T) D with it, so with
+    X = dA A + dP P (= dD D)
+
+        dG_t = sum_s X_ts - sum_s X_st + the row scales' shares,
+        dq = (dP D) k,    dk = (dP D)^T q + (dA D + (dA D)^T) k
+
+    : no level, and a key head's two value heads' planes are ADDED before
+    these products, so dq and dk leave once a key head. Every row scale s
+    gives s ds (a row's dot product) to dG, G_C's share goes to every
+    token, and dg is dG's reversed running sum."""
+    c = CHUNK
+    left = _iota((c, 2 * c), 1) < c
+    halves = (slice(0, c), slice(c, 2 * c))
+    half = lambda x: pltpu.roll(x, c, 1)  # noqa: E731  the halves swapped
+    col, a_t, p_t, strict, decay = _scalar_scores(keys, g_row)
+    b_col, inv = _turned(b_row), _blocks(inv)
+    from_start = jnp.exp(col)
+    # [P0^T do0; P1^T do1]
+    pt_do = _dot(_blocks(p_t), jnp.concatenate(dos, 0), _NN)
+    rhs, du, scales = [], [], []
+    for rows, (_, k), v, st, dst in zip(halves, keys * 2, values, states,
+                                        dstates):
+        end = col[rows][c - 1:]                      # G_C, [1, 1]
+        to_end = jnp.exp(end - col[rows])
+        rhs.append(v - from_start[rows] * _dot(k, st, _NT))
+        du.append(pt_do[rows] + to_end * _dot(k, dst, _NT))
+        scales.append((from_start[rows], to_end, jnp.exp(end)))
+    rhs = jnp.concatenate(rhs, 0)
+    u = _dot(inv, b_col * rhs, _TN)                  # [u0; u1]
+    dw = _dot(inv, jnp.concatenate(du, 0), _NN)      # M^-T du
+    drhs = b_col * dw
+    # u_h against [-dw_h; do_h]: [dM_h^T | dP_h^T], then pair by pair
+    d0, d1 = (_dot(u[rows], jnp.concatenate([-dw[rows], do], 0), _NT)
+              for rows, do in zip(halves, dos))
+    dm_t = jnp.where(left, d0, half(d1))             # [dM0^T | dM1^T]
+    dp_t = jnp.where(left, half(d0), d1)             # [dP0^T | dP1^T]
+    db_row = jnp.sum(dm_t * a_t, axis=0, keepdims=True) + _turned(
+        jnp.sum(dw * rhs, axis=1, keepdims=True))
+    da_t = dm_t * b_row
+    x_t = da_t * a_t + dp_t * p_t                    # (dD D)^T
+    d_a, d_p = da_t * strict, dp_t * decay           # to the raw planes
+    # a head's [(dP D)^T | (dA D)^T], added over a key head's heads
+    planes = (jnp.where(left, d_p, half(d_a)), jnp.where(left, half(d_p), d_a))
+    if len(keys) == 1:
+        planes = (planes[0] + planes[1],)
+    dq, dk = [], []
+    for (q, k), z in zip(keys, planes):
+        rows = _dot(z, k, _TN)                 # [(dP D) k; (dA D) k]
+        dq.append(rows[:c])
+        dk.append(rows[c:] + _dot(z, jnp.concatenate([q, k], 0), _NN))
+    total = lambda x: jnp.sum(  # noqa: E731
+        jnp.sum(x, axis=1, keepdims=True), axis=0, keepdims=True)
+    dbig, at_end, dstarts = [], [], []
+    for hd, (rows, do, st, dst, (in_, out_, whole)) in enumerate(zip(
+            halves, dos, states, dstates, scales)):
+        key = hd * (len(keys) - 1)
+        q, k = keys[key]
+        down, in_ = jnp.concatenate([do, -drhs[rows]], 0), jnp.concatenate(
+            [in_, in_], 0)
+        # [d(q e^G); d(k e^G)] and d(k e^{G_C - G}), with their scales
+        d_in = in_ * _dot(down, st, _NN)
+        d_out = out_ * _dot(u[rows], dst, _NN)
+        dstarts.append(whole * dst + _dot(
+            in_ * down, jnp.concatenate([q, k], 0), _TN))
+        dq[key] = dq[key] + d_in[:c]
+        dk[key] = dk[key] + d_in[c:] + d_out
+        lanes = jnp.where(left == (hd == 0), x_t, 0.0)
+        dbig.append(jnp.sum(q * d_in[:c] + k * (d_in[c:] - d_out), axis=1,
+                            keepdims=True)
+                    - jnp.sum(lanes, axis=1, keepdims=True))
+        at_end.append(total(k * d_out) + whole * total(st * dst))
+    dg = jnp.sum(x_t, axis=0, keepdims=True) + _turned(jnp.concatenate(dbig, 0))
+    dg_row = _lane_running_sum(dg, reverse=True) + jnp.where(
+        _iota((1, 2 * c), 1) < c, *at_end)
+    return dq, dk, drhs, dg_row, db_row, dstarts
+
+
+def _scalar_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, s_ref,
+                       inv_ref, dq_ref, dk_ref, dv_ref, dg_ref, db_ref,
+                       dst_ref, *, q_scale, heads_k, heads):
+    """``_bwd_kernel`` at a scalar gate (``_scalar_bwd_pair``): the
+    chunks last first, ``dst_ref`` the states' cotangents; dq and dk
+    leave at the key heads, a pair of them a loop step."""
+    c = CHUNK
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        dst_ref[...] = jnp.zeros_like(dst_ref)
+
+    def key_pair(step, carry):
+        heads_of = lambda ref, pair: [ref[2 * pair + hd] for hd in (0, 1)]  # noqa: E731
+        loaded = [
+            (pair, keys, g_ref[pl.ds(pair, 1), :], b_ref[pl.ds(pair, 1), :],
+             _pair_planes(v_ref, pair, heads), _pair_planes(do_ref, pair, heads),
+             heads_of(s_ref, pair), heads_of(dst_ref, pair), inv_ref[pair])
+            for pair, keys in _scalar_pairs(
+                step, heads_k, heads, q_scale, q_ref, k_ref)]
+        done = [(pair, _scalar_bwd_pair(*operands))
+                for pair, *operands in loaded]
+        dq, dk = [], []
+        for pair, (dq_, dk_, drhs, dg_row, db_row, dstarts) in done:
+            dq, dk = dq + dq_, dk + dk_
+            _store_pair(dv_ref, pair, heads, drhs[:c], drhs[c:])
+            dg_ref[pl.ds(pair, 1), :] = dg_row
+            db_ref[pl.ds(pair, 1), :] = db_row
+            for hd in (0, 1):
+                dst_ref[2 * pair + hd] = dstarts[hd]
+        _store_pair(dq_ref, step, heads_k, *(x * q_scale for x in dq))
+        _store_pair(dk_ref, step, heads_k, *dk)
+        return carry
+
+    jax.lax.fori_loop(0, heads_k // 2, key_pair, 0)
+
+
+def _scalar_heads(q, k, *values):
+    """(q, k) at an even number of key heads and ``values`` (arrays with
+    the value heads on axis 2) at as many times more as they came with:
+    an odd count gains a key head of zeros and its value heads (heads that
+    neither decay nor write, and whose cotangents are zero)."""
+    if q.shape[2] % 2 == 0:
+        return (q, k), values
+    more = lambda x, n: jnp.pad(  # noqa: E731
+        x, ((0, 0), (0, 0), (0, n)) + ((0, 0),) * (x.ndim - 3))
+    per_key = values[0].shape[2] // q.shape[2]
+    return (more(q, 1), more(k, 1)), tuple(more(x, per_key) for x in values)
+
+
+def _scalar_operands(q, k, v, g, beta, reverse=False):
+    """``_kernel_operands`` at a scalar gate: q and k have their own head
+    count, g goes as beta does."""
+    b, t = q.shape[:2]
+    n, hv = t // CHUNK, v.shape[2]
+    chunk = (lambda m: n - 1 - m) if reverse else (lambda m: m)
+    q, k, v = (x if x.dtype == jnp.bfloat16 else x.astype(jnp.float32)
+               for x in (q, k, v))
+    rows = lambda x: x.reshape(b, t * x.shape[2], x.shape[-1])  # noqa: E731
+    spec = lambda x: pl.BlockSpec(  # noqa: E731
+        (1, CHUNK * x.shape[2], x.shape[-1]), lambda i, m: (i, chunk(m), 0),
+        memory_space=pltpu.VMEM)
+    row_spec = pl.BlockSpec((None, None, hv // 2, 2 * CHUNK),
+                            lambda i, m: (i, chunk(m), 0, 0),
+                            memory_space=pltpu.VMEM)
+    kept_specs = [
+        pl.BlockSpec((None, None, hv, v.shape[-1], q.shape[-1]),
+                     lambda i, m: (chunk(m), i, 0, 0, 0),
+                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((None, None, hv // 2, CHUNK, 2 * CHUNK),
+                     lambda i, m: (i, chunk(m), 0, 0, 0),
+                     memory_space=pltpu.VMEM)]
+    return ((rows(q), rows(k), rows(v), _pair_rows(g), _pair_rows(beta)),
+            [spec(q), spec(k), spec(v), row_spec, row_spec], spec, kept_specs)
+
+
+@functools.partial(jax.jit, inline=True,
+                   static_argnames=("q_scale", "keep_states", "interpret"))
+def _scalar_forward(q, k, v, g, beta, q_scale, keep_states, interpret):
+    """``_kernel_forward`` at a scalar gate: q, k [B, T, Hk, dk],
+    v [B, T, Hv, dv], g and beta [B, T, Hv], Hv one or two times Hk."""
+    heads = v.shape[2]
+    (q, k), (v, g, beta) = _scalar_heads(q, k, v, g, beta)
+    b, t, h, dv = v.shape
+    dk, n = q.shape[-1], t // CHUNK
+    operands, in_specs, spec, kept_specs = _scalar_operands(q, k, v, g, beta)
+    o = jax.ShapeDtypeStruct((b, t * h, dv), jnp.float32)
+    out_specs, out_shape = [spec(v)], [o]
+    if keep_states:
+        out_specs += kept_specs
+        out_shape += [
+            jax.ShapeDtypeStruct((n, b, h, dv, dk), jnp.float32),
+            jax.ShapeDtypeStruct((b, n, h // 2, CHUNK, 2 * CHUNK), jnp.float32)]
+    out = pl.pallas_call(
+        functools.partial(_scalar_fwd_kernel, q_scale=q_scale,
+                          heads_k=q.shape[2], heads=h,
+                          keep_states=keep_states),
+        grid=(b, n),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((h, dv, dk), jnp.float32)],
+        compiler_params=_COMPILER_PARAMS,
+        interpret=interpret,
+        name=SCALAR_KERNEL_NAME,
+    )(*operands)
+    o = out[0].reshape(b, t, h, dv)[:, :, :heads]
+    return o, (tuple(out[1:]) if keep_states else None)
+
+
+@functools.partial(jax.jit, inline=True,
+                   static_argnames=("q_scale", "interpret"))
+def _scalar_backward(q, k, v, g, beta, kept, do, q_scale, interpret):
+    """``_kernel_backward`` at a scalar gate: dq and dk at the key heads,
+    dg and dbeta [B, T, Hv]."""
+    heads_k, heads, types = q.shape[2], v.shape[2], (q.dtype, k.dtype, v.dtype)
+    (q, k), (v, g, beta, do) = _scalar_heads(q, k, v, g, beta, do)
+    b, t, h, dv = v.shape
+    operands, in_specs, spec, kept_specs = _scalar_operands(
+        q, k, v, g, beta, reverse=True)
+    # (the interpreter and a block's 32-bit view: ``_kernel_backward``)
+    like = lambda x: jax.ShapeDtypeStruct(  # noqa: E731
+        x.shape, jnp.float32 if interpret else x.dtype)
+    dq, dk_, dv_, dg, dbeta = pl.pallas_call(
+        functools.partial(_scalar_bwd_kernel, q_scale=q_scale,
+                          heads_k=q.shape[2], heads=h),
+        grid=(b, t // CHUNK),
+        in_specs=[*in_specs, spec(do), *kept_specs],
+        out_specs=in_specs,
+        out_shape=[like(x) for x in operands],
+        scratch_shapes=[pltpu.VMEM((h, dv, q.shape[-1]), jnp.float32)],
+        compiler_params=_COMPILER_PARAMS,
+        interpret=interpret,
+        name=SCALAR_BACKWARD_KERNEL_NAME,
+    )(*operands, do.reshape(b, t * h, dv), *kept)
+    dq, dk_, dv_ = (
+        x.reshape((b, t, -1, x.shape[-1]))[:, :, :n].astype(dt)
+        for x, n, dt in zip((dq, dk_, dv_), (heads_k, heads_k, heads), types))
+    return (dq, dk_, dv_, *(_token_rows(x)[:, :, :heads] for x in (dg, dbeta)))
+
+
+_scalar_path = _kernel_pair(_scalar_forward, _scalar_backward)
 
 
 def kda_path(dk: int, dv: int, chunk: int = CHUNK,
-             interpret: bool | None = None) -> tuple[str, str]:
+             interpret: bool | None = None,
+             gate_heads: tuple[int, int] | None = None) -> tuple[str, str]:
     """(path, why) ``kda_chunked`` takes at these widths on this backend:
-    ("kernel", ...) or ("scan", the reason it is not the kernel)."""
+    ("kernel", ...) or ("scan", the reason it is not the kernel).
+    ``gate_heads`` is (key heads, value heads) of a call with ONE decay a
+    value head (a gate of rank 3), None of one with a decay a channel."""
     if chunk != CHUNK:
         return "scan", f"chunk {chunk} is not the kernel's {CHUNK}"
     if dk % 128 or dv % 128:
         return "scan", f"dk {dk}, dv {dv} are not multiples of 128"
+    if gate_heads is not None and gate_heads[1] not in (
+            gate_heads[0], 2 * gate_heads[0]):
+        return "scan", ("{1} value heads on {0} key heads: the scalar-gate "
+                        "kernels take one or two a key head").format(*gate_heads)
     backend = jax.default_backend()
     if interpret is None and backend != "tpu":
         return "scan", f"the backend is {backend}, not a TPU"
-    return "kernel", "interpreted" if interpret else "compiled for the TPU"
+    how = "interpreted" if interpret else "compiled for the TPU"
+    return "kernel", how if gate_heads is None else f"scalar gate, {how}"
+
+
+def _per_channel(q, k, v, g):
+    """(q, k, g) as the per-channel definitions above take them: a gate of
+    rank 3 (ONE decay a value head) stands for all its key channels, and
+    key head j serves value heads j r .. j r + r - 1."""
+    if g.ndim == 4:
+        return q, k, g
+    r = v.shape[2] // q.shape[2]
+    q, k = (jnp.repeat(x, r, axis=2) for x in (q, k))
+    return q, k, jnp.broadcast_to(g[..., None], g.shape + q.shape[-1:])
 
 
 def kda_chunked(q, k, v, g, beta, chunk: int = CHUNK, q_scale: float = 1.0,
@@ -699,36 +1133,51 @@ def kda_chunked(q, k, v, g, beta, chunk: int = CHUNK, q_scale: float = 1.0,
     """o [B, T, H, dv] float32 of the recurrence above from S_0 = 0, for
     the queries ``q * q_scale``.
 
-    q, k [B, T, H, dk], v [B, T, H, dv] (kept in the type they come in,
-    bfloat16 activations for one, until a chunk's float32 products),
-    g [B, T, H, dk] (log decay, <= 0), beta [B, T, H]. ``T`` need not be
-    a multiple of ``chunk``: the tail is padded with tokens that neither
-    decay nor write (g = 0, beta = 0, k = 0) and their outputs are
-    dropped. Which path runs, forward and backward (the two kernels, or
-    the scan and its transposition), is ``kda_path``'s answer;
+    The gate's rank says which delta rule: g [B, T, H, dk] is a log decay
+    (<= 0) a key channel, with q, k [B, T, H, dk], v [B, T, H, dv] and
+    beta [B, T, H]; g [B, T, Hv] is ONE log decay a value head (Gated
+    DeltaNet), with q, k [B, T, Hk, dk] at the key heads, Hv a multiple
+    of Hk, v [B, T, Hv, dv] and beta [B, T, Hv] (the gradients of q and k
+    come back at the key heads, g's [B, T, Hv]). q, k, v are kept in the
+    type they come in, bfloat16 activations for one, until a chunk's
+    float32 products. ``T`` need not be a multiple of ``chunk``: the tail
+    is padded with tokens that neither decay nor write (g = 0, beta = 0,
+    k = 0) and their outputs are dropped. Which path runs, forward and
+    backward (a kernel pair, or the scan and its transposition after the
+    scalar gate is repeated and broadcast), is ``kda_path``'s answer;
     ``interpret`` is for tests (True: the kernels, interpreted, off the
     TPU; False: the kernels compiled, for a TPU that is described and not
     attached).
     """
     if chunk & (chunk - 1):
         raise ValueError(f"chunk={chunk} must be a power of two")
+    scalar = g.ndim == 3
+    if scalar and v.shape[2] % q.shape[2]:
+        raise ValueError(
+            f"{v.shape[2]} value heads on {q.shape[2]} key heads")
     t = q.shape[1]
     g, beta = g.astype(jnp.float32), beta.astype(jnp.float32)
     pad = (-t) % chunk
     if pad:
         widths = lambda x: ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2)  # noqa: E731
         q, k, v, g, beta = (jnp.pad(x, widths(x)) for x in (q, k, v, g, beta))
-    if kda_path(q.shape[-1], v.shape[-1], chunk, interpret)[0] == "kernel":
-        o = _kernel_path(q, k, v, g, beta, float(q_scale), bool(interpret))
+    gate_heads = (q.shape[2], v.shape[2]) if scalar else None
+    if kda_path(q.shape[-1], v.shape[-1], chunk, interpret,
+                gate_heads)[0] == "kernel":
+        o = (_scalar_path if scalar else _kernel_path)(
+            q, k, v, g, beta, float(q_scale), bool(interpret))
     else:
+        q, k, g = _per_channel(q, k, v, g)
         o = _scan_forward(q, k, v, g, beta, chunk, q_scale)
     return o[:, :t]
 
 
 def kda_recurrent(q, k, v, g, beta):
-    """The recurrence itself, token by token (tests and small sizes)."""
+    """The recurrence itself, token by token (tests and small sizes), at
+    either form of the gate (``kda_chunked``'s words)."""
     f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
     q, k, v, g, beta = (f32(x) for x in (q, k, v, g, beta))
+    q, k, g = _per_channel(q, k, v, g)
     b, _, h, dk = q.shape
 
     def step(s, xs):

@@ -67,16 +67,18 @@ class LMMetaArch:
             "gated_attn": ("gqa_core", gqa, None),
             # under a per-query selection: an operand, not a shape
             "dsa": ("dsa_core", gqa, None)}
-        delta = {  # the core's scope, the delta rule's (key, value) widths
-            # and the head counts of the planes the mixer's chains lay out
+        gdn_heads = (dc.linear_num_key_heads, dc.linear_num_value_heads)
+        delta = {  # the core's scope, the delta rule's (key, value) widths,
+            # the head counts of the planes the mixer's chains lay out and,
+            # where the gate is ONE decay a value head, those of its call
             "kda": ("kda_core", dc.kda_head_dim, dc.kda_head_dim,
-                    (dc.kda_num_heads,)),
+                    (dc.kda_num_heads,), None),
             "gdn": ("gdn_core", dc.linear_key_head_dim, dc.linear_value_head_dim,
-                    (dc.linear_num_key_heads, dc.linear_num_value_heads))}
+                    gdn_heads, gdn_heads)}
         for i, (mixer, _) in enumerate(dc.layers, 1):
             if mixer in delta:
-                scope, dk, dv, heads = delta[mixer]
-                path, why = kda_path(dk, dv)
+                scope, dk, dv, heads, gate_heads = delta[mixer]
+                path, why = kda_path(dk, dv, gate_heads=gate_heads)
                 logger.info("layer %d %s, both passes: %s (%s)", i, scope, path, why)
                 path, why = mixer_chain_path(rows[1], (dk, dv), heads, dc.dtype)
                 logger.info("layer %d %s_mixer's chains, both passes: %s (%s)",

@@ -3,7 +3,8 @@ attached (on-chip-measurement guide §2.3): flash attention forward and
 backward, plain and segment-masked, at the 768 px (N=2309) and 1024 px
 (N=4101) ViT-L token counts, the fused layernorm forward and
 backward at ViT-L width, and the delta rule's chunk forward and
-backward at the decoder cell's shapes, the causal attention kernels
+backward at the decoder cell's shapes (both gate forms: a decay a
+channel, ONE decay a value head), the causal attention kernels
 at both decoder cells' published shapes (under a selection too, and the
 index loss's kernel beside them), and the delta-rule mixers'
 chains (``ops/mixer_chains.py``) at both delta-rule cells', the gated
@@ -137,6 +138,45 @@ def test_kda_chunk_kernels_compile_for_v5e(one_chip, states):
         # over the chunks beside the kernels
         assert "f32[128,2,32,128,128]" in text
         assert "f32[2,128,16,64,128]" in text and " while(" not in text
+
+
+@pytest.mark.parametrize("states", [False, True], ids=["primal", "states"])
+def test_gdn_chunk_kernels_compile_for_v5e(one_chip, states):
+    """``ops/kda.py``'s scalar-gate pair at the ``qwen3_next`` cell's
+    shapes (2 sequences of 8,192 tokens, 32 value heads on 16 key heads
+    of 128 x 128, bfloat16 q, k, v, ONE float32 log decay a value head):
+    the primal, and the gradient's program — the forward rule with the
+    kept states and inverses and the backward kernel, which packs a lone
+    key head's bfloat16 dq and dk into its half of a word."""
+    from dinov3_tpu.ops.kda import (
+        KERNEL_NAME,
+        SCALAR_BACKWARD_KERNEL_NAME,
+        SCALAR_KERNEL_NAME,
+        kda_chunked,
+    )
+
+    key, value = ((2, 8192, 16, 128), jnp.bfloat16), (
+        (2, 8192, 32, 128), jnp.bfloat16)
+    row, o = ((2, 8192, 32), jnp.float32), (value[0], jnp.float32)
+
+    def fwd(*x):
+        return kda_chunked(*x, q_scale=128 ** -0.5, interpret=False)
+
+    def bwd(*x):
+        return jax.vjp(fwd, *x[:-1])[1](x[-1])
+
+    shapes = [key, key, value, row, row]
+    text = _compiled_text(*((bwd, one_chip, *shapes, o) if states else (
+        fwd, one_chip, *shapes)))
+    assert SCALAR_KERNEL_NAME in text and KERNEL_NAME not in text
+    assert text.count("tpu_custom_call") == (2 if states else 1)
+    assert (SCALAR_BACKWARD_KERNEL_NAME in text) == states
+    # no plane of the gate broadcast over the key channels, no q or k at
+    # the value heads, no loop over the chunks beside the kernels
+    assert "f32[2,8192,32,128,128]" not in text and " while(" not in text
+    if states:
+        assert "f32[128,2,32,128,128]" in text
+        assert "f32[2,128,16,64,128]" in text
 
 
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])

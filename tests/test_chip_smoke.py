@@ -53,7 +53,7 @@ TINY = {
     "gqa_shapes": {"window": (1, 512, 6, 2, 128, 128, 200),
                    "global": (1, 512, 6, 2, 128, 128, None),
                    "mla": (2, 256, 2, 2, 192, 128, None)},
-    "gdn_shape": (1, 128, 2, 128),
+    "gdn_shape": (1, 128, 1, 2, 128),
     "gdn_attn_shapes": {"gated": (1, 512, 4, 2, 256, 256, None)},
     "dsa_shape": (1, 256, 2, 1, 128, 2, 16, 32),
     "sconv_shape": (1, 256, 128),
@@ -127,8 +127,8 @@ def test_smoke_rehearsal_runs_every_phase(smoke, monkeypatch, capsys):
                    "entry point takes the kernel (interpreted)",
                    "gqa: global core, tiles: first calls",
                    "gqa: mla core, kernel at blocks 256 x 128",
-                   "gdn: delta rule (1, 128, 2, 128) at a scalar gate: the "
-                   "entry point takes the kernel (interpreted)",
+                   "gdn: delta rule (1, 128, 1, 2, 128) at a scalar gate: the "
+                   "entry point takes the kernel (scalar gate, interpreted)",
                    "gdn: delta rule: norm of the difference over the norm",
                    "gqa: gated core (1, 512, 4, 2, 256, 256) window None: the "
                    "entry point takes the kernel (interpreted)",

@@ -449,9 +449,10 @@ def test_the_paths_are_read_off_shapes_at_the_published_sizes():
     from dinov3_tpu.ops.kda import kda_path
 
     shapes = ((2, 8192, 16, 256), (2, 8192, 2, 256), (2, 8192, 2, 256))
-    assert kda_path(128, 128, interpret=False)[0] == "kernel"
+    assert kda_path(128, 128, interpret=False, gate_heads=(16, 32)) == (
+        "kernel", "scalar gate, compiled for the TPU")
     assert causal_attention_path(shapes, None, False)[0] == "kernel"
-    assert kda_path(128, 128)[0] == "scan"
+    assert kda_path(128, 128, gate_heads=(16, 32))[0] == "scan"
     assert causal_attention_path(shapes)[0] == "tiles"
     # ONE row of 16,384 tokens would not fit the backward's VMEM
     one_row = tuple((1, 16384) + s[2:] for s in shapes)

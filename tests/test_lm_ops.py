@@ -5,7 +5,9 @@ parameters and bodies as they were):
 (a) the chunked delta rule (ops/kda.py) against the recurrence, forward and
     gradient, over lengths that are and are not multiples of the chunk, at
     any decay; its Pallas kernels (interpreted) against both, and which
-    path a call takes;
+    path a call takes; the same at a gate of rank 3 (ONE decay a value
+    head, q and k at the key heads): the scalar-gate kernel pair against
+    the recurrence and against the per-channel pair;
 (b) latent attention's blockwise causal core (ops/attention.py, q/k wider
     than v) against a plain masked softmax.
 """
@@ -228,6 +230,153 @@ def test_kda_dispatch_reads_the_path_off_the_input():
     assert KERNEL_NAME not in program(128)
     assert KERNEL_NAME not in program(128, chunk=32, interpret=True)
     assert KERNEL_NAME in program(128, interpret=True)
+
+
+# ---------------- (a') the delta rule at a scalar gate ----------------
+
+def _scalar_inputs(seed, b, t, hk, hv, dtype=jnp.float32, rate=1.6):
+    """``_kda_inputs`` at heads of 128 with q and k at ``hk`` key heads and
+    ONE log decay a value head and token (a gate of rank 3)."""
+    q, k, v, g, beta = _kda_inputs(seed, b, t, hv, 128, 128, rate)
+    return (q[:, :, :hk].astype(dtype), k[:, :, :hk].astype(dtype),
+            v.astype(dtype), g[..., 0], beta)
+
+
+def _five_grads(fn, x):
+    return jax.jit(jax.grad(
+        lambda *a: jnp.sum(jnp.sin(fn(*a))), argnums=(0, 1, 2, 3, 4)))(*x)
+
+
+def _same_gradient(got, want):
+    assert got.shape == want.shape and got.dtype == want.dtype
+    if got.dtype == jnp.bfloat16:        # a unit in the last place
+        got, want = got.astype(jnp.float32), want.astype(jnp.float32)
+        assert float(jnp.max(jnp.abs(got - want))) <= 2 ** -7 * float(
+            jnp.max(jnp.abs(want)))
+    else:
+        assert _grad_gap(got, want) <= 0
+
+
+@pytest.mark.parametrize("b, hk, hv, t, dtype", [
+    (1, 1, 2, 128, "float32"), (1, 2, 2, 100, "float32"),
+    (1, 2, 4, 192, "bfloat16"), (2, 3, 3, 64, "bfloat16")],
+    ids=["r2", "r1_tail", "r2_bf16", "r1_odd_bf16"])
+def test_scalar_gate_kernels_are_the_recurrence_and_the_per_channel_kernels(
+        b, hk, hv, t, dtype):
+    """The scalar-gate kernel pair (interpreted) at one and two value heads
+    a key head, float32 and bfloat16 q, k, v, whole chunks, a padded tail,
+    and an odd head count on two sequences of ONE chunk (the state and its
+    cotangent are zeroed a sequence): the token recurrence's output and
+    five gradients, and those of the per-channel kernels fed q and k
+    repeated and the gate broadcast — their dq and dk summed over a key
+    head's value heads, their dg over the key channels."""
+    from dinov3_tpu.ops.kda import _per_channel, kda_chunked, kda_recurrent
+
+    x = _scalar_inputs(t, b, t, hk, hv, jnp.dtype(dtype))
+    kernel = lambda *a: kda_chunked(*a, q_scale=0.25, interpret=True)  # noqa: E731
+    got = jax.jit(kernel)(*x)
+    assert got.shape == (b, t, hv, 128) and got.dtype == jnp.float32
+    np.testing.assert_allclose(
+        got, 0.25 * jax.jit(kda_recurrent)(*x), atol=2e-6)
+    got_g = _five_grads(kernel, x)
+    for g, w in zip(got_g, _five_grads(
+            lambda *a: 0.25 * kda_recurrent(*a), x)):
+        _same_gradient(g, w)
+    # the per-channel kernels at the repeated and broadcast operands, in
+    # float32 so that the sums over heads and channels round once
+    q, k, v, g, beta = (a.astype(jnp.float32) for a in x)
+    wide = _per_channel(q, k, v, g)
+    np.testing.assert_allclose(
+        got, jax.jit(kernel)(wide[0], wide[1], v, wide[2], beta), atol=2e-6)
+    dq, dk, dv, dg, dbeta = _five_grads(
+        kernel, (wide[0], wide[1], v, wide[2], beta))
+    heads = lambda a: a.reshape(b, t, hk, hv // hk, 128).sum(3)  # noqa: E731
+    for g, w in zip(got_g, (heads(dq), heads(dk), dv, dg.sum(-1), dbeta)):
+        _same_gradient(g, w.astype(g.dtype))
+
+
+def test_scalar_gate_kernels_are_finite_at_the_published_decays():
+    """21 nats a token on one head (16 x softplus(1), the fastest head of
+    the published initial values) beside 1e-6 on its pair: above the
+    diagonal G_t - G_s reaches 63 x 21, whose exponential is inf and inf x
+    0 NaN, so the kernels mask the EXPONENT. Output and five gradients
+    finite, and the recurrence's."""
+    from dinov3_tpu.ops.kda import kda_chunked, kda_recurrent
+
+    q, k, v, g, beta = _scalar_inputs(11, 1, 128, 1, 2, jnp.bfloat16)
+    g = jnp.broadcast_to(jnp.array([-21.0, -1e-6]), g.shape)
+    x = (q, k, v, g, beta)
+    kernel = lambda *a: kda_chunked(*a, interpret=True)  # noqa: E731
+    got = jax.jit(kernel)(*x)
+    assert bool(jnp.isfinite(got).all())
+    np.testing.assert_allclose(got, jax.jit(kda_recurrent)(*x), atol=2e-5)
+    for g_, w in zip(_five_grads(kernel, x), _five_grads(kda_recurrent, x)):
+        g_, w = g_.astype(jnp.float32), w.astype(jnp.float32)
+        assert bool(jnp.isfinite(g_).all())
+        assert float(jnp.max(jnp.abs(g_ - w))) <= 2 ** -7 * float(
+            jnp.max(jnp.abs(w))) + 1e-6
+
+
+@pytest.mark.parametrize("case, hk, hv, dk, kw, path, why", [
+    ("kernel", 2, 4, 128, dict(interpret=True), "kernel", "scalar gate, interpreted"),
+    ("described", 16, 32, 128, dict(interpret=False), "kernel",
+     "scalar gate, compiled for the TPU"),
+    ("one_a_key_head", 2, 2, 128, dict(interpret=True), "kernel", "scalar gate"),
+    ("cpu", 2, 4, 128, dict(), "scan", "the backend is cpu"),
+    ("narrow", 2, 4, 16, dict(interpret=True), "scan", "multiples of 128"),
+    ("chunk", 2, 4, 128, dict(chunk=32, interpret=True), "scan", "chunk 32"),
+    ("four_a_key_head", 1, 4, 128, dict(interpret=True), "scan",
+     "4 value heads on 1 key heads"),
+])
+def test_scalar_gate_dispatch_reads_the_path_off_the_operands(
+        case, hk, hv, dk, kw, path, why):
+    """A gate of rank 3 takes the scalar-gate kernels where the per-channel
+    ones would run and the head grouping is one or two value heads a key
+    head; everywhere else q and k are repeated, the gate broadcast and the
+    plain scan runs. ``kda_path`` says which and why, and the program
+    holds the kernel's name or it does not; a gate of rank 4 never reaches
+    the scalar pair."""
+    from dinov3_tpu.ops.kda import (
+        KERNEL_NAME,
+        SCALAR_KERNEL_NAME,
+        kda_chunked,
+        kda_path,
+    )
+
+    found = kda_path(dk, dk, gate_heads=(hk, hv), **kw)
+    assert found[0] == path and why in found[1], found
+    if case == "described":     # nothing here can lower it
+        return
+    x = _scalar_inputs(0, 1, 64, hk, hv)
+    x = tuple(a[..., :dk] if a.ndim == 4 else a for a in x)
+    program = str(jax.make_jaxpr(lambda *a: kda_chunked(*a, **kw))(*x))
+    assert (SCALAR_KERNEL_NAME in program) == (path == "kernel")
+    assert KERNEL_NAME not in program
+    wide = jnp.broadcast_to(x[3][..., None], x[3].shape + (dk,))
+    program = str(jax.make_jaxpr(lambda *a: kda_chunked(*a, **kw))(
+        x[2][..., :dk], x[2][..., :dk], x[2], wide, x[4]))
+    assert SCALAR_KERNEL_NAME not in program
+
+
+def test_scalar_gate_gradient_program_is_two_forward_kernels_and_one_backward():
+    """``test_kda_gradient_program_is_two_forward_kernels_and_one_backward``
+    at a gate of rank 3: under a layer's remat the primal, the forward
+    rule and ONE backward kernel of the scalar pair, no loop over chunks;
+    and a mismatched head grouping is refused by name."""
+    from dinov3_tpu.ops.kda import (
+        SCALAR_BACKWARD_KERNEL_NAME,
+        SCALAR_KERNEL_NAME,
+        kda_chunked,
+    )
+
+    x = _scalar_inputs(0, 1, 128, 1, 2)
+    layer = jax.checkpoint(lambda *a: kda_chunked(*a, interpret=True))
+    assert sorted(_loops_and_kernels(jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(layer(*a)), argnums=(0, 1, 2, 3, 4)))(*x).jaxpr,
+        [])) == [SCALAR_BACKWARD_KERNEL_NAME, SCALAR_KERNEL_NAME,
+                 SCALAR_KERNEL_NAME]
+    with pytest.raises(ValueError, match="3 value heads on 2 key heads"):
+        kda_chunked(*_scalar_inputs(0, 1, 64, 2, 3))
 
 
 # ---------------- (b) the causal blockwise core ----------------
