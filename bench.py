@@ -446,19 +446,15 @@ def main():
     batch_np = make_synthetic_batch(cfg, B, seed=0)
     batch = {k: jnp.asarray(v) for k, v in batch_np.items()}
 
-    # the sharded-update padding guardrail (configs/config.py
-    # warn_update_shard_padding) fires inside build_train_setup, where
-    # the param shapes first exist — capture it into the record like the
-    # tiling warnings above
+    # the layout guardrails fire inside build_train_setup, where the
+    # param shapes and the mesh first coexist — capture them into the
+    # record like the tiling warnings above: the zero3 layout guardrail
+    # (configs/config.py warn_zero3_padding)
     import warnings as _bwarnings
 
     with _bwarnings.catch_warnings(record=True) as _bcaught:
         _bwarnings.simplefilter("always")
         setup = build_train_setup(cfg, batch)
-    pad_warnings = [str(w.message) for w in _bcaught
-                    if "sharded-update flat master axis" in str(w.message)]
-    # ... and the zero3 layout guardrail (configs/config.py
-    # warn_zero3_padding), same capture pattern
     zero3_warnings = [str(w.message) for w in _bcaught
                       if "zero3 master layout" in str(w.message)]
     # ... and the bucket-plan guardrail (configs/config.py
@@ -527,7 +523,7 @@ def main():
         # utils.classify_copy's (rng / donation_async / update_shard /
         # small / large) and utils.classify_collective's (all_reduce /
         # reduce_scatter / all_gather / ppermute / all_to_all /
-        # unattributed; the sharded-update A/B reads the grad-sync story
+        # unattributed; an update-arm A/B reads the grad-sync story
         # straight from by_class)
         from dinov3_tpu.utils import hlo_collective_census, hlo_copy_census
 
@@ -683,8 +679,6 @@ def main():
         rec["collective_census"] = coll_census
     if tiling_warning:
         rec["batch_tiling_warning"] = tiling_warning
-    if pad_warnings:
-        rec["update_shard_padding_warning"] = "; ".join(pad_warnings)
     if zero3_warnings:
         rec["zero3_padding_warning"] = "; ".join(zero3_warnings)
     if bucket_warnings:

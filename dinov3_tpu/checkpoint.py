@@ -25,12 +25,12 @@ logger = logging.getLogger("dinov3")
 def _adapt_opt_leaf(stored, like):
     """One Adam-moment leaf: checkpoint layout -> ``state_like`` layout.
 
-    The sharded update engine (train/fused_update.py,
-    ``optim.sharded_update``) stores mu/nu as flat arrays zero-padded to
-    a multiple of the data-axis size; the replicated engines store them
-    param-shaped. Both directions are lossless: flat -> full drops the
-    (inert, exactly-zero) padding; full -> flat re-adds zeros. Returns a
-    numpy array in ``like``'s shape.
+    The bucketed arm's on-disk layout (train/fused_update.py,
+    ``optim.bucketed_collectives``) stores mu/nu per leaf as flat arrays
+    zero-padded to a multiple of the data-axis size; the other arms
+    store them param-shaped. Both directions are lossless: flat -> full
+    drops the (inert, exactly-zero) padding; full -> flat re-adds zeros.
+    Returns a numpy array in ``like``'s shape.
     """
     import numpy as np
 
@@ -41,10 +41,10 @@ def _adapt_opt_leaf(stored, like):
     for d in like.shape:
         n_like *= int(d)
     if v.ndim == 1 and v.size >= n_like:
-        # sharded checkpoint -> replicated/model layout
+        # per-leaf flat checkpoint -> model layout
         return v[:n_like].reshape(like.shape)
     if len(like.shape) == 1 and v.size <= like.shape[0]:
-        # replicated checkpoint -> sharded flat layout
+        # model-layout checkpoint -> per-leaf flat layout
         flat = v.reshape(-1)
         return np.pad(flat, (0, int(like.shape[0]) - flat.size))
     raise ValueError(
@@ -93,8 +93,8 @@ def _bucketed_moments(state, plan) -> bool:
 
 
 def _flat_moment_abstract(plan):
-    """Per-leaf flat padded abstract moments (``sharded_adam_zeros``
-    shapes) for ``plan``'s student tree — the layout bucketed moments
+    """Per-leaf flat padded abstract moments (one ``[padded_flat_size]``
+    array a leaf) for ``plan``'s student tree — the layout bucketed moments
     persist as. Plain ShapeDtypeStructs, no sharding: the restore path
     stages them addressably and re-places them bucket-by-bucket."""
     import numpy as np
@@ -458,8 +458,8 @@ class Checkpointer:
 
         Checkpoints cross update-engine arms in both directions: a
         replicated-arm checkpoint (param-shaped adam moments) restores
-        into a sharded-update run (flat padded moments,
-        ``optim.sharded_update``) and vice versa — the moment leaves are
+        into the bucketed arm's on-disk layout (per-leaf flat padded
+        moments) and vice versa — the moment leaves are
         detected by shape against the stored metadata, restored in their
         STORED layout, and adapted losslessly (``_adapt_opt_leaf``) onto
         ``state_like``'s placement. The adapting path stages the moments
@@ -471,16 +471,16 @@ class Checkpointer:
         replicated <-> zero3 restores are pure re-placements (orbax
         restores each leaf straight into ``state_like``'s sharding; the
         local-npz backend ``device_put``s per leaf) and need no shape
-        adaptation at all; flat-sharded-update <-> zero3 crossings ride
+        adaptation at all; per-leaf flat <-> zero3 crossings ride
         the same ``_adapt_opt_leaf`` flat/full path as flat <->
-        replicated. Round-trips and resume determinism across all three
-        arms are pinned in tests/test_zero3.py.
+        replicated. Round-trips and resume determinism across the arms
+        are pinned in tests/test_zero3.py and tests/test_ckpt_zero3.py.
 
         The bucketed arm (``optim.bucketed_collectives``) carries its
         moments as {bucket_name: flat} dicts — a different TREE, not
         just different shapes — but persists them per-leaf (``save``
-        above), so its checkpoints are indistinguishable on disk from
-        the flat-sharded arm's. Restoring INTO a bucketed run restores
+        above): one flat padded array a leaf, whatever the plan.
+        Restoring INTO a bucketed run restores
         against the per-leaf on-disk layout first (riding the same
         ``_adapt_opt_leaf`` machinery when the checkpoint came from a
         replicated/zero3 arm) and re-buckets at the end

@@ -82,6 +82,18 @@ def _parse_value(text: str) -> Any:
             return text
 
 
+# ``section.key``s that went with the code they selected. A recipe or an
+# override that still sets one is refused as an unknown key is
+# (``load_config``), with what decides the matter now in the message
+REMOVED_KEYS = {
+    "optim.sharded_update": (
+        "the flat per-leaf sharded update engine is gone; on a pure "
+        "data-parallel mesh optim.bucketed_collectives chooses between "
+        "the bucketed engine (auto/true) and the replicated fused "
+        "engine (false)"),
+}
+
+
 def apply_dot_overrides(cfg: ConfigNode, overrides: Iterable[str]) -> ConfigNode:
     """Apply ``a.b.c=value`` overrides in place; numeric components index
     lists.
@@ -138,8 +150,9 @@ def apply_dot_overrides(cfg: ConfigNode, overrides: Iterable[str]) -> ConfigNode
         else:
             if not allow_new and leaf not in node:
                 raise KeyError(
-                    f"override {item!r}: unknown key {path!r} (prefix "
-                    "with '+' to add new keys)"
+                    f"override {item!r}: unknown key {path!r} ("
+                    + REMOVED_KEYS.get(
+                        path, "prefix with '+' to add new keys") + ")"
                 )
             if (not allow_new and isinstance(node.get(leaf), dict)
                     and not isinstance(value, dict)):
@@ -183,6 +196,11 @@ def load_config(
     if "batch_size_per_gpu" in cfg.train:
         cfg.train.batch_size_per_device = cfg.train.pop("batch_size_per_gpu")
     apply_dot_overrides(cfg, overrides)
+    # a recipe's keys are merged unchecked, and '+' adds any key
+    for path, why in REMOVED_KEYS.items():
+        section, _, leaf = path.partition(".")
+        if leaf in (cfg.get(section) or {}):
+            raise KeyError(f"unknown key {path!r}: {why}")
     apply_scaling_rules_to_cfg(cfg)
     # batch-tiling guardrail: a silent 2.4x cliff is a footgun in a
     # framework whose selling point is TPU-first layout awareness
@@ -448,7 +466,7 @@ def warn_zero3_padding(
     master elements replicated — leaves where no free dimension divides
     the shard count ``dp`` (parallel/sharding.py zero3_replicated_waste),
     the layout's per-device overhead over a perfect 1/dp split and the
-    analogue of the flat update engine's ``warn_update_shard_padding``.
+    analogue of the bucketed engine's ``warn_bucket_padding``.
     Fired at training-setup build (train/setup.py, where the leaf shapes
     and the mesh first coexist) and recorded by ``bench.py``; returns
     the message, or None when the overhead is negligible."""
@@ -492,12 +510,12 @@ def warn_zero3_no_stream(cfg: ConfigNode, stacklevel: int = 2) -> str | None:
 
 
 def update_shard_padding_waste(leaf_sizes, dp: int) -> float:
-    """Fraction of zero-padded lanes the sharded update engine carries.
+    """Fraction of zero-padded lanes the flat per-leaf layout carries.
 
-    The engine (train/fused_update.py make_sharded_update) flattens each
-    master/moment/teacher leaf and zero-pads it to a multiple of the
-    data-axis size ``dp``; padded lanes are inert but still cost HBM
-    traffic and storage on every replica's 1/dp shard. Per-leaf padding
+    A bucket member (train/fused_update.py flatten_update_leaf) is a
+    master/moment/teacher leaf flattened and zero-padded to a multiple
+    of the data-axis size ``dp``; padded lanes are inert but still cost
+    HBM traffic and storage on every replica's 1/dp shard. Per-leaf padding
     is at most ``dp - 1`` elements, so the fraction only matters when a
     model is dominated by tiny leaves or ``dp`` is very large. Returns
     ``padded_extra / total`` (0.0 for an empty tree).
@@ -509,32 +527,6 @@ def update_shard_padding_waste(leaf_sizes, dp: int) -> float:
         total += n
         extra += (-n) % dp
     return extra / total if total else 0.0
-
-
-def warn_update_shard_padding(
-    leaf_sizes, dp: int, threshold: float = 0.01, stacklevel: int = 2,
-) -> str | None:
-    """Warn when sharded-update zero-padding wastes > ``threshold`` of
-    the flattened master size at the chosen data-axis size — the
-    axis-labelled guardrail style of ``warn_bad_batch_tiling``. Fired at
-    training-setup build (train/setup.py, where the param shapes first
-    exist) and by ``bench.py`` (recorded in the bench JSON); returns the
-    message, or None when the padding is negligible."""
-    waste = update_shard_padding_waste(leaf_sizes, dp)
-    if waste <= threshold:
-        return None
-    msg = (
-        f"sharded-update flat master axis: zero-padding to the "
-        f"data-axis size dp={dp} wastes {waste:.1%} of the flattened "
-        f"master size (> {threshold:.0%}) — every replica streams that "
-        f"padding through its 1/dp update shard each step "
-        f"(train/fused_update.py). Use a smaller data-parallel axis for "
-        f"this model, or set optim.sharded_update=false."
-    )
-    import warnings
-
-    warnings.warn(msg, stacklevel=stacklevel + 1)
-    return msg
 
 
 def warn_reshard_padding(
@@ -617,17 +609,19 @@ def bucketed_collectives_wished(cfg: ConfigNode) -> bool:
 
     ``optim.bucketed_collectives``: auto (default) = on — the coalesced
     schedule is the default whenever the setup-time conditions hold.
-    The mesh picks the arm: flat (non-zero3) meshes bucket the sharded
-    UPDATE phase (one reduce-scatter + one all-gather per ~128 MiB
-    flat bucket, train/fused_update.py make_bucketed_update; needs the
-    fused sharded update); zero3 meshes select the UNIFIED arm — the
+    The mesh picks the arm (train/setup.py resolve_update_arm): pure
+    data-parallel meshes shard the UPDATE phase by buckets (one
+    reduce-scatter + one all-gather per ~128 MiB flat bucket,
+    train/fused_update.py make_bucketed_update; needs
+    optim.fused_update); zero3 meshes select the UNIFIED arm — the
     non-block subtree gathers of the forward and their transposed grad
     reduce-scatters coalesce into hierarchy-aware gather buckets
     (gather_zero3_bucketed: intra-slice RS then inter-slice AG staging
     on dp×fsdp meshes) while the update stays shard-local zero3 and the
     block stacks keep the per-block in-scan stream. true = insist
-    (setup raises if the flat arm's conditions cannot hold); false =
-    the per-leaf schedules, the bitwise test oracles for BOTH arms."""
+    (setup raises if the bucketed arm's conditions cannot hold); false =
+    the test oracles: the replicated fused engine on a pure
+    data-parallel mesh, the per-leaf gathers on a zero3 mesh."""
     b = (cfg.get("optim") or {}).get("bucketed_collectives", "auto")
     if isinstance(b, str):
         bl = b.lower()
@@ -701,7 +695,7 @@ def warn_bucket_padding(
     stats, target_bytes: int, threshold: float = 0.05, stacklevel: int = 2,
 ) -> list[str]:
     """Guardrails on a built bucket plan — the axis-labelled style of
-    ``warn_update_shard_padding``, fired at training-setup build
+    ``warn_bad_batch_tiling``, fired at training-setup build
     (train/setup.py, where the plan is first assembled) and recorded by
     ``bench.py``.
 
@@ -760,7 +754,7 @@ def warn_telemetry_flush_period(
 ) -> str | None:
     """Warn when ``telemetry.flush_every`` exceeds the checkpoint period
     or the eval period — the axis-labelled guardrail style of
-    ``warn_update_shard_padding``.
+    ``warn_bad_batch_tiling``.
 
     The async metrics engine (telemetry/ring.py) holds up to
     ``flush_every`` metric rows on device between flushes; a restart

@@ -11,8 +11,8 @@ block i+1 overlaps block i — is then the backend scheduler's decision,
 invisible in the annotation-level program.
 
 ``streamed_block_scan`` below is the same schedule written EXPLICITLY,
-the convention ``make_sharded_update_schedule`` established for the
-sharded update engine: a ``lax.scan`` whose carry holds the NEXT block's
+the convention ``make_bucketed_update_schedule`` keeps for the
+bucketed update engine: a ``lax.scan`` whose carry holds the NEXT block's
 already-gathered weights — iteration i issues the gather of block i+1
 (named scope ``zero3_prefetch``) before running block i's compute on the
 weights gathered one iteration earlier, so the compiled HLO contains the
@@ -224,7 +224,7 @@ def bucketed_stream_scan(
     (and, under ``jax.grad``, its transpose ``psum_scatter`` inside the
     BACKWARD while loop — the overlap-placement evidence
     ``utils.hlo_collective_placement`` classifies and
-    scripts/cost_buckets.py censuses: param gathers ride the forward
+    tests/test_buckets.py censuses: param gathers ride the forward
     loop, the coalesced grad reduce-scatter of bucket *i* is issued as
     backward leaves bucket *i*'s consume, under bucket *i-1*'s backward
     compute).

@@ -24,7 +24,6 @@ from dinov3_tpu.parallel.pipeline import PipelinedBlocks, pipe_axis_size
 from dinov3_tpu.parallel.reshard import (
     RESHARD_SCOPES,
     TopologyDesc,
-    arm_name,
     describe_topology,
     moments_convert_needed,
     reshard_state,
@@ -62,7 +61,6 @@ __all__ = [
     "ring_attention_local",
     "RESHARD_SCOPES",
     "TopologyDesc",
-    "arm_name",
     "describe_topology",
     "moments_convert_needed",
     "reshard_state",

@@ -12,7 +12,7 @@ exactly that collective program. This module packages the whole train
 state that way:
 
 - ``TopologyDesc`` names one side of a transition: mesh + opt-state arm
-  (replicated / flat / bucketed / zero3 / unified) + the state's
+  (replicated / bucketed / zero3 / unified) + the state's
   ``NamedSharding`` tree (+ the ``BucketPlan`` when the arm needs one).
   ``topology_of(setup)`` derives it from a ``TrainSetup``.
 - ``reshard_state(state, src, dst)`` moves a live ``TrainState`` from
@@ -20,9 +20,9 @@ state that way:
   (params / adam-mu / adam-nu / rest), each under its own ``reshard_*``
   named scope so the PR-13 anatomy census attributes every inserted
   collective (``unattributed`` pinned 0, no "other" leakage). Arm
-  changes (flat <-> model-shaped <-> bucketed moment layouts, including
-  dp changes that re-pad the flat forms) convert INSIDE the same
-  program — reshape/pad/slice are free riders on the data movement.
+  changes (model-shaped <-> bucketed moment layouts, including dp
+  changes that re-pad the buckets' flat members) convert INSIDE the
+  same program — reshape/pad/slice are free riders on the data movement.
 - When the target mesh is a different device set (a true resize, e.g.
   dp=8 -> dp=4 on half the devices), no single XLA program can span
   both device assignments: the engine stages the arm conversion on the
@@ -53,16 +53,15 @@ RESHARD_SCOPES = (
     "reshard_params", "reshard_mu", "reshard_nu", "reshard_rest",
 )
 
-# opt-state arms and their adam-moment storage layout:
+# opt-state arms (train/setup.py resolve_update_arm) and their
+# adam-moment storage layout:
 #   model  — param-shaped mu/nu (replicated arm; zero3/unified differ
 #            only in PLACEMENT, which the shardings carry)
-#   flat   — per-leaf flat [padded to a multiple of dp] (optim.sharded_update)
 #   bucket — {bucket_name: flat [S_b]} dicts (optim.bucketed_collectives)
 ARM_LAYOUT = {
     "replicated": "model",
     "zero3": "model",
     "unified": "model",
-    "flat": "flat",
     "bucketed": "bucket",
 }
 
@@ -89,18 +88,6 @@ class TopologyDesc:
         return tuple(d.id for d in self.mesh.devices.flat)
 
 
-def arm_name(setup) -> str:
-    """The opt-state arm a ``TrainSetup`` resolved to."""
-    if getattr(setup, "bucketed", False):
-        return "bucketed"
-    if getattr(setup, "zero3", False):
-        return "unified" if getattr(setup, "zero3_buckets", False) \
-            else "zero3"
-    if getattr(setup, "sharded_update", False):
-        return "flat"
-    return "replicated"
-
-
 def topology_of(setup) -> TopologyDesc:
     """Derive the ``TopologyDesc`` of a built ``TrainSetup`` (the state
     may be concrete or abstract — only shapes/dtypes are read)."""
@@ -109,7 +96,7 @@ def topology_of(setup) -> TopologyDesc:
         lambda x: jax.ShapeDtypeStruct(tuple(x.shape), x.dtype), student)
     return TopologyDesc(
         mesh=setup.mesh,
-        arm=arm_name(setup),
+        arm=setup.arm,
         dp=update_shard_size(setup.mesh),
         shardings=setup.state_shardings,
         student_like=like,
@@ -134,38 +121,30 @@ def _moments_to_model(m, src: TopologyDesc):
     """Arm storage layout -> the model-shaped canonical."""
     from dinov3_tpu.train.fused_update import unflatten_update_leaf
 
-    kind = ARM_LAYOUT[src.arm]
-    if kind == "bucket":
-        m = src.bucket_plan.buckets_to_flat_tree(dict(m))
-        kind = "flat"
-    if kind == "flat":
-        return jax.tree.map(
-            lambda f, p: unflatten_update_leaf(f, p), m, src.student_like)
-    return m
+    if ARM_LAYOUT[src.arm] == "model":
+        return m
+    # through the per-leaf flat padded intermediate
+    flat = src.bucket_plan.buckets_to_flat_tree(dict(m))
+    return jax.tree.map(unflatten_update_leaf, flat, src.student_like)
 
 
 def _moments_from_model(m, dst: TopologyDesc):
     """Model-shaped canonical -> ``dst``'s arm storage layout."""
     from dinov3_tpu.train.fused_update import flatten_update_leaf
 
-    kind = ARM_LAYOUT[dst.arm]
-    if kind == "model":
+    if ARM_LAYOUT[dst.arm] == "model":
         return m
     flat = jax.tree.map(lambda x: flatten_update_leaf(x, dst.dp), m)
-    if kind == "flat":
-        return flat
     return dst.bucket_plan.flat_tree_to_buckets(flat)
 
 
 def moments_convert_needed(src: TopologyDesc, dst: TopologyDesc) -> bool:
     """Whether the adam moments change STORAGE layout (not just
-    placement) across the transition. flat/bucket layouts depend on dp
-    (the zero padding) and, bucketed, on the plan itself."""
+    placement) across the transition. The bucket layout depends on dp
+    (the members' zero padding) and on the plan itself."""
     sk, dk = ARM_LAYOUT[src.arm], ARM_LAYOUT[dst.arm]
     if sk != dk:
         return True
-    if sk == "flat":
-        return src.dp != dst.dp
     if sk == "bucket":
         return (src.dp != dst.dp
                 or src.bucket_plan is not dst.bucket_plan
@@ -292,8 +271,8 @@ def reshard_state(
         "padding_warnings": [],
     }
     if (moments_convert_needed(src, dst)
-            and ARM_LAYOUT[dst.arm] in ("flat", "bucket")):
-        # the target re-pads the flat moment layouts to ITS dp — a
+            and ARM_LAYOUT[dst.arm] == "bucket"):
+        # the target re-pads the buckets' flat members to ITS dp — a
         # permanent per-step tax the one-time reshard signs up for;
         # gate it (configs/config.py warn_reshard_padding live mode)
         from dinov3_tpu.configs.config import warn_reshard_padding

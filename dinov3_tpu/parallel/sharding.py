@@ -59,29 +59,21 @@ DEFAULT_LOGICAL_RULES = (
     # as "batch" — see constrain_packed_rows below for why the row
     # ORDER, not just the rule, is what keeps the pack shard-local.
     ("packed_rows", ("dcn_data", "data", "fsdp")),
-    # cross-replica sharded update (train/fused_update.py
-    # make_sharded_update): the flat padded axis of every optimizer-
-    # moment leaf splits over the SAME axes as "batch", so the
-    # reduce-scatter of grads and the all-gather of updated params
-    # lower onto the mesh axes the batch already rides — each data
-    # replica owns 1/dp of every master/moment/teacher leaf for the
-    # update phase (Xu et al. 2020's automatic cross-replica sharding,
-    # realized through GSPMD annotations instead of a manual pass).
-    ("update_shard", ("dcn_data", "data", "fsdp")),
     # bucketed collective engine (train/fused_update.py
     # make_bucketed_update): the flat axis of every COALESCED update
     # bucket — a few large concatenations of padded-flat leaves grouped
-    # by (submodel, dtype, param-group) — splits over the same axes as
-    # "update_shard", so the one-reduce-scatter-per-bucket grad sync and
-    # the one-all-gather-per-bucket param/teacher re-materialization
-    # ride the mesh axes the batch already rides. Same placement as
-    # "update_shard", separate NAME: the census and the sharding
-    # metadata can tell a per-leaf flat shard from a coalesced bucket.
+    # by (submodel, dtype, param-group) — splits over the SAME axes as
+    # "batch", so the one-reduce-scatter-per-bucket grad sync and the
+    # one-all-gather-per-bucket param/teacher re-materialization ride
+    # the mesh axes the batch already rides — each data replica owns
+    # 1/dp of every master/moment/teacher leaf for the update phase
+    # (Xu et al. 2020's automatic cross-replica sharding, realized
+    # through GSPMD annotations instead of a manual pass).
     ("bucket", ("dcn_data", "data", "fsdp")),
 )
 
-# the mesh axes the sharded update engine splits over — one tuple shared
-# by the logical rule above, the in-graph constraint below, and the
+# the mesh axes a sharded update splits over — one tuple shared by the
+# logical rule above, the in-graph constraints below, and the
 # setup-time axis-size product, so the three can never disagree
 UPDATE_SHARD_AXES = ("dcn_data", "data", "fsdp")
 
@@ -177,11 +169,11 @@ def update_shard_size(mesh: Mesh | None = None) -> int:
 def constrain_update_shard(x: jax.Array,
                            mesh: Mesh | None = None) -> jax.Array:
     """Pin a flat padded update-phase leaf (1-D, size divisible by
-    ``update_shard_size``) onto the data axes — the "update_shard"
-    logical rule. The sharded update engine routes every flattened
-    grad/master/moment/teacher leaf through this, so the grad
-    reduce-scatter and the param all-gather lower onto the same mesh
-    axes as "batch". No-op without a mesh (replicated test shapes)."""
+    ``update_shard_size``) onto the data axes. The bucketed engine
+    routes every flattened master/teacher/multiplier leaf, and every
+    member it slices out of a bucket, through this, so its leaf-by-leaf
+    math runs on 1/dp shards over the same mesh axes as "batch". No-op
+    without a mesh (replicated test shapes)."""
     if mesh is None:
         from dinov3_tpu.parallel.context import get_current_mesh
 
@@ -286,7 +278,7 @@ def state_shardings_from_abstract(
 # The zero3 engine (train/setup.py, parallel.zero3) stores every master/
 # teacher/moment leaf in its MODEL shape but sharded over the data axes
 # on one dividing dimension — unlike the flat padded layout of the
-# sharded UPDATE engine ("update_shard" above), which is a step-internal
+# bucketed UPDATE engine ("bucket" above), which is a step-internal
 # packing. Keeping the model shape is what makes the rest of the system
 # compose: the scanned block stack enters ``lax.scan`` still sharded and
 # each block is all-gathered *inside* the loop at its use (a flat layout
@@ -391,9 +383,9 @@ def zero3_replicated_waste(
 ) -> float:
     """Fraction of master elements zero3 cannot shard (no free dim
     divides the shard count) — the layout's per-device overhead over a
-    perfect 1/dp split, the analogue of the flat engine's zero-padding
-    waste. ``shapes_and_names``: iterable of (shape, names) pairs from
-    the boxed abstract tree. Returns 0.0 for an empty tree."""
+    perfect 1/dp split, the analogue of the bucketed engine's
+    zero-padding waste. ``shapes_and_names``: iterable of (shape, names)
+    pairs from the boxed abstract tree. Returns 0.0 for an empty tree."""
     total = stuck = 0
     for shape, names in shapes_and_names:
         n = 1

@@ -2,14 +2,11 @@ from dinov3_tpu.train.fused_update import (
     BucketPlan,
     build_bucketed_update,
     build_fused_update,
-    build_sharded_update,
     bucketed_adam_zeros,
     make_bucket_plan,
     make_bucketed_update,
     make_bucketed_update_schedule,
     make_fused_update,
-    make_sharded_update,
-    make_sharded_update_schedule,
 )
 from dinov3_tpu.train.optimizer import (
     build_optimizer,
@@ -35,8 +32,6 @@ from dinov3_tpu.train.train_step import TrainState, make_train_step
 
 __all__ = [
     "build_fused_update", "make_fused_update",
-    "build_sharded_update", "make_sharded_update",
-    "make_sharded_update_schedule",
     "BucketPlan", "make_bucket_plan", "bucketed_adam_zeros",
     "build_bucketed_update", "make_bucketed_update",
     "make_bucketed_update_schedule",

@@ -1,5 +1,5 @@
 """Single-pass fused clip + AdamW + teacher-EMA update engine, and its
-cross-replica sharded form.
+cross-replica sharded forms.
 
 The r5 on-chip profile (``PROFILE_r05.json``, docs/PERFORMANCE.md) puts
 28.5% of the ViT-L step in norm/reduce fusions whose largest named
@@ -37,42 +37,42 @@ derivation (train/setup.py eval_shape) and buffer donation are
 identical on both paths. Toggle with ``optim.fused_update`` (default
 on); the bench A/B rung has not been run on the chip.
 
-Cross-replica SHARDED update (``make_sharded_update``, toggled by
-``optim.sharded_update``, auto = on when the data-parallel axis product
-is > 1): every replica of the fused engine above still runs the full
-single-pass update over the complete fp32 master/moment/teacher trees —
-dp-way redundant compute and HBM traffic on exactly the weight-shaped
-~12 ms/step floor. Following "Automatic Cross-Replica Sharding of
-Weight Update in Data-Parallel Training" (Xu et al., 2020), the sharded
-engine reshapes the update phase into
+Cross-replica SHARDED forms. Every replica of the engine above runs the
+full single pass over the complete fp32 master/moment/teacher trees:
+dp-way redundant compute and HBM traffic on exactly that weight-shaped
+floor. Following "Automatic Cross-Replica Sharding of Weight Update in
+Data-Parallel Training" (Xu et al., 2020) a data-parallel mesh reshapes
+the update phase into
 
-    reduce-scatter(grads) -> per-shard clip+AdamW+EMA over 1/dp of
-    every leaf -> all-gather(updated student + EMA'd teacher)
+    reduce-scatter(grads) -> clip+AdamW+EMA over 1/dp of every leaf ->
+    all-gather(updated student + EMA'd teacher)
 
-realized through GSPMD sharding annotations (parallel/sharding.py
-"update_shard" rule, the same mesh axes "batch" rides) instead of a
-manual collective pass: each leaf is flattened, zero-padded to a
-multiple of dp (padded lanes are inert — g=p=mu=nu=0 stays 0 through
-the update math), and pinned shard-wise with
-``constrain_update_shard``; the optimizer moments are BORN in that flat
-sharded layout (``sharded_adam_zeros``, train/setup.py), so each
-replica stores 1/dp of mu/nu (ZeRO-1) and the update's elementwise
-traffic drops by the same factor. The per-submodel clip norms come out
-as shard-local partial sums + one small psum (the same
-``per_submodel_norms`` graph, now over the flat sharded leaves), so
-clipping matches the replicated oracle up to reduction associativity.
-The jit-level out_shardings re-materialize the updated student/teacher
-in their model layout — the all-gather. On this container's XLA:CPU the
-grad sync lowers structurally as all-reduce + fused dynamic-slice (the
-pre-rewrite form); TPU/GPU XLA's collective optimizer rewrites that
-pair into the reduce-scatter the annotations describe —
-``make_sharded_update_schedule`` below is the same schedule written
-with explicit collectives (shard_map + psum_scatter/all_gather), used
-by scripts/cost_sharded_update.py so the committed census shows the
-post-rewrite collective set on any backend. The replicated fused engine
-stays the test oracle behind ``optim.sharded_update=false``
-(leaf-for-leaf equivalence pinned in tests/test_sharded_update.py);
-the on-chip A/B has not been run on the chip.
+and which engine does so follows from the mesh (train/setup.py
+``resolve_update_arm``, the one place that chooses):
+
+- one device: ``make_fused_update`` as it stands (what every benchmark
+  cell runs), and on any mesh behind ``optim.bucketed_collectives=
+  false`` with GSPMD's all-reduce in front of it: the oracle the
+  sharded forms are tested against;
+- a pure data-parallel mesh: ``make_bucketed_update`` below. The leaves
+  are coalesced into large flat buckets, ONE reduce-scatter a bucket
+  for the grads and ONE all-gather a bucket for each updated tree, the
+  adam moments BORN in the bucket layout so that each replica stores
+  1/dp of mu/nu (ZeRO-1). A leaf enters a bucket flat and zero-padded to
+  a multiple of dp (``flatten_update_leaf``; padded lanes are inert:
+  g = p = mu = nu = 0 stays 0 through the update math), which is also
+  the layout the moments have on disk (checkpoint.py);
+- an fsdp mesh (ZeRO-3, ``parallel.zero3``): masters, teacher and
+  moments are already sharded in their model shapes, the update is
+  ``make_fused_update`` run shard-local, and what is coalesced is the
+  forward's non-block weight gathers (``gather_zero3_bucketed`` at the
+  end of this file).
+
+GSPMD's annotations express the collectives (parallel/sharding.py
+"bucket" rule). XLA:CPU lowers the grad sync as all-reduce + dynamic-
+slice, which TPU XLA rewrites into the reduce-scatter they describe;
+``make_bucketed_update_schedule`` is the same schedule with explicit
+collectives, whose census shows the rewritten set on any backend.
 """
 
 from __future__ import annotations
@@ -147,9 +147,9 @@ def update_leaf_math(g, p, mu, nu, t, lm, wm, is_ll, scale,
     """The single-pass clip+AdamW+EMA per-leaf rule.
 
     Single source of truth for the update math: the replicated fused
-    engine, the cross-replica sharded engine, and the explicit-collective
-    schedule program all call this exact function (on full leaves, flat
-    1/dp shards, and shard_map-local shards respectively), so the three
+    engine, the bucketed engine, and its explicit-collective schedule
+    program all call this exact function (on full leaves, flat 1/dp
+    shards, and shard_map-local shards respectively), so the three
     step programs cannot drift apart. Returns ``(new_param, new_mu,
     new_nu[, new_teacher])``.
     """
@@ -291,7 +291,11 @@ def build_fused_update(
     )
 
 
-# ---------------- cross-replica sharded update engine ----------------
+# ---------------- the flat per-leaf layout ----------------
+#
+# A leaf flat and zero-padded to a multiple of dp: what a bucket's
+# members are made of, and the layout the bucketed arm's moments have
+# on disk (checkpoint.py, parallel/reshard.py).
 
 def padded_flat_size(n: int, dp: int) -> int:
     """Flat leaf size padded up to a multiple of the shard count."""
@@ -314,7 +318,7 @@ def flatten_update_leaf(x, dp: int):
     direction is 0/(sqrt(0)+eps) = 0, weight decay contributes
     wd*wm*0 = 0, and the lane stays exactly 0 forever — flatten/
     unflatten round-trips are lossless (pinned in
-    tests/test_sharded_update.py).
+    tests/test_buckets.py).
     """
     flat = x.reshape(-1)
     pad = (-flat.size) % dp
@@ -328,395 +332,20 @@ def unflatten_update_leaf(flat, like):
     return flat[: leaf_size(like)].reshape(like.shape)
 
 
-def sharded_adam_zeros(student_abstract: Any, dp: int) -> Any:
-    """Flat sharded-layout Adam moment zeros, boxed for sharding
-    derivation.
-
-    Mirrors ``optax.scale_by_adam``'s ``zeros_like`` init but in the
-    sharded engine's storage layout: one flat [padded] leaf per param
-    (padded_flat_size), boxed with the "update_shard" LOGICAL axis (the
-    same ``with_logical_partitioning`` box class the model params use,
-    so unboxing under a mesh context resolves through the logical rules
-    instead of demanding a literal mesh axis) —
-    ``state_shardings_from_abstract`` then lays each replica's 1/dp
-    slice onto the data axes. Used by train/setup.py's boxed init;
-    ``student_abstract`` is the *unboxed* student param tree (abstract
-    or concrete — only shapes/dtypes are read).
-    """
-    import flax.linen as nn
-
-    def z(p):
-        init = nn.with_logical_partitioning(
-            lambda: jnp.zeros((padded_flat_size(leaf_size(p), dp),),
-                              p.dtype),
-            ("update_shard",),
-        )
-        return init()
-
-    return jax.tree.map(z, student_abstract)
-
-
-def _check_sharded_opt_state(opt_state, grads, dp: int) -> None:
-    if not isinstance(opt_state, ScheduledAdamWState):
-        raise TypeError(
-            "sharded update engine requires the scheduled_adamw state, "
-            f"got {type(opt_state).__name__}"
-        )
-    g0 = jax.tree.leaves(grads)[0]
-    mu0 = jax.tree.leaves(opt_state.adam.mu)[0]
-    want = padded_flat_size(leaf_size(g0), dp)
-    if mu0.ndim != 1 or mu0.shape[0] != want:
-        raise TypeError(
-            "sharded update engine requires the flat sharded opt state "
-            f"(mu leaf {mu0.shape}, expected ({want},) at dp={dp}); init "
-            "via build_train_setup with optim.sharded_update on, or "
-            "restore through Checkpointer (which adapts replicated "
-            "checkpoints to the sharded layout)"
-        )
-
-
-def make_sharded_update(
-    schedules: Schedules,
-    lr_mult: Any,
-    wd_mult: Any,
-    is_last_layer: Any,
-    mesh: Any,
-    b1: float = 0.9,
-    b2: float = 0.999,
-    eps: float = 1e-8,
-    clip_grad: float | None = None,
-    ema: bool = True,
-) -> Callable:
-    """Build the cross-replica sharded engine (module docstring).
-
-    Same contract as ``make_fused_update`` — ``update(grads, params,
-    teacher, opt_state, momentum) -> (new_params, new_teacher,
-    new_opt_state, norms)`` — except ``opt_state.adam.mu/nu`` leaves are
-    flat [padded] arrays in the "update_shard" layout
-    (``sharded_adam_zeros``). Params/teacher enter and leave in their
-    model layout; their shard-layout forms live only inside the step.
-    """
-    from dinov3_tpu.parallel.sharding import (
-        constrain_update_shard,
-        update_shard_size,
-    )
-
-    dp = update_shard_size(mesh)
-    lr_arr = jnp.asarray(schedules.lr, jnp.float32)
-    ll_lr_arr = jnp.asarray(schedules.last_layer_lr, jnp.float32)
-    wd_arr = jnp.asarray(schedules.weight_decay, jnp.float32)
-    do_clip = clip_grad is not None and clip_grad > 0
-
-    def to_shard(x):
-        with jax.named_scope("update_shard_pack"):
-            return constrain_update_shard(flatten_update_leaf(x, dp), mesh)
-
-    def mult_to_shard(m, like):
-        # scalar multipliers ride along unchanged; scanned-stack [L,1,..]
-        # multiplier arrays are materialized per element before the leaf
-        # shape is flattened away (XLA fuses the broadcast into the
-        # update kernel)
-        if getattr(m, "ndim", 0) == 0:
-            return m
-        return to_shard(jnp.broadcast_to(m, like.shape).astype(jnp.float32))
-
-    def from_shard(flat, like):
-        with jax.named_scope("update_shard_unpack"):
-            return unflatten_update_leaf(flat, like)
-
-    def update(grads, params, teacher, opt_state, momentum):
-        _check_sharded_opt_state(opt_state, grads, dp)
-        i = jnp.minimum(opt_state.count, lr_arr.shape[0] - 1)
-        lr_t, ll_lr_t, wd_t = lr_arr[i], ll_lr_arr[i], wd_arr[i]
-        count_inc = _safe_int32_increment(opt_state.adam.count)
-        bc1 = 1 - b1 ** count_inc
-        bc2 = 1 - b2 ** count_inc
-
-        g_flat = jax.tree.map(to_shard, grads)
-        p_flat = jax.tree.map(to_shard, params)
-        t_flat = (jax.tree.map(to_shard, teacher) if ema
-                  else jax.tree.map(lambda _: jnp.float32(0.0), g_flat))
-        lm_flat = jax.tree.map(mult_to_shard, lr_mult, params)
-        wm_flat = jax.tree.map(mult_to_shard, wd_mult, params)
-        # fusion cut: the flat working set is materialized here, so the
-        # elementwise update subgraph below compiles independently of
-        # how the flat leaves were produced — the bucketed engine
-        # (make_bucketed_update) shares this exact subgraph behind the
-        # same barrier. The REDUCTION path is bitwise identical between
-        # the two arms regardless (the shard-interleaved bucket layout
-        # makes the coalesced reduce-scatter compute segment-for-segment
-        # the per-leaf sums; tests/test_buckets.py pins moments + clip
-        # norms bitwise). The elementwise outputs are bitwise wherever
-        # the backend honors the barrier as a fusion boundary; XLA:CPU
-        # expands optimization-barrier away pre-fusion, so on the CPU
-        # test harness params/teacher may drift by ~1-2 ulp of FMA
-        # contraction context (pinned at the PR-5 tolerances).
-        (g_flat, p_flat, t_flat, lm_flat, wm_flat, mu_in, nu_in) = (
-            jax.lax.optimization_barrier(
-                (g_flat, p_flat, t_flat, lm_flat, wm_flat,
-                 opt_state.adam.mu, opt_state.adam.nu)))
-        norms = {}
-        if do_clip:
-            # the identical per_submodel_norms graph as the oracle, now
-            # over the flat sharded leaves: GSPMD lowers it as
-            # shard-local partial norms + one small psum
-            norms = per_submodel_norms(g_flat)
-            scales = {
-                k: jnp.minimum(1.0, clip_grad / jnp.maximum(n, 1e-12))
-                for k, n in norms.items()
-            }
-            scale_tree = {
-                k: jax.tree.map(lambda _, s=scales[k]: s, sub)
-                for k, sub in g_flat.items()
-            }
-        else:
-            scale_tree = jax.tree.map(lambda _: _NO_CLIP, g_flat)
-
-        def leaf(g, p, mu, nu, t, lm, wm, is_ll, scale):
-            return update_leaf_math(
-                g, p, mu, nu, t, lm, wm, is_ll, scale,
-                lr_t, ll_lr_t, wd_t, bc1, bc2, b1, b2, eps, momentum, ema,
-            )
-
-        n_out = 4 if ema else 3
-        fused = jax.tree.map(
-            leaf, g_flat, p_flat, mu_in, nu_in,
-            t_flat, lm_flat, wm_flat, is_last_layer, scale_tree,
-        )
-        outs = jax.tree.transpose(
-            jax.tree.structure(g_flat),
-            jax.tree.structure(tuple(range(n_out))),
-            fused,
-        )
-        # closing fusion cut (comment above): the consumers — per-leaf
-        # unflatten here, bucket re-pack in the bucketed engine — stay
-        # out of the shared math subgraph
-        outs = jax.lax.optimization_barrier(outs)
-        if ema:
-            p_new_flat, new_mu, new_nu, t_new_flat = outs
-            new_teacher = jax.tree.map(from_shard, t_new_flat, teacher)
-        else:
-            p_new_flat, new_mu, new_nu = outs
-            new_teacher = teacher
-        # the jit-level out_shardings restore the model layout — this
-        # unflatten is where GSPMD inserts the param/teacher all-gather
-        new_params = jax.tree.map(from_shard, p_new_flat, params)
-        new_opt_state = ScheduledAdamWState(
-            count=opt_state.count + 1,
-            adam=optax.ScaleByAdamState(
-                count=count_inc, mu=new_mu, nu=new_nu
-            ),
-        )
-        return new_params, new_teacher, new_opt_state, norms
-
-    return update
-
-
-def build_sharded_update(
-    cfg, params: Any, schedules: Schedules, mesh: Any, ema: bool = True
-) -> Callable:
-    """Wire config -> multiplier trees -> sharded engine
-    (``build_fused_update``'s twin; same inputs, same validation)."""
-    lr_mult, wd_mult, is_last = build_multiplier_trees(
-        params,
-        layerwise_decay=cfg.optim.layerwise_decay,
-        patch_embed_lr_mult=cfg.optim.patch_embed_lr_mult,
-        dino_head_wd_multiplier=cfg.optim.dino_head_wd_multiplier,
-    )
-    if cfg.optim.optimizer != "adamw":
-        raise ValueError(
-            f"sharded update engine supports adamw only, got "
-            f"{cfg.optim.optimizer!r}; set optim.sharded_update=false"
-        )
-    return make_sharded_update(
-        schedules, lr_mult, wd_mult, is_last, mesh,
-        b1=cfg.optim.adamw_beta1, b2=cfg.optim.adamw_beta2,
-        clip_grad=cfg.optim.clip_grad, ema=ema,
-    )
-
-
-def make_sharded_update_schedule(
-    schedules: Schedules,
-    lr_mult: Any,
-    wd_mult: Any,
-    is_last_layer: Any,
-    mesh: Any,
-    b1: float = 0.9,
-    b2: float = 0.999,
-    eps: float = 1e-8,
-    clip_grad: float | None = None,
-    ema: bool = True,
-) -> Callable:
-    """The sharded update schedule with EXPLICIT collectives.
-
-    ``make_sharded_update`` expresses the schedule through GSPMD
-    annotations, which this container's XLA:CPU lowers as all-reduce +
-    fused dynamic-slice (the pre-rewrite form of reduce-scatter; the
-    TPU/GPU collective optimizer performs that rewrite). This builder
-    writes the same schedule as a shard_map island whose collectives
-    are spelled out — ``psum_scatter`` (reduce-scatter) over the
-    stacked per-replica partial grads, shard-local
-    ``update_leaf_math``, ``all_gather`` of the updated student/teacher,
-    and ONE small psum for the per-submodel clip norms — so the
-    compiled HLO contains the literal reduce-scatter/all-gather ops on
-    every backend. scripts/cost_sharded_update.py compiles this program
-    for the committed collective census and per-device byte accounting;
-    tests/test_sharded_update.py pins both its numerics (against the
-    fused oracle) and its collective set.
-
-    Returns ``schedule(grad_partials, params, teacher, opt_state,
-    momentum) -> (new_params, new_teacher, new_opt_state, norms)`` where
-    ``grad_partials`` leaves are [dp, *leaf_shape] stacks of the
-    per-replica partial gradients (dim 0 sharded over the data axes —
-    what the data-parallel backward holds before any grad sync), and
-    ``opt_state`` is in the flat sharded layout (``sharded_adam_zeros``).
-    """
-    from dinov3_tpu.parallel.sharding import (
-        UPDATE_SHARD_AXES,
-        update_shard_size,
-    )
-    from jax.sharding import PartitionSpec as P
-
-    dp = update_shard_size(mesh)
-    axes = tuple(a for a in UPDATE_SHARD_AXES if a in mesh.shape)
-    lr_arr = jnp.asarray(schedules.lr, jnp.float32)
-    ll_lr_arr = jnp.asarray(schedules.last_layer_lr, jnp.float32)
-    wd_arr = jnp.asarray(schedules.weight_decay, jnp.float32)
-    do_clip = clip_grad is not None and clip_grad > 0
-    shard_spec, rep_spec = P(axes), P()
-
-    def schedule(grad_partials, params, teacher, opt_state, momentum):
-        _check_sharded_opt_state(
-            opt_state, jax.tree.map(lambda g: g[0], grad_partials), dp
-        )
-        # flat padded shard-layout forms of everything the local body
-        # consumes (multipliers materialized per element, as in
-        # make_sharded_update; the in_specs slice each replica's shard)
-        p_flat = jax.tree.map(lambda p: flatten_update_leaf(p, dp), params)
-        t_flat = (jax.tree.map(lambda t: flatten_update_leaf(t, dp), teacher)
-                  if ema else jax.tree.map(lambda _: 0.0, grad_partials))
-        mults = jax.tree.map(
-            lambda m, p: m if getattr(m, "ndim", 0) == 0 else
-            flatten_update_leaf(
-                jnp.broadcast_to(m, p.shape).astype(jnp.float32), dp),
-            {"lm": lr_mult, "wm": wd_mult},
-            {"lm": params, "wm": params},
-        )
-        # per-leaf specs: scalar multipliers are replicated, flat padded
-        # leaves live in the shard layout
-        mults_spec = jax.tree.map(
-            lambda m: rep_spec if getattr(m, "ndim", 0) == 0 else shard_spec,
-            mults,
-        )
-        tf_spec = shard_spec if ema else rep_spec
-
-        def body(gp, pf, tf, mu, nu, ms, count, adam_count, mom):
-            i = jnp.minimum(count, lr_arr.shape[0] - 1)
-            lr_t, ll_lr_t, wd_t = lr_arr[i], ll_lr_arr[i], wd_arr[i]
-            count_inc = _safe_int32_increment(adam_count)
-            bc1 = 1 - b1 ** count_inc
-            bc2 = 1 - b2 ** count_inc
-            # reduce-scatter: each replica's full partial grad -> the
-            # cross-replica SUM of its own 1/dp shard
-            g_shard = jax.tree.map(
-                lambda g: jax.lax.psum_scatter(
-                    flatten_update_leaf(g[0], dp), axes,
-                    scatter_dimension=0, tiled=True),
-                gp,
-            )
-            norms = {}
-            if do_clip:
-                # shard-local partial norms + ONE small psum (a dict of
-                # scalars) — the whole-grad norms, never materializing
-                # a whole grad anywhere
-                partial = {
-                    k: sum(jnp.sum(jnp.square(l.astype(jnp.float32)))
-                           for l in jax.tree.leaves(sub))
-                    for k, sub in g_shard.items()
-                }
-                norms = {k: jnp.sqrt(v)
-                         for k, v in jax.lax.psum(partial, axes).items()}
-                scale_tree = {
-                    k: jax.tree.map(
-                        lambda _, s=jnp.minimum(
-                            1.0, clip_grad / jnp.maximum(norms[k], 1e-12)
-                        ): s, sub)
-                    for k, sub in g_shard.items()
-                }
-            else:
-                scale_tree = jax.tree.map(lambda _: _NO_CLIP, g_shard)
-
-            def leaf(g, p, mu_l, nu_l, t, lm, wm, is_ll, scale):
-                return update_leaf_math(
-                    g, p, mu_l, nu_l, t, lm, wm, is_ll, scale,
-                    lr_t, ll_lr_t, wd_t, bc1, bc2, b1, b2, eps, mom, ema,
-                )
-
-            n_out = 4 if ema else 3
-            fused = jax.tree.map(
-                leaf, g_shard, pf, mu, nu, tf,
-                ms["lm"], ms["wm"], is_last_layer, scale_tree,
-            )
-            outs = jax.tree.transpose(
-                jax.tree.structure(g_shard),
-                jax.tree.structure(tuple(range(n_out))),
-                fused,
-            )
-            # all-gather: updated student (+ EMA'd teacher) shards back
-            # to every replica
-            def gather(x):
-                return jax.lax.all_gather(x, axes, tiled=True)
-
-            if ema:
-                p_new, new_mu, new_nu, t_new = outs
-                t_full = jax.tree.map(gather, t_new)
-            else:
-                p_new, new_mu, new_nu = outs
-                t_full = tf
-            p_full = jax.tree.map(gather, p_new)
-            return p_full, t_full, new_mu, new_nu, norms
-
-        p_full, t_full, new_mu, new_nu, norms = jax.shard_map(
-            body, mesh=mesh,
-            in_specs=(shard_spec, shard_spec, tf_spec, shard_spec,
-                      shard_spec, mults_spec, rep_spec, rep_spec, rep_spec),
-            out_specs=(rep_spec, rep_spec, shard_spec, shard_spec, rep_spec),
-            check_vma=False,
-        )(grad_partials, p_flat, t_flat, opt_state.adam.mu,
-          opt_state.adam.nu, mults, opt_state.count, opt_state.adam.count,
-          momentum)
-
-        new_params = jax.tree.map(unflatten_update_leaf, p_full, params)
-        new_teacher = (jax.tree.map(unflatten_update_leaf, t_full, teacher)
-                       if ema else teacher)
-        new_opt_state = ScheduledAdamWState(
-            count=opt_state.count + 1,
-            adam=optax.ScaleByAdamState(
-                count=_safe_int32_increment(opt_state.adam.count),
-                mu=new_mu, nu=new_nu,
-            ),
-        )
-        return new_params, new_teacher, new_opt_state, norms
-
-    return schedule
-
-
 # ---------------- bucketed collective engine ----------------
 #
-# The per-leaf sharded schedule above prices the ViT-L update phase at
-# one reduce-scatter per leaf + two all-gathers per leaf (COST_SHUP_r10:
-# 357 RS + 714 AG) — small-message latency-bound at production mesh
-# sizes (PAPERS.md arxiv 2408.13356: sub-MiB collectives are dominated
-# by per-message launch cost, not wire bytes). The bucketed engine
-# (optim.bucketed_collectives, auto = on when the sharded update
-# engages; the per-leaf schedule stays the bitwise oracle behind
-# =false) coalesces the update-phase leaves into a small fixed set of
-# large flat BUCKETS — grouped by (submodel, dtype, param-group) so the
-# per-submodel clip norms and the last-layer lr never mix inside a
-# bucket — and issues ONE reduce-scatter per bucket for the grads and
-# ONE all-gather per bucket for the updated params (plus one for the
-# EMA'd teacher): the SimpleFSDP coalescing (arxiv 2411.00284) written
-# at the same level as make_sharded_update.
+# One reduce-scatter and two all-gathers a LEAF (357 + 714 at ViT-L) are
+# small messages, latency-bound at production mesh sizes (PAPERS.md
+# arxiv 2408.13356: sub-MiB collectives are dominated by per-message
+# launch cost, not wire bytes). The bucketed engine
+# (optim.bucketed_collectives, auto = on on a pure data-parallel mesh;
+# =false is the replicated fused engine, its oracle) coalesces the
+# update-phase leaves into a small fixed set of large flat BUCKETS —
+# grouped by (submodel, dtype, param-group) so the per-submodel clip
+# norms and the last-layer lr never mix inside a bucket — and issues ONE
+# reduce-scatter per bucket for the grads and ONE all-gather per bucket
+# for the updated params (plus one for the EMA'd teacher): the
+# SimpleFSDP coalescing (arxiv 2411.00284).
 #
 # The bucket layout is SHARD-INTERLEAVED: a bucket is the row-major
 # flattening of a [dp, S_b/dp] matrix whose row k holds, member by
@@ -726,18 +355,19 @@ def make_sharded_update_schedule(
 # range is dp-aligned). Two properties follow:
 #
 # * sharding the bucket over the data axes (the "bucket" rule) gives
-#   each replica row k — the SAME elements the per-leaf layout's shards
-#   hold, so a bucket reduce-scatter computes, segment for segment, the
-#   identical sums the per-leaf reduce-scatters compute;
+#   each replica row k — every member leaf's k-th shard — so a bucket
+#   reduce-scatter computes, segment for segment, the sums a
+#   reduce-scatter of each leaf alone would: the result does not depend
+#   on the plan (tests/test_buckets.py pins the moments and clip norms
+#   BITWISE between the default plan and one of a leaf a bucket);
 # * extracting one member from a dim-0-sharded bucket is a column slice
-#   of the [dp, S_b/dp] view — shard-LOCAL, no data movement — so the
-#   engine runs the per-leaf update math graph (scalar multipliers,
-#   per_submodel_norms, update_leaf_math per leaf) unchanged between
-#   the bucket-granular collectives, and the bucketed arm is BITWISE
-#   the per-leaf arm (pinned in tests/test_buckets.py).
+#   of the [dp, S_b/dp] view — shard-LOCAL, no data movement — so
+#   between the bucket-granular collectives the engine runs the update
+#   math leaf by leaf (scalar multipliers, per_submodel_norms,
+#   update_leaf_math per leaf) on flat 1/dp shards.
 #
 # The adam moments are BORN in the bucket layout (bucketed_adam_zeros);
-# checkpoints always persist the per-leaf layout and convert at the
+# checkpoints always persist the per-leaf flat layout and convert at the
 # save/restore boundary (buckets_to_flat_tree / flat_tree_to_buckets —
 # pure index permutations, bitwise lossless both ways).
 
@@ -856,8 +486,7 @@ class BucketPlan:
         return out
 
     def pack_flat_tree(self, flat_tree, constrain_fn=None):
-        """Per-leaf flat padded tree (the per-leaf engine's working
-        layout) -> bucket layout."""
+        """Per-leaf flat padded tree -> bucket layout."""
         leaves = self._leaves(flat_tree)
         out = {}
         for b in self.buckets:
@@ -899,11 +528,11 @@ class BucketPlan:
         return jax.tree.unflatten(self.treedef, out_leaves)
 
     def buckets_to_flat_tree(self, bucket_dict):
-        """Bucket layout -> the PER-LEAF flat padded layout
-        (``sharded_adam_zeros`` shapes). The checkpoint adapter uses
-        this so on-disk moments are always per-leaf — a bucketed run's
-        checkpoint restores into any arm and vice versa. Numpy in ->
-        numpy out (the host-side restore path)."""
+        """Bucket layout -> the PER-LEAF flat padded layout (one
+        ``[padded_flat_size]`` array a leaf). The checkpoint adapter
+        uses this so on-disk moments are always per-leaf — a bucketed
+        run's checkpoint restores into any arm and vice versa. Numpy in
+        -> numpy out (the host-side restore path)."""
         out_leaves = [None] * self.n_leaves
         for b in self.buckets:
             mat = bucket_dict[b.name].reshape(self.dp, -1)
@@ -1034,9 +663,8 @@ def make_bucket_plan(
 
 def bucketed_adam_zeros(plan: BucketPlan) -> dict:
     """Adam moment zeros BORN in the bucket layout, boxed with the
-    "bucket" logical axis for sharding derivation (the
-    ``sharded_adam_zeros`` convention — each replica stores 1/dp of
-    every bucket)."""
+    "bucket" logical axis for sharding derivation (each replica stores
+    1/dp of every bucket)."""
     import flax.linen as nn
 
     def z(b):
@@ -1085,15 +713,15 @@ def make_bucketed_update(
 ) -> Callable:
     """Build the bucketed collective engine (section comment above).
 
-    Same contract as ``make_sharded_update`` except
-    ``opt_state.adam.mu/nu`` are {bucket_name: flat [S_b]} dicts in the
-    shard-interleaved bucket layout (``bucketed_adam_zeros``). The
-    per-leaf working forms BETWEEN the collectives — and therefore the
-    whole elementwise math graph: scalar multipliers,
-    ``per_submodel_norms``, ``update_leaf_math`` per leaf — are
-    identical to ``make_sharded_update``'s; only the collective
-    granularity changes. Grads are bucket-packed under the
-    ``bucket_pack`` named scope (where GSPMD places the ONE
+    Same contract as ``make_fused_update`` — ``update(grads, params,
+    teacher, opt_state, momentum) -> (new_params, new_teacher,
+    new_opt_state, norms)`` — except ``opt_state.adam.mu/nu`` are
+    {bucket_name: flat [S_b]} dicts in the shard-interleaved bucket
+    layout (``bucketed_adam_zeros``). Params/teacher enter and leave in
+    their model layout; BETWEEN the collectives the elementwise math —
+    scalar multipliers, ``per_submodel_norms``, ``update_leaf_math`` —
+    runs leaf by leaf on flat 1/dp shards. Grads are bucket-packed
+    under the ``bucket_pack`` named scope (where GSPMD places the ONE
     reduce-scatter per bucket); the updated student/teacher are
     bucket-packed and re-materialized under ``bucket_unpack`` (the ONE
     all-gather per bucket site).
@@ -1115,8 +743,7 @@ def make_bucketed_update(
     # gather whole buckets only on model-parallel-free meshes: with a
     # tensor/seq/pipe/expert axis the member leaves carry model-parallel
     # placements a replicated bucket would undo — the per-leaf
-    # unflatten + jit-level out_shardings then place the gathers, as in
-    # make_sharded_update
+    # unflatten + jit-level out_shardings then place the gathers
     gather_whole = mesh is None or all(
         int(mesh.shape.get(a, 1)) <= 1
         for a in ("tensor", "seq", "pipe", "expert"))
@@ -1126,6 +753,10 @@ def make_bucketed_update(
             return constrain_update_shard(flatten_update_leaf(x, dp), mesh)
 
     def mult_to_shard(m, like):
+        # scalar multipliers ride along unchanged; scanned-stack [L,1,..]
+        # multiplier arrays are materialized per element before the leaf
+        # shape is flattened away (XLA fuses the broadcast into the
+        # update kernel)
         if getattr(m, "ndim", 0) == 0:
             return m
         return to_shard(jnp.broadcast_to(m, like.shape).astype(jnp.float32))
@@ -1152,16 +783,16 @@ def make_bucketed_update(
         wm_flat = jax.tree.map(mult_to_shard, wd_mult, params)
         mu_flat = plan.unpack_flat_tree(opt_state.adam.mu)
         nu_flat = plan.unpack_flat_tree(opt_state.adam.nu)
-        # fusion cut, mirroring make_sharded_update exactly: behind
-        # this barrier the norms + per-leaf update subgraph is the
-        # IDENTICAL graph over identically-shaped flat leaves — the
-        # bucket slices/concats would otherwise fuse into the math and
-        # vectorize it differently. Backends that honor the barrier as
-        # a fusion boundary compile the same kernels for both arms;
-        # XLA:CPU expands the barrier pre-fusion, where the moments and
-        # clip norms still stay bitwise (the interleaved layout fixes
-        # the reduction segments) and params/teacher sit within ~1-2
-        # ulp of the per-leaf arm (see make_sharded_update's comment).
+        # fusion cut: behind this barrier the norms + per-leaf update
+        # subgraph is the SAME graph over the same flat leaves whatever
+        # the plan — the bucket slices/concats would otherwise fuse
+        # into the math and vectorize it differently. Backends that
+        # honor the barrier as a fusion boundary compile the same
+        # kernels under every plan; XLA:CPU expands the barrier
+        # pre-fusion, where the moments and clip norms still stay
+        # bitwise (the interleaved layout fixes the reduction segments)
+        # and params/teacher sit within ~1-2 ulp of FMA contraction
+        # context between two plans (tests/test_buckets.py).
         (g_flat, p_flat, t_flat, lm_flat, wm_flat, mu_flat, nu_flat) = (
             jax.lax.optimization_barrier(
                 (g_flat, p_flat, t_flat, lm_flat, wm_flat,
@@ -1169,8 +800,9 @@ def make_bucketed_update(
 
         norms = {}
         if do_clip:
-            # the identical per_submodel_norms graph as the per-leaf
-            # engine, over identical flat sharded leaves
+            # the replicated engine's per_submodel_norms graph over the
+            # flat sharded leaves: GSPMD lowers it as shard-local
+            # partial norms + one small psum
             norms = per_submodel_norms(g_flat)
             scales = {
                 k: jnp.minimum(1.0, clip_grad / jnp.maximum(n, 1e-12))
@@ -1244,8 +876,8 @@ def build_bucketed_update(
     plan: BucketPlan, ema: bool = True,
 ) -> Callable:
     """Wire config -> multiplier trees -> bucketed engine
-    (``build_sharded_update``'s twin; same inputs, same validation,
-    plus the setup-built ``BucketPlan``)."""
+    (``build_fused_update``'s twin; same inputs, same validation,
+    plus the mesh and the setup-built ``BucketPlan``)."""
     lr_mult, wd_mult, is_last = build_multiplier_trees(
         params,
         layerwise_decay=cfg.optim.layerwise_decay,
@@ -1278,24 +910,37 @@ def make_bucketed_update_schedule(
     clip_grad: float | None = None,
     ema: bool = True,
 ) -> Callable:
-    """The bucketed update schedule with EXPLICIT collectives — the
-    ``make_sharded_update_schedule`` convention for the bucketed
-    engine, compiled by scripts/cost_buckets.py for the committed
-    census (COST_BUCKET_r13.json).
+    """The bucketed update schedule with EXPLICIT collectives.
+
+    ``make_bucketed_update`` expresses the schedule through GSPMD
+    annotations, which this container's XLA:CPU lowers as all-reduce +
+    fused dynamic-slice (the pre-rewrite form of reduce-scatter; the
+    TPU/GPU collective optimizer performs that rewrite). This builder
+    writes the same schedule as a shard_map island whose collectives
+    are spelled out, so the compiled HLO contains the literal
+    reduce-scatter/all-gather ops on every backend
+    (COST_BUCKET_r13.json is a census of it at ViT-L dp=8;
+    tests/test_buckets.py pins its numerics against the engine and its
+    collective set).
 
     Per bucket: the members' padded-flat partial grads are
     shard-interleaved into the bucket layout and reduce-scattered with
     ONE ``psum_scatter`` (scope ``bucket_pack``); because of the
     interleave, each replica's [S_b/dp] reduce-scatter result is the
-    member-by-member concatenation of exactly the shards the per-leaf
-    schedule's reduce-scatters produce, so the body slices the members
-    back out LOCALLY and runs the per-leaf twin's own shard-local
-    program (per-leaf ``update_leaf_math``, per-submodel partial norms
-    + one small psum) unchanged; the updated student and EMA'd teacher
-    shards re-concatenate and come back with ONE ``all_gather`` per
-    bucket each (scope ``bucket_unpack``). Same signature as
-    ``make_sharded_update_schedule`` (stacked [dp, *leaf] grad
-    partials), ``opt_state`` in the bucket layout.
+    member-by-member concatenation of each member leaf's own shard, so
+    the body slices the members back out LOCALLY and runs the
+    shard-local program leaf by leaf (``update_leaf_math``,
+    per-submodel partial norms + ONE small psum for the clip norms, the
+    whole grad never materialized anywhere); the updated student and
+    EMA'd teacher shards re-concatenate and come back with ONE
+    ``all_gather`` per bucket each (scope ``bucket_unpack``).
+
+    Returns ``schedule(grad_partials, params, teacher, opt_state,
+    momentum) -> (new_params, new_teacher, new_opt_state, norms)`` where
+    ``grad_partials`` leaves are [dp, *leaf_shape] stacks of the
+    per-replica partial gradients (dim 0 sharded over the data axes —
+    what the data-parallel backward holds before any grad sync), and
+    ``opt_state`` is in the bucket layout (``bucketed_adam_zeros``).
     """
     from dinov3_tpu.parallel.sharding import (
         UPDATE_SHARD_AXES,
@@ -1316,8 +961,9 @@ def make_bucketed_update_schedule(
     def schedule(grad_partials, params, teacher, opt_state, momentum):
         _check_bucketed_opt_state(opt_state, plan)
         # flat padded shard-layout forms of everything the local body
-        # consumes per LEAF (identical to the per-leaf twin; only the
-        # grads and the updated outputs travel in bucket form)
+        # consumes per LEAF (only the grads and the updated outputs
+        # travel in bucket form; the in_specs slice each replica's
+        # shard)
         p_flat = jax.tree.map(lambda p: flatten_update_leaf(p, dp), params)
         t_flat = (jax.tree.map(lambda t: flatten_update_leaf(t, dp), teacher)
                   if ema else jax.tree.map(lambda _: 0.0, grad_partials))
@@ -1344,8 +990,8 @@ def make_bucketed_update_schedule(
             # ONE reduce-scatter per bucket over the shard-interleaved
             # concat of the members' padded-flat partial grads; row k of
             # the interleave is the concat of the members' k-th shards,
-            # so the local result is the concat of the per-leaf
-            # reduce-scatter results, member by member
+            # so the local result is the concat of each member's own
+            # summed shard
             rs = {}
             with jax.named_scope("bucket_pack"):
                 for b in plan.buckets:

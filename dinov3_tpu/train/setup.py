@@ -11,17 +11,19 @@ specs (dinov3_jax/train/train.py:319-604). Here:
   each device materializes only its own shard (no replicate-then-slice),
 - the train step is jitted with donated state and explicit in/out
   shardings; XLA's SPMD partitioner inserts all collectives,
-- under the cross-replica sharded update engine (optim.sharded_update,
-  auto = on at data-parallel size > 1), the adam moments are born in the
-  flat "update_shard" layout — each replica stores and updates 1/dp of
-  every master/moment/teacher leaf (train/fused_update.py),
+- which update engine a mesh gets is decided in ONE place,
+  ``resolve_update_arm`` below,
+- under the bucketed engine (a pure data-parallel mesh), the adam
+  moments are born in the flat "bucket" layout — each replica stores and
+  updates 1/dp of every master/moment/teacher leaf
+  (train/fused_update.py),
 - under the ZeRO-3 weight-streaming engine (parallel.zero3, auto = on
-  at fsdp > 1 — supersedes the flat engine), the fp32 masters, EMA
-  teacher AND adam moments are ALL born sharded over the data axes in
-  their model shapes (parallel/sharding.py zero3_*): compute weights
-  re-materialize at use (per block inside the block scan, ops/block.py),
-  the update runs shard-local, and the step's out_shardings keep the
-  masters sharded — no trailing all-gather.
+  at fsdp > 1), the fp32 masters, EMA teacher AND adam moments are ALL
+  born sharded over the data axes in their model shapes
+  (parallel/sharding.py zero3_*): compute weights re-materialize at use
+  (per block inside the block scan, ops/block.py), the update runs
+  shard-local, and the step's out_shardings keep the masters sharded —
+  no trailing all-gather.
 """
 
 from __future__ import annotations
@@ -94,13 +96,8 @@ class TrainSetup:
     step_fn: Callable  # step_fn(state, batch, scalars, rng) -> (state, metrics)
     batch_shardings: dict
     fused_update: Callable | None = None  # single-pass engine, None = optax chain
-    sharded_update: bool = False  # cross-replica sharded form of the engine
-    zero3: bool = False  # ZeRO-3 weight-streaming layout (masters sharded)
-    bucketed: bool = False  # coalesced bucket form of the sharded engine
+    arm: str = "replicated"  # resolve_update_arm's answer
     bucket_plan: Any = None  # the leaf->bucket assignment (BucketPlan)
-    # unified engine (zero3 × buckets): the non-block zero3 gathers and
-    # their grad reduce-scatters run as hierarchy-aware flat buckets
-    zero3_buckets: bool = False
     zero3_bucket_plan: Any = None  # Zero3GatherPlan (student tree)
     accum_steps: int = 1  # microbatched gradient accumulation
     # train.low_precision (ops/lowp.py): resolved arm + the setup-time
@@ -112,6 +109,23 @@ class TrainSetup:
     # (the per-step-fetch oracle path is then the only metrics path)
     telemetry_builder: Callable | None = None
     _telemetry_cache: Any = dataclasses.field(default=None, repr=False)
+
+    @property
+    def bucketed(self) -> bool:
+        """Coalesced bucket form of the cross-replica sharded update."""
+        return self.arm == "bucketed"
+
+    @property
+    def zero3(self) -> bool:
+        """ZeRO-3 weight-streaming layout (masters sharded)."""
+        return self.arm in ("zero3", "unified")
+
+    @property
+    def zero3_buckets(self) -> bool:
+        """Unified engine (zero3 x buckets): the non-block zero3 gathers
+        and their grad reduce-scatters run as hierarchy-aware flat
+        buckets."""
+        return self.arm == "unified"
 
     def mask_rows_limit(self, host_batch: dict) -> int | None:
         """The masked tokens ONE ``sample_ibot_masks`` call of
@@ -144,6 +158,59 @@ class TrainSetup:
         if self._telemetry_cache is None:
             self._telemetry_cache = self.telemetry_builder()
         return self._telemetry_cache
+
+
+def resolve_update_arm(cfg: ConfigNode, mesh, zero3_gather: bool) -> str:
+    """Which update engine this config gets on this mesh: the one place
+    that chooses, and where every conflict between the switches raises.
+    Reads the config and the mesh's shape, nothing else.
+
+    - ``"zero3"`` / ``"unified"``: ``parallel.zero3`` wished (auto = on
+      at ``parallel.fsdp > 1``) on a data-axis product > 1. Masters,
+      teacher and moments are born sharded over the data axes in their
+      MODEL shapes (parallel/sharding.py zero3_*) and the update runs
+      shard-local through the plain engine, so nothing is left for
+      update buckets to coalesce; ``optim.bucketed_collectives`` (auto =
+      on) then chooses ``"unified"``, where the forward's non-block
+      gathers and their transposed grad reduce-scatters run as
+      hierarchy-aware gather buckets (``gather_zero3_bucketed``) —
+      wherever the meta-arch gathers at all (``zero3_gather``: not on a
+      model-parallel mesh, not a decoder). No update-engine
+      requirements there, so no raises.
+    - ``"bucketed"``: any other mesh with a data-axis product > 1, under
+      ``optim.bucketed_collectives`` (auto = on) and
+      ``optim.fused_update``: one reduce-scatter + one all-gather per
+      ~128 MiB flat bucket around the fused single pass over 1/dp
+      shards (``make_bucketed_update``).
+    - ``"replicated"``: one device, or either switch off: every replica
+      updates whole leaves behind GSPMD's all-reduce — the oracle the
+      sharded arms are tested against.
+
+    Whether the math is the fused single pass or the optax chain is
+    ``optim.fused_update``'s alone (``TrainSetup.fused_update``)."""
+    from dinov3_tpu.configs.config import (
+        bucketed_collectives_wished,
+        zero3_wished,
+    )
+    from dinov3_tpu.parallel.sharding import update_shard_size
+
+    dp = update_shard_size(mesh)
+    fused = bool(cfg.optim.get("fused_update", True))
+    bucketed = bucketed_collectives_wished(cfg)
+    if zero3_wished(cfg) and dp > 1:
+        return "unified" if bucketed and zero3_gather else "zero3"
+    asked = str(cfg.optim.get("bucketed_collectives", "auto")).lower()
+    if bucketed and asked != "auto" and not fused:
+        raise ValueError(
+            "optim.bucketed_collectives=true requires "
+            "optim.fused_update=true on non-zero3 meshes (the "
+            "bucketed engine is the fused single-pass math over "
+            "bucket shards; only the unified zero3 gather-bucket "
+            "arm — parallel.zero3 on an fsdp>1 mesh — works without "
+            "it); re-enable fused_update or set "
+            "bucketed_collectives=false"
+        )
+    return "bucketed" if bucketed and fused and dp > 1 else "replicated"
 
 
 def build_train_setup(
@@ -279,164 +346,60 @@ def _build_train_setup(
             lambda r: meta.init_params(r, example_batch), rng
         )
     optimizer = build_optimizer(cfg, abstract_params["student"], schedules)
-    # default path: the single-pass fused clip+AdamW+EMA engine (state
-    # pytree identical to the optax chain's, so init/sharding/checkpoints
-    # below are path-independent); optim.fused_update=false selects the
-    # optax oracle chain
-    fused = None
-    fused_wished = bool(cfg.optim.get("fused_update", True))
-    # cross-replica sharded update (train/fused_update.py
-    # make_sharded_update): auto = on when the data-parallel axis product
-    # is > 1 (each replica then updates 1/dp of every master/moment/
-    # teacher leaf and stores 1/dp of the adam moments); the replicated
-    # fused engine stays the oracle behind optim.sharded_update=false.
-    # The sharded engine is built on the fused single-pass math, so it
-    # only engages when fused_update is on.
     from dinov3_tpu.parallel.sharding import update_shard_size
 
     dp = update_shard_size(mesh)
-    # ZeRO-3 weight streaming (parallel.zero3, default on at fsdp > 1):
-    # masters/teacher/moments born sharded over the data axes in their
-    # MODEL shapes (parallel/sharding.py zero3_*), compute weights
-    # re-materialized at use (per block inside the scan). It SUPERSEDES
-    # the flat sharded-update engine: the moments are already 1/dp here
-    # and the update runs shard-local through the plain fused engine —
-    # a flat repack would just add an all-to-all per step.
-    from dinov3_tpu.configs.config import zero3_wished
-
-    use_zero3 = zero3_wished(cfg) and dp > 1
-    sharded_wished = cfg.optim.get("sharded_update", "auto")
-    sharded_explicit = (not isinstance(sharded_wished, str)
-                        or sharded_wished.lower() != "auto")
-    if isinstance(sharded_wished, str):
-        sharded_wished = sharded_wished.lower() in ("auto", "true", "on")
-    if use_zero3 and sharded_explicit and bool(sharded_wished):
-        raise ValueError(
-            "optim.sharded_update=true conflicts with parallel.zero3: "
-            "under zero3 the masters AND moments are already sharded "
-            "and the update is shard-local — the flat update_shard "
-            "repack would reshard them every step. Set "
-            "optim.sharded_update=auto (it yields to zero3) or "
-            "parallel.zero3=false."
-        )
-    use_sharded = (bool(sharded_wished) and fused_wished and dp > 1
-                   and not use_zero3)
-    if (bool(sharded_wished) and not fused_wished and sharded_explicit):
-        raise ValueError(
-            "optim.sharded_update=true requires optim.fused_update=true "
-            "(the sharded engine is the fused single-pass math over "
-            "1/dp shards); set sharded_update=false or re-enable "
-            "fused_update"
-        )
-    # Bucketed collective engine (optim.bucketed_collectives, auto = on).
-    # Two arms share the flag:
-    # * flat meshes (no zero3): when the sharded update engages, its
-    #   per-leaf schedule (one RS + two AGs per leaf) coalesces into one
-    #   RS/AG per ~128 MiB flat bucket (make_bucketed_update);
-    # * zero3 meshes: the UNIFIED arm — the non-block subtree gathers of
-    #   the forward (and their transposed grad reduce-scatters) coalesce
-    #   into hierarchy-aware gather buckets (gather_zero3_bucketed;
-    #   staged intra/inter collectives on dp×fsdp meshes), while the
-    #   update itself stays shard-local zero3 and the block stacks keep
-    #   their per-block in-scan stream.
-    # The per-leaf engines stay the bitwise oracles behind =false.
-    from dinov3_tpu.configs.config import bucketed_collectives_wished
-
-    bucketed_raw = (cfg.get("optim") or {}).get(
-        "bucketed_collectives", "auto")
-    bucketed_explicit = (not isinstance(bucketed_raw, str)
-                         or bucketed_raw.lower() != "auto")
-    bucketed_wished = bucketed_collectives_wished(cfg)
-    if bucketed_explicit and bucketed_wished and not use_zero3:
-        # (under zero3 the flag selects the unified gather-bucket arm —
-        # no update-engine requirements there, so no raises)
-        if not fused_wished:
-            raise ValueError(
-                "optim.bucketed_collectives=true requires "
-                "optim.fused_update=true on non-zero3 meshes (the flat "
-                "bucketed engine is the fused single-pass math over "
-                "bucket shards; only the unified zero3 gather-bucket "
-                "arm — parallel.zero3 on an fsdp>1 mesh — works without "
-                "it); re-enable fused_update or set "
-                "bucketed_collectives=false"
-            )
-        if sharded_explicit and not bool(sharded_wished):
-            raise ValueError(
-                "optim.bucketed_collectives=true requires the sharded "
-                "update path (optim.sharded_update=auto/true) on "
-                "non-zero3 meshes: the flat buckets ARE the coalesced "
-                "form of its update_shard layout (zero3 meshes instead "
-                "select the unified gather-bucket arm, which has no "
-                "such requirement). Unset sharded_update=false or set "
-                "bucketed_collectives=false."
-            )
-    use_bucketed = (bucketed_wished and use_sharded)
-    use_sharded = use_sharded and not use_bucketed
-    # the unified arm: zero3 layout + gather buckets. meta computed the
-    # same wish from cfg alone; setup has the final word (dp gate).
-    use_zero3_buckets = bool(use_zero3 and bucketed_wished
-                             and meta.zero3_gather)
-    meta.zero3_buckets = use_zero3_buckets
+    arm = resolve_update_arm(cfg, mesh, meta.zero3_gather)
+    use_zero3 = arm in ("zero3", "unified")
+    # meta computed the same wish from cfg alone; setup has the final
+    # word (dp gate)
+    meta.zero3_buckets = arm == "unified"
     zero3_bucket_plan = None
-    if use_zero3_buckets:
+    if arm == "unified":
         from dinov3_tpu.train.fused_update import make_zero3_bucket_plan
 
         zero3_bucket_plan = make_zero3_bucket_plan(
             abstract_params["student"], mesh)
+    # default math: the single-pass fused clip+AdamW+EMA engine (state
+    # pytree identical to the optax chain's, so init/sharding/checkpoints
+    # below are path-independent); optim.fused_update=false selects the
+    # optax oracle chain (fused stays None)
+    fused = None
     bucket_plan = None
-    if fused_wished:
+    if arm == "bucketed":
+        # the leaf -> bucket assignment, built ONCE per setup from
+        # the abstract params (the TelemetryPlan convention) and
+        # shared by the engine, the opt-state init, the checkpoint
+        # adapter and the census scripts
+        from dinov3_tpu.configs.config import warn_bucket_padding
         from dinov3_tpu.train.fused_update import (
             build_bucketed_update,
-            build_fused_update,
-            build_sharded_update,
+            make_bucket_plan,
         )
+        from dinov3_tpu.train.param_groups import build_multiplier_trees
 
-        if use_bucketed:
-            # the leaf -> bucket assignment, built ONCE per setup from
-            # the abstract params (the TelemetryPlan convention) and
-            # shared by the engine, the opt-state init, the checkpoint
-            # adapter and the census scripts
-            from dinov3_tpu.configs.config import warn_bucket_padding
-            from dinov3_tpu.train.fused_update import make_bucket_plan
-            from dinov3_tpu.train.param_groups import (
-                build_multiplier_trees,
-            )
+        _, _, is_last = build_multiplier_trees(
+            abstract_params["student"],
+            layerwise_decay=cfg.optim.layerwise_decay,
+            patch_embed_lr_mult=cfg.optim.patch_embed_lr_mult,
+            dino_head_wd_multiplier=cfg.optim.dino_head_wd_multiplier,
+        )
+        bucket_plan = make_bucket_plan(
+            abstract_params["student"], dp, is_last_layer=is_last,
+        )
+        warn_bucket_padding(
+            bucket_plan.padding_stats(), bucket_plan.target_bytes)
+        fused = build_bucketed_update(
+            cfg, abstract_params["student"], schedules, mesh,
+            bucket_plan, ema=meta.ema_teacher,
+        )
+    elif bool(cfg.optim.get("fused_update", True)):
+        from dinov3_tpu.train.fused_update import build_fused_update
 
-            _, _, is_last = build_multiplier_trees(
-                abstract_params["student"],
-                layerwise_decay=cfg.optim.layerwise_decay,
-                patch_embed_lr_mult=cfg.optim.patch_embed_lr_mult,
-                dino_head_wd_multiplier=cfg.optim.dino_head_wd_multiplier,
-            )
-            bucket_plan = make_bucket_plan(
-                abstract_params["student"], dp, is_last_layer=is_last,
-            )
-            warn_bucket_padding(
-                bucket_plan.padding_stats(), bucket_plan.target_bytes)
-            fused = build_bucketed_update(
-                cfg, abstract_params["student"], schedules, mesh,
-                bucket_plan, ema=meta.ema_teacher,
-            )
-        elif use_sharded:
-            fused = build_sharded_update(
-                cfg, abstract_params["student"], schedules, mesh,
-                ema=meta.ema_teacher,
-            )
-            # padding guardrail: warn when the per-leaf zero-padding to
-            # a multiple of dp wastes > 1% of the flat master size
-            from dinov3_tpu.configs.config import warn_update_shard_padding
-            from dinov3_tpu.train.fused_update import leaf_size
-
-            warn_update_shard_padding(
-                [leaf_size(l)
-                 for l in jax.tree.leaves(abstract_params["student"])],
-                dp,
-            )
-        else:
-            fused = build_fused_update(
-                cfg, abstract_params["student"], schedules,
-                ema=meta.ema_teacher,
-            )
+        fused = build_fused_update(
+            cfg, abstract_params["student"], schedules,
+            ema=meta.ema_teacher,
+        )
 
     def boxed_init(r):
         params = meta.init_params(r, example_batch, unbox=False)
@@ -444,7 +407,7 @@ def _build_train_setup(
         # mu/nu trees inherit the logical-axis boxes — one eval_shape
         # covers params and optimizer state.
         opt_state = optimizer.init(params["student"])
-        if use_bucketed:
+        if bucket_plan is not None:
             # the bucketed engine's moments are BORN in the bucket
             # layout ({bucket_name: flat [S_b]}, 1/dp per replica via
             # the "bucket" logical rule) — same ScheduledAdamWState
@@ -458,23 +421,6 @@ def _build_train_setup(
                     count=opt_state.adam.count,
                     mu=bucketed_adam_zeros(bucket_plan),
                     nu=bucketed_adam_zeros(bucket_plan),
-                )
-            )
-        elif use_sharded:
-            # the sharded engine's moments are BORN in the flat
-            # "update_shard" layout (1/dp per replica, ZeRO-1) — same
-            # ScheduledAdamWState pytree, flat padded mu/nu leaves
-            import flax.linen as nn
-            import optax
-
-            from dinov3_tpu.train.fused_update import sharded_adam_zeros
-
-            student_unboxed = nn.meta.unbox(params["student"])
-            opt_state = opt_state._replace(
-                adam=optax.ScaleByAdamState(
-                    count=opt_state.adam.count,
-                    mu=sharded_adam_zeros(student_unboxed, dp),
-                    nu=sharded_adam_zeros(student_unboxed, dp),
                 )
             )
         lowp_state = None
@@ -689,9 +635,7 @@ def _build_train_setup(
         cfg=cfg, meta=meta, mesh=mesh, schedules=schedules,
         optimizer=optimizer, state=state, state_shardings=state_shardings,
         step_fn=step_fn, batch_shardings=b_shardings, fused_update=fused,
-        sharded_update=use_sharded, zero3=use_zero3,
-        bucketed=use_bucketed, bucket_plan=bucket_plan,
-        zero3_buckets=use_zero3_buckets,
+        arm=arm, bucket_plan=bucket_plan,
         zero3_bucket_plan=zero3_bucket_plan,
         accum_steps=accum_steps,
         lowp_arm=lp["arm"],

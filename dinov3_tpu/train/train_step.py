@@ -17,13 +17,14 @@ The update phase itself has three implementations:
   attacking the ~12 ms/step weight-shaped HBM floor the r5 profile put
   inside the 28.5% norm/reduce bucket (PROFILE_r05.json,
   docs/PERFORMANCE.md);
-- the cross-replica SHARDED form of that engine (default whenever the
-  data-parallel axis product is > 1, ``optim.sharded_update``): the
-  grads are reduce-scattered, each replica runs the same single pass
-  over 1/dp of every leaf (moments stored sharded — ZeRO-1), and the
-  updated student/teacher are all-gathered back into model layout. Both
-  fused forms plug in through the same ``fused_update`` callable below —
-  the step body cannot tell them apart.
+- the cross-replica SHARDED form of that engine (the bucketed engine,
+  default on a pure data-parallel mesh, ``optim.bucketed_collectives``):
+  the grads are reduce-scattered a bucket at a time, each replica runs
+  the same single pass over 1/dp of every leaf (moments stored sharded
+  — ZeRO-1), and the updated student/teacher are all-gathered back into
+  model layout. Both fused forms plug in through the same
+  ``fused_update`` callable below — the step body cannot tell them
+  apart.
 
 Step randomness likewise has two implementations (the copy/small-op
 sink, 14.8% of the r5 profile): the step-wide RNG plan (rng/plan.py,

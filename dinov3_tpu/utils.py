@@ -300,10 +300,10 @@ def classify_copy(line: str) -> str:
       pad/reshape/concat/slice traffic the packing engine introduces,
       attributed so the census ceiling names it instead of silently
       absorbing it.
-    - "update_shard": copies inside the sharded update engine's
-      flatten/pad/unflatten walk (the ``update_shard_pack``/
+    - "update_shard": copies inside the bucketed update engine's
+      per-leaf flatten/pad/unflatten walk (the ``update_shard_pack``/
       ``update_shard_unpack`` named scopes in
-      train/fused_update.py make_sharded_update) — the leaf-layout
+      train/fused_update.py make_bucketed_update) — the leaf-layout
       traffic the cross-replica sharding introduces, named for the same
       reason.
     - "telemetry": the async metrics ring's in-place row writes (the
@@ -400,12 +400,12 @@ def hlo_copy_census(hlo_text: str) -> dict:
     }
 
 
-# ---------------- compiled-HLO collective census (shared by
-# scripts/cost_sharded_update.py and `bench.py --census`) ----------------
+# ---------------- compiled-HLO collective census (shared by the
+# scripts/cost_*.py censuses and `bench.py --census`) ----------------
 
 # collective op kinds the census attributes; anything else that smells
 # like a collective lands in "unattributed" — a structural regression
-# when it appears (the sharded-update census pins it at 0)
+# when it appears (the engines' census tests pin it at 0)
 HLO_COLLECTIVE_CLASSES = {
     "all-reduce": "all_reduce",
     "reduce-scatter": "reduce_scatter",
@@ -455,7 +455,7 @@ def classify_collective(line: str) -> str | None:
 
 
 # named-scope markers -> attribution category for collectives: the
-# engine scopes (zero3 weight streaming, the sharded update's flat
+# engine scopes (zero3 weight streaming, the bucketed update's flat
 # pack, crop packing) wrap their materialization/collective sites, and
 # the GSPMD-inserted collectives inherit the scope in their op_name
 # metadata — so the census can say WHICH engine asked for each

@@ -1,6 +1,9 @@
 """Step-anatomy artifact: the committed evidence behind ANATOMY_r17.json
 — MEASURED per-scope device time with the exposed/overlapped collective
-split, for all four training arms, on the 8-simulated-device CPU mesh.
+split, for the training arms, on the 8-simulated-device CPU mesh
+(ANATOMY_r17.json is a static record: it also holds a "flat" arm, a
+per-leaf sharded engine that no longer exists and that this script does
+not make).
 
 Where the COST_* artifacts census the compiled HLO (static placement:
 "the RS sits inside the backward while-loop"), this one EXECUTES each
@@ -19,10 +22,7 @@ compiled):
 
 - **replicated**: ViT-L dp=8 update phase — stacked per-replica grads
   summed (the implicit grad all-reduce) + the fused replicated update.
-- **flat (PR 5)**: ``make_sharded_update_schedule`` — one
-  reduce-scatter per leaf, shard-local update, one all-gather per
-  updated leaf (1074 collectives/step, all latency-bound).
-- **bucketed (PR 9)**: ``make_bucketed_update_schedule`` — the same
+- **bucketed (PR 9)**: ``make_bucketed_update_schedule`` — the
   update through ~128 MB buckets (bucket_pack RS / bucket_unpack AG),
   PLUS the executed overlap twin (``jax.grad`` of
   ``bucketed_stream_scan`` at truncated depth): its ledger must show
@@ -157,10 +157,9 @@ def _materialize(tree, shardings):
 
 
 def update_phase_arms(cfg) -> dict:
-    """The three update-phase arms (replicated / flat / bucketed) over
-    the real ViT-L tree, executed — same program construction as
-    scripts/cost_buckets.py update_phase_twins, plus the replicated
-    fused-update arm."""
+    """The two update-phase arms (replicated / bucketed) over the real
+    ViT-L tree, executed: the bucketed schedule twin from stacked
+    per-replica partial grads, and the replicated fused-update arm."""
     import flax.linen as nn
     import jax
     import jax.numpy as jnp
@@ -177,12 +176,8 @@ def update_phase_arms(cfg) -> dict:
         make_bucket_plan,
         make_bucketed_update_schedule,
         make_fused_update,
-        make_sharded_update_schedule,
     )
-    from dinov3_tpu.train.fused_update import (
-        bucketed_adam_zeros,
-        sharded_adam_zeros,
-    )
+    from dinov3_tpu.train.fused_update import bucketed_adam_zeros
     from dinov3_tpu.train.optimizer import ScheduledAdamWState
     from dinov3_tpu.train.ssl_meta_arch import SSLMetaArch
 
@@ -231,8 +226,6 @@ def update_phase_arms(cfg) -> dict:
                     nn.meta.unbox(zeros_fn()))))
 
     fused = make_fused_update(schedules, lm, wm, isll, **kw)
-    perleaf = make_sharded_update_schedule(schedules, lm, wm, isll, mesh,
-                                           **kw)
     bucketed = make_bucketed_update_schedule(schedules, lm, wm, isll, mesh,
                                              plan, **kw)
 
@@ -242,9 +235,6 @@ def update_phase_arms(cfg) -> dict:
         g = jax.tree.map(lambda x: jnp.sum(x, 0), gs)
         return fused(g, p, t, s, m)[:3]
 
-    def perleaf_arm(gs, p, t, s, m):
-        return perleaf(gs, p, t, s, m)[:3]
-
     def bucketed_arm(gs, p, t, s, m):
         return bucketed(gs, p, t, s, m)[:3]
 
@@ -252,12 +242,10 @@ def update_phase_arms(cfg) -> dict:
         lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype), student))
     opt_rep_sh = ScheduledAdamWState(
         rep, optax.ScaleByAdamState(rep, rep_tree, rep_tree))
-    opt_pl = opt_state_of(lambda: sharded_adam_zeros(student, DP))
     opt_bk = opt_state_of(lambda: bucketed_adam_zeros(plan))
 
     arms = {
         "replicated": (repl_arm, opt_rep, opt_rep_sh),
-        "flat": (perleaf_arm, opt_pl, opt_sharding(opt_pl)),
         "bucketed": (bucketed_arm, opt_bk, opt_sharding(opt_bk)),
     }
     out = {}
@@ -358,8 +346,8 @@ def stream_twin(cfg, which: str) -> dict:
             return jnp.sum(y.astype(jnp.float32))
 
         args_abs = (shards_abs, x_abs)
-        # x rides data-sharded (unlike the census-only twin in
-        # cost_buckets.py, this one EXECUTES, so x must match).
+        # x rides data-sharded (unlike the census-only twin of
+        # tests/test_buckets.py, this one EXECUTES, so x must match).
         in_sh = (NamedSharding(mesh, P(None, axes)), x_sh)
 
     _log(f"compiling executed {which} stream twin "
@@ -469,13 +457,6 @@ def main():
     arms["bucketed"]["overlap_twin"] = overlap
 
     # ---- cross-arm acceptance pins (ISSUE 13) ----
-    # flat arm: 3x the per-leaf collectives of the bucketed arm's
-    # handful (the coalescing story, now in measured time)
-    flat_n = sum(c["n_events"]
-                 for c in arms["flat"]["anatomy"]["collectives"].values())
-    bk_n = sum(c["n_events"]
-               for c in arms["bucketed"]["anatomy"]["collectives"].values())
-    assert flat_n > 3 * bk_n, (flat_n, bk_n)
     # bucketed update arm: collective time lands in the bucket_* scopes
     assert any(s.startswith("bucket")
                for s in arms["bucketed"]["anatomy"]["collectives"]), (
@@ -502,7 +483,7 @@ def main():
     rec = round_floats({
         "what": ("step-anatomy ledger: measured per-scope device time, "
                  "exposed/overlapped collective ms, and backward-interval "
-                 "placement for all four training arms"),
+                 "placement for the training arms"),
         "arch": "vit_large",
         "dp": DP,
         "traced_steps": TRACED_STEPS,

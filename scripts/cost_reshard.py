@@ -104,7 +104,6 @@ SMOL = [
 
 TOPOLOGIES = {
     "replicated@dp8": ["parallel.data=8", "parallel.zero3=false",
-                       "optim.sharded_update=false",
                        "optim.bucketed_collectives=false"],
     "zero3@2x4": ["parallel.data=2", "parallel.fsdp=4",
                   "parallel.zero3=true",

@@ -26,8 +26,8 @@ grad-RS per optimizer step. Three instruments, all on the 2×4
   engine's grad reduce-scatters in the pre-rewrite all-reduce+slice
   form (the slice carries the ``bucket_rs_*`` scope in its op_name);
   the schedule twin above is the committed proof of the post-rewrite
-  collective set, exactly as for the flat bucketed engine
-  (scripts/cost_buckets.py).
+  collective set, exactly as for the bucketed update engine
+  (``make_bucketed_update_schedule``).
 - **Accum sweep**: the same step at ``optim.accum_steps`` ∈ {1,2,4} —
   executed (loss trajectories recorded) and censused; the pin is that
   the bucket collective count DOES NOT grow with accum_steps (the
@@ -232,7 +232,7 @@ def main():
     if SMOKE:
         apply_dot_overrides(cfg, SMOL + MESH_OVR)
     else:
-        # twins at the real ViT-L tree (the cost_buckets.py convention);
+        # twins at the real ViT-L tree;
         # the head/embed/norm tail is what the unified arm coalesces
         import importlib.util
 

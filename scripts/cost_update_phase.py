@@ -42,19 +42,12 @@ One JSON line on stdout:
 ``floor_bytes``: read g+p+mu+nu+t, write p+mu+nu+t = 9 fp32 passes over
 the parameter count, plus the up-front clip-norm read of g = 10.
 
-Since PR 5 the default update path at data-parallel size > 1 is the
-CROSS-REPLICA SHARDED form of the fused engine (optim.sharded_update,
-train/fused_update.py make_sharded_update). On the single simulated
-device this script compiles with, the sharded engine auto-falls back to
-the replicated fused form, so the chain-vs-fused numbers above remain
-exactly reproducible (they ARE the dp=1 program). Pass a second ``dp``
-argument > 1 to also compile the sharded arm over ``dp`` simulated
-devices and record its per-device bytes next to the replicated ones
-(``bytes_sharded_per_device`` / ``sharded_reduction_pct_vs_fused``);
-the full collective story for that arm is
-scripts/cost_sharded_update.py's COST_SHUP_r10.json.
+On a data-parallel mesh the update runs in a cross-replica sharded
+form (train/setup.py resolve_update_arm); this script compiles for ONE
+device, where every mesh kind's update is the replicated fused engine
+measured here.
 
-Usage: JAX_PLATFORMS=cpu python scripts/cost_update_phase.py [arch] [dp]
+Usage: JAX_PLATFORMS=cpu python scripts/cost_update_phase.py [arch]
 """
 
 from __future__ import annotations
@@ -85,7 +78,7 @@ def _bytes_accessed(fn, args, donate=()) -> float:
     return float(analysis["bytes accessed"])
 
 
-def measure(cfg, dp: int = 1) -> dict:
+def measure(cfg) -> dict:
     import jax
     import jax.numpy as jnp
     import optax
@@ -137,7 +130,7 @@ def measure(cfg, dp: int = 1) -> dict:
     )
     total = sum(passes.values())
     floor = 10 * 4 * n_params
-    rec = {
+    return {
         "n_params": n_params,
         "bytes_chain_passes": passes,
         "bytes_chain_total": total,
@@ -146,87 +139,16 @@ def measure(cfg, dp: int = 1) -> dict:
         "floor_bytes": floor,
         "fused_over_floor": round(bytes_fused / floor, 3),
     }
-    if dp > 1:
-        # the sharded arm (the dp>1 default since PR 5): the GSPMD
-        # engine's per-device update program over a dp-way data mesh
-        import flax.linen as nn
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        from dinov3_tpu.parallel.context import set_current_mesh
-        from dinov3_tpu.parallel.mesh import MeshSpec, build_mesh
-        from dinov3_tpu.parallel.sharding import UPDATE_SHARD_AXES
-        from dinov3_tpu.train import make_sharded_update
-        from dinov3_tpu.train.fused_update import sharded_adam_zeros
-        from dinov3_tpu.train.optimizer import ScheduledAdamWState
-        from dinov3_tpu.train.param_groups import build_multiplier_trees
-
-        mesh = build_mesh(MeshSpec(data=dp))
-        set_current_mesh(mesh)
-        lm, wm, isll = build_multiplier_trees(
-            student,
-            layerwise_decay=cfg.optim.layerwise_decay,
-            patch_embed_lr_mult=cfg.optim.patch_embed_lr_mult,
-            dino_head_wd_multiplier=cfg.optim.dino_head_wd_multiplier,
-        )
-        sharded = make_sharded_update(
-            schedules, lm, wm, isll, mesh,
-            b1=cfg.optim.adamw_beta1, b2=cfg.optim.adamw_beta2,
-            clip_grad=clip, ema=True)
-        opt_sh = jax.eval_shape(
-            lambda p: ScheduledAdamWState(
-                jnp.zeros((), jnp.int32),
-                optax.ScaleByAdamState(
-                    jnp.zeros((), jnp.int32),
-                    nn.meta.unbox(sharded_adam_zeros(p, dp)),
-                    nn.meta.unbox(sharded_adam_zeros(p, dp)))),
-            student)
-        rep = NamedSharding(mesh, P())
-        axes = tuple(a for a in UPDATE_SHARD_AXES if a in mesh.shape)
-        shard = NamedSharding(mesh, P(axes))
-        rep_tree = jax.tree.map(lambda _: rep, student)
-        opt_sh_sh = ScheduledAdamWState(
-            rep, optax.ScaleByAdamState(
-                rep,
-                jax.tree.map(lambda _: shard, opt_sh.adam.mu),
-                jax.tree.map(lambda _: shard, opt_sh.adam.nu)))
-        with mesh:
-            compiled = jax.jit(
-                lambda g, p, t, s, m: sharded(g, p, t, s, m)[:3],
-                in_shardings=(rep_tree, rep_tree, rep_tree, opt_sh_sh, rep),
-                out_shardings=(rep_tree, rep_tree, opt_sh_sh),
-                donate_argnums=(1, 2, 3),
-            ).lower(student, student, student, opt_sh, momentum).compile()
-        analysis = compiled.cost_analysis()
-        if isinstance(analysis, list):
-            analysis = analysis[0]
-        rec["sharded_dp"] = dp
-        rec["bytes_sharded_per_device"] = float(analysis["bytes accessed"])
-        rec["sharded_reduction_pct_vs_fused"] = round(
-            100.0 * (1.0 - rec["bytes_sharded_per_device"] / bytes_fused), 1)
-    return rec
 
 
 def main():
     arch = sys.argv[1] if len(sys.argv) > 1 else "vit_large"
-    dp = int(sys.argv[2]) if len(sys.argv) > 2 else 1
-    if dp > 1 and "xla_force_host_platform_device_count" not in \
-            os.environ.get("XLA_FLAGS", ""):
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={dp}").strip()
-    if dp > 1:
-        import jax
-
-        try:
-            jax.config.update("jax_num_cpu_devices", dp)
-        except AttributeError:
-            pass  # XLA_FLAGS above covers old jaxlibs
     from dinov3_tpu.configs import apply_dot_overrides, get_default_config
 
     cfg = get_default_config()
     apply_dot_overrides(cfg, bench.build_step_overrides(arch, 0))
     rec = {"arch": arch}
-    rec.update(measure(cfg, dp))
+    rec.update(measure(cfg))
     print(json.dumps(rec))
 
 

@@ -22,7 +22,7 @@ Three instruments, all on the 8-simulated-device CPU mesh:
   ``zero3_gather``, never "unattributed"), and the in-loop all-gather
   story. The double-buffered prefetch schedule is censused on the
   EXPLICIT twin (``models/streaming.streamed_block_scan``, the
-  ``make_sharded_update_schedule`` convention): a ViT-L block stack in
+  ``make_bucketed_update_schedule`` convention): a ViT-L block stack in
   the bf16 stream layout, compiled standalone, whose in-loop gathers
   are ``zero3_prefetch``-scoped — issued one full block of compute
   ahead of their consumer. The twin takes the bf16 stack as a program

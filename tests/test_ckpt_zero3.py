@@ -2,8 +2,8 @@
 last file the suite's workers are handed and so the one that ended the run
 alone: names and bodies as they were, the helpers imported from there):
 zero3 -> replicated -> zero3 round-trips bitwise and resumes
-deterministically; a flat sharded-update checkpoint restores into a zero3
-run."""
+deterministically; a checkpoint in the bucketed arm's per-leaf flat
+on-disk layout restores into a zero3 run."""
 
 import jax
 import jax.tree_util as jtu
@@ -21,8 +21,7 @@ def test_checkpoint_replicated_zero3_roundtrip(tmp_path, eight_devices):
     from dinov3_tpu.train import put_batch
 
     s_z, batch = _setup(["parallel.zero3=true"], 16, eight_devices)
-    s_r, _ = _setup(["parallel.zero3=false", "optim.sharded_update=false"],
-                    16, eight_devices)
+    s_r, _ = _setup(["parallel.zero3=false"], 16, eight_devices)
     d = put_batch(batch, s_z.batch_shardings)
     state1, _ = s_z.step_fn(s_z.state, d, s_z.scalars(0), jax.random.key(0))
 
@@ -54,27 +53,33 @@ def test_checkpoint_replicated_zero3_roundtrip(tmp_path, eight_devices):
 
 
 def test_checkpoint_flat_arm_to_zero3(tmp_path, eight_devices):
-    """A PR-5 flat-sharded-update checkpoint (flat padded moments)
-    restores into a zero3 run: the moments come back model-shaped
-    through the _adapt_opt_leaf flat->full path, bitwise equal to the
-    unpadded flat values, and the zero3 step runs from them."""
+    """A bucketed-arm checkpoint (on disk: per-leaf flat padded
+    moments) restores into a zero3 run: the moments come back
+    model-shaped through the _adapt_opt_leaf flat->full path, bitwise
+    equal to the unpadded flat values, and the zero3 step runs from
+    them."""
     from dinov3_tpu.checkpoint import Checkpointer
     from dinov3_tpu.train import put_batch
     from dinov3_tpu.train.fused_update import unflatten_update_leaf
 
-    s_flat, batch = _setup(["parallel.zero3=false"], 16, eight_devices)
-    assert s_flat.sharded_update  # the PR-5 arm (dp-only default)
+    s_flat, batch = _setup(["parallel.zero3=false",
+                            "optim.bucketed_collectives=auto"], 16,
+                           eight_devices)
+    assert s_flat.arm == "bucketed"  # the dp-only default
     d = put_batch(batch, s_flat.batch_shardings)
     state1, _ = s_flat.step_fn(s_flat.state, d, s_flat.scalars(0),
                                jax.random.key(0))
-    ck = Checkpointer(str(tmp_path / "ck"), async_save=False)
+    ck = Checkpointer(str(tmp_path / "ck"), async_save=False,
+                      bucket_plan=s_flat.bucket_plan)
     ck.save(1, state1)
     ck.wait_until_finished()
 
     s_z, _ = _setup(["parallel.zero3=true"], 16, eight_devices)
-    restored = ck.restore(s_z.state, 1)
+    restored = Checkpointer(str(tmp_path / "ck"), async_save=False).restore(
+        s_z.state, 1)
     for (path, flat), (_, full), (_, like) in zip(
-        _flat_params(state1.opt_state.adam.mu),
+        _flat_params(s_flat.bucket_plan.buckets_to_flat_tree(
+            state1.opt_state.adam.mu)),
         _flat_params(restored.opt_state.adam.mu),
         _flat_params(s_z.state.params["student"]),
     ):

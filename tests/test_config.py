@@ -329,20 +329,14 @@ def _auto_flash_min_seq(s, want):
         == FLASH_NEVER_SEQ
 
 
-def _auto_sharded_update(s, want):
-    # under all-auto another engine owns the update shards on every
-    # mesh (bucketed on dp, zero3 on fsdp), and the moments are 1/dp
+def _auto_bucketed_collectives(s, want):
+    # a sharded engine owns the update on every mesh of several devices
+    # (bucketed on dp, zero3 on fsdp), and the moments are 1/dp
     from dinov3_tpu.parallel.sharding import update_shard_size
 
-    assert s.sharded_update is False
+    assert s.arm == want["arm"]
     assert _device_share(s.state.opt_state.adam.mu) \
         == 1 / update_shard_size(s.mesh)
-
-
-def _auto_bucketed_collectives(s, want):
-    from dinov3_tpu.parallel.reshard import arm_name
-
-    assert arm_name(s) == want["arm"]
     assert (s.bucketed, s.zero3_buckets) \
         == (want["bucketed"], want["zero3_buckets"])
     assert (s.bucket_plan is not None) == want["bucketed"]
@@ -390,7 +384,6 @@ _AUTO_KEYS = {
     "kernels.flash_min_seq": _auto_flash_min_seq,
 }
 _AUTO_MESH_KEYS = {
-    "optim.sharded_update": _auto_sharded_update,
     "optim.bucketed_collectives": _auto_bucketed_collectives,
     "parallel.zero3": _auto_zero3,
     "train.resume_topology": _auto_resume_topology,
@@ -447,6 +440,94 @@ def test_auto_key_resolves_from_config_and_mesh(auto_setups, key, mesh):
         f"{key} defaults to auto in ssl_default_config.yaml and has no "
         f"row here: say what resolves it, and from what")
     check(auto_setups(mesh), _MESHES[mesh][2])
+
+
+# ---------------- the update engine a mesh gets ----------------
+#
+# train/setup.py resolve_update_arm is the one place that chooses. The
+# table: mesh kind x switches -> the arm, or the raise. Under all-auto
+# one device is replicated, pure dp bucketed, an fsdp axis unified.
+
+_ARM_MESHES = {
+    "one": ([], 1),
+    "dp8": (["parallel.data=-1"], 8),
+    "dp2xfsdp4": (["parallel.data=2", "parallel.fsdp=4"], 8),
+    "fsdp8": (["parallel.fsdp=8"], 8),
+}
+_BUCKETS_NEED_FUSED = "raises: bucketed_collectives=true requires"
+# switches -> the arm on (one, dp8, dp2xfsdp4, fsdp8)
+_ARM_TABLE = {
+    "auto": ("replicated", "bucketed", "unified", "unified"),
+    "optim.fused_update=false":
+        ("replicated", "replicated", "unified", "unified"),
+    "optim.bucketed_collectives=false":
+        ("replicated", "replicated", "zero3", "zero3"),
+    "optim.bucketed_collectives=true":
+        ("replicated", "bucketed", "unified", "unified"),
+    "parallel.zero3=false":
+        ("replicated", "bucketed", "bucketed", "bucketed"),
+    "parallel.zero3=true":
+        ("replicated", "unified", "unified", "unified"),
+    # the one conflict left: update buckets ARE the fused single pass,
+    # so insisting on them without it raises wherever zero3 does not own
+    # the update (one device included: the wish cannot hold there)
+    "optim.bucketed_collectives=true optim.fused_update=false":
+        (_BUCKETS_NEED_FUSED, _BUCKETS_NEED_FUSED, "unified", "unified"),
+    # zero3 owns the update with or without buckets of its own
+    "parallel.zero3=true optim.bucketed_collectives=false":
+        ("replicated", "zero3", "zero3", "zero3"),
+}
+
+
+def _arm_cases():
+    for switches, arms in _ARM_TABLE.items():
+        for mesh, want in zip(_ARM_MESHES, arms):
+            yield pytest.param(
+                mesh, switches, want,
+                id=f"{mesh}-{switches.replace(' ', '+')}")
+
+
+@pytest.mark.parametrize("mesh,switches,want", list(_arm_cases()))
+def test_resolve_update_arm(eight_devices, mesh, switches, want):
+    """The arm is a function of the config and the mesh's shape: no
+    model is built."""
+    from dinov3_tpu.configs.config import zero3_stream_wished
+    from dinov3_tpu.parallel.mesh import MeshSpec, build_mesh
+    from dinov3_tpu.train.setup import resolve_update_arm
+
+    overrides, n = _ARM_MESHES[mesh]
+    cfg = get_default_config()
+    apply_dot_overrides(
+        cfg, overrides + ([] if switches == "auto" else switches.split()))
+    m = build_mesh(MeshSpec.from_cfg(cfg.parallel),
+                   devices=eight_devices[:n])
+    gathers = zero3_stream_wished(cfg)  # what SSLMetaArch hands setup
+    if want.startswith("raises: "):
+        with pytest.raises(ValueError, match=want[len("raises: "):]):
+            resolve_update_arm(cfg, m, gathers)
+        return
+    assert resolve_update_arm(cfg, m, gathers) == want
+    # a meta-arch that gathers nothing itself (a decoder; a
+    # model-parallel mesh) has no gathers to bucket
+    assert resolve_update_arm(cfg, m, False) \
+        == {"unified": "zero3"}.get(want, want)
+
+
+def test_removed_key_is_refused(tmp_path):
+    """A recipe or an override that still sets ``optim.sharded_update``
+    fails at ``load_config`` as an unknown key does, and the message
+    names the key that decides now."""
+    import yaml as _yaml
+
+    for overrides in (["optim.sharded_update=false"],
+                      ["+optim.sharded_update=false"]):
+        with pytest.raises(KeyError, match="optim.bucketed_collectives"):
+            load_config(overrides=overrides)
+    p = tmp_path / "run.yaml"
+    p.write_text(_yaml.safe_dump({"optim": {"sharded_update": True}}))
+    with pytest.raises(KeyError, match="optim.bucketed_collectives"):
+        load_config(p)
+    assert "sharded_update" not in get_default_config().optim
 
 
 @pytest.mark.parametrize("arch", ["ssl", "lm"])

@@ -37,7 +37,6 @@ from dinov3_tpu.configs import apply_dot_overrides, get_default_config
 from dinov3_tpu.parallel.reshard import (
     ARM_LAYOUT,
     RESHARD_SCOPES,
-    arm_name,
     describe_topology,
     moments_convert_needed,
     reshard_state,
@@ -58,7 +57,7 @@ SMOL = [
 ]
 
 REP8 = ["parallel.data=8", "parallel.zero3=false",
-        "optim.sharded_update=false", "optim.bucketed_collectives=false"]
+        "optim.bucketed_collectives=false"]
 Z24 = ["parallel.data=2", "parallel.fsdp=4", "parallel.zero3=true",
        "optim.bucketed_collectives=false"]
 BUK8 = ["parallel.data=8", "parallel.zero3=false",
@@ -68,7 +67,7 @@ U24 = ["parallel.data=2", "parallel.fsdp=4", "parallel.zero3=true",
 Z8 = ["parallel.data=8", "parallel.zero3=true",
       "optim.bucketed_collectives=false"]
 REP24 = ["parallel.data=2", "parallel.fsdp=4", "parallel.zero3=false",
-         "optim.sharded_update=false", "optim.bucketed_collectives=false"]
+         "optim.bucketed_collectives=false"]
 
 
 def _setup(extra, devices=None, init_state=True):
@@ -128,17 +127,18 @@ def test_reshard_scopes_registered():
 
 def test_arm_layout_table():
     assert set(ARM_LAYOUT) == {
-        "replicated", "zero3", "unified", "flat", "bucketed"}
+        "replicated", "zero3", "unified", "bucketed"}
     assert ARM_LAYOUT["replicated"] == "model"
     assert ARM_LAYOUT["unified"] == "model"
-    assert ARM_LAYOUT["flat"] == "flat"
     assert ARM_LAYOUT["bucketed"] == "bucket"
 
 
 def test_arm_name_resolution(topo):
-    assert arm_name(topo["s_r"]) == "replicated"
-    assert arm_name(topo["s_z"]) == "zero3"
-    assert arm_name(topo["s_b"]) == "bucketed"
+    assert topo["s_r"].arm == "replicated"
+    assert topo["s_z"].arm == "zero3"
+    assert topo["s_b"].arm == "bucketed"
+    for s in topo["s_r"], topo["s_z"], topo["s_b"]:
+        assert topology_of(s).arm == s.arm
 
 
 def test_describe_topology(topo):
@@ -239,7 +239,6 @@ def test_true_resize_transfer_path(topo, eight_devices):
     device sets, so every group ships via the staged device_put path —
     still in memory, values bitwise, placement on the 4-device mesh."""
     s_4, _ = _setup(["parallel.data=4", "parallel.zero3=false",
-                     "optim.sharded_update=false",
                      "optim.bucketed_collectives=false"],
                     devices=eight_devices[:4], init_state=False)
     src = topology_of(topo["s_r"])
@@ -403,15 +402,14 @@ def test_orbax_backend_truncated_save_not_latest(topo, tmp_path):
 
 
 def test_reshard_report_padding_warnings(topo, eight_devices):
-    """A transition into a flat-layout arm records the re-padding
-    guardrail outcome (ISSUE 19 satellite: captured into bench records
-    like the PR-9 bucket guardrail). vit_test leaves divide dp=8
-    cleanly, so the list is present and empty here."""
-    s_f, _ = _setup(["parallel.data=8", "parallel.zero3=false",
-                     "optim.bucketed_collectives=false"],
-                    devices=eight_devices, init_state=False)
-    assert arm_name(s_f) == "flat"
+    """A transition into the bucketed arm, whose members are flat and
+    padded to a multiple of dp, records the re-padding guardrail
+    outcome (ISSUE 19 satellite: captured into bench records like the
+    PR-9 bucket guardrail). vit_test leaves divide dp=8 cleanly, so the
+    list is present and empty here."""
+    assert topo["s_b"].arm == "bucketed"
     _, rep = reshard_state(
-        topo["state1"], topology_of(topo["s_r"]), topology_of(s_f))
+        topo["state1"], topology_of(topo["s_r"]),
+        topology_of(topo["s_b"]))
     assert rep["padding_warnings"] == []
     assert rep["census_ok"]

@@ -12,8 +12,8 @@ Pins:
   reproduces the per-image oracle's CLS + pooled-patch features on
   ragged traffic, while its compile count stays pinned at 1 (the
   oracle's grows with shape diversity — the pathology under test);
-- serving weights: checkpoints from all FOUR opt-state arms
-  (replicated / PR-5 flat / PR-9 bucketed / PR-7 zero3) resolve to
+- serving weights: checkpoints from all THREE opt-state layouts
+  (replicated / bucketed / zero3) resolve to
   ONE bitwise-identical bf16 serving tree, and the bf16 cast is
   deterministic + idempotent;
 - the evals/features.py ragged-tail fix: a partial final batch runs
@@ -288,21 +288,20 @@ def test_build_serve_engine_dispatch(tiny_serve):
     assert isinstance(eng, OracleServeEngine) and eng.mode == "per_image"
 
 
-# ---------------- serving weights: the four arms ----------------
+# ---------------- serving weights: the three arms ----------------
 
-def test_serving_tree_from_all_four_arms(tmp_path, eight_devices):
+def test_serving_tree_from_every_arm(tmp_path, eight_devices):
     """One training step per opt-state arm from the same init, one
     checkpoint each; load_serving_model resolves every one of them to
     the SAME bf16 serving tree bitwise (the params tree is model-shaped
-    in all four arms — only the adam moments' layout differs)."""
+    in every arm — only the adam moments' layout differs)."""
     from dinov3_tpu.checkpoint import Checkpointer
     from dinov3_tpu.data import make_synthetic_batch
     from dinov3_tpu.train import build_train_setup, put_batch
 
     arms = {
-        "replicated": ["parallel.zero3=false", "optim.sharded_update=false",
+        "replicated": ["parallel.zero3=false",
                        "optim.bucketed_collectives=false"],
-        "flat": ["parallel.zero3=false", "optim.bucketed_collectives=false"],
         "bucketed": ["parallel.zero3=false",
                      "optim.bucketed_collectives=true"],
         "zero3": ["parallel.zero3=true"],
@@ -330,7 +329,7 @@ def test_serving_tree_from_all_four_arms(tmp_path, eight_devices):
 
     flat = {n: jtu.tree_flatten_with_path(t)[0] for n, t in trees.items()}
     ref = flat["replicated"]
-    for name in ("flat", "bucketed", "zero3"):
+    for name in ("bucketed", "zero3"):
         assert len(flat[name]) == len(ref)
         for (path, a), (_, b) in zip(ref, flat[name]):
             assert a.dtype == b.dtype
@@ -340,7 +339,7 @@ def test_serving_tree_from_all_four_arms(tmp_path, eight_devices):
     assert floats and all(l.dtype == jnp.bfloat16 for l in floats)
 
     # int8 quantization is a pure function of the serving tree, so the
-    # four arms must also quantize identically — bitwise q AND scale
+    # three arms must also quantize identically — bitwise q AND scale
     # (the fleet's weights fingerprint keys the feature cache on this)
     from dinov3_tpu.serve import (
         QuantLeaf,
@@ -354,7 +353,7 @@ def test_serving_tree_from_all_four_arms(tmp_path, eight_devices):
         for n, t in qtrees.items()}
     qref = qflat["replicated"]
     assert any(isinstance(l, QuantLeaf) for _, l in qref)
-    for name in ("flat", "bucketed", "zero3"):
+    for name in ("bucketed", "zero3"):
         for (path, a), (_, b) in zip(qref, qflat[name]):
             if isinstance(a, QuantLeaf):
                 assert np.array_equal(np.asarray(a.q), np.asarray(b.q)), (

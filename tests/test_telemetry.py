@@ -205,6 +205,41 @@ def test_oracle_vs_ring_bitwise_metric_equality(eight_devices):
             assert float(flushed[it][k]) == want, (it, k)
 
 
+def test_classify_collective_attribution():
+    from dinov3_tpu.utils import classify_collective
+
+    ent = "ENTRY %main.1 (p0: f32[8]) -> f32[8] {\n"
+    cases = {
+        "  %ar = f32[128]{0} all-reduce(f32[128]{0} %x), replica_groups={}":
+            "all_reduce",
+        "  %ars = (f32[128], f32[128]) all-reduce-start(f32[128] %x)":
+            "all_reduce",
+        "  %rs = f32[16]{0} reduce-scatter(f32[128]{0} %x), dimensions={0}":
+            "reduce_scatter",
+        "  %ag = f32[128]{0} all-gather(f32[16]{0} %x), dimensions={0}":
+            "all_gather",
+        "  %cp = f32[16]{0} collective-permute(f32[16]{0} %x)": "ppermute",
+        "  %aa = f32[16]{0} all-to-all(f32[16]{0} %x)": "all_to_all",
+        "  %cb = f32[16]{0} collective-broadcast(f32[16]{0} %x)":
+            "unattributed",
+        # -done halves and non-collectives don't count
+        "  %ard = f32[128]{0} all-reduce-done((f32[128], f32[128]) %ars)":
+            None,
+        "  %f = f32[128]{0} fusion(f32[128]{0} %x), kind=kLoop": None,
+        "  %red = f32[] reduce(f32[128]{0} %x, f32[] %c)": None,
+    }
+    for line, want in cases.items():
+        assert classify_collective(line) == want, line
+    # whole-module census over the same lines
+    from dinov3_tpu.utils import hlo_collective_census
+
+    census = hlo_collective_census(ent + "\n".join(cases) + "\n}")
+    assert census["by_class"]["all_reduce"]["ops"] == 2
+    assert census["by_class"]["reduce_scatter"]["ops"] == 1
+    assert census["by_class"]["reduce_scatter"]["bytes"] == 16 * 4
+    assert census["unattributed"] == 1
+
+
 def test_telemetry_step_census_pinned(eight_devices):
     """Copy census of the EXACT compiled telemetry step: the ring
     writes carry the "telemetry" named-scope attribution, the total is

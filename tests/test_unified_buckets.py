@@ -47,7 +47,7 @@ These tests pin:
 - the hierarchical option of the bucketed stream scan (bitwise vs the
   flat gather, staged scopes present);
 - cross-arm checkpoints (unified <-> per-leaf zero3 bitwise + resume
-  determinism; PR-5 flat-arm checkpoint restoring into the unified
+  determinism; a bucketed-arm checkpoint restoring into the unified
   arm);
 - the committed COST_UNIFIED_r18.json acceptance numbers.
 """
@@ -322,7 +322,7 @@ def arms_unified(eight_devices):
     from dinov3_tpu.train import put_batch
 
     common = ["parallel.data=-1", "parallel.fsdp=2",
-              "parallel.zero3=auto", "optim.sharded_update=false",
+              "parallel.zero3=auto",
               "compute_precision.compute_dtype=fp32"]
     s_u, batch = _setup(common, 16, eight_devices)
     s_o, _ = _setup(common + ["optim.bucketed_collectives=false"], 16,
@@ -348,7 +348,7 @@ def test_setup_explicit_bucketed_composes_with_zero3(eight_devices):
     conflicts — it selects the unified arm even with the fused update
     disabled."""
     s, _ = _setup(["parallel.data=-1", "parallel.fsdp=2",
-                   "parallel.zero3=auto", "optim.sharded_update=false",
+                   "parallel.zero3=auto",
                    "optim.bucketed_collectives=true"], 16, eight_devices)
     assert s.zero3 and s.zero3_buckets
 
@@ -435,7 +435,7 @@ def accum_arms(eight_devices):
     from dinov3_tpu.train import put_batch
 
     common = ["parallel.data=-1", "parallel.fsdp=2",
-              "parallel.zero3=auto", "optim.sharded_update=false"]
+              "parallel.zero3=auto"]
     out = {}
     d = None
     for accum in (1, 2, 4):
@@ -702,30 +702,38 @@ def test_checkpoint_unified_perleaf_roundtrip(tmp_path, arms_unified):
 
 
 def test_checkpoint_flat_arm_into_unified(tmp_path, eight_devices):
-    """A dp-only PR-5 flat-sharded-update checkpoint restores into the
-    unified zero3 arm (moments come back model-shaped through the
-    flat->full adapt path) and the unified step runs from it."""
+    """A dp-only bucketed-arm checkpoint (on disk: per-leaf flat padded
+    moments) restores into the unified zero3 arm (moments come back
+    model-shaped through the flat->full adapt path) and the unified
+    step runs from it."""
     from dinov3_tpu.checkpoint import Checkpointer
     from dinov3_tpu.train import put_batch
+    from dinov3_tpu.train.fused_update import unflatten_update_leaf
 
-    s_flat, batch = _setup(["parallel.zero3=false",
-                            "optim.bucketed_collectives=false"], 16,
-                           eight_devices)
-    assert s_flat.sharded_update and not s_flat.zero3
+    s_flat, batch = _setup(["parallel.zero3=false"], 16, eight_devices)
+    assert s_flat.arm == "bucketed"
+    plan = s_flat.bucket_plan
     d_flat = put_batch(batch, s_flat.batch_shardings)
     state1, _ = s_flat.step_fn(s_flat.state, d_flat, s_flat.scalars(0),
                                jax.random.key(0))
-    ck = Checkpointer(str(tmp_path / "ck"), async_save=False)
+    ck = Checkpointer(str(tmp_path / "ck"), async_save=False,
+                      bucket_plan=plan)
     ck.save(1, state1)
     ck.wait_until_finished()
 
     s_u, batch_u = _setup(
-        ["parallel.data=-1", "parallel.fsdp=2", "parallel.zero3=auto",
-         "optim.sharded_update=false"], 16, eight_devices)
-    assert s_u.zero3_buckets
-    restored = ck.restore(s_u.state, 1)
+        ["parallel.data=-1", "parallel.fsdp=2", "parallel.zero3=auto"],
+        16, eight_devices)
+    assert s_u.arm == "unified" and s_u.zero3_buckets
+    restored = Checkpointer(str(tmp_path / "ck"), async_save=False).restore(
+        s_u.state, 1)
     assert_trees_bitwise(state1.params, restored.params,
                          "flat -> unified params")
+    assert_trees_bitwise(
+        jax.tree.map(unflatten_update_leaf,
+                     plan.buckets_to_flat_tree(state1.opt_state.adam.mu),
+                     state1.params["student"]),
+        restored.opt_state.adam.mu, "flat -> unified mu")
     d_u = put_batch(batch_u, s_u.batch_shardings)
     _, m = s_u.step_fn(restored, d_u, s_u.scalars(1), jax.random.key(0))
     assert np.isfinite(float(m["total_loss"]))

@@ -23,11 +23,11 @@ These tests pin:
 - dp-only and dp x fsdp dryruns, plus the unrolled (scan_layers=false)
   path;
 - setup wiring: auto-on at fsdp > 1, model-SHAPED sharded moments (not
-  the PR-5 flat layout), oracle fallback, the explicit
-  sharded_update=true conflict raising;
+  the bucketed arm's flat layout), oracle fallback;
 - cross-arm checkpoints in all directions (replicated <-> zero3 as pure
-  re-placements; PR-5 flat <-> zero3 through the _adapt_opt_leaf
-  flat/full path), with bitwise round-trips and resume determinism;
+  re-placements; the bucketed arm's per-leaf flat on-disk layout <->
+  zero3 through the _adapt_opt_leaf flat/full path), with bitwise
+  round-trips and resume determinism (tests/test_ckpt_zero3.py);
 - the layout guardrails (warn_zero3_padding / warn_zero3_no_stream) and
   the committed COST_Z3_r12.json / MEM_r12.json acceptance numbers
   (>= 70% master reduction, replicated-fraction pin, attributed
@@ -73,9 +73,10 @@ def _setup(extra, batch_size, devices):
     from dinov3_tpu.train import build_train_setup
 
     cfg = get_default_config()
-    # pin the bucketed engine (PR 9) off: this file pins the zero3-vs-
-    # PR-5-flat arm topology, and bucketed otherwise auto-supersedes
-    # the flat engine's slot on dp-only meshes
+    # pin the buckets off: this file pins the per-leaf zero3 arm against
+    # the replicated one, and =false selects exactly those two (zero3
+    # without gather buckets; the replicated fused engine on a mesh
+    # zero3 does not take)
     apply_dot_overrides(
         cfg, SMOL + ["optim.bucketed_collectives=false"] + list(extra))
     batch = {k: jnp.asarray(v) for k, v in
@@ -179,9 +180,9 @@ def test_zero3_guardrails(recwarn):
 
 def test_setup_wiring_and_toggles(eight_devices):
     # explicit true on a dp-only mesh: masters sharded, moments
-    # model-SHAPED and sharded (not the PR-5 flat layout)
+    # model-SHAPED and sharded (not the bucketed arm's flat layout)
     setup, _ = _setup(["parallel.zero3=true"], 16, eight_devices)
-    assert setup.zero3 and not setup.sharded_update
+    assert setup.arm == "zero3" and setup.zero3
     for (path, leaf), (_, sh) in zip(
         _flat_params(setup.state.params["student"])[:16],
         _flat_params(setup.state_shardings.params["student"])[:16],
@@ -195,33 +196,29 @@ def test_setup_wiring_and_toggles(eight_devices):
 
     # auto: on at fsdp>1, off on a dp-only mesh
     s_fsdp, _ = _setup(["parallel.fsdp=2"], 16, eight_devices)
-    assert s_fsdp.zero3 and not s_fsdp.sharded_update
+    assert s_fsdp.arm == "zero3" and s_fsdp.zero3
     s_dp, _ = _setup([], 16, eight_devices)
-    assert not s_dp.zero3 and s_dp.sharded_update  # PR-5 default intact
+    assert s_dp.arm == "replicated" and not s_dp.zero3
 
-    # =false: replicated oracle (and the flat engine resumes its slot)
+    # =false: the replicated update, placed by the logical rules alone
     s_off, _ = _setup(["parallel.fsdp=2", "parallel.zero3=false"], 16,
                       eight_devices)
-    assert not s_off.zero3 and s_off.sharded_update
-
-    # explicit flat engine + zero3 is a misconfiguration
-    with pytest.raises(ValueError, match="zero3"):
-        _setup(["parallel.zero3=true", "optim.sharded_update=true"], 16,
-               eight_devices)
+    assert s_off.arm == "replicated" and not s_off.zero3
+    assert jax.tree.leaves(s_off.state.opt_state.adam.mu)[0].shape \
+        == p0.shape
 
 
 # ---------------- bitwise equivalence ----------------
 
 @pytest.fixture(scope="module")
 def arms_dp(eight_devices):
-    """zero3 vs replicated arms on the dp-only 8-device mesh, with the
-    replicated arm's flat update engine ALSO stripped so the comparison
-    isolates the master layout (both arms run the fused update)."""
+    """zero3 vs replicated arms on the dp-only 8-device mesh: the
+    comparison isolates the master layout (both arms run the fused
+    update)."""
     from dinov3_tpu.train import put_batch
 
     s_z, batch = _setup(["parallel.zero3=true"], 16, eight_devices)
-    s_r, _ = _setup(["parallel.zero3=false", "optim.sharded_update=false"],
-                    16, eight_devices)
+    s_r, _ = _setup(["parallel.zero3=false"], 16, eight_devices)
     d = put_batch(batch, s_z.batch_shardings)
     return s_z, s_r, d
 
@@ -279,7 +276,7 @@ def test_dryrun_dp_fsdp(eight_devices):
     # loss (measured here: 8.5426 vs 8.5593 at step 2; cause pinned in
     # tests/test_parallel.py test_sharded_matches_single_device)
     common = ["parallel.data=-1", "parallel.fsdp=2",
-              "optim.sharded_update=false", "student.layerscale=1.0",
+              "student.layerscale=1.0",
               "compute_precision.compute_dtype=fp32"]
     s_z, batch = _setup(common + ["parallel.zero3=auto"], 16,
                         eight_devices)
@@ -316,8 +313,8 @@ def test_dryrun_unrolled_blocks(eight_devices):
 
     s_z, batch = _setup(["parallel.zero3=true", "train.scan_layers=false"],
                         16, eight_devices)
-    s_r, _ = _setup(["parallel.zero3=false", "optim.sharded_update=false",
-                     "train.scan_layers=false"], 16, eight_devices)
+    s_r, _ = _setup(["parallel.zero3=false", "train.scan_layers=false"],
+                    16, eight_devices)
     d = put_batch(batch, s_z.batch_shardings)
     st_z, m_z = s_z.step_fn(s_z.state, d, s_z.scalars(0), jax.random.key(0))
     st_r, m_r = s_r.step_fn(s_r.state, d, s_r.scalars(0), jax.random.key(0))
