@@ -8,7 +8,9 @@
                                     # the third decoder's two cores, the
                                     # fourth's sparse attention, the
                                     # fifth's gated short convolution and
-                                    # 64-wide causal core
+                                    # 64-wide causal core, the routed
+                                    # layers' experts' block, the sixth's
+                                    # rotary turn and latent core at 16k
     python chip_smoke.py --phases lm   # one chip, that phase alone
     python chip_smoke.py --chips 4  # four chips: ONLY the sharded train
                                     # arms and their one-device comparison
@@ -105,6 +107,12 @@ SIZES = {
     # core at 32 query heads on 8 key/value heads of 64
     "sconv_shape": (4, 8192, 2048),
     "sconv_attn_shapes": {"heads64": (4, 8192, 32, 8, 64, 64, None)},
+    # the sixth decoder's turn and latent core at published sizes (--phases
+    # mla): q [B, T, heads, 128 + 64] beside ONE shared key head of 64, then
+    # the causal core at ONE row of 16,384 tokens, 32 heads of 192 | 128 (the
+    # backward's residency at its limit to the byte)
+    "mla_shape": (1, 16384, 32, 192, 64),
+    "mla_attn_shapes": {"mla16k": (1, 16384, 32, 32, 192, 128, None)},
     # the routed layers' held experts' block at the five decoder cells'
     # published shapes (--phases moe): [buffer rows, held experts, D, H,
     # gate, the shares of the buffer routed rows fill] — the cells' measured
@@ -126,7 +134,7 @@ SIZES = {
 }
 
 ONE_CHIP_PHASES = ("trainer", "accum", "kernels", "serve", "lm", "gqa", "gdn",
-                   "dsa", "sconv", "moe")
+                   "dsa", "sconv", "moe", "mla")
 
 _T0 = time.time()
 
@@ -496,7 +504,7 @@ def _timed(fn, x, n=3):
 
 # ------------------------------------------- the banded grouped-query core
 
-def phase_gqa(shapes: str = "gqa_shapes") -> None:
+def phase_gqa(shapes: str = "gqa_shapes", with_tiles: bool = True) -> None:
     """``ops/attention.py causal_blockwise_attention`` as the decoders'
     layers call it, at the published head sizes and whole contexts: the
     window and the global grouped-query core of the ``smallthinker``
@@ -507,7 +515,9 @@ def phase_gqa(shapes: str = "gqa_shapes") -> None:
     float32 (whole rows of keys, k and v repeated for every query head, a
     block of queries at a time so that it fits) and against the plain
     tiles; forward alone and forward + backward timed on both paths; then
-    the kernel path at other block sizes.
+    the kernel path at other block sizes. ``with_tiles`` False leaves the
+    plain tiles out, where they do not fit the chip (32 ungrouped heads of
+    192 | 128 at 16,384 tokens: 18.2 GB, my chip run, PR 45).
 
     A new kernel can hang the chip where every rehearsal passed (PERF.md
     section 6, PR 26): the phase has a time limit of its own."""
@@ -579,7 +589,7 @@ def phase_gqa(shapes: str = "gqa_shapes") -> None:
 
         _compiled_has_kernel(out_and_grads(kernel), *x)
         found = {}
-        for path, fn in (("kernel", kernel), ("tiles", tiles)):
+        for path, fn in (("kernel", kernel), ("tiles", tiles))[:1 + with_tiles]:
             first_f, ms_f, _ = _timed(jax.jit(fn), x)
             first, ms, found[path] = _timed(out_and_grads(fn), x)
             log(f"gqa: {name} core, {path}: first calls {first_f:.1f}s and "
@@ -589,9 +599,10 @@ def phase_gqa(shapes: str = "gqa_shapes") -> None:
             want = out_and_grads(dense(t, h, hk, d, w))(*x)
         log(f"gqa: {name} core: norm of the difference over the norm, "
             f"output and gradients q k v: kernel to the dense masked softmax "
-            f"{said(gaps(found['kernel'], want))}; tiles to it "
-            f"{said(gaps(found['tiles'], want))}; kernel to tiles "
-            f"{said(gaps(found['kernel'], found['tiles']))}")
+            f"{said(gaps(found['kernel'], want))}" + (
+                f"; tiles to it {said(gaps(found['tiles'], want))}; kernel "
+                f"to tiles {said(gaps(found['kernel'], found['tiles']))}"
+                if with_tiles else ""))
         for got in found.values():
             assert all(g <= 2e-2 for g in gaps(got, want)), name
         for obq, obkv in SIZES["gqa_blocks"]:
@@ -726,6 +737,64 @@ def phase_sconv() -> None:
     assert all(math.isfinite(g) for g in gaps) and max(gaps) <= 3e-3, gaps
     faulthandler.cancel_dump_traceback_later()
     phase_gqa("sconv_attn_shapes")
+
+
+def phase_mla() -> None:
+    """The ``deepseek_v3`` family's mixer stand-alone at published sizes.
+    The interleaved rotary turn (``ops/rope.py rope_apply_interleaved``) of
+    every query head's last 64 channels and of the ONE shared key head,
+    bfloat16 ends, against a complex multiplication in float32, forward and
+    forward + backward timed; then the latent core at one row of 16,384
+    tokens through ``phase_gqa``'s rows, the kernel pair against the dense
+    masked softmax alone (the plain tiles do not fit the chip there)."""
+    import jax
+    import jax.numpy as jnp
+
+    from dinov3_tpu.ops.rope import rope_apply_interleaved, token_rope_pair_sincos
+
+    b, t, h, d, rope = SIZES["mla_shape"]
+    ks = jax.random.split(jax.random.key(8), 2)
+    x = (jax.random.normal(ks[0], (b, t, h, d), jnp.bfloat16),
+         jax.random.normal(ks[1], (b, t, 1, rope), jnp.bfloat16))
+
+    def turn(q, kpe):
+        table = token_rope_pair_sincos(t, rope, 1e6)
+        return (rope_apply_interleaved(q, *table),
+                rope_apply_interleaved(kpe, *table))
+
+    def by_complex(q, kpe):
+        rates = 1e6 ** (-jnp.arange(0, rope, 2, dtype=jnp.float32) / rope)
+        angle = jnp.arange(t, dtype=jnp.float32)[:, None] * rates
+        phase = jax.lax.complex(jnp.cos(angle), jnp.sin(angle))[None, :, None]
+
+        def one(z):
+            lead, z = z[..., :z.shape[-1] - rope], z[..., -rope:]
+            z = z.astype(jnp.float32)
+            z = jax.lax.complex(z[..., 0::2], z[..., 1::2]) * phase
+            return jnp.concatenate(
+                [lead.astype(jnp.float32), z.real, z.imag], -1)
+        return one(q), one(kpe)
+
+    def out_and_grads(f):
+        return jax.jit(lambda *a: (*f(*a), *jax.grad(lambda *y: sum(
+            jnp.sum(jnp.sin(o.astype(jnp.float32))) for o in f(*y)),
+            argnums=(0, 1))(*a)))
+
+    first_f, ms_f, _ = _timed(jax.jit(turn), x)
+    first, ms, got = _timed(out_and_grads(turn), x)
+    log(f"mla: turn {(b, t, h, d)} + {(b, t, 1, rope)}: first calls "
+        f"{first_f:.1f}s and {first:.1f}s, forward {ms_f:.2f} ms, forward + "
+        f"backward {ms:.2f} ms")
+    want = out_and_grads(by_complex)(*x)
+    f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
+    gaps = [float(jnp.linalg.norm(f32(a) - f32(r)) / jnp.linalg.norm(f32(r)))
+            for a, r in zip(got, want)]
+    log("mla: turn: norm of the difference over the norm, to the complex "
+        "multiplication, q kpe and gradients q kpe "
+        + " ".join(f"{g:.3e}" for g in gaps))
+    # (bfloat16 ends: one rounding of the output, one of each gradient)
+    assert all(math.isfinite(g) for g in gaps) and max(gaps) <= 1e-2, gaps
+    phase_gqa("mla_attn_shapes", with_tiles=False)
 
 
 def phase_moe() -> None:
@@ -1261,7 +1330,8 @@ def main(argv=None) -> int:
         run = {"trainer": phase_trainer, "accum": phase_accum,
                "kernels": phase_kernels, "serve": phase_serve,
                "lm": phase_lm, "gqa": phase_gqa, "gdn": phase_gdn,
-               "dsa": phase_dsa, "sconv": phase_sconv, "moe": phase_moe}
+               "dsa": phase_dsa, "sconv": phase_sconv, "moe": phase_moe,
+               "mla": phase_mla}
         for name in phases:
             run[name]()
     else:

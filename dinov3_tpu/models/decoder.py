@@ -1,4 +1,4 @@
-"""A causal decoder whose mixer and FFN are chosen layer by layer. Five
+"""A causal decoder whose mixer and FFN are chosen layer by layer. Six
 families (``student.arch``): ``kimi_linear`` (Kimi Linear, Moonshot AI;
 ``config.json`` of Kimi-Linear-48B-A3B-Instruct and the model's report:
 KDA and MLA mixers, a dense SwiGLU, routed + shared experts),
@@ -14,7 +14,11 @@ grouped-query attention reads, for each query, the ``topk`` keys a
 learned indexer selects; text tokens only) and ``lfm2_moe`` (LFM2-MoE,
 LiquidAI; ``config.json`` of LFM2-24B-A2B: gated short convolutions 3 : 1
 with grouped-query attention on heads of 64, leading dense SwiGLU layers,
-then routed experts behind a biased sigmoid router, a tied head).
+then routed experts behind a biased sigmoid router, a tied head) and
+``deepseek_v3`` (the DeepSeek-V3 block as Kakao's Kanana-2 runs it;
+``config.json`` of kanana-2-30b-a3b-instruct-2601: latent attention with
+a ROTATED shared key on every layer, a leading dense SwiGLU, then routed
+experts behind a biased sigmoid router beside two shared ones).
 
 Pre-norm residual layers, RMSNorm everywhere (``qwen3_next``: zero-centred,
 n(x) = x / rms(x) * (1 + w), but for the delta rule's output norm):
@@ -28,10 +32,19 @@ n(x) = x / rms(x) * (1 + w), but for the delta rule's output norm):
   S_t = (I - b_t k_t k_t^T) Diag(a_t) S_{t-1} + b_t k_t v_t^T; o_t = S_t^T q_t;
   y_t = W_o [RMSNorm_head(o_t) * sigmoid(W_g2 W_g1 x_t)]. The delta rule
   itself is ``ops/kda.py`` (chunked, float32).
-- **MLA** without rotary (``mla_use_nope``): q_t = W_q x_t, heads of
-  qk_nope + qk_rope; [c_t ; kpe_t] = W_kva x_t; [k_nope ; v] =
-  W_kvb RMSNorm(c_t); k = [k_nope ; kpe_t for every head]; causal
-  softmax(q k^T / sqrt(d_qk)) v through ``ops/attention.py``; W_o.
+- **MLA**: q_t = W_q x_t, heads of qk_nope + qk_rope; [c_t ; kpe_t] =
+  W_kva x_t; [k_nope ; v] = W_kvb RMSNorm(c_t); k = [k_nope ; kpe_t for
+  every head]; causal softmax(q k^T / sqrt(d_qk)) v through
+  ``ops/attention.py``; W_o. WITHOUT rotary (``kimi_linear``:
+  ``mla_use_nope``) the qk_rope channels are one more slice of q and of
+  the shared key, never turned. WITH rotary (``deepseek_v3``:
+  ``rope_interleave``) the last qk_rope channels of every q head and the
+  ONE key head kpe are turned over token positions before kpe is repeated
+  over the heads, NEIGHBOURING channels a pair: (2i, 2i + 1) of token t by
+  t * rope_theta^(-2i / qk_rope), float32 (``ops/rope.py
+  rope_apply_interleaved``; the turned slice is held evens' results
+  before odds', in q and k alike, so the scores are the published ones).
+  No scaling of positions or of the softmax.
 - **GQA** (``swa`` | ``full_attn``): q = W_q x as ``num_attention_heads``
   heads of ``head_dim``, k = W_k x and v = W_v x as ``num_key_value_heads``;
   query head i reads key/value head i // (heads / kv heads). ``swa``: q
@@ -92,12 +105,19 @@ n(x) = x / rms(x) * (1 + w), but for the delta rule's output norm):
   chosen logits of a router that reads n2(x') like its experts, plus ONE
   shared expert times sigmoid(w_s . n2(x')) (``shared_expert_gate``); the
   row buffer of the routed layer holds ``expert_rows_factor`` times its
-  experts' even share (the recipe's ``lm.expert_rows_factor``).
+  experts' even share (the recipe's ``lm.expert_rows_factor``;
+  ``deepseek_v3``'s recipe sets it too).
   ``keye_vl2``: every layer routed, SwiGLU experts, softmax over the
   chosen logits of a router that reads n2(x'), no shared expert.
   ``lfm2_moe``: SwiGLU of ``intermediate_size`` in the first
   ``num_dense_layers`` layers, then routed SwiGLU experts under Kimi's
   sigmoid rule with w = s_sel / (sum(s_sel) + 1e-6), no shared expert.
+  ``deepseek_v3``: SwiGLU of ``intermediate_size`` in the first
+  ``first_k_dense_replace`` layers, then routed SwiGLU experts under the
+  same rule (``topk_method`` ``noaux_tc`` with ONE group: the
+  ``num_experts_per_tok`` largest of s + bias) with w =
+  ``routed_scaling_factor`` * s_sel / (sum(s_sel) + 1e-20), plus
+  ``n_shared_experts`` shared ones as ONE SwiGLU of that many widths.
 
 The vocabulary may be a slice (``vocab_size`` rows of the published
 table): ids, logits and the loss are over the slice. Embedding and head
@@ -108,7 +128,9 @@ head applied a block of tokens at a time so that the ``[tokens, vocab]``
 logits never exist whole.
 
 The step's phases (``utils.STEP_PHASES``): ``lm_embed``, ``kda_mixer``
-(inner ``kda_core``), ``mla_mixer`` (inner ``mla_core``), ``swa_mixer`` and ``full_attn_mixer``
+(inner ``kda_core``), ``mla_mixer`` (inner ``mla_rope``: the two turns,
+nothing else, and ``mla_core``: the repeat of the shared key and the
+attention call), ``swa_mixer`` and ``full_attn_mixer``
 (inner ``gqa_core``), ``gdn_mixer`` (inner ``gdn_core``),
 ``gated_attn_mixer`` (inner ``gqa_core``), ``dsa_mixer`` (inner
 ``dsa_index``: the indexer's projections and score planes, ``dsa_select``:
@@ -143,7 +165,9 @@ from dinov3_tpu.ops.mixer_chains import (
 from dinov3_tpu.ops.norms import LayerNorm, RMSNorm
 from dinov3_tpu.ops.rope import (
     rope_apply_full,
+    rope_apply_interleaved,
     rope_apply_leading,
+    token_rope_pair_sincos,
     token_rope_sincos,
 )
 from dinov3_tpu.ops.sparse_index import (
@@ -213,6 +237,8 @@ class DecoderConfig:
     attn_qk_norm: bool = False         # ... and norms every q and k head
     router_norm_eps: float = 0.0       # the sigmoid rule's normaliser
     tie_word_embeddings: bool = False  # the head is the embedding table
+    # deepseek_v3
+    mla_rotary: bool = False           # an "mla" layer turns q_pe and kpe
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     reduce_dtype: Any = jnp.float32
@@ -226,7 +252,8 @@ class DecoderConfig:
                   "smallthinker": _smallthinker_fields,
                   "qwen3_next": _qwen3_next_fields,
                   "keye_vl2": _keye_vl2_fields,
-                  "lfm2_moe": _lfm2_moe_fields}[str(cfg.student.arch)]
+                  "lfm2_moe": _lfm2_moe_fields,
+                  "deepseek_v3": _deepseek_v3_fields}[str(cfg.student.arch)]
         return cls(dtype=policy.compute_dtype,
                    param_dtype=param_dtype or policy.param_dtype,
                    reduce_dtype=policy.reduce_dtype, **family(cfg.lm))
@@ -389,6 +416,47 @@ def _lfm2_moe_fields(lm) -> dict:
         router_norm_eps=LFM2_ROUTER_EPS,
         expert_shards=lm.expert_shards, expert_shard=lm.expert_shard,
         tie_word_embeddings=True, router="sigmoid", gate="silu")
+
+
+DEEPSEEK_V3_ROUTER_EPS = 1e-20  # the public implementation's, added to the sum
+
+
+def _deepseek_v3_fields(lm) -> dict:
+    if lm.get("q_lora_rank") is not None:
+        raise ValueError("lm.q_lora_rank: only null (a full q projection)")
+    if int(lm.n_group) != 1 or int(lm.topk_group) != 1:
+        raise ValueError("lm.n_group / lm.topk_group: only 1 / 1 (ONE group "
+                         "of experts: the group-limited step selects all)")
+    if str(lm.scoring_func) != "sigmoid" or str(lm.topk_method) != "noaux_tc" \
+            or not bool(lm.norm_topk_prob):
+        raise ValueError("the routed layer is a renormalised sigmoid router "
+                         "with a selection bias (lm.scoring_func sigmoid, "
+                         "lm.topk_method noaux_tc, lm.norm_topk_prob)")
+    if not bool(lm.rope_interleave) or lm.get("rope_scaling") is not None:
+        raise ValueError("lm.rope_interleave: only true; lm.rope_scaling: "
+                         "only null (positions as they are, no softmax "
+                         "scale of their own)")
+    if int(lm.moe_layer_freq) != 1:
+        raise ValueError("every layer past the leading dense ones is routed "
+                         "(lm.moe_layer_freq 1)")
+    return dict(
+        layers=tuple(("mla", "dense" if i < int(lm.first_k_dense_replace)
+                      else "moe") for i in range(int(lm.num_hidden_layers))),
+        hidden_size=lm.hidden_size, vocab_size=lm.vocab_size,
+        rms_norm_eps=lm.rms_norm_eps, intermediate_size=lm.intermediate_size,
+        num_attention_heads=lm.num_attention_heads,
+        kv_lora_rank=lm.kv_lora_rank, qk_nope_head_dim=lm.qk_nope_head_dim,
+        qk_rope_head_dim=lm.qk_rope_head_dim, v_head_dim=lm.v_head_dim,
+        rope_theta=float(lm.rope_theta), mla_rotary=True,
+        num_experts=lm.n_routed_experts,
+        num_experts_per_token=lm.num_experts_per_tok,
+        moe_intermediate_size=lm.moe_intermediate_size,
+        num_shared_experts=lm.n_shared_experts,
+        routed_scaling_factor=float(lm.routed_scaling_factor),
+        router_norm_eps=DEEPSEEK_V3_ROUTER_EPS,
+        expert_shards=lm.expert_shards, expert_shard=lm.expert_shard,
+        expert_rows_factor=float(lm.expert_rows_factor),
+        router="sigmoid", gate="silu")
 
 
 def _dense(features: int, axes, name: str, dtype, param_dtype) -> nn.Dense:
@@ -670,12 +738,17 @@ class ShortConvMixer(nn.Module):
 
 
 class MLAMixer(nn.Module):
+    """Latent attention (the module's docstring, **MLA**): ``rope_theta``
+    None carries the qk_rope channels unturned, a number turns them,
+    neighbouring channels a pair."""
+
     num_heads: int
     kv_lora_rank: int
     qk_nope_head_dim: int
     qk_rope_head_dim: int
     v_head_dim: int
     eps: float = 1e-5
+    rope_theta: float | None = None
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     reduce_dtype: Any = jnp.float32
@@ -696,12 +769,19 @@ class MLAMixer(nn.Module):
                     name="kv_a_norm")(c)
         kvb = _dense(h * (nope + dv), (None, "heads"), "kv_b", **kw)(c)
         kvb = kvb.reshape(b, t, h, nope + dv)
-        # no rotation is applied (mla_use_nope): kpe is one more slice
-        # of the key, the same for every head
-        k = jnp.concatenate([
-            kvb[..., :nope],
-            jnp.broadcast_to(kpe[:, :, None, :], (b, t, h, rope))], axis=-1)
+        if self.rope_theta is not None:
+            # ONE [B, T, 1, rope] turn of the shared key, not one a head;
+            # float32 tables: the turn itself is float32, its ends bf16
+            with jax.named_scope("mla_rope"):
+                table = token_rope_pair_sincos(t, rope, self.rope_theta)
+                q = rope_apply_interleaved(q, *table)
+                kpe = rope_apply_interleaved(kpe[:, :, None, :], *table)[:, :, 0]
         with jax.named_scope("mla_core"):
+            # kpe, turned or not (mla_use_nope), is one more slice of the
+            # key, the same for every head
+            k = jnp.concatenate([
+                kvb[..., :nope],
+                jnp.broadcast_to(kpe[:, :, None, :], (b, t, h, rope))], axis=-1)
             o = dispatch_attention(q, k, kvb[..., nope:], causal=True,
                                    reduce_dtype=self.reduce_dtype)
         return _dense(x.shape[-1], ("heads", "embed"), "o_proj", **kw)(
@@ -871,6 +951,7 @@ class DecoderLayer(nn.Module):
                 y = MLAMixer(c.num_attention_heads, c.kv_lora_rank,
                              c.qk_nope_head_dim, c.qk_rope_head_dim,
                              c.v_head_dim, c.rms_norm_eps,
+                             c.rope_theta if c.mla_rotary else None,
                              reduce_dtype=c.reduce_dtype, name="mla", **kw)(
                                  norm("norm1")(x))
                 x = x + y.astype(x.dtype)

@@ -1,5 +1,7 @@
 """2-D rotary position embeddings for ViT patch grids, as pure functions
-(and ``token_rope_sincos``: a decoder's 1-D table over token positions).
+(and ``token_rope_sincos``: a decoder's 1-D table over token positions;
+``token_rope_pair_sincos`` / ``rope_apply_interleaved``: the same turn on
+NEIGHBOURING channel pairs, latent attention's).
 
 Math parity with the reference module (dinov3_jax/layers/rope_position_encoding.py):
 - period spectrum from ``base ** (2j / (D_head/2))`` for j in [0, D_head/4)
@@ -260,6 +262,53 @@ def rope_apply_leading(
     rq, rk = rope_apply_full(q[..., :width], k[..., :width], sin, cos)
     return (jnp.concatenate([rq, q[..., width:]], axis=-1),
             jnp.concatenate([rk, k[..., width:]], axis=-1))
+
+
+def token_rope_pair_sincos(
+    n_tokens: int, width: int, theta: float, dtype=jnp.float32,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """(sin, cos), each [n_tokens, width / 2], of a rotary embedding over
+    token positions 0..n_tokens-1 on ``width`` channels: pair i of token t
+    turns by t * theta^(-2i / width). ``token_rope_sincos``'s angles, once
+    each (which two channels make pair i is the caller's)."""
+    if width % 2:
+        raise ValueError(f"width must be even, got {width}")
+    rates = jnp.asarray(theta, jnp.float32) ** (
+        -jnp.arange(0, width, 2, dtype=jnp.float32) / width)
+    angles = jnp.arange(n_tokens, dtype=jnp.float32)[:, None] * rates[None, :]
+    return jnp.sin(angles).astype(dtype), jnp.cos(angles).astype(dtype)
+
+
+def rope_apply_interleaved(
+    x: jnp.ndarray, sin: jnp.ndarray, cos: jnp.ndarray,
+) -> jnp.ndarray:
+    """Turn the TRAILING ``2 * sin.shape[-1]`` channels of every head of
+    x ([B, N, heads, width]; one head is a shared key) by the table
+    ([N, pairs]: ``token_rope_pair_sincos``), NEIGHBOURING channels a
+    pair (``rope_interleave``): (u, w) = channels (2i, 2i + 1) of the
+    slice -> (u cos - w sin, u sin + w cos). The leading channels carry
+    no position and pass as they are.
+
+    The turned slice comes back with every pair's first result before
+    every pair's second, [u' for all i ; w' for all i] (the order the
+    public latent-attention code leaves it in): a query and a key turned
+    by this function meet channel for channel, so their products are the
+    published ones. Computed in the table's type (float32 tables upcast
+    x transiently), returned in x's."""
+    pairs = sin.shape[-1]
+    lead = x.shape[-1] - 2 * pairs
+    if lead < 0:
+        raise ValueError(f"{2 * pairs} turned channels on heads of {x.shape[-1]}")
+    compute = jnp.promote_types(x.dtype, sin.dtype)
+    s = sin[None, :, None, :].astype(compute)
+    c = cos[None, :, None, :].astype(compute)
+    z = x[..., lead:].astype(compute).reshape(x.shape[:-1] + (pairs, 2))
+    u, w = z[..., 0], z[..., 1]
+    turned = jnp.concatenate([u * c - w * s, u * s + w * c], axis=-1)
+    turned = turned.astype(x.dtype)
+    if not lead:
+        return turned
+    return jnp.concatenate([x[..., :lead], turned], axis=-1)
 
 
 def rope_packed_rows(

@@ -552,7 +552,8 @@ STEP_PHASES = (
     # stays ``update``
     "lm_embed",           # the token embedding's gather
     "kda_mixer",          # a KDA layer's mixer (inner: kda_core)
-    "mla_mixer",          # a latent-attention layer's mixer (inner: mla_core)
+    "mla_mixer",          # a latent-attention layer's mixer (inner:
+                          # mla_rope, mla_core)
     "swa_mixer",          # a grouped-query layer with a window and rotary
     "full_attn_mixer",    # ... with neither (inner of both: gqa_core)
     "gdn_mixer",          # a Gated DeltaNet layer's mixer (inner: gdn_core)

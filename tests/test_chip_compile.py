@@ -9,8 +9,10 @@ at both decoder cells' published shapes (under a selection too, and the
 index loss's kernel beside them), and the delta-rule mixers'
 chains (``ops/mixer_chains.py``) at both delta-rule cells', the gated
 short convolution's chain and the causal pair at heads of 64 at the
-``lfm2_moe`` cell's, and the routed layers' experts' block
-(``ops/grouped_matmul.py``) at the five decoder cells' — each with
+``lfm2_moe`` cell's, the causal pair at the ``deepseek_v3`` cell's ONE
+row of 16,384 tokens at 192 | 128 (the backward's residency at its limit),
+and the routed layers' experts' block
+(``ops/grouped_matmul.py``) at the six decoder cells' — each with
 ``interpret=False``, each asserting
 a Mosaic ``tpu_custom_call`` in the compiled text. What the chip's
 compiler would refuse (a slice off the tiling, too much VMEM) fails
@@ -187,7 +189,8 @@ def test_gdn_chunk_kernels_compile_for_v5e(one_chip, states):
     ((1, 16384, 28, 128), 4, 128, None),
     ((2, 8192, 32, 192), 32, 128, None),
     ((2, 8192, 16, 256), 2, 256, None),
-], ids=["window", "global", "mla", "gated"])
+    ((1, 16384, 32, 192), 32, 128, None),
+], ids=["window", "global", "mla", "gated", "mla16k"])
 def test_causal_attention_kernels_compile_for_v5e(one_chip, q, kv, dv,
                                                   window, direction):
     """``ops/causal_attention.py`` at the shapes the two decoder cells
@@ -475,9 +478,10 @@ def test_ibot_row_ce_gradient_compiles_without_a_loop_for_v5e(one_chip):
     ("qwen3-next-ep16-pretrain-8k", 40960, 32, 2048, 512, "silu"),
     ("keye-vl2-ep8-pretrain-16k", 32768, 16, 2048, 768, "silu"),
     ("kimi-linear-ep32-pretrain-8k", 8192, 8, 2304, 1024, "silu"),
+    ("kanana2-ep8-pretrain-16k", 49152, 16, 2048, 768, "silu"),
 ])
 def test_experts_block_compiles_for_v5e(one_chip, cell, cap, held, d, h, gate):
-    """The held experts' block (``ops/grouped_matmul.py``) at the five
+    """The held experts' block (``ops/grouped_matmul.py``) at the six
     decoder cells' routed layers, both passes: two kernels forward and
     three backward; the float32 buffers XLA holds
     beside them stay under the combine's operand and its cotangent."""

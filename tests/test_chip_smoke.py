@@ -59,6 +59,8 @@ TINY = {
     "sconv_shape": (1, 256, 128),
     "sconv_attn_shapes": {"heads64": (1, 256, 8, 2, 64, 64, None)},
     "moe_shapes": {"tiny": (512, 4, 128, 128, "silu", (0.3,))},
+    "mla_shape": (1, 256, 2, 192, 64),
+    "mla_attn_shapes": {"mla16k": (1, 256, 1, 1, 192, 128, None)},
     "gqa_shipped_blocks": (128, 256),
     "gqa_blocks": [(256, 128)],
     "gqa_timeout_s": 600,
@@ -151,6 +153,11 @@ def test_smoke_rehearsal_runs_every_phase(smoke, monkeypatch, capsys):
                    "kernel (interpreted), 256 rows a visit",
                    "moe: tiny fill 0.3: norm of the difference over the norm",
                    "largest value past the last group 0.0e+00",
+                   "mla: turn (1, 256, 2, 192) + (1, 256, 1, 64): first calls",
+                   "mla: turn: norm of the difference over the norm, to the "
+                   "complex multiplication",
+                   "gqa: mla16k core (1, 256, 1, 1, 192, 128) window None: the "
+                   "entry point takes the kernel (interpreted)",
                    "all phases passed"):
         assert needle in said, needle
     assert "mesh[" not in said  # the four-chip phase is behind --chips 4
