@@ -90,8 +90,7 @@ SIZES = {
     # block_kv) pairs timed on the kernel path beside the shipped 512 x
     # 1,024 (information: why it stays)
     "gqa_shapes": {"window": (1, 16384, 28, 4, 128, 128, 4096),
-                   "global": (1, 16384, 28, 4, 128, 128, None),
-                   "mla": (2, 8192, 32, 32, 192, 128, None)},
+                   "global": (1, 16384, 28, 4, 128, 128, None)},
     # the third decoder's two cores at published sizes (--phases gdn): the
     # delta rule with ONE decay a head, [B, T, key heads, value heads,
     # d_k = d_v],
@@ -107,12 +106,19 @@ SIZES = {
     # core at 32 query heads on 8 key/value heads of 64
     "sconv_shape": (4, 8192, 2048),
     "sconv_attn_shapes": {"heads64": (4, 8192, 32, 8, 64, 64, None)},
-    # the sixth decoder's turn and latent core at published sizes (--phases
-    # mla): q [B, T, heads, 128 + 64] beside ONE shared key head of 64, then
-    # the causal core at ONE row of 16,384 tokens, 32 heads of 192 | 128 (the
-    # backward's residency at its limit to the byte)
+    # latent attention at published sizes (--phases mla): the plain arm's
+    # turn of q [B, T, heads, 128 + 64] beside ONE shared key head of 64;
+    # the latent kernel pair, [B, T, heads, rope_theta, the plain tiles fit
+    # the chip beside it], at the 8k decoder's two rows (no turn) and the
+    # sixth decoder's ONE row of 16,384 tokens (32 heads of 128 | 64 | 128;
+    # the tiles would take 18.2 GB there); and the generic pair at the
+    # same row with q and k a whole 256 wide: what a head's key written
+    # out and q and k padded would cost the KERNELS (a kernel's loss told
+    # from a copy's)
     "mla_shape": (1, 16384, 32, 192, 64),
-    "mla_attn_shapes": {"mla16k": (1, 16384, 32, 32, 192, 128, None)},
+    "mla_latent_shapes": {"mla8k": (2, 8192, 32, None, True),
+                          "mla16k": (1, 16384, 32, 1e6, False)},
+    "mla_attn_shapes": {"wide16k": (1, 16384, 32, 32, 256, 128, None)},
     # the routed layers' held experts' block at the five decoder cells'
     # published shapes (--phases moe): [buffer rows, held experts, D, H,
     # gate, the shares of the buffer routed rows fill] — the cells' measured
@@ -504,6 +510,60 @@ def _timed(fn, x, n=3):
 
 # ------------------------------------------- the banded grouped-query core
 
+def _dense_causal(t, h, hk, d, w, rows=256):
+    """The dense masked softmax of [B, T, h, d] q on hk key/value heads in
+    float32: whole rows of keys, k and v repeated for every query head, a
+    block of ``rows`` queries at a time so that it fits."""
+    import jax
+    import jax.numpy as jnp
+
+    def fn(q, k, v):
+        q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+        k, v = (jnp.repeat(x, h // hk, axis=2) for x in (k, v))
+        pad = (-t) % rows
+        qp = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
+
+        @jax.checkpoint
+        def block(args):
+            qb, first = args
+            z = jnp.einsum("bqhd,bkhd->bhqk", qb, k) * d ** -0.5
+            at = jnp.minimum(first + jnp.arange(rows), t - 1)[:, None]
+            key = jnp.arange(t)[None, :]
+            seen = key <= at if w is None else (key <= at) & (key > at - w)
+            p = jax.nn.softmax(jnp.where(seen, z, -jnp.inf), -1)
+            return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+        blocks = jnp.moveaxis(qp.reshape(q.shape[0], -1, rows, h, d), 1, 0)
+        o = jax.lax.map(block, (blocks, jnp.arange(blocks.shape[0]) * rows))
+        return jnp.moveaxis(o, 0, 1).reshape(
+            q.shape[0], t + pad, h, -1)[:, :t]
+    return fn
+
+
+def _out_and_grads(f):
+    """The output and a gradient that weighs every element, on all three
+    operands."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.jit(lambda *x: (f(*x), *jax.grad(
+        lambda *y: jnp.sum(jnp.sin(f(*y).astype(jnp.float32))),
+        argnums=(0, 1, 2))(*x)))
+
+
+def _gaps(got, want):
+    """The difference's norm over the reference's, a result each."""
+    import jax.numpy as jnp
+
+    f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
+    return [float(jnp.linalg.norm(f32(a) - f32(r)) / jnp.linalg.norm(f32(r)))
+            for a, r in zip(got, want)]
+
+
+def _said(xs) -> str:
+    return " ".join(f"{x:.3e}" for x in xs)
+
+
 def phase_gqa(shapes: str = "gqa_shapes", with_tiles: bool = True) -> None:
     """``ops/attention.py causal_blockwise_attention`` as the decoders'
     layers call it, at the published head sizes and whole contexts: the
@@ -533,41 +593,6 @@ def phase_gqa(shapes: str = "gqa_shapes", with_tiles: bool = True) -> None:
     faulthandler.dump_traceback_later(
         float(SIZES["gqa_timeout_s"]), exit=True, file=sys.__stderr__)
 
-    def dense(t, h, hk, d, w, rows=256):
-        def fn(q, k, v):
-            q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
-            k, v = (jnp.repeat(x, h // hk, axis=2) for x in (k, v))
-            pad = (-t) % rows
-            qp = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
-
-            @jax.checkpoint
-            def block(args):
-                qb, first = args
-                z = jnp.einsum("bqhd,bkhd->bhqk", qb, k) * d ** -0.5
-                at = jnp.minimum(first + jnp.arange(rows), t - 1)[:, None]
-                key = jnp.arange(t)[None, :]
-                seen = key <= at if w is None else (key <= at) & (key > at - w)
-                p = jax.nn.softmax(jnp.where(seen, z, -jnp.inf), -1)
-                return jnp.einsum("bhqk,bkhd->bqhd", p, v)
-
-            blocks = jnp.moveaxis(qp.reshape(q.shape[0], -1, rows, h, d), 1, 0)
-            o = jax.lax.map(block, (blocks, jnp.arange(blocks.shape[0]) * rows))
-            return jnp.moveaxis(o, 0, 1).reshape(
-                q.shape[0], t + pad, h, -1)[:, :t]
-        return fn
-
-    def out_and_grads(f):
-        return jax.jit(lambda *x: (f(*x), *jax.grad(
-            lambda *y: jnp.sum(jnp.sin(f(*y).astype(jnp.float32))),
-            argnums=(0, 1, 2))(*x)))
-
-    def gaps(got, want):  # the difference's norm over the reference's
-        f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
-        return [float(jnp.linalg.norm(f32(a) - f32(r)) / jnp.linalg.norm(f32(r)))
-                for a, r in zip(got, want)]
-
-    said = lambda xs: " ".join(f"{x:.3e}" for x in xs)  # noqa: E731
-
     bq, bkv = SIZES["gqa_shipped_blocks"]
     for name, (b, t, h, hk, d, dv, w) in SIZES[shapes].items():
         ks = jax.random.split(jax.random.key(2), 3)
@@ -587,26 +612,26 @@ def phase_gqa(shapes: str = "gqa_shapes", with_tiles: bool = True) -> None:
         def tiles(*a, w=w):
             return causal_tiles(*a, bq, bkv, jnp.float32, w)
 
-        _compiled_has_kernel(out_and_grads(kernel), *x)
+        _compiled_has_kernel(_out_and_grads(kernel), *x)
         found = {}
         for path, fn in (("kernel", kernel), ("tiles", tiles))[:1 + with_tiles]:
             first_f, ms_f, _ = _timed(jax.jit(fn), x)
-            first, ms, found[path] = _timed(out_and_grads(fn), x)
+            first, ms, found[path] = _timed(_out_and_grads(fn), x)
             log(f"gqa: {name} core, {path}: first calls {first_f:.1f}s and "
                 f"{first:.1f}s, forward {ms_f:.1f} ms, forward + backward "
                 f"{ms:.1f} ms")
         with jax.default_matmul_precision("highest"):
-            want = out_and_grads(dense(t, h, hk, d, w))(*x)
+            want = _out_and_grads(_dense_causal(t, h, hk, d, w))(*x)
         log(f"gqa: {name} core: norm of the difference over the norm, "
             f"output and gradients q k v: kernel to the dense masked softmax "
-            f"{said(gaps(found['kernel'], want))}" + (
-                f"; tiles to it {said(gaps(found['tiles'], want))}; kernel "
-                f"to tiles {said(gaps(found['kernel'], found['tiles']))}"
+            f"{_said(_gaps(found['kernel'], want))}" + (
+                f"; tiles to it {_said(_gaps(found['tiles'], want))}; kernel "
+                f"to tiles {_said(_gaps(found['kernel'], found['tiles']))}"
                 if with_tiles else ""))
         for got in found.values():
-            assert all(g <= 2e-2 for g in gaps(got, want)), name
+            assert all(g <= 2e-2 for g in _gaps(got, want)), name
         for obq, obkv in SIZES["gqa_blocks"]:
-            first, ms, _ = _timed(out_and_grads(functools.partial(
+            first, ms, _ = _timed(_out_and_grads(functools.partial(
                 kernel, bq=obq, bkv=obkv)), x)
             log(f"gqa: {name} core, kernel at blocks {obq} x {obkv}: first "
                 f"call {first:.1f}s, forward + backward {ms:.1f} ms")
@@ -740,17 +765,33 @@ def phase_sconv() -> None:
 
 
 def phase_mla() -> None:
-    """The ``deepseek_v3`` family's mixer stand-alone at published sizes.
-    The interleaved rotary turn (``ops/rope.py rope_apply_interleaved``) of
+    """Latent attention stand-alone at published sizes. The plain arm's
+    interleaved rotary turn (``ops/rope.py rope_apply_interleaved``) of
     every query head's last 64 channels and of the ONE shared key head,
     bfloat16 ends, against a complex multiplication in float32, forward and
-    forward + backward timed; then the latent core at one row of 16,384
-    tokens through ``phase_gqa``'s rows, the kernel pair against the dense
-    masked softmax alone (the plain tiles do not fit the chip there)."""
+    forward + backward timed. Then the latent kernel pair
+    (``ops/causal_attention.py latent_attention``) as ``MLAMixer`` calls
+    it — q, kvb and the shared key as the projections leave them, q's
+    rope channels turned in the kernels — at both latent cells' shapes:
+    the path ``latent_attention_path`` takes there, output and the
+    gradients of q, kvb and kpe against the dense masked softmax in
+    float32 (the key written out a head, the turn by
+    ``rope_apply_interleaved``) and, where they fit, against the plain
+    arm (the key repeated, the plain tiles), forward and forward +
+    backward timed. Last the generic pair through ``phase_gqa``'s rows
+    at heads of 256 | 128: the kernels alone on operands already padded."""
+    import faulthandler
+
     import jax
     import jax.numpy as jnp
 
-    from dinov3_tpu.ops.rope import rope_apply_interleaved, token_rope_pair_sincos
+    from dinov3_tpu.ops import causal_attention as kernels
+    from dinov3_tpu.ops.attention import causal_tiles
+    from dinov3_tpu.ops.rope import (
+        rope_apply_interleaved,
+        rope_apply_pairs,
+        token_rope_pair_sincos,
+    )
 
     b, t, h, d, rope = SIZES["mla_shape"]
     ks = jax.random.split(jax.random.key(8), 2)
@@ -786,14 +827,70 @@ def phase_mla() -> None:
         f"{first_f:.1f}s and {first:.1f}s, forward {ms_f:.2f} ms, forward + "
         f"backward {ms:.2f} ms")
     want = out_and_grads(by_complex)(*x)
-    f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
-    gaps = [float(jnp.linalg.norm(f32(a) - f32(r)) / jnp.linalg.norm(f32(r)))
-            for a, r in zip(got, want)]
+    gaps = _gaps(got, want)
     log("mla: turn: norm of the difference over the norm, to the complex "
-        "multiplication, q kpe and gradients q kpe "
-        + " ".join(f"{g:.3e}" for g in gaps))
+        "multiplication, q kpe and gradients q kpe " + _said(gaps))
     # (bfloat16 ends: one rounding of the output, one of each gradient)
     assert all(math.isfinite(g) for g in gaps) and max(gaps) <= 1e-2, gaps
+
+    interpret = bool(SIZES["kernel_interpret"])
+    faulthandler.dump_traceback_later(
+        float(SIZES["gqa_timeout_s"]), exit=True, file=sys.__stderr__)
+    # the latent entry's own blocks (the test's table names smaller ones)
+    bq, bkv = SIZES.get("mla_blocks", (kernels.LATENT_BLOCK_Q,
+                                       kernels.LATENT_BLOCK_KV))
+    nope, dv = d - rope, 128
+    for name, (b, t, h, theta, with_tiles) in SIZES["mla_latent_shapes"].items():
+        path, why = kernels.latent_attention_path(
+            t, h, (nope, rope, dv), interpret or None, bq, bkv)
+        log(f"mla: {name} latent core {(b, t, h, nope, rope, dv)} theta "
+            f"{theta}: the mixer takes the {path} ({why})")
+        assert path == "kernel", (name, path, why)
+        ks = jax.random.split(jax.random.key(9), 3)
+        x = tuple(jax.random.normal(key, (b, t, w), jnp.bfloat16)
+                  for key, w in zip(ks, (h * d, h * (nope + dv), rope)))
+        table = None if theta is None else token_rope_pair_sincos(t, rope, theta)
+
+        def kernel(q, kvb, kpe, table=table, theta=theta):
+            if table is not None:
+                kpe = rope_apply_pairs(kpe, *table)
+            return kernels.latent_attention(q, kvb, kpe, theta, bq, bkv, interpret)
+
+        def a_key_a_head(core, b=b, t=t, h=h, table=table):
+            """``MLAMixer``'s plain arm around ``core(q, k, v)``."""
+            def fn(q, kvb, kpe):
+                q, kvb = q.reshape(b, t, h, d), kvb.reshape(b, t, h, nope + dv)
+                kpe = kpe[:, :, None, :]
+                if table is not None:
+                    q = rope_apply_interleaved(q, *table)
+                    kpe = rope_apply_interleaved(kpe, *table)
+                k = jnp.concatenate([kvb[..., :nope], jnp.broadcast_to(
+                    kpe, (b, t, h, rope))], axis=-1)
+                return core(q, k, kvb[..., nope:]).reshape(b, t, h * dv)
+            return fn
+
+        tiles = a_key_a_head(lambda *a: causal_tiles(  # (at ITS blocks)
+            *a, *SIZES["gqa_shipped_blocks"], jnp.float32, None))
+        _compiled_has_kernel(_out_and_grads(kernel), *x)
+        found = {}
+        for path, fn in (("kernel", kernel), ("tiles", tiles))[:1 + with_tiles]:
+            first_f, ms_f, _ = _timed(jax.jit(fn), x)
+            first, ms, found[path] = _timed(_out_and_grads(fn), x)
+            log(f"mla: {name} latent core, {path}: first calls {first_f:.1f}s "
+                f"and {first:.1f}s, forward {ms_f:.1f} ms, forward + backward "
+                f"{ms:.1f} ms")
+        with jax.default_matmul_precision("highest"):
+            want = _out_and_grads(a_key_a_head(_dense_causal(
+                t, h, h, d, None)))(*(a.astype(jnp.float32) for a in x))
+        log(f"mla: {name} latent core: norm of the difference over the norm, "
+            f"output and gradients q kvb kpe: kernel to the dense masked "
+            f"softmax {_said(_gaps(found['kernel'], want))}" + (
+                f"; tiles to it {_said(_gaps(found['tiles'], want))}; kernel "
+                f"to tiles {_said(_gaps(found['kernel'], found['tiles']))}"
+                if with_tiles else ""))
+        for got in found.values():
+            assert all(g <= 2e-2 for g in _gaps(got, want)), name
+    faulthandler.cancel_dump_traceback_later()
     phase_gqa("mla_attn_shapes", with_tiles=False)
 
 

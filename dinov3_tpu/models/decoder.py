@@ -151,6 +151,10 @@ import jax
 import jax.numpy as jnp
 
 from dinov3_tpu.ops.attention import dispatch_attention
+from dinov3_tpu.ops.causal_attention import (
+    latent_attention,
+    latent_attention_path,
+)
 from dinov3_tpu.ops.common import l2_normalize, part, trunc_normal_init
 from dinov3_tpu.ops.ffn import ROWS_CAPACITY_FACTOR, RoutedExpertsFFN, SwiGLUFFN
 from dinov3_tpu.ops.kda import kda_chunked
@@ -167,6 +171,7 @@ from dinov3_tpu.ops.rope import (
     rope_apply_full,
     rope_apply_interleaved,
     rope_apply_leading,
+    rope_apply_pairs,
     token_rope_pair_sincos,
     token_rope_sincos,
 )
@@ -752,6 +757,7 @@ class MLAMixer(nn.Module):
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     reduce_dtype: Any = jnp.float32
+    core_interpret: bool | None = None   # tests: latent_attention_path's
 
     @nn.compact
     def __call__(self, x):
@@ -761,13 +767,30 @@ class MLAMixer(nn.Module):
                           self.v_head_dim)
         kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
         xc = x.astype(self.dtype)
+        # the kernel pair reads q, kvb and the ONE shared key where the
+        # projections leave them; the plain tiles want a key a head
+        latent = latent_attention_path(
+            t, h, (nope, rope, dv), self.core_interpret, dtype=self.dtype,
+            reduce_dtype=self.reduce_dtype)[0] == "kernel"
         q = _dense(h * (nope + rope), ("embed", "heads"), "q_proj", **kw)(xc)
-        q = q.reshape(b, t, h, nope + rope)
+        if not latent:
+            q = q.reshape(b, t, h, nope + rope)
         kva = _dense(self.kv_lora_rank + rope, ("embed", None), "kv_a", **kw)(xc)
         c, kpe = kva[..., :self.kv_lora_rank], kva[..., self.kv_lora_rank:]
         c = RMSNorm(epsilon=self.eps, param_dtype=self.param_dtype,
                     name="kv_a_norm")(c)
         kvb = _dense(h * (nope + dv), (None, "heads"), "kv_b", **kw)(c)
+        if latent:
+            if self.rope_theta is not None:
+                # q's rope channels are turned in the kernels, neighbours
+                # staying neighbours: the shared key likewise, here
+                with jax.named_scope("mla_rope"):
+                    kpe = rope_apply_pairs(
+                        kpe, *token_rope_pair_sincos(t, rope, self.rope_theta))
+            with jax.named_scope("mla_core"):
+                o = latent_attention(q, kvb, kpe, self.rope_theta,
+                                     interpret=bool(self.core_interpret))
+            return _dense(x.shape[-1], ("heads", "embed"), "o_proj", **kw)(o)
         kvb = kvb.reshape(b, t, h, nope + dv)
         if self.rope_theta is not None:
             # ONE [B, T, 1, rope] turn of the shared key, not one a head;

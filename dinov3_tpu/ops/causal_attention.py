@@ -1,5 +1,6 @@
 """The causal tiles of ``ops/attention.py causal_blockwise_attention`` as
-two Pallas kernels: banded, grouped heads in the rows, value width free.
+two Pallas kernels: banded, grouped heads in the rows, value width free;
+and latent attention's pair, which takes its key in two parts.
 
 The mathematics is the plain path's (an online softmax over key tiles,
 from the band's first tile to the diagonal's, masked entries a finite
@@ -75,10 +76,33 @@ sequence in float32 VMEM scratch, as dk and dv above) never leave VMEM:
 no plane a head, no target plane, no [queries, keys] float32 at all in
 HBM.
 
-A q/k width that is not a multiple of the 128-lane tile (latent
-attention's 192) is padded with zeros up to one (a copy through HBM on
-each pass; the products are unchanged, a 192-wide contraction fills two
-MXU passes as a 256-wide one does).
+Latent attention (``latent_attention``: ``latent_attn_fwd`` /
+``latent_attn_bwd``) is a pair of its own, because its operands differ in
+KIND: a key in two arrays, 128 channels a head beside the head's values
+in ``kvb`` and ONE shared 64 (``kpe``), and q 192 wide a head, which is a
+lane group and a half. A score is the sum of two products, q_nope .
+k_nope + q_rope . kpe, made as ONE 256-deep product (float32
+accumulation): a head's q as [nope | its rope channels in its half of a
+lane group, zeros in the other] against [k_nope | kpe, kpe]. Nothing a
+head is written to HBM on the way in or out: q arrives a PAIR of heads a
+block, 384 lanes as the projection leaves them, and the four parts are
+put in place once a query block in VMEM (``pltpu.roll`` by 64, as the
+heads of 64 are); a rotary turn of q's rope channels is made there too,
+neighbouring lanes a pair (two rolls by one lane), so no XLA pass ever
+touches the [tokens, heads, 192] plane; k_nope and v are ONE 256-lane
+block of ``kvb`` as it lies and their cotangents leave the same way; the
+shared key's cotangent sums over all heads of a sequence in a float32
+block that stays in VMEM through the sequence's whole grid, and is
+rounded once. dq of a pair is one block as q is, so the backward's grid
+runs (sequence, pair, query block, head of the pair, key tile) and holds
+dk and dv of BOTH heads (their blocks in ONE buffer each: they change
+sixteen times a sequence). The band's arithmetic, the online softmax and
+the backward's five products are the generic pair's. A 192-deep
+contraction fills two 128-deep MXU passes as a 256-deep one does:
+what this pair saves is copies, not products.
+
+A q/k width that is neither a multiple of the 128-lane tile nor packed
+takes the plain tiles (``causal_attention_path``).
 """
 
 from __future__ import annotations
@@ -92,6 +116,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 KERNEL_NAME = "causal_attn_fwd"
 BACKWARD_KERNEL_NAME = "causal_attn_bwd"
+LATENT_KERNEL_NAME = "latent_attn_fwd"
+LATENT_BACKWARD_KERNEL_NAME = "latent_attn_bwd"
 INDEX_LOSS_KERNEL_NAME = "index_loss_value"
 INDEX_LOSS_GRAD_KERNEL_NAME = "index_loss_grad"
 _LANES = 128
@@ -100,6 +126,12 @@ _NEG = -1e30
 # what the backward may hold of one key/value head's dk and dv: the
 # float32 accumulators and the (double-buffered) blocks they leave in
 _RESIDENT_BYTES = 48 * 1024 * 1024
+# ... and of a PAIR of latent attention's heads beside the shared key's
+# cotangent, each in ONE buffer (``latent_attention_path``)
+_LATENT_RESIDENT_BYTES = 64 * 1024 * 1024
+# the latent pair's (queries a block, keys a tile): its own, no plain path
+# shares them
+LATENT_BLOCK_Q, LATENT_BLOCK_KV = 1024, 1024
 _COMPILER_PARAMS = pltpu.CompilerParams(
     # sequences, key/value heads, query blocks, a block's key tiles
     dimension_semantics=("parallel", "parallel", "arbitrary", "arbitrary"),
@@ -132,11 +164,12 @@ def _band(i, block_q, block_kv, window):
     return first, last - first + 1
 
 
-def _tile_place(block_q, block_kv, window):
+def _tile_place(block_q, block_kv, window, axes=(2, 3)):
     """Of this grid step: (the tile is of the block's band, an edge
     crosses it, its first key less the block's first query, the tile's
-    index, the band's length)."""
-    i, t = pl.program_id(2), pl.program_id(3)
+    index, the band's length). ``axes``: the grid's axes of the query
+    block and of the band's tile."""
+    i, t = (pl.program_id(a) for a in axes)
     first, count = _band(i, block_q, block_kv, window)
     off = (first + t) * block_kv - i * block_q
     edge = off + block_kv > 1                       # the diagonal
@@ -187,6 +220,20 @@ def _unplaced(ref, planes, per_kv):
             _iota(low.shape, 1) < _HALF, low, high).astype(ref.dtype)
 
 
+def _online_softmax(s, v, j, m_scr, l_scr, acc_scr):
+    """One key tile's float32 scores ``s`` [block_q, block_kv] and values
+    ``v`` into head ``j``'s running maximum, sum and accumulator."""
+    block_kv, dv = s.shape[1], v.shape[1]
+    m_prev = m_scr[j]
+    m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - pltpu.repeat(m_next, block_kv // _LANES, axis=1))
+    alpha = jnp.exp(m_prev - m_next)
+    l_scr[j] = alpha * l_scr[j] + jnp.sum(p, axis=1, keepdims=True)
+    m_scr[j] = m_next
+    acc_scr[j] = acc_scr[j] * pltpu.repeat(
+        alpha, dv // _LANES, axis=1) + _dot(p.astype(v.dtype), v)
+
+
 def _fwd_kernel(*refs, scale, group, block_q, block_kv, window, keep_lse,
                 selected=False, packed=False):
     """q_ref [block_q, g * d], k_ref [block_kv, d], v_ref [block_kv, dv],
@@ -232,14 +279,7 @@ def _fwd_kernel(*refs, scale, group, block_q, block_kv, window, keep_lse,
                 s = jnp.where(_seen(s.shape, 0, off, window), s, _NEG)
             if selected:
                 s = jnp.where(sel_ref[...].astype(jnp.int32) != 0, s, _NEG)
-            m_prev = m_scr[j]
-            m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            p = jnp.exp(s - pltpu.repeat(m_next, block_kv // _LANES, axis=1))
-            alpha = jnp.exp(m_prev - m_next)
-            l_scr[j] = alpha * l_scr[j] + jnp.sum(p, axis=1, keepdims=True)
-            m_scr[j] = m_next
-            acc_scr[j] = acc_scr[j] * pltpu.repeat(
-                alpha, dv // _LANES, axis=1) + _dot(p.astype(v.dtype), v)
+            _online_softmax(s, v, j, m_scr, l_scr, acc_scr)
             return carry
 
         jax.lax.fori_loop(0, group, head, 0)
@@ -365,22 +405,19 @@ def _packs(d: int, dv: int, hk: int) -> bool:
 
 
 def _operands(q, k, block_q, block_kv, window, dv=None):
-    """(q and k widened to the lane tile, the grid, the block of a
-    [B, N, h * w] array of query heads, the block of a [B, N, hk * w]
-    array of keys or values along the band, the block of a [B, hk, g, N]
-    array of row statistics, the [block_q, block_kv] block of a [B, N, N]
-    selection along the band, the query heads a grid step and how many
-    key/value heads a step holds: 1 or 2). Heads of 64 + 64 (``_packs``): nothing is widened, a
-    grid step is a PAIR of key/value heads, one 128-lane block of the
-    keys as they lie, and the 2 g query heads that read them; the row
-    statistics are then [B, hk / 2, 2 g, N], the same bytes."""
+    """(the grid, the block of a [B, N, h * w] array of query heads, the
+    block of a [B, N, hk * w] array of keys or values along the band, the
+    block of a [B, hk, g, N] array of row statistics, the [block_q,
+    block_kv] block of a [B, N, N] selection along the band, the query
+    heads a grid step and how many key/value heads a step holds: 1 or 2).
+    Heads of 64 + 64 (``_packs``): a grid step is a PAIR of key/value
+    heads, one 128-lane block of the keys as they lie, and the 2 g query
+    heads that read them; the row statistics are then [B, hk / 2, 2 g,
+    N], the same bytes."""
     b, n, h, d = q.shape
     hk = k.shape[2]
-    packed = _packs(d, dv, hk)
-    pack = 2 if packed else 1
-    g, pad = h // hk * pack, 0 if packed else (-d) % _LANES
-    if pad:
-        q, k = (jnp.pad(x, ((0, 0),) * 3 + ((0, pad),)) for x in (q, k))
+    pack = 2 if _packs(d, dv, hk) else 1
+    g = h // hk * pack
     blocks = n // block_q
     steps = max(_band(i, block_q, block_kv, window)[1] for i in range(blocks))
 
@@ -400,7 +437,7 @@ def _operands(q, k, block_q, block_kv, window, dv=None):
     pair = pl.BlockSpec(
         (None, block_q, block_kv),
         lambda s, kh, i, t: (s, i, band_tile(s, kh, i, t)[1]), memory_space=vmem)
-    return q, k, (b, hk // pack, blocks, steps), rows, keys, stats, pair, g, pack
+    return (b, hk // pack, blocks, steps), rows, keys, stats, pair, g, pack
 
 
 # A ``pallas_call`` traces its kernel body every time it is called, and a
@@ -414,11 +451,10 @@ def _kernel_forward(q, k, v, scale, window, block_q, block_kv, keep_lse,
                     interpret, selection=None):
     """(o [B, N, h, dv], the rows' log-sum-exp [B, hk, g, N] float32 or
     None) as one ``pallas_call``."""
-    b, n, h, _ = q.shape
+    b, n, h, d = q.shape
     hk, dv = v.shape[2], v.shape[3]
-    q, k, grid, rows, keys, stats, pair, g, pack = _operands(
+    grid, rows, keys, stats, pair, g, pack = _operands(
         q, k, block_q, block_kv, window, dv)
-    d = q.shape[-1]
     packed, wide = pack == 2, pack * d    # (the scratch planes' width)
     flat = lambda x: x.reshape(b, n, -1)  # noqa: E731
     chosen = () if selection is None else (selection,)
@@ -453,18 +489,17 @@ def _kernel_forward(q, k, v, scale, window, block_q, block_kv, keep_lse,
     "scale", "window", "block_q", "block_kv", "interpret"))
 def _kernel_backward(q, k, v, o, lse, do, scale, window, block_q, block_kv,
                      interpret, selection=None):
-    """The cotangents of q, k, v, in their types and widths, as one
-    ``pallas_call`` from what the forward rule kept."""
-    b, n, h, width = q.shape
+    """The cotangents of q, k, v, in their types, as one ``pallas_call``
+    from what the forward rule kept."""
+    b, n, h, d = q.shape
     hk, dv = v.shape[2], v.shape[3]
-    packed = _packs(width, dv, hk)
+    packed = _packs(d, dv, hk)
     if not packed:
         # a product would round its float32 operands on the TPU: multiply, add
         delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
         delta = jnp.swapaxes(delta, 1, 2).reshape(b, hk, h // hk, n)
-    qp, kp, grid, rows, keys, stats, pair, g, pack = _operands(
+    grid, rows, keys, stats, pair, g, pack = _operands(
         q, k, block_q, block_kv, window, dv)
-    d = qp.shape[-1]
     wide = pack * d
     flat = lambda x: x.reshape(b, n, -1)  # noqa: E731
     delta_spec = stats
@@ -498,9 +533,9 @@ def _kernel_backward(q, k, v, o, lse, do, scale, window, block_q, block_kv,
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
         name=BACKWARD_KERNEL_NAME,
-    )(flat(qp), flat(kp), flat(v), flat(do), lse, delta, *chosen)
-    return (dq.reshape(b, n, h, d)[..., :width],
-            dk.reshape(b, n, hk, d)[..., :width], dv_.reshape(b, n, hk, dv))
+    )(flat(q), flat(k), flat(v), flat(do), lse, delta, *chosen)
+    return (dq.reshape(b, n, h, d), dk.reshape(b, n, hk, d),
+            dv_.reshape(b, n, hk, dv))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -565,6 +600,351 @@ def _kernel_attention_selected_bwd(scale, block_q, block_kv, interpret, res, ct)
 # output), and several outputs under it break JAX's DCE (PERF.md, PR 39)
 kernel_attention_selected.defvjp(
     _kernel_attention_selected_fwd, _kernel_attention_selected_bwd)
+
+
+# ---------------------------------------------------------------- latent
+# Latent attention's key comes in two parts: 128 channels a head
+# (``k_nope``, beside the head's v in ``kvb``) and ONE shared 64 (``kpe``).
+# The pair below reads q, kvb and kpe where the projections left them and
+# writes their cotangents where the projections' transposes want them.
+
+def _swap_pairs(x):
+    """Neighbouring lanes exchanged: (2i, 2i + 1) -> (2i + 1, 2i)."""
+    even = _iota(x.shape, 1) % 2 == 0
+    return jnp.where(even, pltpu.roll(x, x.shape[1] - 1, 1), pltpu.roll(x, 1, 1))
+
+
+def _latent_q(q_ref, table, odd, dtype):
+    """One head of a PAIR's block as ``q_proj`` leaves it (q_ref [block_q,
+    384]: [nope | rope] of the even head, then of the odd one: lane groups
+    [nope_e], [rope_e | nope_o's first half], [nope_o's second | rope_o])
+    as (nope [block_q, 128], rope [block_q, 128]): the head's rope
+    channels in ITS half of the lane group (the even head's the lower),
+    zeros in the other, so that a product with the shared key laid twice
+    side by side is the product with those 64 channels. ``table``: () or
+    the block's (cos, sin) rows of ``_latent_tables``; the turn is
+    float32, its ends the operands' type (``rope_apply_interleaved``'s
+    arithmetic, each result left where its channel was)."""
+    g = [q_ref[:, m * _LANES:(m + 1) * _LANES].astype(jnp.float32)
+         for m in range(3)]
+    low = _iota(g[0].shape, 1) < _HALF
+    rope = jnp.where(low, g[1], g[2])                  # [rope_e | rope_o]
+    if table:
+        cos, sin = (ref[...] for ref in table)
+        rope = rope * cos + _swap_pairs(rope) * sin
+    if odd:
+        nope = jnp.where(low, pltpu.roll(g[1], _HALF, 1),
+                         pltpu.roll(g[2], _HALF, 1))
+    else:
+        nope = g[0]
+    rope = jnp.where(low != odd, rope, 0.0)
+    return nope.astype(dtype), rope.astype(dtype)
+
+
+def _place_latent_q(q_scr, q_ref, table, head):
+    """``_latent_q`` of head ``head`` (a traced scalar: its parity picks
+    the body) into q_scr [block_q, 256]."""
+    for odd in (False, True):
+        @pl.when((head % 2 == 1) == odd)
+        def _(odd=odd):
+            nope, rope = _latent_q(q_ref, table, odd, q_scr.dtype)
+            q_scr[:, :_LANES] = nope
+            q_scr[:, _LANES:] = rope
+
+
+def _latent_key(kv_ref, kpe_ref):
+    """(k [block_kv, 256]: the head's k_nope beside the shared key laid
+    twice, v [block_kv, 128]) of a tile: a 256-deep product with
+    ``_latent_q``'s planes is q_nope . k_nope + q_rope . kpe, both
+    accumulated in float32 inside the one product."""
+    return (jnp.concatenate([kv_ref[:, :_LANES], kpe_ref[...]], axis=1),
+            kv_ref[:, _LANES:])
+
+
+def _latent_fwd_kernel(*refs, scale, block_q, block_kv, turned, keep_lse):
+    """``_fwd_kernel`` of ONE head a grid step whose operands lie as latent
+    attention's projections leave them: q_ref [block_q, 384] (the head's
+    PAIR, ``_latent_q``), kv_ref [block_kv, 256] (the head's [k_nope | v]
+    out of ``kvb``), kpe_ref [block_kv, 128] (the shared key, twice),
+    (``turned``: cos_ref and sin_ref [block_q, 128] float32,) o_ref
+    [block_q, 128], (lse_ref [1, block_q]); scratch: the head's q [block_q,
+    256], then ``_fwd_kernel``'s. Everything after the score is
+    ``_fwd_kernel``'s."""
+    q_ref, kv_ref, kpe_ref, *refs = refs
+    table, refs = (tuple(refs[:2]), refs[2:]) if turned else ((), refs)
+    if keep_lse:
+        o_ref, lse_ref, q_scr, m_scr, l_scr, acc_scr = refs
+    else:
+        o_ref, q_scr, m_scr, l_scr, acc_scr = refs
+    run, edge, off, _, count = _tile_place(block_q, block_kv, None)
+    head, t = pl.program_id(1), pl.program_id(3)
+
+    @pl.when(t == 0)
+    def _():
+        _place_latent_q(q_scr, q_ref, table, head)
+        m_scr[...] = jnp.full_like(m_scr, _NEG)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def tile(masked):
+        k, v = _latent_key(kv_ref, kpe_ref)
+        s = _dot(q_scr[...], k, _NT) * scale
+        if masked:
+            s = jnp.where(_seen(s.shape, 0, off, None), s, _NEG)
+        _online_softmax(s, v, 0, m_scr, l_scr, acc_scr)
+
+    _both_bodies(run, edge, tile)
+
+    @pl.when(t == count - 1)
+    def _():
+        total = l_scr[0]
+        o_ref[...] = (acc_scr[0] / total).astype(o_ref.dtype)
+        if keep_lse:
+            lse_ref[...] = (m_scr[0] + jnp.log(total)).T[:1]
+
+
+def _latent_bwd_kernel(*refs, scale, block_q, block_kv, turned):
+    """``_bwd_kernel`` for the forward above, a PAIR of heads a step of
+    the grid's second axis and the pair's two heads its fourth (sequence,
+    pair, query block, head of the pair, key tile): dq of a pair is one
+    block as q is, so both heads' parts have to be in VMEM when it
+    leaves. The forward's blocks, do_ref and o_ref like its o_ref (the
+    rows' sum(o * do) is made here, as the packed heads' is), lse_ref
+    [1, block_q]; dq_ref like q_ref; dkv_ref [N, 512]: the pair's [dk_nope
+    | dv | dk_nope | dv] as ``kvb`` lies, the whole sequence, float32
+    accumulators [2 N, 128] each until the pair's last tile; dkpe_ref [N,
+    128] FLOAT32: the shared key's cotangent summed over every head of the
+    sequence where it lies (the block stays through the whole grid of a
+    sequence; the even heads' sum in the lower half, the odd heads' in the
+    upper: the caller adds the halves and rounds once). Scratch: the
+    head's q [block_q, 256], the rows' sum(o * do) [1, block_q], dq of
+    both heads [2, block_q, 256] float32, dk and dv."""
+    q_ref, kv_ref, kpe_ref, *refs = refs
+    table, refs = (tuple(refs[:2]), refs[2:]) if turned else ((), refs)
+    (do_ref, o_ref, lse_ref, dq_ref, dkv_ref, dkpe_ref,
+     q_scr, delta_scr, dq_scr, dk_scr, dv_scr) = refs
+    n = dkv_ref.shape[0]
+    run, edge, off, at, count = _tile_place(block_q, block_kv, None, (2, 4))
+    pair, i, j, t = (pl.program_id(a) for a in (1, 2, 3, 4))
+    last = jnp.logical_and(j == 1, t == count - 1)
+
+    @pl.when(jnp.logical_and(jnp.logical_and(pair == 0, i == 0),
+                             jnp.logical_and(j == 0, t == 0)))
+    def _():
+        dkpe_ref[...] = jnp.zeros_like(dkpe_ref)
+
+    @pl.when(jnp.logical_and(i == 0, t == 0))
+    def _():
+        rows = pl.ds(pl.multiple_of(j * n, block_kv), n)
+        dk_scr[rows, :] = jnp.zeros((n, _LANES), jnp.float32)
+        dv_scr[rows, :] = jnp.zeros((n, _LANES), jnp.float32)
+
+    @pl.when(t == 0)
+    def _():
+        _place_latent_q(q_scr, q_ref, table, j)
+        both = o_ref[...].astype(jnp.float32) * do_ref[...].astype(jnp.float32)
+        delta_scr[...] = jnp.broadcast_to(
+            jnp.sum(both, axis=1, keepdims=True), both.shape).T[:1]
+        dq_scr[j] = jnp.zeros(dq_scr.shape[1:], jnp.float32)
+
+    def tile(masked):
+        k, v = _latent_key(kv_ref, kpe_ref)
+        q, do = q_scr[...], do_ref[...]
+        keys = pl.ds(pl.multiple_of(at * block_kv, block_kv), block_kv)
+        held = pl.ds(pl.multiple_of(j * n + at * block_kv, block_kv), block_kv)
+        s = _dot(k, q, _NT) * scale                      # [block_kv, block_q]
+        if masked:
+            s = jnp.where(_seen(s.shape, 1, off, None), s, _NEG)
+        p = jnp.exp(s - lse_ref[...])
+        dv_scr[held, :] += _dot(p.astype(do.dtype), do)
+        ds = p * (_dot(v, do, _NT) - delta_scr[...]) * scale
+        dk = _dot(ds.astype(q.dtype), q)                 # [dk_nope | d kpe, twice]
+        dk_scr[held, :] += dk[:, :_LANES]
+        dkpe_ref[keys, :] += dk[:, _LANES:]
+        dq_scr[j] += _dot(ds.T.astype(k.dtype), k)
+
+    _both_bodies(run, edge, tile)
+
+    @pl.when(last)
+    def _():
+        # ``_latent_q`` undone: each head's rope part is in ITS half of its
+        # plane's upper lane group (the product with the key laid twice
+        # wrote it into both)
+        low = _iota((block_q, _LANES), 1) < _HALF
+        rope = jnp.where(low, dq_scr[0][:, _LANES:], dq_scr[1][:, _LANES:])
+        if table:
+            cos, sin = (ref[...] for ref in table)
+            rope = rope * cos + _swap_pairs(rope * sin)
+        odd = pltpu.roll(dq_scr[1][:, :_LANES], _HALF, 1)
+        for m, x in enumerate((dq_scr[0][:, :_LANES], jnp.where(low, rope, odd),
+                               jnp.where(low, odd, rope))):
+            dq_ref[:, m * _LANES:(m + 1) * _LANES] = x.astype(dq_ref.dtype)
+
+    @pl.when(jnp.logical_and(i == pl.num_programs(2) - 1, last))
+    def _():
+        for m, scr in enumerate((dk_scr, dv_scr, dk_scr, dv_scr)):
+            dkv_ref[:, m * _LANES:(m + 1) * _LANES] = scr[
+                (m // 2) * n:(m // 2 + 1) * n, :].astype(dkv_ref.dtype)
+
+
+def _latent_tables(n: int, theta: float):
+    """(cos, sin) [N, 128] float32 for ``_latent_q``'s lane group of two
+    heads' rope channels, neighbours a pair: pair i of token t turns by
+    t * theta^(-2i / 64); lane 2i holds (cos, -sin), lane 2i + 1 (cos,
+    sin), so that x * cos + (x's neighbours exchanged) * sin is the turn."""
+    from dinov3_tpu.ops.rope import token_rope_pair_sincos
+
+    sin, cos = token_rope_pair_sincos(n, _HALF, theta)
+    return (jnp.tile(jnp.repeat(cos, 2, axis=-1), (1, 2)),
+            jnp.tile(jnp.stack([-sin, sin], axis=-1).reshape(n, _HALF), (1, 2)))
+
+
+def _latent_operands(q, kvb, kpe, theta, block_q, block_kv, pairs):
+    """(operands, their blocks) both latent kernels start with: q, kvb,
+    the shared key laid twice, and with a ``theta`` the two tables.
+    ``pairs``: the grid is the backward's (sequence, pair, query block,
+    head of the pair, key tile), else the forward's (sequence, head, query
+    block, key tile)."""
+    n = q.shape[1]
+
+    def at(index):  # an index map of either grid, written for (s, head, i, t)
+        if pairs:
+            return lambda s, p, i, j, t: index(s, 2 * p + j, i, t)
+        return index
+
+    def tile(i, t):
+        return jnp.minimum(t, _band(i, block_q, block_kv, None)[1] - 1)
+
+    table = () if theta is None else _latent_tables(n, theta)
+    spec = lambda shape, index: pl.BlockSpec(  # noqa: E731
+        shape, at(index), memory_space=pltpu.VMEM)
+    return (q, kvb, jnp.concatenate([kpe, kpe], axis=-1), *table), [
+        spec((None, block_q, 3 * _LANES), lambda s, kh, i, t: (s, i, kh // 2)),
+        spec((None, block_kv, 2 * _LANES), lambda s, kh, i, t: (s, tile(i, t), kh)),
+        spec((None, block_kv, _LANES), lambda s, kh, i, t: (s, tile(i, t), 0)),
+    ] + [spec((block_q, _LANES), lambda s, kh, i, t: (i, 0))] * len(table), spec
+
+
+def _latent_sizes(q, kvb, block_q, block_kv):
+    b, n, _ = q.shape
+    h = kvb.shape[-1] // (2 * _LANES)
+    return b, n, h, (q.shape[-1] // h) ** -0.5, n // block_q, _band(
+        n // block_q - 1, block_q, block_kv, None)[1]
+
+
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "theta", "block_q", "block_kv", "keep_lse", "interpret"))
+def _latent_forward(q, kvb, kpe, theta, block_q, block_kv, keep_lse, interpret):
+    """(o [B, N, h * 128], the rows' log-sum-exp [B, h, 1, N] float32 or
+    None) as one ``pallas_call``."""
+    b, n, h, scale, blocks, steps = _latent_sizes(q, kvb, block_q, block_kv)
+    operands, in_specs, spec = _latent_operands(
+        q, kvb, kpe, theta, block_q, block_kv, pairs=False)
+    out_specs = [spec((None, block_q, _LANES), lambda s, kh, i, t: (s, i, kh))]
+    out_shape = [jax.ShapeDtypeStruct((b, n, h * _LANES), q.dtype)]
+    if keep_lse:
+        out_specs.append(spec((None, None, 1, block_q),
+                              lambda s, kh, i, t: (s, kh, 0, i)))
+        out_shape.append(jax.ShapeDtypeStruct((b, h, 1, n), jnp.float32))
+    row = pltpu.VMEM((1, block_q, _LANES), jnp.float32)
+    out = pl.pallas_call(
+        functools.partial(_latent_fwd_kernel, scale=scale, block_q=block_q,
+                          block_kv=block_kv, turned=theta is not None,
+                          keep_lse=keep_lse),
+        grid=(b, h, blocks, steps),
+        in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((block_q, 2 * _LANES), q.dtype), row, row, row],
+        compiler_params=_COMPILER_PARAMS,
+        interpret=interpret,
+        name=LATENT_KERNEL_NAME,
+    )(*operands)
+    return out[0], out[1] if keep_lse else None
+
+
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "theta", "block_q", "block_kv", "interpret"))
+def _latent_backward(q, kvb, kpe, o, lse, do, theta, block_q, block_kv,
+                     interpret):
+    """The cotangents of q, kvb and kpe, each laid out as its primal, as
+    one ``pallas_call`` from what the forward rule kept."""
+    b, n, h, scale, blocks, steps = _latent_sizes(q, kvb, block_q, block_kv)
+    operands, in_specs, spec = _latent_operands(
+        q, kvb, kpe, theta, block_q, block_kv, pairs=True)
+    head = spec((None, block_q, _LANES), lambda s, kh, i, t: (s, i, kh))
+    # the whole sequence of a pair (of every head: dkpe) stays where it
+    # is until it is done: one buffer, not the pipeline's two
+    whole = lambda w, index: pl.BlockSpec(  # noqa: E731
+        (None, n, w), index, memory_space=pltpu.VMEM,
+        pipeline_mode=pl.Buffered(1))
+    dq, dkvb, dkpe = pl.pallas_call(
+        functools.partial(_latent_bwd_kernel, scale=scale, block_q=block_q,
+                          block_kv=block_kv, turned=theta is not None),
+        grid=(b, h // 2, blocks, 2, steps),
+        in_specs=in_specs + [head, head, spec(
+            (None, None, 1, block_q), lambda s, kh, i, t: (s, kh, 0, i))],
+        out_specs=[
+            pl.BlockSpec((None, block_q, 3 * _LANES),
+                         lambda s, p, i, j, t: (s, i, p), memory_space=pltpu.VMEM),
+            whole(4 * _LANES, lambda s, p, i, j, t: (s, 0, p)),
+            whole(_LANES, lambda s, p, i, j, t: (s, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(kvb.shape, kvb.dtype),
+                   jax.ShapeDtypeStruct((b, n, _LANES), jnp.float32)],
+        scratch_shapes=[
+            pltpu.VMEM((block_q, 2 * _LANES), q.dtype),
+            pltpu.VMEM((1, block_q), jnp.float32),
+            pltpu.VMEM((2, block_q, 2 * _LANES), jnp.float32),
+            pltpu.VMEM((2 * n, _LANES), jnp.float32),
+            pltpu.VMEM((2 * n, _LANES), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            # (dkpe sums over the pairs of a sequence: no axis but the
+            # first may be split)
+            dimension_semantics=("parallel",) + ("arbitrary",) * 4,
+            vmem_limit_bytes=_COMPILER_PARAMS.vmem_limit_bytes),
+        interpret=interpret,
+        name=LATENT_BACKWARD_KERNEL_NAME,
+    )(*operands, do, o, lse)
+    # the even heads' sum and the odd heads', float32: rounded ONCE
+    return dq, dkvb, (dkpe[..., :_HALF] + dkpe[..., _HALF:]).astype(kpe.dtype)
+
+
+def latent_attention(q, kvb, kpe, theta=None, block_q: int = LATENT_BLOCK_Q,
+                     block_kv: int = LATENT_BLOCK_KV, interpret: bool = False):
+    """Causal latent attention on the kernel path, every operand as its
+    projection leaves it: q [B, N, h * 192] (a head's [128 | 64]), kvb
+    [B, N, h * 256] (a head's [k_nope 128 | v 128]) and the ONE shared key
+    kpe [B, N, 64]; o [B, N, h * 128]. A score is (q_nope . k_nope +
+    q_rope . kpe) / sqrt(192). ``theta``: None takes q's rope channels and
+    kpe as they are; a number turns q's rope channels in the kernels,
+    NEIGHBOURING channels a pair, each result left where its channel was
+    (``rope_apply_interleaved`` lays them evens' first: the same sum in
+    another order), and wants kpe turned likewise by the caller
+    (``ops/rope.py rope_apply_pairs``). ``latent_attention_path`` says
+    which shapes it takes."""
+    return _latent_attention(q, kvb, kpe, theta, block_q, block_kv, interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _latent_attention(q, kvb, kpe, theta, block_q, block_kv, interpret):
+    return _latent_forward(q, kvb, kpe, theta=theta, block_q=block_q,
+                           block_kv=block_kv, keep_lse=False,
+                           interpret=interpret)[0]
+
+
+def _latent_attention_fwd(q, kvb, kpe, theta, block_q, block_kv, interpret):
+    o, lse = _latent_forward(q, kvb, kpe, theta=theta, block_q=block_q,
+                             block_kv=block_kv, keep_lse=True,
+                             interpret=interpret)
+    return o, (q, kvb, kpe, o, lse)
+
+
+def _latent_attention_bwd(theta, block_q, block_kv, interpret, res, do):
+    return _latent_backward(*res, do, theta=theta, block_q=block_q,
+                            block_kv=block_kv, interpret=interpret)
+
+
+_latent_attention.defvjp(_latent_attention_fwd, _latent_attention_bwd,
+                        optimize_remat=True)
 
 
 def _index_loss_kernel(*refs, scale, norm, group, index_heads, block_q,
@@ -824,6 +1204,31 @@ def index_loss_tiles(qi, ki, a, selection, q, k, lse, with_grad=False,
                   jnp.swapaxes(dat, 1, 2))
 
 
+def _refuses_types(dtype, reduce_dtype) -> str | None:
+    if dtype not in (jnp.bfloat16, jnp.float32):
+        return f"{jnp.dtype(dtype).name} is neither bfloat16 nor float32"
+    if reduce_dtype != jnp.float32:
+        return (f"statistics in {jnp.dtype(reduce_dtype).name}: the "
+                "kernels' are float32")
+    return None
+
+
+def _refuses_blocks(n: int, block_q: int, block_kv: int) -> str | None:
+    if block_q % _LANES or block_kv % _LANES:
+        return f"blocks of {block_q} x {block_kv} are not multiples of {_LANES}"
+    if n % block_q or n % block_kv:
+        return (f"{n} tokens are not whole blocks of {block_q} queries and "
+                f"{block_kv} keys")
+    return None
+
+
+def _on_backend(interpret: bool | None) -> tuple[str, str]:
+    backend = jax.default_backend()
+    if interpret is None and backend != "tpu":
+        return "tiles", f"the backend is {backend}, not a TPU"
+    return "kernel", "interpreted" if interpret else "compiled for the TPU"
+
+
 def causal_attention_path(shapes, window: int | None = None,
                           interpret: bool | None = None, block_q: int = 512,
                           block_kv: int = 1024, dtype=jnp.bfloat16,
@@ -832,33 +1237,56 @@ def causal_attention_path(shapes, window: int | None = None,
     these three ``shapes`` and this one ``dtype`` on this backend:
     ("kernel", ...) or ("tiles", the reason it is not the kernel)."""
     (_, n, _, d), (_, _, hk, _), (_, _, _, dv) = shapes
-    if dtype not in (jnp.bfloat16, jnp.float32):
-        return "tiles", f"{jnp.dtype(dtype).name} is neither bfloat16 nor float32"
-    if reduce_dtype != jnp.float32:
-        return "tiles", (f"statistics in {jnp.dtype(reduce_dtype).name}: the "
-                         "kernels' are float32")
+    if why := _refuses_types(dtype, reduce_dtype):
+        return "tiles", why
     packed = _packs(d, dv, hk)
-    if dv % _LANES and not packed:
+    if (d % _LANES or dv % _LANES) and not packed:
         return "tiles", (
-            f"the value width {dv} is not a multiple of {_LANES}, nor are the "
+            f"the widths {d} + {dv} are not multiples of {_LANES}, nor are the "
             f"heads {_HALF} + {_HALF} wide on an even number of key/value "
-            f"heads ({d} + {dv} on {hk})")
-    if block_q % _LANES or block_kv % _LANES:
-        return "tiles", (f"blocks of {block_q} x {block_kv} are not "
-                         f"multiples of {_LANES}")
-    if n % block_q or n % block_kv:
-        return "tiles", (f"{n} tokens are not whole blocks of {block_q} "
-                         f"queries and {block_kv} keys")
+            f"heads ({hk})")
+    if why := _refuses_blocks(n, block_q, block_kv):
+        return "tiles", why
     # (heads of 64: a PAIR of key/value heads is resident, 128 + 128)
-    wide, wide_v = (_LANES, _LANES) if packed else (d + (-d) % _LANES, dv)
+    wide, wide_v = (_LANES, _LANES) if packed else (d, dv)
     resident = n * (wide + wide_v) * (4 + 2 * jnp.dtype(dtype).itemsize)
     if resident > _RESIDENT_BYTES:
         return "tiles", (f"dk and dv of {n} tokens ({resident >> 20} MiB) "
                          "do not fit the backward's VMEM")
-    backend = jax.default_backend()
-    if interpret is None and backend != "tpu":
-        return "tiles", f"the backend is {backend}, not a TPU"
-    return "kernel", "interpreted" if interpret else "compiled for the TPU"
+    return _on_backend(interpret)
+
+
+def latent_attention_path(tokens: int, heads: int, widths,
+                          interpret: bool | None = None,
+                          block_q: int = LATENT_BLOCK_Q,
+                          block_kv: int = LATENT_BLOCK_KV, dtype=jnp.bfloat16,
+                          reduce_dtype=jnp.float32) -> tuple[str, str]:
+    """(path, why) a latent-attention mixer takes for ``heads`` heads of
+    ``widths`` (qk_nope, qk_rope, v) over ``tokens`` tokens a sequence, in
+    this one ``dtype`` on this backend: ("kernel", ...) is
+    ``latent_attention``, ("tiles", the reason it is not) the key
+    repeated for every head and ``causal_blockwise_attention``.
+
+    The backward holds a PAIR of heads' dk_nope and dv of the whole
+    sequence in float32 and the block they leave in (one buffer), and the
+    shared key's cotangent in float32: ``tokens * (512 * (4 + itemsize) +
+    512)`` bytes, 56 MiB at 16,384 tokens of bfloat16 under a VMEM limit
+    of 100 (the rest is tiles and planes); 20,480 tokens are refused."""
+    if why := _refuses_types(dtype, reduce_dtype):
+        return "tiles", why
+    if tuple(widths) != (_LANES, _HALF, _LANES) or heads % 2:
+        return "tiles", (
+            f"{heads} heads of {' | '.join(map(str, widths))}: the kernels "
+            f"take an even number of {_LANES} | {_HALF} | {_LANES}")
+    if why := _refuses_blocks(tokens, block_q, block_kv):
+        return "tiles", why
+    resident = tokens * (4 * _LANES * (4 + jnp.dtype(dtype).itemsize)
+                         + 4 * _LANES)
+    if resident > _LATENT_RESIDENT_BYTES:
+        return "tiles", (
+            f"a pair of heads' dk and dv and the shared key's of {tokens} "
+            f"tokens ({resident >> 20} MiB) do not fit the backward's VMEM")
+    return _on_backend(interpret)
 
 
 def index_loss_path(shapes, index_width: int, **how) -> tuple[str, str]:

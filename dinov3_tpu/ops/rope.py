@@ -311,6 +311,23 @@ def rope_apply_interleaved(
     return jnp.concatenate([x[..., :lead], turned], axis=-1)
 
 
+def rope_apply_pairs(
+    x: jnp.ndarray, sin: jnp.ndarray, cos: jnp.ndarray,
+) -> jnp.ndarray:
+    """``rope_apply_interleaved`` of x [B, N, 2 * pairs] (one head, every
+    channel turned) with each result left WHERE ITS CHANNEL WAS: channel
+    2i -> u cos - w sin, 2i + 1 -> u sin + w cos. A permutation of that
+    function's turned slice: the product of two vectors turned by this
+    function is the same sum in another order
+    (``ops/causal_attention.py latent_attention`` turns its queries so)."""
+    compute = jnp.promote_types(x.dtype, sin.dtype)
+    z = x.astype(compute).reshape(x.shape[:-1] + (sin.shape[-1], 2))
+    u, w = z[..., 0], z[..., 1]
+    s, c = sin.astype(compute), cos.astype(compute)
+    return jnp.stack([u * c - w * s, u * s + w * c], axis=-1).reshape(
+        x.shape).astype(x.dtype)
+
+
 def rope_packed_rows(
     global_table: tuple[jnp.ndarray, jnp.ndarray],
     local_table: tuple[jnp.ndarray, jnp.ndarray],

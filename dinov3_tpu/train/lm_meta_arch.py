@@ -31,7 +31,11 @@ import jax.numpy as jnp
 
 from dinov3_tpu.configs import ConfigNode
 from dinov3_tpu.models import build_backbone
-from dinov3_tpu.ops.causal_attention import causal_attention_path, index_loss_path
+from dinov3_tpu.ops.causal_attention import (
+    causal_attention_path,
+    index_loss_path,
+    latent_attention_path,
+)
 from dinov3_tpu.ops.ffn import routed_rows_capacity
 from dinov3_tpu.ops.grouped_matmul import grouped_matmul_path
 from dinov3_tpu.ops.kda import kda_path
@@ -60,10 +64,9 @@ class LMMetaArch:
         # the paths are static a shape: which calls take a kernel IS how
         # often it engages (the trace then names the kernels)
         rows = (int(cfg.train.batch_size_per_device), int(cfg.lm.seq_len))
-        heads, qk = dc.num_attention_heads, dc.qk_nope_head_dim + dc.qk_rope_head_dim
+        heads = dc.num_attention_heads
         gqa = ((heads, dc.head_dim),) + ((dc.num_key_value_heads, dc.head_dim),) * 2
         cores = {  # the scope, the (heads, width) of q, k, v and the window
-            "mla": ("mla_core", ((heads, qk),) * 2 + ((heads, dc.v_head_dim),), None),
             "swa": ("gqa_core", gqa, dc.sliding_window),
             "full_attn": ("gqa_core", gqa, None),
             "gated_attn": ("gqa_core", gqa, None),
@@ -99,6 +102,13 @@ class LMMetaArch:
                                              dc.dtype)
                 logger.info("layer %d sconv_chain (conv), both passes: %s (%s)",
                             i, path, why)
+            elif mixer == "mla":
+                path, why = latent_attention_path(
+                    rows[1], dc.num_attention_heads,
+                    (dc.qk_nope_head_dim, dc.qk_rope_head_dim, dc.v_head_dim),
+                    dtype=dc.dtype, reduce_dtype=dc.reduce_dtype)
+                logger.info("layer %d mla_core (mla), both passes: %s (%s)", i,
+                            path, why)
             else:
                 scope, shapes, window = cores[mixer]
                 path, why = causal_attention_path(

@@ -9,8 +9,8 @@ at both decoder cells' published shapes (under a selection too, and the
 index loss's kernel beside them), and the delta-rule mixers'
 chains (``ops/mixer_chains.py``) at both delta-rule cells', the gated
 short convolution's chain and the causal pair at heads of 64 at the
-``lfm2_moe`` cell's, the causal pair at the ``deepseek_v3`` cell's ONE
-row of 16,384 tokens at 192 | 128 (the backward's residency at its limit),
+``lfm2_moe`` cell's, the latent pair at both latent cells' (and the
+``deepseek_v3`` cell's whole mixer around it: no plane a head),
 and the routed layers' experts' block
 (``ops/grouped_matmul.py``) at the six decoder cells' — each with
 ``interpret=False``, each asserting
@@ -187,16 +187,13 @@ def test_gdn_chunk_kernels_compile_for_v5e(one_chip, states):
 @pytest.mark.parametrize("q, kv, dv, window", [
     ((1, 16384, 28, 128), 4, 128, 4096),
     ((1, 16384, 28, 128), 4, 128, None),
-    ((2, 8192, 32, 192), 32, 128, None),
     ((2, 8192, 16, 256), 2, 256, None),
-    ((1, 16384, 32, 192), 32, 128, None),
-], ids=["window", "global", "mla", "gated", "mla16k"])
+], ids=["window", "global", "gated"])
 def test_causal_attention_kernels_compile_for_v5e(one_chip, q, kv, dv,
                                                   window, direction):
-    """``ops/causal_attention.py`` at the shapes the two decoder cells
+    """``ops/causal_attention.py`` at the shapes the decoder cells
     send ``causal_blockwise_attention``: the 16k cell's window and global
-    grouped-query layers (28 query heads on 4 of 128) and the 8k cell's
-    latent attention (q and k 192 wide, v 128) and the ``qwen3_next``
+    grouped-query layers (28 query heads on 4 of 128) and the ``qwen3_next``
     cell's gated attention (16 query heads on 2 of 256, values 256 wide: 8
     heads a key tile), at the shipped blocks. The
     gradient's program holds the forward rule and ONE backward kernel."""
@@ -227,6 +224,91 @@ def test_causal_attention_kernels_compile_for_v5e(one_chip, q, kv, dv,
     assert text.count("tpu_custom_call") == (1 if direction == "fwd" else 2)
     assert (BACKWARD_KERNEL_NAME in text) == (direction == "bwd")
     assert " while(" not in text
+
+
+def _no_plane_a_head(text: str) -> None:
+    """No array of the ENTRY computation is a [.., 32, 192] or [.., 32,
+    256] plane: q or a key a head written out, padded or laid out anew."""
+    import re
+
+    entry = text[text.index("ENTRY"):]
+    assert not re.findall(r"\w+\[[\d,]*,32,(?:192|256)\]", entry)
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+@pytest.mark.parametrize("rows, tokens, theta", [
+    (2, 8192, None), (1, 16384, 1e6)], ids=["mla", "mla16k"])
+def test_latent_attention_kernels_compile_for_v5e(one_chip, rows, tokens,
+                                                  theta, direction):
+    """The latent pair at both latent cells' shapes (``kimi_linear``'s two
+    rows of 8,192 tokens, the key unturned; ``deepseek_v3``'s ONE row of
+    16,384, q turned in the kernels: a pair of heads' dk and dv resident
+    beside the shared key's), 32 heads of 128 | 64 | 128, every operand as
+    its projection leaves it: the program is the kernels and the shared
+    key laid twice, kvb goes in whole, and nothing a head is written."""
+    import re
+
+    from dinov3_tpu.ops.causal_attention import (
+        KERNEL_NAME,
+        LATENT_BACKWARD_KERNEL_NAME,
+        LATENT_KERNEL_NAME,
+        latent_attention,
+        latent_attention_path,
+    )
+
+    assert latent_attention_path(tokens, 32, (128, 64, 128), False) == (
+        "kernel", "compiled for the TPU")
+    shapes = [((rows, tokens, 32 * w), jnp.bfloat16) for w in (192, 256)] + [
+        ((rows, tokens, 64), jnp.bfloat16)]
+
+    def fwd(q, kvb, kpe):
+        return latent_attention(q, kvb, kpe, theta)
+
+    def bwd(q, kvb, kpe, do):
+        return jax.vjp(fwd, q, kvb, kpe)[1](do)
+
+    text = _compiled_text(*((fwd, one_chip, *shapes) if direction == "fwd" else (
+        bwd, one_chip, *shapes, ((rows, tokens, 32 * 128), jnp.bfloat16))))
+    assert LATENT_KERNEL_NAME in text and KERNEL_NAME not in text
+    assert text.count("tpu_custom_call") == (1 if direction == "fwd" else 2)
+    assert (LATENT_BACKWARD_KERNEL_NAME in text) == (direction == "bwd")
+    assert " while(" not in text
+    _no_plane_a_head(text)
+    # q and kvb are the kernels' operands as the program received them
+    calls = re.findall(r"custom-call\((%[\w.]+), (%[\w.]+),", text)
+    assert calls and all(q.startswith("%q") and kvb.startswith("%kvb")
+                         for q, kvb in calls), calls
+
+
+def test_latent_mixer_program_holds_no_plane_a_head_for_v5e(one_chip):
+    """``MLAMixer`` at the ``deepseek_v3`` cell's shape under a layer's
+    remat, value and gradient (``core_interpret`` False: described as on
+    the chip): between the projections and the kernels no pad, no
+    concatenate and no copy of q or of a key a head — no such array is in
+    the program — and the generic pair is not in it."""
+    import flax.linen as nn
+
+    from dinov3_tpu.models.decoder import MLAMixer
+    from dinov3_tpu.ops.causal_attention import (
+        KERNEL_NAME,
+        LATENT_BACKWARD_KERNEL_NAME,
+        LATENT_KERNEL_NAME,
+    )
+
+    mixer = MLAMixer(32, 512, 128, 64, 128, 1e-6, 1e6, core_interpret=False)
+    x = jax.ShapeDtypeStruct((1, 16384, 2048), jnp.bfloat16, sharding=one_chip)
+    params = jax.tree.map(
+        lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=one_chip),
+        nn.meta.unbox(jax.eval_shape(mixer.init, jax.random.key(0), x)))
+    layer = jax.checkpoint(mixer.apply)
+    text = jax.jit(jax.grad(
+        lambda p, x: jnp.sum(layer(p, x).astype(jnp.float32)),
+        argnums=(0, 1))).lower(params, x).compile().as_text()
+    assert LATENT_KERNEL_NAME in text and KERNEL_NAME not in text
+    assert LATENT_BACKWARD_KERNEL_NAME in text
+    assert text.count("tpu_custom_call") == 2  # the forward rule, the backward
+    _no_plane_a_head(text)
+    assert "mla_rope" in text and "mla_core" in text
 
 
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])

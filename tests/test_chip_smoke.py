@@ -51,8 +51,7 @@ TINY = {
     # lanes of 128 and blocks that are multiples of it: what the kernels
     # (interpreted here) take; a window no block divides
     "gqa_shapes": {"window": (1, 512, 6, 2, 128, 128, 200),
-                   "global": (1, 512, 6, 2, 128, 128, None),
-                   "mla": (2, 256, 2, 2, 192, 128, None)},
+                   "global": (1, 512, 6, 2, 128, 128, None)},
     "gdn_shape": (1, 128, 1, 2, 128),
     "gdn_attn_shapes": {"gated": (1, 512, 4, 2, 256, 256, None)},
     "dsa_shape": (1, 256, 2, 1, 128, 2, 16, 32),
@@ -60,7 +59,10 @@ TINY = {
     "sconv_attn_shapes": {"heads64": (1, 256, 8, 2, 64, 64, None)},
     "moe_shapes": {"tiny": (512, 4, 128, 128, "silu", (0.3,))},
     "mla_shape": (1, 256, 2, 192, 64),
-    "mla_attn_shapes": {"mla16k": (1, 256, 1, 1, 192, 128, None)},
+    "mla_blocks": (128, 256),
+    "mla_latent_shapes": {"mla8k": (2, 256, 2, None, True),
+                          "mla16k": (1, 256, 2, 1e6, False)},
+    "mla_attn_shapes": {"wide16k": (1, 256, 1, 1, 256, 128, None)},
     "gqa_shipped_blocks": (128, 256),
     "gqa_blocks": [(256, 128)],
     "gqa_timeout_s": 600,
@@ -129,7 +131,7 @@ def test_smoke_rehearsal_runs_every_phase(smoke, monkeypatch, capsys):
                    "gqa: window core (1, 512, 6, 2, 128, 128) window 200: the "
                    "entry point takes the kernel (interpreted)",
                    "gqa: global core, tiles: first calls",
-                   "gqa: mla core, kernel at blocks 256 x 128",
+                   "gqa: global core, kernel at blocks 256 x 128",
                    "gdn: delta rule (1, 128, 1, 2, 128) at a scalar gate: the "
                    "entry point takes the kernel (scalar gate, interpreted)",
                    "gdn: delta rule: norm of the difference over the norm",
@@ -156,7 +158,15 @@ def test_smoke_rehearsal_runs_every_phase(smoke, monkeypatch, capsys):
                    "mla: turn (1, 256, 2, 192) + (1, 256, 1, 64): first calls",
                    "mla: turn: norm of the difference over the norm, to the "
                    "complex multiplication",
-                   "gqa: mla16k core (1, 256, 1, 1, 192, 128) window None: the "
+                   "mla: mla8k latent core (2, 256, 2, 128, 64, 128) theta None: "
+                   "the mixer takes the kernel (interpreted)",
+                   "mla: mla8k latent core, tiles: first calls",
+                   "mla: mla16k latent core (1, 256, 2, 128, 64, 128) theta "
+                   "1000000.0: the mixer takes the kernel (interpreted)",
+                   "mla: mla16k latent core: norm of the difference over the "
+                   "norm, output and gradients q kvb kpe: kernel to the dense "
+                   "masked softmax",
+                   "gqa: wide16k core (1, 256, 1, 1, 256, 128) window None: the "
                    "entry point takes the kernel (interpreted)",
                    "all phases passed"):
         assert needle in said, needle

@@ -7,8 +7,11 @@
 (b) ``MLAMixer``'s rotary arm against the reference's ``attention``, value
     and every leaf's gradient (the two controls of the turn differ); its
     ``rope_theta`` None arm bit-equal to the lines the parent had.
-(c) The causal kernel pair, interpreted, at 192 | 128 against the plain
-    tiles, both passes.
+(c) ``MLAMixer`` at 128 | 64 | 128 on the latent kernel pair,
+    interpreted (``core_interpret``: the test's switch), against its plain
+    arm, value and every leaf's gradient, turned and unturned; the turn
+    that leaves each result where its channel was is a permutation of
+    the interleaved one.
 (d) The routed layer's rule: the bias moves the choice and not the
     weight; the 1e-20; the 2.448. Two shared experts are one MLP of twice
     the width.
@@ -219,38 +222,62 @@ def test_mla_mixer_turns_as_the_reference_and_not_at_all_without_theta():
     assert _rel(got, y) > 0.05
 
 
-# ---------------- (c) 192 | 128 on the kernel pair ----------------
+# ---------------- (c) 128 | 64 | 128 on the latent kernel pair ----------------
 
-def test_interpreted_pair_at_192_and_128_is_the_plain_tiles():
-    """2 heads of 192 | 128 (q and k padded to 256 lanes in HBM), float32,
-    blocks of 128: output, and the gradient of q, k and v."""
-    from dinov3_tpu.ops.attention import causal_tiles
-    from dinov3_tpu.ops.causal_attention import (
-        causal_attention_path,
-        kernel_attention,
-    )
+@pytest.mark.parametrize("theta", [None, 1e6], ids=["unturned", "turned"])
+def test_mixer_on_the_interpreted_latent_pair_is_its_plain_arm(theta):
+    """2 heads of 128 | 64 | 128 over 2 rows of 2,048 tokens (the shipped
+    blocks: two query blocks, two key tiles), float32: the kernel arm
+    reads q, kvb and the shared key as the projections leave them (no key
+    a head, nothing padded) under the SAME parameter tree."""
+    import flax.linen as nn
 
-    b, n, h, d, dv = 1, 256, 2, 192, 128
-    ks = jax.random.split(jax.random.key(7), 4)
-    q, k = (jax.random.normal(key, (b, n, h, d)) for key in ks[:2])
-    v, do = (jax.random.normal(key, (b, n, h, dv)) for key in ks[2:])
-    assert causal_attention_path((q.shape, k.shape, v.shape), None, True, 128,
-                                 128, jnp.float32)[0] == "kernel"
+    from dinov3_tpu.models.decoder import MLAMixer
+    from dinov3_tpu.ops.causal_attention import latent_attention_path
 
-    def both(fn):
-        def run(q, k, v):
-            o, vjp = jax.vjp(fn, q, k, v)
-            return (o, *vjp(do))
-        return jax.jit(run)(q, k, v)
+    kw = dict(num_heads=2, kv_lora_rank=32, qk_nope_head_dim=128,
+              qk_rope_head_dim=64, v_head_dim=128, eps=1e-6, rope_theta=theta,
+              dtype=jnp.float32)
+    plain, pair = MLAMixer(**kw), MLAMixer(**kw, core_interpret=True)
+    assert latent_attention_path(2048, 2, (128, 64, 128), None,
+                                 dtype=jnp.float32)[0] == "tiles"
+    assert latent_attention_path(2048, 2, (128, 64, 128), True,
+                                 dtype=jnp.float32) == ("kernel", "interpreted")
+    ks = jax.random.split(jax.random.key(5), 3)
+    x = jax.random.normal(ks[0], (2, 2048, 64))
+    params = spread(nn.meta.unbox(jax.jit(plain.init)(ks[1], x)["params"]), ks[2])
+    assert jax.tree.map(jnp.shape, params) == jax.tree.map(
+        jnp.shape, nn.meta.unbox(jax.eval_shape(pair.init, ks[1], x)["params"]))
+
+    def both(mixer):
+        fn = lambda p, x: mixer.apply({"params": p}, x)  # noqa: E731
+        return jax.jit(lambda p, x: (fn(p, x), jax.grad(
+            lambda p, x: jnp.sum(jnp.sin(fn(p, x))), argnums=(0, 1))(p, x)))(
+                params, x)
 
     with jax.default_matmul_precision("highest"):
-        got = both(lambda q, k, v: kernel_attention(
-            q, k, v, d ** -0.5, None, 128, 128, True))
-        want = both(lambda q, k, v: causal_tiles(
-            q, k, v, 128, 128, jnp.float32, None))
-    for name, a, w in zip(("o", "dq", "dk", "dv"), got, want):
-        assert a.shape == w.shape
-        assert _rel(a, w) < 2e-5, (name, _rel(a, w))
+        (y, (gp, gx)), (want, (wp, wx)) = both(pair), both(plain)
+    assert set(wp) == {"q_proj", "kv_a", "kv_a_norm", "kv_b", "o_proj"}
+    gaps = {"y": _rel(y, want), "dx": _rel(gx, wx),
+            **{k: jax.tree.leaves(_rel(gp[k], wp[k]))[0] for k in wp}}
+    assert max(gaps.values()) < 2e-5, gaps
+
+
+def test_turn_in_place_is_a_permutation_of_the_interleaved_turn():
+    from dinov3_tpu.ops.rope import (
+        rope_apply_interleaved,
+        rope_apply_pairs,
+        token_rope_pair_sincos,
+    )
+
+    x = jax.random.normal(jax.random.key(2), (2, 50, 64))
+    table = token_rope_pair_sincos(50, 64, 1e6)
+    there = rope_apply_interleaved(x[:, :, None, :], *table)[:, :, 0]
+    here = rope_apply_pairs(x, *table)
+    np.testing.assert_array_equal(here[..., 0::2], there[..., :32])
+    np.testing.assert_array_equal(here[..., 1::2], there[..., 32:])
+    low = rope_apply_pairs(x.astype(jnp.bfloat16), *table)
+    assert low.dtype == jnp.bfloat16
 
 
 # ---------------- (d) the routed layer's rule, the shared experts ----------------
@@ -561,27 +588,38 @@ def test_config_rules():
 
 
 def test_the_paths_are_read_off_shapes_at_the_published_sizes(caplog):
-    """``causal_attention_path`` and ``grouped_matmul_path`` at the cell's
+    """``latent_attention_path`` and ``grouped_matmul_path`` at the cells'
     shapes: on a TPU (``interpret=False``: described, not attached) the
-    latent core of ONE row of 16,384 tokens at 32 heads of 192 | 128 takes
-    the kernel pair — its backward's residency is the limit to the byte —
-    and the routed layers their kernels; here, on the CPU, the plain paths,
-    and the set-up log says which, a line a layer."""
+    latent core of ONE row of 16,384 tokens at 32 heads of 128 | 64 | 128
+    (this recipe's) and of two rows of 8,192 (``kimi_linear``'s) takes the
+    latent kernel pair — a key a head at 192 would take the plain tiles:
+    the pad arm is gone — and the routed layers their kernels; here, on
+    the CPU, the plain paths, and the set-up log says which, a line a
+    layer, for both latent recipes."""
     import logging
 
     from dinov3_tpu.ops import causal_attention
-    from dinov3_tpu.ops.causal_attention import causal_attention_path
+    from dinov3_tpu.ops.causal_attention import (
+        causal_attention_path,
+        latent_attention_path,
+    )
     from dinov3_tpu.ops.ffn import routed_rows_capacity
     from dinov3_tpu.ops.grouped_matmul import grouped_matmul_path
     from dinov3_tpu.train.lm_meta_arch import LMMetaArch
 
-    at = lambda n: ((1, n, 32, 192), (1, n, 32, 192), (1, n, 32, 128))  # noqa: E731
-    assert causal_attention_path(at(16384), None, False) == (
-        "kernel", "compiled for the TPU")
-    assert 16384 * (256 + 128) * 8 == causal_attention._RESIDENT_BYTES
-    path, why = causal_attention_path(at(32768), None, False)
+    widths = (128, 64, 128)
+    for n in (16384, 8192):
+        assert latent_attention_path(n, 32, widths, False) == (
+            "kernel", "compiled for the TPU")
+        assert latent_attention_path(n, 32, widths)[0] == "tiles"
+    # a pair's dk_nope and dv, float32 and the bfloat16 block they leave
+    # in, and the shared key's float32: 56 MiB of the 64 the path allows
+    assert 16384 * (512 * 6 + 512) == 56 << 20
+    assert causal_attention._LATENT_RESIDENT_BYTES == 64 << 20
+    path, why = latent_attention_path(32768, 32, widths, False)
     assert path == "tiles" and "do not fit the backward's VMEM" in why
-    assert causal_attention_path(at(16384))[0] == "tiles"
+    a_key_a_head = ((1, 16384, 32, 192),) * 2 + ((1, 16384, 32, 128),)
+    assert causal_attention_path(a_key_a_head, None, False)[0] == "tiles"
     # (the recipe's lm.expert_rows_factor: 4.0 even shares of 12,288 rows)
     cap = routed_rows_capacity(16384, 6, 128, 16,
                                load_config(RECIPE).lm.expert_rows_factor)
@@ -590,6 +628,14 @@ def test_the_paths_are_read_off_shapes_at_the_published_sizes(caplog):
     with caplog.at_level(logging.INFO, logger="dinov3"):
         LMMetaArch(load_config(RECIPE))
     said = [r.getMessage() for r in caplog.records]
-    assert sum("mla_core (mla), both passes: tiles" in s for s in said) == 5
+    assert sum("mla_core (mla), both passes: tiles (the backend is cpu, not a "
+               "TPU)" in s for s in said) == 5
     assert sum("moe_experts, both passes: ragged_dot (the backend is cpu, not a "
                "TPU)" in s for s in said) == 4  # a line a routed layer
+    caplog.clear()
+    with caplog.at_level(logging.INFO, logger="dinov3"):
+        LMMetaArch(load_config(os.path.join(
+            REPO, "configs", "train", "kimi_linear_ep32.yaml")))
+    said = [r.getMessage() for r in caplog.records]
+    assert sum("mla_core (mla), both passes: tiles (the backend is cpu, not a "
+               "TPU)" in s for s in said) == 1
