@@ -119,16 +119,19 @@ SIZES = {
     "mla_latent_shapes": {"mla8k": (2, 8192, 32, None, True),
                           "mla16k": (1, 16384, 32, 1e6, False)},
     "mla_attn_shapes": {"wide16k": (1, 16384, 32, 32, 256, 128, None)},
-    # the routed layers' held experts' block at the five decoder cells'
-    # published shapes (--phases moe): [buffer rows, held experts, D, H,
-    # gate, the shares of the buffer routed rows fill] — the cells' measured
-    # fills, and a quarter and the whole of the 16k cell's buffer
+    # the routed layers' row movement and held experts' block at the six
+    # decoder cells' published shapes (--phases moe; moe_rows: the row
+    # movement alone): [tokens, choices a token, buffer rows, held experts,
+    # D, H, gate, the shares of the buffer routed rows fill] — the cells'
+    # measured fills, and a quarter and the whole of the 16k cell's buffer
     "moe_shapes": {
-        "smallthinker": (49152, 16, 2560, 768, "relu", (0.25, 0.55, 1.0)),
-        "lfm2": (32768, 8, 2048, 1536, "silu", (0.8,)),
-        "qwen3_next": (40960, 32, 2048, 512, "silu", (0.26,)),
-        "keye_vl2": (32768, 16, 2048, 768, "silu", (0.5,)),
-        "kimi_linear": (8192, 8, 2304, 1024, "silu", (0.5,))},
+        "smallthinker": (16384, 6, 49152, 16, 2560, 768, "relu",
+                         (0.25, 0.55, 1.0)),
+        "lfm2": (32768, 4, 32768, 8, 2048, 1536, "silu", (0.8,)),
+        "qwen3_next": (16384, 10, 40960, 32, 2048, 512, "silu", (0.26,)),
+        "keye_vl2": (16384, 8, 32768, 16, 2048, 768, "silu", (0.5,)),
+        "kimi_linear": (16384, 8, 8192, 8, 2304, 1024, "silu", (0.5,)),
+        "kanana2": (16384, 6, 49152, 16, 2048, 768, "silu", (0.44,))},
     "gqa_shipped_blocks": (512, 1024),
     "gqa_blocks": [(512, 512), (1024, 1024), (256, 1024)],
     "gqa_timeout_s": 1200,
@@ -141,6 +144,8 @@ SIZES = {
 
 ONE_CHIP_PHASES = ("trainer", "accum", "kernels", "serve", "lm", "gqa", "gdn",
                    "dsa", "sconv", "moe", "mla")
+# asked for by name only: a part of a phase above, alone
+PART_PHASES = ("moe_rows",)
 
 _T0 = time.time()
 
@@ -894,15 +899,114 @@ def phase_mla() -> None:
     phase_gqa("mla_attn_shapes", with_tiles=False)
 
 
-def phase_moe() -> None:
-    """The held experts' block of ``ops/ffn.py RoutedExpertsFFN``
-    stand-alone (``ops/grouped_matmul.py``) at the five decoder cells'
-    published shapes and measured fills: the kernels against the
-    ``lax.ragged_dot`` form, forward and forward + backward timed on
-    both, the two ``ragged_dot`` calls of the forward timed alone (do they
-    cost by the buffer or by the rows?), output and the four gradients
-    compared, and the rows past the last group read back as exact zeros
-    out of a buffer that holds 1e6 there."""
+def _moe_lists(n, k, cap, sizes, form):
+    """``ops/routed_rows.py``'s lists as a layer's routing would make them:
+    ``sum(sizes)`` of the ``n * k`` (token, choice) pairs at random, dealt
+    to the held experts by ``sizes`` and kept in pair order inside an
+    expert's group, then the pairs routed elsewhere in pair order."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dinov3_tpu.ops.routed_rows import row_lists
+
+    rng = np.random.default_rng(11)
+    n_here = int(np.sum(sizes))
+    here = rng.permutation(n * k)[:n_here]
+    groups = np.split(here, np.cumsum(sizes)[:-1])
+    away = np.setdiff1d(np.arange(n * k), here)
+    order = np.concatenate([np.sort(g) for g in groups] + [away])[:cap]
+    kept = jnp.arange(cap) < n_here
+    return row_lists(jnp.asarray(order, jnp.int32), kept, n, k, form), kept
+
+
+def _moe_rows(name, n, k, cap, d, fill, sizes) -> None:
+    """One routed layer's row movement ALONE: dispatch, combine and the
+    transpose of each, in each form of ``ops/routed_rows.py`` and as the
+    parent of PR 47 wrote them (a fill-mode ``take`` under a row mask, a
+    float32 ``.at[].add``, JAX's own transposition), ms a call and the
+    GB/s of the rows each must at least read and write; the forms'
+    results compared with the parent's."""
+    import jax
+    import jax.numpy as jnp
+
+    from dinov3_tpu.ops import routed_rows as rr
+
+    ks = jax.random.split(jax.random.key(8), 4)
+    x = jax.random.normal(ks[0], (n, d), jnp.bfloat16)
+    dy = jax.random.normal(ks[1], (n, d), jnp.bfloat16)
+    plain, kept = _moe_lists(n, k, cap, sizes, "scatter")
+    # what the experts' block leaves past the last group: zeros
+    out = jnp.where(kept[:, None], jax.random.normal(ks[2], (cap, d)), 0.0)
+    d_rows = jnp.where(kept[:, None], jax.random.normal(
+        ks[3], (cap, d)), 0.0).astype(jnp.bfloat16)
+    token = plain.token
+
+    def parent_dispatch(x):
+        return jnp.where(kept[:, None], jnp.take(x, token, axis=0), 0)
+
+    def parent_combine(out):
+        return jnp.zeros((n, d), jnp.float32).at[token].add(out).astype(
+            jnp.bfloat16)
+
+    interpret = bool(SIZES["kernel_interpret"])
+    forms = {"parent": (parent_dispatch, parent_combine)}
+    for form in ("sorted", "scatter"):
+        lists, _ = _moe_lists(n, k, cap, sizes, form)
+        forms[form] = (
+            functools.partial(rr.dispatch_rows, lists=lists,
+                              interpret=interpret),
+            functools.partial(rr.combine_rows, lists=lists, n_tokens=n,
+                              dtype=jnp.bfloat16, interpret=interpret))
+    pick = rr.combine_form(n, cap, d, interpret or None)
+    log(f"moe_rows: {name} N {n} K {k} cap {cap} D {d} fill {fill}: "
+        f"{n * k / cap:.1f} pairs a buffer row, the layer's combine takes "
+        f"the {pick}")
+    gb = {"dispatch": 2 * cap * d * 2, "combine": cap * d * 4 + n * d * 2,
+          "combine^T": 2 * cap * d * 2, "dispatch^T": cap * d * 2 + n * d * 2}
+    found = {}
+    for form, (dispatch, combine) in forms.items():
+        t = lambda f: jax.jit(  # noqa: E731
+            lambda a, ct: jax.vjp(f, a)[1](ct)[0])
+        calls = {"dispatch": (jax.jit(dispatch), (x,)),
+                 "combine": (jax.jit(combine), (out,)),
+                 "combine^T": (t(combine), (out, dy)),
+                 "dispatch^T": (t(dispatch), (x, d_rows))}
+        said, total = [], 0.0
+        for what, (fn, args) in calls.items():
+            first, ms, found[form, what] = _timed(fn, args, n=5)
+            total += ms
+            said.append(f"{what} {ms:.3f} ms ({gb[what] / ms / 1e6:.0f} GB/s, "
+                        f"first {first:.1f}s)")
+        log(f"moe_rows: {name} fill {fill}, {form}: " + ", ".join(said)
+            + f"; all four {total:.3f} ms")
+    f32 = lambda a: jnp.asarray(a, jnp.float32)  # noqa: E731
+    for (form, what), got in found.items():
+        want = found["parent", what]
+        if what == "combine^T":  # the parent's is float32 of the same rows
+            assert got.dtype == jnp.float32, (form, got.dtype)
+        # (the rows past the last group: the parent masks them, the forms
+        # leave them to the block)
+        if what == "dispatch":
+            got = jnp.where(kept[:, None], got, 0)
+        if what == "combine^T":
+            got, want = (jnp.where(kept[:, None], a, 0) for a in (got, want))
+        gap = float(jnp.linalg.norm(f32(got) - f32(want))
+                    / jnp.linalg.norm(f32(want)))
+        assert gap <= 1e-2, (name, form, what, gap)
+    log(f"moe_rows: {name} fill {fill}: every form within 1e-2 of the "
+        "parent's by norm, all four")
+
+
+def phase_moe(block: bool = True) -> None:
+    """The routed layers of ``ops/ffn.py RoutedExpertsFFN`` stand-alone at
+    the six decoder cells' published shapes and measured fills, a shape at
+    a time: its row movement (``_moe_rows``; ``--phases moe_rows`` stops
+    there), then its held experts' block (``ops/grouped_matmul.py``): the kernels
+    against the ``lax.ragged_dot`` form, forward and forward + backward
+    timed on both, the two ``ragged_dot`` calls of the forward timed alone
+    (do they cost by the buffer or by the rows?), output and the four
+    gradients compared, and the rows past the last group read back as
+    exact zeros out of a buffer that holds 1e6 there."""
     import faulthandler
 
     import jax
@@ -914,7 +1018,19 @@ def phase_moe() -> None:
     interpret = bool(SIZES["kernel_interpret"])
     faulthandler.dump_traceback_later(
         float(SIZES["gqa_timeout_s"]), exit=True, file=sys.__stderr__)
-    for name, (cap, held, d, hid, gate, fills) in SIZES["moe_shapes"].items():
+
+    def group_sizes(held, fill, cap):
+        share = np.random.default_rng(7).uniform(0.8, 1.2, held)
+        sizes = np.floor(share / share.sum() * fill * cap).astype(np.int32)
+        sizes[0] += int(fill * cap) - sizes.sum()
+        return sizes
+
+    for name, (n, k, cap, held, d, hid, gate, fills) in SIZES[
+            "moe_shapes"].items():
+        fill = fills[min(1, len(fills) - 1)]  # the measured fill
+        _moe_rows(name, n, k, cap, d, fill, group_sizes(held, fill, cap))
+        if not block:
+            continue
         path, why = gm.grouped_matmul_path(cap, d, hid, jnp.bfloat16,
                                            interpret=interpret or None)
         tm = gm.row_tile(cap)
@@ -926,9 +1042,7 @@ def phase_moe() -> None:
         w3 = jax.random.normal(ks[1], (held, hid, d)) * hid ** -0.5
         ct = jax.random.normal(ks[2], (cap, d))
         for fill in fills:
-            share = np.random.default_rng(7).uniform(0.8, 1.2, held)
-            sizes = np.floor(share / share.sum() * fill * cap).astype(np.int32)
-            sizes[0] += int(fill * cap) - sizes.sum()
+            sizes = group_sizes(held, fill, cap)
             kept = jnp.arange(cap) < int(sizes.sum())
             rows = jnp.where(kept[:, None], jax.random.normal(
                 ks[3], (cap, d)), 1e6).astype(jnp.bfloat16)
@@ -958,7 +1072,7 @@ def phase_moe() -> None:
                 log(f"moe: {name} fill {fill}, {which}: first call "
                     f"{first:.1f}s, forward {fwd:.2f} ms, forward + backward "
                     f"{ms:.2f} ms")
-            if fill == fills[min(1, len(fills) - 1)]:  # the measured fill
+            if fill == fills[min(1, len(fills) - 1)]:
                 for other in (t for t in (128, 256, 512)
                               if t != tm and cap % t == 0):
                     _, ms, _ = _timed(both_passes(functools.partial(
@@ -1398,7 +1512,7 @@ def main(argv=None) -> int:
                          f"(default all: {','.join(ONE_CHIP_PHASES)})")
     args = ap.parse_args(argv)
     phases = [p for p in args.phases.split(",") if p]
-    unknown = sorted(set(phases) - set(ONE_CHIP_PHASES))
+    unknown = sorted(set(phases) - set(ONE_CHIP_PHASES + PART_PHASES))
     if unknown:
         raise SystemExit(f"chip_smoke: unknown phases {unknown}")
 
@@ -1428,6 +1542,7 @@ def main(argv=None) -> int:
                "kernels": phase_kernels, "serve": phase_serve,
                "lm": phase_lm, "gqa": phase_gqa, "gdn": phase_gdn,
                "dsa": phase_dsa, "sconv": phase_sconv, "moe": phase_moe,
+               "moe_rows": functools.partial(phase_moe, block=False),
                "mla": phase_mla}
         for name in phases:
             run[name]()

@@ -136,8 +136,10 @@ attention call), ``swa_mixer`` and ``full_attn_mixer``
 ``dsa_index``: the indexer's projections and score planes, ``dsa_select``:
 the thresholds, ``dsa_core``, ``dsa_index_loss``), ``sconv_mixer`` (inner ``sconv_chain``: the kernel
 pair or the plain chain, nothing else), ``dense_ffn``, ``moe_ffn`` (inner
-``moe_route``, ``moe_experts`` from the routed layer, ``moe_shared``: the
-shared expert, with its gate where it has one), ``lm_head_loss``.
+``moe_route``, ``moe_experts`` from the routed layer — inside it
+``moe_rows``, the dispatch and combine of ``ops/routed_rows.py`` —
+``moe_shared``: the shared expert, with its gate where it has one),
+``lm_head_loss``.
 """
 
 from __future__ import annotations

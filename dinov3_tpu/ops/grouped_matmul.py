@@ -42,11 +42,12 @@ a layer's rematerialisation traces nothing new.
 Between the two products, and between the two passes, crosses ONE
 bfloat16 ``[rows, 2 H]`` plane, [g ; u] (beside ``rows``, the block's
 input); both passes make act(g) * u of it in VMEM. No float32
-``[rows, 2 H]`` plane exists anywhere, and one float32 ``[rows, D]`` a
-pass: the combine's operand. The cotangent of ``out`` is rounded to
-bfloat16 where it enters a product (as XLA's default precision does on a
-TPU), every accumulation is float32, the weights' gradients leave as
-float32.
+``[rows, 2 H]`` plane exists anywhere, and one float32 ``[rows, D]``:
+the forward combine's operand. The cotangent of ``out`` is rounded to
+bfloat16 where it enters the backward (as XLA's default precision does
+with a product's operand on a TPU; the layer's arrives in bfloat16, so
+nothing is lost and no float32 plane is read), every accumulation is
+float32, the weights' gradients leave as float32.
 
 ``grouped_matmul_path`` reads the path and the tiles off the shapes;
 ``ragged_experts_block`` is the ``lax.ragged_dot`` form: the fall-back,
@@ -84,9 +85,9 @@ def ragged_experts_block(rows, w12, w3, w_rows, sizes, kept, gate: str):
     on a v5e whatever the buffer held — in the BACKWARD pass too, where
     the gradient with respect to those rows went into the tokens'
     gradient and was a million times the true one (my chip runs, PR 27).
-    Both ends are masked by ``kept``, values and gradients alike (the
-    caller masks ``rows``)."""
+    Both ends are masked by ``kept``, values and gradients alike."""
     dtype = rows.dtype
+    rows = jnp.where(kept[:, None], rows, 0)
     h = jax.lax.ragged_dot(
         rows, w12.astype(dtype), sizes,
         preferred_element_type=jnp.float32).astype(dtype)
@@ -329,7 +330,7 @@ def _dw12_body(rows, dh, *, mine):
 def _dw3_body(h, w_rows, ct, *, mine, gate):
     act, _, u = _gate_parts(h, gate)
     return (jnp.where(mine, act * u, 0.0),
-            (ct[...] * w_rows[...]).astype(h.dtype))
+            (ct[...].astype(jnp.float32) * w_rows[...]).astype(h.dtype))
 
 
 # ------------------------------------------------------------ the block
@@ -357,7 +358,11 @@ def _backward(rows, h, w12, w3, w_rows, meta, ct, gate, tm, interpret):
     rule kept and ``ct``, the cotangent of ``out``."""
     d = rows.shape[1]
     groups, hid = w3.shape[:2]
-    ct, w_col = ct.astype(jnp.float32), w_rows[:, None]
+    # rounded HERE, where it enters the products, and not widened: the
+    # layer's combine hands on a bfloat16 cotangent behind the widening
+    # its rule's signature needs, and XLA folds the pair away, so the
+    # kernels read a plane of half the bytes (``ops/routed_rows.py``)
+    ct, w_col = ct.astype(rows.dtype), w_rows[:, None]
     dh, d_w, d_rows = _walk(
         functools.partial(_back_body, gate=gate), EXPERTS_BACK, meta, tm,
         [ct, w_col, h], [w3.astype(rows.dtype), w12.astype(rows.dtype)],

@@ -40,6 +40,7 @@ from dinov3_tpu.ops.ffn import routed_rows_capacity
 from dinov3_tpu.ops.grouped_matmul import grouped_matmul_path
 from dinov3_tpu.ops.kda import kda_path
 from dinov3_tpu.ops.mixer_chains import mixer_chain_path
+from dinov3_tpu.ops.routed_rows import combine_form
 
 logger = logging.getLogger("dinov3")
 
@@ -81,15 +82,20 @@ class LMMetaArch:
             "gdn": ("gdn_core", dc.linear_key_head_dim, dc.linear_value_head_dim,
                     gdn_heads, gdn_heads)}
         held = dc.num_experts // dc.expert_shards
+        tokens = rows[0] * rows[1]
         cap = routed_rows_capacity(
-            rows[0] * rows[1], dc.num_experts_per_token, dc.num_experts, held,
+            tokens, dc.num_experts_per_token, dc.num_experts, held,
             dc.expert_rows_factor)
         for i, (mixer, ffn) in enumerate(dc.layers, 1):
             if ffn != "dense":
                 path, why = grouped_matmul_path(
                     cap, dc.hidden_size, dc.moe_intermediate_size, dc.dtype)
-                logger.info("layer %d moe_experts, both passes: %s (%s)", i,
-                            path, why)
+                logger.info(
+                    "layer %d moe_experts, both passes: %s (%s); rows moved "
+                    "by gathers through index lists, the combine %s at %.1f "
+                    "(token, choice) pairs a buffer row", i, path, why,
+                    combine_form(tokens, cap, dc.hidden_size),
+                    tokens * dc.num_experts_per_token / cap)
             if mixer in delta:
                 scope, dk, dv, heads, gate_heads = delta[mixer]
                 path, why = kda_path(dk, dv, gate_heads=gate_heads)

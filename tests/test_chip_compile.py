@@ -585,3 +585,42 @@ def test_experts_block_compiles_for_v5e(one_chip, cell, cap, held, d, h, gate):
     compiled = jax.jit(both).lower(*args).compile()
     assert compiled.as_text().count("tpu_custom_call") == 5
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * cap * d * 4
+
+
+@pytest.mark.parametrize("cell, n, k, cap, d", [
+    ("smallthinker-ep4-pretrain-16k", 16384, 6, 49152, 2560),
+    ("qwen3-next-ep16-pretrain-8k", 16384, 10, 40960, 2048),
+    ("kimi-linear-ep32-pretrain-8k", 16384, 8, 8192, 2304),
+])
+def test_row_movement_compiles_for_v5e(one_chip, cell, n, k, cap, d):
+    """Dispatch, combine and the transpose of each (``ops/routed_rows.py``)
+    as a TPU runs them, from abstract lists: the token-sorted sum is one
+    kernel a pass, and the one float32 plane of the buffer's height that
+    is made is the forward combine's operand in token order."""
+    from dinov3_tpu.ops import routed_rows as rr
+
+    form = rr.combine_form(n, cap, d, interpret=False)
+    assert form == "sorted"
+    shapes = jax.eval_shape(
+        lambda o, kept: rr.row_lists(o, kept, n, k, form),
+        jax.ShapeDtypeStruct((cap,), jnp.int32),
+        jax.ShapeDtypeStruct((cap,), jnp.bool_))
+    lists = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), shapes)
+
+    def both(lists, x, src, dy, d_rows):
+        rows, db = jax.vjp(lambda x: rr.dispatch_rows(x, lists, False), x)
+        y, cb = jax.vjp(lambda s: rr.combine_rows(
+            s, lists, n, jnp.bfloat16, False), src)
+        # the experts' backward rounds the cotangent it is handed
+        return rows, y, db(d_rows), cb(dy)[0].astype(jnp.bfloat16)
+
+    args = [jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in (
+        ((n, d), jnp.bfloat16), ((cap, d), jnp.float32),
+        ((n, d), jnp.bfloat16), ((cap, d), jnp.bfloat16))]
+    text = jax.jit(both).lower(lists, *args).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    entry = text[text.index("ENTRY"):]
+    made = [ln for ln in entry.splitlines()
+            if f" f32[{cap},{d}]" in ln.split("(")[0] and "parameter" not in ln]
+    assert len(made) == 1, made

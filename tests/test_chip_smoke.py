@@ -57,7 +57,7 @@ TINY = {
     "dsa_shape": (1, 256, 2, 1, 128, 2, 16, 32),
     "sconv_shape": (1, 256, 128),
     "sconv_attn_shapes": {"heads64": (1, 256, 8, 2, 64, 64, None)},
-    "moe_shapes": {"tiny": (512, 4, 128, 128, "silu", (0.3,))},
+    "moe_shapes": {"tiny": (256, 4, 512, 4, 128, 128, "silu", (0.3,))},
     "mla_shape": (1, 256, 2, 192, 64),
     "mla_blocks": (128, 256),
     "mla_latent_shapes": {"mla8k": (2, 256, 2, None, True),
@@ -151,6 +151,11 @@ def test_smoke_rehearsal_runs_every_phase(smoke, monkeypatch, capsys):
                    "sconv: chain: norm of the difference over the norm",
                    "gqa: heads64 core (1, 256, 8, 2, 64, 64) window None: the "
                    "entry point takes the kernel (interpreted)",
+                   "moe_rows: tiny N 256 K 4 cap 512 D 128 fill 0.3: 2.0 pairs a "
+                   "buffer row, the layer's combine takes the sorted",
+                   "moe_rows: tiny fill 0.3, sorted: dispatch",
+                   "moe_rows: tiny fill 0.3: every form within 1e-2 of the "
+                   "parent's by norm, all four",
                    "moe: tiny (512, 4, 128, 128) silu: the layer takes the "
                    "kernel (interpreted), 256 rows a visit",
                    "moe: tiny fill 0.3: norm of the difference over the norm",

@@ -631,7 +631,9 @@ def test_the_paths_are_read_off_shapes_at_the_published_sizes(caplog):
     assert sum("mla_core (mla), both passes: tiles (the backend is cpu, not a "
                "TPU)" in s for s in said) == 5
     assert sum("moe_experts, both passes: ragged_dot (the backend is cpu, not a "
-               "TPU)" in s for s in said) == 4  # a line a routed layer
+               "TPU); rows moved by gathers through index lists, the combine "
+               "scatter at 2.0 (token, choice) pairs a buffer row" in s
+               for s in said) == 4  # a line a routed layer
     caplog.clear()
     with caplog.at_level(logging.INFO, logger="dinov3"):
         LMMetaArch(load_config(os.path.join(

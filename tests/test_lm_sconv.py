@@ -538,4 +538,6 @@ def test_the_paths_are_read_off_shapes_at_the_published_sizes(caplog):
     assert sum("sconv_chain (conv), both passes: plain" in s for s in said) == 4
     assert sum("gqa_core (full_attn), both passes: tiles" in s for s in said) == 1
     assert sum("moe_experts, both passes: ragged_dot (the backend is cpu, not a "
-               "TPU)" in s for s in said) == 4  # a line a routed layer
+               "TPU); rows moved by gathers through index lists, the combine "
+               "scatter at 4.0 (token, choice) pairs a buffer row" in s
+               for s in said) == 4  # a line a routed layer
