@@ -10,7 +10,10 @@
                                     # fifth's gated short convolution and
                                     # 64-wide causal core, the routed
                                     # layers' experts' block, the sixth's
-                                    # rotary turn and latent core at 16k
+                                    # rotary turn and latent core at 16k,
+                                    # the seventh's state-space scan, its
+                                    # 32 | 2 causal core and un-gated
+                                    # experts
     python chip_smoke.py --phases lm   # one chip, that phase alone
     python chip_smoke.py --chips 4  # four chips: ONLY the sharded train
                                     # arms and their one-device comparison
@@ -132,6 +135,17 @@ SIZES = {
         "keye_vl2": (16384, 8, 32768, 16, 2048, 768, "silu", (0.5,)),
         "kimi_linear": (16384, 8, 8192, 8, 2304, 1024, "silu", (0.5,)),
         "kanana2": (16384, 6, 49152, 16, 2048, 768, "silu", (0.44,))},
+    # the seventh decoder's three kernels at published sizes (--phases
+    # ssd): the state-space scan, [B, T, heads, head width, groups, state],
+    # the chunks its kernel pair is timed at beside the shipped one, the
+    # causal core at SIXTEEN query heads a key/value head, and the un-gated
+    # experts' block (a row as "moe_shapes" has them: W1 [2688, 1856], 14.5
+    # lane tiles)
+    "ssd_shape": (2, 8192, 64, 64, 8, 128),
+    "ssd_chunks": (256,),
+    "ssd_attn_shapes": {"gqa16": (2, 8192, 32, 2, 128, 128, None)},
+    "ssd_moe_shapes": {
+        "nemotron3": (16384, 6, 36864, 8, 2688, 1856, "relu2", (0.17, 0.5))},
     "gqa_shipped_blocks": (512, 1024),
     "gqa_blocks": [(512, 512), (1024, 1024), (256, 1024)],
     "gqa_timeout_s": 1200,
@@ -143,7 +157,7 @@ SIZES = {
 }
 
 ONE_CHIP_PHASES = ("trainer", "accum", "kernels", "serve", "lm", "gqa", "gdn",
-                   "dsa", "sconv", "moe", "mla")
+                   "dsa", "sconv", "moe", "mla", "ssd")
 # asked for by name only: a part of a phase above, alone
 PART_PHASES = ("moe_rows",)
 
@@ -769,6 +783,83 @@ def phase_sconv() -> None:
     phase_gqa("sconv_attn_shapes")
 
 
+def phase_ssd() -> None:
+    """The ``nemotron_h`` family's three kernels stand-alone at published
+    sizes. The state-space scan (``ops/ssd.py ssd_chunked``: u, B and C in
+    one joined plane, dt > 0 and ONE rate a head, at rates as large as the
+    family's initial values give: A up to 16, dt up to 0.1 and a few
+    steps near 1) on the path ``ssd_path`` takes there, the kernel pair
+    against the plain scan, output and the three gradients (the plane's
+    by its u, B and C parts), both timed, then the pair at other chunks;
+    the causal core at 32 | 2 heads of 128 through ``phase_gqa``'s rows;
+    the un-gated experts' block at 2688 x 1856 through ``phase_moe``'s."""
+    import faulthandler
+
+    import jax
+    import jax.numpy as jnp
+
+    from dinov3_tpu.ops import ssd
+
+    interpret = bool(SIZES["kernel_interpret"])
+    faulthandler.dump_traceback_later(
+        float(SIZES["gqa_timeout_s"]), exit=True, file=sys.__stderr__)
+    b, t, h, p, g, n = SIZES["ssd_shape"]
+    sizes = (h, p, g, n)
+    path, why = ssd.ssd_path(*sizes, t, jnp.bfloat16, interpret or None)
+    log(f"ssd: scan {(b, t, h, p, g, n)}: the entry point takes the {path} "
+        f"({why})")
+    assert path == "kernel", (path, why)
+    ks = jax.random.split(jax.random.key(8), 3)
+    xbc = jax.random.normal(ks[0], (b, t, h * p + 2 * g * n), jnp.bfloat16)
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (b, t, h)) * 1.5 - 4.0)
+    a = -jnp.linspace(1.0, 16.0, h)
+    x = (xbc, dt, a)
+    weigh = jax.random.normal(ks[2], (b, t, h * p))
+
+    def out_and_grads(f):
+        # a LINEAR weight: y is tens here, and sin of a bfloat16 y turns
+        # the output's rounding into percents of the gradient, on either
+        # path
+        return jax.jit(lambda *x: (f(*x), *jax.grad(
+            lambda *y: jnp.sum(weigh * f(*y).astype(jnp.float32)),
+            argnums=(0, 1, 2))(*x)))
+
+    def core(which, chunk=ssd.KERNEL_CHUNK):
+        def fn(xbc, dt, a):
+            if which == "scan":
+                return ssd._scan_forward(xbc, dt, a, *sizes,
+                                         ssd.PLAIN_CHUNK).astype(xbc.dtype)
+            return ssd.kernel_scan(xbc, dt, a, (*sizes, chunk), interpret)
+        return fn
+
+    found = {}
+    for name, fn in (("kernel", core("kernel")), ("scan", core("scan"))):
+        first, fwd, _ = _timed(jax.jit(fn), x)
+        _, ms, found[name] = _timed(out_and_grads(fn), x)
+        log(f"ssd: scan, {name}: first call {first:.1f}s, forward {fwd:.2f} "
+            f"ms, forward + backward {ms:.2f} ms")
+    inner, gn = h * p, g * n
+    parts = lambda r: (r[0], r[1][..., :inner],  # noqa: E731
+                       r[1][..., inner:inner + gn], r[1][..., inner + gn:],
+                       r[2], r[3])
+    gaps = _gaps(parts(found["kernel"]), parts(found["scan"]))
+    log("ssd: scan: norm of the difference over the norm, kernel to plain "
+        "scan, output and gradients u B C dt a " + _said(gaps))
+    assert all(math.isfinite(v) for v in gaps) and max(gaps) <= 2e-2, gaps
+    for chunk in SIZES["ssd_chunks"]:
+        fn = core("kernel", chunk)
+        _, fwd, _ = _timed(jax.jit(fn), x)
+        _, ms, out = _timed(out_and_grads(fn), x)
+        log(f"ssd: scan, kernel at chunks of {chunk}: forward {fwd:.2f} ms, "
+            f"forward + backward {ms:.2f} ms; to the plain scan "
+            + _said(_gaps(parts(out), parts(found["scan"]))))
+    faulthandler.cancel_dump_traceback_later()
+    # (the plain tiles at this shape are minutes of compiling for a path
+    # that does not ship: the dense masked softmax is the oracle)
+    phase_gqa("ssd_attn_shapes", with_tiles=False)
+    phase_moe(shapes="ssd_moe_shapes")
+
+
 def phase_mla() -> None:
     """Latent attention stand-alone at published sizes. The plain arm's
     interleaved rotary turn (``ops/rope.py rope_apply_interleaved``) of
@@ -997,7 +1088,7 @@ def _moe_rows(name, n, k, cap, d, fill, sizes) -> None:
         "parent's by norm, all four")
 
 
-def phase_moe(block: bool = True) -> None:
+def phase_moe(block: bool = True, shapes: str = "moe_shapes") -> None:
     """The routed layers of ``ops/ffn.py RoutedExpertsFFN`` stand-alone at
     the six decoder cells' published shapes and measured fills, a shape at
     a time: its row movement (``_moe_rows``; ``--phases moe_rows`` stops
@@ -1025,20 +1116,21 @@ def phase_moe(block: bool = True) -> None:
         sizes[0] += int(fill * cap) - sizes.sum()
         return sizes
 
-    for name, (n, k, cap, held, d, hid, gate, fills) in SIZES[
-            "moe_shapes"].items():
+    for name, (n, k, cap, held, d, hid, gate, fills) in SIZES[shapes].items():
         fill = fills[min(1, len(fills) - 1)]  # the measured fill
         _moe_rows(name, n, k, cap, d, fill, group_sizes(held, fill, cap))
         if not block:
             continue
         path, why = gm.grouped_matmul_path(cap, d, hid, jnp.bfloat16,
-                                           interpret=interpret or None)
+                                           interpret=interpret or None,
+                                           gate=gate)
         tm = gm.row_tile(cap)
         log(f"moe: {name} {(cap, held, d, hid)} {gate}: the layer takes the "
             f"{path} ({why}), {tm} rows a visit")
         assert path == "kernel", (path, why)
         ks = jax.random.split(jax.random.key(6), 5)
-        w12 = jax.random.normal(ks[0], (held, d, 2 * hid)) * d ** -0.5
+        w12 = jax.random.normal(  # an un-gated expert's first matrix: H wide
+            ks[0], (held, d, hid if gate == gm.UNGATED else 2 * hid)) * d ** -0.5
         w3 = jax.random.normal(ks[1], (held, hid, d)) * hid ** -0.5
         ct = jax.random.normal(ks[2], (cap, d))
         for fill in fills:
@@ -1080,8 +1172,9 @@ def phase_moe(block: bool = True) -> None:
                     log(f"moe: {name} fill {fill}, kernel at {other} rows a "
                         f"visit: forward + backward {ms:.2f} ms")
             alone = []
-            for left, right in ((rows, w12), (found["kernel"][0].astype(
-                    jnp.bfloat16)[:, :hid], w3)):
+            for left, right in ((rows, w12), (jnp.pad(
+                    found["kernel"][0].astype(jnp.bfloat16),
+                    ((0, 0), (0, max(hid - d, 0))))[:, :hid], w3)):
                 _, ms, _ = _timed(jax.jit(lambda a, b, n: jax.lax.ragged_dot(
                     a, b.astype(a.dtype), n,
                     preferred_element_type=jnp.float32)), (left, right, sizes))
@@ -1543,7 +1636,7 @@ def main(argv=None) -> int:
                "lm": phase_lm, "gqa": phase_gqa, "gdn": phase_gdn,
                "dsa": phase_dsa, "sconv": phase_sconv, "moe": phase_moe,
                "moe_rows": functools.partial(phase_moe, block=False),
-               "mla": phase_mla}
+               "mla": phase_mla, "ssd": phase_ssd}
         for name in phases:
             run[name]()
     else:

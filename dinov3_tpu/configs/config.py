@@ -169,7 +169,7 @@ def apply_dot_overrides(cfg: ConfigNode, overrides: Iterable[str]) -> ConfigNode
 # architectures (``student.arch``) that are token decoders trained on a
 # next-token loss (models/decoder.py, train/lm_meta_arch.py)
 LM_ARCHS = ("kimi_linear", "smallthinker", "qwen3_next", "keye_vl2",
-            "lfm2_moe", "deepseek_v3")
+            "lfm2_moe", "deepseek_v3", "nemotron_h")
 
 
 def is_lm_arch(cfg: ConfigNode) -> bool:

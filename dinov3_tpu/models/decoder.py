@@ -1,4 +1,4 @@
-"""A causal decoder whose mixer and FFN are chosen layer by layer. Six
+"""A causal decoder whose mixer and FFN are chosen layer by layer. Seven
 families (``student.arch``): ``kimi_linear`` (Kimi Linear, Moonshot AI;
 ``config.json`` of Kimi-Linear-48B-A3B-Instruct and the model's report:
 KDA and MLA mixers, a dense SwiGLU, routed + shared experts),
@@ -18,9 +18,18 @@ then routed experts behind a biased sigmoid router, a tied head) and
 ``deepseek_v3`` (the DeepSeek-V3 block as Kakao's Kanana-2 runs it;
 ``config.json`` of kanana-2-30b-a3b-instruct-2601: latent attention with
 a ROTATED shared key on every layer, a leading dense SwiGLU, then routed
-experts behind a biased sigmoid router beside two shared ones).
+experts behind a biased sigmoid router beside two shared ones) and
+``nemotron_h`` (Nemotron-H as NVIDIA's Nemotron 3 Nano runs it;
+``config.json`` of NVIDIA-Nemotron-3-Nano-30B-A3B-BF16: blocks of ONE
+sublayer each, as ``hybrid_override_pattern`` spells them — M a Mamba-2
+mixer, * grouped-query attention without positions, E un-gated
+squared-ReLU experts behind a biased sigmoid router beside a shared one).
 
-Pre-norm residual layers, RMSNorm everywhere (``qwen3_next``: zero-centred,
+A layer is a mixer and a feed-forward part, each pre-normed (``norm1``,
+``norm2``) with its own residual add; a block may be ONE sublayer
+(``layers`` entries ``(mixer, None)`` / ``(None, ffn)``: ``nemotron_h``),
+then it has one ``norm`` and one residual add. RMSNorm everywhere
+(``qwen3_next``: zero-centred,
 n(x) = x / rms(x) * (1 + w), but for the delta rule's output norm):
 
 - **KDA** (Kimi Delta Attention), per head h with d_k = d_v = head_dim,
@@ -93,6 +102,22 @@ n(x) = x / rms(x) * (1 + w), but for the delta rule's output norm):
   ``ops/mixer_chains.py gated_short_conv`` on a TPU, the plain XLA chain
   elsewhere. ``lfm2_moe``'s ``full_attn`` layers are GQA with n_q, n_k on
   every q and k head and the whole head rotated, no window.
+- **SSD** (``ssm``; Mamba-2's state-space duality), ``mamba_num_heads``
+  heads of ``mamba_head_dim`` (inner width their product) on a state of
+  ``ssm_state_size`` in ``n_groups`` groups: [z ; xBC ; dt] = W_in x,
+  widths inner | inner + 2 G N | heads in that order, no bias;
+  xBC' = SiLU(c + conv(xBC)), ONE causal depthwise convolution of width
+  ``conv_kernel`` WITH a bias c over the joined channels; [u ; B ; C] =
+  xBC', head i reads group i // (heads / groups)'s B and C;
+  dt = softplus(dt + dt_bias), a = -exp(A_log), ONE rate a head;
+  S_t = e^{dt_t a} S_{t-1} + dt_t u_t B_t^T, y_t = S_t C_t + D u_t
+  (``ops/ssd.py``: a kernel pair on a TPU at heads of 64 on a state of
+  128, the plain chunked scan elsewhere; the state, the decays and dt
+  float32); g = y * SiLU(z), normalised over each GROUP's inner / G
+  channels (the gate BEFORE the norm, one scale over the inner width);
+  W_out g. The two chains between the matmuls (``ssm_chain``) are XLA's.
+  ``nemotron_h``'s ``full_attn`` blocks are GQA with no rotation, no
+  window and no q/k norm.
 - **FFN**: ``kimi_linear``: SwiGLU of ``intermediate_size`` in the first
   ``first_k_dense_replace`` layers; after them the routed experts this
   shard holds (``ops/ffn.py RoutedExpertsFFN``, sigmoid router) plus
@@ -118,6 +143,11 @@ n(x) = x / rms(x) * (1 + w), but for the delta rule's output norm):
   ``num_experts_per_tok`` largest of s + bias) with w =
   ``routed_scaling_factor`` * s_sel / (sum(s_sel) + 1e-20), plus
   ``n_shared_experts`` shared ones as ONE SwiGLU of that many widths.
+  ``nemotron_h``: every E block routed, UN-GATED experts of two matrices,
+  W2 relu(W1 x)^2 (``gate`` "relu2": ``w1`` [held, D, H], ``w2``
+  [held, H, D]; H may end in half a lane tile), under ``deepseek_v3``'s
+  rule (ONE group, sum + 1e-20, ``routed_scaling_factor``), plus ONE
+  shared un-gated MLP of ``moe_shared_expert_intermediate_size``.
 
 The vocabulary may be a slice (``vocab_size`` rows of the published
 table): ids, logits and the loss are over the slice. Embedding and head
@@ -135,7 +165,9 @@ attention call), ``swa_mixer`` and ``full_attn_mixer``
 ``gated_attn_mixer`` (inner ``gqa_core``), ``dsa_mixer`` (inner
 ``dsa_index``: the indexer's projections and score planes, ``dsa_select``:
 the thresholds, ``dsa_core``, ``dsa_index_loss``), ``sconv_mixer`` (inner ``sconv_chain``: the kernel
-pair or the plain chain, nothing else), ``dense_ffn``, ``moe_ffn`` (inner
+pair or the plain chain, nothing else), ``ssm_mixer`` (inner ``ssd_core``:
+the scan alone, and ``ssm_chain``: the convolution, softplus, the skip,
+the gate and the grouped norm), ``dense_ffn``, ``moe_ffn`` (inner
 ``moe_route``, ``moe_experts`` from the routed layer — inside it
 ``moe_rows``, the dispatch and combine of ``ops/routed_rows.py`` —
 ``moe_shared``: the shared expert, with its gate where it has one),
@@ -158,7 +190,12 @@ from dinov3_tpu.ops.causal_attention import (
     latent_attention_path,
 )
 from dinov3_tpu.ops.common import l2_normalize, part, trunc_normal_init
-from dinov3_tpu.ops.ffn import ROWS_CAPACITY_FACTOR, RoutedExpertsFFN, SwiGLUFFN
+from dinov3_tpu.ops.ffn import (
+    ROWS_CAPACITY_FACTOR,
+    Mlp,
+    RoutedExpertsFFN,
+    SwiGLUFFN,
+)
 from dinov3_tpu.ops.kda import kda_chunked
 from dinov3_tpu.ops.mixer_chains import (
     IN_ORDER,
@@ -177,6 +214,7 @@ from dinov3_tpu.ops.rope import (
     token_rope_pair_sincos,
     token_rope_sincos,
 )
+from dinov3_tpu.ops.ssd import ssd_chunked
 from dinov3_tpu.ops.sparse_index import (
     INT_MIN,
     index_loss,
@@ -197,7 +235,8 @@ class DecoderConfig:
 
     hidden_size: int
     vocab_size: int
-    layers: tuple              # ((mixer, ffn), ...)
+    layers: tuple              # ((mixer, ffn), ...); one of a pair may be
+                               # None: a block of ONE sublayer
     rms_norm_eps: float
     num_attention_heads: int
     num_experts: int
@@ -246,6 +285,13 @@ class DecoderConfig:
     tie_word_embeddings: bool = False  # the head is the embedding table
     # deepseek_v3
     mla_rotary: bool = False           # an "mla" layer turns q_pe and kpe
+    # nemotron_h
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_n_groups: int = 0            # groups of heads that share B and C
+    ssm_state_size: int = 0
+    time_step_limits: tuple = (1e-3, 1e-1, 1e-4)   # min, max, floor of dt
+    shared_expert_width: int = 0       # of the ONE un-gated shared MLP
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     reduce_dtype: Any = jnp.float32
@@ -260,7 +306,8 @@ class DecoderConfig:
                   "qwen3_next": _qwen3_next_fields,
                   "keye_vl2": _keye_vl2_fields,
                   "lfm2_moe": _lfm2_moe_fields,
-                  "deepseek_v3": _deepseek_v3_fields}[str(cfg.student.arch)]
+                  "deepseek_v3": _deepseek_v3_fields,
+                  "nemotron_h": _nemotron_h_fields}[str(cfg.student.arch)]
         return cls(dtype=policy.compute_dtype,
                    param_dtype=param_dtype or policy.param_dtype,
                    reduce_dtype=policy.reduce_dtype, **family(cfg.lm))
@@ -466,6 +513,58 @@ def _deepseek_v3_fields(lm) -> dict:
         router="sigmoid", gate="silu")
 
 
+NEMOTRON_H_BLOCKS = {"M": ("ssm", None), "*": ("full_attn", None),
+                     "E": (None, "moe")}
+
+
+def _nemotron_h_fields(lm) -> dict:
+    depth, pattern = int(lm.num_hidden_layers), str(lm.hybrid_override_pattern)
+    unknown = sorted(set(pattern) - set(NEMOTRON_H_BLOCKS))
+    if unknown or len(pattern) != depth:
+        raise ValueError(
+            f"lm.hybrid_override_pattern {pattern!r} must name each of the "
+            f"{depth} blocks M (Mamba-2), E (routed experts) or * "
+            f"(attention): {unknown or len(pattern)}")
+    if int(lm.n_group) != 1 or int(lm.topk_group) != 1:
+        raise ValueError("lm.n_group / lm.topk_group: only 1 / 1 (ONE group "
+                         "of experts: the group-limited step selects all)")
+    if not bool(lm.norm_topk_prob) or str(lm.mlp_hidden_act) != "relu2" \
+            or int(lm.n_shared_experts) != 1:
+        raise ValueError("the routed block is a renormalised sigmoid router "
+                         "over un-gated squared-ReLU experts beside ONE "
+                         "shared one (lm.norm_topk_prob, lm.mlp_hidden_act "
+                         "relu2, lm.n_shared_experts 1)")
+    if str(lm.mamba_hidden_act) != "silu" or not bool(lm.use_conv_bias):
+        raise ValueError("lm.mamba_hidden_act: only silu; lm.use_conv_bias: "
+                         "only true")
+    biased = [k for k in ("mamba_proj_bias", "use_bias", "attention_bias",
+                          "mlp_bias") if bool(lm[k])]
+    if biased:
+        raise ValueError(f"lm.{biased[0]}: only false (no projection has a "
+                         "bias)")
+    return dict(
+        layers=tuple(NEMOTRON_H_BLOCKS[kind] for kind in pattern),
+        hidden_size=lm.hidden_size, vocab_size=lm.vocab_size,
+        rms_norm_eps=lm.layer_norm_epsilon,
+        num_attention_heads=lm.num_attention_heads,
+        num_key_value_heads=lm.num_key_value_heads, head_dim=lm.head_dim,
+        mamba_num_heads=lm.mamba_num_heads, mamba_head_dim=lm.mamba_head_dim,
+        mamba_n_groups=lm.n_groups, ssm_state_size=lm.ssm_state_size,
+        short_conv_kernel_size=lm.conv_kernel,
+        time_step_limits=(float(lm.time_step_min), float(lm.time_step_max),
+                          float(lm.time_step_floor)),
+        num_experts=lm.n_routed_experts,
+        num_experts_per_token=lm.num_experts_per_tok,
+        moe_intermediate_size=lm.moe_intermediate_size,
+        num_shared_experts=lm.n_shared_experts,
+        shared_expert_width=lm.moe_shared_expert_intermediate_size,
+        routed_scaling_factor=float(lm.routed_scaling_factor),
+        router_norm_eps=DEEPSEEK_V3_ROUTER_EPS,
+        expert_shards=lm.expert_shards, expert_shard=lm.expert_shard,
+        expert_rows_factor=float(lm.expert_rows_factor),
+        router="sigmoid", gate="relu2")
+
+
 def _dense(features: int, axes, name: str, dtype, param_dtype) -> nn.Dense:
     return nn.Dense(features, use_bias=False, dtype=dtype,
                     param_dtype=param_dtype, name=name,
@@ -478,6 +577,12 @@ def _swiglu(width: int, name: str, dtype, param_dtype) -> SwiGLUFFN:
         raise ValueError(f"SwiGLU width {width} must be even")
     return SwiGLUFFN(hidden_dim=width * 3 // 2, use_bias=False, align_to=1,
                      dtype=dtype, param_dtype=param_dtype, name=name)
+
+
+def _relu2_mlp(width: int, name: str, dtype, param_dtype) -> Mlp:
+    """W2 relu(W1 x)^2: an un-gated feed-forward of two matrices."""
+    return Mlp(hidden_dim=width, act=lambda h: jnp.square(nn.relu(h)),
+               use_bias=False, dtype=dtype, param_dtype=param_dtype, name=name)
 
 
 def causal_depthwise_conv(x, kernel):
@@ -494,11 +599,13 @@ def a_log_init(key, shape, dtype=jnp.float32, lo=1.0):
                    ).astype(dtype)
 
 
-def dt_bias_init(key, shape, dtype=jnp.float32, lo=1e-3, hi=1e-1):
-    """softplus(dt_bias) = dt, dt log-uniform on [lo, hi] (the released
-    code's init, after Mamba's)."""
+def dt_bias_init(key, shape, dtype=jnp.float32, lo=1e-3, hi=1e-1, floor=0.0):
+    """softplus(dt_bias) = dt, dt log-uniform on [lo, hi] and no less than
+    ``floor`` (the released code's init, after Mamba's)."""
     dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32,
                                     jnp.log(lo), jnp.log(hi)))
+    if floor:
+        dt = jnp.maximum(dt, floor)
     return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
 
 
@@ -744,6 +851,80 @@ class ShortConvMixer(nn.Module):
         return _dense(c, ("heads", "embed"), "out_proj", **kw)(y)
 
 
+class Mamba2Mixer(nn.Module):
+    """y = W_out gnorm((ssd(u, B, C, dt) + D u) * SiLU(z)), [z ; xBC ; dt] =
+    W_in x, [u ; B ; C] = SiLU(conv(xBC) + c) (the module's docstring,
+    **SSD**). The recurrence is ``ops/ssd.py``'s; everything else between
+    the two matmuls is two float32 chains with bfloat16 ends, each
+    rematerialised by itself (``KDAMixer``'s words: a block's backward
+    keeps their ends, not the float32 planes in between)."""
+
+    num_heads: int
+    head_dim: int
+    groups: int
+    state: int
+    conv_size: int = 4
+    eps: float = 1e-5
+    time_step_limits: tuple = (1e-3, 1e-1, 1e-4)
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+    core_interpret: bool | None = None   # tests: ops/ssd.py ssd_path's
+
+    @nn.compact
+    def __call__(self, x):
+        b, t, _ = x.shape
+        h, p, g, n = self.num_heads, self.head_dim, self.groups, self.state
+        inner, joined = h * p, h * p + 2 * g * n
+        kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
+        f32 = lambda v: v.astype(jnp.float32)  # noqa: E731
+        # [z | xBC | dt], the published order of in_proj's columns
+        plane = _dense(inner + joined + h, ("embed", "heads"), "in_proj",
+                       **kw)(x.astype(self.dtype))
+        taps = self.param("conv", part(conv_taps_init, (None, "heads")),
+                          (self.conv_size, joined), self.param_dtype)
+        bias = self.param("conv_bias", part(nn.initializers.zeros, ("heads",)),
+                          (joined,), self.param_dtype)
+        a_log = self.param("A_log", part(a_log_init, ("heads",)), (h,),
+                           self.param_dtype)
+        lo, hi, floor = self.time_step_limits
+        dt_bias = self.param(
+            "dt_bias", part(functools.partial(dt_bias_init, lo=lo, hi=hi,
+                                              floor=floor), ("heads",)),
+            (h,), self.param_dtype)
+        skip = self.param("D", part(nn.initializers.ones, ("heads",)), (h,),
+                          self.param_dtype)
+        scale = self.param("norm_scale", part(nn.initializers.ones, ("heads",)),
+                           (inner,), self.param_dtype)
+
+        @jax.checkpoint
+        def conv_act(plane, taps, bias, dt_bias):
+            xbc = nn.silu(causal_depthwise_conv(
+                f32(plane[..., inner:inner + joined]), f32(taps)) + f32(bias))
+            dt = jax.nn.softplus(f32(plane[..., inner + joined:])
+                                 + f32(dt_bias))
+            return xbc.astype(self.dtype), dt
+
+        @jax.checkpoint
+        def gated_group_norm(y, xbc, plane, skip, scale):
+            # the gate BEFORE the norm, and the norm over a GROUP's channels
+            y = f32(y).reshape(b, t, h, p) + f32(skip)[:, None] * f32(
+                xbc[..., :inner]).reshape(b, t, h, p)
+            y = y.reshape(b, t, inner) * nn.silu(f32(plane[..., :inner]))
+            y = y.reshape(b, t, g, inner // g)
+            y = y * jax.lax.rsqrt(
+                jnp.mean(jnp.square(y), axis=-1, keepdims=True) + self.eps)
+            return (y.reshape(b, t, inner) * f32(scale)).astype(self.dtype)
+
+        with jax.named_scope("ssm_chain"):
+            xbc, dt = conv_act(plane, taps, bias, dt_bias)
+        with jax.named_scope("ssd_core"):
+            y = ssd_chunked(xbc, dt, -jnp.exp(f32(a_log)), h, p, g, n,
+                            interpret=self.core_interpret)
+        with jax.named_scope("ssm_chain"):
+            y = gated_group_norm(y, xbc, plane, skip, scale)
+        return _dense(x.shape[-1], ("heads", "embed"), "out_proj", **kw)(y)
+
+
 class MLAMixer(nn.Module):
     """Latent attention (the module's docstring, **MLA**): ``rope_theta``
     None carries the qk_rope channels unturned, a number turns them,
@@ -949,9 +1130,13 @@ class DSAMixer(nn.Module):
 
 
 class DecoderLayer(nn.Module):
-    mixer: str                 # "kda" | "mla" | "swa" | "full_attn" | "gdn"
-                               # | "gated_attn" | "dsa" | "conv"
-    ffn: str                   # "dense" | "moe"
+    """A block: a mixer and a feed-forward part, each pre-normed (``norm1``,
+    ``norm2``) with its own residual add — or ONE of the two (the other
+    None: ``nemotron_h``), then one ``norm`` and one residual add."""
+
+    mixer: str | None          # "kda" | "mla" | "swa" | "full_attn" | "gdn"
+                               # | "gated_attn" | "dsa" | "conv" | "ssm"
+    ffn: str | None            # "dense" | "moe"
     cfg: Any                   # the frozen ``DecoderConfig``
     keep_selection: bool = False   # "dsa": the selection among the aux
 
@@ -965,7 +1150,19 @@ class DecoderLayer(nn.Module):
         # a phase holds its pre-norm and its residual add: what is left
         # outside every phase is what the compiler makes between layers
         x_in = x
-        if self.mixer == "kda":
+        norm1 = "norm1" if self.ffn is not None else "norm"
+        norm2 = "norm2" if self.mixer is not None else "norm"
+        if self.mixer is None:
+            pass
+        elif self.mixer == "ssm":
+            with step_phase("ssm_mixer"):
+                y = Mamba2Mixer(c.mamba_num_heads, c.mamba_head_dim,
+                                c.mamba_n_groups, c.ssm_state_size,
+                                c.short_conv_kernel_size, c.rms_norm_eps,
+                                c.time_step_limits, name="ssm", **kw)(
+                                    norm(norm1)(x))
+                x = x + y.astype(x.dtype)
+        elif self.mixer == "kda":
             with step_phase("kda_mixer"):
                 y = KDAMixer(c.kda_num_heads, c.kda_head_dim,
                              c.short_conv_kernel_size, c.rms_norm_eps,
@@ -1016,16 +1213,18 @@ class DecoderLayer(nn.Module):
                     c.rotary_dim or None, gated,
                     norm if gated or c.attn_qk_norm else None,
                     reduce_dtype=c.reduce_dtype, name="attn", **kw)(
-                        norm("norm1")(x))
+                        norm(norm1)(x))
                 x = x + y.astype(x.dtype)
         aux = None
-        if self.ffn == "dense":
+        if self.ffn is None:
+            pass
+        elif self.ffn == "dense":
             with step_phase("dense_ffn"):
                 y = _swiglu(c.intermediate_size, "mlp", **kw)(norm("norm2")(x))
                 x = x + y.astype(x.dtype)
         else:
             with step_phase("moe_ffn"):
-                y = norm("norm2")(x)
+                y = norm(norm2)(x)
                 routed, aux = RoutedExpertsFFN(
                     c.moe_intermediate_size, c.num_experts,
                     c.num_experts_per_token, c.expert_shards, c.expert_shard,
@@ -1035,9 +1234,11 @@ class DecoderLayer(nn.Module):
                         y, x_in if c.router_reads_layer_input else None)
                 if c.num_shared_experts:
                     with jax.named_scope("moe_shared"):
-                        shared = _swiglu(
-                            c.moe_intermediate_size * c.num_shared_experts,
-                            "shared", **kw)(y)
+                        shared = (
+                            _relu2_mlp(c.shared_expert_width, "shared", **kw)
+                            if c.gate == "relu2" else _swiglu(
+                                c.moe_intermediate_size * c.num_shared_experts,
+                                "shared", **kw))(y)
                         if c.shared_expert_gate:
                             shared = shared * jax.nn.sigmoid(_dense(
                                 1, ("embed", None), "shared_gate", **kw)(

@@ -251,7 +251,9 @@ class RoutedExpertsFFN(nn.Module):
             renormalised over the chosen ones); no bias exists;
         y = sum over the selected experts e held here of
             w_e W3_e (act(g) * u), [g ; u] = W12_e x, act = ``gate``
-            ("silu": SwiGLU, "relu": ReGLU)
+            ("silu": SwiGLU, "relu": ReGLU); ``gate`` "relu2" is an
+            UN-GATED expert of two matrices, w_e W2_e relu(W1_e x)^2,
+            held as ``w1`` [held, D, H] and ``w2`` [held, H, D]
 
     ``x_r`` is ``x`` unless the caller hands in ``router_input`` (same
     leading shape): a layer whose router reads another tensor than its
@@ -289,7 +291,7 @@ class RoutedExpertsFFN(nn.Module):
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     router: str = "sigmoid"   # | "softmax"
-    gate: str = "silu"        # | "relu"
+    gate: str = "silu"        # | "relu" | "relu2" (un-gated)
     norm_eps: float = 0.0     # "sigmoid": added to the chosen scores' sum
     # ``grouped_matmul_path``'s: None = this backend's path, True = the
     # kernels interpreted (a test's way onto the kernel path off the chip)
@@ -309,7 +311,7 @@ class RoutedExpertsFFN(nn.Module):
             raise ValueError(
                 f"shard {self.shard} of {self.shards} over {E} experts")
         if self.router not in ("sigmoid", "softmax") \
-                or self.gate not in ("silu", "relu"):
+                or self.gate not in ("silu", "relu", "relu2"):
             raise ValueError(f"router {self.router!r}, gate {self.gate!r}")
         held = E // self.shards
         first = self.shard * held
@@ -326,11 +328,14 @@ class RoutedExpertsFFN(nn.Module):
             bias = self.param(
                 "router_bias", part(nn.initializers.zeros, (None,)),
                 (E,), self.param_dtype)
+        gated = self.gate != "relu2"
         w12 = self.param(
-            "w12", part(trunc_normal_init(), ("experts", "embed", "mlp")),
-            (held, D, 2 * H), self.param_dtype)
+            "w12" if gated else "w1",
+            part(trunc_normal_init(), ("experts", "embed", "mlp")),
+            (held, D, 2 * H if gated else H), self.param_dtype)
         w3 = self.param(
-            "w3", part(trunc_normal_init(), ("experts", "mlp", "embed")),
+            "w3" if gated else "w2",
+            part(trunc_normal_init(), ("experts", "mlp", "embed")),
             (held, H, D), self.param_dtype)
 
         with jax.named_scope("moe_route"):
@@ -376,7 +381,7 @@ class RoutedExpertsFFN(nn.Module):
             # (``ragged_dot``'s need: PR 27's fault)
             rows = dispatch_rows(x2.astype(self.dtype), lists, self.interpret)
             path, _ = grouped_matmul_path(cap, D, H, self.dtype,
-                                          self.interpret)
+                                          self.interpret, self.gate)
             if path == "kernel":
                 out = experts_block(rows, w12, w3, w_rows, sizes, self.gate,
                                     row_tile(cap), bool(self.interpret))

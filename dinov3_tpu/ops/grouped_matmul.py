@@ -4,7 +4,10 @@ back — as grouped-matmul Pallas kernels under one ``jax.custom_vjp``:
 
     out[r] = w_rows[r] * (act(g) * u) W3_e,  [g ; u] = rows[r] W12_e,
 
-for the rows r of expert e's group (``sizes[e]`` rows, the groups one
+(or, ``gate`` "relu2", an UN-GATED expert of two matrices: out[r] =
+w_rows[r] * relu(h)^2 W2_e, h = rows[r] W1_e — the same five kernels with
+the first matrix and the plane between the products H wide, not 2 H, and
+no u half: ``_hidden``) for the rows r of expert e's group (``sizes[e]`` rows, the groups one
 after another from row 0) and exactly 0, value and gradient, for every
 row past the last group whatever the buffer holds there.
 
@@ -76,6 +79,7 @@ _COMPILER_PARAMS = dict(vmem_limit_bytes=100 * 1024 * 1024)
 _NN = (((1,), (0,)), ((), ()))
 _NT = (((1,), (1,)), ((), ()))
 _ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+UNGATED = "relu2"  # an expert of two matrices: W2 relu(W1 x)^2, no u half
 
 
 def ragged_experts_block(rows, w12, w3, w_rows, sizes, kept, gate: str):
@@ -91,9 +95,13 @@ def ragged_experts_block(rows, w12, w3, w_rows, sizes, kept, gate: str):
     h = jax.lax.ragged_dot(
         rows, w12.astype(dtype), sizes,
         preferred_element_type=jnp.float32).astype(dtype)
-    g, u = jnp.split(h, 2, axis=-1)
+    if gate == UNGATED:
+        hidden = jnp.square(jax.nn.relu(h))
+    else:
+        g, u = jnp.split(h, 2, axis=-1)
+        hidden = _ACTS[gate](g) * u
     out = jax.lax.ragged_dot(
-        _ACTS[gate](g) * u, w3.astype(dtype), sizes,
+        hidden, w3.astype(dtype), sizes,
         preferred_element_type=jnp.float32)
     return jnp.where(kept[:, None], out, 0.0) * w_rows[:, None]
 
@@ -119,20 +127,28 @@ def _column_tiles(rows: int, columns: int) -> int:
 
 
 def grouped_matmul_path(cap: int, d: int, h: int, dtype,
-                        interpret: bool | None = None) -> tuple[str, str]:
+                        interpret: bool | None = None,
+                        gate: str = "silu") -> tuple[str, str]:
     """(path, why) the experts' block of a ``[cap, d]`` row buffer over
     experts of hidden width ``h`` takes on this backend:
-    ("kernel", ...) or ("ragged_dot", the reason it is not the kernels)."""
+    ("kernel", ...) or ("ragged_dot", the reason it is not the kernels).
+    ``gate`` says how wide an expert's first matrix is: [d, 2 h], or
+    [d, h] of an un-gated expert (``UNGATED``). The hidden width may end
+    in HALF a lane tile (1856 = 14.5 tiles: the [rows, h] plane and the
+    matrices are whole blocks as they lie, and Mosaic pads the last tile
+    in VMEM and masks it in a contraction); the rows' width d may not,
+    since the row movement's kernel takes whole tiles."""
     if jnp.dtype(dtype) != jnp.bfloat16:
         return "ragged_dot", f"the rows are {jnp.dtype(dtype).name}, not bfloat16"
-    if d % LANES or h % LANES:
+    if d % LANES or h % (LANES // 2):
         return "ragged_dot", (f"widths {d} and {h} are not whole tiles of "
                               f"{LANES} lanes")
     if row_tile(cap) is None:
         return "ragged_dot", f"a buffer of {cap} rows is not whole tiles of 128"
-    if d * 2 * h * 2 > _WEIGHT_BLOCK_BYTES:
+    wide = h if gate == UNGATED else 2 * h
+    if d * wide * 2 > _WEIGHT_BLOCK_BYTES:
         return "ragged_dot", (
-            f"an expert's [{d}, {2 * h}] matrix ({d * 4 * h >> 20} MiB) is "
+            f"an expert's [{d}, {wide}] matrix ({d * wide * 2 >> 20} MiB) is "
             f"more than a VMEM block of {_WEIGHT_BLOCK_BYTES >> 20} MiB")
     backend = jax.default_backend()
     if interpret is None and backend != "tpu":
@@ -257,17 +273,32 @@ def _gate_parts(h_ref, gate):
     return g * s, s * (1.0 + g * (1.0 - s)), u
 
 
+def _hidden(h_ref, gate):
+    """The rows the second product reads, [tm, H] float32: act(g) * u of a
+    gated tile, relu(h)^2 of an un-gated one (``gate`` "relu2": the tile
+    is [tm, H], there is no u half)."""
+    if gate == UNGATED:
+        r = jnp.maximum(h_ref[...].astype(jnp.float32), 0.0)
+        return r * r
+    act, _, u = _gate_parts(h_ref, gate)
+    return act * u
+
+
 def _up_body(rows, w12):
     return (_dot(rows[...], w12[...]),)
 
 
 def _down_body(h, w_rows, w3, *, gate):
-    act, _, u = _gate_parts(h, gate)
-    return (_dot((act * u).astype(h.dtype), w3[...]) * w_rows[...],)
+    return (_dot(_hidden(h, gate).astype(h.dtype), w3[...]) * w_rows[...],)
 
 
 def _back_body(ct, w_rows, h, w3, w12, *, gate):
     da = _dot(ct[...].astype(h.dtype), w3[...], _NT)   # of w_rows * (a W3)
+    if gate == UNGATED:
+        r = jnp.maximum(h[...].astype(jnp.float32), 0.0)
+        d_w = jnp.sum(r * r * da, axis=1, keepdims=True)
+        dh = (da * w_rows[...] * (2.0 * r)).astype(h.dtype)
+        return dh, d_w, _dot(dh, w12[...], _NT)
     act, slope, u = _gate_parts(h, gate)
     d_w = jnp.sum(act * u * da, axis=1, keepdims=True)
     da = da * w_rows[...]
@@ -328,8 +359,7 @@ def _dw12_body(rows, dh, *, mine):
 
 
 def _dw3_body(h, w_rows, ct, *, mine, gate):
-    act, _, u = _gate_parts(h, gate)
-    return (jnp.where(mine, act * u, 0.0),
+    return (jnp.where(mine, _hidden(h, gate), 0.0),
             (ct[...].astype(jnp.float32) * w_rows[...]).astype(h.dtype))
 
 
@@ -342,9 +372,10 @@ def _dw3_body(h, w_rows, ct, *, mine, gate):
     "gate", "tm", "interpret"))
 def _forward(rows, w12, w3, w_rows, meta, gate, tm, interpret):
     """(out [cap, D] float32, [g ; u] [cap, 2 H] as the rows' type)."""
-    d, hid = rows.shape[1], w3.shape[1]
+    d = rows.shape[1]
     h, = _walk(_up_body, EXPERTS_UP, meta, tm, [rows],
-               [w12.astype(rows.dtype)], [2 * hid], [rows.dtype], interpret)
+               [w12.astype(rows.dtype)], [w12.shape[2]], [rows.dtype],
+               interpret)
     out, = _walk(functools.partial(_down_body, gate=gate), EXPERTS_DOWN, meta,
                  tm, [h, w_rows[:, None]], [w3.astype(rows.dtype)], [d],
                  [jnp.float32], interpret)
@@ -366,9 +397,10 @@ def _backward(rows, h, w12, w3, w_rows, meta, ct, gate, tm, interpret):
     dh, d_w, d_rows = _walk(
         functools.partial(_back_body, gate=gate), EXPERTS_BACK, meta, tm,
         [ct, w_col, h], [w3.astype(rows.dtype), w12.astype(rows.dtype)],
-        [2 * hid, 1, d], [rows.dtype, jnp.float32, rows.dtype], interpret)
+        [w12.shape[2], 1, d], [rows.dtype, jnp.float32, rows.dtype],
+        interpret)
     d_w12 = _sum_groups(_dw12_body, EXPERTS_DW12, meta, tm,
-                        (groups, d, 2 * hid), [rows], dh, interpret)
+                        (groups, d, w12.shape[2]), [rows], dh, interpret)
     d_w3 = _sum_groups(functools.partial(_dw3_body, gate=gate), EXPERTS_DW3,
                        meta, tm, (groups, hid, d), [h, w_col], ct, interpret)
     return (d_rows, d_w12.astype(w12.dtype), d_w3.astype(w3.dtype),
@@ -405,9 +437,10 @@ _block.defvjp(_block_fwd, _block_bwd)
 def experts_block(rows, w12, w3, w_rows, sizes, gate, tm, interpret):
     """The block on the kernel path: ``rows`` [cap, D] bfloat16 sorted by
     expert, ``w12`` [held, D, 2 H] and ``w3`` [held, H, D] as the module
-    holds them (rounded to the rows' type here), ``w_rows`` [cap] float32,
+    holds them (rounded to the rows' type here; ``gate`` "relu2": ``w12``
+    is W1 [held, D, H] and ``w3`` W2), ``w_rows`` [cap] float32,
     ``sizes`` [held] int32 (their sum at most cap), ``gate`` "silu" |
-    "relu", ``tm`` = ``row_tile(cap)``: [cap, D] float32, the rows
+    "relu" | "relu2", ``tm`` = ``row_tile(cap)``: [cap, D] float32, the rows
     past the last group exactly 0 (``grouped_matmul_path`` says which
     shapes it takes). The visits are made here, once, outside the rule:
     both passes are given them."""

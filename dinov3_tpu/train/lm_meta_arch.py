@@ -41,6 +41,7 @@ from dinov3_tpu.ops.grouped_matmul import grouped_matmul_path
 from dinov3_tpu.ops.kda import kda_path
 from dinov3_tpu.ops.mixer_chains import mixer_chain_path
 from dinov3_tpu.ops.routed_rows import combine_form
+from dinov3_tpu.ops.ssd import ssd_path
 
 logger = logging.getLogger("dinov3")
 
@@ -87,16 +88,29 @@ class LMMetaArch:
             tokens, dc.num_experts_per_token, dc.num_experts, held,
             dc.expert_rows_factor)
         for i, (mixer, ffn) in enumerate(dc.layers, 1):
-            if ffn != "dense":
+            if ffn == "moe":
                 path, why = grouped_matmul_path(
-                    cap, dc.hidden_size, dc.moe_intermediate_size, dc.dtype)
+                    cap, dc.hidden_size, dc.moe_intermediate_size, dc.dtype,
+                    gate=dc.gate)
                 logger.info(
                     "layer %d moe_experts, both passes: %s (%s); rows moved "
                     "by gathers through index lists, the combine %s at %.1f "
                     "(token, choice) pairs a buffer row", i, path, why,
                     combine_form(tokens, cap, dc.hidden_size),
                     tokens * dc.num_experts_per_token / cap)
-            if mixer in delta:
+            if mixer is None:   # a block of the feed-forward part alone
+                continue
+            if mixer == "ssm":
+                path, why = ssd_path(
+                    dc.mamba_num_heads, dc.mamba_head_dim, dc.mamba_n_groups,
+                    dc.ssm_state_size, rows[1], dc.dtype)
+                logger.info("layer %d ssd_core, both passes: %s (%s)", i, path,
+                            why)
+                logger.info(
+                    "layer %d ssm_mixer's chains, both passes: plain (%s)", i,
+                    mixer_chain_path(rows[1], (dc.mamba_head_dim,),
+                                     (dc.mamba_num_heads,), dc.dtype)[1])
+            elif mixer in delta:
                 scope, dk, dv, heads, gate_heads = delta[mixer]
                 path, why = kda_path(dk, dv, gate_heads=gate_heads)
                 logger.info("layer %d %s, both passes: %s (%s)", i, scope, path, why)

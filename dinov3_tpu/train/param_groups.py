@@ -79,7 +79,7 @@ def build_multiplier_trees(
         if (
             name.endswith("bias")
             or "norm" in name
-            or path[-1] in ("gamma", "A_log")
+            or path[-1] in ("gamma", "A_log", "D")
         ):
             wd = 0.0
         if "patch_embed" in name:

@@ -564,6 +564,9 @@ STEP_PHASES = (
                           # dsa_core, dsa_index_loss)
     "sconv_mixer",        # a gated short convolution layer's mixer (inner:
                           # sconv_chain)
+    "ssm_mixer",          # a Mamba-2 block's mixer (inner: ssd_core, the
+                          # scan alone; ssm_chain, the rest between the
+                          # two matmuls)
     "dense_ffn",          # the dense SwiGLU of the leading layers
     "moe_ffn",            # routed + shared experts (inner: moe_route,
                           # moe_experts, moe_shared)
@@ -572,7 +575,7 @@ STEP_PHASES = (
 # the phases only a decoder's step opens
 LM_STEP_PHASES = ("lm_embed", "kda_mixer", "mla_mixer", "swa_mixer",
                   "full_attn_mixer", "gdn_mixer", "gated_attn_mixer",
-                  "dsa_mixer", "sconv_mixer", "dense_ffn", "moe_ffn",
+                  "dsa_mixer", "sconv_mixer", "ssm_mixer", "dense_ffn", "moe_ffn",
                   "lm_head_loss")
 
 _PHASE_WRAPPER = re.compile(r"^(jvp|transpose|checkpoint|remat)\((.*)\)$")

@@ -1,5 +1,5 @@
 """sha256 of the lowered step of benchmark configurations: the yardstick
-"the step program did not change" (PERF.md section 6, PRs 29-45).
+"the step program did not change" (PERF.md section 6, PRs 29-48).
 
     JAX_PLATFORMS=cpu python scripts/lowered_step_sha.py [<config> ...]
 

@@ -11,8 +11,11 @@ chains (``ops/mixer_chains.py``) at both delta-rule cells', the gated
 short convolution's chain and the causal pair at heads of 64 at the
 ``lfm2_moe`` cell's, the latent pair at both latent cells' (and the
 ``deepseek_v3`` cell's whole mixer around it: no plane a head),
+the state-space scan's pair (``ops/ssd.py``) and the causal pair at
+SIXTEEN query heads a key/value head at the ``nemotron_h`` cell's,
 and the routed layers' experts' block
-(``ops/grouped_matmul.py``) at the six decoder cells' — each with
+(``ops/grouped_matmul.py``) at the seven decoder cells' (the seventh's
+un-gated, 14.5 lane tiles wide) — each with
 ``interpret=False``, each asserting
 a Mosaic ``tpu_custom_call`` in the compiled text. What the chip's
 compiler would refuse (a slice off the tiling, too much VMEM) fails
@@ -183,19 +186,58 @@ def test_gdn_chunk_kernels_compile_for_v5e(one_chip, states):
         assert "f32[2,128,16,64,128]" in text
 
 
+@pytest.mark.parametrize("states", [False, True], ids=["primal", "states"])
+def test_ssd_chunk_kernels_compile_for_v5e(one_chip, states):
+    """``ops/ssd.py``'s pair at the ``nemotron_h`` cell's shapes (2
+    sequences of 8,192 tokens, 64 heads of 64 on a state of 128 in 8
+    groups, one bfloat16 plane [u | B | C] of 6144 lanes, a float32 step a
+    head and token): the primal, and the gradient's program — the forward
+    rule with each chunk's starting state and the backward kernel."""
+    from dinov3_tpu.ops.ssd import (
+        BACKWARD_KERNEL_NAME,
+        KERNEL_CHUNK,
+        KERNEL_NAME,
+        ssd_chunked,
+    )
+
+    plane, step, rate = (((2, 8192, 6144), jnp.bfloat16),
+                         ((2, 8192, 64), jnp.float32), ((64,), jnp.float32))
+
+    def fwd(*x):
+        return ssd_chunked(*x, 64, 64, 8, 128, interpret=False)
+
+    def bwd(*x):
+        return jax.vjp(fwd, *x[:-1])[1](x[-1])
+
+    text = _compiled_text(*((bwd, one_chip, plane, step, rate, (
+        (2, 8192, 4096), jnp.bfloat16)) if states else (
+            fwd, one_chip, plane, step, rate)))
+    assert KERNEL_NAME in text
+    assert text.count("tpu_custom_call") == (2 if states else 1)
+    assert (BACKWARD_KERNEL_NAME in text) == states
+    # no plane of B or C repeated over a group's heads, no loop over the
+    # chunks beside the kernels; the kept states are a chunk's, a group's
+    assert "[2,8192,64,128]" not in text and " while(" not in text
+    kept = f"f32[2,{8192 // KERNEL_CHUNK},8,128,512]"
+    assert (kept in text) == states
+
+
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
 @pytest.mark.parametrize("q, kv, dv, window", [
     ((1, 16384, 28, 128), 4, 128, 4096),
     ((1, 16384, 28, 128), 4, 128, None),
     ((2, 8192, 16, 256), 2, 256, None),
-], ids=["window", "global", "gated"])
+    ((2, 8192, 32, 128), 2, 128, None),
+], ids=["window", "global", "gated", "gqa16"])
 def test_causal_attention_kernels_compile_for_v5e(one_chip, q, kv, dv,
                                                   window, direction):
     """``ops/causal_attention.py`` at the shapes the decoder cells
     send ``causal_blockwise_attention``: the 16k cell's window and global
     grouped-query layers (28 query heads on 4 of 128) and the ``qwen3_next``
     cell's gated attention (16 query heads on 2 of 256, values 256 wide: 8
-    heads a key tile), at the shipped blocks. The
+    heads a key tile) and the ``nemotron_h`` cell's attention block (32
+    query heads on 2 of 128: SIXTEEN a key/value head in a grid step), at
+    the shipped blocks. The
     gradient's program holds the forward rule and ONE backward kernel."""
     from dinov3_tpu.ops.causal_attention import (
         BACKWARD_KERNEL_NAME,
@@ -561,16 +603,20 @@ def test_ibot_row_ce_gradient_compiles_without_a_loop_for_v5e(one_chip):
     ("keye-vl2-ep8-pretrain-16k", 32768, 16, 2048, 768, "silu"),
     ("kimi-linear-ep32-pretrain-8k", 8192, 8, 2304, 1024, "silu"),
     ("kanana2-ep8-pretrain-16k", 49152, 16, 2048, 768, "silu"),
+    ("nemotron3-nano-ep16-pretrain-8k", 36864, 8, 2688, 1856, "relu2"),
 ])
 def test_experts_block_compiles_for_v5e(one_chip, cell, cap, held, d, h, gate):
-    """The held experts' block (``ops/grouped_matmul.py``) at the six
+    """The held experts' block (``ops/grouped_matmul.py``) at the seven
     decoder cells' routed layers, both passes: two kernels forward and
     three backward; the float32 buffers XLA holds
-    beside them stay under the combine's operand and its cotangent."""
+    beside them stay under the combine's operand and its cotangent. The
+    seventh's experts are UN-GATED (W1 [2688, 1856]: the hidden width ends
+    in half a lane tile, which Mosaic pads and masks in VMEM)."""
     from dinov3_tpu.ops import grouped_matmul as gm
 
-    assert gm.grouped_matmul_path(cap, d, h, jnp.bfloat16,
-                                  interpret=False)[0] == "kernel"
+    assert gm.grouped_matmul_path(cap, d, h, jnp.bfloat16, interpret=False,
+                                  gate=gate)[0] == "kernel"
+    wide = h if gate == gm.UNGATED else 2 * h
 
     def both(rows, w12, w3, w_rows, sizes, ct):
         out, vjp = jax.vjp(lambda *a: gm.experts_block(
@@ -579,7 +625,7 @@ def test_experts_block_compiles_for_v5e(one_chip, cell, cap, held, d, h, gate):
         return out, vjp(ct)
 
     args = [jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in (
-        ((cap, d), jnp.bfloat16), ((held, d, 2 * h), jnp.float32),
+        ((cap, d), jnp.bfloat16), ((held, d, wide), jnp.float32),
         ((held, h, d), jnp.float32), ((cap,), jnp.float32),
         ((held,), jnp.int32), ((cap, d), jnp.float32))]
     compiled = jax.jit(both).lower(*args).compile()

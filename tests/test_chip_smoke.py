@@ -63,6 +63,10 @@ TINY = {
     "mla_latent_shapes": {"mla8k": (2, 256, 2, None, True),
                           "mla16k": (1, 256, 2, 1e6, False)},
     "mla_attn_shapes": {"wide16k": (1, 256, 1, 1, 256, 128, None)},
+    "ssd_shape": (1, 256, 2, 64, 1, 128),
+    "ssd_chunks": (256,),
+    "ssd_attn_shapes": {"gqa16": (1, 256, 16, 1, 128, 128, None)},
+    "ssd_moe_shapes": {"tiny15": (256, 4, 512, 4, 128, 192, "relu2", (0.3,))},
     "gqa_shipped_blocks": (128, 256),
     "gqa_blocks": [(256, 128)],
     "gqa_timeout_s": 600,
@@ -173,6 +177,15 @@ def test_smoke_rehearsal_runs_every_phase(smoke, monkeypatch, capsys):
                    "masked softmax",
                    "gqa: wide16k core (1, 256, 1, 1, 256, 128) window None: the "
                    "entry point takes the kernel (interpreted)",
+                   "ssd: scan (1, 256, 2, 64, 1, 128): the entry point takes "
+                   "the kernel (interpreted)",
+                   "ssd: scan: norm of the difference over the norm, kernel "
+                   "to plain scan, output and gradients u B C dt a",
+                   "ssd: scan, kernel at chunks of 256: forward",
+                   "gqa: gqa16 core (1, 256, 16, 1, 128, 128) window None: the "
+                   "entry point takes the kernel (interpreted)",
+                   "moe: tiny15 (512, 4, 128, 192) relu2: the layer takes the "
+                   "kernel (interpreted), 256 rows a visit",
                    "all phases passed"):
         assert needle in said, needle
     assert "mesh[" not in said  # the four-chip phase is behind --chips 4

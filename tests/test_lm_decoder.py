@@ -385,10 +385,10 @@ def test_compiled_step_holds_the_decoder_phases(tiny_setup):
     names = re.findall(r'op_name="([^"]*)"', text)
     found = {classify_step_phase(n) for n in names}
     # (the other decoder families' mixers are tests/test_lm_gqa.py's and
-    # tests/test_lm_gdn.py's and tests/test_lm_dsa.py's)
+    # tests/test_lm_gdn.py's and tests/test_lm_dsa.py's ... test_lm_ssd.py's)
     assert {p for p, _ in found} - {None} == set(LM_STEP_PHASES) - {
         "swa_mixer", "full_attn_mixer", "gdn_mixer", "gated_attn_mixer",
-        "dsa_mixer", "sconv_mixer"} | {
+        "dsa_mixer", "sconv_mixer", "ssm_mixer"} | {
             "update", "telemetry_ring"}
     for phase in ("kda_mixer", "mla_mixer", "dense_ffn", "moe_ffn",
                   "lm_head_loss"):

@@ -321,7 +321,7 @@ def test_cell_and_its_files(bench, conf):
         if CELL in m.get("workloads", ()):
             assert os.path.isfile(os.path.join(
                 BENCH, "layer_metrics", m["name"] + ".py"))
-            assert CELL in m["workloads"][-3:]   # (PRs 41, 45 appended one each)
+            assert CELL in m["workloads"][-4:]   # (PRs 41, 45, 48 appended one each)
             if m["name"] in listed[-7:]:
                 assert m["workloads"] == [CELL], m["name"]
                 assert set(m) == {"name", "unit", "better", "source", "layer",

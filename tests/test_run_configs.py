@@ -46,7 +46,8 @@ def test_recipe_abstract_build(path):
             "qwen3_next": "test_lm_gdn",
             "keye_vl2": "test_lm_dsa",
             "lfm2_moe": "test_lm_sconv",
-            "deepseek_v3": "test_lm_mla"}[str(cfg.student.arch)]).TINY
+            "deepseek_v3": "test_lm_mla",
+            "nemotron_h": "test_lm_ssd"}[str(cfg.student.arch)]).TINY
 
         from dinov3_tpu.train.lm_meta_arch import LMMetaArch
 

@@ -28,7 +28,7 @@ if BENCH not in sys.path:
 CELLS = ["vitl16-pretrain", "vits16-pretrain", "kimi-linear-ep32-pretrain-8k",
          "smallthinker-ep4-pretrain-16k", "qwen3-next-ep16-pretrain-8k",
          "keye-vl2-ep8-pretrain-16k", "lfm2-ep8-pretrain-8k",
-         "kanana2-ep8-pretrain-16k"]
+         "kanana2-ep8-pretrain-16k", "nemotron3-nano-ep16-pretrain-8k"]
 METRICS = ["setup_import_s", "setup_build_s", "setup_plan_trace_s",
            "setup_jit_trace_s", "setup_lower_s", "setup_compile_load_s",
            "setup_programs"]
