@@ -138,11 +138,15 @@ SIZES = {
     # the seventh decoder's three kernels at published sizes (--phases
     # ssd): the state-space scan, [B, T, heads, head width, groups, state],
     # the chunks its kernel pair is timed at beside the shipped one, the
+    # blocks the two chains around it are timed at, the
     # causal core at SIXTEEN query heads a key/value head, and the un-gated
     # experts' block (a row as "moe_shapes" has them: W1 [2688, 1856], 14.5
     # lane tiles)
     "ssd_shape": (2, 8192, 64, 64, 8, 128),
     "ssd_chunks": (256,),
+    # (time block, lane block) pairs Mamba2Mixer's two chains are timed at
+    # beside the shipped ones
+    "ssd_chain_blocks": ((128, 2048), (512, 2048), (256, 1024), (256, 512)),
     "ssd_attn_shapes": {"gqa16": (2, 8192, 32, 2, 128, 128, None)},
     "ssd_moe_shapes": {
         "nemotron3": (16384, 6, 36864, 8, 2688, 1856, "relu2", (0.17, 0.5))},
@@ -791,7 +795,7 @@ def phase_ssd() -> None:
     steps near 1) on the path ``ssd_path`` takes there, the kernel pair
     against the plain scan, output and the three gradients (the plane's
     by its u, B and C parts), both timed, then the pair at other chunks;
-    the causal core at 32 | 2 heads of 128 through ``phase_gqa``'s rows;
+    the two chains around the scan (``_ssd_chains``); the causal core at 32 | 2 heads of 128 through ``phase_gqa``'s rows;
     the un-gated experts' block at 2688 x 1856 through ``phase_moe``'s."""
     import faulthandler
 
@@ -853,11 +857,102 @@ def phase_ssd() -> None:
         log(f"ssd: scan, kernel at chunks of {chunk}: forward {fwd:.2f} ms, "
             f"forward + backward {ms:.2f} ms; to the plain scan "
             + _said(_gaps(parts(out), parts(found["scan"]))))
+    _ssd_chains(b, t, h, p, g, n, interpret)
     faulthandler.cancel_dump_traceback_later()
     # (the plain tiles at this shape are minutes of compiling for a path
     # that does not ship: the dense masked softmax is the oracle)
     phase_gqa("ssd_attn_shapes", with_tiles=False)
     phase_moe(shapes="ssd_moe_shapes")
+
+
+def _ssd_chains(b, t, h, p, g, n, interpret) -> None:
+    """``Mamba2Mixer``'s two chains stand-alone (``ops/mixer_chains.py
+    ssm_conv_silu`` and ``ssm_gate_norm``) on in_proj's lane-tiled plane
+    [z | xBC | dt | zeros]: each kernel pair against the plain chain of
+    ``models/decoder.py`` written out, output and every gradient, both
+    timed forward and forward + backward with the GB/s of the bytes a pass
+    has to move (every operand read once, every result written once,
+    bfloat16), then the pair at other time and lane blocks."""
+    import jax
+    import jax.numpy as jnp
+
+    from dinov3_tpu.models.decoder import causal_depthwise_conv
+    from dinov3_tpu.ops import mixer_chains as mc
+
+    inner, joined = h * p, h * p + 2 * g * n
+    wide = -(-(inner + joined + h) // mc.LANES) * mc.LANES
+    path, why = mc.ssm_chain_path(t, inner, joined, g, jnp.bfloat16,
+                                  interpret=interpret or None)
+    log(f"ssd: chains {(b, t)} x {inner} | {joined} | {h} in a plane of "
+        f"{wide}: the mixer takes the {path} ({why})")
+    assert path == "kernel", (path, why)
+    f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
+    ks = jax.random.split(jax.random.key(9), 9)
+    draw = lambda k, *shape: jax.random.normal(  # noqa: E731
+        ks[k], shape, jnp.float32)
+    plane, xbc, y = (draw(k, b, t, w).astype(jnp.bfloat16)
+                     for k, w in enumerate((wide, joined, inner)))
+    rows = 2 * b * t / 1e6   # MB a bfloat16 lane of every token
+
+    def conv(block=mc.SSM_TIME_BLOCK, lanes=None):
+        return lambda plane, taps, bias: mc.ssm_conv_silu(
+            plane, taps, bias, first=inner, block=block, lanes=lanes,
+            interpret=interpret)
+
+    def plain_conv(plane, taps, bias):
+        return jax.nn.silu(causal_depthwise_conv(
+            f32(plane[..., inner:inner + joined]), taps) + bias
+        ).astype(plane.dtype)
+
+    def norm(block=mc.SSM_TIME_BLOCK, lanes=None):
+        return lambda y, xbc, plane, skip, scale: mc.ssm_gate_norm(
+            y, xbc, plane, jnp.repeat(skip, p), scale, g, 1e-5, block=block,
+            lanes=lanes, interpret=interpret)
+
+    def plain_norm(y, xbc, plane, skip, scale):
+        v = f32(y).reshape(b, t, h, p) + skip[:, None] * f32(
+            xbc[..., :inner]).reshape(b, t, h, p)
+        v = v.reshape(b, t, inner) * jax.nn.silu(f32(plane[..., :inner]))
+        v = v.reshape(b, t, g, inner // g)
+        v = v * jax.lax.rsqrt(jnp.mean(jnp.square(v), -1, keepdims=True) + 1e-5)
+        return (v.reshape(b, t, inner) * scale).astype(y.dtype)
+
+    # (name, the pair at given blocks, the plain chain, operands, the
+    # gradients' names, MB forward and forward + backward)
+    chains = (
+        ("conv", conv, plain_conv,
+         (plane, 0.5 * draw(3, 4, joined), 0.5 * draw(4, joined)),
+         "plane taps bias", 2 * joined * rows, 5 * joined * rows),
+        ("norm", norm, plain_norm,
+         (y, xbc, plane, 1 + 0.5 * draw(5, h), 1 + 0.3 * draw(6, inner)),
+         "y xbc plane D scale", 4 * inner * rows, 11 * inner * rows))
+    for name, pair, plain, x, grads, mb_fwd, mb_both in chains:
+        weigh = draw(7, *plain(*x).shape)
+
+        def out_and_grads(f, n_args=len(x), weigh=weigh):
+            return jax.jit(lambda *a: (f(*a), *jax.grad(
+                lambda *c: jnp.sum(weigh * f32(f(*c))),
+                argnums=tuple(range(n_args)))(*a)))
+
+        def timed(fn, said):
+            first, fwd, _ = _timed(jax.jit(fn), x)
+            _, ms, out = _timed(out_and_grads(fn), x)
+            log(f"ssd: chain {name}, {said}: first call {first:.1f}s, forward "
+                f"{fwd:.2f} ms ({mb_fwd / fwd:.0f} GB/s of {mb_fwd:.0f} MB), "
+                f"forward + backward {ms:.2f} ms ({mb_both / ms:.0f} GB/s of "
+                f"{mb_both:.0f} MB)")
+            return out
+
+        got = timed(pair(), "kernel")
+        want = timed(plain, "plain")
+        gaps = _gaps(got, want)
+        log(f"ssd: chain {name}: norm of the difference over the norm, kernel "
+            f"to plain chain, output and gradients {grads} " + _said(gaps))
+        assert all(math.isfinite(v) for v in gaps) and max(gaps) <= 1e-2, gaps
+        for block, lanes in SIZES["ssd_chain_blocks"]:
+            out = timed(pair(block, lanes),
+                        f"kernel at blocks of {block} x {lanes}")
+            assert max(_gaps(out, want)) <= 1e-2, (name, block, lanes)
 
 
 def phase_mla() -> None:

@@ -115,7 +115,9 @@ n(x) = x / rms(x) * (1 + w), but for the delta rule's output norm):
   128, the plain chunked scan elsewhere; the state, the decays and dt
   float32); g = y * SiLU(z), normalised over each GROUP's inner / G
   channels (the gate BEFORE the norm, one scale over the inner width);
-  W_out g. The two chains between the matmuls (``ssm_chain``) are XLA's.
+  W_out g. The two chains between the matmuls (``ssm_chain``) are the
+  kernel pairs ``ops/mixer_chains.py ssm_conv_silu`` and ``ssm_gate_norm``
+  on a TPU (``ssm_chain_path``), the plain XLA chains elsewhere.
   ``nemotron_h``'s ``full_attn`` blocks are GQA with no rotation, no
   window and no q/k norm.
 - **FFN**: ``kimi_linear``: SwiGLU of ``intermediate_size`` in the first
@@ -167,7 +169,8 @@ attention call), ``swa_mixer`` and ``full_attn_mixer``
 the thresholds, ``dsa_core``, ``dsa_index_loss``), ``sconv_mixer`` (inner ``sconv_chain``: the kernel
 pair or the plain chain, nothing else), ``ssm_mixer`` (inner ``ssd_core``:
 the scan alone, and ``ssm_chain``: the convolution, softplus, the skip,
-the gate and the grouped norm), ``dense_ffn``, ``moe_ffn`` (inner
+the gate and the grouped norm, as two kernel pairs or the plain chains),
+``dense_ffn``, ``moe_ffn`` (inner
 ``moe_route``, ``moe_experts`` from the routed layer — inside it
 ``moe_rows``, the dispatch and combine of ``ops/routed_rows.py`` —
 ``moe_shared``: the shared expert, with its gate where it has one),
@@ -204,6 +207,9 @@ from dinov3_tpu.ops.mixer_chains import (
     gated_short_conv,
     log_decay,
     mixer_chain_path,
+    ssm_chain_path,
+    ssm_conv_silu,
+    ssm_gate_norm,
 )
 from dinov3_tpu.ops.norms import LayerNorm, RMSNorm
 from dinov3_tpu.ops.rope import (
@@ -565,10 +571,34 @@ def _nemotron_h_fields(lm) -> dict:
         router="sigmoid", gate="relu2")
 
 
-def _dense(features: int, axes, name: str, dtype, param_dtype) -> nn.Dense:
-    return nn.Dense(features, use_bias=False, dtype=dtype,
-                    param_dtype=param_dtype, name=name,
-                    kernel_init=part(trunc_normal_init(), axes))
+class _LaneTiledDense(nn.Module):
+    """``_dense``'s product (the same ``kernel`` leaf, no bias) with zero
+    columns appended up to whole lane tiles. XLA lays a ``[B, T,
+    features]`` plane whose width is no whole tile out TOKEN-minor, and a
+    kernel that cuts its blocks from the plane then pays a transposing
+    copy of it a pass; the appended columns cost the product their share
+    (64 of 10,368 in ``Mamba2Mixer``) and nothing else reads them."""
+
+    features: int
+    kernel_init: Callable = None
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        kernel = self.param("kernel", self.kernel_init,
+                            (x.shape[-1], self.features), self.param_dtype)
+        kernel = jnp.pad(kernel.astype(self.dtype),
+                         ((0, 0), (0, -self.features % 128)))
+        return jnp.dot(x.astype(self.dtype), kernel)
+
+
+def _dense(features: int, axes, name: str, dtype, param_dtype,
+           lane_tiled: bool = False) -> nn.Module:
+    kind = _LaneTiledDense if lane_tiled else functools.partial(
+        nn.Dense, use_bias=False)
+    return kind(features, dtype=dtype, param_dtype=param_dtype, name=name,
+                kernel_init=part(trunc_normal_init(), axes))
 
 
 def _swiglu(width: int, name: str, dtype, param_dtype) -> SwiGLUFFN:
@@ -855,9 +885,15 @@ class Mamba2Mixer(nn.Module):
     """y = W_out gnorm((ssd(u, B, C, dt) + D u) * SiLU(z)), [z ; xBC ; dt] =
     W_in x, [u ; B ; C] = SiLU(conv(xBC) + c) (the module's docstring,
     **SSD**). The recurrence is ``ops/ssd.py``'s; everything else between
-    the two matmuls is two float32 chains with bfloat16 ends, each
-    rematerialised by itself (``KDAMixer``'s words: a block's backward
-    keeps their ends, not the float32 planes in between)."""
+    the two matmuls is two float32 chains with bfloat16 ends: on a TPU at
+    bfloat16, whole time blocks and lane-tiled widths the kernel pairs
+    ``ops/mixer_chains.py ssm_conv_silu`` and ``ssm_gate_norm``, which cut
+    their blocks from in_proj's plane and the convolution's where they
+    lie (``ssm_chain_path`` says which and why; softplus(dt + dt_bias)
+    over ``heads`` lanes stays XLA's); elsewhere the plain XLA chains
+    below, each rematerialised by itself. Either way a block's backward
+    keeps the chains' ends, not the float32 planes in between
+    (``KDAMixer``'s words)."""
 
     num_heads: int
     head_dim: int
@@ -869,6 +905,7 @@ class Mamba2Mixer(nn.Module):
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     core_interpret: bool | None = None   # tests: ops/ssd.py ssd_path's
+    chains_interpret: bool | None = None   # tests: ops/mixer_chains.py's
 
     @nn.compact
     def __call__(self, x):
@@ -877,9 +914,13 @@ class Mamba2Mixer(nn.Module):
         inner, joined = h * p, h * p + 2 * g * n
         kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
         f32 = lambda v: v.astype(jnp.float32)  # noqa: E731
-        # [z | xBC | dt], the published order of in_proj's columns
+        chain = dict(interpret=self.chains_interpret)
+        fused = ssm_chain_path(t, inner, joined, g, self.dtype,
+                               **chain)[0] == "kernel"
+        # [z | xBC | dt], the published order of in_proj's columns (and
+        # under the kernels zeros up to a whole lane tile after them)
         plane = _dense(inner + joined + h, ("embed", "heads"), "in_proj",
-                       **kw)(x.astype(self.dtype))
+                       lane_tiled=fused, **kw)(x.astype(self.dtype))
         taps = self.param("conv", part(conv_taps_init, (None, "heads")),
                           (self.conv_size, joined), self.param_dtype)
         bias = self.param("conv_bias", part(nn.initializers.zeros, ("heads",)),
@@ -915,13 +956,25 @@ class Mamba2Mixer(nn.Module):
                 jnp.mean(jnp.square(y), axis=-1, keepdims=True) + self.eps)
             return (y.reshape(b, t, inner) * f32(scale)).astype(self.dtype)
 
+        @jax.checkpoint
+        def step_size(plane, dt_bias):   # conv_act's, of a lane-tiled plane
+            return jax.nn.softplus(
+                f32(plane[..., inner + joined:inner + joined + h])
+                + f32(dt_bias))
+
         with jax.named_scope("ssm_chain"):
-            xbc, dt = conv_act(plane, taps, bias, dt_bias)
+            if fused:
+                xbc = ssm_conv_silu(plane, taps, bias, first=inner, **chain)
+                dt = step_size(plane, dt_bias)
+            else:
+                xbc, dt = conv_act(plane, taps, bias, dt_bias)
         with jax.named_scope("ssd_core"):
             y = ssd_chunked(xbc, dt, -jnp.exp(f32(a_log)), h, p, g, n,
                             interpret=self.core_interpret)
         with jax.named_scope("ssm_chain"):
-            y = gated_group_norm(y, xbc, plane, skip, scale)
+            y = (ssm_gate_norm(y, xbc, plane, jnp.repeat(f32(skip), p), scale,
+                               g, self.eps, **chain)
+                 if fused else gated_group_norm(y, xbc, plane, skip, scale))
         return _dense(x.shape[-1], ("heads", "embed"), "out_proj", **kw)(y)
 
 

@@ -40,6 +40,27 @@ block / tail / taps-gradient machinery:
   plane read where they lie; the backward writes [dB ; dC ; du] as one
   such plane.
 
+and the two chains of ``Mamba2Mixer`` around its scan, flat planes too
+(``ops/ssd.py`` takes and returns ``[B, T, channels]``), their grid
+(sequence, LANE block, time block): the channels are independent, or
+independent a group, so a block of lanes is a parallel axis:
+
+- ``ssm_conv_silu``: y = SiLU(conv(x) + bias), the causal depthwise
+  convolution of width W WITH a bias and an activation and no gates, over
+  the ``joined`` channels [u | B | C] that start at lane ``first`` of
+  in_proj's plane, cut from the plane where they lie. The backward sums
+  the taps' and the bias's gradients ([W, C] and [C], rows of one
+  ``[8, C]`` float32 block a sequence) and carries ds like the others.
+- ``ssm_gate_norm``: out = v / sqrt(mean_group v^2 + eps) * scale with
+  v = (y + D u) * SiLU(z): the skip, the gate BEFORE the norm, the norm
+  over a GROUP's channels (``inner / groups``, whole lane tiles); y the
+  scan's output, u the first ``inner`` lanes of the convolution's plane,
+  z the first ``inner`` lanes of in_proj's, D a lane vector (a head's
+  value over its channels, repeated outside). The backward writes dy, du
+  and dz and sums the gradients of D and of the scale a lane (two rows
+  of one ``[8, inner]`` float32 block a sequence; D's is folded to a
+  value a head outside, by JAX's transposition of the repeat).
+
 Each is a ``jax.custom_vjp`` whose residuals are the chain's INPUTS and
 nothing else (what the plain chains' ``jax.checkpoint`` keeps); the
 backward kernel makes the float32 intermediates again in VMEM and applies
@@ -53,13 +74,15 @@ taps reach W - 1 tokens ahead).
 
 Rounding is where the plain chains round: the inputs are the bfloat16
 projection results, everything inside is float32, the outputs are rounded
-once (to nearest even) or stay float32. ``mixer_chain_path`` reads the
-path off what it can see; the plain chains live in the mixers.
+once (to nearest even) or stay float32. ``mixer_chain_path`` (and
+``ssm_chain_path`` for ``Mamba2Mixer``'s pair) reads the path off what it
+can see; the plain chains live in the mixers.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -80,6 +103,12 @@ DECAY_KERNEL_NAME = "log_decay_fwd"
 DECAY_BACKWARD_KERNEL_NAME = "log_decay_bwd"
 GATED_CONV_KERNEL_NAME = "gated_short_conv_fwd"
 GATED_CONV_BACKWARD_KERNEL_NAME = "gated_short_conv_bwd"
+SSM_CONV_KERNEL_NAME = "ssm_conv_silu_fwd"
+SSM_CONV_BACKWARD_KERNEL_NAME = "ssm_conv_silu_bwd"
+SSM_NORM_KERNEL_NAME = "ssm_gate_norm_fwd"
+SSM_NORM_BACKWARD_KERNEL_NAME = "ssm_gate_norm_bwd"
+SSM_TIME_BLOCK = 256   # tokens a grid step of Mamba2Mixer's two chains
+SSM_LANE_BLOCK = 2048  # channels a grid step of them, at most
 # a step holds its blocks twice over (the pipeline's two buffers): 4 MB a
 # 128 x 8192 bfloat16 plane and its gradient, past what a kernel gets unasked
 _COMPILER_PARAMS = pltpu.CompilerParams(
@@ -109,6 +138,19 @@ def mixer_chain_path(length: int, head_dims, heads, dtype,
     if interpret is None and backend != "tpu":
         return "plain", f"the backend is {backend}, not a TPU"
     return "kernel", "interpreted" if interpret else "compiled for the TPU"
+
+
+def ssm_chain_path(length: int, inner: int, joined: int, groups: int, dtype,
+                   block: int = SSM_TIME_BLOCK,
+                   interpret: bool | None = None) -> tuple[str, str]:
+    """``mixer_chain_path`` for ``Mamba2Mixer``'s two chains: ``inner``
+    channels of y, u and z in ``groups`` norm groups, ``joined`` channels
+    [u | B | C] under the convolution, ``length`` tokens a sequence."""
+    if joined % LANES or inner % groups or (inner // groups) % LANES:
+        return "plain", (f"{joined} joined channels, {inner} in {groups} norm "
+                         f"groups: not whole lane tiles of {LANES}")
+    return mixer_chain_path(length, (inner // groups,), (), dtype, block,
+                            interpret)
 
 
 # ------------------------------------------------------------ helpers
@@ -184,8 +226,8 @@ def _shifted(x, edge, k: int, back: bool):
     return pltpu.roll(jnp.concatenate([x, edge], 0), n + _EDGE - k, 0)[:n]
 
 
-def _first_step_zeros(ref):
-    @pl.when(pl.program_id(1) == 0)
+def _first_step_zeros(ref, axis: int = 1):
+    @pl.when(pl.program_id(axis) == 0)
     def _():
         ref[...] = jnp.zeros_like(ref)
 
@@ -787,3 +829,345 @@ def log_decay(f, a_log, dt_bias, block: int = TIME_BLOCK,
     rate = jnp.repeat(-jnp.exp(a_log.astype(jnp.float32)), d)[None]
     return _decay_chain(f, rate, dt_bias.astype(jnp.float32)[None], heads,
                         int(block), bool(interpret))
+
+
+# ---------------------------------------- Mamba-2: convolution, bias, SiLU
+
+
+_SSM_COMPILER_PARAMS = pltpu.CompilerParams(
+    # sequences, lane blocks, time blocks
+    dimension_semantics=("parallel", "parallel", "arbitrary"),
+    vmem_limit_bytes=64 * 1024 * 1024)
+
+
+def _lane_block(width: int, unit: int = LANES) -> int:
+    """The widest block of whole ``unit``s that divides ``width`` and is
+    no wider than ``SSM_LANE_BLOCK`` (one unit where a unit is wider)."""
+    return max(k for k in range(unit, max(SSM_LANE_BLOCK, unit) + 1, unit)
+               if width % k == 0)
+
+
+def _ssm_specs(b, t, block, lanes, first=0, reverse=False):
+    """The block specs of a grid (sequences, lane blocks, time blocks):
+    ``cut`` a block of ``block`` tokens of ``lanes`` channels from lane
+    ``first`` on, ``halo`` the 16 rows before it (``_conv_operands``'
+    words), ``own`` such a block of a plane of its own, ``rows`` a lane
+    block of an ``[8, C]`` tile of parameters and ``sums`` a sequence's
+    row of their gradient, which stays in VMEM over the time blocks; with
+    ``reverse`` time step m is block ``n`` - 1 - m."""
+    if block % _HALO or first % lanes:
+        raise ValueError(f"a time block of {block}, {lanes} lanes a block "
+                         f"from lane {first}: not whole tiles")
+    n, per, off = t // block, block // _HALO, first // lanes
+    at = (lambda m: n - 1 - m) if reverse else (lambda m: m)
+    spec = lambda shape, index: pl.BlockSpec(  # noqa: E731
+        shape, index, memory_space=pltpu.VMEM)
+    return dict(
+        n=n,
+        cut=spec((1, block, lanes), lambda i, j, m: (i, at(m), off + j)),
+        own=spec((1, block, lanes), lambda i, j, m: (i, at(m), j)),
+        halo=spec((1, _HALO, lanes), lambda i, j, m: (
+            i, jnp.maximum(at(m) * per - 1, 0), off + j)),
+        rows=spec((_EDGE, lanes), lambda i, j, m: (0, j)),
+        sums=spec((None, _EDGE, lanes), lambda i, j, m: (i, 0, j)))
+
+
+def _ssm_conv_pre(x_ref, halo_ref, rows_ref, cols, width, starts):
+    """s = conv(x) + bias of 128 channels, float32 [block, 128], and the W
+    shifted planes (``_conv_pre``'s words); ``rows_ref`` [8, lanes]: the W
+    taps, then the bias."""
+    x = x_ref[0, :, cols].astype(jnp.float32)
+    before = halo_ref[0, :, cols].astype(jnp.float32)[_EDGE:]
+    before = jnp.where(starts, 0.0, before)
+    planes = [_shifted(x, before, width - 1 - j, back=True)
+              for j in range(width)]
+    s = sum(rows_ref[j:j + 1, cols] * planes[j] for j in range(width))
+    return s + rows_ref[width:width + 1, cols], planes
+
+
+def _ssm_conv_fwd_kernel(x_ref, halo_ref, rows_ref, y_ref, *, width):
+    starts = pl.program_id(2) == 0
+
+    def one(g):
+        cols = _lanes(g, LANES)
+        s, _ = _ssm_conv_pre(x_ref, halo_ref, rows_ref, cols, width, starts)
+        y_ref[0, :, cols] = (s * jax.nn.sigmoid(s)).astype(y_ref.dtype)
+
+    _each_group(y_ref.shape[-1] // LANES, one)
+
+
+def _ssm_conv_bwd_kernel(x_ref, halo_ref, rows_ref, dy_ref, dx_ref, sums_ref,
+                         carry_ref, *, width, last_step):
+    """The blocks come LAST FIRST (``_conv_bwd_kernel``'s words);
+    ``carry_ref`` [8, lanes] holds the first rows of ds of the block after
+    this one. With s made again and ds = dy * SiLU'(s):
+
+        dx_t = sum_j k_j ds_{t + W - 1 - j},
+        dk_j += sum_t ds_t x_{t - W + 1 + j},   dbias += sum_t ds_t
+    """
+    first = pl.program_id(2) == 0            # the sequence's LAST block
+    starts = pl.program_id(2) == last_step
+    _first_step_zeros(sums_ref, axis=2)
+
+    def one(g):
+        cols = _lanes(g, LANES)
+        s, planes = _ssm_conv_pre(x_ref, halo_ref, rows_ref, cols, width,
+                                  starts)
+        sig = jax.nn.sigmoid(s)
+        ds = dy_ref[0, :, cols].astype(jnp.float32) * sig * (
+            1.0 + s * (1.0 - sig))
+        after = jnp.where(first, 0.0, carry_ref[:, cols])
+        carry_ref[:, cols] = ds[:_EDGE]
+        dx = sum(rows_ref[j:j + 1, cols] * _shifted(
+            ds, after, width - 1 - j, back=False) for j in range(width))
+        dx_ref[0, :, cols] = dx.astype(dx_ref.dtype)
+        for j in range(width):
+            sums_ref[j:j + 1, cols] += jnp.sum(ds * planes[j], axis=0,
+                                               keepdims=True)
+        sums_ref[width:width + 1, cols] += jnp.sum(ds, axis=0, keepdims=True)
+
+    _each_group(dx_ref.shape[-1] // LANES, one)
+
+
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "width", "first", "block", "lanes", "interpret"))
+def _ssm_conv_forward(x, rows, width, first, block, lanes, interpret):
+    b, t, _ = x.shape
+    c = rows.shape[1]
+    sp = _ssm_specs(b, t, block, lanes, first)
+    return pl.pallas_call(
+        functools.partial(_ssm_conv_fwd_kernel, width=width),
+        grid=(b, c // lanes, sp["n"]),
+        in_specs=[sp["cut"], sp["halo"], sp["rows"]],
+        out_specs=sp["own"],
+        out_shape=jax.ShapeDtypeStruct((b, t, c), x.dtype),
+        compiler_params=_SSM_COMPILER_PARAMS,
+        interpret=interpret,
+        name=SSM_CONV_KERNEL_NAME,
+    )(x, x, rows)
+
+
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "width", "first", "block", "lanes", "interpret"))
+def _ssm_conv_backward(x, rows, dy, width, first, block, lanes, interpret):
+    """(dx [B, T, C] in x's type, the rows' gradient [8, C])."""
+    b, t, _ = x.shape
+    c = rows.shape[1]
+    sp = _ssm_specs(b, t, block, lanes, first, reverse=True)
+    dx, drows = pl.pallas_call(
+        functools.partial(_ssm_conv_bwd_kernel, width=width,
+                          last_step=sp["n"] - 1),
+        grid=(b, c // lanes, sp["n"]),
+        in_specs=[sp["cut"], sp["halo"], sp["rows"], sp["own"]],
+        out_specs=[sp["own"], sp["sums"]],
+        out_shape=[jax.ShapeDtypeStruct((b, t, c), x.dtype),
+                   jax.ShapeDtypeStruct((b, _EDGE, c), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((_EDGE, lanes), jnp.float32)],
+        compiler_params=_SSM_COMPILER_PARAMS,
+        interpret=interpret,
+        name=SSM_CONV_BACKWARD_KERNEL_NAME,
+    )(x, x, rows, dy)
+    return dx, jnp.sum(drows, axis=0)
+
+
+def _lanes_from(dx, first: int, width: int):
+    """dx as lanes ``first`` on of a plane ``width`` wide, zeros around."""
+    return jnp.pad(dx, ((0, 0), (0, 0),
+                        (first, width - first - dx.shape[-1])))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6))
+def _ssm_conv_chain(x, rows, width, first, block, lanes, interpret):
+    return _ssm_conv_forward(x, rows, width=width, first=first, block=block,
+                             lanes=lanes, interpret=interpret)
+
+
+def _ssm_conv_chain_fwd(x, rows, *static):
+    return _ssm_conv_chain(x, rows, *static), (x, rows)
+
+
+def _ssm_conv_chain_bwd(width, first, block, lanes, interpret, res, dy):
+    x, rows = res
+    dx, drows = _ssm_conv_backward(x, rows, dy, width=width, first=first,
+                                   block=block, lanes=lanes,
+                                   interpret=interpret)
+    return _lanes_from(dx, first, x.shape[-1]), drows
+
+
+_ssm_conv_chain.defvjp(_ssm_conv_chain_fwd, _ssm_conv_chain_bwd)
+
+
+def _packed_rows(*vectors):
+    """[8, C] float32: the vectors' rows ([n, C] or [C]) one under the
+    other, zeros below (a float32 tile of parameters a kernel is handed,
+    and the layout of the sums its backward returns)."""
+    rows = jnp.concatenate(
+        [jnp.atleast_2d(v).astype(jnp.float32) for v in vectors])
+    return jnp.pad(rows, ((0, _EDGE - rows.shape[0]), (0, 0)))
+
+
+def ssm_conv_silu(x, taps, bias, first: int = 0, block: int = SSM_TIME_BLOCK,
+                  lanes: int | None = None, interpret: bool | None = None):
+    """y [B, T, C] in x's type = SiLU(conv(x[..., first:first + C]) + bias):
+    the causal depthwise convolution under ``taps`` [W, C] (tap j reads
+    the token W - 1 - j before; nothing before a sequence's first token),
+    float32 inside. x [B, T, >= first + C] bfloat16 is read where it lies
+    (its cotangent: zeros outside the C channels); T whole blocks, C and
+    ``first`` whole lane tiles, W <= 7. ``lanes``: the channels a grid
+    step (the widest block that divides both, unasked)."""
+    width, c = taps.shape
+    if c % LANES or first % LANES or first + c > x.shape[-1] \
+            or width + 1 > _EDGE or bias.shape != (c,):
+        raise ValueError(f"a plane {x.shape} from lane {first} under taps "
+                         f"{taps.shape} and a bias {bias.shape}")
+    lanes = int(lanes or _lane_block(math.gcd(first, c)))
+    return _ssm_conv_chain(x, _packed_rows(taps, bias), width, int(first),
+                           int(block), lanes, bool(interpret))
+
+
+# ---------------------------- Mamba-2: skip, gate, then the grouped norm
+
+
+def _ssm_norm_parts(y_ref, u_ref, z_ref, rows_ref, cols, eps):
+    """Of one norm group (``cols``), float32 [block, group]: u, z, the
+    gate's sigmoid, a = y + D u, the normalised n = v / rms(v) of v = a *
+    SiLU(z), and 1 / rms(v) [block, 1]. ``rows_ref`` [8, lanes]: D a
+    lane, then the scale."""
+    u = u_ref[0, :, cols].astype(jnp.float32)
+    z = z_ref[0, :, cols].astype(jnp.float32)
+    sig = jax.nn.sigmoid(z)
+    a = y_ref[0, :, cols].astype(jnp.float32) + rows_ref[0:1, cols] * u
+    v = a * (z * sig)
+    r = jax.lax.rsqrt(jnp.mean(v * v, axis=-1, keepdims=True) + eps)
+    return u, z, sig, a, v * r, r
+
+
+def _ssm_norm_fwd_kernel(y_ref, u_ref, z_ref, rows_ref, out_ref, *, group,
+                         eps):
+    def one(g):
+        cols = _lanes(g, group)
+        normed = _ssm_norm_parts(y_ref, u_ref, z_ref, rows_ref, cols, eps)[4]
+        out_ref[0, :, cols] = (normed * rows_ref[1:2, cols]).astype(
+            out_ref.dtype)
+
+    _each_group(out_ref.shape[-1] // group, one)
+
+
+def _ssm_norm_bwd_kernel(y_ref, u_ref, z_ref, rows_ref, dout_ref, dy_ref,
+                         du_ref, dz_ref, sums_ref, *, group, eps):
+    """With a, v, n = v r made again and dn = dout * scale:
+
+        dv = r (dn - n mean_group(dn n)),   da = dv SiLU(z) = dy,
+        du = D da,   dz = dv a SiLU'(z),
+        dD += sum_t da u,   dscale += sum_t dout n   (a lane each)
+    """
+    _first_step_zeros(sums_ref, axis=2)
+
+    def one(g):
+        cols = _lanes(g, group)
+        u, z, sig, a, normed, r = _ssm_norm_parts(
+            y_ref, u_ref, z_ref, rows_ref, cols, eps)
+        dout = dout_ref[0, :, cols].astype(jnp.float32)
+        dn = dout * rows_ref[1:2, cols]
+        dv = r * (dn - normed * jnp.mean(dn * normed, axis=-1, keepdims=True))
+        da = dv * (z * sig)
+        dy_ref[0, :, cols] = da.astype(dy_ref.dtype)
+        du_ref[0, :, cols] = (da * rows_ref[0:1, cols]).astype(du_ref.dtype)
+        dz_ref[0, :, cols] = (dv * a * sig * (1.0 + z * (1.0 - sig))).astype(
+            dz_ref.dtype)
+        sums_ref[0:1, cols] += jnp.sum(da * u, axis=0, keepdims=True)
+        sums_ref[1:2, cols] += jnp.sum(dout * normed, axis=0, keepdims=True)
+
+    _each_group(dy_ref.shape[-1] // group, one)
+
+
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "group", "eps", "block", "lanes", "interpret"))
+def _ssm_norm_forward(y, xbc, plane, rows, group, eps, block, lanes,
+                      interpret):
+    b, t, inner = y.shape
+    sp = _ssm_specs(b, t, block, lanes)
+    return pl.pallas_call(
+        functools.partial(_ssm_norm_fwd_kernel, group=group, eps=eps),
+        grid=(b, inner // lanes, sp["n"]),
+        in_specs=[sp["own"]] * 3 + [sp["rows"]],
+        out_specs=sp["own"],
+        out_shape=jax.ShapeDtypeStruct(y.shape, y.dtype),
+        compiler_params=_SSM_COMPILER_PARAMS,
+        interpret=interpret,
+        name=SSM_NORM_KERNEL_NAME,
+    )(y, xbc, plane, rows)
+
+
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "group", "eps", "block", "lanes", "interpret"))
+def _ssm_norm_backward(y, xbc, plane, rows, dout, group, eps, block, lanes,
+                       interpret):
+    """(dy, du, dz, each [B, T, inner] in its operand's type, the rows'
+    gradient [8, inner])."""
+    b, t, inner = y.shape
+    sp = _ssm_specs(b, t, block, lanes)
+    dy, du, dz, drows = pl.pallas_call(
+        functools.partial(_ssm_norm_bwd_kernel, group=group, eps=eps),
+        grid=(b, inner // lanes, sp["n"]),
+        in_specs=[sp["own"]] * 3 + [sp["rows"], sp["own"]],
+        out_specs=[sp["own"]] * 3 + [sp["sums"]],
+        out_shape=[jax.ShapeDtypeStruct(y.shape, x.dtype)
+                   for x in (y, xbc, plane)]
+        + [jax.ShapeDtypeStruct((b, _EDGE, inner), jnp.float32)],
+        compiler_params=_SSM_COMPILER_PARAMS,
+        interpret=interpret,
+        name=SSM_NORM_BACKWARD_KERNEL_NAME,
+    )(y, xbc, plane, rows, dout)
+    return dy, du, dz, jnp.sum(drows, axis=0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _ssm_norm_chain(y, xbc, plane, rows, group, eps, block, lanes, interpret):
+    return _ssm_norm_forward(y, xbc, plane, rows, group=group, eps=eps,
+                             block=block, lanes=lanes, interpret=interpret)
+
+
+def _ssm_norm_chain_fwd(y, xbc, plane, rows, *static):
+    return _ssm_norm_chain(y, xbc, plane, rows, *static), (y, xbc, plane, rows)
+
+
+def _ssm_norm_chain_bwd(group, eps, block, lanes, interpret, res, dout):
+    y, xbc, plane, rows = res
+    dy, du, dz, drows = _ssm_norm_backward(
+        *res, dout, group=group, eps=eps, block=block, lanes=lanes,
+        interpret=interpret)
+    return (dy, _lanes_from(du, 0, xbc.shape[-1]),
+            _lanes_from(dz, 0, plane.shape[-1]), drows)
+
+
+_ssm_norm_chain.defvjp(_ssm_norm_chain_fwd, _ssm_norm_chain_bwd)
+
+
+def ssm_gate_norm(y, xbc, plane, skip, scale, groups: int, eps: float = 1e-5,
+                  block: int = SSM_TIME_BLOCK, lanes: int | None = None,
+                  interpret: bool | None = None):
+    """out [B, T, inner] in y's type = v / sqrt(mean_group v^2 + eps) *
+    scale, v = (y + skip * u) * SiLU(z), the mean over each of ``groups``
+    stretches of inner / groups channels, float32 inside.
+
+    y [B, T, inner] bfloat16 (the scan's output); u and z the first
+    ``inner`` lanes of ``xbc`` [B, T, >= inner] and of ``plane``
+    [B, T, >= inner], read where they lie (their cotangents: zeros past
+    them); ``skip`` and ``scale`` [inner], a value a lane. T whole blocks,
+    a group whole lane tiles. ``lanes``: the channels a grid step, whole
+    groups (the widest block of them that divides ``inner``, unasked)."""
+    inner = y.shape[-1]
+    group = inner // groups
+    if inner % groups or group % LANES or skip.shape != (inner,) \
+            or scale.shape != (inner,) or min(
+                xbc.shape[-1], plane.shape[-1]) < inner:
+        raise ValueError(f"y {y.shape} in {groups} groups, u of {xbc.shape}, "
+                         f"z of {plane.shape}, skip {skip.shape}, scale "
+                         f"{scale.shape}")
+    lanes = int(lanes or _lane_block(inner, group))
+    if lanes % group:
+        raise ValueError(f"{lanes} lanes a block are not whole groups of "
+                         f"{group}")
+    return _ssm_norm_chain(y, xbc, plane, _packed_rows(skip, scale), group,
+                           float(eps), int(block), lanes, bool(interpret))

@@ -39,7 +39,7 @@ from dinov3_tpu.ops.causal_attention import (
 from dinov3_tpu.ops.ffn import routed_rows_capacity
 from dinov3_tpu.ops.grouped_matmul import grouped_matmul_path
 from dinov3_tpu.ops.kda import kda_path
-from dinov3_tpu.ops.mixer_chains import mixer_chain_path
+from dinov3_tpu.ops.mixer_chains import mixer_chain_path, ssm_chain_path
 from dinov3_tpu.ops.routed_rows import combine_form
 from dinov3_tpu.ops.ssd import ssd_path
 
@@ -106,10 +106,13 @@ class LMMetaArch:
                     dc.ssm_state_size, rows[1], dc.dtype)
                 logger.info("layer %d ssd_core, both passes: %s (%s)", i, path,
                             why)
-                logger.info(
-                    "layer %d ssm_mixer's chains, both passes: plain (%s)", i,
-                    mixer_chain_path(rows[1], (dc.mamba_head_dim,),
-                                     (dc.mamba_num_heads,), dc.dtype)[1])
+                inner = dc.mamba_num_heads * dc.mamba_head_dim
+                path, why = ssm_chain_path(
+                    rows[1], inner,
+                    inner + 2 * dc.mamba_n_groups * dc.ssm_state_size,
+                    dc.mamba_n_groups, dc.dtype)
+                logger.info("layer %d ssm_mixer's chains, both passes: %s (%s)",
+                            i, path, why)
             elif mixer in delta:
                 scope, dk, dv, heads, gate_heads = delta[mixer]
                 path, why = kda_path(dk, dv, gate_heads=gate_heads)

@@ -223,6 +223,88 @@ def test_ssd_chunk_kernels_compile_for_v5e(one_chip, states):
 
 
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
+@pytest.mark.parametrize("chain", ["conv", "norm"])
+def test_ssm_chain_kernels_compile_for_v5e(one_chip, chain, direction):
+    """``Mamba2Mixer``'s two chains at the ``nemotron_h`` cell's shapes (2
+    sequences of 8,192 tokens; in_proj's plane [z 4096 | xBC 6144 | dt 64]
+    and zeros up to 10,368 lanes, 8 norm groups of 512): one kernel a pass,
+    its blocks cut from the planes where they lie — no copy and no fusion
+    of a plane's size beside the forward call, and beside the backward
+    only the zeros around what it wrote."""
+    from dinov3_tpu.ops import mixer_chains as mc
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    plane, xbc, y = (((2, 8192, n), bf16) for n in (10368, 6144, 4096))
+    assert mc.ssm_chain_path(8192, 4096, 6144, 8, bf16,
+                             interpret=False)[0] == "kernel"
+    if chain == "conv":
+        names = (mc.SSM_CONV_KERNEL_NAME, mc.SSM_CONV_BACKWARD_KERNEL_NAME)
+        shapes, ct = [plane, ((4, 6144), f32), ((6144,), f32)], xbc
+
+        def fwd(plane, taps, bias):
+            return mc.ssm_conv_silu(plane, taps, bias, first=4096,
+                                    interpret=False)
+    else:
+        names = (mc.SSM_NORM_KERNEL_NAME, mc.SSM_NORM_BACKWARD_KERNEL_NAME)
+        shapes, ct = [y, xbc, plane, ((4096,), f32), ((4096,), f32)], y
+
+        def fwd(y, xbc, plane, skip, scale):
+            return mc.ssm_gate_norm(y, xbc, plane, skip, scale, 8, 1e-5,
+                                    interpret=False)
+
+    def bwd(*x):
+        return jax.vjp(fwd, *x[:-1])[1](x[-1])
+
+    fn, shapes = (fwd, shapes) if direction == "fwd" else (bwd, shapes + [ct])
+    text = _compiled_text(fn, one_chip, *shapes)
+    assert text.count("tpu_custom_call") == 1
+    assert names[direction == "bwd"] in text
+    entry = text[text.index("ENTRY"):]
+    assert " copy(" not in entry and "[2,8192," not in "".join(
+        line for line in entry.splitlines() if " fusion(" in line)
+    assert entry.count(" pad(") == (0 if direction == "fwd" else
+                                    1 if chain == "conv" else 2)
+
+
+def test_mamba2_mixer_program_cuts_its_blocks_from_a_lane_tiled_plane_for_v5e(
+        one_chip):
+    """The whole mixer's gradient program with the scan and both chains on
+    their kernels: in_proj's plane is ``[2, 8192, 10368]`` (81 lane tiles:
+    at its published 10,304 lanes XLA lays it out token-minor and every
+    kernel that reads it pays a transposing copy), no copy of a plane's
+    size is in the program, every kernel is there once (the rule's forward
+    kernels and the backward's), and the three parts of the plane's
+    cotangent reach in_proj's products without being joined in HBM."""
+    import flax.linen as nn
+
+    from dinov3_tpu.models.decoder import Mamba2Mixer
+    from dinov3_tpu.ops import mixer_chains as mc
+
+    mixer = Mamba2Mixer(64, 64, 8, 128, core_interpret=False,
+                        chains_interpret=False)
+    x = jax.ShapeDtypeStruct((2, 8192, 2688), jnp.bfloat16, sharding=one_chip)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        nn.meta.unbox(jax.eval_shape(mixer.init, jax.random.key(0), x)))
+    assert params["params"]["in_proj"]["kernel"].shape == (2688, 10304)
+
+    def loss(params, x):
+        return jnp.sum(mixer.apply(params, x).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    entry = text[text.index("ENTRY"):]
+    assert "bf16[2,8192,10368]{2,1,0" in entry
+    assert "[2,8192,10304]" not in entry
+    assert not [line for line in entry.splitlines() if " copy(" in line
+                and "[2,8192," in line and ",64]" not in line.split("copy(")[0]]
+    for name in (mc.SSM_CONV_KERNEL_NAME, mc.SSM_CONV_BACKWARD_KERNEL_NAME,
+                 mc.SSM_NORM_KERNEL_NAME, mc.SSM_NORM_BACKWARD_KERNEL_NAME):
+        assert entry.count(f"%{name}") >= 1, name
+    assert entry.count('custom_call_target="tpu_custom_call"') == 6
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
 @pytest.mark.parametrize("q, kv, dv, window", [
     ((1, 16384, 28, 128), 4, 128, 4096),
     ((1, 16384, 28, 128), 4, 128, None),

@@ -65,6 +65,7 @@ TINY = {
     "mla_attn_shapes": {"wide16k": (1, 256, 1, 1, 256, 128, None)},
     "ssd_shape": (1, 256, 2, 64, 1, 128),
     "ssd_chunks": (256,),
+    "ssd_chain_blocks": ((64, 128),),
     "ssd_attn_shapes": {"gqa16": (1, 256, 16, 1, 128, 128, None)},
     "ssd_moe_shapes": {"tiny15": (256, 4, 512, 4, 128, 192, "relu2", (0.3,))},
     "gqa_shipped_blocks": (128, 256),
@@ -182,6 +183,15 @@ def test_smoke_rehearsal_runs_every_phase(smoke, monkeypatch, capsys):
                    "ssd: scan: norm of the difference over the norm, kernel "
                    "to plain scan, output and gradients u B C dt a",
                    "ssd: scan, kernel at chunks of 256: forward",
+                   "ssd: chains (1, 256) x 128 | 384 | 2 in a plane of 640: "
+                   "the mixer takes the kernel (interpreted)",
+                   "ssd: chain conv: norm of the difference over the norm, "
+                   "kernel to plain chain, output and gradients plane taps "
+                   "bias",
+                   "ssd: chain norm: norm of the difference over the norm, "
+                   "kernel to plain chain, output and gradients y xbc plane D "
+                   "scale",
+                   "ssd: chain norm, kernel at blocks of 64 x 128: first call",
                    "gqa: gqa16 core (1, 256, 16, 1, 128, 128) window None: the "
                    "entry point takes the kernel (interpreted)",
                    "moe: tiny15 (512, 4, 128, 192) relu2: the layer takes the "

@@ -263,6 +263,34 @@ def test_mixer_on_the_interpreted_kernels_is_its_plain_path():
     assert max(jax.tree.leaves(gaps)) < 5e-2, gaps
 
 
+def test_mixer_on_the_interpreted_chains_is_its_plain_path():
+    """``Mamba2Mixer`` at 8 heads of 64 in 2 norm groups, bfloat16, two
+    time blocks: the two chains' kernel pairs (``chains_interpret``: the
+    test's switch; in_proj's plane then ends in zero lanes up to a whole
+    tile) against the plain chains on ONE set of parameters, value and
+    every leaf's gradient; the scan between them is the plain one on both
+    sides."""
+    from dinov3_tpu.models.decoder import Mamba2Mixer
+    from dinov3_tpu.ops.mixer_chains import SSM_TIME_BLOCK
+
+    ks = jax.random.split(jax.random.key(6), 3)
+    x = jax.random.normal(ks[0], (2, 2 * SSM_TIME_BLOCK, 64), jnp.bfloat16)
+    mixers = [Mamba2Mixer(8, 64, 2, 128, chains_interpret=flag)
+              for flag in (None, True)]
+    params = spread(jax.jit(mixers[0].init)(ks[1], x)["params"], ks[2], 0.1)
+    assert params["in_proj"]["kernel"].shape == (64, 512 + 1024 + 8)
+    out = [jax.jit(jax.value_and_grad(lambda p, x, m=m: jnp.sum(jnp.sin(
+        m.apply({"params": p}, x).astype(jnp.float32))), argnums=(0, 1)))(
+            params, x) for m in mixers]
+    (plain, gp), (kernel, gk) = out
+    assert abs(float(plain) - float(kernel)) < 2e-2 * abs(float(plain)) + 1e-2
+    assert jax.tree.structure(gk) == jax.tree.structure(gp)
+    assert {k for k in gp[0]} == {"in_proj", "conv", "conv_bias", "A_log",
+                                  "dt_bias", "D", "norm_scale", "out_proj"}
+    gaps = _rel(gk, gp)
+    assert max(jax.tree.leaves(gaps)) < 5e-2, gaps
+
+
 # ---------------- (c) a block of one sublayer ----------------
 
 def test_a_block_of_one_sublayer_is_one_norm_and_one_residual_add():
@@ -659,7 +687,8 @@ def test_config_rules():
 def test_the_paths_are_read_off_shapes_at_the_published_sizes(caplog):
     """``ssd_path``, ``causal_attention_path`` and ``grouped_matmul_path``
     at the cell's shapes: on a TPU (``interpret=False``: described, not
-    attached) the scan at 64 heads of 64 on 128 in 8 groups, the causal
+    attached) the scan at 64 heads of 64 on 128 in 8 groups, the two
+    chains around it, the causal
     core at [2, 8192, 32 | 2, 128] (SIXTEEN query heads a key/value head)
     and the un-gated experts at 2688 x 1856 (14.5 lane tiles) take their
     kernels; here, on the CPU, the plain paths, and the set-up log says
@@ -669,11 +698,16 @@ def test_the_paths_are_read_off_shapes_at_the_published_sizes(caplog):
     from dinov3_tpu.ops.causal_attention import causal_attention_path
     from dinov3_tpu.ops.ffn import routed_rows_capacity
     from dinov3_tpu.ops.grouped_matmul import grouped_matmul_path
+    from dinov3_tpu.ops.mixer_chains import ssm_chain_path
     from dinov3_tpu.ops.ssd import ssd_path
     from dinov3_tpu.train.lm_meta_arch import LMMetaArch
 
     assert ssd_path(64, 64, 8, 128, 8192, jnp.bfloat16, False) == (
         "kernel", "compiled for the TPU")
+    # the chains around it, at in_proj's [2, 8192, 10304]: 4,096 channels
+    # in 8 norm groups, 6,144 under the convolution
+    assert ssm_chain_path(8192, 4096, 6144, 8, jnp.bfloat16,
+                          interpret=False) == ("kernel", "compiled for the TPU")
     shapes = ((2, 8192, 32, 128),) + ((2, 8192, 2, 128),) * 2
     assert causal_attention_path(shapes, None, False) == (
         "kernel", "compiled for the TPU")
@@ -690,7 +724,8 @@ def test_the_paths_are_read_off_shapes_at_the_published_sizes(caplog):
     said = [r.getMessage() for r in caplog.records]
     cpu = "(the backend is cpu, not a TPU)"
     assert sum(f"ssd_core, both passes: scan {cpu}" in s for s in said) == 4
-    assert sum("ssm_mixer's chains, both passes: plain" in s for s in said) == 4
+    assert sum(f"ssm_mixer's chains, both passes: plain {cpu}" in s
+               for s in said) == 4
     assert sum(f"gqa_core (full_attn), both passes: tiles {cpu}" in s
                for s in said) == 1
     assert sum(f"moe_experts, both passes: ragged_dot {cpu}; rows moved by "

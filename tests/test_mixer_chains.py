@@ -160,7 +160,105 @@ def test_log_decay_is_the_plain_chain():
         _close(g, w, rel=rel)
 
 
+def _plain_ssm_conv(plane, taps, bias, first):
+    """``Mamba2Mixer.conv_act``'s convolution, written out."""
+    x = plane[..., first:first + taps.shape[1]].astype(f32)
+    return jax.nn.silu(causal_depthwise_conv(x, taps.astype(f32))
+                       + bias.astype(f32)).astype(plane.dtype)
+
+
+@pytest.mark.parametrize("lanes, first", [(None, 512), (128, 512), (256, 0)],
+                         ids=["2_lane_blocks", "8_lane_blocks", "a_slice"])
+def test_ssm_conv_silu_is_the_plain_chain(lanes, first):
+    """[z | xBC | dt] of 512 | 1,024 | 8 lanes, the joined channels cut
+    from the plane where they lie (or handed as a plane of their own):
+    value, the plane's gradient (zeros outside the joined channels), the
+    taps' and the bias's, over four time blocks of two sequences."""
+    joined, width = 1024, first + 1024 + (8 if first else 0)
+    plane, taps, bias, dy = _draw(5, (B, T, width), (4, joined), (joined,),
+                                  (B, T, joined))
+    plane, dy = plane.astype(bf16), dy.astype(bf16)
+
+    def kernel_path(plane, taps, bias):
+        return mc.ssm_conv_silu(plane, taps, bias, first=first, block=BLOCK,
+                                lanes=lanes, interpret=True)
+
+    args = (plane, 0.5 * taps, 0.5 * bias)
+    got, (dx, dw, db) = _value_and_grads(kernel_path, args, dy)
+    want, (dx_w, dw_w, db_w) = _value_and_grads(
+        functools.partial(_plain_ssm_conv, first=first), args, dy)
+    assert got.dtype == bf16 and got.shape == (B, T, joined)
+    _close(got, want, rel=1 / 128)
+    assert np.mean(np.asarray(got) != np.asarray(want)) < 1e-3
+    assert dx.dtype == bf16 and dx.shape == plane.shape
+    _close(dx, dx_w, rel=1 / 128)
+    assert dw.dtype == db.dtype == f32
+    _close(dw, dw_w)
+    _close(db, db_w)
+    if first:                              # nothing flows into z's or dt's
+        assert not np.asarray(dx[..., :first]).any()
+        assert not np.asarray(dx[..., first + joined:]).any()
+
+
+def _plain_ssm_norm(y, xbc, plane, skip, scale, groups, eps):
+    """``Mamba2Mixer.gated_group_norm``, written out: the skip a HEAD,
+    the gate before the norm, the norm over a group's channels."""
+    b, t, inner = y.shape
+    h = skip.shape[0]
+    v = y.astype(f32).reshape(b, t, h, -1) + skip.astype(f32)[:, None] * (
+        xbc[..., :inner].astype(f32).reshape(b, t, h, -1))
+    v = v.reshape(b, t, inner) * jax.nn.silu(plane[..., :inner].astype(f32))
+    v = v.reshape(b, t, groups, inner // groups)
+    v = v * jax.lax.rsqrt(jnp.mean(jnp.square(v), axis=-1, keepdims=True) + eps)
+    return (v.reshape(b, t, inner) * scale.astype(f32)).astype(y.dtype)
+
+
+@pytest.mark.parametrize("groups, lanes", [(4, None), (4, 128), (1, None),
+                                           (2, 256)],
+                         ids=["4_groups_a_block", "4_groups_4_blocks",
+                              "1_group", "2_groups_2_blocks"])
+def test_ssm_gate_norm_is_the_plain_chain(groups, lanes):
+    """y of 512 channels (8 heads of 64), u the first 512 lanes of a
+    1,024-lane plane, z the first 512 of a 1,544-lane one: value and the
+    gradients of y, u (zeros past it), z (zeros past it), D a HEAD and the
+    scale, the two sums over four time blocks of two sequences."""
+    inner, heads, eps = 512, 8, 1e-5
+    y, xbc, plane, skip, scale, dout = _draw(
+        6, (B, T, inner), (B, T, 1024), (B, T, 1544), (heads,), (inner,),
+        (B, T, inner))
+    y, xbc, plane, dout = (a.astype(bf16) for a in (y, xbc, plane, dout))
+    args = (y, xbc, plane, 1 + skip, 1 + 0.3 * scale)
+
+    def kernel_path(y, xbc, plane, skip, scale):
+        return mc.ssm_gate_norm(y, xbc, plane, jnp.repeat(skip, inner // heads),
+                                scale, groups, eps, block=BLOCK, lanes=lanes,
+                                interpret=True)
+
+    got, grads = _value_and_grads(kernel_path, args, dout)
+    want, grads_w = _value_and_grads(functools.partial(
+        _plain_ssm_norm, groups=groups, eps=eps), args, dout)
+    assert got.dtype == bf16
+    _close(got, want, rel=1 / 128)
+    assert np.mean(np.asarray(got) != np.asarray(want)) < 1e-3
+    for g, w, a, rel in zip(grads, grads_w, args, (1 / 128,) * 3 + (2e-5,) * 2):
+        assert g.dtype == a.dtype and g.shape == a.shape
+        _close(g, w, rel=rel)
+    assert not np.asarray(grads[1][..., inner:]).any()
+    assert not np.asarray(grads[2][..., inner:]).any()
+
+
 @pytest.mark.parametrize("kwargs, path, why", [
+    (dict(ssm=True), "plain", "the backend is cpu, not a TPU"),
+    (dict(ssm=True, interpret=True), "kernel", "interpreted"),
+    (dict(ssm=True, interpret=False), "kernel", "compiled for the TPU"),
+    (dict(ssm=True, dtype=f32, interpret=True), "plain", "float32"),
+    (dict(ssm=True, joined=6144 + 64, interpret=True), "plain",
+     "6208 joined channels"),
+    (dict(ssm=True, groups=3, interpret=True), "plain", "in 3 norm groups"),
+    (dict(ssm=True, groups=64, interpret=True), "plain",
+     "not whole lane tiles"),
+    (dict(ssm=True, length=8192 + 128, interpret=True), "plain",
+     "not whole blocks of 256"),
     (dict(), "plain", "the backend is cpu, not a TPU"),
     (dict(interpret=True), "kernel", "interpreted"),
     (dict(interpret=False), "kernel", "compiled for the TPU"),
@@ -171,8 +269,16 @@ def test_log_decay_is_the_plain_chain():
     (dict(dtype=f32, interpret=True), "plain", "float32"),
 ])
 def test_mixer_chain_path_reads_the_operands(kwargs, path, why):
-    args = dict(length=8192, head_dims=(128, 128), heads=(16, 32), dtype=bf16)
-    got, said = mc.mixer_chain_path(**{**args, **kwargs})
+    """(``ssm``: ``ssm_chain_path``, ``Mamba2Mixer``'s twin of it, at the
+    published 4,096 channels in 8 norm groups and 6,144 joined.)"""
+    if kwargs.get("ssm"):
+        args = dict(length=8192, inner=4096, joined=6144, groups=8, dtype=bf16)
+        kwargs = {k: v for k, v in kwargs.items() if k != "ssm"}
+        got, said = mc.ssm_chain_path(**{**args, **kwargs})
+    else:
+        args = dict(length=8192, head_dims=(128, 128), heads=(16, 32),
+                    dtype=bf16)
+        got, said = mc.mixer_chain_path(**{**args, **kwargs})
     assert got == path and why in said
 
 
